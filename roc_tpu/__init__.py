@@ -23,5 +23,4 @@ Layer map (the TPU-native analog of SURVEY.md §1):
 
 __version__ = "0.2.0"
 
-import roc_tpu._jax_compat  # noqa: F401  (installs jax 0.4.x polyfills)
 from roc_tpu.graph.csr import Csr  # noqa: F401

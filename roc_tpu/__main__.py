@@ -9,8 +9,10 @@ Here `-file <prefix>` consumes the same on-disk dataset format; `-dataset
 
 from __future__ import annotations
 
+import math
 import sys
 
+from roc_tpu import cache, device
 from roc_tpu.graph import datasets
 from roc_tpu.models import build_model
 from roc_tpu.train.config import parse_args
@@ -26,6 +28,7 @@ def main(argv=None) -> int:
         # cluster (GKE/TPU-VM auto-detection inside initialize()).
         import jax
         jax.distributed.initialize()
+    cache.enable_compile_cache()
     if not cfg.layers:
         print("error: -layers is required (e.g. -layers 1433-16-7)",
               file=sys.stderr)
@@ -83,6 +86,9 @@ def main(argv=None) -> int:
           f"        decay_rate = {cfg.decay_rate:.4f} decay_steps = {cfg.decay_steps}",
           file=sys.stderr)
     print(f"        Layers: {' '.join(map(str, cfg.layers))}", file=sys.stderr)
+    # JAX falls back to the CPU when libtpu finds no chip: say where the
+    # run landed before it spends any time there.
+    print(f"        {device.banner()}", file=sys.stderr)
 
     if cfg.filename:
         ds = datasets.load_roc_dataset(cfg.filename, cfg.layers[0],
@@ -118,6 +124,10 @@ def main(argv=None) -> int:
     # One trainer build — the partition, the plans, and the compiled steps
     # are shared by -check-sharding, -analyze, and the training run.
     trainer = make_trainer(cfg, ds, model)
+    gd = getattr(trainer, "gdata", None)       # the stream executor has none
+    print(f"        aggregate_backend = {cfg.aggregate_backend} -> "
+          f"{getattr(gd, 'backend', 'stream')} "
+          f"(pallas_interpret={not device.on_tpu()})", file=sys.stderr)
     if cfg.check_sharding and cfg.num_parts > 1:
         from roc_tpu.parallel.check import check_shard_consistency
         check_shard_consistency(cfg, ds, model, sharded_trainer=trainer)
@@ -125,8 +135,7 @@ def main(argv=None) -> int:
               f"({cfg.num_parts} parts, halo={cfg.halo})", file=sys.stderr)
 
     if not cfg.analyze:
-        trainer.train()
-        return 0
+        return _exit_code(trainer.train())
 
     # -analyze: static audit of the lowered steps before the run, retrace
     # report after it.  Budget diffs apply only when this exact config has
@@ -140,7 +149,7 @@ def main(argv=None) -> int:
     if report.key in budgets:
         violations += analysis.compare_report(report, budgets[report.key])
     with analysis.RetraceGuard(on_violation="record") as guard:
-        trainer.train()
+        stats = trainer.train()
     print(guard.report(), file=sys.stderr)
     violations += guard.violations
     if violations:
@@ -149,7 +158,17 @@ def main(argv=None) -> int:
         return 3
     print("# -analyze: clean (collective audit + retrace guard)",
           file=sys.stderr)
-    return 0
+    return _exit_code(stats)
+
+
+def _exit_code(stats) -> int:
+    """The non-finite guard skips bad updates and train() returns; a run
+    that ENDS non-finite has still failed."""
+    if math.isfinite(stats.final_loss):
+        return 0
+    print(f"error: final loss is {stats.final_loss} (non-finite)",
+          file=sys.stderr)
+    return 1
 
 
 if __name__ == "__main__":

@@ -20,9 +20,11 @@ What is budgeted per step function (train and eval separately):
     line (e.g. ``all_reduce``) are budgeted count-only (elems 0);
   * lines mentioning ``f64`` and ``convert``-to-f64 upcasts (normally 0 —
     the tree is fp32/bf16 by design);
-  * the entry arguments' ``mhlo.sharding`` signature — a dropped or
-    altered placement (e.g. a replicated tensor that should be
-    parts-sharded) changes this string before it changes any op count.
+  * the entry arguments' sharding signature, read off the
+    ``sdy.sharding`` annotations the Shardy partitioner emits (jax 0.9
+    lowers with it by default) — a dropped or altered placement (e.g. a
+    replicated tensor that should be parts-sharded) changes this string
+    before it changes any op count.
 
 Budgets are keyed ``model/dataset/p<parts>/<configured-backend>/<exchange>``
 and are *lowering*-level: regenerate with ``tools/roclint.py
@@ -51,7 +53,7 @@ _OP_RES = {op: re.compile(r"\bstablehlo\." + op + r"\b")
            for op in TRACKED_OPS}
 _ARROW_TENSOR_RE = re.compile(r"->\s*tensor<([^>]*)>")
 _CONVERT_F64_RE = re.compile(r"stablehlo\.convert\b.*->\s*tensor<[^>]*f64")
-_SHARDING_RE = re.compile(r'mhlo\.sharding = "([^"]+)"')
+_SHARDING_RE = re.compile(r"sdy\.sharding = #sdy\.sharding<([^>]*)>")
 
 
 def _tensor_elems(body: str) -> int:
@@ -64,7 +66,8 @@ def _tensor_elems(body: str) -> int:
 
 
 def _main_arg_shardings(txt: str) -> List[str]:
-    """Per-entry-arg mhlo.sharding strings ("" = unannotated), in order."""
+    """Per-entry-arg sdy.sharding bodies, e.g. ``@mesh, [{"parts"}, {}]``
+    ("" = unannotated), in order."""
     i = txt.find("@main(")
     if i < 0:
         return []
@@ -306,7 +309,6 @@ def build_audit_trainer(spec: AuditSpec, *, exchange: Optional[str] = None):
     ``exchange`` overrides the lowered exchange mode while keeping the
     spec's budget key — the seeded-mutation tests use this to audit an
     allgather program against the halo budget."""
-    import roc_tpu  # noqa: F401 — installs the jax.shard_map polyfill
     from roc_tpu.graph import datasets
     from roc_tpu.models import build_model
     from roc_tpu.train.config import Config
@@ -325,7 +327,6 @@ def build_audit_trainer(spec: AuditSpec, *, exchange: Optional[str] = None):
 
 def build_audit_engine(spec: AuditSpec):
     """Cold-start (queueless) the serving engine for one serve row."""
-    import roc_tpu  # noqa: F401 — installs the jax.shard_map polyfill
     from roc_tpu.graph import datasets
     from roc_tpu.models import build_model
     from roc_tpu.serve.engine import ServeEngine

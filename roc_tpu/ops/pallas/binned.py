@@ -479,11 +479,22 @@ _MODEL_H = 256                # nominal width: plans are H-independent
 # roofline constant (obs/roofline.py, stdlib-only): one re-fit lands in
 # bench.py, the memory estimator, and this credit at once.
 from roc_tpu.obs.roofline import PEAK_BW as _HBM_BW  # noqa: E402
-# VMEM feasibility for choose_geometry's candidates, at the nominal model
-# width and bf16 staging (the "fast" precision the hardware path runs):
-# phase 1 holds the ch x sb one-hot, double gbuf, and an sb x H x block;
-# phase 2 the ch2 x rb one-hot, a ch2 x H staging chunk, and the fp32
-# rb x H resident window.  ~16 MB/core on v5e; leave headroom.
+# Scoped VMEM.  Mosaic gives a kernel 16 MiB of the v5e's 128 MiB unless
+# it is asked for more, and that default is below what the flat and wide
+# presets need at H=256 (the round-2 CH2=8192 compile failure).  The
+# two-pass kernels therefore ask per call: vmem_limit_bytes =
+# _p1_vmem_bytes / _p2_vmem_bytes at the REAL padded width and precision
+# (never more than _VMEM_LIMIT_MAX — beyond it the compile fails with
+# Mosaic's own message).  choose_geometry, the tuned tier and the tuner's
+# lattice admit a candidate when its nominal footprint (_MODEL_H, fast)
+# is within _VMEM_NOMINAL_CAP: the largest preset today (GEOM_FLAT,
+# 32 MiB nominal) asks for 86 MiB at H=512 exact, still inside the chip.
+_VMEM_NOMINAL_CAP = 40 * (1 << 20)
+_VMEM_LIMIT_MAX = 100 * (1 << 20)
+# Gate for the opt-in fused families' own *_vmem_ok formulas (fused,
+# mega, cross-layer, fused GAT).  Those formulas are NOT checked against
+# Mosaic and their kernels still compile under the 16 MiB default
+# (CHANGES.md PR 21 has the compile inventory).
 _VMEM_BUDGET = 14 * (1 << 20)
 
 
@@ -550,24 +561,59 @@ def _matmul_cost(num_edges: int, num_rows: int) -> float:
     return _matmul_chunks(num_edges, num_rows) * rate
 
 
+# What Mosaic allocates for one grid step of the two-pass kernels: the
+# pipelined operand blocks (double-buffered; a (rows, 1) int32 block
+# lane-pads to 128 lanes), the scratch, and the values the body
+# materialises — the bf16 one-hot, each fp32 dot result, the masked /
+# split copies of the feature operand.  An UPPER bound, checked with
+# libtpu's compiler for a v5e topology (PR 21): every preset at H in
+# {128, 256, 512} and both precisions compiles with the limit set to
+# this model, and the smallest limit that compiles — bisected for 44
+# kernel/geometry/width/precision cases, pinned in
+# tests/test_chip_smoke.py — is below it in every case.  _VMEM_SLACK
+# covers Mosaic's own small scratch.
+_VMEM_SLACK = 2 * (1 << 20)
+
+
+def _p1_vmem_bytes(geom: Geometry, H: int = _MODEL_H,
+                   exact: bool = False) -> int:
+    stg = staging_itemsize(geom, exact)
+    two = 2 if geom.flat else 1       # flat: two x blocks, two one-hots
+    nd = 3 if exact else 1            # exact: hi/mid/lo split dots
+    blocks = (2 * geom.ch * H * stg               # gbuf scratch
+              + 2 * geom.ch * 128 * 4             # srcl (ch, 1) int32
+              + 2 * two * geom.sb * H * 4)        # x block(s)
+    body = two * (geom.ch * geom.sb * 2 + nd * geom.ch * H * 4)
+    if exact:
+        body += 3 * geom.sb * H * 2 + 2 * geom.sb * H * 4
+    return blocks + body + _VMEM_SLACK
+
+
+def _p2_vmem_bytes(geom: Geometry, H: int = _MODEL_H,
+                   exact: bool = False) -> int:
+    stg = staging_itemsize(geom, exact)
+    nd = 3 if exact else 1
+    blocks = (2 * geom.ch2 * H * stg              # staging chunk
+              + 2 * geom.ch2 * 128 * 4            # dstl (ch2, 1) int32
+              + 2 * geom.rb * H * 4)              # resident out window
+    body = (geom.ch2 * geom.rb * 2 + geom.ch2 * H * stg
+            + nd * geom.rb * H * 4)
+    if exact:
+        body += 3 * geom.ch2 * H * 2 + 2 * geom.ch2 * H * 4
+    return blocks + body + _VMEM_SLACK
+
+
 def _vmem_bytes(geom: Geometry, H: int = _MODEL_H,
                 exact: bool = False) -> int:
-    if geom.flat:
-        # Flat staging dtype is a function of the geometry's unit (fp32 at
-        # 8 rows — they tear bf16 (16, 128) tiles — bf16 at unit=16);
-        # phase 1 streams TWO x blocks per chunk.
-        stg = staging_itemsize(geom, exact)
-        p1 = (geom.ch * geom.sb * 2 + 2 * geom.ch * H * stg
-              + 2 * geom.sb * H * 4)
-        p2 = (geom.ch2 * geom.rb * 2 + geom.ch2 * H * stg
-              + geom.rb * H * 4)
-        return max(p1, p2)
-    stg = 4 if exact else 2
-    p1 = (geom.ch * geom.sb * 2 + 2 * geom.ch * H * stg
-          + geom.sb * H * 4)
-    p2 = (geom.ch2 * geom.rb * 2 + geom.ch2 * H * stg
-          + geom.rb * H * 4)
-    return max(p1, p2)
+    """Scoped VMEM the geometry's larger phase needs (see above)."""
+    return max(_p1_vmem_bytes(geom, H, exact),
+               _p2_vmem_bytes(geom, H, exact))
+
+
+def _vmem_params(need: int):
+    """Mosaic compiler params asking for ``need`` bytes of scoped VMEM."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(int(need), _VMEM_LIMIT_MAX))
 
 
 def _binned_cost_model(padded_rows: int, geom: Geometry,
@@ -1078,7 +1124,7 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
     stats_cache = {}
     for g in cands:
         g = g.check()
-        if _vmem_bytes(g) > _VMEM_BUDGET:
+        if _vmem_bytes(g) > _VMEM_NOMINAL_CAP:
             continue
         sk = (g.sb, g.rb)
         if sk not in stats_cache:
@@ -1272,13 +1318,13 @@ def build_binned_plan(edge_src: np.ndarray, edge_dst: np.ndarray,
 
 def _plan_cache_dir() -> str:
     """Plan cache location; '' disables.  ROC_PLAN_CACHE=0 opts out,
-    ROC_PLAN_CACHE_DIR overrides (tests point it at tmp dirs)."""
+    ROC_PLAN_CACHE_DIR overrides (tests point it at tmp dirs); the
+    default sits inside the checkout (roc_tpu/cache.py), so plans built
+    by one checkout's builders are never served to another's kernels."""
     if os.environ.get("ROC_PLAN_CACHE", "1") == "0":
         return ""
-    return os.environ.get(
-        "ROC_PLAN_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     f"roc_plans_u{os.getuid()}"))
+    from roc_tpu.cache import cache_dir
+    return os.environ.get("ROC_PLAN_CACHE_DIR") or cache_dir("plans")
 
 
 def _plan_cache_path(edge_src, edge_dst, num_rows, table_rows,
@@ -1965,6 +2011,7 @@ def _p1_run(x, blk, off, srcl, nchunks: int, stg_rows: int,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((stg_rows, H), st),
+        compiler_params=_vmem_params(_p1_vmem_bytes(geom, H, exact)),
         interpret=interpret,
     )(blk, off, srcl, x)
 
@@ -2096,6 +2143,7 @@ def _p1_flat_run(x, blk, blk2, dsrc, ddst, srcl, nchunks: int,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((stg_rows, H),
                                        staging_dtype(geom, exact)),
+        compiler_params=_vmem_params(_p1_vmem_bytes(geom, H, exact)),
         interpret=interpret,
     )(blk, blk2, dsrc, ddst, srcl, x, x)
 
@@ -2141,6 +2189,7 @@ def _p2_run(stg, obi, first, dstl, nchunks: int, out_rows: int,
     return pl.pallas_call(
         partial(_p2_kernel, exact=exact, geom=geom), grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((out_rows, H), jnp.float32),
+        compiler_params=_vmem_params(_p2_vmem_bytes(geom, H, exact)),
         interpret=interpret,
     )(obi, first, dstl, stg)
 
@@ -3685,6 +3734,18 @@ def run_binned_region_bwd(g, y, x, ws, in_degree, fwd_plan: BinnedPlan,
 _EAGER_WARNED = [False]
 
 
+def _unfused(out_g):
+    """Keep a scan body's phase-2 call out of XLA's output fusion.  With
+    more than one bin group XLA fuses the Mosaic call with the scan's
+    stacking dynamic-update-slice into one kCustom fusion, and that
+    fusion is compiled under the 16 MiB default scoped-VMEM limit — the
+    kernel's own vmem_limit_bytes is not carried over (seen compiling the
+    Reddit-shape step for a v5e: "Scoped allocation with size 18.25M and
+    limit 16.00M").  The barrier costs one extra copy of the [rows, H]
+    group output."""
+    return jax.lax.optimization_barrier(out_g)
+
+
 def run_binned(x, plan: BinnedPlan, interpret: bool = False,
                precision: str = "fast"):
     """out[v] = sum over in-edges of x[src] via the two-phase schedule.
@@ -3698,7 +3759,7 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
     Call under jit (the trainer always does): measured on v5e at Reddit
     scale, the eager path pays ~6x in scan dispatch overhead (1.65 s vs
     213 ms jitted — docs/PERF.md)."""
-    if not _EAGER_WARNED[0] and jax.core.trace_state_clean():
+    if not _EAGER_WARNED[0] and not isinstance(x, jax.core.Tracer):
         _EAGER_WARNED[0] = True
         warnings.warn(
             "run_binned called outside a jit trace: the eager scan path "
@@ -3762,7 +3823,7 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
                 out_g = _p2_run(stg, obi, first, dstl, C2,
                                 plan.bins_per_group * geom.rb, interpret,
                                 exact, geom)
-            return None, out_g
+            return None, _unfused(out_g)
 
         _, outs = jax.lax.scan(
             fbody, None,
@@ -3781,7 +3842,7 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
             out_g = _p2_run(stg, obi, first, dstl, C2,
                             plan.bins_per_group * geom.rb, interpret,
                             exact, geom)
-        return None, out_g
+        return None, _unfused(out_g)
 
     _, outs = jax.lax.scan(
         body, None,

@@ -1050,12 +1050,9 @@ def _shard_gctx(gd_block, shard_nodes: int, exchange: str) -> GraphCtx:
 
         def attend_edge(h, a_src, a_dst, slope):
             if gd_block.gat_plans is not None:
-                # pcast: same promotion note as _vertex_attend — replicated
-                # params, device-varying hand-written cotangents
-                av = jax.lax.pcast(a_src, PARTS_AXIS, to="varying")
-                dv = jax.lax.pcast(a_dst, PARTS_AXIS, to="varying")
                 return edge_gat_attend(
-                    h, av, dv, gd_block.gat_plans, (edge_src, edge_dst),
+                    h, a_src, a_dst, gd_block.gat_plans,
+                    (edge_src, edge_dst),
                     slope, ops.matmul_precision(gd_block.precision))
             return _edge_attend(gd_block, h, a_src, a_dst, slope)
 
@@ -1147,15 +1144,7 @@ def _vertex_attend(table_flat, gdj, S: int, h_local, a_src, a_dst, slope):
     tab = table_flat.reshape(-1, kk, fd)
     if gdj.gat_plans is not None:
         from roc_tpu.ops.edge import gat_attend_plan
-        # pcast: the attention params are replicated (unvarying) but the
-        # custom vjp's hand-written backward produces shard-local
-        # (device-varying) cotangents; ordinary ops get this promotion
-        # implicitly (linear-layer weights), custom vjps must do it
-        # themselves or the vma typecheck rejects the bwd rule.  Grad
-        # semantics unchanged: per-shard partials, explicit psum upstream.
-        av = jax.lax.pcast(a_src, PARTS_AXIS, to="varying")
-        dv = jax.lax.pcast(a_dst, PARTS_AXIS, to="varying")
-        return gat_attend_plan(h_local, tab, av, dv, gdj.gat_plans,
+        return gat_attend_plan(h_local, tab, a_src, a_dst, gdj.gat_plans,
                                (gdj.edge_src, gdj.edge_dst), slope,
                                ops.matmul_precision(gdj.precision))
     return ops.gat_attend(h_local, tab, gdj.edge_src, gdj.edge_dst, S,
@@ -1831,8 +1820,20 @@ class SpmdTrainer(BaseTrainer):
             # per-device dropout masks: fold the device index into the key
             # (k stacked parts draw distinct rows of the same stream)
             key = jax.random.fold_in(key, jax.lax.axis_index(PARTS_AXIS))
+            # Differentiate a device-VARYING view of the replicated params:
+            # the cotangents then stay local and the psum below is the one
+            # gradient all-reduce.  Under check_vma jax would otherwise
+            # all-reduce them itself (the transpose of its implicit
+            # replicated->varying cast), and psum of that already-summed
+            # value multiplies it by P — P-times-too-large grads against
+            # the weight-decay term, and two all-reduces per weight.  (It
+            # is also what the hand-written custom-vjp backwards need:
+            # their shard-local cotangents typecheck only against varying
+            # primals.)
+            params_v = jax.tree.map(
+                lambda p: jax.lax.pcast(p, PARTS_AXIS, to="varying"), params)
             loss_l, grads_l = jax.value_and_grad(local_loss)(
-                params, x, labels, mask, gd, key)
+                params_v, x, labels, mask, gd, key)
             # all-reduce over ICI (replaces gather-to-one-GPU + serial sum)
             grads = jax.tree.map(lambda g: jax.lax.psum(g, PARTS_AXIS),
                                  grads_l)

@@ -48,6 +48,7 @@ import numpy as np
 from roc_tpu import fault, obs
 from roc_tpu.analysis import retrace as _retrace
 from roc_tpu.analysis import witness as _witness
+from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
 from roc_tpu.models.model import Model
 from roc_tpu.serve.queue import MicrobatchQueue, ServeFuture
@@ -166,8 +167,7 @@ class ServeEngine:
         n, mega = self.bundle.num_nodes, self.bundle.megafuse
         # qidx is consumed once per dispatch — donate it where donation
         # is implemented (TPU); on CPU the hint would only warn.
-        donate = (4,) if jax.default_backend() in obs.roofline.TPU_BACKENDS \
-            else ()
+        donate = (4,) if on_tpu() else ()
 
         @partial(jax.jit, donate_argnums=donate)
         def serve_step(params, x, gdata, valid, qidx):

@@ -19,8 +19,7 @@ before it is trusted; any surprise — no pinned space, a copying
 
 ``STREAM_BW_BYTES_S`` is the assumed host<->device streaming bandwidth
 used for the ledger's predicted transfer-seconds pair
-(``ROC_STREAM_BW_BYTES`` overrides, same pattern as the roofline's
-``ROC_BENCH_PEAK_BW_BYTES``).
+(``ROC_STREAM_BW_BYTES`` overrides).
 """
 
 from __future__ import annotations

@@ -32,7 +32,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 from roc_tpu.ops.pallas.binned import (Geometry, _DMA_CLS, _MODEL_H,
-                                       _VMEM_BUDGET, _vmem_bytes,
+                                       _VMEM_NOMINAL_CAP, _vmem_bytes,
                                        staging_itemsize)
 
 # Geometry axes.  Window pairs stay square or one step apart — the cost
@@ -121,7 +121,7 @@ def _admissible(g: Geometry) -> bool:
         g.check()
     except AssertionError:
         return False
-    return _vmem_bytes(g) <= _VMEM_BUDGET
+    return _vmem_bytes(g) <= _VMEM_NOMINAL_CAP
 
 
 def candidate_lattice(storage_dtype: str = "fp32",
@@ -146,7 +146,7 @@ def candidate_lattice(storage_dtype: str = "fp32",
                     # resident in VMEM — charge it before admitting
                     if depth == 3 and (_vmem_bytes(g) + g.ch * _MODEL_H
                                        * staging_itemsize(g, False)
-                                       > _VMEM_BUDGET):
+                                       > _VMEM_NOMINAL_CAP):
                         continue
                     for mega in megas:
                         # the fusion-depth axis only acts on the fused

@@ -34,13 +34,13 @@ runs it over the selftest sweep's output)::
          "trial_s":   <winning confirmation-trial seconds>,
          "source":    "surrogate" | "device"}}}}
 
-Unlike the ``measured`` rate table (binned.measured_calibration), tuned
-entries apply on ANY backend: they are a policy choice (which schedule to
-build), not a rate claim, and the CI tests exercise the tier under
-interpret.  The rates themselves keep the refusal contract — see
-refit.py.  Entry geometries are still re-validated at lookup time
-(Geometry.check() + the VMEM budget) so a hand-edited or stale file
-degrades to the analytic model instead of crashing a run.
+Off the chip every entry applies: the tier is a policy choice (which
+schedule to build) and the CI tests exercise it under interpret.  On a
+TPU only ``"source": "device"`` entries apply — a winner picked by the
+CPU surrogate must not decide which kernel the chip compiles.  Entry
+geometries are re-validated at lookup time (Geometry.check() + the VMEM
+budget) so a hand-edited or stale file degrades to the analytic model
+instead of crashing a run.
 """
 
 from __future__ import annotations
@@ -52,9 +52,10 @@ import warnings
 
 import numpy as np
 
+from roc_tpu.device import on_tpu
 from roc_tpu.obs.ledger import content_key
 from roc_tpu.ops.pallas.binned import (Geometry, _plan_cache_dir,
-                                       _vmem_bytes, _VMEM_BUDGET)
+                                       _vmem_bytes, _VMEM_NOMINAL_CAP)
 
 VERSION = 1
 _GEOM_FIELDS = len(Geometry._fields)
@@ -226,9 +227,13 @@ def _warn_once(key, msg: str) -> None:
 
 
 def _entry_geom(path: str, gkey: str, vkey: str, e: dict):
-    """Entry -> validated Geometry, or None (warn-once) when the stored
-    tuple no longer passes the live invariants/VMEM budget — e.g. a file
-    from a future field layout or a hand-edit."""
+    """Entry -> validated Geometry, or None: a surrogate entry on a TPU
+    (silently — the analytic model is the intended answer there), or
+    (warn-once) a stored tuple that no longer passes the live
+    invariants/VMEM budget — e.g. a file from a future field layout or a
+    hand-edit."""
+    if e.get("source") != "device" and on_tpu():
+        return None
     try:
         g = Geometry(*e["geom"]).check()
     except (AssertionError, TypeError):
@@ -236,7 +241,7 @@ def _entry_geom(path: str, gkey: str, vkey: str, e: dict):
                    f"tuned entry {vkey} for {gkey.split('|')[-1]} has an "
                    f"invalid geometry; falling back to the analytic model")
         return None
-    if _vmem_bytes(g) > _VMEM_BUDGET:
+    if _vmem_bytes(g) > _VMEM_NOMINAL_CAP:
         _warn_once((path, gkey, vkey),
                    f"tuned entry {vkey} geometry {tuple(g)} exceeds the "
                    f"VMEM budget; falling back to the analytic model")

@@ -25,14 +25,6 @@ def main():
     __graft_entry__._pin_cpu_platform(devices_per_proc)
 
     import jax
-    try:
-        # Old jax (< 0.5) defaults CPU collectives to "none" and refuses
-        # multiprocess computations; gloo needs the distributed client
-        # initialized below, which is why this cannot live in
-        # _pin_cpu_platform (it would break single-process callers).
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - option renamed on newer jax
-        pass
     jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                                num_processes=nprocs, process_id=proc_id)
     assert jax.process_index() == proc_id

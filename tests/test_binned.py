@@ -658,7 +658,7 @@ def test_cost_model_grid_validation():
         truth = {}
         for g in cands:
             g = g.check()
-            if B._vmem_bytes(g) > B._VMEM_BUDGET:
+            if B._vmem_bytes(g) > B._VMEM_NOMINAL_CAP:
                 continue
             plan = B.build_binned_plan(src, dst, n, n, geom=g)
             G, C1 = plan.p1_blk.shape

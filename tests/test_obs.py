@@ -594,78 +594,3 @@ def test_measurement_records_auto_export_calibration_gauge(tmp_path):
     assert reg.write_prometheus(path)
     text = open(path, encoding="utf-8").read()
     assert 'roc_calibration_ratio{model="wire_bytes"} 1.1' in text
-
-
-# -- perf ledger (tools/perf_ledger.py) ------------------------------------
-
-def _perf_ledger_mod():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_ledger", os.path.join(os.path.dirname(__file__), "..",
-                                    "tools", "perf_ledger.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_rounds(root, rounds):
-    for n, env in rounds:
-        with open(os.path.join(root, f"BENCH_r{n:02d}.json"), "w") as f:
-            json.dump(env, f)
-
-
-def test_perf_ledger_fold_and_schema(tmp_path):
-    pl = _perf_ledger_mod()
-    root = str(tmp_path)
-    _write_rounds(root, [
-        (1, {"n": 1, "cmd": "python bench.py", "rc": 1,
-             "tail": "RuntimeError: tunnel wedged",
-             "parsed": {"metric": "epoch_time", "value": None, "unit": "s",
-                        "error": "RuntimeError: tunnel wedged"}}),
-        (2, {"n": 2, "cmd": "python bench.py", "rc": 0, "tail": "",
-             "parsed": {"metric": "epoch_time", "value": 0.7, "unit": "s",
-                        "mfu": 0.002, "roofline_frac": 0.06,
-                        "fusion": "mega"}}),
-    ])
-    with open(os.path.join(root, "BENCH_LAST_HW.json"), "w") as f:
-        json.dump({"metric": "epoch_time", "value": 0.7, "unit": "s",
-                   "measured_at": "2026-08-02T00:00:00Z"}, f)
-    assert pl.check(root) == []
-    traj = pl.fold(root)
-    assert [r["round"] for r in traj["rounds"]] == [1, 2]
-    assert traj["rounds"][0]["error"]           # failed round keeps receipt
-    assert traj["rounds"][1]["mfu"] == 0.002
-    assert traj["last_hw"]["value"] == 0.7
-    md = pl.markdown(traj)
-    assert "| 2 | 0 | epoch_time | 0.7 | s |" in md
-    assert "fusion=mega" in md                  # leg-distinguishing stamps
-    assert "tunnel wedged" in md                # failure line is data
-
-
-def test_perf_ledger_check_flags_malformed(tmp_path):
-    pl = _perf_ledger_mod()
-    root = str(tmp_path)
-    _write_rounds(root, [
-        (1, {"n": 7, "cmd": "x", "rc": 0, "tail": "",   # n != filename
-             "parsed": {"metric": "m", "unit": "s"}}),  # value missing,
-    ])                                                  # no error either
-    errs = pl.check(root)
-    assert any("n=7" in e for e in errs)
-    assert any("parsed.value" in e for e in errs)
-
-
-def test_perf_ledger_md_block_is_idempotent(tmp_path):
-    pl = _perf_ledger_mod()
-    root = str(tmp_path)
-    os.makedirs(os.path.join(root, "docs"))
-    with open(os.path.join(root, "docs", "PERF.md"), "w") as f:
-        f.write("# PERF\n\nhand-written content\n")
-    _write_rounds(root, [(1, {"n": 1, "cmd": "x", "rc": 0, "tail": "",
-                              "parsed": {"metric": "m", "value": 1.0,
-                                         "unit": "s"}})])
-    table = pl.markdown(pl.fold(root))
-    assert pl.update_perf_md(table, root)
-    assert pl.update_perf_md(table, root)       # second run must replace
-    text = open(os.path.join(root, "docs", "PERF.md")).read()
-    assert text.count(pl.MD_BEGIN) == 1
-    assert "hand-written content" in text       # never clobbers prose

@@ -77,6 +77,28 @@ def test_spmd_matches_single_device(parts, halo):
                                float(m_ref.train_loss), rtol=5e-3, atol=1e-2)
 
 
+def test_sharded_grads_are_summed_once():
+    """The gradient all-reduce must SUM the shards' gradients — once.
+    Under check_vma (the xla and matmul backends) jax all-reduces the
+    cotangent of a replicated parameter itself; an explicit psum on top
+    multiplies the gradient by P.  Adam hides a scaled gradient except
+    against the weight-decay term, so the pin uses a strong decay: every
+    loss must track the single-device run to fp32 reassociation (the
+    P-times-too-large gradient drifts to 1e-4 by epoch 8)."""
+    ds = small_ds()
+
+    def losses(parts):
+        cfg = Config(layers=[ds.in_dim, 8, ds.num_classes],
+                     learning_rate=0.01, weight_decay=0.5, dropout_rate=0.0,
+                     eval_every=10**9, num_parts=parts,
+                     aggregate_backend="xla")
+        cls = SpmdTrainer if parts > 1 else Trainer
+        tr = cls(cfg, ds, build_gcn(cfg.layers, 0.0))
+        return [float(tr.run_epoch()) for _ in range(8)]
+
+    np.testing.assert_allclose(losses(4), losses(1), rtol=5e-6)
+
+
 def test_halo_equals_allgather_exactly():
     ds = small_ds(seed=7)
     m1 = build_gcn([ds.in_dim, 8, ds.num_classes], 0.0)
@@ -469,7 +491,7 @@ def test_halo_overlap_local_dots_independent_of_collective():
     # boundaries (shard_map body, pjit, the matmul backend's lax.scan):
     # an eqn's tainted invars map positionally onto its sub-jaxpr's
     # invars, and a sub-jaxpr with tainted outvars taints the eqn.
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     saw = {"a2a": False, "clean": False, "tainted": False}
 

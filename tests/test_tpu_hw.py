@@ -10,19 +10,22 @@ pinned to CPU, so they skip there; run them on hardware with:
         --override-ini= -o addopts=  # or simply: python tests/test_tpu_hw.py
 """
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+# direct `python tests/test_tpu_hw.py`: the repo root is not on sys.path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 tpu = jax.default_backend() == "tpu"
 pytestmark = pytest.mark.skipif(not tpu, reason="requires a real TPU backend")
 
-try:                                    # pytest (repo root on sys.path)
-    from tests.test_binned import oracle_bf16 as _oracle_bf16
-except ImportError:                     # direct `python tests/test_tpu_hw.py`
-    from test_binned import oracle_bf16 as _oracle_bf16
+from tests.test_binned import oracle_bf16 as _oracle_bf16
 
 
 def _cases():
@@ -282,15 +285,17 @@ def test_edge_gat_windowed_plans_on_hw():
 if __name__ == "__main__":   # direct hardware run, no pytest/conftest
     if not tpu:
         raise SystemExit("no TPU backend")
-    test_binned_compiles_and_matches_on_hw()
-    test_binned_vjp_on_hw()
-    test_matmul_backend_on_hw()
-    test_matmul_fast_precision_on_hw()
-    test_binned_avg_on_hw()
-    test_binned_no_pipeline_fallback_on_hw()
-    test_binned_exact_on_hw()
-    test_gat_plan_on_hw()
-    test_binned_sparse_geometries_on_hw()
-    test_binned_flat_on_hw()
-    test_edge_gat_windowed_plans_on_hw()
-    print("tpu hardware tests: all ok")
+    import traceback
+    failed = []
+    for name, fn in [(k, v) for k, v in globals().items()
+                     if k.startswith("test_") and callable(v)]:
+        try:
+            fn()
+            print(f"{name}: ok", flush=True)
+        except Exception as e:  # every verdict is wanted, not the first
+            failed.append(name)
+            traceback.print_exc()
+            print(f"{name}: FAILED {type(e).__name__}: {str(e)[:2000]}",
+                  flush=True)
+    print(f"tpu hardware tests: {len(failed)} failed {failed}")
+    raise SystemExit(1 if failed else 0)

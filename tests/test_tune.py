@@ -83,7 +83,7 @@ def test_lattice_deterministic_sorted_admissible():
     assert len({c.label for c in a}) == len(a)      # labels are keys
     for c in a:
         c.geom.check()                               # admissible only
-        assert B._vmem_bytes(c.geom) <= B._VMEM_BUDGET
+        assert B._vmem_bytes(c.geom) <= B._VMEM_NOMINAL_CAP
     # bf16 storage adds the 16-row-unit flat family
     bf = lattice.candidate_lattice("bf16")
     assert any(c.geom.unit == 16 for c in bf)

@@ -47,9 +47,9 @@ echo "== kernel step budgets =="
 timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python tools/check_kernel_budgets.py || {
     echo "preflight: kernel step budgets RED" >&2; exit 1; }
-# Bench-artifact schema: the BENCH_rNN.json round receipts feed the
-# perf-ledger fold (BENCH_TRAJECTORY.json / docs/PERF.md table); a field
-# rename in the driver would break that join silently months later.
+# Bench-artifact schema: BENCH_SERVE.json is written outside the test
+# suite; a field rename in tools/serve_bench.py would otherwise surface
+# months later.
 echo "== bench artifact schema =="
 timeout -k 10 60 python tools/perf_ledger.py --check || {
     echo "preflight: bench artifact schema RED" >&2; exit 1; }

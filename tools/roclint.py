@@ -28,7 +28,7 @@ import argparse
 import os
 import sys
 
-DEFAULT_PATHS = ["roc_tpu", "tools", "bench.py"]
+DEFAULT_PATHS = ["roc_tpu", "tools", "bench.py", "chip_smoke.py"]
 
 
 def _pin_cpu_topology():
@@ -70,7 +70,7 @@ def list_waivers(paths):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="roclint", description=__doc__)
     ap.add_argument("paths", nargs="*", help="files/dirs to lint "
-                    "(default: roc_tpu tools bench.py)")
+                    "(default: roc_tpu tools bench.py chip_smoke.py)")
     ap.add_argument("--audit", action="store_true",
                     help="lower the audit matrix and diff against "
                     "budgets.json (skips the lint pass unless paths given)")

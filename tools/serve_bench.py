@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Serving bench: p50/p99 latency at offered QPS + cold-start artifact.
 
-Emits `BENCH_SERVE.json` (schema gated by `tools/perf_ledger.py --check`
-and folded into BENCH_TRAJECTORY.json under its own "serve" key — NEVER
-a training-claim round row):
+Emits `BENCH_SERVE.json` (schema gated by `tools/perf_ledger.py --check`;
+serving latency is never a training claim):
 
   {"metric": "serve_p50", "value": ..., "unit": "s",
    "p50_s": ..., "p99_s": ..., "qps_offered": ..., "qps_achieved": ...,
