@@ -330,8 +330,13 @@ def device_budget_bytes() -> int:
 
 
 def measured_peak_bytes() -> Optional[int]:
-    """Max peak-bytes-in-use across local devices, None where the platform
-    keeps no allocator stats (CPU)."""
+    """Peak HBM held on the fullest local device, None where the platform
+    keeps no allocator stats (CPU): the allocator's `peak_bytes_in_use`
+    (live arrays) plus, where it is reported, `peak_bytes_reserved`, the
+    scratch the loaded programs reserve for their temporaries, which
+    `bytes_in_use` leaves out (4.39 GB beside 1.29 GB for the binned Reddit
+    step on a v5e; PERF.md, PR 22, finding 4).  The same sum as the
+    benchmark's `peak_hbm_gib`."""
     import jax
     peak = 0
     for d in jax.local_devices():
@@ -340,5 +345,6 @@ def measured_peak_bytes() -> Optional[int]:
         except Exception:
             stats = None
         if stats and "peak_bytes_in_use" in stats:
-            peak = max(peak, int(stats["peak_bytes_in_use"]))
+            peak = max(peak, int(stats["peak_bytes_in_use"])
+                       + int(stats.get("peak_bytes_reserved", 0)))
     return peak or None

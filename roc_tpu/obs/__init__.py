@@ -1,7 +1,8 @@
 """Unified runtime observability: span tracer, metrics channel, watchdog.
 
   tracer.py    host-side span ring (the ONE sanctioned wall-clock site —
-               roclint's raw-timing rule), Chrome trace-event export
+               roclint's raw-timing rule), Chrome trace-event export, and
+               the same spans as `roc.<name>` jax.profiler annotations
   channel.py   in-graph metrics riding the jitted step's return pytree
                (zero host syncs / collectives / retraces)
   metrics.py   registry + exporters over the balance-telemetry JSONL schema
@@ -20,10 +21,10 @@ span without pulling jax/numpy at import time); the jax/numpy-facing
 pieces load on first attribute access.
 """
 
-from roc_tpu.obs.tracer import (SpanTracer, enable, enabled, get_tracer,
-                                span, validate_chrome_trace)
+from roc_tpu.obs.tracer import (SpanTracer, annotate, enable, enabled,
+                                get_tracer, span, validate_chrome_trace)
 
-__all__ = ["SpanTracer", "enable", "enabled", "get_tracer", "span",
+__all__ = ["SpanTracer", "annotate", "enable", "enabled", "get_tracer", "span",
            "validate_chrome_trace", "MetricsRegistry", "PerfWatchdog",
            "channel", "load_jsonl", "seed_for_graph", "roofline", "ledger",
            "get_ledger"]

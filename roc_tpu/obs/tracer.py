@@ -1,4 +1,5 @@
-"""Host-side span tracer: ring buffer, monotonic clocks, Chrome export.
+"""Host-side span tracer: ring buffer, monotonic clocks, Chrome export,
+and the same spans as `jax.profiler` trace annotations.
 
 The repo's timing story before this module was ad hoc: epoch wall-clock in
 the driver, probe loops in the balancer, `_time.perf_counter()` pairs in
@@ -18,10 +19,19 @@ Disabled spans cost two `perf_counter_ns` calls and a list append/pop
 (~1 µs; the selftest and tests/test_obs.py gate this), so instrumentation
 stays on the hot path unconditionally.
 
-Export is Chrome trace-event JSON (`{"traceEvents": [{"ph": "X", ...}]}`,
-timestamps/durations in microseconds) — loadable directly in Perfetto /
-chrome://tracing, so a host-side trace from a `-obs` run lines up next to
-the device-side xprof trace from `-profile`.
+Two clocks, one span system.  The ring stamps `time.perf_counter_ns`,
+which no device trace shares: its export is Chrome trace-event JSON
+(`{"traceEvents": [{"ph": "X", ...}]}`, timestamps/durations in
+microseconds), loadable in Perfetto / chrome://tracing on its own.  To put
+the spans on the clock the device plane uses, the same switch arms a
+bridge: while armed, every span is *also* a `jax.profiler.TraceAnnotation`
+named ``roc.<name>`` (span args as the annotation's stats), opened and
+closed with the span, so a live profiler session (the benchmark's
+`--trace 1`, the CLI's `-profile DIR`) holds the program's spans on the
+host plane of its `.xplane.pb`, nested as the spans nest.  `annotate()`
+arms the bridge alone, for a profiled window without `-obs`.  This module
+imports no JAX: `jax.profiler` is looked up by the first span after
+arming, and where it cannot be imported the spans go on without it.
 """
 
 from __future__ import annotations
@@ -34,6 +44,7 @@ from collections import deque
 from typing import Dict, List, Optional, Set
 
 DEFAULT_CAPACITY = 65536  # spans kept; old ones fall off the ring
+ANNOTATION_PREFIX = "roc."  # a span's name in a jax.profiler trace
 
 
 class Span:
@@ -78,7 +89,8 @@ class _SpanCtx:
     """Context manager for one span: measures on exit, records into the
     tracer's ring only when tracing is enabled at close time."""
 
-    __slots__ = ("_tracer", "name", "args", "start_ns", "dur_ns", "depth")
+    __slots__ = ("_tracer", "name", "args", "start_ns", "dur_ns", "depth",
+                 "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self._tracer = tracer
@@ -87,20 +99,30 @@ class _SpanCtx:
         self.start_ns = 0
         self.dur_ns = 0
         self.depth = 0
+        self._ann = None
 
     def __enter__(self) -> "_SpanCtx":
-        stack = self._tracer._stack()
+        t = self._tracer
+        stack = t._stack()
         self.depth = len(stack)
         stack.append(self)
+        make = t._annotation
+        if make is not None:
+            # the annotation encloses the span's own clock reads
+            self._ann = make(ANNOTATION_PREFIX + self.name, **self.args)
+            if self._ann is not None:
+                self._ann.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_ns = time.perf_counter_ns() - self.start_ns
-        stack = self._tracer._stack()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        t = self._tracer
+        stack = t._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        t = self._tracer
         if t.enabled:
             t._ring.append(Span(self.name, self.start_ns, self.dur_ns,
                                 threading.get_ident(), self.depth,
@@ -117,8 +139,30 @@ class SpanTracer:
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.enabled = False
+        self._annotation = None     # makes a span's TraceAnnotation when armed
         self._ring: deque = deque(maxlen=capacity)
         self._tls = threading.local()
+
+    def annotate(self, on: bool = True) -> bool:
+        """Arm (or disarm) the trace annotations alone; returns whether
+        they were armed before, for a caller that restores it."""
+        was = self._annotation is not None
+        if bool(on) != was:
+            self._annotation = self._first_annotation if on else None
+        return was
+
+    def _first_annotation(self, name: str, **args):
+        """The first span after arming looks `jax.profiler` up (not this
+        module's import: kernel modules import it before JAX).  Without a
+        usable profiler the bridge disarms and the spans go on alone."""
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:
+            self._annotation = None
+            return None
+        if self._annotation is not None:    # not disarmed meanwhile
+            self._annotation = TraceAnnotation
+        return TraceAnnotation(name, **args)
 
     def _stack(self) -> list:
         stack = getattr(self._tls, "stack", None)
@@ -197,7 +241,6 @@ def validate_chrome_trace(obj) -> List[str]:
 # env into cfg.obs and the driver calls enable() for the CLI path.
 
 _TRACER = SpanTracer()
-_TRACER.enabled = os.environ.get("ROC_OBS", "") == "1"
 
 
 def get_tracer() -> SpanTracer:
@@ -209,8 +252,20 @@ def span(name: str, **args) -> _SpanCtx:
 
 
 def enable(on: bool = True):
+    """The one switch: record spans into the ring and mirror them as
+    ``roc.<name>`` trace annotations."""
     _TRACER.enabled = bool(on)
+    _TRACER.annotate(on)
+
+
+def annotate(on: bool = True) -> bool:
+    """Arm the trace annotations without recording (a `-profile` window
+    without `-obs`); returns the previous state."""
+    return _TRACER.annotate(on)
 
 
 def enabled() -> bool:
     return _TRACER.enabled
+
+
+enable(os.environ.get("ROC_OBS", "") == "1")

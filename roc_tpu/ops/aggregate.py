@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from roc_tpu.obs.tracer import span as _obs_span
+
 
 def _vary_like(init, ref):
     """Promote a scan-carry init to ``ref``'s device-varying vma annotation
@@ -173,11 +175,12 @@ def build_aggregate_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
     for plan in (fwd, bwd):
         assert np.all(np.diff(np.asarray(plan.obi)) <= 1), \
             "chunk plan skips output windows (obi jump > 1)"
-    return AggregatePlans(
-        fwd_obi=jnp.asarray(fwd.obi), fwd_first=jnp.asarray(fwd.first),
-        fwd_edst=jnp.asarray(fwd.edst), fwd_esrc=jnp.asarray(fwd.esrc),
-        bwd_obi=jnp.asarray(bwd.obi), bwd_first=jnp.asarray(bwd.first),
-        bwd_edst=jnp.asarray(bwd.edst), bwd_esrc=jnp.asarray(bwd.esrc))
+    with _obs_span("plan_to_device"):
+        return AggregatePlans(
+            fwd_obi=jnp.asarray(fwd.obi), fwd_first=jnp.asarray(fwd.first),
+            fwd_edst=jnp.asarray(fwd.edst), fwd_esrc=jnp.asarray(fwd.esrc),
+            bwd_obi=jnp.asarray(bwd.obi), bwd_first=jnp.asarray(bwd.first),
+            bwd_edst=jnp.asarray(bwd.edst), bwd_esrc=jnp.asarray(bwd.esrc))
 
 
 def pad_plans(plans: "list[AggregatePlans]", min_fwd: int = 0,
@@ -379,9 +382,10 @@ def build_binned_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
             return spec
         if forced:
             return GEOM_PRESETS[forced]
-        g, _ = choose_geometry(src, dst, n, t, force=True,
-                               storage_dtype=storage_dtype,
-                               fuse_linear=fuse)
+        with _obs_span("choose_geometry", edges=len(src)):
+            g, _ = choose_geometry(src, dst, n, t, force=True,
+                                   storage_dtype=storage_dtype,
+                                   fuse_linear=fuse)
         return g or _default_geom()
 
     forced_env = os.environ.get("ROC_BINNED_GEOM", "")

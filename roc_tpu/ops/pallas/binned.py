@@ -1266,54 +1266,70 @@ def build_binned_plan(edge_src: np.ndarray, edge_dst: np.ndarray,
     if cache is not None and os.path.exists(cache):
         with _obs_span("plan_cache_load", rows=num_rows,
                        edges=len(edge_src)):
-            plan = _plan_cache_load(cache, num_rows, table_rows, geom)
-        if plan is not None:
+            loaded = _plan_cache_load(cache, num_rows, table_rows, geom)
+        if loaded is not None:
+            plan = _plan_from_host(*loaded, num_rows, table_rows, geom)
             _ledger_note_plan(plan, len(edge_src))
             return plan
     global _PLAN_BUILD_COUNT
     _PLAN_BUILD_COUNT += 1
-    if len(edge_src) >= (1 << 20) and native.available():
-        if geom.flat:
-            (p1_srcl, p1_blk, p1_blk2, p1_dsrc, p1_ddst, p2_dstl, p2_obi,
-             p2_first, bpg) = native.binned_flat_plan(
-                 edge_src, edge_dst, num_rows, table_rows,
-                 group_row_target, geom)
-            G, C1 = p1_blk.shape
-            C2 = p2_obi.shape[1]
-            plan = _attach_fused(BinnedPlan(
-                p1_srcl=jnp.asarray(p1_srcl.reshape(G, C1 * geom.ch, 1)),
-                p1_off=None,
-                p1_blk=jnp.asarray(p1_blk),
-                p2_dstl=jnp.asarray(p2_dstl.reshape(G, C2 * geom.ch2, 1)),
-                p2_obi=jnp.asarray(p2_obi),
-                p2_first=jnp.asarray(p2_first),
-                p1_blk2=jnp.asarray(p1_blk2),
-                p1_dsrc=jnp.asarray(p1_dsrc),
-                p1_ddst=jnp.asarray(p1_ddst),
-                num_rows=num_rows, table_rows=table_rows,
-                bins_per_group=bpg, geom=geom))
-        else:
-            (p1_srcl, p1_off, p1_blk, p2_dstl, p2_obi, p2_first,
-             bpg) = native.binned_plan(edge_src, edge_dst, num_rows,
-                                       table_rows, group_row_target, geom)
-            G, C1 = p1_blk.shape
-            C2 = p2_obi.shape[1]
-            plan = BinnedPlan(
-                p1_srcl=jnp.asarray(p1_srcl.reshape(G, C1 * geom.ch, 1)),
-                p1_off=jnp.asarray(p1_off),
-                p1_blk=jnp.asarray(p1_blk),
-                p2_dstl=jnp.asarray(p2_dstl.reshape(G, C2 * geom.ch2, 1)),
-                p2_obi=jnp.asarray(p2_obi),
-                p2_first=jnp.asarray(p2_first),
-                num_rows=num_rows, table_rows=table_rows,
-                bins_per_group=bpg, geom=geom)
+    host = None
+    if len(edge_src) >= (1 << 20):
+        # (a checkout's first run compiles the library in `available()`)
+        with _obs_span("plan_native_build", edges=len(edge_src)):
+            if native.available():
+                host, bpg = _native_plan_arrays(
+                    edge_src, edge_dst, num_rows, table_rows,
+                    group_row_target, geom)
+    if host is not None:
+        plan = _plan_from_host(host, bpg, num_rows, table_rows, geom)
     else:
         plan = _build_binned_plan_numpy(edge_src, edge_dst, num_rows,
                                         table_rows, group_row_target, geom)
     if cache is not None:
-        _plan_cache_save(cache, plan)
+        with _obs_span("plan_cache_save", edges=len(edge_src)):
+            _plan_cache_save(cache, plan)
     _ledger_note_plan(plan, len(edge_src))
     return plan
+
+
+def _native_plan_arrays(edge_src, edge_dst, num_rows, table_rows,
+                        group_row_target, geom):
+    """(host arrays, bins per group) from the C++ builder, in the shapes
+    BinnedPlan documents."""
+    from roc_tpu import native
+    if geom.flat:
+        (p1_srcl, p1_blk, p1_blk2, p1_dsrc, p1_ddst, p2_dstl, p2_obi,
+         p2_first, bpg) = native.binned_flat_plan(
+             edge_src, edge_dst, num_rows, table_rows, group_row_target,
+             geom)
+        extra = dict(p1_blk2=p1_blk2, p1_dsrc=p1_dsrc, p1_ddst=p1_ddst)
+    else:
+        (p1_srcl, p1_off, p1_blk, p2_dstl, p2_obi, p2_first,
+         bpg) = native.binned_plan(edge_src, edge_dst, num_rows,
+                                   table_rows, group_row_target, geom)
+        extra = dict(p1_off=p1_off)
+    G, C1 = p1_blk.shape
+    C2 = p2_obi.shape[1]
+    return dict(p1_srcl=p1_srcl.reshape(G, C1 * geom.ch, 1), p1_blk=p1_blk,
+                p2_dstl=p2_dstl.reshape(G, C2 * geom.ch2, 1),
+                p2_obi=p2_obi, p2_first=p2_first, **extra), bpg
+
+
+def _plan_from_host(host: dict, bins_per_group: int, num_rows: int,
+                    table_rows: int, geom: Geometry) -> BinnedPlan:
+    """Place one plan's host arrays (from the cache, the native or the
+    NumPy builder) on the device, and attach the fused step lists to a
+    flat plan.  `jnp.asarray` may return before the bytes have landed: the
+    span times the calls, and a transfer's tail falls to whatever waits
+    for it next."""
+    with _obs_span("plan_to_device",
+                   bytes=sum(int(v.nbytes) for v in host.values())):
+        placed = {k: jnp.asarray(v) for k, v in host.items()}
+    plan = BinnedPlan(**{"p1_off": None, **placed}, num_rows=num_rows,
+                      table_rows=table_rows, bins_per_group=bins_per_group,
+                      geom=geom)
+    return _attach_fused(plan) if geom.flat else plan
 
 
 def _plan_cache_dir() -> str:
@@ -1338,8 +1354,9 @@ def _plan_cache_path(edge_src, edge_dst, num_rows, table_rows,
         return None
     import hashlib
     h = hashlib.sha1()
-    h.update(np.ascontiguousarray(edge_src, np.int64).tobytes())
-    h.update(np.ascontiguousarray(edge_dst, np.int64).tobytes())
+    with _obs_span("plan_key", edges=len(edge_src)):
+        h.update(np.ascontiguousarray(edge_src, np.int64).tobytes())
+        h.update(np.ascontiguousarray(edge_dst, np.int64).tobytes())
     # v3: the geometry tuple grew the flat-unit field (bf16 staging), so
     # v2 files no longer match any key — a bf16<->fp32 storage flip can
     # never hit a stale plan.  (v2 was the flat-schedule field itself.)
@@ -1349,37 +1366,28 @@ def _plan_cache_path(edge_src, edge_dst, num_rows, table_rows,
 
 
 def _plan_cache_load(path, num_rows, table_rows, geom):
-    """Best-effort load; None on any mismatch/corruption (rebuilds)."""
+    """Best-effort read of a cached plan into host memory: (arrays, bins
+    per group), or None on any mismatch/corruption (rebuilds).  The fused
+    step lists are NOT cached — _plan_from_host rebuilds them from the
+    flat arrays (cheap next to the plan build they key on)."""
     try:
         with np.load(path) as z:
             meta = z["meta"]
             if (int(meta[0]) != num_rows or int(meta[1]) != table_rows
                     or tuple(int(v) for v in z["geom"]) != tuple(geom)):
                 return None
-            G = z["p1_blk"].shape[0]
-            C1 = z["p1_blk"].shape[1]
-            C2 = z["p2_obi"].shape[1]
-            plan = BinnedPlan(
-                p1_srcl=jnp.asarray(z["p1_srcl"].reshape(
-                    G, C1 * geom.ch, 1)),
-                p1_off=(jnp.asarray(z["p1_off"])
-                        if not geom.flat else None),
-                p1_blk=jnp.asarray(z["p1_blk"]),
-                p2_dstl=jnp.asarray(z["p2_dstl"].reshape(
-                    G, C2 * geom.ch2, 1)),
-                p2_obi=jnp.asarray(z["p2_obi"]),
-                p2_first=jnp.asarray(z["p2_first"]),
-                p1_blk2=(jnp.asarray(z["p1_blk2"])
-                         if geom.flat else None),
-                p1_dsrc=(jnp.asarray(z["p1_dsrc"].reshape(
-                    G, C1, geom.kd)) if geom.flat else None),
-                p1_ddst=(jnp.asarray(z["p1_ddst"].reshape(
-                    G, C1, geom.kd)) if geom.flat else None),
-                num_rows=num_rows, table_rows=table_rows,
-                bins_per_group=int(meta[2]), geom=geom)
-            # fused step lists are NOT cached — rebuilt from the flat
-            # arrays (cheap next to the plan build they key on)
-            return _attach_fused(plan) if geom.flat else plan
+            names = ["p1_srcl", "p1_blk", "p2_dstl", "p2_obi", "p2_first"]
+            names += ["p1_blk2", "p1_dsrc", "p1_ddst"] if geom.flat \
+                else ["p1_off"]
+            host = {k: z[k] for k in names}
+        G, C1 = host["p1_blk"].shape
+        C2 = host["p2_obi"].shape[1]
+        host["p1_srcl"] = host["p1_srcl"].reshape(G, C1 * geom.ch, 1)
+        host["p2_dstl"] = host["p2_dstl"].reshape(G, C2 * geom.ch2, 1)
+        if geom.flat:
+            host["p1_dsrc"] = host["p1_dsrc"].reshape(G, C1, geom.kd)
+            host["p1_ddst"] = host["p1_ddst"].reshape(G, C1, geom.kd)
+        return host, int(meta[2])
     except Exception:
         return None
 
@@ -1452,6 +1460,17 @@ def _build_binned_plan_numpy(edge_src: np.ndarray, edge_dst: np.ndarray,
     if geom.flat:
         return _build_flat_plan_numpy(edge_src, edge_dst, num_rows,
                                       table_rows, group_row_target, geom)
+    with _obs_span("plan_numpy_build", edges=len(edge_src)):
+        host, bpg = _slot_plan_arrays_numpy(
+            edge_src, edge_dst, num_rows, table_rows, group_row_target,
+            geom)
+    return _plan_from_host(host, bpg, num_rows, table_rows, geom)
+
+
+def _slot_plan_arrays_numpy(edge_src, edge_dst, num_rows: int,
+                            table_rows: int, group_row_target: int,
+                            geom: Geometry):
+    """Host arrays of the slot schedule, and the bins per group."""
     SB, CH, SLOT, RB, CH2 = geom[:5]      # noqa: N806 — shadow the module
     NSLOT, SLOT2 = geom.nslot, geom.slot2   # constants with plan geometry
     edge_src = np.asarray(edge_src, np.int64)
@@ -1566,21 +1585,26 @@ def _build_binned_plan_numpy(edge_src: np.ndarray, edge_dst: np.ndarray,
         p2_first[g, :len(obi)] = first
         if len(obi) < C2:   # pad chunks: revisit last bin, add only zeros
             p2_obi[g, len(obi):] = obi[-1]
-    return BinnedPlan(
-        p1_srcl=jnp.asarray(p1_srcl.reshape(G, C1 * CH, 1)),
-        p1_off=jnp.asarray(p1_off),
-        p1_blk=jnp.asarray(p1_blk),
-        p2_dstl=jnp.asarray(p2_dstl.reshape(G, C2 * CH2, 1)),
-        p2_obi=jnp.asarray(p2_obi),
-        p2_first=jnp.asarray(p2_first),
-        num_rows=num_rows, table_rows=table_rows,
-        bins_per_group=bins_per_group, geom=geom)
+    return dict(p1_srcl=p1_srcl.reshape(G, C1 * CH, 1), p1_off=p1_off,
+                p1_blk=p1_blk, p2_dstl=p2_dstl.reshape(G, C2 * CH2, 1),
+                p2_obi=p2_obi, p2_first=p2_first), bins_per_group
 
 
 def _build_flat_plan_numpy(edge_src: np.ndarray, edge_dst: np.ndarray,
                            num_rows: int, table_rows: int,
                            group_row_target: int,
                            geom: Geometry) -> BinnedPlan:
+    """The flat-schedule oracle builder, placed on the device."""
+    with _obs_span("plan_numpy_build", edges=len(edge_src)):
+        host, bpg = _flat_plan_arrays_numpy(
+            edge_src, edge_dst, num_rows, table_rows, group_row_target,
+            geom)
+    return _plan_from_host(host, bpg, num_rows, table_rows, geom)
+
+
+def _flat_plan_arrays_numpy(edge_src, edge_dst, num_rows: int,
+                            table_rows: int, group_row_target: int,
+                            geom: Geometry):
     """Flat-schedule oracle builder (geom.flat): same sort and cell
     machinery as the slot builder, but cells pad to unit_rows-row units
     (8 for fp32 staging, 16 for the bf16 tile-aligned variant),
@@ -1745,19 +1769,10 @@ def _build_flat_plan_numpy(edge_src: np.ndarray, edge_dst: np.ndarray,
         p2_first[g, :len(obi)] = first
         if len(obi) < C2:
             p2_obi[g, len(obi):] = obi[-1]
-    plan = BinnedPlan(
-        p1_srcl=jnp.asarray(p1_srcl.reshape(G, C1 * CH, 1)),
-        p1_off=None,
-        p1_blk=jnp.asarray(p1_blk),
-        p2_dstl=jnp.asarray(p2_dstl.reshape(G, C2 * CH2, 1)),
-        p2_obi=jnp.asarray(p2_obi),
-        p2_first=jnp.asarray(p2_first),
-        p1_blk2=jnp.asarray(p1_blk2),
-        p1_dsrc=jnp.asarray(p1_dsrc),
-        p1_ddst=jnp.asarray(p1_ddst),
-        num_rows=num_rows, table_rows=table_rows,
-        bins_per_group=bins_per_group, geom=geom)
-    return _attach_fused(plan)
+    return dict(p1_srcl=p1_srcl.reshape(G, C1 * CH, 1), p1_blk=p1_blk,
+                p2_dstl=p2_dstl.reshape(G, C2 * CH2, 1), p2_obi=p2_obi,
+                p2_first=p2_first, p1_blk2=p1_blk2, p1_dsrc=p1_dsrc,
+                p1_ddst=p1_ddst), bins_per_group
 
 
 def _attach_fused(plan: BinnedPlan) -> BinnedPlan:
@@ -1769,12 +1784,25 @@ def _attach_fused(plan: BinnedPlan) -> BinnedPlan:
     two-pass).  Built host-side at plan/cache/pad time: inside jit the
     plan arrays are tracers, so the schedule cannot be derived at trace
     time.  run_binned re-gates on the real H before using it."""
+    with _obs_span("plan_fused_steps"):
+        steps = _fused_step_arrays(plan)
+    if steps is None:
+        return plan
+    with _obs_span("plan_to_device",
+                   bytes=sum(int(v.nbytes) for v in steps.values())):
+        return dataclasses.replace(
+            plan, **{k: jnp.asarray(v) for k, v in steps.items()})
+
+
+def _fused_step_arrays(plan: BinnedPlan):
+    """Host arrays of the fused step list (BinnedPlan's ``f_*`` fields),
+    or None where the plan cannot fuse."""
     geom = plan.geom
     if not (geom is not None and geom.flat and geom.ch == geom.ch2):
-        return plan
+        return None
     G, C2 = plan.p2_obi.shape
     if C2 * geom.ch2 > _FUSE_MAX_STG_ROWS:
-        return plan
+        return None
     CH, RB, KD, bpg = geom.ch, geom.rb, geom.kd, plan.bins_per_group
     srcl = np.asarray(plan.p1_srcl).reshape(G, -1)
     dstl = np.asarray(plan.p2_dstl).reshape(G, -1)
@@ -1846,16 +1874,9 @@ def _attach_fused(plan: BinnedPlan) -> BinnedPlan:
         f_blk[len(steps):] = cur_blk
         f_blk2[len(steps):] = cur_blk2
         f_obi[len(steps):] = cur_obi
-    return dataclasses.replace(
-        plan,
-        f_meta=jnp.asarray(f_meta),
-        f_rows=jnp.asarray(f_rows.reshape(S * CH, 1)),
-        f_blk=jnp.asarray(f_blk),
-        f_blk2=jnp.asarray(f_blk2),
-        f_obi=jnp.asarray(f_obi),
-        f_dsrc=jnp.asarray(f_dsrc),
-        f_ddst=jnp.asarray(f_ddst),
-        f_last=jnp.asarray(f_last))
+    return dict(f_meta=f_meta, f_rows=f_rows.reshape(S * CH, 1), f_blk=f_blk,
+                f_blk2=f_blk2, f_obi=f_obi, f_dsrc=f_dsrc, f_ddst=f_ddst,
+                f_last=f_last)
 
 
 # ---------------------------------------------------------------------------

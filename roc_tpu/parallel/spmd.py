@@ -1639,7 +1639,8 @@ class SpmdTrainer(BaseTrainer):
                     "attention backend (-aggr-backend matmul/binned); the "
                     "xla path's _edge_attend serializes on TPU")
         else:
-            self.part = partition_graph(ds.graph, P_)
+            with obs.span("partition", parts=P_):
+                self.part = partition_graph(ds.graph, P_)
             self._use_edge_shard = self._resolve_edge_shard()
         if self._use_edge_shard and self._exchange_mode == "ring":
             if jax.process_index() == 0:
@@ -1680,17 +1681,20 @@ class SpmdTrainer(BaseTrainer):
         self._node_spec = NamedSharding(self.mesh, P(PARTS_AXIS))
         self._repl_spec = NamedSharding(self.mesh, P())
 
-        self._place_data(gd)
-
-        self.params = jax.device_put(model.init_params(self.key),
-                                     self._repl_spec)
-        self.opt_state = jax.device_put(self.optimizer.init(self.params),
-                                        self._repl_spec)
+        with obs.span("place_data", parts=P_):
+            self._place_data(gd)
+        with obs.span("init_params"):
+            self.params = jax.device_put(model.init_params(self.key),
+                                         self._repl_spec)
+            self.opt_state = jax.device_put(
+                self.optimizer.init(self.params), self._repl_spec)
         # Plan activation memory once per setup, before the steps trace:
         # reshards keep the plan (the per-device shard shape is frozen), so
         # the step cache below still hits on a same-structure rebuild.
-        self._resolve_mem_plan()
-        self._build_steps(gd)
+        with obs.span("mem_plan"):
+            self._resolve_mem_plan()
+        with obs.span("step_build"):
+            self._build_steps(gd)
 
     def _place_data(self, gd: ShardedGraphData):
         """Place the node tensors + graph data for the current partition

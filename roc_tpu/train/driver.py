@@ -109,10 +109,12 @@ def resolve_backend_geom(backend: str, num_edges: int, num_rows: int = 0,
         from roc_tpu.ops.pallas.binned import binned_viable, choose_geometry
         if AUTO_BINNED and num_rows:
             if edge_src is not None:
-                g, _ = choose_geometry(edge_src, edge_dst, num_rows,
-                                       table_rows,
-                                       storage_dtype=storage_dtype,
-                                       fuse_linear=fuse_linear)
+                # beside plan_build, not under it (dense_graph_data)
+                with obs.span("choose_geometry", edges=num_edges):
+                    g, _ = choose_geometry(edge_src, edge_dst, num_rows,
+                                           table_rows,
+                                           storage_dtype=storage_dtype,
+                                           fuse_linear=fuse_linear)
                 if g is not None:
                     return "binned", g
             elif binned_viable(num_rows, table_rows, num_edges):
@@ -311,17 +313,18 @@ def dense_graph_data(graph, backend: str = "xla",
                                     graph.num_nodes, graph.num_edges,
                                     gat_heads, gat_head_dim, fused=True),
                                 "bytes")
-    return DenseGraphData(
-        edge_src=jnp.asarray(graph.col_idx, jnp.int32),
-        edge_dst=jnp.asarray(graph.dst_idx, jnp.int32),
-        in_degree=jnp.asarray(graph.in_degrees, jnp.float32),
-        plans=plans,
-        gat_plans=gat_plans,
-        gat_bplans=gat_bplans,
-        backend=backend,
-        precision=precision,
-        gat_fused=gat_fused,
-    )
+    with obs.span("place_data", what="edges"):
+        return DenseGraphData(
+            edge_src=jnp.asarray(graph.col_idx, jnp.int32),
+            edge_dst=jnp.asarray(graph.dst_idx, jnp.int32),
+            in_degree=jnp.asarray(graph.in_degrees, jnp.float32),
+            plans=plans,
+            gat_plans=gat_plans,
+            gat_bplans=gat_bplans,
+            backend=backend,
+            precision=precision,
+            gat_fused=gat_fused,
+        )
 
 
 def make_gctx(g: DenseGraphData, num_nodes: int,
@@ -483,7 +486,8 @@ class BaseTrainer:
         self.model = model
         self.optimizer = Adam(alpha=config.learning_rate,
                               weight_decay=config.weight_decay)
-        self.key = jax.random.PRNGKey(config.seed)
+        with obs.span("init_params", what="key"):   # the first device op
+            self.key = jax.random.PRNGKey(config.seed)
         self.epoch = 0
         self.dtype = jnp.bfloat16 if config.use_bf16 else jnp.float32
         # fault harness: arm -fault specs that arrived via the flag (the
@@ -907,8 +911,10 @@ class BaseTrainer:
                      "updates")
 
     def evaluate(self) -> ops.PerfMetrics:
-        return self._eval_step(self.params, self.x, self.labels, self.mask,
-                               self.gdata)
+        # the jitted call until it returns to Python, like step_call
+        with obs.span("eval_call"):
+            return self._eval_step(self.params, self.x, self.labels,
+                                   self.mask, self.gdata)
 
     def predict_logits(self):
         """Inference logits for every (padded, for SPMD) node row."""
@@ -916,10 +922,16 @@ class BaseTrainer:
 
     def run_epoch(self):
         cfg = self.config
-        if self.epoch != 0 and self.epoch % cfg.decay_steps == 0:
-            self.optimizer.alpha *= cfg.decay_rate  # gnn.cc:100-101
-        step_key = jax.random.fold_in(self.key, self.epoch)
-        loss = self._run_step(step_key, jnp.float32(self.optimizer.alpha))
+        # what the host makes and transfers for this step alone
+        with obs.span("step_args"):
+            if self.epoch != 0 and self.epoch % cfg.decay_steps == 0:
+                self.optimizer.alpha *= cfg.decay_rate  # gnn.cc:100-101
+            step_key = jax.random.fold_in(self.key, self.epoch)
+            alpha = jnp.float32(self.optimizer.alpha)
+        # the jitted call until it returns to Python: a device gap after
+        # this has closed is no longer the host's enqueueing
+        with obs.span("step_call"):
+            loss = self._run_step(step_key, alpha)
         self.epoch += 1
         return loss
 
@@ -933,7 +945,7 @@ class BaseTrainer:
         p_off, p_cnt = cfg.profile_window()
         prof_start = start + min(p_off, max(cfg.num_epochs - 1, 0))
         prof_stop = min(prof_start + p_cnt, start + cfg.num_epochs)
-        tracing = False
+        tracing = annotated = False
         loss = float("nan")
         rebalance_events = []
         peak_hbm = []
@@ -958,6 +970,11 @@ class BaseTrainer:
             try:
                 for epoch in range(start, start + cfg.num_epochs):
                     if cfg.profile_dir and epoch == prof_start:
+                        # a profile without the program's spans is the
+                        # case operators hit: annotate the window (the
+                        # tracer's bridge alone, not the -obs metrics
+                        # channel, which would change the compiled step)
+                        annotated = obs.annotate(True)
                         jax.profiler.start_trace(cfg.profile_dir)
                         tracing = True
                     # the sync IS the measurement: an epoch "ends" when its
@@ -968,23 +985,31 @@ class BaseTrainer:
                         with obs.span("device_sync"):
                             device_sync(loss)
                     self.epoch_times.append(sp_epoch.dur_s)
-                    hbm, peak_src = self._peak_hbm()
+                    with obs.span("peak_hbm"):
+                        hbm, peak_src = self._peak_hbm()
                     peak_hbm.append(hbm)
                     if self.balancer is not None:
                         self.balancer.telemetry.record_epoch(
                             epoch, self.epoch_times[-1], peak_hbm=hbm,
                             peak_hbm_source=peak_src)
-                    self._obs_epoch(epoch, sp_epoch.dur_s, loss, print_fn)
-                    self._check_nonfinite(epoch, print_fn)
+                    if self._metrics is not None:       # -obs only
+                        with obs.span("obs_epoch", epoch=epoch):
+                            self._obs_epoch(epoch, sp_epoch.dur_s, loss,
+                                            print_fn)
+                    with obs.span("check_nonfinite"):
+                        self._check_nonfinite(epoch, print_fn)
                     if tracing and epoch + 1 == prof_stop:
                         device_sync(self.params)
                         jax.profiler.stop_trace()
+                        obs.annotate(annotated)
                         tracing = False
                         print_fn(f"# profiler trace written to "
                                  f"{cfg.profile_dir}")
                     if epoch % cfg.eval_every == 0:
                         with obs.span("eval", epoch=epoch):
-                            m = jax.device_get(self.evaluate())
+                            m = self.evaluate()
+                            with obs.span("eval_fetch"):
+                                m = jax.device_get(m)
                         print_fn(format_metrics(epoch, m))
                     if (cfg.checkpoint_path and cfg.checkpoint_every and
                             (epoch + 1) % cfg.checkpoint_every == 0):
@@ -995,8 +1020,9 @@ class BaseTrainer:
                     done = epoch + 1 - start
                     if (self.balancer is not None and done < cfg.num_epochs
                             and done % cfg.balance_every == 0):
-                        ev = self.balancer.step(self, epoch + 1,
-                                                cfg.num_epochs - done)
+                        with obs.span("balance", epoch=epoch):
+                            ev = self.balancer.step(self, epoch + 1,
+                                                    cfg.num_epochs - done)
                         if ev is not None:
                             rebalance_events.append(ev)
                             if cfg.verbose:
@@ -1008,7 +1034,8 @@ class BaseTrainer:
                     # After the balance round, so an armed RetraceGuard
                     # sees a reshard's (cache-missing) rebuild as the
                     # violation it is.
-                    _retrace.epoch_boundary(done)
+                    with obs.span("retrace_boundary"):
+                        _retrace.epoch_boundary(done)
                     if self._stop_signal is not None:
                         name = signal.Signals(self._stop_signal).name
                         print_fn(f"# fault: {name} received — epoch "
@@ -1021,6 +1048,7 @@ class BaseTrainer:
                 # dies on the leaked session
                 if tracing:
                     jax.profiler.stop_trace()
+                    obs.annotate(annotated)
                 for s, h in installed.items():
                     signal.signal(s, h)
             device_sync(self.params)
@@ -1111,14 +1139,23 @@ class Trainer(BaseTrainer):
             megafuse=self.config.megafuse,
             autotune=self.config.autotune,
             gat_heads=gheads, gat_head_dim=gdim)
-        self.x = jnp.asarray(ds.features, self.dtype)
-        self.labels = jnp.asarray(ds.onehot_labels(), jnp.float32)
-        self.mask = jnp.asarray(ds.mask, jnp.int32)
-        self.params = model.init_params(self.key)
-        self.opt_state = self.optimizer.init(self.params)
+        with obs.span("place_data", what="nodes"):
+            self.x = jnp.asarray(ds.features, self.dtype)
+            self.labels = jnp.asarray(ds.onehot_labels(), jnp.float32)
+            self.mask = jnp.asarray(ds.mask, jnp.int32)
+        with obs.span("init_params"):
+            self.params = model.init_params(self.key)
+            self.opt_state = self.optimizer.init(self.params)
         self.num_nodes = ds.graph.num_nodes
-        n = self.num_nodes
-        self._resolve_mem_plan()
+        with obs.span("mem_plan"):
+            self._resolve_mem_plan()
+        with obs.span("step_build"):
+            self._build_steps()
+
+    def _build_steps(self):
+        """The jitted train, eval and logits steps (closures over the
+        model, the loss and the node count; nothing compiles here)."""
+        model, n = self.model, self.num_nodes
         loss_fn = self._loss_fn()
         mega = self.config.megafuse
         fdepth = getattr(self.config, "fusion_depth", 1)

@@ -54,6 +54,7 @@ import numpy as np
 
 from roc_tpu.device import on_tpu
 from roc_tpu.obs.ledger import content_key
+from roc_tpu.obs.tracer import span as _obs_span
 from roc_tpu.ops.pallas.binned import (Geometry, _plan_cache_dir,
                                        _vmem_bytes, _VMEM_NOMINAL_CAP)
 
@@ -288,7 +289,8 @@ def stale_plan_geom(edge_src, edge_dst, num_rows: int, table_rows: int,
     doc = load_store(path)
     if doc is None:
         return None
-    gkey = graph_key(edge_src, edge_dst, num_rows, table_rows)
+    with _obs_span("plan_key", edges=len(edge_src)):
+        gkey = graph_key(edge_src, edge_dst, num_rows, table_rows)
     variants = doc["entries"].get(gkey)
     if not variants:
         return None

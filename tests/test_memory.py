@@ -235,6 +235,36 @@ def test_trainstats_carry_peak_hbm(monkeypatch):
     assert all(b > 0 for b in stats.peak_hbm_bytes)
 
 
+def test_measured_peak_is_in_use_plus_reserved(monkeypatch):
+    """What the v5e reported for gcn-reddit.regular (PERF.md, PR 22,
+    finding 4): 1.29 GB of live arrays and 4.39 GB of program scratch
+    reserved beside them.  The program's counter reports their sum on the
+    fullest device, as the benchmark's `peak_hbm_gib` does."""
+    import jax
+
+    from roc_tpu import memory
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    chip = {"peak_bytes_in_use": 1287452160,
+            "peak_bytes_reserved": 4394631168}
+    devs = [Dev({"peak_bytes_in_use": 1000, "peak_bytes_reserved": 2000}),
+            Dev(chip)]
+    monkeypatch.setattr(jax, "local_devices", lambda: devs)
+    assert memory.measured_peak_bytes() == 5682083328
+    # a backend that reports no reserve: in use alone, as before
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [Dev({"peak_bytes_in_use": 7})])
+    assert memory.measured_peak_bytes() == 7
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(None)])
+    assert memory.measured_peak_bytes() is None
+
+
 # -- CPU acceptance criterion (products shape) ----------------------------
 
 def test_products_shape_peak_reduction():

@@ -34,8 +34,10 @@ def _obs_reset():
     obs state never leaks across tests."""
     tr = obs.get_tracer()
     prev = tr.enabled
+    prev_annotate = tr.annotate(False)     # each test arms what it needs
     yield
     tr.enabled = prev
+    tr.annotate(prev_annotate)
     tr.clear()
 
 
@@ -93,6 +95,280 @@ def test_validate_chrome_trace_flags_bad_events():
     assert validate_chrome_trace(
         {"traceEvents": [{"ph": "X", "name": "a", "ts": "oops", "dur": 1,
                           "pid": 1, "tid": 1}]}) != []
+
+
+# -- the bridge to the profiler's clock ------------------------------------
+
+def _host_events(trace_dir, prefix="roc."):
+    """(name, start ns, duration ns, stats) of the host plane's events
+    whose name starts with ``prefix``, by start."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(ev.name, ev.start_ns, ev.duration_ns,
+                         dict(ev.stats)) for ev in line.events
+                        if ev.name.startswith(prefix)]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _profiled(trace_dir, body):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _nested_spans():
+    for i in range(20):
+        with obs.span("outer", i=i, backend="binned"):
+            with obs.span("inner"):
+                np.ones(2000).sum()
+
+
+def test_enabled_spans_are_trace_annotations_on_the_profilers_clock(
+        tmp_path):
+    """Recording on + a live jax.profiler session: every span is also a
+    `roc.<name>` event on the host plane of the .xplane.pb, nested as the
+    spans nest, as long as the span (a median over 20, so that a
+    pre-empted worker does not fail it), its args as the event's stats."""
+    obs.get_tracer().clear()
+    obs.enable(True)
+    _profiled(tmp_path, _nested_spans)
+    spans = sorted(obs.get_tracer().spans(), key=lambda s: s.start_ns)
+    events = _host_events(tmp_path)
+    assert [e[0] for e in events] == ["roc.outer", "roc.inner"] * 20
+    assert [s.name for s in spans] == ["outer", "inner"] * 20
+    off = []
+    for (name, start, dur, stats), sp in zip(events, spans):
+        assert name == "roc." + sp.name
+        off.append(abs(dur - sp.dur_ns))
+    assert sorted(off)[len(off) // 2] < 200e3           # ns
+    for (_, o_start, o_dur, o_stats), (_, i_start, i_dur, _) in zip(
+            events[0::2], events[1::2]):
+        assert o_start <= i_start and i_start + i_dur <= o_start + o_dur
+    assert events[3 * 2][3] == {"i": 3, "backend": "binned"}
+
+
+def test_disabled_spans_leave_no_trace_annotation(tmp_path):
+    obs.enable(False)
+    _profiled(tmp_path, _nested_spans)
+    assert _host_events(tmp_path) == []
+    assert obs.get_tracer().spans() == []
+
+
+def test_annotate_arms_the_bridge_without_recording(tmp_path):
+    """What `-profile` without `-obs` uses: annotations in the trace, no
+    span in the ring, and the previous state handed back."""
+    obs.enable(False)
+    assert obs.annotate(True) is False
+    _profiled(tmp_path, _nested_spans)
+    assert obs.annotate(False) is True
+    assert len(_host_events(tmp_path)) == 40
+    assert obs.get_tracer().spans() == [] and not obs.enabled()
+
+
+def test_tracer_imports_without_jax_and_survives_its_absence():
+    """Kernel modules import the tracer before JAX: importing it, and
+    opening a disabled span, leaves `jax` out of sys.modules; where
+    `jax.profiler` cannot be imported an armed tracer goes on span-only."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys\n"
+        "import roc_tpu.obs.tracer as t\n"
+        "with t.span('quiet'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'import pulled jax in'\n"
+        "sys.modules['jax'] = None      # import jax now raises\n"
+        "t.enable(True)\n"
+        "with t.span('loud', k=1):\n"
+        "    pass\n"
+        "assert [s.name for s in t.get_tracer().spans()] == ['loud']\n"
+        "assert t.get_tracer()._annotation is None\n"
+        "print('ok')\n")
+    env = {k: v for k, v in os.environ.items() if k != "ROC_OBS"}
+    p = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0 and p.stdout.strip() == "ok", p.stderr[-2000:]
+
+
+# -- spans where the work happens ------------------------------------------
+
+SETUP_METRICS = ("geometry_s", "plan_key_s", "plan_fetch_s", "plan_place_s",
+                 "place_s")
+
+
+def _setup_metric_spans():
+    """The span names the five set-up metrics sum (their data files)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = set()
+    for metric in SETUP_METRICS:
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               metric + ".json"), encoding="utf-8") as f:
+            names |= set(json.load(f)["spans"])
+    return names
+
+
+def test_make_trainer_is_attributed_cold_and_warm(tmp_path, monkeypatch):
+    """A cold then a warm `make_trainer` on the binned backend with the plan
+    cache on: each records the spans its road takes, and the spans that
+    the set-up metrics sum are siblings: none opens inside another, so
+    their sum can be held against the whole."""
+    from roc_tpu.train.driver import make_trainer
+    monkeypatch.setenv("ROC_PLAN_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("ROC_PLAN_CACHE_MIN_EDGES", "0")
+    monkeypatch.delenv("ROC_PLAN_CACHE", raising=False)
+    summed = _setup_metric_spans()
+    ds = _dataset(n=300, deg=5.0)
+    obs.enable(True)
+    seen = {}
+    for run in ("cold", "warm"):
+        obs.get_tracer().clear()
+        cfg = Config(layers=[8, 8, 3], num_epochs=1, eval_every=1000,
+                     dropout_rate=0.0, aggregate_backend="binned")
+        make_trainer(cfg, ds, build_gcn(cfg.layers, 0.0))
+        spans = obs.get_tracer().spans()
+        seen[run] = {s.name for s in spans}
+        assert {"choose_geometry", "plan_key", "plan_to_device",
+                "place_data", "init_params", "mem_plan", "step_build",
+                "plan_build"} <= seen[run], run
+        mine = sorted((s for s in spans if s.name in summed),
+                      key=lambda s: s.start_ns)
+        assert len({s.tid for s in mine}) == 1
+        for a, b in zip(mine, mine[1:]):
+            assert a.start_ns + a.dur_ns <= b.start_ns, (run, a.name, b.name)
+        # ... and every one of them inside the plan build or beside it
+        assert {s.depth for s in mine} <= {0, 1}
+    assert seen["cold"] & {"plan_native_build", "plan_numpy_build"}
+    assert "plan_cache_save" in seen["cold"]
+    assert "plan_cache_load" not in seen["cold"]
+    assert "plan_cache_load" in seen["warm"]
+    assert not seen["warm"] & {"plan_native_build", "plan_numpy_build",
+                               "plan_cache_save"}
+    assert len(list(tmp_path.glob("binned_plan_*.npz"))) == 2
+
+
+def test_fused_step_lists_have_a_span_of_their_own():
+    """`_attach_fused` left `plan_cache_load`: a flat plan small enough to
+    fuse records `plan_fused_steps`, and its arrays go to the device under
+    `plan_to_device` like the rest."""
+    from roc_tpu.ops.pallas import binned as B
+    rng = np.random.default_rng(0)
+    dst = np.sort(rng.integers(0, 600, 4000))
+    src = rng.integers(0, 600, 4000)
+    obs.enable(True)
+    obs.get_tracer().clear()
+    plan = B._build_binned_plan_numpy(src, dst, 600, 600, geom=B.GEOM_FLAT)
+    assert plan.f_meta is not None
+    names = [s.name for s in sorted(obs.get_tracer().spans(),
+                                    key=lambda s: s.start_ns)]
+    assert names == ["plan_numpy_build", "plan_to_device",
+                     "plan_fused_steps", "plan_to_device"]
+
+
+def test_every_epoch_of_train_is_attributed():
+    """Three epochs of `train()`: what the host does for a step and between
+    two steps has a span, once an epoch, none inside another."""
+    tr = _trainer(False, num_epochs=3, eval_every=2)
+    obs.enable(True)
+    obs.get_tracer().clear()
+    tr.train(print_fn=lambda *a, **k: None)
+    spans = obs.get_tracer().spans()
+    count = {}
+    for s in spans:
+        count[s.name] = count.get(s.name, 0) + 1
+    for name in ("step_args", "step_call", "peak_hbm", "check_nonfinite",
+                 "retrace_boundary", "epoch", "step_dispatch",
+                 "device_sync"):
+        assert count.get(name) == 3, (name, count)
+    assert count["eval"] == count["eval_call"] == count["eval_fetch"] == 2
+    assert "obs_epoch" not in count and "metrics_fetch" not in count
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    # step_args then step_call inside step_dispatch; the rest between epochs
+    for args, call, disp in zip(by_name["step_args"], by_name["step_call"],
+                                by_name["step_dispatch"]):
+        assert disp.start_ns <= args.start_ns
+        assert args.start_ns + args.dur_ns <= call.start_ns
+        assert call.start_ns + call.dur_ns <= disp.start_ns + disp.dur_ns
+    for ep, hbm in zip(by_name["epoch"], by_name["peak_hbm"]):
+        assert ep.start_ns + ep.dur_ns <= hbm.start_ns and hbm.depth == 1
+    for ev, call, fetch in zip(by_name["eval"], by_name["eval_call"],
+                               by_name["eval_fetch"]):
+        assert ev.start_ns <= call.start_ns <= fetch.start_ns
+        assert fetch.start_ns + fetch.dur_ns <= ev.start_ns + ev.dur_ns
+
+
+def test_sharded_setup_and_balance_rounds_are_attributed():
+    """The SPMD trainer's set-up roads (partition, halo maps, per-shard
+    plans, placement, steps) and the balancer's rounds between epochs."""
+    ds = _dataset(n=400, deg=4.0, in_dim=16, classes=4, seed=3)
+    cfg = Config(layers=[16, 16, 4], num_epochs=3, num_parts=4, halo=True,
+                 eval_every=1000, dropout_rate=0.0, balance_every=1,
+                 aggregate_backend="matmul")
+    obs.enable(True)
+    obs.get_tracer().clear()
+    tr = SpmdTrainer(cfg, ds, build_gcn(cfg.layers, 0.0))
+    setup = [s.name for s in sorted(obs.get_tracer().spans(),
+                                    key=lambda s: s.start_ns)
+             if s.depth == 0]
+    assert setup == ["init_params", "partition", "halo_build", "plan_build",
+                     "place_data", "init_params", "mem_plan", "step_build"]
+    assert "plan_to_device" in obs.get_tracer().span_types()
+    assert tr.balancer is not None
+    obs.get_tracer().clear()
+    tr.train(print_fn=lambda *a, **k: None)
+    spans = obs.get_tracer().spans()
+    rounds = [s for s in spans if s.name == "balance"]
+    assert len(rounds) == 2            # never after the last epoch
+    probes = [s for s in spans if s.name == "probe"]
+    assert probes and all(
+        any(r.start_ns <= p.start_ns and p.start_ns + p.dur_ns
+            <= r.start_ns + r.dur_ns for r in rounds) for p in probes)
+
+
+def test_obs_epoch_span_only_runs_with_obs(tmp_path):
+    tr = _trainer(True, tmp_path, num_epochs=2)
+    obs.get_tracer().clear()
+    tr.train(print_fn=lambda *a, **k: None)
+    names = [s.name for s in obs.get_tracer().spans()]
+    assert names.count("obs_epoch") == 2 == names.count("metrics_fetch")
+
+
+def test_profile_without_obs_holds_the_spans_and_the_same_step(tmp_path):
+    """`-profile DIR` without `-obs`: the profiled window's trace holds the
+    program's spans as `roc.*` events, nothing is recorded in the ring,
+    the metrics channel stays off, and the step is traced as often as
+    without `-profile` (the same program)."""
+    traces = {}
+    for profile in ("", str(tmp_path / "prof")):
+        tr = _trainer(False, num_epochs=4, eval_every=2,
+                      profile_dir=profile, profile_epochs="1:3")
+        obs.get_tracer().clear()
+        with RetraceGuard(warmup=0, on_violation="record") as g:
+            tr.train(print_fn=lambda *a, **k: None)
+        traces[profile] = dict(g.counts)
+        assert tr._metrics is None and tr._last_step_metrics is None
+        assert obs.get_tracer().spans() == [] and not obs.enabled()
+        assert obs.annotate(False) is False      # disarmed after the window
+    assert traces[""] == traces[str(tmp_path / "prof")]
+    assert traces[""]["train_step"] == 1
+    names = [e[0] for e in _host_events(tmp_path / "prof")]
+    for name in ("roc.step_args", "roc.step_call", "roc.peak_hbm",
+                 "roc.check_nonfinite", "roc.epoch"):
+        assert names.count(name) == 3, (name, names)
+    assert names.count("roc.eval") == 1 == names.count("roc.eval_fetch")
 
 
 # -- watchdog --------------------------------------------------------------
