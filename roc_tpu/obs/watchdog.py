@@ -357,24 +357,33 @@ _BUDGETS_PATH = os.path.join(os.path.dirname(os.path.dirname(
 def seed_for_graph(num_rows: int, num_edges: int,
                    geometry: str = "default",
                    path: str = "") -> Optional[float]:
-    """Predicted binned-kernel floor (seconds per aggregation pass) for a
+    """Predicted binned-kernel time (seconds per aggregation pass) for a
     graph shape pinned in tools/kernel_budgets.json: the committed
-    steps_total x the measured per-grid-step overhead the binned cost
-    model uses (`_CHUNK_OVERHEAD_S`, 9.6-12.2 us measured on v5e).  None
+    schedule counts (padded rows, steps per phase) priced by the binned
+    cost model itself (`_binned_cost_model`, re-fit from chip times in
+    PR 24), so this seed and the geometry policy cannot disagree.  None
     when the shape isn't pinned — the EWMA then warms up from measured
-    epochs instead.  This is a *floor* (one aggregation pass, no matmuls),
-    so seeding only arms the "order of magnitude off" detector early; it
-    never replaces measured epochs, which take over after one EWMA step."""
+    epochs instead.  This is a *floor* on the epoch (one aggregation
+    pass, no linears), so seeding only arms the "order of magnitude off"
+    detector early; it never replaces measured epochs, which take over
+    after one EWMA step."""
     try:
         with open(path or _BUDGETS_PATH, encoding="utf-8") as f:
             budgets = json.load(f)
-        from roc_tpu.ops.pallas.binned import _CHUNK_OVERHEAD_S
+        from roc_tpu.ops.pallas.binned import (GEOM_PRESETS,
+                                               _binned_cost_model,
+                                               _default_geom)
         for entry in budgets.values():
             if entry.get("num_rows") == num_rows and \
                     entry.get("num_edges") == num_edges:
                 geo = entry["geometries"].get(geometry)
                 if geo:
-                    return float(geo["steps_total"]) * _CHUNK_OVERHEAD_S
+                    geom = (_default_geom() if geometry == "default"
+                            else GEOM_PRESETS[geometry])
+                    return float(_binned_cost_model(
+                        geo["padded_rows"], geom,
+                        steps1=geo["steps_phase1"],
+                        steps2=geo["steps_phase2"]))
     except (OSError, ValueError, KeyError, ImportError):
         # seeding is strictly best-effort: no budgets file / unpinned
         # shape degrades to measured-epoch warmup, the documented

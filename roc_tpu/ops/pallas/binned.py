@@ -450,20 +450,38 @@ def binned_viable(num_rows: int, table_rows: int, num_edges: int,
     return num_blocks * num_bins * SLOT * 4 <= num_edges * 5
 
 
-# Cost-model calibration, measured on v5e at Reddit shape (docs/PERF.md,
-# 2026-07-31): both phases are per-grid-step-overhead-bound at ~10/12 us
-# per chunk, with the one-hot MACs sustaining ~35-44% of the 197 TF/s bf16
-# peak when they dominate; phase 1 additionally pays a per-slot-DMA issue
-# cost — the SLOT sweep's own signal (32 -> 128 saved 19.3 ms on ~624k
-# fewer DMAs at equal padded rows = ~31 ns per slot DMA), without which
-# the model would mis-rank small-slot presets above the measured SLOT=128
-# winner on dense graphs.  t_phase1 = max(MAC, chunk overhead) + slot-DMA
-# issue; the matmul backend's cost is its issue-rate-bound row gather
-# (~10 ns/row, H-independent up to ~128 lanes) plus its cheap VB=8
-# one-hot dots — calibrated end to end: 23.5M edges -> 351 ms = 15 ns/edge.
-_MXU_EFF_FLOPS = 69e12        # 35% of v5e bf16 peak (phase-1 measured)
-_CHUNK_OVERHEAD_S = 11e-6     # per grid step (9.6-12.2 us measured)
-_SLOT_DMA_S = 31e-9           # per staging slot DMA (SLOT sweep delta)
+# Cost-model calibration: re-fit in PR 24 (2026-09-30) from per-kernel device
+# times on one TPU v5 lite chip (jax 0.9.0, libtpu 0.0.34), both benchmark
+# graphs (232,965 nodes, 23.4 M / 23.5 M in-edges), both plan directions
+# pinned to each preset in turn, widths 256 and 128.  The measured rows are
+# committed beside this file as binned_chip_table.json, and
+# tests/test_binned.py holds the model to every row of it (15 % on phase 1
+# + phase 2 at width 256, and the measured order).  What the chip showed:
+#   * the one-hot matmuls of BOTH phases run the MXU at its published peak
+#     (197 TFLOP/s bf16; fitting the rate freely gives 199-207), so
+#     _MXU_EFF_FLOPS is the peak itself — the round-2 figure of 35 % was
+#     the old stack's;
+#   * on top of the MACs the two-pass phase 1 pays 0.44 us a grid step and
+#     54 ns a real staging-slot DMA (slot 128 -> 32 -> 16: 222 k, 834 k,
+#     1,487 k slots a sweep; residuals under 2.2 % on seven rows);
+#   * phase 2 pays 0.43 ns a staging row it reads (mask, one-hot build and
+#     operand streaming follow the rows, not the step count: 4096- and
+#     8192-row chunks cost the same per row);
+#   * the flat phase 1 pays for every descriptor SLOT it walks, real or
+#     not: 54 ns a visit, and each grid step visits all KD = ch / unit
+#     slots twice (issue, then drain two steps later) — 55 us of a 65 us
+#     step at KD 512 — plus 27 ns a real copy.  GEOM_FLAT (KD 512, 875 k
+#     copies) and GEOM_FLAT_SPARSE (KD 256, 325 k copies, twice the steps)
+#     walk the same 6.3 M slots a sweep and take the same 406 / 419 ms.
+# Every term is linear in its rate, so tune/refit.py re-solves them by
+# least squares over the same regressors (_cost_terms).  The matmul
+# backend's constant below was NOT re-fit here.
+from roc_tpu.obs.roofline import PEAK_FLOPS as _MXU_EFF_FLOPS  # noqa: E402
+_CHUNK_OVERHEAD_S = 0.44e-6   # per phase-1 grid step, beyond its MACs
+_SLOT_DMA_S = 54e-9           # per real staging-slot DMA (two-pass phase 1)
+_P2_ROW_S = 0.43e-9           # per staging row phase 2 reads
+_FLAT_SLOT_S = 54e-9          # per descriptor slot visited (flat phase 1)
+_FLAT_COPY_S = 27e-9          # per real size-classed copy (flat phase 1)
 # Matmul backend: per-chunk cost of the one-hot scan (gather EB rows +
 # S1/S2 dots + DUS).  Re-fit 2026-08-04 from the round-2 Reddit point
 # (23.5M edges -> 351 ms) against the REAL chunk count — ceil(E/EB) edge
@@ -496,24 +514,37 @@ _VMEM_LIMIT_MAX = 100 * (1 << 20)
 # Mosaic and their kernels still compile under the 16 MiB default
 # (CHANGES.md PR 21 has the compile inventory).
 _VMEM_BUDGET = 14 * (1 << 20)
+# HBM admission for choose_geometry, the analogue of _VMEM_NOMINAL_CAP: a
+# candidate's per-group temporaries (_group_hbm_bytes, from shapes) may
+# take a quarter of a v5e's 16 GiB.  The rest belongs to the features,
+# the activations kept for the backward pass, the plans and the
+# optimiser.  Measured (PR 24, one chip, Reddit shape): the default
+# two-pass group needs 2.8 GB and the step peaks at 4.84 GiB, GEOM_FLAT
+# 3.3 GB and 5.29 GiB, GEOM_WIDE (grt 1 << 23) 11.2 GB and 12.69 GiB.
+_HBM_GROUP_CAP = 4 * (1 << 30)
 
 
 _MEASURED_CAL: dict = {}   # path -> parsed rates (None = no device table)
 
 
 def measured_calibration(path: str = ""):
-    """Device-measured kernel rates from the ``measured`` table
+    """The device-measured matmul rate from the ``measured`` table
     tools/kernel_bench.py persists into tools/kernel_budgets.json:
-    ``{"chunk_s": <binned per-grid-step s>, "mm_chunk_s": <matmul
-    per-chunk s or None>}`` (medians over the benched shapes/variants).
+    ``{"mm_chunk_s": <matmul per-chunk s>}`` (median over the benched
+    shapes).
 
-    Returns None — analytic constants stay in charge — when no table
+    Returns None — the analytic constant stays in charge — when no table
     exists, the table was recorded in interpret mode (CPU harness
-    timings, not rates), or ROC_NO_MEASURED_CAL=1 kills it.  The cost
-    model (_binned_cost_model / _matmul_cost) and the balance prior
-    (balance/cost_model.py) warm-start from these in place of the
-    hand-fit _CHUNK_OVERHEAD_S / _MM_CHUNK_S.  Cached per path;
-    ROC_MEASURED_CAL_PATH overrides the default table location."""
+    timings, not rates), it has no matmul row, or ROC_NO_MEASURED_CAL=1
+    kills it.  _matmul_cost and the balance prior
+    (balance/cost_model.py) warm-start from it in place of the hand-fit
+    _MM_CHUNK_S.  The binned kernels' rates are NOT read from this table
+    (until PR 24 one median ``per_step_s`` over every kernel and variant
+    stood in for _CHUNK_OVERHEAD_S): a flat step costs 65 us and a
+    two-pass step 4-7 us on the chip, no median of the two prices
+    either, and the per-family rates live with their measured rows in
+    binned_chip_table.json.  Cached per path; ROC_MEASURED_CAL_PATH
+    overrides the default table location."""
     if os.environ.get("ROC_NO_MEASURED_CAL"):
         return None
     if not path:
@@ -529,18 +560,12 @@ def measured_calibration(path: str = ""):
         with open(path, encoding="utf-8") as f:
             m = json.load(f).get("measured") or {}
         if not m.get("interpret", True):
-            steps, mm = [], []
-            for shp in m.get("shapes", {}).values():
-                for row in shp.get("kernels", {}).values():
-                    if row.get("variant") == "matmul":
-                        mm.append(float(row["per_chunk_s"]))
-                    elif "per_step_s" in row:
-                        steps.append(float(row["per_step_s"]))
-            if steps:
-                steps.sort()
-                mm.sort()
-                cal = {"chunk_s": steps[len(steps) // 2],
-                       "mm_chunk_s": mm[len(mm) // 2] if mm else None}
+            mm = sorted(float(row["per_chunk_s"])
+                        for shp in m.get("shapes", {}).values()
+                        for row in shp.get("kernels", {}).values()
+                        if row.get("variant") == "matmul")
+            if mm:
+                cal = {"mm_chunk_s": mm[len(mm) // 2]}
     except (OSError, ValueError, KeyError, TypeError):
         cal = None
     _MEASURED_CAL[path] = cal
@@ -616,45 +641,88 @@ def _vmem_params(need: int):
         vmem_limit_bytes=min(int(need), _VMEM_LIMIT_MAX))
 
 
+def _cost_terms(padded_rows: int, geom: Geometry, H: int = _MODEL_H,
+                steps1: int = None, steps2: int = None,
+                copies: int = None) -> dict:
+    """What ONE aggregation pass at this geometry does, counted: the
+    regressors of the cost model, by the name of the rate that prices
+    each (_COST_RATES).  Modeled seconds are the sum of rate x count, so
+    the tuner's surrogate prices through the same counts with its own
+    rates and tune/refit.py solves the rates back from them.
+
+    ``steps1``/``steps2`` are exact grid step counts (_plan_steps):
+    with them the schedule that is priced is the real one, per-(group,
+    block) chunk rounding and per-group max-padding included; without
+    them the ideal padded_rows / chunk.  ``copies`` is the flat
+    schedule's real descriptor count (_flat_copies over the cell
+    statistics); without it every padded unit is taken for one copy."""
+    s1 = steps1 if steps1 is not None else padded_rows / geom.ch
+    s2 = steps2 if steps2 is not None else padded_rows / geom.ch2
+    terms = {"mxu": (s1 * geom.ch * geom.sb + s2 * geom.ch2 * geom.rb)
+             * H * 2,
+             "p1_step": s1, "p2_row": s2 * geom.ch2,
+             "slot_dma": 0.0, "flat_slot": 0.0, "flat_copy": 0.0}
+    if geom.flat:
+        # issue walks all KD descriptor slots of the step, drain walks
+        # them again two steps later, real or -1 alike
+        terms["flat_slot"] = 2 * s1 * geom.kd
+        terms["flat_copy"] = (copies if copies is not None
+                              else padded_rows / geom.unit_rows)
+    else:
+        terms["slot_dma"] = padded_rows / geom.slot
+    return terms
+
+
+# seconds per count of each _cost_terms regressor
+_COST_RATES = {"mxu": 1.0 / _MXU_EFF_FLOPS, "p1_step": _CHUNK_OVERHEAD_S,
+               "p2_row": _P2_ROW_S, "slot_dma": _SLOT_DMA_S,
+               "flat_slot": _FLAT_SLOT_S, "flat_copy": _FLAT_COPY_S}
+
+
 def _binned_cost_model(padded_rows: int, geom: Geometry,
                        H: int = _MODEL_H, steps1: int = None,
-                       steps2: int = None) -> float:
+                       steps2: int = None, copies: int = None) -> float:
     """Modeled seconds for ONE aggregation pass at this geometry, given the
-    actual slot-padded staging row count (from cell statistics).
+    actual padded staging row count (from cell statistics): the counts of
+    _cost_terms at the rates the chip measured (calibration block above).
+    Phase 1 is MACs + steps + staging DMAs (per real slot in the two-pass
+    schedule, per descriptor slot walked and per real copy in the flat
+    one), phase 2 is MACs + staging rows read."""
+    terms = _cost_terms(padded_rows, geom, H, steps1, steps2, copies)
+    return sum(_COST_RATES[k] * v for k, v in terms.items())
 
-    With ``steps1``/``steps2`` (exact grid step counts, _plan_steps) the
-    MAC and per-step-overhead terms price the REAL schedule — including
-    per-(group, block) chunk rounding and per-group max-padding, the
-    effects the wide-chunk presets exist to shrink.  Without them the
-    model falls back to the ideal padded_rows/chunk approximation."""
-    rows1 = steps1 * geom.ch if steps1 is not None else padded_rows
-    rows2 = steps2 * geom.ch2 if steps2 is not None else padded_rows
-    mac1 = rows1 * geom.sb * H * 2 / _MXU_EFF_FLOPS
-    mac2 = rows2 * geom.rb * H * 2 / _MXU_EFF_FLOPS
-    # Per-grid-step overhead: the measured rate from the last hardware
-    # kernel_bench run when one is committed, the hand-fit constant
-    # otherwise (measured_calibration — interpret tables never apply).
-    cal = measured_calibration()
-    step_s = (cal or {}).get("chunk_s") or _CHUNK_OVERHEAD_S
-    ov1 = (steps1 if steps1 is not None
-           else padded_rows / geom.ch) * step_s
-    ov2 = (steps2 if steps2 is not None
-           else padded_rows / geom.ch2) * step_s
-    if geom.flat:
-        # Flat staging writes are per-run size-classed DMAs, not per-slot:
-        # a typical cell (~1 run) moves in a few descriptors.  Modeled at
-        # an average 4-unit copy, scaled by the staging itemsize relative
-        # to the bf16 slot schedule the constant was fit on (fp32 8-row
-        # units pay 2x the bytes; bf16 16-row units pay 1x on half the
-        # descriptors) — constants to be re-fit from the next hardware
-        # window (ROADMAP standing item; the policy and the grid test
-        # price candidates through this same branch, so the ranking is
-        # self-consistent either way).
-        dma1 = (padded_rows / (geom.unit_rows * 4) * _SLOT_DMA_S
-                * (staging_itemsize(geom, False) / 2))
-    else:
-        dma1 = padded_rows / geom.slot * _SLOT_DMA_S
-    return max(mac1, ov1) + dma1 + max(mac2, ov2)
+
+def _flat_copies(cnt: np.ndarray, geom: Geometry):
+    """Real staging copies a flat plan issues for these cell occupancies
+    (None for a two-pass geometry, which has none): each cell's units
+    decompose greedily into _DMA_CLS size classes, as the plan builder
+    decomposes a run (a 113-edge cell pads to 15 units = 3 x 4 + 3 x 1,
+    six copies).  Cells cut by a chunk boundary add a few; at Reddit
+    shape this count is the built plan's, 875,088."""
+    if not geom.flat:
+        return None
+    units = -(-np.asarray(cnt, np.int64) // geom.unit_rows)
+    n = 0
+    for c in _DMA_CLS:
+        n += int((units // c).sum())
+        units = units % c
+    return n
+
+
+def _group_hbm_bytes(geom: Geometry, steps1: int, steps2: int,
+                     groups: int, H: int = _MODEL_H) -> int:
+    """HBM the scan over bin groups holds for ONE group at a time, from
+    shapes: the staging buffer [C2 * ch2, H] in its staging dtype plus the
+    larger of the two `[rows, 1]` int32 index operands (p1_srcl while
+    phase 1 runs, p2_dstl while phase 2 does), which the step keeps in
+    the tiled layout, 128 lanes a row.  Against the compiler's own
+    analysis of the Reddit train step for a v5e (PR 24): default two-pass
+    2.78 GB here and 4.10 GB of temporaries there, GEOM_FLAT 3.33 and
+    4.65, GEOM_WIDE 11.2 and 12.5 — the same 1.3 GB apart in all three."""
+    p1_rows = steps1 // max(groups, 1) * geom.ch
+    stg_rows = steps2 // max(groups, 1) * geom.ch2
+    return (stg_rows * H * staging_itemsize(geom, False)
+            + max(p1_rows, stg_rows) * 128 * 4)
 
 
 def _cell_stats(edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -737,6 +805,18 @@ def _flat_pack(stream_g: np.ndarray, stream_units: np.ndarray,
     return c1_per_g, segs
 
 
+def _plan_groups(geom: Geometry, num_rows: int, table_rows: int,
+                 num_edges: int):
+    """(bins, source blocks, bins per group, groups) the plan builders
+    lay this shape out in at this geometry."""
+    num_bins = max(-(-num_rows // geom.rb), 1)
+    num_blocks = max(-(-table_rows // geom.sb), 1)
+    bpg = max(min(num_bins,
+                  int(geom.group_rows / max(num_edges / num_bins, 1)),
+                  _K2_CAP // num_blocks), 1)
+    return num_bins, num_blocks, bpg, -(-num_bins // bpg)
+
+
 def _flat_plan_steps(cell_blk, cell_bin, cnt, geom, num_bins, num_blocks,
                      bpg, G):
     """Flat-schedule arm of _plan_steps: cells pad to unit_rows, phase-1
@@ -771,12 +851,8 @@ def _plan_steps(cell_blk: np.ndarray, cell_bin: np.ndarray,
     group runs the per-group MAXIMUM chunk count (one stacked static
     program), so group-count and rounding effects are priced, which is
     what makes the chunk-count lever visible to the cost model."""
-    num_bins = max(-(-num_rows // geom.rb), 1)
-    num_blocks = max(-(-table_rows // geom.sb), 1)
-    bpg = max(min(num_bins,
-                  int(geom.group_rows / max(num_edges / num_bins, 1)),
-                  _K2_CAP // num_blocks), 1)
-    G = -(-num_bins // bpg)
+    num_bins, num_blocks, bpg, G = _plan_groups(geom, num_rows, table_rows,
+                                                num_edges)
     if geom.flat:
         return _flat_plan_steps(cell_blk, cell_bin, cnt, geom, num_bins,
                                 num_blocks, bpg, G)
@@ -826,12 +902,8 @@ def _fused_sched_stats(cell_blk, cell_bin, cnt, geom, num_rows, table_rows,
     term)."""
     if not (geom.flat and geom.ch == geom.ch2):
         return None
-    num_bins = max(-(-num_rows // geom.rb), 1)
-    num_blocks = max(-(-table_rows // geom.sb), 1)
-    bpg = max(min(num_bins,
-                  int(geom.group_rows / max(num_edges / num_bins, 1)),
-                  _K2_CAP // num_blocks), 1)
-    G = -(-num_bins // bpg)
+    num_bins, num_blocks, bpg, G = _plan_groups(geom, num_rows, table_rows,
+                                                num_edges)
     U = geom.unit_rows
     cell_units = -(-cnt // U)
     gb = (cell_bin // bpg) * num_blocks + cell_blk
@@ -1014,7 +1086,8 @@ def _priced_tuned(edge_src, edge_dst, num_rows, table_rows, E, geom,
     cblk, cbin, cnt = _cell_stats(edge_src, edge_dst, geom.sb, geom.rb)
     padded, s1, s2 = _plan_steps(cblk, cbin, cnt, geom, num_rows,
                                  table_rows, E)
-    t = _binned_cost_model(padded, geom, steps1=s1, steps2=s2)
+    t = _binned_cost_model(padded, geom, steps1=s1, steps2=s2,
+                           copies=_flat_copies(cnt, geom))
     if fuse_linear:
         fs = _fused_sched_stats(cblk, cbin, cnt, geom, num_rows,
                                 table_rows, E)
@@ -1049,6 +1122,14 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
     on the one-hot matmul side instead, the dense hub cells staying
     binned.  A hybrid winner is returned with ``hub_minc`` set on the
     geometry; build_binned_plans splits the edge list accordingly.
+
+    ADMISSION, from shapes alone: a candidate is skipped when its
+    nominal scoped-VMEM footprint is over _VMEM_NOMINAL_CAP, and when the
+    temporaries one bin group holds in HBM (_group_hbm_bytes: staging plus
+    the larger lane-padded index operand, at _MODEL_H) are over
+    _HBM_GROUP_CAP — GEOM_WIDE's grt 1 << 23 halves the steps of the
+    default and is 4 % faster on the chip at Reddit shape, for 12.7 GiB
+    of peak HBM against 4.8 (PR 24).
 
     Returns (geom, modeled_seconds), with geom None when matmul wins (and
     the seconds then model matmul).  ``force=True`` always returns the best
@@ -1119,6 +1200,11 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
     if fuse_linear:
         rt = (2 * num_rows * _MODEL_H * 4 / _HBM_BW
               + -(-num_rows // 512) * _CHUNK_OVERHEAD_S)
+
+    def fits_hbm(g, s1, s2, edges):
+        groups = _plan_groups(g, num_rows, table_rows, edges)[3]
+        return _group_hbm_bytes(g, s1, s2, groups) <= _HBM_GROUP_CAP
+
     best, best_t = None, float("inf")
     best_steps = None   # winner's (s1, s2) for the calibration ledger
     stats_cache = {}
@@ -1134,18 +1220,24 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
         cblk, cbin, cnt = stats_cache[sk]
         padded, s1, s2 = _plan_steps(cblk, cbin, cnt, g, num_rows,
                                      table_rows, E)
-        t = _binned_cost_model(padded, g, steps1=s1, steps2=s2)
-        if rt:
-            fs = _fused_sched_stats(cblk, cbin, cnt, g, num_rows,
-                                    table_rows, E)
-            if fs is None:
-                t += rt
-            else:
-                # fused layer: real chunks only, matmul in-pipeline —
-                # scale the two-pass aggregation model by the step ratio
-                t *= fs[0] / max(s1 + s2, 1)
-        if t < best_t:
-            best, best_t, best_steps = g, t, (s1, s2)
+        # admitted by memory as by VMEM, from shapes: a group's staging
+        # and index operands must leave the step its HBM (the hybrid
+        # below is admitted on its own, smaller, schedule)
+        if fits_hbm(g, s1, s2, E):
+            t = _binned_cost_model(padded, g, steps1=s1, steps2=s2,
+                                   copies=_flat_copies(cnt, g))
+            if rt:
+                fs = _fused_sched_stats(cblk, cbin, cnt, g, num_rows,
+                                        table_rows, E)
+                if fs is None:
+                    t += rt
+                else:
+                    # fused layer: real chunks only, matmul in-pipeline —
+                    # scale the two-pass aggregation model by the step
+                    # ratio
+                    t *= fs[0] / max(s1 + s2, 1)
+            if t < best_t:
+                best, best_t, best_steps = g, t, (s1, s2)
         # Hybrid variant: the sub-half-full cells' edges go to the matmul
         # side (they pay its per-chunk rate but no slot padding); the
         # matmul window floor is a fixed cost of having a matmul side at
@@ -1160,6 +1252,8 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
             padded_d, s1_d, s2_d = _plan_steps(
                 cblk[keep], cbin[keep], cnt[keep], g, num_rows,
                 table_rows, E - E_thin)
+            if not fits_hbm(g, s1_d, s2_d, E - E_thin):
+                continue
             t_h = (_binned_cost_model(padded_d, g, steps1=s1_d,
                                       steps2=s2_d)
                    + _matmul_cost(E_thin, num_rows)

@@ -22,9 +22,11 @@ that into a measured SEARCH:
   store.py      the content-keyed ``tuned.json`` tier `choose_geometry`
                 consults BEFORE its analytic model — same key discipline
                 as the ROC_PLAN_CACHE plan cache, stored alongside it.
-  refit.py      re-solve _CHUNK_OVERHEAD_S, the flat staging-DMA term,
-                and the matmul per-chunk rate from trial records; on
-                device, emit the kernel_budgets.json measured table.
+  refit.py      re-solve the rates of binned's cost terms (per step,
+                per staging row, per slot DMA, per flat descriptor slot
+                and copy) and the matmul per-chunk rate from trial
+                records; on device, emit the kernel_budgets.json
+                measured table.
 
 Entry points: ``python -m roc_tpu.tune`` (see __main__.py), the driver's
 ``-autotune`` / ``ROC_AUTOTUNE=1`` flag, and hw_revalidate step 3h.

@@ -204,8 +204,8 @@ def main(argv=None) -> int:
     if args.refit:
         print("tune: refit rates "
               + json.dumps({k: rates[k] for k in
-                            ("chunk_s", "slot_dma_s", "flat_dma_s",
-                             "mm_chunk_s")}, sort_keys=True))
+                            (*R.RATE_NAMES, "mm_chunk_s")},
+                           sort_keys=True))
         print("tune: refit vs committed constants "
               + json.dumps({k: round(v, 4) for k, v in
                             sorted(rates["vs_constants"].items())}))

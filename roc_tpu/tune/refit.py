@@ -1,39 +1,36 @@
 """Refit: re-solve the cost-model rate constants from trial records.
 
-The analytic model prices one aggregation pass as
+binned's analytic model prices one aggregation pass as a sum of rate x
+count over ``_cost_terms`` (MXU flops, phase-1 steps, phase-2 staging
+rows, slot DMAs, flat descriptor slots walked, flat copies), and the
+matmul backend as ``chunks * mm_chunk_s``.  Each trial record carries
+its measured seconds AND those counts — so recovering the rates is a
+small linear least-squares, not a re-derive:
 
-    t = max(mac1, s1*chunk_s) + dma_units*slot_dma_s + max(mac2, s2*chunk_s)
+    t_i - mxu_i / peak = sum_k rate_k * count_ik
 
-(surrogate.analytic_seconds — the parameterized mirror of binned's
-``_binned_cost_model``), and the matmul backend as ``chunks *
-mm_chunk_s``.  Each trial record carries its measured seconds AND its
-schedule facts (step counts, the DMA regressor, flat/non-flat) — so
-recovering the rates is a small linear least-squares, not a re-derive:
-
-    t_i = chunk_s * steps_i + slot_dma_s * dma_nonflat_i
-                            + flat_dma_s * dma_flat_i
-
-over the overhead-bound, knob-default aggregation trials (MAC-bound
-trials are excluded: their max() clamps break linearity in the rate;
-knob-variant trials are excluded: the screen's priors would contaminate
-the solve).  When the sweep's PROBE records are present they are the
-whole calibration set — the halving's survivors cluster around the
-winner, leaving steps and dma_units nearly collinear, while the probes
-are designed pairs that pull those columns apart (search.REFIT_PROBES).  ``flat_dma_s`` is the flat staging-DMA term solved as its
-own column — same nominal constant today, but the hardware fit is
-allowed to disagree (the flat schedule's size-classed copies are a
-different DMA population than the slot schedule's, which is exactly the
-standing re-fit question in the ROADMAP).  ``mm_chunk_s`` is the median
-implied rate of the matmul reference trials.
+over the knob-default aggregation trials (knob-variant trials are
+excluded: the screen's priors would contaminate the solve; the MXU's
+rate is the published peak and is not solved).  When the sweep's PROBE
+records are present they are the whole calibration set — the halving's
+survivors cluster around the winner, leaving the counts nearly
+collinear, while the probes are designed contrasts that pull the
+columns apart (search.REFIT_PROBES).  A column no trial exercises (no
+flat trial, say) drops out rather than polluting the fit.
+``mm_chunk_s`` is the median implied rate of the matmul reference
+trials.
 
 On the CI surrogate the recovered rates must land within 5% of the
 generating constants (surrogate.CONSTANTS) — the acceptance pin that
 proves sweep -> ledger records -> refit closes the loop.  On device the
-same solve produces the real constants, and ``to_measured_table`` /
-``update_budgets`` persist them in the kernel_bench ``measured`` format
-(tools/kernel_budgets.json) that ``measured_calibration`` and the
-balance prior warm-start from — with the same refusal contract:
-``update_budgets`` will not commit an interpret table as rates.
+same solve produces the real constants; the binned rates then go, with
+their measured rows, where PR 24's went (ops/pallas/binned.py's
+calibration block and binned_chip_table.json), and ``to_measured_table``
+/ ``update_budgets`` persist the trials in the kernel_bench ``measured``
+format (tools/kernel_budgets.json) that ``measured_calibration`` reads
+the matmul rate from and the balance prior warm-starts from — with the
+same refusal contract: ``update_budgets`` will not commit an interpret
+table as rates.
 """
 
 from __future__ import annotations
@@ -43,7 +40,11 @@ import os
 
 import numpy as np
 
+from roc_tpu.ops.pallas.binned import _COST_RATES
 from roc_tpu.tune.surrogate import CONSTANTS
+
+#: The solved columns: every cost term but the MXU's.
+RATE_NAMES = tuple(k for k in _COST_RATES if k != "mxu")
 
 
 def _fields(tr):
@@ -54,18 +55,17 @@ def _fields(tr):
                                    "tune_probe") or "steps" not in tr:
             return None
         return {"t": float(tr["value"]), "steps": int(tr["steps"]),
-                "dma_units": float(tr.get("dma_units", 0.0)),
+                "terms": {k: float(tr.get(k, 0.0)) for k in _COST_RATES},
                 "flat": bool(tr.get("flat", 0)),
-                "mac_bound": bool(tr.get("mac_bound", False)),
                 "default_knobs": bool(tr.get("default_knobs", True)),
                 "matmul": bool(tr.get("matmul", False)),
                 "stage": str(tr.get("stage", "")),
                 "variant": str(tr.get("variant", "")),
                 "shape": str(tr.get("shape", ""))}
-    return {"t": tr.trial_s, "steps": tr.steps, "dma_units": tr.dma_units,
+    return {"t": tr.trial_s, "steps": tr.steps,
+            "terms": {k: float(tr.terms.get(k, 0.0)) for k in _COST_RATES},
             "flat": bool(tr.geom and tr.geom[7]) if len(tr.geom) > 7
-            else False, "mac_bound": tr.mac_bound,
-            "default_knobs": tr.default_knobs,
+            else False, "default_knobs": tr.default_knobs,
             "matmul": tr.stage == "matmul", "stage": tr.stage,
             "variant": tr.variant, "shape": tr.shape}
 
@@ -74,10 +74,9 @@ def refit_rates(trials) -> dict:
     """Solve the rate constants from trial records (TrialRecords from a
     live sweep, or ledger measurement dicts from the JSONL stream).
 
-    Returns {chunk_s, slot_dma_s, flat_dma_s, mm_chunk_s, n_agg, n_mm,
-    vs_constants: {name: refit/committed ratio}} — rates are None when
-    no eligible trials identify them (e.g. no flat trials survived the
-    halving: the flat column drops out rather than polluting the fit)."""
+    Returns {<RATE_NAMES>, mm_chunk_s, n_agg, n_mm, vs_constants: {name:
+    refit/committed ratio}} — a rate is None when no eligible trial
+    exercises it."""
     agg, mm = [], []
     for tr in trials:
         f = _fields(tr)
@@ -87,45 +86,40 @@ def refit_rates(trials) -> dict:
             if f["steps"] > 0:
                 mm.append(f["t"] / f["steps"])
             continue
-        if f["mac_bound"] or not f["default_knobs"] or \
-                "+fuse" in f["variant"]:
+        if not f["default_knobs"] or "+fuse" in f["variant"]:
             continue
         agg.append(f)
     # The probe stage is search.py's designed experiment; the halving's
-    # own survivors cluster (near-collinear steps vs dma_units), so when
-    # probes exist they ARE the calibration set.
+    # own survivors cluster (near-collinear counts), so when probes exist
+    # they ARE the calibration set.
     probes = [f for f in agg if f["stage"] == "probe"]
     if probes:
         agg = probes
-    out = {"chunk_s": None, "slot_dma_s": None, "flat_dma_s": None,
-           "mm_chunk_s": None, "n_agg": len(agg), "n_mm": len(mm)}
+    out = {**{k: None for k in RATE_NAMES}, "mm_chunk_s": None,
+           "n_agg": len(agg), "n_mm": len(mm)}
     if agg:
-        cols = [[f["steps"] for f in agg],
-                [0.0 if f["flat"] else f["dma_units"] for f in agg],
-                [f["dma_units"] if f["flat"] else 0.0 for f in agg]]
-        names = ["chunk_s", "slot_dma_s", "flat_dma_s"]
-        # drop all-zero columns (no flat or no non-flat trials) so the
-        # lstsq stays full-rank and deterministic
-        keep = [i for i, c in enumerate(cols) if any(v != 0 for v in c)]
-        A = np.asarray([cols[i] for i in keep], dtype=np.float64).T
-        b = np.asarray([f["t"] for f in agg], dtype=np.float64)
+        # drop all-zero columns so the lstsq stays full-rank and
+        # deterministic
+        keep = [k for k in RATE_NAMES
+                if any(f["terms"][k] != 0 for f in agg)]
+        A = np.asarray([[f["terms"][k] for k in keep] for f in agg],
+                       dtype=np.float64)
+        t = np.asarray([f["t"] for f in agg], dtype=np.float64)
+        b = t - np.asarray([f["terms"]["mxu"] for f in agg],
+                           dtype=np.float64) * _COST_RATES["mxu"]
         # measurement noise is multiplicative (a fraction of each total),
         # so weight rows by 1/t: otherwise the long trials' absolute
-        # noise drowns the small DMA column's contrast
-        w = 1.0 / np.maximum(b, 1e-12)
+        # noise drowns the small columns' contrast
+        w = 1.0 / np.maximum(t, 1e-12)
         sol, *_ = np.linalg.lstsq(A * w[:, None], b * w, rcond=None)
-        for i, v in zip(keep, sol):
-            out[names[i]] = float(v)
+        for k, v in zip(keep, sol):
+            out[k] = float(v)
     if mm:
         mm.sort()
         out["mm_chunk_s"] = mm[len(mm) // 2]
-    committed = {"chunk_s": CONSTANTS["chunk_s"],
-                 "slot_dma_s": CONSTANTS["slot_dma_s"],
-                 "flat_dma_s": CONSTANTS["slot_dma_s"],
-                 "mm_chunk_s": CONSTANTS["mm_chunk_s"]}
     out["vs_constants"] = {
-        k: out[k] / committed[k]
-        for k in committed if out.get(k) is not None and committed[k]}
+        k: out[k] / CONSTANTS[k]
+        for k in CONSTANTS if out.get(k) is not None and CONSTANTS[k]}
     return out
 
 
@@ -151,8 +145,7 @@ def to_measured_table(trials, interpret: bool, platform: str = "",
             kernels["matmul"] = {
                 "variant": "matmul", "chunks": f["steps"],
                 "total_s": f["t"], "per_chunk_s": f["t"] / f["steps"]}
-        elif stage == "confirm" and f["default_knobs"] \
-                and not f["mac_bound"]:
+        elif stage == "confirm" and f["default_knobs"]:
             kernels[f"tuned/{label}"] = {
                 "variant": "flat" if f["flat"] else "twopass",
                 "steps_total": f["steps"], "total_s": f["t"],

@@ -23,15 +23,17 @@ the record refit.py solves the matmul per-chunk rate from.
 
 A PROBE stage rides along too (``REFIT_PROBES``): the halving keeps
 whatever geometries happen to win, and winners cluster — their step
-counts and DMA-unit counts are nearly collinear, so a rate solve over
-winners alone is ill-conditioned (the first selftest run recovered
-chunk_s at 0.3% of truth and slot_dma_s at 18x).  The probes are a
-designed experiment instead: pairs sharing (sb, rb, slot) — identical
-padded rows, so identical DMA units — at halved chunk widths isolate
-the per-step rate, and pairs sharing chunk widths at slot 16/64/128
-isolate the per-DMA rate; each probe is measured with many averaged
-draws (CI) or extra reps (device).  Refit solves from the probes when
-present and falls back to trial records otherwise.
+counts and DMA counts are nearly collinear, so a rate solve over winners
+alone is ill-conditioned (the first selftest run recovered chunk_s at
+0.3% of truth and slot_dma_s at 18x).  The probes are a designed
+experiment instead, one contrast per rate of binned's ``_cost_terms``:
+halved phase-1 chunks at equal phase 2 isolate the per-step rate, halved
+phase-2 chunks the per-row rate, slots 16/64/128 at equal chunks the
+per-slot-DMA rate, and flat probes at two chunk widths and two window
+sizes pull the descriptor walk (steps x KD) from the real copies (cells);
+each probe is measured with many averaged draws (CI) or extra reps
+(device).  Refit solves from the probes when present and falls back to
+trial records otherwise.
 
 The sweep never reads tuned.json (trial plans build with
 ``tuned_ok=False``) and never writes outside the store handed to
@@ -52,9 +54,13 @@ from roc_tpu.tune.lattice import KernelConfig, candidate_lattice
 
 # Synthetic sweep shapes, mirroring tools/kernel_bench.py: the CI shape
 # is the mega-shard scale where every variant's gates admit it; device
-# mode adds the dense/sparse scales the step-budget table pins.
-SHAPES_CI = [("mega_shard_scaled", 1024, 8192, 2)]
-SHAPES_DEVICE = SHAPES_CI + [
+# mode adds the dense/sparse scales the step-budget table pins.  The
+# second CI shape is there for refit: at one shape the flat probes issue
+# nearly the same number of copies (64-70), so the per-copy rate is not
+# identified; half the rows and edges halve the copies.
+SHAPES_CI = [("mega_shard_scaled", 1024, 8192, 2),
+             ("tiny", 512, 4096, 3)]
+SHAPES_DEVICE = SHAPES_CI[:1] + [
     ("reddit_scaled", 32768, 4_194_304, 0),
     ("products_scaled", 262_144, 2_097_152, 1),
 ]
@@ -70,16 +76,15 @@ class Shape(NamedTuple):
 
 class TrialRecord(NamedTuple):
     """One measured (or surrogate) trial, carrying the schedule FACTS
-    (step counts, padded rows, DMA regressor) refit.py needs to solve
-    rates without re-deriving plans."""
+    (binned._cost_terms: what the pass does, counted) refit.py needs to
+    solve rates without re-deriving plans."""
     shape: str
     variant: str
     label: str
     geom: tuple
     stage: str           # "trial" | "confirm" | "probe" | "matmul"
     steps: int           # s1 + s2 (matmul: chunk count)
-    dma_units: float     # surrogate.dma_units (matmul: 0)
-    mac_bound: bool      # a MAC-dominated phase pollutes the rate solve
+    terms: dict          # surrogate.cost_terms (matmul: {})
     default_knobs: bool  # knob priors applied? (refit calibrates w/o)
     modeled_s: float
     trial_s: float
@@ -96,17 +101,18 @@ def synth_shape(name: str, num_rows: int, num_edges: int,
     return Shape(name, num_rows, num_rows, src[order], dst[order])
 
 
-#: Refit's designed experiment (module docstring).  All chunk widths
-#: stay under the MAC-bound line at H=_MODEL_H (ch*sb*H*2/_MXU_EFF_FLOPS
-#: < _CHUNK_OVERHEAD_S) so every probe prices linearly in the rates.
+#: Refit's designed experiment (module docstring).
 REFIT_PROBES = (
-    B.Geometry(512, 1024, 16, 512, 1024),    # step/DMA baseline
-    B.Geometry(512, 2048, 16, 512, 2048),    # same DMA units, half steps
-    B.Geometry(512, 1024, 64, 512, 1024),    # same chunks, 1/4 DMA units
-    B.Geometry(512, 1024, 128, 512, 1024),   # same chunks, 1/8 DMA units
+    B.Geometry(512, 1024, 16, 512, 1024),    # baseline
+    B.Geometry(512, 2048, 16, 512, 1024),    # half the phase-1 steps
+    B.Geometry(512, 1024, 16, 512, 2048),    # other phase-2 chunk rounding
+    B.Geometry(512, 1024, 64, 512, 1024),    # same chunks, 1/4 slot DMAs
+    B.Geometry(512, 1024, 128, 512, 1024),   # same chunks, 1/8 slot DMAs
     B.Geometry(512, 2048, 128, 512, 2048),
-    B.Geometry(512, 1024, 16, 512, 1024, 0, 0, 1),   # flat staging-DMA
-    B.Geometry(512, 2048, 16, 512, 2048, 0, 0, 1),   # flat, half steps
+    B.Geometry(512, 1024, 16, 512, 1024, 0, 0, 1),     # flat, KD 128
+    B.Geometry(512, 2048, 16, 512, 2048, 0, 0, 1),     # flat, KD 256
+    B.Geometry(1024, 1024, 16, 1024, 1024, 0, 0, 1),   # flat, fewer cells
+    B.Geometry(1024, 2048, 16, 1024, 2048, 0, 0, 1),
 )
 
 
@@ -119,17 +125,6 @@ def refit_probes():
 def _default_knobs(cfg: KernelConfig) -> bool:
     return (tuple(cfg.dma_cls) == B._DMA_CLS and cfg.depth == 2
             and cfg.dimension_semantics == "arbitrary" and not cfg.mega)
-
-
-def _mac_bound(cfg: KernelConfig, sched, H: int = B._MODEL_H) -> bool:
-    """True when either phase's MAC term beats its overhead term — such a
-    trial's total no longer moves linearly with the per-step rate, so
-    refit excludes it."""
-    _, s1, s2 = sched
-    g = cfg.geom
-    mac1 = s1 * g.ch * g.sb * H * 2 / B._MXU_EFF_FLOPS
-    mac2 = s2 * g.ch2 * g.rb * H * 2 / B._MXU_EFF_FLOPS
-    return mac1 > s1 * B._CHUNK_OVERHEAD_S or mac2 > s2 * B._CHUNK_OVERHEAD_S
 
 
 def _trial_key(shape: Shape, variant: str, cfg_label: str,
@@ -220,16 +215,14 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
                                device, reps=1)
             # schedule FACTS ride the measurement record so refit can
             # re-solve rates straight from the JSONL stream
+            terms = S.cost_terms(cfg.geom, _stats(cfg.geom), sched)
             led.measure("tune_trial", key, t_trial, "s",
                         stage="trial", steps=sched[1] + sched[2],
-                        dma_units=S.dma_units(sched[0], cfg.geom),
                         flat=int(cfg.geom.flat),
-                        mac_bound=_mac_bound(cfg, sched),
-                        default_knobs=_default_knobs(cfg))
+                        default_knobs=_default_knobs(cfg), **terms)
             trials.append(TrialRecord(
                 shape.name, vkey, cfg.label, tuple(cfg.geom), "trial",
-                sched[1] + sched[2], S.dma_units(sched[0], cfg.geom),
-                _mac_bound(cfg, sched), _default_knobs(cfg),
+                sched[1] + sched[2], terms, _default_knobs(cfg),
                 t_model, t_trial))
             tried.append((t_trial, t_model, cfg, sched))
         tried.sort(key=lambda r: (r[0], r[2].label))
@@ -242,16 +235,14 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
             led.predict("tune_confirm", key, t_model, "s")
             t_conf = _measure(cfg, shape, t_model, "confirm", seed,
                               device, reps=5)
+            terms = S.cost_terms(cfg.geom, _stats(cfg.geom), sched)
             led.measure("tune_confirm", key, t_conf, "s",
                         stage="confirm", steps=sched[1] + sched[2],
-                        dma_units=S.dma_units(sched[0], cfg.geom),
                         flat=int(cfg.geom.flat),
-                        mac_bound=_mac_bound(cfg, sched),
-                        default_knobs=_default_knobs(cfg))
+                        default_knobs=_default_knobs(cfg), **terms)
             trials.append(TrialRecord(
                 shape.name, vkey, cfg.label, tuple(cfg.geom), "confirm",
-                sched[1] + sched[2], S.dma_units(sched[0], cfg.geom),
-                _mac_bound(cfg, sched), _default_knobs(cfg),
+                sched[1] + sched[2], terms, _default_knobs(cfg),
                 t_model, t_conf))
             confirmed.append((t_conf, t_model, cfg))
         confirmed.sort(key=lambda r: (r[0], r[2].label))
@@ -272,16 +263,14 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
                 led.predict("tune_probe", key, t_model, "s")
                 t_probe = _measure(cfg, shape, t_model, "probe", seed,
                                    device, reps=5)
+                terms = S.cost_terms(cfg.geom, _stats(cfg.geom), sched)
                 led.measure("tune_probe", key, t_probe, "s",
                             stage="probe", steps=sched[1] + sched[2],
-                            dma_units=S.dma_units(sched[0], cfg.geom),
                             flat=int(cfg.geom.flat),
-                            mac_bound=_mac_bound(cfg, sched),
-                            default_knobs=True)
+                            default_knobs=True, **terms)
                 trials.append(TrialRecord(
                     shape.name, vkey, cfg.label, tuple(cfg.geom), "probe",
-                    sched[1] + sched[2], S.dma_units(sched[0], cfg.geom),
-                    _mac_bound(cfg, sched), True, t_model, t_probe))
+                    sched[1] + sched[2], terms, True, t_model, t_probe))
 
         # matmul reference trial: sanity anchor + refit's mm-rate record
         mm_model = S.matmul_seconds(len(shape.edge_src), shape.num_rows)
@@ -294,12 +283,11 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
                     stage="matmul",
                     steps=B._matmul_chunks(len(shape.edge_src),
                                            shape.num_rows),
-                    dma_units=0.0, flat=0, mac_bound=False,
-                    default_knobs=True, matmul=True)
+                    flat=0, default_knobs=True, matmul=True)
         trials.append(TrialRecord(
             shape.name, vkey, "matmul", (), "matmul",
             B._matmul_chunks(len(shape.edge_src), shape.num_rows),
-            0.0, False, True, mm_model, mm_trial))
+            {}, True, mm_model, mm_trial))
 
         gkey = tstore.graph_key(shape.edge_src, shape.edge_dst,
                                 shape.num_rows, shape.table_rows)

@@ -3,16 +3,14 @@ surrogate, and the device timing path.
 
 Three layers, one formula:
 
-* ``analytic_seconds`` mirrors binned's ``_binned_cost_model`` exactly —
-  same terms, same exact ``_plan_steps`` schedule inputs — but takes the
-  rate constants as PARAMETERS instead of reading module globals +
-  ``measured_calibration()``.  The search screens and the surrogate both
-  price through this closed world, so a measured table committed on some
-  machine can never leak into the CI sweep's arithmetic (the
-  byte-identical-tuned.json pin depends on that), and refit.py can solve
-  the inverse problem against the same structure it was generated from.
-  ``test_tune.py::test_analytic_matches_binned_cost_model`` pins the
-  mirror against the production model so they cannot drift apart.
+* ``analytic_seconds`` IS binned's ``_binned_cost_model`` — the same
+  ``_cost_terms`` counts over the same exact ``_plan_steps`` schedule —
+  with the rates as a PARAMETER instead of the module's ``_COST_RATES``.
+  The search screens and the surrogate both price through this closed
+  world (the byte-identical-tuned.json pin depends on that), and
+  refit.py solves the inverse problem against the same counts it was
+  generated from.  ``test_tune.py::test_analytic_seconds_mirrors_cost_model``
+  pins the two together.
 
 * ``surrogate_seconds`` is the CI pseudo-measurement: the analytic time
   times ``(1 + eps)`` with eps drawn from sha256 over (seed, salt,
@@ -38,47 +36,38 @@ import hashlib
 import numpy as np
 
 from roc_tpu.ops.pallas import binned as B
-from roc_tpu.ops.pallas.binned import (Geometry, _CHUNK_OVERHEAD_S,
-                                       _MM_CHUNK_S, _MODEL_H,
-                                       _MXU_EFF_FLOPS, _SLOT_DMA_S,
-                                       staging_itemsize)
+from roc_tpu.ops.pallas.binned import (Geometry, _COST_RATES, _MM_CHUNK_S,
+                                       _MODEL_H)
 
-#: The generating constants, by refit-able name.  These are the exact
-#: values the CI surrogate manufactures its timings from, so the refit
-#: acceptance test closes the loop: sweep -> records -> refit -> these.
-CONSTANTS = {"chunk_s": _CHUNK_OVERHEAD_S, "slot_dma_s": _SLOT_DMA_S,
+#: The generating constants, by refit-able name: every rate of binned's
+#: cost model but the MXU's (the published peak, not a fit) plus the
+#: matmul backend's.  These are the exact values the CI surrogate
+#: manufactures its timings from, so the refit acceptance test closes
+#: the loop: sweep -> records -> refit -> these.
+CONSTANTS = {**{k: v for k, v in _COST_RATES.items() if k != "mxu"},
              "mm_chunk_s": _MM_CHUNK_S}
 
 #: Surrogate noise half-width (fractional).
 NOISE = 0.02
 
 
+def cost_terms(geom: Geometry, stats, sched, H: int = _MODEL_H) -> dict:
+    """binned._cost_terms for a candidate at its exact schedule ``sched``
+    = (padded, s1, s2), the flat copy count taken from the cell
+    statistics as choose_geometry takes it."""
+    padded, s1, s2 = sched
+    return B._cost_terms(padded, geom, H, s1, s2,
+                         copies=B._flat_copies(stats[2], geom))
+
+
 def analytic_seconds(padded_rows: int, geom: Geometry, steps1: int,
-                     steps2: int, H: int = _MODEL_H,
-                     chunk_s: float = _CHUNK_OVERHEAD_S,
-                     slot_dma_s: float = _SLOT_DMA_S) -> float:
+                     steps2: int, H: int = _MODEL_H, copies: int = None,
+                     rates: dict = None) -> float:
     """One aggregation pass at this geometry — ``_binned_cost_model``
-    with the rates as explicit parameters (see module docstring)."""
-    rows1 = steps1 * geom.ch
-    rows2 = steps2 * geom.ch2
-    mac1 = rows1 * geom.sb * H * 2 / _MXU_EFF_FLOPS
-    mac2 = rows2 * geom.rb * H * 2 / _MXU_EFF_FLOPS
-    ov1 = steps1 * chunk_s
-    ov2 = steps2 * chunk_s
-    dma1 = dma_units(padded_rows, geom) * slot_dma_s
-    return max(mac1, ov1) + dma1 + max(mac2, ov2)
-
-
-def dma_units(padded_rows: int, geom: Geometry) -> float:
-    """The staging-DMA regressor: how many slot-DMA-equivalents phase 1
-    issues.  Factored out of analytic_seconds because refit solves the
-    rate per THIS unit — non-flat schedules issue one DMA per slot, flat
-    schedules one size-classed copy per ~4 units scaled by the staging
-    itemsize (the flat staging-DMA term the ISSUE names)."""
-    if geom.flat:
-        return (padded_rows / (geom.unit_rows * 4)
-                * (staging_itemsize(geom, False) / 2))
-    return padded_rows / geom.slot
+    with the rates as an explicit parameter (see module docstring)."""
+    rates = {**_COST_RATES, **(rates or {})}
+    terms = B._cost_terms(padded_rows, geom, H, steps1, steps2, copies)
+    return sum(rates[k] * v for k, v in terms.items())
 
 
 def matmul_seconds(num_edges: int, num_rows: int,
@@ -97,10 +86,11 @@ def knob_factors(cfg) -> tuple:
 
       dma_cls (32, 8, 1): doubled size classes halve the descriptor
         count on dense runs but round thin runs up harder — net prior
-        -4% on the staging-DMA term.
+        -4% on the staging-DMA terms (slot, descriptor walk, copy).
       depth 3: a third pipeline buffer hides more of the DMA launch
-        window behind compute — prior -2% on per-step overhead, paid in
-        VMEM (lattice.py admissibility already charges the buffer).
+        window behind compute — prior -2% on the per-step and per-row
+        terms, paid in VMEM (lattice.py admissibility already charges
+        the buffer).
       dimension_semantics "parallel": neutral (1.0) — both phases carry
         cross-step staging dependences, so until a device run proves the
         revolving-window lowering legal AND faster it cannot win a tie.
@@ -122,9 +112,7 @@ def knob_factors(cfg) -> tuple:
 
 def modeled_seconds(cfg, stats, num_rows: int, table_rows: int,
                     num_edges: int, fuse_linear: bool = False,
-                    chunk_s: float = _CHUNK_OVERHEAD_S,
-                    slot_dma_s: float = _SLOT_DMA_S,
-                    sched=None) -> tuple:
+                    rates: dict = None, sched=None) -> tuple:
     """Candidate price at exact schedule counts: (seconds, sched) where
     sched = (padded, s1, s2) feeds the trial records refit solves from.
     Mirrors choose_geometry's pricing structure: a fused (mega) candidate
@@ -135,14 +123,15 @@ def modeled_seconds(cfg, stats, num_rows: int, table_rows: int,
     for this geometry (knob variants share schedules)."""
     cblk, cbin, cnt = stats
     g = cfg.geom
-    padded, s1, s2 = sched if sched is not None else B._plan_steps(
+    rates = {**_COST_RATES, **(rates or {})}
+    sched = sched if sched is not None else B._plan_steps(
         cblk, cbin, cnt, g, num_rows, table_rows, num_edges)
+    padded, s1, s2 = sched
     ovf, dmaf = knob_factors(cfg)
-    mac_ov1 = max(s1 * g.ch * g.sb * _MODEL_H * 2 / _MXU_EFF_FLOPS,
-                  s1 * chunk_s * ovf)
-    mac_ov2 = max(s2 * g.ch2 * g.rb * _MODEL_H * 2 / _MXU_EFF_FLOPS,
-                  s2 * chunk_s * ovf)
-    t = mac_ov1 + dma_units(padded, g) * slot_dma_s * dmaf + mac_ov2
+    factor = {"mxu": 1.0, "p1_step": ovf, "p2_row": ovf,
+              "slot_dma": dmaf, "flat_slot": dmaf, "flat_copy": dmaf}
+    t = sum(rates[k] * factor[k] * v
+            for k, v in cost_terms(g, stats, sched).items())
     if cfg.mega:
         fs = B._fused_sched_stats(cblk, cbin, cnt, g, num_rows,
                                   table_rows, num_edges)
@@ -158,7 +147,7 @@ def modeled_seconds(cfg, stats, num_rows: int, table_rows: int,
                     t * 0.5)
     elif fuse_linear:
         t += (2 * num_rows * _MODEL_H * 4 / B._HBM_BW
-              + -(-num_rows // 512) * chunk_s)
+              + -(-num_rows // 512) * rates["p1_step"])
     return t, (padded, s1, s2)
 
 

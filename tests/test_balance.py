@@ -329,8 +329,10 @@ def test_balancer_reshards_and_matches_unbalanced_loss():
 
 
 def test_measured_calibration_table_parsing(tmp_path, monkeypatch):
-    """binned.measured_calibration: device tables yield rates, interpret
-    tables and the kill switch yield None (analytic constants stay)."""
+    """binned.measured_calibration: device tables yield the matmul rate
+    (the binned kernels' per-step rows are not blended into one median:
+    their rates live in binned_chip_table.json), interpret tables and the
+    kill switch yield None (analytic constants stay)."""
     import roc_tpu.ops.pallas.binned as B
     tbl = {"measured": {"interpret": True, "platform": "cpu", "shapes": {
         "s": {"kernels": {
@@ -346,7 +348,7 @@ def test_measured_calibration_table_parsing(tmp_path, monkeypatch):
     tbl["measured"]["interpret"] = False
     p.write_text(json.dumps(tbl))
     B._MEASURED_CAL.clear()
-    assert B.measured_calibration() == {"chunk_s": 1e-5, "mm_chunk_s": 2e-6}
+    assert B.measured_calibration() == {"mm_chunk_s": 2e-6}
     monkeypatch.setenv("ROC_NO_MEASURED_CAL", "1")
     assert B.measured_calibration() is None
     monkeypatch.delenv("ROC_NO_MEASURED_CAL")
@@ -409,7 +411,7 @@ def test_measured_prior_reaches_r2_in_fewer_probes(monkeypatch):
                 return k
         return len(X_probe) + 1
 
-    k_measured = probes_to_r2({"chunk_s": 1e-5, "mm_chunk_s": rate_true})
+    k_measured = probes_to_r2({"mm_chunk_s": rate_true})
     k_default = probes_to_r2(None)
     assert k_measured < k_default, (k_measured, k_default)
     assert k_measured <= 3, k_measured

@@ -500,7 +500,21 @@ def test_choose_geometry_policy():
     src = rng.integers(0, n, e).astype(np.int64)
     dst = rng.integers(0, n, e).astype(np.int64)
     g, t = B.choose_geometry(src, dst, n, n)
-    assert g is not None and g.slot == 128, (g, t)
+    assert g is not None and g.slot == 128 and not g.flat, (g, t)
+
+    # Reddit-like occupancy itself: about 113 edges a 512 x 512 cell (the
+    # benchmark's graphs hold 207,610 cells for 23.4 M in-edges).  The
+    # flat descriptor walk is what the chip is slowest at there (PR 24:
+    # 406 ms a sweep against 64 for the two-pass phase 1), so the pick is
+    # the slot-128 two-pass schedule, and flat prices at over twice it.
+    n, e = 32768, 64 * 64 * 113
+    src = rng.integers(0, n, e).astype(np.int64)
+    dst = rng.integers(0, n, e).astype(np.int64)
+    g, t = B.choose_geometry(src, dst, n, n)
+    assert g is not None and g.slot == 128 and not g.flat, (g, t)
+    _, t_flat = B.choose_geometry(src, dst, n, n, candidates=[B.GEOM_FLAT],
+                                  force=True)
+    assert t_flat > 2 * t, (t_flat, t)
 
     # uniform products-density: ~13 edges per (512,512) cell.  The refit
     # model prices the matmul backend's per-VB-window >=1-chunk floor
@@ -673,6 +687,98 @@ def test_cost_model_grid_validation():
         else:
             mismatches.append((n, deg, order, pick, best_true))
     assert match >= 0.9 * len(cells), (match, len(cells), mismatches)
+
+
+def _chip_table():
+    import json
+    import os as _os
+    from roc_tpu.ops.pallas import binned as B
+    path = _os.path.join(_os.path.dirname(B.__file__),
+                         "binned_chip_table.json")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def test_cost_model_reproduces_chip_table():
+    """The re-fit model against the device times it was fit to (PR 24,
+    one v5e chip, ops/pallas/binned_chip_table.json): every row's phase 1
+    + phase 2 at width 256 within 15 %, each phase's own rate families
+    included, and within a cell the model orders the presets as the chip
+    does (pairs the chip holds under 2 % apart count as ties).  The table
+    says what it is, and its rates are the module's."""
+    from roc_tpu.ops.pallas import binned as B
+    doc = _chip_table()
+    for key in ("platform", "device_kind", "jax", "libtpu", "date", "pr"):
+        assert doc.get(key), key
+    assert doc["platform"] == "tpu" and doc["pr"] == 24
+    assert doc["rates"] == {
+        "mxu_flops": B._MXU_EFF_FLOPS, "p1_step_s": B._CHUNK_OVERHEAD_S,
+        "p2_row_s": B._P2_ROW_S, "slot_dma_s": B._SLOT_DMA_S,
+        "flat_slot_s": B._FLAT_SLOT_S, "flat_copy_s": B._FLAT_COPY_S}
+    by_cell = {}
+    for row in doc["rows"]:
+        geom = B.Geometry(*row["geom"]).check()
+        measured = (row["p1_ms"]["256"] + row["p2_ms"]["256"]) / 1e3
+        model = B._binned_cost_model(
+            row["padded_rows"], geom, H=256, steps1=row["steps1"],
+            steps2=row["steps2"], copies=row.get("copies"))
+        assert abs(model / measured - 1) <= 0.15, \
+            (row["cell"], row["preset"], model, measured)
+        if not geom.flat:
+            assert row["slot_dmas"] == row["padded_rows"] // geom.slot
+        by_cell.setdefault(row["cell"], []).append(
+            (measured, model, row["preset"]))
+    assert len(doc["rows"]) >= 10 and len(by_cell) == 2
+    for cell, rows in by_cell.items():
+        for m_a, p_a, n_a in rows:
+            for m_b, p_b, n_b in rows:
+                if m_a < 0.98 * m_b:
+                    assert p_a < p_b, (cell, n_a, n_b, (m_a, p_a),
+                                       (m_b, p_b))
+
+
+def test_choose_geometry_memory_admission(monkeypatch):
+    """A candidate whose per-group temporaries (staging + the larger
+    lane-padded index operand, from shapes) are over _HBM_GROUP_CAP is
+    skipped as a VMEM-inadmissible one is: at the chip table's own counts
+    GEOM_WIDE (12.69 GiB of peak HBM measured) is out and the default and
+    GEOM_FLAT are in; and on a graph where the wider group prices lowest,
+    lowering the cap between the two candidates' needs moves the pick."""
+    from roc_tpu.ops.pallas import binned as B
+    need = {}
+    for row in _chip_table()["rows"]:
+        if row["cell"].endswith(".regular"):
+            need[row["preset"]] = B._group_hbm_bytes(
+                B.Geometry(*row["geom"]), row["steps1"], row["steps2"],
+                row["groups"])
+    assert need["default"] < need["flat"] < B._HBM_GROUP_CAP < need["wide"]
+    assert 2.7e9 < need["default"] < 2.9e9 and 11e9 < need["wide"] < 11.5e9
+
+    rng = np.random.default_rng(9)
+    n, e = 16384, 32 * 32 * 113
+    src = rng.integers(0, n, e).astype(np.int64)
+    dst = rng.integers(0, n, e).astype(np.int64)
+    small = B._default_geom()._replace(grt=1 << 14)    # 8 groups
+    big = B._default_geom()._replace(grt=1 << 17)      # 1 group
+    bytes_of = {}
+    for g in (small, big):
+        cblk, cbin, cnt = B._cell_stats(src, dst, g.sb, g.rb)
+        _, s1, s2 = B._plan_steps(cblk, cbin, cnt, g, n, n, e)
+        bytes_of[g] = B._group_hbm_bytes(
+            g, s1, s2, B._plan_groups(g, n, n, e)[3])
+    assert bytes_of[small] < bytes_of[big]
+    pick, _ = B.choose_geometry(src, dst, n, n, candidates=[small, big],
+                                force=True)
+    assert pick == big                      # fewer groups, fewer steps
+    monkeypatch.setattr(B, "_HBM_GROUP_CAP",
+                        (bytes_of[small] + bytes_of[big]) // 2)
+    pick, t = B.choose_geometry(src, dst, n, n, candidates=[small, big],
+                                force=True)
+    assert pick == small, (pick, t)
+    # nothing admissible: no pick, as when every candidate is over VMEM
+    monkeypatch.setattr(B, "_HBM_GROUP_CAP", 1)
+    assert B.choose_geometry(src, dst, n, n, candidates=[small, big],
+                             force=True)[0] is None
 
 
 def test_hybrid_forced_correctness():

@@ -415,10 +415,14 @@ def test_watchdog_straggler_detection():
 
 def test_watchdog_budget_seed():
     """reddit_scaled is pinned in tools/kernel_budgets.json: the seed is
-    its committed steps_total x the binned per-grid-step overhead."""
-    from roc_tpu.ops.pallas.binned import _CHUNK_OVERHEAD_S
+    its committed schedule (padded rows, steps per phase) at the binned
+    cost model's own price."""
+    from roc_tpu.ops.pallas.binned import (_binned_cost_model,
+                                           _default_geom)
     seed = seed_for_graph(32768, 4194304)
-    assert seed == pytest.approx(3358 * _CHUNK_OVERHEAD_S)
+    assert seed == pytest.approx(_binned_cost_model(
+        4454144, _default_geom(), steps1=2240, steps2=1118))
+    assert 0.01 < seed < 0.05       # 19 ms at the rates of PR 24
     assert seed_for_graph(17, 17) is None  # unpinned shape -> warmup EWMA
 
 

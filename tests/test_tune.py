@@ -90,14 +90,23 @@ def test_lattice_deterministic_sorted_admissible():
     assert not any(c.geom.unit == 16 for c in a)
 
 
-def test_refit_probes_admissible_and_not_mac_bound():
+def test_refit_probes_admissible_and_identify_every_rate(swept):
+    """The designed experiment must exercise every rate refit solves,
+    with its counts pulled apart enough for least squares: the probes'
+    column-scaled count matrix at the CI shape has full rank and a
+    modest condition number."""
     probes = search.refit_probes()
     assert len(probes) >= 5
-    for cfg in probes:
-        # linear pricing is the whole point of the designed experiment
-        assert cfg.geom.ch * cfg.geom.sb * B._MODEL_H * 2 \
-            / B._MXU_EFF_FLOPS < B._CHUNK_OVERHEAD_S
-    assert any(cfg.geom.flat for cfg in probes)      # flat_dma_s column
+    assert any(cfg.geom.flat for cfg in probes)      # the flat columns
+    _, _, trials = swept
+    rows = [tr.terms for tr in trials if tr.stage == "probe"]
+    assert len(rows) == len(probes) * len(_SHAPE_SPECS)
+    A = np.asarray([[r[k] for k in refit.RATE_NAMES] for r in rows],
+                   dtype=np.float64)
+    assert (A.max(axis=0) > 0).all(), refit.RATE_NAMES
+    A = A / A.max(axis=0)
+    assert np.linalg.matrix_rank(A) == len(refit.RATE_NAMES)
+    assert np.linalg.cond(A) < 200, np.linalg.cond(A)
 
 
 # ------------------------------------------------------------------ store
@@ -359,16 +368,15 @@ def test_refit_from_ledger_dicts(swept):
                  "probe": "tune_probe",
                  "matmul": "tune_trial"}[tr.stage]
         dicts.append({"model": model, "value": tr.trial_s,
-                      "steps": tr.steps, "dma_units": tr.dma_units,
+                      "steps": tr.steps, **tr.terms,
                       "flat": int(tr.geom[7]) if len(tr.geom) > 7 else 0,
-                      "mac_bound": tr.mac_bound,
                       "default_knobs": tr.default_knobs,
                       "matmul": tr.stage == "matmul",
                       "stage": tr.stage, "variant": tr.variant,
                       "shape": tr.shape})
     a = refit.refit_rates(trials)
     b = refit.refit_rates(dicts)
-    for k in ("chunk_s", "slot_dma_s", "flat_dma_s", "mm_chunk_s"):
+    for k in (*refit.RATE_NAMES, "mm_chunk_s"):
         if a[k] is None:
             assert b[k] is None
         else:
@@ -420,10 +428,13 @@ def test_analytic_seconds_mirrors_cost_model(monkeypatch):
     for geom in (B.GEOM_MID, B.GEOM_SPARSE, B.GEOM_FLAT,
                  B.GEOM_FLAT_SPARSE, B.GEOM_WIDE):
         for padded, s1, s2 in ((1 << 16, 40, 20), (1 << 20, 700, 350)):
-            np.testing.assert_allclose(
-                S.analytic_seconds(padded, geom, s1, s2),
-                B._binned_cost_model(padded, geom, steps1=s1, steps2=s2),
-                rtol=1e-12, err_msg=str(tuple(geom)))
+            for copies in (None, padded // 24):
+                np.testing.assert_allclose(
+                    S.analytic_seconds(padded, geom, s1, s2,
+                                       copies=copies),
+                    B._binned_cost_model(padded, geom, steps1=s1,
+                                         steps2=s2, copies=copies),
+                    rtol=1e-12, err_msg=str(tuple(geom)))
 
 
 def test_noise_is_deterministic_and_bounded():

@@ -150,8 +150,8 @@ note "    ... then the geometry AUTOTUNER (roc_tpu/tune): successive-"
 note "    halving sweep of the kernel-config lattice at the device shapes,"
 note "    winners persisted content-keyed into tuned.json beside the plan"
 note "    cache (choose_geometry consults them before its analytic model"
-note "    on the very next run), the refit stage re-solving chunk_s /"
-note "    slot_dma_s / flat-DMA / mm_chunk_s from the trial records into"
+note "    on the very next run), the refit stage re-solving the binned"
+note "    cost terms' rates and mm_chunk_s from the trial records into"
 note "    the kernel_budgets measured table, and the calibration report"
 note "    grading every trial's predict/measure pair.  One command:"
 timeout 3600 python -m roc_tpu.tune --device --shapes device \

@@ -32,9 +32,12 @@ hardware table never trips the schedule-drift gate.  Each benched plan
 is also written to the content-keyed plan cache (the bench forces
 ROC_PLAN_CACHE_MIN_EDGES=0 for its own builds), so a trainer hitting the
 same graph content warm-starts its plan build from disk; the measured
-per-grid-step and per-chunk rates are what binned.measured_calibration
-feeds back into choose_geometry's cost model and the balance prior
-(cost_model.fit seeds them at MEASURED_PRIOR_WEIGHT).
+matmul per-chunk rate is what binned.measured_calibration feeds back
+into _matmul_cost and the balance prior (cost_model.fit seeds it at
+MEASURED_PRIOR_WEIGHT).  The binned rows' per-grid-step times are kept
+in the table for reading and are not blended into the cost model: its
+per-kernel-family rates were fit to ops/pallas/binned_chip_table.json
+(PR 24).
 
 The bench attaches the calibration ledger around each choose_geometry
 call and measures the winner's wall time under the same plan content
