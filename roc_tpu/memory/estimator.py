@@ -175,7 +175,7 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
             else:
                 out_bytes = rows * out_dim * itemsize
             t = _op_forward_s(op, in_dim, out_dim, rows, edges)
-            full += out_bytes
+            full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize)
             fwd += t
             if op.kind in SAVED_KINDS or op.attrs.get("ckpt_boundary"):
                 saved += out_bytes
@@ -199,6 +199,22 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
     # aggregate is one transposed aggregation + accumulation)
     return ModelEstimate(layers=tuple(layers), fixed_bytes=int(fixed_bytes),
                          base_step_s=3.0 * total_fwd, rows=rows, edges=edges)
+
+
+def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
+    """Per-EDGE bytes a gat op keeps from forward to backward on the plan
+    attention path (ops.edge._gat_plan_fwd): the shifted exponentials
+    ``e [K, E]`` at the activation width and the score's sign ``qpos
+    [K, E]`` bool.  Both carry edges on the lane axis, so these are the
+    bytes the device holds (the old [E, K] layout held 16 x as much at
+    K = 8: 128 lanes a row); the attention-dropout mask is redrawn, not
+    kept.  They live inside the custom VJP: an all-KEEP step holds them
+    (``bytes_full``), a planned layer recomputes them with the layer
+    (``bytes_saved`` counts tagged outputs only).  0 for any other op; the
+    dense xla path (small graphs) lets autodiff keep more than this."""
+    if op.kind != "gat":
+        return 0
+    return int(op.attrs["heads"]) * int(edges) * (itemsize + 1)
 
 
 def mega_bwd_cotangent_drop(model, rows: int, itemsize: int = 4) -> int:
@@ -228,8 +244,8 @@ def gat_residual_drop(model, rows: int, edges: int,
     from roc_tpu.models.model import gat_matches
     total = 0
     for rec in gat_matches(model).values():
-        k = rec["heads"]
-        total += edges * k * (itemsize + 1) - 2 * rows * k * 4
+        total += (gat_edge_residual_bytes(rec["gat"], edges, itemsize)
+                  - 2 * rows * rec["heads"] * 4)
     return max(total, 0)
 
 
@@ -266,6 +282,14 @@ def estimate_for_trainer(trainer) -> ModelEstimate:
     itemsize = int(np.dtype(trainer.dtype).itemsize)
     fixed = fixed_bytes_for(trainer.model, rows, ds.features.shape[1],
                             ds.num_classes, edges, itemsize)
+    # the attention plans (six [C, EB] int32 arrays of ~1.2 E slots) are
+    # step arguments like the edge arrays: one device's share of them
+    gat_plans = getattr(getattr(trainer, "gdata", None), "gat_plans", None)
+    if gat_plans is not None:
+        import jax
+        devices = max(int(trainer.config.num_parts) // max(k, 1), 1)
+        fixed += sum(int(a.size) * a.dtype.itemsize
+                     for a in jax.tree.leaves(gat_plans)) // devices
     # halo frontier (round 16): rows other shards reference still
     # round-trip HBM at fused region boundaries — the received halo
     # block is [P*K] rows per device in halo-exchange mode; 0 on a

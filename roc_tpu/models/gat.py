@@ -9,11 +9,17 @@ model exercises the TPU realization of that latent capability
 softmax, attention-weighted aggregation — all sharded over the same vertex
 partition, with the halo/all_gather exchange reused for the source table.
 
-Recipe per hidden layer (paper §2.2):
-    t = dropout(t)
-    t = gat(t, head_dim, heads)   # multi-head, concatenated
+Recipe per hidden layer (paper sections 2.2 and 3.3):
+    t = dropout(t, p)
+    t = gat(t, head_dim, heads, attn_drop=p)   # multi-head, concatenated;
+                                  # the normalised attention coefficients
+                                  # are dropped per edge and head at the
+                                  # same rate p, not renormalised
     t = elu(t)                    # not on the output layer
-Output layer: single head sized to num_classes, then softmax CE.
+Output layer: single head sized to num_classes, then softmax CE.  The paper
+has one p (0.6) for both dropouts, and so has ``build_gat``: its one
+``dropout_rate`` is the rate of the inputs and of the coefficients.
+Evaluation drops nothing.  No bias, as in the paper's equations.
 """
 
 from __future__ import annotations
@@ -33,7 +39,8 @@ def build_gat(layers: Sequence[int], dropout_rate: float = 0.5,
     for i in range(1, len(layers)):
         last = i == len(layers) - 1
         t = model.dropout(t, dropout_rate)
-        t = model.gat(t, layers[i], heads=1 if last else heads, slope=slope)
+        t = model.gat(t, layers[i], heads=1 if last else heads, slope=slope,
+                      attn_drop=dropout_rate)
         if not last:
             t = model.elu(t)
         model.end_layer()

@@ -44,9 +44,12 @@ class GraphCtx(NamedTuple):
     """Everything an op needs to know about the (shard of the) graph."""
     aggregate: Callable[[jnp.ndarray, str], jnp.ndarray]  # x, aggr_type -> out
     in_degree: jnp.ndarray  # [N_local] float32, >= 1
-    # attention aggregation: (h [N,K,F], a_src [K,F], a_dst [K,F], slope)
-    # -> [N, K, F]; built by the same driver/spmd code that builds
-    # ``aggregate`` (it owns the halo/all_gather exchange).
+    # attention aggregation: (h [N,K,F], a_src [K,F], a_dst [K,F], slope,
+    # drop) -> [N, K, F]; built by the same driver/spmd code that builds
+    # ``aggregate`` (it owns the halo/all_gather exchange).  ``drop`` is
+    # None (evaluation, or no attention dropout) or (key, rate): the
+    # normalised coefficients are dropped per edge and head
+    # (ops.edge.attention_keep).
     attend: Optional[Callable] = None
     # whole-layer megakernel hook:
     # (x, w, activation, aggr, fold) -> out or None.
@@ -189,6 +192,19 @@ def mega_matches(model: "Model") -> Dict[int, dict]:
                     "gone": (op.out, agg.out) + ((n2.out,)
                                                  if final is not n2 else ())}
     return found
+
+
+def attention_drop(op: "OpNode", key, train: bool):
+    """The ``drop`` argument of ``GraphCtx.attend`` for one gat op in one
+    step: (the step's key folded with the op's dropout slot, the rate) in
+    training when the op drops coefficients, else None.  The one place the
+    key is derived, so a test can ask ops.edge.attention_keep for the very
+    mask a step used."""
+    rate = op.attrs.get("attn_drop", 0.0)
+    if not (train and rate):
+        return None
+    assert key is not None, "training attention dropout needs a PRNG key"
+    return jax.random.fold_in(key, op.attrs["slot"]), rate
 
 
 def gat_matches(model: "Model") -> Dict[int, dict]:
@@ -406,15 +422,22 @@ class Model:
         return out
 
     def gat(self, t: TensorRef, head_dim: int, heads: int = 1,
-            slope: float = 0.2) -> TensorRef:
+            slope: float = 0.2, attn_drop: float = 0.0) -> TensorRef:
         """Multi-head graph-attention layer (W-projection + attention
         aggregation, heads concatenated).  Exercises the edge-tensor path
-        the reference left latent (create_edge_tensor, gnn.cc:534-589)."""
+        the reference left latent (create_edge_tensor, gnn.cc:534-589).
+        ``attn_drop``: dropout rate on the normalised attention
+        coefficients in training (Velickovic et al. section 3.3); it takes
+        a dropout slot of its own, so its mask is independent of every
+        input dropout's."""
         out = self._new(head_dim * heads)
-        self._emit(OpNode("gat", (t.id,), out.id,
-                          {"in_dim": t.dim, "head_dim": head_dim,
-                           "heads": heads, "slope": slope,
-                           "param": f"gat_{self.num_linear}"}))
+        attrs = {"in_dim": t.dim, "head_dim": head_dim,
+                 "heads": heads, "slope": slope, "attn_drop": attn_drop,
+                 "param": f"gat_{self.num_linear}"}
+        if attn_drop:
+            attrs["slot"] = self.num_dropout
+            self.num_dropout += 1
+        self._emit(OpNode("gat", (t.id,), out.id, attrs))
         self.num_linear += 1
         return out
 
@@ -470,6 +493,29 @@ class Model:
                         ka, kk * fd, 1).reshape(kk, fd)
                 i += 1
         return params
+
+    def keep_masks(self, key, num_nodes: int, num_edges: int) -> dict:
+        """The keep masks a training step with ``key`` draws on one device,
+        by op index: [N, d] bool for a dropout op, [K, E] bool (in-edges in
+        CSR order) for a gat op that drops coefficients.  Drawn through the
+        very functions the step calls (ops.dropout_keep,
+        ops.edge.attention_keep) from the same folded keys: a test compares
+        training-mode arithmetic with a reference that is GIVEN the masks."""
+        from roc_tpu.memory.estimator import _op_out_dims
+        from roc_tpu.ops.dropout import dropout_keep
+        from roc_tpu.ops.edge import attention_keep
+        dims, masks = _op_out_dims(self), {}
+        for idx, op in enumerate(self.ops):
+            if op.kind == "gat":
+                drop = attention_drop(op, key, True)
+                if drop is not None:
+                    masks[idx] = attention_keep(
+                        drop[0], drop[1], op.attrs["heads"], num_edges)
+            elif op.kind == "dropout" and op.attrs["rate"]:
+                masks[idx] = dropout_keep(
+                    jax.random.fold_in(key, op.attrs["slot"]),
+                    op.attrs["rate"], (num_nodes, dims[op.inputs[0]]))
+        return masks
 
     # -- execution --------------------------------------------------------
     def apply(self, params: Dict[str, Any], x: jnp.ndarray, gctx: GraphCtx,
@@ -542,8 +588,9 @@ class Model:
                 kk, fd = op.attrs["heads"], op.attrs["head_dim"]
                 h = ops.linear(a, params[name + "_w"]).reshape(-1, kk, fd)
                 out = gctx.attend(h, params[name + "_asrc"],
-                                  params[name + "_adst"],
-                                  op.attrs["slope"]).reshape(-1, kk * fd)
+                                  params[name + "_adst"], op.attrs["slope"],
+                                  attention_drop(op, key, train)
+                                  ).reshape(-1, kk * fd)
             elif op.kind == "activation":
                 out = ops.apply_activation(a, op.attrs["mode"])
             elif op.kind == "add":

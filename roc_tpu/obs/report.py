@@ -84,6 +84,13 @@ def summarize_metrics(records: List[dict]) -> List[str]:
                     "mfu", "roofline_frac"):
             if key in last:
                 lines.append(f"#   final {key} = {last[key]:.6g}")
+    for r in records:
+        if r.get("type") == "attention":
+            lines.append(
+                f"# attention: backend={r.get('backend', '?')} "
+                f"gat_fused={r.get('fused', '?')} gat_plan_pad_ratio="
+                f"{r.get('gat_plan_pad_ratio', 0):.4f} gat_score_bytes="
+                f"{r.get('gat_score_bytes', 0)}")
     for r in trains:
         lines.append(f"#   verdict: {r.get('watchdog_verdict', '?')} "
                      f"({r.get('epochs', '?')} epochs, "

@@ -231,10 +231,17 @@ def pad_plans(plans: "list[AggregatePlans]", min_fwd: int = 0,
 _MM_CB = 512   # chunks per scan step
 
 
-def _one_hot_dots(g, ed, ob, cb, precision):
-    """S1/S2 one-hot matmuls for one scan step (see module comment)."""
+def _one_hot_dots(g, ed, ob, cb, precision, combine_precision=None):
+    """S1/S2 one-hot matmuls for one scan step (see module comment).
+    ``combine_precision`` (default: ``precision``) feeds the S2 dot, which
+    adds the chunks' float32 partial sums of a window: at the MXU's default
+    precision it rounds each PARTIAL SUM to bf16, a second rounding on top
+    of the features' (the matmul backend's 0.9e-3 to 1.8e-3 on the chip);
+    the attention path passes "highest" there (PERF.md PR 25)."""
     from roc_tpu.ops.pallas.segment_sum import EB, VB
     H = g.shape[-1]
+    if combine_precision is None:
+        combine_precision = precision
     s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
           == ed[:, None, :]).astype(g.dtype)
     psum = jax.lax.dot_general(
@@ -245,7 +252,7 @@ def _one_hot_dots(g, ed, ob, cb, precision):
           == lw[None, :]).astype(g.dtype)
     outs = jax.lax.dot_general(
         s2, psum.reshape(cb, VB * H), (((1,), (0,)), ((), ())),
-        precision=precision, preferred_element_type=jnp.float32)
+        precision=combine_precision, preferred_element_type=jnp.float32)
     return outs.reshape(cb * VB, H)   # fp32: accumulated across steps
 
 

@@ -14,9 +14,13 @@ import jax
 import jax.numpy as jnp
 
 
+def dropout_keep(key, rate: float, shape):
+    """The keep mask ``dropout`` draws from ``key``: Bernoulli(1 - rate)."""
+    return jax.random.bernoulli(key, 1.0 - rate, shape=shape)
+
+
 def dropout(key, x, rate: float, train: bool):
     """Inverted dropout; identity when not training or rate == 0."""
     if not train or rate == 0.0:
         return x
-    keep = jax.random.bernoulli(key, 1.0 - rate, shape=x.shape)
-    return jnp.where(keep, x / (1.0 - rate), 0.0)
+    return jnp.where(dropout_keep(key, rate, x.shape), x / (1.0 - rate), 0.0)

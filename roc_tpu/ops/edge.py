@@ -69,8 +69,38 @@ from roc_tpu.ops.aggregate import (          # noqa: E402
 _GAT_CHUNK_MIN = 1024     # floor on edge-chunk length (tests shrink it)
 
 
+def attention_keep(key, rate: float, heads: int, num_edges: int):
+    """The attention-dropout keep mask, ``[K, E]`` bool (True = kept), of
+    one gat op for one step: Bernoulli(1 - rate) per edge and head
+    (Velickovic et al. section 3.3: dropout on the normalised attention
+    coefficients).  EVERY attention path draws its mask through this one
+    function from the same key, so the dense, chunked and plan paths drop
+    the same coefficients, and the plan path's hand-derived backward
+    regenerates the mask here instead of saving ``[K, E]`` again."""
+    return jax.random.bernoulli(key, 1.0 - rate, shape=(heads, num_edges))
+
+
+def _drop_args(drop):
+    """(key, rate) of a ``drop`` argument, (None, 0.0) when it drops
+    nothing (None: evaluation; no key; rate 0): what the custom VJPs take,
+    the rate static."""
+    key, rate = drop if drop is not None else (None, 0.0)
+    return (None, 0.0) if key is None or not rate else (key, float(rate))
+
+
+def _keep_scale(drop, heads: int, num_edges: int, dtype):
+    """``keep / (1 - p)`` as a ``[K, E]`` multiplier, or None when the call
+    drops nothing."""
+    key, rate = _drop_args(drop)
+    if key is None:
+        return None
+    keep = attention_keep(key, rate, heads, num_edges)
+    return jnp.where(keep, jnp.asarray(1.0 / (1.0 - rate), dtype),
+                     jnp.asarray(0.0, dtype))
+
+
 def gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
-               a_src, a_dst, slope: float):
+               a_src, a_dst, slope: float, drop=None):
     """Multi-head graph attention aggregation (GAT).
 
     h:       [N_local, K, F] W-projected features of the *destination* rows.
@@ -79,18 +109,26 @@ def gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
     a_src/a_dst: [K, F] attention vectors (the two halves of the GAT `a`).
     Per edge: s_e = LeakyReLU(a_dst.h[dst_e] + a_src.table[src_e]);
     alpha = edge_softmax(s); out[v] = sum_e alpha_e * table[src_e].
+    ``drop`` = (key, rate) in training: the normalised coefficients are
+    dropped per edge and head (:func:`attention_keep`), scaled by
+    1 / (1 - rate), and NOT renormalised; None in evaluation.
     Returns [N_local, K, F].
     """
     E, (K, F) = edge_src.shape[0], h.shape[1:]
     if E * K * F > _GAT_CHUNK_THRESHOLD_ELEMS:
         return _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes,
-                                   a_src, a_dst, slope)
-    as_t = jnp.einsum("tkf,kf->tk", table, a_src)     # [T, K]
-    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst)         # [N_local, K]
+                                   a_src, a_dst, slope, drop)
+    as_t = jnp.einsum("tkf,kf->tk", table, a_src,
+                      precision="highest")            # [T, K]
+    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst,
+                      precision="highest")            # [N_local, K]
     s = jax.nn.leaky_relu(
         jnp.take(ad_l, edge_dst, axis=0) + jnp.take(as_t, edge_src, axis=0),
         negative_slope=slope)                          # [E, K]
     alpha = edge_softmax(s, edge_dst, num_nodes)       # [E, K]
+    w = _keep_scale(drop, K, E, alpha.dtype)
+    if w is not None:
+        alpha = alpha * w.T
     g = jnp.take(table, edge_src, axis=0)              # [E, K, F]
     return jax.ops.segment_sum(g * alpha[:, :, None], edge_dst,
                                num_segments=num_nodes,
@@ -98,7 +136,7 @@ def gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 
 
 def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
-                        a_src, a_dst, slope: float):
+                        a_src, a_dst, slope: float, drop=None):
     """Memory-bounded GAT: never materializes [E, K, F].
 
     Standard streaming softmax shape: (1) one edge-chunk scan accumulates
@@ -108,7 +146,8 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
     dense path (softmax shift by the exact per-dst max), different sum
     order — equal up to float reassociation.  Working set per step:
     [chunk, K, F] plus the [N, K(, F)] accumulators.  Pad edges (routed to
-    pad dst rows) only pollute pad rows.
+    pad dst rows) only pollute pad rows.  Attention dropout (``drop``)
+    scales the output's terms and never the normalizer's.
 
     The bound must survive autodiff, where lax.scan stacks per-step
     residuals back up to O(E*K*F): the accumulate body is rematerialized
@@ -117,8 +156,10 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
     (stop_gradient on m: softmax is shift-invariant, d out/d m == 0).
     """
     E, (K, F) = edge_src.shape[0], h.shape[1:]
-    as_t = jnp.einsum("tkf,kf->tk", table, a_src)     # [T, K]
-    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst)         # [N_local, K]
+    as_t = jnp.einsum("tkf,kf->tk", table, a_src,
+                      precision="highest")            # [T, K]
+    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst,
+                      precision="highest")            # [N_local, K]
 
     chunk = max(_GAT_CHUNK_TARGET_ELEMS // max(K * F, 1), _GAT_CHUNK_MIN)
     nchunks = -(-E // chunk)
@@ -127,6 +168,10 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
     src = jnp.pad(edge_src, (0, pad)).reshape(nchunks, chunk)
     dst = jnp.pad(edge_dst, (0, pad),
                   constant_values=num_nodes).reshape(nchunks, chunk)
+    w = _keep_scale(drop, K, E, as_t.dtype)
+    # the [E, K] view of the mask rides the scan as one more per-chunk input
+    wc = None if w is None else jnp.pad(w.T, ((0, pad), (0, 0))).reshape(
+        nchunks, chunk, K)
 
     def scores(s_ids, d_ids):
         return jax.nn.leaky_relu(
@@ -152,20 +197,22 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 
     def acc_body(carry, sl):
         z, out = carry
-        s_ids, d_ids = sl
+        s_ids, d_ids = sl[:2]
         e = jnp.exp(scores(s_ids, d_ids)
                     - jnp.take(m, d_ids, axis=0))     # [chunk, K]
         z = z.at[d_ids].add(e, indices_are_sorted=True,
                             mode="promise_in_bounds")
         g = jnp.take(table, s_ids, axis=0)            # [chunk, K, F]
-        out = out.at[d_ids].add(g * e[:, :, None], indices_are_sorted=True,
+        ew = e if wc is None else e * sl[2]
+        out = out.at[d_ids].add(g * ew[:, :, None], indices_are_sorted=True,
                                 mode="promise_in_bounds")
         return (z, out), None
     z0 = _vary_like(jnp.zeros((num_nodes + 1, K), as_t.dtype), as_t)
     o0 = _vary_like(jnp.zeros((num_nodes + 1, K, F), h.dtype), h)
     (z, out), _ = jax.lax.scan(  # scan-body remat, not an activation plan:
         # residuals here would be O(E) per chunk  # roclint: allow(remat) — scan-body remat; residuals would be O(E) per chunk
-        jax.checkpoint(acc_body, prevent_cse=False), (z0, o0), (src, dst))
+        jax.checkpoint(acc_body, prevent_cse=False), (z0, o0),
+        (src, dst) if wc is None else (src, dst, wc))
     # _Z_GUARD (rationale at its definition above): edgeless rows would
     # otherwise hit 0/0 in fwd or 0 * inf in the division transpose (live
     # rows have z >= 1 by the max shift)
@@ -194,12 +241,22 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # Segment-max (the softmax shift) is the same one-hot window machinery with
 # masked max in place of the MXU dot.
 #
+# LAYOUT: every per-edge array of this path (q, s, e, qpos, the dropout
+# multiplier, de, dq) is [K, E] — heads on the sublane axis, EDGES ON THE
+# LANE AXIS.  The TPU tiles the two minor dimensions to (8, 128): an [E, 8]
+# float32 array is stored at 128 lanes a row, 16 x its size (12 GB apiece at
+# the Reddit shape, K = 8), an [8, E] one at its size (752 MB).  Node-sized
+# score tables are [K, N] for the same reason; only the [*, K*F] feature
+# rows keep nodes/slots on the sublane axis.  tests/test_gat_layout.py pins
+# it in the jaxpr.
+#
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
 # backward is built from these primitives plus plain gathers — autodiff of
 # the forward would otherwise transpose every gather into a scatter.
 
 _PLAN_CB_SUM = 512   # chunks per scan step, one-hot dot passes
-_PLAN_CB_MAX = 128   # smaller: the masked-max intermediate is [cb, cb, VB, K]
+_PLAN_CB_MAX = 128   # smaller: the masked-max intermediate is [K, cb, cb, VB]
+_LANE_GATHER_CHUNK = 1 << 20   # indices a step of a long [K, M] lane gather
 
 
 class GatPlans(NamedTuple):
@@ -208,7 +265,9 @@ class GatPlans(NamedTuple):
 
     dst_*: chunks over the dst-sorted edge list, windows = destination rows
            (num_rows).  ``pos`` indexes [E,...] edge arrays (dst order);
-           ``nid`` is the edge's SOURCE row in the feature table.
+           ``nid`` is the edge's SOURCE row in the feature table.  Built
+           ALIGNED (_aligned_position_plan): slot j of a chunk is position
+           ``EB * block + j``, so the device reads whole blocks.
     src_*: chunks over the src-sorted edge list, windows = table rows
            (table_rows).  ``pos`` again indexes dst-ordered edge arrays
            (the src-sort permutation is folded in); ``nid`` is the edge's
@@ -252,6 +311,52 @@ def _position_plan(keys_sorted, pos, nids_by_pos, num_rows):
         nid.astype(np.int32)
 
 
+def _aligned_position_plan(keys_sorted, nids, num_rows):
+    """The dst-keyed position plan, cut so that every chunk lies inside ONE
+    aligned block of EB consecutive positions: the dst-sorted edge list is
+    cut at every multiple of EB and at every window boundary, and a chunk's
+    slot j IS position ``EB * block + j`` (slots outside the piece are
+    masked, ``edst == VB``).  The device then reads a chunk's values as one
+    aligned [K, EB] block of the per-edge array, a row gather of whole
+    (8, 128) tiles, where a chunk at an arbitrary offset costs a column
+    gather of EB lanes (``_slot_reader``; PERF.md PR 25: 526 ms a pass over
+    the Reddit plan).  About E / EB + windows chunks against the packed
+    plan's E / EB x 1.14.  Same invariants as build_chunk_plan: chunks in
+    window order, every window at least one (an empty window gets one
+    all-masked chunk)."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    keys = np.asarray(keys_sorted, np.int64)
+    nids = np.asarray(nids, np.int64)
+    E = keys.shape[0]
+    assert E == 0 or np.all(np.diff(keys) >= 0), "keys not sorted"
+    num_windows = max((num_rows + VB - 1) // VB, 1)
+    win, blk = keys // VB, np.arange(E, dtype=np.int64) // EB
+    cut = np.ones(E, bool)
+    cut[1:] = (win[1:] != win[:-1]) | (blk[1:] != blk[:-1])
+    lo = np.flatnonzero(cut)                 # first position of each piece
+    hi = np.append(lo[1:], E)
+    has = np.zeros(num_windows, bool)
+    has[win[lo]] = True
+    empty = np.flatnonzero(~has)
+    nothing = np.zeros_like(empty)
+    c_win = np.concatenate([win[lo], empty])
+    order = np.argsort(c_win, kind="stable")  # pieces of a window: in order
+    c_win = c_win[order]
+    c_blk = np.concatenate([blk[lo], nothing])[order]
+    c_lo = np.concatenate([lo, nothing])[order]
+    c_hi = np.concatenate([hi, nothing])[order]
+    pos = c_blk[:, None] * EB + np.arange(EB, dtype=np.int64)[None, :]
+    valid = (pos >= c_lo[:, None]) & (pos < c_hi[:, None])
+    safe = np.minimum(pos, max(E - 1, 0))
+    if E == 0:
+        edst, nid = np.full_like(pos, VB), np.zeros_like(pos)
+    else:
+        edst = np.where(valid, keys[safe] - c_win[:, None] * VB, VB)
+        nid = np.where(valid, nids[safe], 0)
+    return (c_win.astype(np.int32), edst.astype(np.int32),
+            pos.astype(np.int32), nid.astype(np.int32))
+
+
 def build_gat_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
                     num_rows: int, table_rows: int) -> GatPlans:
     """Host-side schedule build.  ``edge_dst`` must be sorted ascending
@@ -259,9 +364,7 @@ def build_gat_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
     ids under a halo exchange)."""
     edge_src = np.asarray(edge_src, np.int64)
     edge_dst = np.asarray(edge_dst, np.int64)
-    E = edge_src.shape[0]
-    pos = np.arange(E, dtype=np.int64)
-    d = _position_plan(edge_dst, pos, edge_src, num_rows)
+    d = _aligned_position_plan(edge_dst, edge_src, num_rows)
     order = np.argsort(edge_src, kind="stable")
     s = _position_plan(edge_src[order], order, edge_dst, table_rows)
     return GatPlans(*(jnp.asarray(a) for a in d + s),
@@ -314,187 +417,322 @@ def _pad_steps(obi, edst, pos, nid, cb):
     return obi, edst, pos, nid, (C + pad) // cb
 
 
-def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision):
+def _take_lanes(x, idx):
+    """``x[:, idx]`` for a ``[K, M]`` array: the one gather of the [K, E]
+    layout (every per-edge array of the plan path keeps edges on the lane
+    axis; module comment above).
+
+    The TPU compiler gathers rows: it reads ``x`` as [M, K] rows and writes
+    an [n, K] row-major result, K padded to 128 lanes, before transposing
+    it back (AOT-compiled step, PERF.md PR 25: one 11.2 GB temporary per
+    edge-sized gather at the Reddit shape).  So a long gather walks the
+    index list in chunks and lands each chunk's [K, chunk] in place: the
+    padded temporary is bounded by the chunk (512 MB), the result is not
+    padded at all."""
+    n, c = idx.shape[0], _LANE_GATHER_CHUNK
+    if n <= c:
+        return jnp.take(x, idx, axis=1, mode="clip")
+    from roc_tpu.ops.aggregate import _vary_like
+    nchunks = -(-n // c)
+    ids = jnp.pad(idx, (0, nchunks * c - n)).reshape(nchunks, c)
+
+    def body(out, sl):
+        i, chunk_ids = sl
+        g = jnp.take(x, chunk_ids, axis=1, mode="clip")       # [K, c]
+        return jax.lax.dynamic_update_slice(out, g, (0, i * c)), None
+
+    out = _vary_like(jnp.zeros((x.shape[0], nchunks * c), x.dtype), x)
+    out, _ = jax.lax.scan(body, out, (jnp.arange(nchunks), ids))
+    return out[:, :n]
+
+
+def _head_expand(heads: int, head_dim: int, dtype):
+    """``[K, K*F]`` 0/1 matrix with X[k, k*F + f] = 1: ``w.T @ X`` repeats a
+    per-head value over the head's F lanes and ``X @ p.T`` sums a head's F
+    lanes, on the MXU, without a ``[n, K]`` array (K on the lane axis) ever
+    existing.  At "highest" both are exact in float32: one factor is 0/1."""
+    return jnp.repeat(jnp.eye(heads, dtype=dtype), head_dim, axis=1)
+
+
+def _plan_scan_shapes(obi, num_rows: int, cb_max: int):
+    from roc_tpu.ops.pallas.segment_sum import VB
+    cb = min(cb_max, max(8, obi.shape[0]))
+    num_windows = (num_rows + VB - 1) // VB
+    return cb, num_windows - 1 + cb      # acc windows: DUS never clamps
+
+
+def _slot_reader(edge_w, cb: int, aligned: bool):
+    """``read(po)``: the per-slot values ``edge_w[:, po]`` of one scan step
+    as ``[cb, K, EB]``, for ``po`` [cb, EB] slot positions.
+
+    ``aligned`` (every dst-keyed plan, _aligned_position_plan): a chunk's
+    slots are one aligned block of EB positions, so the per-edge array is
+    re-laid once a pass as [blocks, K, EB] and a step gathers cb whole
+    blocks, (8, 128) tiles, instead of cb x EB columns (a column gather
+    costs 15 to 20 ns an index on a v5e, 526 ms a pass over the Reddit
+    plan; PERF.md PR 25).  Masked slots read their block's other values,
+    always finite and always dropped (edst == VB matches no row).
+    Otherwise (the src-keyed plans, positions in src order) it is the
+    column gather; one head reads a flat 1-D copy, made once outside the
+    scan (194 ms against 318 + a per-step squeeze XLA does not hoist)."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+    K, E = edge_w.shape
+    if aligned:
+        nb = -(-E // EB)
+        blocks = jnp.pad(edge_w, ((0, 0), (0, nb * EB - E))).reshape(
+            K, nb, EB).transpose(1, 0, 2)                 # [blocks, K, EB]
+        return lambda po: jnp.take(blocks, po[:, 0] // EB, axis=0,
+                                   mode="clip")
+    if K == 1:
+        flat = edge_w.reshape(-1)
+        return lambda po: jnp.take(flat, po.reshape(cb * EB),
+                                   mode="clip").reshape(cb, 1, EB)
+    return lambda po: _take_lanes(edge_w, po.reshape(cb * EB)).reshape(
+        K, cb, EB).transpose(1, 0, 2)
+
+
+def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
+              aligned: bool = False):
     """Segment-sum over plan windows of per-slot values
-    ``edge_w[pos] (⊗) node_x[nid]`` — the one-hot MXU machinery of
+    ``edge_w[:, pos] (⊗) node_x[nid]`` — the one-hot MXU machinery of
     ops.aggregate._matmul_run generalized to edge-position plans.
 
-      edge_w: [E, K] or None;  node_x: [R2, K, F] or None (not both None).
-    Returns [num_rows, K] (node_x None) or [num_rows, K, F].
+      edge_w: [K, E] or None;  node_x: [R2, K, F] or None (not both None).
+      ``aligned``: the plan is dst-keyed (:func:`_slot_reader`).
+    Returns [K, num_rows] (node_x None: always summed at "highest") or
+    [num_rows, K, F] (``precision`` feeds the one-hot dots).
     """
-    from roc_tpu.ops.aggregate import _one_hot_dots
+    from roc_tpu.ops.aggregate import _one_hot_dots, _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
-    C = obi.shape[0]
-    cb = min(_PLAN_CB_SUM, max(8, C))
+    cb, acc_windows = _plan_scan_shapes(obi, num_rows, _PLAN_CB_SUM)
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
-    K = edge_w.shape[1] if edge_w is not None else node_x.shape[1]
-    F = node_x.shape[2] if node_x is not None else None
-    H = K if F is None else K * F
-    num_windows = (num_rows + VB - 1) // VB
-    acc_rows = (num_windows - 1 + cb) * VB
+    K = edge_w.shape[0] if edge_w is not None else node_x.shape[1]
+    ref = edge_w if edge_w is not None else node_x
+    read = None if edge_w is None else _slot_reader(edge_w, cb, aligned)
+    xs = (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+          pos.reshape(nsteps, cb, EB), nid.reshape(nsteps, cb, EB))
+
+    if node_x is None:
+        # [K, E] in, [K, rows] out: the S1 dot contracts the slot axis of
+        # [cb, K, EB] directly, so K never reaches the lane axis
+        def body_k(acc, sl):
+            ob, ed, po, _ = sl
+            g = read(po)                                      # [cb, K, EB]
+            s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
+                  == ed[:, None, :]).astype(g.dtype)
+            psum = jax.lax.dot_general(          # [cb, K, VB]
+                g, s1, (((2,), (2,)), ((0,), (0,))), precision="highest",
+                preferred_element_type=jnp.float32)
+            s2 = (jax.lax.broadcasted_iota(jnp.int32, (cb, cb), 0)
+                  == (ob - ob[0])[None, :]).astype(g.dtype)
+            outs = jax.lax.dot_general(
+                s2, psum.reshape(cb, K * VB), (((1,), (0,)), ((), ())),
+                precision="highest", preferred_element_type=jnp.float32)
+            cur = jax.lax.dynamic_slice(acc, (ob[0], 0), (cb, K * VB))
+            return jax.lax.dynamic_update_slice(acc, cur + outs,
+                                                (ob[0], 0)), None
+
+        acc = _vary_like(jnp.zeros((acc_windows, K * VB), jnp.float32), ref)
+        acc, _ = jax.lax.scan(body_k, acc, xs)
+        out = acc.reshape(acc_windows, K, VB).transpose(1, 0, 2)
+        return out.reshape(K, acc_windows * VB)[:, :num_rows].astype(
+            ref.dtype)
+
+    F = node_x.shape[2]
+    H = K * F
+    flat = node_x.reshape(node_x.shape[0], H)
+    expand = _head_expand(K, F, jnp.float32) if edge_w is not None else None
 
     def body(acc, sl):
         ob, ed, po, ni = sl
-        if node_x is not None:
-            g = jnp.take(node_x.reshape(node_x.shape[0], K * F),
-                         ni.reshape(cb * EB), axis=0, mode="clip")
-            if edge_w is not None:
-                w = jnp.take(edge_w, po.reshape(cb * EB), axis=0,
-                             mode="clip")
-                g = (g.reshape(-1, K, F) * w[:, :, None]).reshape(-1, H)
-        else:
-            g = jnp.take(edge_w, po.reshape(cb * EB), axis=0, mode="clip")
-        outs = _one_hot_dots(g, ed, ob, cb, precision)
+        g = jnp.take(flat, ni.reshape(cb * EB), axis=0, mode="clip")
+        if edge_w is not None:
+            g = g * jax.lax.dot_general(          # [cb, EB, K*F]
+                read(po), expand, (((1,), (0,)), ((), ())),
+                precision="highest", preferred_element_type=jnp.float32
+            ).astype(g.dtype).reshape(cb * EB, H)
+        # one rounding only under `fast`: the products e * h, once, at the
+        # S1 dot; the S2 dot adds float32 partial sums and stays exact
+        # (0.3 % of the pass's MXU work at six passes)
+        outs = _one_hot_dots(g, ed, ob, cb, precision, "highest")
         base = ob[0] * VB
         cur = jax.lax.dynamic_slice(acc, (base, 0), (cb * VB, H))
         return jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0)), None
 
-    from roc_tpu.ops.aggregate import _vary_like
-    ref = edge_w if edge_w is not None else node_x
-    acc = _vary_like(jnp.zeros((acc_rows, H), jnp.float32), ref)
-    acc, _ = jax.lax.scan(
-        body, acc, (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
-                    pos.reshape(nsteps, cb, EB), nid.reshape(nsteps, cb, EB)))
-    out = acc[:num_rows].astype(ref.dtype)
-    return out if F is None else out.reshape(num_rows, K, F)
+    acc = _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), ref)
+    acc, _ = jax.lax.scan(body, acc, xs)
+    return acc[:num_rows].astype(ref.dtype).reshape(num_rows, K, F)
 
 
 def _plan_max(edge_w, obi, edst, pos, num_rows: int):
-    """Segment-max over plan windows of ``edge_w[pos]`` ([E, K] ->
-    [num_rows, K]).  Same window schedule as _plan_sum with masked maxima in
-    place of the one-hot dots; rows with no live slots return -inf."""
+    """Segment-max over the windows of a dst-keyed plan of ``edge_w[:, pos]``
+    ([K, E] -> [K, num_rows]).  Same window schedule as _plan_sum with
+    masked maxima in place of the one-hot dots; rows with no live slots
+    return -inf."""
+    from roc_tpu.ops.aggregate import _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
-    C = obi.shape[0]
-    cb = min(_PLAN_CB_MAX, max(8, C))
+    cb, acc_windows = _plan_scan_shapes(obi, num_rows, _PLAN_CB_MAX)
     obi, edst, pos, _, nsteps = _pad_steps(obi, edst, pos, pos, cb)
-    K = edge_w.shape[1]
-    num_windows = (num_rows + VB - 1) // VB
-    acc_rows = (num_windows - 1 + cb) * VB
+    K = edge_w.shape[0]
     neg = jnp.asarray(-jnp.inf, edge_w.dtype)
+    read = _slot_reader(edge_w, cb, True)
 
     def body(acc, sl):
         ob, ed, po = sl
-        s = jnp.take(edge_w, po.reshape(cb * EB), axis=0,
-                     mode="clip").reshape(cb, EB, K)
+        s = read(po)                                      # [cb, K, EB]
         in_row = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
                   == ed[:, None, :])
-        within = jnp.max(jnp.where(in_row[..., None], s[:, None], neg),
-                         axis=2)                          # [cb, VB, K]
+        within = jnp.max(jnp.where(in_row[:, None], s[:, :, None, :], neg),
+                         axis=3)                          # [cb, K, VB]
         lw = ob - ob[0]
         same_w = (jax.lax.broadcasted_iota(jnp.int32, (cb, cb), 0)
                   == lw[None, :])                         # [w, chunk]
         outs = jnp.max(jnp.where(same_w[:, :, None, None], within[None],
-                                 neg), axis=1)            # [cb, VB, K]
-        # acc is WINDOW-indexed ([W, VB, K]) — base is the window id itself,
-        # unlike the row-indexed accumulator of _plan_sum (ob[0] * VB)
-        cur = jax.lax.dynamic_slice(acc, (ob[0], 0, 0), (cb, VB, K))
+                                 neg), axis=1)            # [cb, K, VB]
+        # acc is WINDOW-indexed ([W, K, VB]) — base is the window id itself,
+        # unlike the row-indexed feature accumulator of _plan_sum
+        cur = jax.lax.dynamic_slice(acc, (ob[0], 0, 0), (cb, K, VB))
         return jax.lax.dynamic_update_slice(
             acc, jnp.maximum(cur, outs), (ob[0], 0, 0)), None
 
-    from roc_tpu.ops.aggregate import _vary_like
-    acc = _vary_like(jnp.full((acc_rows // VB, VB, K), neg), edge_w)
+    acc = _vary_like(jnp.full((acc_windows, K, VB), neg), edge_w)
     acc, _ = jax.lax.scan(
         body, acc, (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
                     pos.reshape(nsteps, cb, EB)))
-    return acc.reshape(acc_rows, K)[:num_rows]
+    return acc.transpose(1, 0, 2).reshape(K, acc_windows * VB)[:, :num_rows]
 
 
-def _edge_contract(du, table, edge_src, edge_dst, dz):
-    """de[e, k] = Σ_f du[dst_e, k, f]·table[src_e, k, f] + dz[dst_e, k],
-    streamed over edge chunks so the [E, K, F] product never materializes."""
+def _edge_contract(du, table, edge_src, edge_dst):
+    """c[k, e] = Σ_f du[dst_e, k, f]·table[src_e, k, f] as ``[K, E]``,
+    streamed over edge chunks so the [E, K, F] product never materializes;
+    each chunk's head sums land in place in the [K, E] result."""
+    from roc_tpu.ops.aggregate import _vary_like
     E, (K, F) = edge_src.shape[0], table.shape[1:]
     chunk = max(_GAT_CHUNK_TARGET_ELEMS // max(K * F, 1), _GAT_CHUNK_MIN)
+    chunk = -(-min(chunk, E) // 128) * 128        # whole lane tiles
     nchunks = -(-E // chunk)
     pad = nchunks * chunk - E
     src = jnp.pad(edge_src, (0, pad)).reshape(nchunks, chunk)
     dst = jnp.pad(edge_dst, (0, pad)).reshape(nchunks, chunk)
+    duf = du.reshape(du.shape[0], K * F)
+    tf = table.reshape(table.shape[0], K * F)
+    collapse = _head_expand(K, F, jnp.float32)
 
-    def body(_, sl):
-        s_ids, d_ids = sl
-        duc = jnp.take(du, d_ids, axis=0)         # [chunk, K, F]
-        tc = jnp.take(table, s_ids, axis=0)
-        return None, (jnp.einsum("ckf,ckf->ck", duc, tc)
-                      + jnp.take(dz, d_ids, axis=0))
-    _, de = jax.lax.scan(body, None, (src, dst))
-    return de.reshape(nchunks * chunk, K)[:E]
+    def body(out, sl):
+        i, s_ids, d_ids = sl
+        prod = (jnp.take(duf, d_ids, axis=0, mode="clip")
+                * jnp.take(tf, s_ids, axis=0, mode="clip"))   # [chunk, K*F]
+        c = jax.lax.dot_general(                              # [K, chunk]
+            collapse, prod, (((1,), (1,)), ((), ())), precision="highest",
+            preferred_element_type=jnp.float32).astype(out.dtype)
+        return jax.lax.dynamic_update_slice(out, c, (0, i * chunk)), None
+
+    out = _vary_like(jnp.zeros((K, nchunks * chunk), du.dtype), du)
+    out, _ = jax.lax.scan(body, out, (jnp.arange(nchunks), src, dst))
+    return out[:, :E]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
-                    slope: float, precision: str = "highest"):
+                    slope: float, precision: str = "highest", drop=None):
     """GAT attention over chunk plans — scatter-free fwd AND bwd.
 
     Same semantics as :func:`gat_attend` (equal up to float reassociation:
-    different summation order).  ``edge_ids`` = (edge_src, edge_dst) [E]
-    arrays in dst-sorted order (table-local src ids under halo).  The
+    different summation order), ``drop`` = (key, rate) included: the same
+    key drops the same coefficients.  ``edge_ids`` = (edge_src, edge_dst)
+    [E] arrays in dst-sorted order (table-local src ids under halo).  The
     backward is hand-derived so no gather is ever transposed into a TPU
     scatter; all reductions ride the dst-/src-keyed plans.
 
     ``precision`` feeds ONLY the two [*, K, F] weighted feature sums (u
     fwd, dtable bwd) — the FLOP carriers; "default" is the fast policy's
-    single-pass bf16 (one feature rounding).  The [E, K] score/normalizer
+    single-pass bf16 (one feature rounding).  The [K, E] score/normalizer
     sums stay at "highest" always: their FLOPs are negligible and the
     softmax normalization stays exact in both modes.
     """
-    out, _ = _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, slope,
-                           precision)
-    return out
+    key, rate = _drop_args(drop)
+    return _gat_plan(h, table, a_src, a_dst, plans, edge_ids, key, slope,
+                     precision, rate)
 
 
-def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, slope,
-                  precision="highest"):
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _gat_plan(h, table, a_src, a_dst, plans, edge_ids, key, slope,
+              precision, rate):
+    return _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key,
+                         slope, precision, rate)[0]
+
+
+def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
+                  precision="highest", rate=0.0):
     edge_src, edge_dst = edge_ids
     N = plans.num_rows
-    K, F = h.shape[1], h.shape[2]
-    as_t = jnp.einsum("tkf,kf->tk", table, a_src)         # [T, K]
-    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst)             # [N, K]
-    q = (jnp.take(ad_l, edge_dst, axis=0)
-         + jnp.take(as_t, edge_src, axis=0))              # [E, K]
+    K, E = h.shape[1], edge_src.shape[0]
+    # the score products are tiny and always float32-exact: at the MXU's
+    # default precision h and a would be rounded to bf16 inside the exp
+    as_t = jnp.einsum("tkf,kf->kt", table, a_src,
+                      precision="highest")                # [K, T]
+    ad_l = jnp.einsum("nkf,kf->kn", h, a_dst,
+                      precision="highest")                # [K, N]
+    q = _take_lanes(ad_l, edge_dst) + _take_lanes(as_t, edge_src)   # [K, E]
     s = jax.nn.leaky_relu(q, negative_slope=slope)
     m = _plan_max(s, plans.dst_obi, plans.dst_edst, plans.dst_pos, N)
     m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-    e = jnp.exp(s - jnp.take(m, edge_dst, axis=0))        # [E, K]
+    e = jnp.exp(s - _take_lanes(m, edge_dst))             # [K, E]
     z = _plan_sum(e, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
-                  plans.dst_nid, N, "highest")            # [N, K]
-    u = _plan_sum(e, table, plans.dst_obi, plans.dst_edst, plans.dst_pos,
-                  plans.dst_nid, N, precision)            # [N, K, F]
+                  plans.dst_nid, N, "highest", True)      # [K, N]
+    # attention dropout: the weighted sum sees the dropped coefficients,
+    # the normaliser never does (alpha~ = alpha * keep / (1 - p))
+    w = _keep_scale((key, rate), K, E, e.dtype)
+    u = _plan_sum(e if w is None else e * w, table, plans.dst_obi,
+                  plans.dst_edst, plans.dst_pos, plans.dst_nid, N,
+                  precision, True)                        # [N, K, F]
     # Guard is _Z_GUARD (rationale at its definition): XLA flushes
     # subnormals to zero,
     # and rows with no in-edges (padded shard rows) have z == 0 → 0/0 NaN.
     # Any live row has z >= 1 (the max edge contributes exp(0)).
     zc = jnp.maximum(z, _Z_GUARD)
-    out = u / zc[:, :, None]
-    return out, (h, table, a_src, a_dst, plans, edge_ids,
+    out = u / zc.T[:, :, None]
+    # the mask is NOT a residual: the backward redraws it from the key
+    return out, (h, table, a_src, a_dst, plans, edge_ids, key,
                  q >= 0, e, zc, out)
 
 
-def _gat_plan_bwd(slope, precision, res, gout):
-    h, table, a_src, a_dst, plans, edge_ids, qpos, e, zc, out = res
+def _int_zeros(tree):
+    """Cotangents of non-differentiable (integer / key) arguments."""
+    return jax.tree.map(
+        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0)
+        if not jnp.issubdtype(a.dtype, jnp.floating) else jnp.zeros_like(a),
+        tree)
+
+
+def _gat_plan_bwd(slope, precision, rate, res, gout):
+    h, table, a_src, a_dst, plans, edge_ids, key, qpos, e, zc, out = res
     edge_src, edge_dst = edge_ids
     N, T = plans.num_rows, plans.table_rows
-    K, F = h.shape[1], h.shape[2]
-    du = gout / zc[:, :, None]                            # [N, K, F]
-    dz = -jnp.einsum("nkf,nkf->nk", gout, out) / zc       # [N, K]
-    de = _edge_contract(du, table, edge_src, edge_dst, dz)
-    dq = e * de * jnp.where(qpos, 1.0, slope)             # [E, K]
+    K, E = h.shape[1], edge_src.shape[0]
+    du = gout / zc.T[:, :, None]                          # [N, K, F]
+    dz = -jnp.einsum("nkf,nkf->kn", gout, out,
+                     precision="highest") / zc            # [K, N]
+    w = _keep_scale((key, rate), K, E, e.dtype)           # the fwd's mask
+    de = _edge_contract(du, table, edge_src, edge_dst)    # [K, E]
+    if w is not None:
+        de = de * w
+    de = de + _take_lanes(dz, edge_dst)
+    dq = e * de * jnp.where(qpos, 1.0, slope)             # [K, E]
     dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
-                     plans.dst_nid, N, "highest")         # [N, K]
+                     plans.dst_nid, N, "highest", True)   # [K, N]
     dast = _plan_sum(dq, None, plans.src_obi, plans.src_edst, plans.src_pos,
-                     plans.src_nid, T, "highest")         # [T, K]
-    dtable = _plan_sum(e, du, plans.src_obi, plans.src_edst, plans.src_pos,
-                       plans.src_nid, T, precision)       # [T, K, F]
-    dtable = dtable + dast[:, :, None] * a_src[None]
-    dh = dadl[:, :, None] * a_dst[None]
-    da_src = jnp.einsum("tk,tkf->kf", dast, table)
-    da_dst = jnp.einsum("nk,nkf->kf", dadl, h)
-    zeros = jax.tree.map(
-        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0)
-        if jnp.issubdtype(a.dtype, jnp.integer) else jnp.zeros_like(a),
-        (plans, edge_ids))
-    return (dh, dtable, da_src, da_dst) + zeros
+                     plans.src_nid, T, "highest")         # [K, T]
+    dtable = _plan_sum(e if w is None else e * w, du, plans.src_obi,
+                       plans.src_edst, plans.src_pos, plans.src_nid, T,
+                       precision)                         # [T, K, F]
+    dtable = dtable + dast.T[:, :, None] * a_src[None]
+    dh = dadl.T[:, :, None] * a_dst[None]
+    da_src = jnp.einsum("kt,tkf->kf", dast, table, precision="highest")
+    da_dst = jnp.einsum("kn,nkf->kf", dadl, h, precision="highest")
+    return (dh, dtable, da_src, da_dst) + _int_zeros((plans, edge_ids, key))
 
 
-gat_attend_plan.defvjp(_gat_plan_fwd, _gat_plan_bwd)
+_gat_plan.defvjp(_gat_plan_fwd, _gat_plan_bwd)
 
 
 # --------------------------------------------------------------------------
@@ -546,12 +784,12 @@ def _gat_binned_fwd(h, table, a_src, a_dst, plans, bplans, edge_ids,
     ng, _ = _gat_fuse_state(bplans, K, F)
     if not ng:
         out, res = _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids,
-                                 slope, precision)
+                                 None, slope, precision)
         return out, (res, None, bplans)
     bprec = "exact" if precision == "highest" else "fast"
     # the oracle's own einsum builds the dst score contribution — shared
     # verbatim so the fused and decline paths agree on it bitwise
-    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst)
+    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst, precision="highest")
     kg = K // ng
     outs, ms, zs = [], [], []
     for gi in range(ng):
@@ -572,14 +810,11 @@ def _gat_binned_bwd(slope, precision, interpret, res, gout):
     res_plan, res_fused, bplans = res
 
     def _aux_zeros():
-        return jax.tree.map(
-            lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0)
-            if jnp.issubdtype(a.dtype, jnp.integer) else jnp.zeros_like(a),
-            bplans)
+        return _int_zeros(bplans)
 
     if res_fused is None:
-        dh, dtable, da_src, da_dst, dplans, dedge = _gat_plan_bwd(
-            slope, precision, res_plan, gout)
+        dh, dtable, da_src, da_dst, dplans, dedge, _ = _gat_plan_bwd(
+            slope, precision, 0.0, res_plan, gout)
         return (dh, dtable, da_src, da_dst, dplans, _aux_zeros(), dedge)
 
     (h, table, a_src, a_dst, plans, edge_ids, ad_l, m_cat, z_cat,
@@ -613,34 +848,33 @@ def _gat_binned_bwd(slope, precision, interpret, res, gout):
         # plane (max is order-independent => the recomputed q/e are the
         # oracle's own) and replay _gat_plan_bwd's plan reductions
         m_nodes = jnp.concatenate(
-            [m_cat[gi, :N, :kg] for gi in range(ng)], axis=1)
+            [m_cat[gi, :N, :kg] for gi in range(ng)], axis=1).T   # [K, N]
         z_nodes = jnp.concatenate(
-            [z_cat[gi, :N, :kg] for gi in range(ng)], axis=1)
+            [z_cat[gi, :N, :kg] for gi in range(ng)], axis=1).T
         zc = jnp.maximum(z_nodes, _Z_GUARD)
-        as_t = jnp.einsum("tkf,kf->tk", table, a_src)
-        q = (jnp.take(ad_l, edge_dst, axis=0)
-             + jnp.take(as_t, edge_src, axis=0))
+        as_t = jnp.einsum("tkf,kf->kt", table, a_src, precision="highest")
+        q = _take_lanes(ad_l.T, edge_dst) + _take_lanes(as_t, edge_src)
         e = jnp.exp(jax.nn.leaky_relu(q, negative_slope=slope)
-                    - jnp.take(m_nodes, edge_dst, axis=0))
-        du = gout / zc[:, :, None]
-        dz = -jnp.einsum("nkf,nkf->nk", gout, out) / zc
-        de = _edge_contract(du, table, edge_src, edge_dst, dz)
+                    - _take_lanes(m_nodes, edge_dst))             # [K, E]
+        du = gout / zc.T[:, :, None]
+        dz = -jnp.einsum("nkf,nkf->kn", gout, out,
+                         precision="highest") / zc
+        de = (_edge_contract(du, table, edge_src, edge_dst)
+              + _take_lanes(dz, edge_dst))
         dq = e * de * jnp.where(q >= 0, 1.0, slope)
         dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst,
-                         plans.dst_pos, plans.dst_nid, N, "highest")
+                         plans.dst_pos, plans.dst_nid, N, "highest",
+                         True).T
         dast = _plan_sum(dq, None, plans.src_obi, plans.src_edst,
-                         plans.src_pos, plans.src_nid, T, "highest")
+                         plans.src_pos, plans.src_nid, T, "highest").T
         dtable_agg = _plan_sum(e, du, plans.src_obi, plans.src_edst,
                                plans.src_pos, plans.src_nid, T, precision)
 
     dtable = dtable_agg + dast[:, :, None] * a_src[None]
     dh = dadl[:, :, None] * a_dst[None]
-    da_src = jnp.einsum("tk,tkf->kf", dast, table)
-    da_dst = jnp.einsum("nk,nkf->kf", dadl, h)
-    zeros = jax.tree.map(
-        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0)
-        if jnp.issubdtype(a.dtype, jnp.integer) else jnp.zeros_like(a),
-        (plans, edge_ids))
+    da_src = jnp.einsum("tk,tkf->kf", dast, table, precision="highest")
+    da_dst = jnp.einsum("nk,nkf->kf", dadl, h, precision="highest")
+    zeros = _int_zeros((plans, edge_ids))
     return (dh, dtable, da_src, da_dst, zeros[0], _aux_zeros(), zeros[1])
 
 

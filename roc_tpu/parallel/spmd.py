@@ -405,7 +405,8 @@ def build_edge_gat_plans_arrays(meta, es, ed,
     """EdgeGatPlans from prebuilt (or per-host byte-range-loaded) block
     arrays; ``allgather`` raises window spans and chunk counts to the
     global maxima (the -perhost static-shape contract)."""
-    from roc_tpu.ops.edge import GatPlans, _position_plan, pad_gat_plans
+    from roc_tpu.ops.edge import (GatPlans, _aligned_position_plan,
+                                  _position_plan, pad_gat_plans)
     NS = meta.num_parts * meta.shard_nodes
     es = np.asarray(es, np.int64)
     ed = np.asarray(ed, np.int64)
@@ -416,9 +417,8 @@ def build_edge_gat_plans_arrays(meta, es, ed,
     es_sorted = np.take_along_axis(es, orders, axis=1)
     sbase, span_s = _block_window(es_sorted, NS, allgather)
     plans = []
-    pos = np.arange(Eb, dtype=np.int64)
     for p in range(L_):
-        d = _position_plan(ed[p] - dbase[p], pos, es[p], span_d)
+        d = _aligned_position_plan(ed[p] - dbase[p], es[p], span_d)
         s = _position_plan(es_sorted[p] - sbase[p], orders[p], ed[p],
                            span_s)
         plans.append(GatPlans(*(jnp.asarray(a) for a in d + s),
@@ -441,9 +441,8 @@ def _scatter_to_owner(part_loc, base, NS: int):
                                 tiled=True)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def edge_gat_attend(h, a_src, a_dst, egp: EdgeGatPlans, edge_ids,
-                    slope: float, precision: str = "highest"):
+                    slope: float, precision: str = "highest", drop=None):
     """GAT attention under edge sharding, scatter-free fwd and bwd (inside
     shard_map; egp fields are this shard's block).
 
@@ -451,15 +450,27 @@ def edge_gat_attend(h, a_src, a_dst, egp: EdgeGatPlans, edge_ids,
     reassociation): block-local plan reductions over exactly Eb edges,
     one `pmax` for the global softmax shift, `psum_scatter` onto owners —
     but every segment reduction rides the one-hot window machinery of
-    ops.edge (_plan_max/_plan_sum), and the backward is hand-derived so no
-    gather transposes into a TPU scatter (the reference's transposed-role
-    relaunch, scattergather_kernel.cu:160-170, at block granularity)."""
-    out, _ = _egat_fwd(h, a_src, a_dst, egp, edge_ids, slope, precision)
-    return out
+    ops.edge (_plan_max/_plan_sum, per-edge arrays [K, Eb]), and the
+    backward is hand-derived so no gather transposes into a TPU scatter
+    (the reference's transposed-role relaunch,
+    scattergather_kernel.cu:160-170, at block granularity).  ``drop`` =
+    (key, rate): attention dropout as in ops.edge.gat_attend_plan, the
+    mask redrawn from the key in the backward."""
+    from roc_tpu.ops.edge import _drop_args
+    key, rate = _drop_args(drop)
+    return _egat(h, a_src, a_dst, egp, edge_ids, key, slope, precision,
+                 rate)
 
 
-def _egat_fwd(h, a_src, a_dst, egp, edge_ids, slope, precision):
-    from roc_tpu.ops.edge import _plan_max, _plan_sum
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _egat(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
+    return _egat_fwd(h, a_src, a_dst, egp, edge_ids, key, slope, precision,
+                     rate)[0]
+
+
+def _egat_fwd(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
+    from roc_tpu.ops.edge import (_keep_scale, _plan_max, _plan_sum,
+                                  _take_lanes)
     es, ed = edge_ids
     S, K, F = h.shape
     pl = egp.plans
@@ -467,29 +478,31 @@ def _egat_fwd(h, a_src, a_dst, egp, edge_ids, slope, precision):
     table = jax.lax.all_gather(h.reshape(S, K * F), PARTS_AXIS, tiled=True)
     NS = table.shape[0]
     table = table.reshape(NS, K, F)
-    # project locally, gather the small [NS, K] score vectors (projecting
+    # project locally, gather the small [K, NS] score vectors (projecting
     # the gathered table would repeat every shard's flops on every device)
-    as_t = jax.lax.all_gather(jnp.einsum("skf,kf->sk", h, a_src),
-                              PARTS_AXIS, tiled=True)
-    ad_t = jax.lax.all_gather(jnp.einsum("skf,kf->sk", h, a_dst),
-                              PARTS_AXIS, tiled=True)
-    q = jnp.take(ad_t, ed, axis=0) + jnp.take(as_t, es, axis=0)  # [Eb, K]
+    as_t = jax.lax.all_gather(jnp.einsum("skf,kf->ks", h, a_src),
+                              PARTS_AXIS, axis=1, tiled=True)
+    ad_t = jax.lax.all_gather(jnp.einsum("skf,kf->ks", h, a_dst),
+                              PARTS_AXIS, axis=1, tiled=True)
+    q = _take_lanes(ad_t, ed) + _take_lanes(as_t, es)            # [K, Eb]
     s = jax.nn.leaky_relu(q, negative_slope=slope)
     NEG = jnp.float32(-1e30)     # finite sentinel: see _ring_attend note
     m_loc = jnp.maximum(
         _plan_max(s, pl.dst_obi, pl.dst_edst, pl.dst_pos, span_d), NEG)
     m_all = jax.lax.dynamic_update_slice(
-        jax.lax.pcast(jnp.full((NS, K), NEG, s.dtype), PARTS_AXIS,
+        jax.lax.pcast(jnp.full((K, NS), NEG, s.dtype), PARTS_AXIS,
                       to="varying"),
-        m_loc, (egp.dst_base, 0))
+        m_loc, (0, egp.dst_base))
     # stop_gradient BEFORE pmax: shift invariance; pmax has no diff rule
-    m = jax.lax.pmax(jax.lax.stop_gradient(m_all), PARTS_AXIS)   # [NS, K]
-    e = jnp.exp(s - jnp.take(m, ed, axis=0))                     # [Eb, K]
+    m = jax.lax.pmax(jax.lax.stop_gradient(m_all), PARTS_AXIS)   # [K, NS]
+    e = jnp.exp(s - _take_lanes(m, ed))                          # [K, Eb]
     z_loc = _plan_sum(e, None, pl.dst_obi, pl.dst_edst, pl.dst_pos,
-                      pl.dst_nid, span_d, "highest")             # [spanD, K]
-    u_loc = _plan_sum(e, table, pl.dst_obi, pl.dst_edst, pl.dst_pos,
-                      pl.dst_nid, span_d, precision)          # [spanD, K, F]
-    z = _scatter_to_owner(z_loc, egp.dst_base, NS)               # [S, K]
+                      pl.dst_nid, span_d, "highest", True)      # [K, spanD]
+    w = _keep_scale((key, rate), K, es.shape[0], e.dtype)
+    u_loc = _plan_sum(e if w is None else e * w, table, pl.dst_obi,
+                      pl.dst_edst, pl.dst_pos, pl.dst_nid, span_d,
+                      precision, True)                    # [spanD, K, F]
+    z = _scatter_to_owner(z_loc.T, egp.dst_base, NS)             # [S, K]
     u = _scatter_to_owner(u_loc.reshape(span_d, K * F),
                           egp.dst_base, NS).reshape(S, K, F)
     # _Z_GUARD (ops/edge.py): big enough to survive BOTH the XLA
@@ -497,12 +510,14 @@ def _egat_fwd(h, a_src, a_dst, egp, edge_ids, slope, precision):
     # edgeless rows); live rows have z >= 1 by the max shift
     zc = jnp.maximum(z, _Z_GUARD)
     out = u / zc[:, :, None]
-    return out, (h, table, a_src, a_dst, egp, edge_ids, q >= 0, e, zc, out)
+    return out, (h, table, a_src, a_dst, egp, edge_ids, key, q >= 0, e, zc,
+                 out)
 
 
-def _egat_bwd(slope, precision, res, gout):
-    from roc_tpu.ops.edge import _edge_contract, _plan_sum
-    h, table, a_src, a_dst, egp, edge_ids, qpos, e, zc, out = res
+def _egat_bwd(slope, precision, rate, res, gout):
+    from roc_tpu.ops.edge import (_edge_contract, _int_zeros, _keep_scale,
+                                  _plan_sum, _take_lanes)
+    h, table, a_src, a_dst, egp, edge_ids, key, qpos, e, zc, out = res
     es, ed = edge_ids
     S, K, F = h.shape
     NS = table.shape[0]
@@ -514,20 +529,24 @@ def _egat_bwd(slope, precision, res, gout):
     # arbitrary destinations, so gather them back to the global id space
     du_t = jax.lax.all_gather(du.reshape(S, K * F), PARTS_AXIS,
                               tiled=True).reshape(NS, K, F)
-    dz_t = jax.lax.all_gather(dz, PARTS_AXIS, tiled=True)        # [NS, K]
-    de = _edge_contract(du_t, table, es, ed, dz_t)               # [Eb, K]
+    dz_t = jax.lax.all_gather(dz.T, PARTS_AXIS, axis=1, tiled=True)  # [K, NS]
+    w = _keep_scale((key, rate), K, es.shape[0], e.dtype)   # the fwd's mask
+    de = _edge_contract(du_t, table, es, ed)                     # [K, Eb]
+    if w is not None:
+        de = de * w
+    de = de + _take_lanes(dz_t, ed)
     dq = e * de * jnp.where(qpos, 1.0, slope)
     dadl = _scatter_to_owner(
         _plan_sum(dq, None, pl.dst_obi, pl.dst_edst, pl.dst_pos,
-                  pl.dst_nid, span_d, "highest"),
+                  pl.dst_nid, span_d, "highest", True).T,
         egp.dst_base, NS)                                        # [S, K]
     dast = _scatter_to_owner(
         _plan_sum(dq, None, pl.src_obi, pl.src_edst, pl.src_pos,
-                  pl.src_nid, span_s, "highest"),
+                  pl.src_nid, span_s, "highest").T,
         egp.src_base, NS)                                        # [S, K]
     dtab = _scatter_to_owner(
-        _plan_sum(e, du_t, pl.src_obi, pl.src_edst, pl.src_pos,
-                  pl.src_nid, span_s, precision
+        _plan_sum(e if w is None else e * w, du_t, pl.src_obi, pl.src_edst,
+                  pl.src_pos, pl.src_nid, span_s, precision
                   ).reshape(span_s, K * F),
         egp.src_base, NS).reshape(S, K, F)
     dh = dtab + dast[:, :, None] * a_src[None] \
@@ -535,14 +554,10 @@ def _egat_bwd(slope, precision, res, gout):
     # per-shard partials; the trainer psums replicated param grads upstream
     da_src = jnp.einsum("sk,skf->kf", dast, h)
     da_dst = jnp.einsum("sk,skf->kf", dadl, h)
-    zeros = jax.tree.map(
-        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0)
-        if jnp.issubdtype(a.dtype, jnp.integer) else jnp.zeros_like(a),
-        (egp, edge_ids))
-    return (dh, da_src, da_dst) + zeros
+    return (dh, da_src, da_dst) + _int_zeros((egp, edge_ids, key))
 
 
-edge_gat_attend.defvjp(_egat_fwd, _egat_bwd)
+_egat.defvjp(_egat_fwd, _egat_bwd)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -675,9 +690,10 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
     gat_plans = None
     if gat_backend == "plan":
         from roc_tpu.ops.edge import build_gat_plans, pad_gat_plans
-        gat_plans = pad_gat_plans(
-            [build_gat_plans(src[i], part.edge_dst[i], S, table_rows)
-             for i in range(P_)])
+        with obs.span("gat_plan_build", parts=P_):
+            gat_plans = pad_gat_plans(
+                [build_gat_plans(src[i], part.edge_dst[i], S, table_rows)
+                 for i in range(P_)])
     return ShardedGraphData(
         edge_src=jnp.asarray(src, jnp.int32),
         edge_dst=jnp.asarray(part.edge_dst, jnp.int32),
@@ -869,7 +885,7 @@ def _ring_aggregate(gd_block, shard_nodes: int, x, aggr: str):
     return acc
 
 
-def _edge_attend(gd_block, h, a_src, a_dst, slope: float):
+def _edge_attend(gd_block, h, a_src, a_dst, slope: float, drop=None):
     """GAT attention under edge sharding — the last cell of the
     model × distribution matrix.
 
@@ -888,7 +904,10 @@ def _edge_attend(gd_block, h, a_src, a_dst, slope: float):
     so on hardware this is the correctness path, not the fast path — the
     plan treatment (windowed per-block schedules like EdgePlans) is the
     known follow-up if edge-sharded attention ever becomes hot.
+    ``drop`` = (key, rate): attention dropout over this block's Eb edges
+    (ops.edge.attention_keep), on the weighted sum only.
     """
+    from roc_tpu.ops.edge import _keep_scale
     S, K, F = h.shape[0], h.shape[1], h.shape[2]
     table = jax.lax.all_gather(
         h.reshape(S, K * F), PARTS_AXIS, tiled=True).reshape(-1, K, F)
@@ -916,7 +935,9 @@ def _edge_attend(gd_block, h, a_src, a_dst, slope: float):
     z_part = jax.ops.segment_sum(e, ed, num_segments=NS,
                                  indices_are_sorted=True)
     g = jnp.take(table, es, axis=0)                 # [Eb, K, F]
-    u_part = jax.ops.segment_sum(g * e[:, :, None], ed, num_segments=NS,
+    w = _keep_scale(drop, K, es.shape[0], e.dtype)
+    ew = e if w is None else e * w.T
+    u_part = jax.ops.segment_sum(g * ew[:, :, None], ed, num_segments=NS,
                                  indices_are_sorted=True)
     z = jax.lax.psum_scatter(z_part, PARTS_AXIS, scatter_dimension=0,
                              tiled=True)            # [S, K] owner rows
@@ -929,7 +950,8 @@ def _edge_attend(gd_block, h, a_src, a_dst, slope: float):
     return u / jnp.maximum(z, _Z_GUARD)[:, :, None]
 
 
-def _ring_attend(gd_block, S: int, h, a_src, a_dst, slope: float):
+def _ring_attend(gd_block, S: int, h, a_src, a_dst, slope: float,
+                 drop=None):
     """GAT attention in ring mode — LITERAL ring attention on the vertex/
     context axis (SURVEY §5.7: the vertex-shard axis IS the sequence axis).
 
@@ -945,8 +967,11 @@ def _ring_attend(gd_block, S: int, h, a_src, a_dst, slope: float):
     recomputes each owner group's scores instead of stacking P steps of
     residuals.  Pad edges carry dst = S (masked); destinations with no
     in-edges anywhere keep z = 0 and emit 0 (same convention as the
-    table-based paths).
+    table-based paths).  ``drop`` = (key, rate): attention dropout, one
+    mask per visiting owner group (the key folded with the owner's index;
+    the rematerialized step redraws it), on the weighted sum only.
     """
+    from roc_tpu.ops.edge import _keep_scale
     P_ = gd_block.ring_src.shape[0]
     K, F = h.shape[1], h.shape[2]
     p = jax.lax.axis_index(PARTS_AXIS)
@@ -984,7 +1009,11 @@ def _ring_attend(gd_block, S: int, h, a_src, a_dst, slope: float):
         z_step = jax.ops.segment_sum(e, ed, num_segments=S + 1,
                                      indices_are_sorted=True)[:S]
         g = jnp.take(hb, es, axis=0)                      # [Eo, K, F]
-        u_step = jax.ops.segment_sum(g * e[:, :, None], ed,
+        w = None if drop is None else _keep_scale(
+            (jax.random.fold_in(drop[0], owner), drop[1]), K, es.shape[0],
+            e.dtype)
+        ew = e if w is None else e * w.T
+        u_step = jax.ops.segment_sum(g * ew[:, :, None], ed,
                                      num_segments=S + 1,
                                      indices_are_sorted=True)[:S]
         # rescale prior mass to the tightened max; no-mass-yet rows have
@@ -1048,13 +1077,13 @@ def _shard_gctx(gd_block, shard_nodes: int, exchange: str) -> GraphCtx:
                 out = ops.divide_by_degree(out, gd_block.in_degree)
             return out
 
-        def attend_edge(h, a_src, a_dst, slope):
+        def attend_edge(h, a_src, a_dst, slope, drop=None):
             if gd_block.gat_plans is not None:
                 return edge_gat_attend(
                     h, a_src, a_dst, gd_block.gat_plans,
                     (edge_src, edge_dst),
-                    slope, ops.matmul_precision(gd_block.precision))
-            return _edge_attend(gd_block, h, a_src, a_dst, slope)
+                    slope, ops.matmul_precision(gd_block.precision), drop)
+            return _edge_attend(gd_block, h, a_src, a_dst, slope, drop)
 
         return GraphCtx(aggregate=aggregate_edge,
                         in_degree=gd_block.in_degree, attend=attend_edge)
@@ -1063,9 +1092,9 @@ def _shard_gctx(gd_block, shard_nodes: int, exchange: str) -> GraphCtx:
         def aggregate_ring(x, aggr):
             return _ring_aggregate(gd_block, shard_nodes, x, aggr)
 
-        def attend_ring(h, a_src, a_dst, slope):
+        def attend_ring(h, a_src, a_dst, slope, drop=None):
             return _ring_attend(gd_block, shard_nodes, h, a_src, a_dst,
-                                slope)
+                                slope, drop)
 
         return GraphCtx(aggregate=aggregate_ring,
                         in_degree=gd_block.in_degree, attend=attend_ring)
@@ -1097,12 +1126,12 @@ def _shard_gctx(gd_block, shard_nodes: int, exchange: str) -> GraphCtx:
         table = _exchange(gd_block, exchange, x)
         return _vertex_aggregate(table, gd_block, shard_nodes, aggr, interp)
 
-    def attend(h, a_src, a_dst, slope):
+    def attend(h, a_src, a_dst, slope, drop=None):
         kk, fd = h.shape[1], h.shape[2]
         table = _exchange(gd_block, exchange,
                           h.reshape(h.shape[0], kk * fd))
         return _vertex_attend(table, gd_block, shard_nodes, h, a_src,
-                              a_dst, slope)
+                              a_dst, slope, drop)
 
     return GraphCtx(aggregate=aggregate, in_degree=gd_block.in_degree,
                     attend=attend)
@@ -1136,7 +1165,8 @@ def _vertex_aggregate(table, gdj, S: int, aggr: str, interp: bool):
     return ops.scatter_gather(table, gdj.edge_src, gdj.edge_dst, S, aggr)
 
 
-def _vertex_attend(table_flat, gdj, S: int, h_local, a_src, a_dst, slope):
+def _vertex_attend(table_flat, gdj, S: int, h_local, a_src, a_dst, slope,
+                   drop=None):
     """One part's GAT attention (plan backend when built, else dense/
     chunked) — shared by both vertex gctx builders.  ``table_flat`` is the
     exchanged [T, K*F] source table for this part."""
@@ -1146,9 +1176,9 @@ def _vertex_attend(table_flat, gdj, S: int, h_local, a_src, a_dst, slope):
         from roc_tpu.ops.edge import gat_attend_plan
         return gat_attend_plan(h_local, tab, a_src, a_dst, gdj.gat_plans,
                                (gdj.edge_src, gdj.edge_dst), slope,
-                               ops.matmul_precision(gdj.precision))
+                               ops.matmul_precision(gdj.precision), drop)
     return ops.gat_attend(h_local, tab, gdj.edge_src, gdj.edge_dst, S,
-                          a_src, a_dst, slope)
+                          a_src, a_dst, slope, drop)
 
 
 def _overcommit_tables(gd_block, k: int, S: int, exchange: str, x):
@@ -1200,13 +1230,18 @@ def _shard_gctx_over(gd_block, S: int, k: int, exchange: str) -> GraphCtx:
             [_vertex_aggregate(tables[j], _part_view(gd_block, j), S, aggr,
                                interp) for j in range(k)], axis=0)
 
-    def attend(h, a_src, a_dst, slope):
+    def attend(h, a_src, a_dst, slope, drop=None):
         kk, fd = h.shape[1], h.shape[2]
         tables = _overcommit_tables(gd_block, k, S, exchange,
                                     h.reshape(h.shape[0], kk * fd))
+        # one mask per local part: the device's key folded with j
+        drops = [None if drop is None
+                 else (jax.random.fold_in(drop[0], j), drop[1])
+                 for j in range(k)]
         return jnp.concatenate(
             [_vertex_attend(tables[j], _part_view(gd_block, j), S,
-                            h[j * S:(j + 1) * S], a_src, a_dst, slope)
+                            h[j * S:(j + 1) * S], a_src, a_dst, slope,
+                            drops[j])
              for j in range(k)], axis=0)
 
     return GraphCtx(aggregate=aggregate,
@@ -1307,8 +1342,10 @@ class SpmdTrainer(BaseTrainer):
                                          fwd_arrays=(eb_src, eb_dst))
             gat_plans = None
             if gat_backend == "plan":
-                gat_plans = build_edge_gat_plans(
-                    ds.graph, self.part.meta, fwd_arrays=(eb_src, eb_dst))
+                with obs.span("gat_plan_build", mode="edge"):
+                    gat_plans = build_edge_gat_plans(
+                        ds.graph, self.part.meta,
+                        fwd_arrays=(eb_src, eb_dst))
             return ShardedGraphData(
                 edge_src=jnp.asarray(eb_src, jnp.int32),
                 edge_dst=jnp.asarray(eb_dst, jnp.int32),
@@ -1429,8 +1466,9 @@ class SpmdTrainer(BaseTrainer):
                                                 b_sct, allgather=ag)
             gat_plans = None
             if gat_backend == "plan":
-                gat_plans = build_edge_gat_plans_arrays(
-                    meta, f_gat, f_sct, allgather=ag)
+                with obs.span("gat_plan_build", mode="edge"):
+                    gat_plans = build_edge_gat_plans_arrays(
+                        meta, f_gat, f_sct, allgather=ag)
             return ShardedGraphData(
                 edge_src=jnp.asarray(f_gat, jnp.int32),
                 edge_dst=jnp.asarray(f_sct, jnp.int32),
@@ -1493,13 +1531,15 @@ class SpmdTrainer(BaseTrainer):
         gat_plans = None
         if gat_backend == "plan":
             from roc_tpu.ops.edge import build_gat_plans, pad_gat_plans
-            local_plans = [build_gat_plans(src[i], local.edge_dst[i], S,
-                                           table_rows)
-                           for i in range(len(part_ids))]
-            f = _allgather_floors(
-                [[p.dst_obi.shape[0] for p in local_plans],
-                 [p.src_obi.shape[0] for p in local_plans]], ag)
-            gat_plans = pad_gat_plans(local_plans, min_d=f[0], min_s=f[1])
+            with obs.span("gat_plan_build", parts=len(part_ids)):
+                local_plans = [build_gat_plans(src[i], local.edge_dst[i], S,
+                                               table_rows)
+                               for i in range(len(part_ids))]
+                f = _allgather_floors(
+                    [[p.dst_obi.shape[0] for p in local_plans],
+                     [p.src_obi.shape[0] for p in local_plans]], ag)
+                gat_plans = pad_gat_plans(local_plans, min_d=f[0],
+                                          min_s=f[1])
         xd, xr, xc = self._xch_meta()
         return ShardedGraphData(
             edge_src=jnp.asarray(src, jnp.int32),

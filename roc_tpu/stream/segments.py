@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
-from roc_tpu.models.model import Model, OpNode
+from roc_tpu.models.model import Model, OpNode, attention_drop
 from roc_tpu.memory.estimator import _op_out_dims
 from roc_tpu import ops
 
@@ -172,6 +172,7 @@ def run_segment(seg: Segment, params, table, own, esrc, edst, indeg, key,
                 h_tab[:num_nodes], h_tab, esrc, edst, num_nodes,
                 params[name + "_asrc"], params[name + "_adst"],
                 op.attrs["slope"],
+                attention_drop(op, key, train and key is not None),
             ).reshape(num_nodes, kk * fd)
 
     for op in seg.body:

@@ -18,6 +18,8 @@ from __future__ import annotations
 import dataclasses
 import os
 import signal
+import sys
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -144,6 +146,36 @@ def resolve_gat_backend(backend: str, num_edges: int) -> str:
         return "plan" if on_tpu() and num_edges >= AUTO_MATMUL_EDGES \
             else "xla"
     return "xla" if backend == "xla" else "plan"
+
+
+def gat_fusion_refused(megafuse: bool) -> str:
+    """Why the fused GAT kernel (ops/pallas/gat.py) is not even tried on
+    this run, or "" when its plan-dependent gates get to decide.  Checked
+    BEFORE its binned plan pair is built.  On a TPU the family is not
+    admitted at all: its max pass asks 20 MiB of scoped VMEM and the
+    compiler of this installation (libtpu 0.0.34) refuses the train step
+    (CHANGES.md PR 21; the repair is ROADMAP Queue 1 item 4), so a run is
+    never routed into it; the trainer's start-up line says so."""
+    from roc_tpu.ops.pallas import gat as _pgat
+    if not megafuse:
+        return "no -megafuse"
+    if os.environ.get("ROC_BINNED_NO_FUSE") or _pgat.gat_fuse_killed():
+        return "kill switch"
+    if on_tpu():
+        return "does not compile on this TPU installation (scoped VMEM)"
+    return ""
+
+
+def gat_plan_stats(plans, num_edges: int) -> dict:
+    """Chunk counts and padding of a GatPlans pair (stacked plans count
+    every shard): what the ``gat_plan_build`` span and the
+    ``gat_plan_pad_ratio`` gauge carry."""
+    d, s = plans.dst_pos, plans.src_pos
+    parts = d.shape[0] if d.ndim == 3 else 1
+    slots = int(d.size + s.size)
+    return {"chunks_dst": int(d.size // d.shape[-1]),
+            "chunks_src": int(s.size // s.shape[-1]), "slots": slots,
+            "pad_ratio": slots / max(2 * parts * int(num_edges), 1)}
 
 
 def model_aggrs(model: Model) -> set:
@@ -274,9 +306,15 @@ def dense_graph_data(graph, backend: str = "xla",
         gat_fused = False
         if gat_backend == "plan":
             from roc_tpu.ops.edge import build_gat_plans
-            gat_plans = build_gat_plans(graph.col_idx, graph.dst_idx,
-                                        graph.num_nodes, graph.num_nodes)
-            if megafuse:
+            from roc_tpu.ops.pallas import gat as _pgat
+            with obs.span("gat_plan_build", edges=graph.num_edges) as sp:
+                gat_plans = build_gat_plans(graph.col_idx, graph.dst_idx,
+                                            graph.num_nodes, graph.num_nodes)
+                sp.args.update(gat_plan_stats(gat_plans, graph.num_edges))
+            # the fused kernel's plan-independent gates first: a binned
+            # plan pair (choose_geometry, ~4 s a direction at the Reddit
+            # shape) is built only for a kernel that may still take it
+            if not gat_fusion_refused(megafuse):
                 # The fused attention megakernel rides the SAME binned plan
                 # family as aggregate->linear fusion; fuse_linear=True so
                 # choose_geometry prices flat (fusable) schedules with the
@@ -285,7 +323,6 @@ def dense_graph_data(graph, backend: str = "xla",
                 # and gat_bplans stays None — the attend closure then runs
                 # the byte-identical unfused composition.
                 from roc_tpu.ops.edge import _gat_fuse_state
-                from roc_tpu.ops.pallas import gat as _pgat
                 bp = ops.build_binned_plans(
                     graph.col_idx, graph.dst_idx, graph.num_nodes,
                     graph.num_nodes, geom="auto",
@@ -347,10 +384,17 @@ def make_gctx(g: DenseGraphData, num_nodes: int,
             return out
         return ops.scatter_gather(x, g.edge_src, g.edge_dst, num_nodes, aggr)
 
-    def attend(h, a_src, a_dst, slope):
+    def attend(h, a_src, a_dst, slope, drop=None):
         # single device: the source table IS the local tensor
         if g.gat_plans is not None:
             if g.gat_bplans is not None:
+                if drop is not None:
+                    raise ValueError(
+                        "the fused GAT kernel (-megafuse, ops/pallas/gat.py"
+                        ": gat_attend_binned) has no attention dropout; "
+                        "train this gat model with -dropout 0 or without "
+                        "-megafuse (it would otherwise train without the "
+                        "coefficients' mask)")
                 # Fused attention megakernel (ops/pallas/gat.py): per-head
                 # score->softmax->aggregate in one binned grid.  Its own
                 # trace-time decline ladder (head width, VMEM, kill
@@ -363,9 +407,9 @@ def make_gctx(g: DenseGraphData, num_nodes: int,
             from roc_tpu.ops.edge import gat_attend_plan
             return gat_attend_plan(h, h, a_src, a_dst, g.gat_plans,
                                    (g.edge_src, g.edge_dst), slope,
-                                   ops.matmul_precision(g.precision))
+                                   ops.matmul_precision(g.precision), drop)
         return ops.gat_attend(h, h, g.edge_src, g.edge_dst, num_nodes,
-                              a_src, a_dst, slope)
+                              a_src, a_dst, slope, drop)
 
     fuse_linear = None
     if megafuse and g.backend == "binned" and g.plans is not None \
@@ -505,6 +549,7 @@ class BaseTrainer:
         self._use_edge_shard = False
         self._obs_init()
         self._setup()
+        self._announce_attention()
         self.balancer = None
         if config.balance_every:
             if self._balance_supported():
@@ -582,6 +627,59 @@ class BaseTrainer:
         # graph shape is pinned there (binned runs); None -> measured warmup
         self.watchdog = obs.PerfWatchdog(
             seed_s=obs.seed_for_graph(g.num_nodes, g.num_edges))
+
+    def attention_info(self) -> Optional[dict]:
+        """What this trainer resolved for its gat ops (None: the model has
+        none): the attention backend ("plan": ops.edge.gat_attend_plan or
+        its sharded kin over GatPlans; "xla": the dense / chunked / ring
+        scans), whether the fused Pallas kernel is attached and, if not,
+        why it was not tried, the GatPlans' padding (plan slots / edges)
+        and the bytes of per-edge residuals a train step keeps between
+        forward and backward on the plan path (e float32 + the score's sign,
+        [K, E] each, per gat op; 0 where autodiff keeps what it likes)."""
+        if not model_has_gat(self.model):
+            return None
+        gd = getattr(self, "gdata", None)   # the streamed trainer has none
+        plans = getattr(gd, "gat_plans", None)
+        plans = getattr(plans, "plans", plans)      # EdgeGatPlans wraps one
+        backend = "plan" if plans is not None else "xla"
+        fused = bool(getattr(gd, "gat_bplans", None) is not None)
+        info = {"backend": backend, "fused": fused,
+                "not_fused_because": "" if fused else (
+                    gat_fusion_refused(self.config.megafuse)
+                    or "the plan-dependent gates declined"),
+                "plan_pad_ratio": 0.0, "score_bytes": 0}
+        if plans is not None:
+            edges = int(gd.edge_src.shape[-1])      # per shard when sharded
+            info["plan_pad_ratio"] = gat_plan_stats(plans, edges)["pad_ratio"]
+            info["score_bytes"] = sum(
+                int(op.attrs["heads"]) * edges * (4 + 1)
+                for op in self.model.ops if op.kind == "gat")
+        return info
+
+    def _announce_attention(self):
+        """The trainer's own start-up line for a gat model, and the same
+        facts as `attention` record + gauges under -obs: a run is never
+        routed into the fused kernel, or out of it, without a word."""
+        info = self.attention_info()
+        if info is None:
+            return
+        why = "" if info["fused"] else f" ({info['not_fused_because']})"
+        print(f"# attention: backend={info['backend']} "
+              f"gat_fused={info['fused']}{why} "
+              f"gat_plan_pad_ratio={info['plan_pad_ratio']:.4f} "
+              f"gat_score_bytes={info['score_bytes']}", file=sys.stderr,
+              flush=True)
+        if self._metrics is not None:
+            self._metrics.emit(
+                "attention", backend=info["backend"], fused=info["fused"],
+                gat_plan_pad_ratio=info["plan_pad_ratio"],
+                gat_score_bytes=info["score_bytes"])
+            for name in ("gat_plan_pad_ratio", "gat_score_bytes"):
+                self._metrics.set_gauge(name, info[name[4:]])
+            self._metrics.set_gauge("gat_backend", 1.0,
+                                    backend=info["backend"],
+                                    fused=str(info["fused"]).lower())
 
     def _obs_epoch(self, epoch: int, wall_s: float, loss, print_fn):
         """Per-epoch drain: fetch the in-graph metrics pytree (ONE

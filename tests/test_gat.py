@@ -177,8 +177,9 @@ def test_gat_plan_multistep_scan_matches_oracle():
     s = np.where(s >= 0, s, 0.2 * s).astype(np.float32)
     mo = np.full((N, K), -np.inf, np.float32)
     np.maximum.at(mo, g.dst_idx, s)
-    m = np.asarray(em._plan_max(jnp.asarray(s), plans.dst_obi,
-                                plans.dst_edst, plans.dst_pos, N))
+    # per-edge arrays of the plan path are [K, E] (edges on the lane axis)
+    m = np.asarray(em._plan_max(jnp.asarray(s.T), plans.dst_obi,
+                                plans.dst_edst, plans.dst_pos, N)).T
     np.testing.assert_allclose(m, mo, rtol=1e-5, atol=1e-5)
     # end-to-end against the dense oracle
     ref = ops.gat_attend(h, h, es, ed, N, a_s, a_d, 0.2)

