@@ -329,12 +329,18 @@ class BinnedPlan:
     """One direction (out = A @ x) of a binned aggregation schedule.
 
     Array fields carry a leading [G] group axis; int fields are static.
-      p1_srcl [G, C1*CH, 1]  src row local to its block (pad rows: 0)
+      p1_srcl [G, C1, CH]    src row local to its block (pad rows: 0)
       p1_off  [G, C1, NSLOT] staging SLOT index per chunk slot
       p1_blk  [G, C1]        x block index per chunk
-      p2_dstl [G, C2*CH2, 1] dst row local to its bin (pad rows: RB)
+      p2_dstl [G, C2, CH2]   dst row local to its bin (pad rows: RB)
       p2_obi  [G, C2]        group-local bin index per chunk (nondecreasing)
       p2_first[G, C2]        1 iff first chunk of its bin
+    The two index arrays are lane-dense: one chunk's indices are one row
+    (the linear order is the builders' [G, C*CH]; only the shape says
+    where a chunk ends), so a group's slice is 4 bytes an index in HBM
+    and the kernels fetch eight chunks' rows as one (8, CH) block.  A
+    [rows, 1] column would tile to 128 lanes a row: 512 bytes an index,
+    re-laid out every scan step (a third of the Reddit epoch until PR 26).
 
     Flat-schedule plans (geom.flat, round 8) reinterpret/extend the set:
     p1_off is None (replaced by the run-list DMA metadata), p1_srcl pad
@@ -515,12 +521,15 @@ _VMEM_LIMIT_MAX = 100 * (1 << 20)
 # (CHANGES.md PR 21 has the compile inventory).
 _VMEM_BUDGET = 14 * (1 << 20)
 # HBM admission for choose_geometry, the analogue of _VMEM_NOMINAL_CAP: a
-# candidate's per-group temporaries (_group_hbm_bytes, from shapes) may
-# take a quarter of a v5e's 16 GiB.  The rest belongs to the features,
-# the activations kept for the backward pass, the plans and the
-# optimiser.  Measured (PR 24, one chip, Reddit shape): the default
-# two-pass group needs 2.8 GB and the step peaks at 4.84 GiB, GEOM_FLAT
-# 3.3 GB and 5.29 GiB, GEOM_WIDE (grt 1 << 23) 11.2 GB and 12.69 GiB.
+# candidate's per-group temporaries (_group_hbm_bytes, from shapes: the
+# staging buffer and a lane-dense index slice) may take a quarter of a
+# v5e's 16 GiB.  The rest belongs to the features, the activations kept
+# for the backward pass, the plans and the optimiser.  At the Reddit
+# shape the model says 1.35 GB for the default two-pass group, 2.23 GB
+# for GEOM_FLAT and 5.51 GB for GEOM_WIDE (grt 1 << 23).  Measured on one
+# chip: the default's step peaks at 3.49 GiB (PR 26; 4.84 with the index
+# operands as [rows, 1] columns, when GEOM_FLAT peaked at 5.29 GiB and
+# GEOM_WIDE at 12.69, PR 24).
 _HBM_GROUP_CAP = 4 * (1 << 30)
 
 
@@ -587,8 +596,8 @@ def _matmul_cost(num_edges: int, num_rows: int) -> float:
 
 
 # What Mosaic allocates for one grid step of the two-pass kernels: the
-# pipelined operand blocks (double-buffered; a (rows, 1) int32 block
-# lane-pads to 128 lanes), the scratch, and the values the body
+# pipelined operand blocks (double-buffered; the index block is eight
+# chunks' lane-dense rows), the scratch, and the values the body
 # materialises — the bf16 one-hot, each fp32 dot result, the masked /
 # split copies of the feature operand.  An UPPER bound, checked with
 # libtpu's compiler for a v5e topology (PR 21): every preset at H in
@@ -606,11 +615,15 @@ def _p1_vmem_bytes(geom: Geometry, H: int = _MODEL_H,
     two = 2 if geom.flat else 1       # flat: two x blocks, two one-hots
     nd = 3 if exact else 1            # exact: hi/mid/lo split dots
     blocks = (2 * geom.ch * H * stg               # gbuf scratch
-              + 2 * geom.ch * 128 * 4             # srcl (ch, 1) int32
+              + 2 * 8 * geom.ch * 4               # srcl (8, ch) int32
               + 2 * two * geom.sb * H * 4)        # x block(s)
-    body = two * (geom.ch * geom.sb * 2 + nd * geom.ch * H * 4)
+    # the one-hot [sb, ch] and the turned copy the dimension-0
+    # contraction makes of it, each fp32 dot result
+    body = two * (2 * geom.ch * geom.sb * 2 + nd * geom.ch * H * 4)
     if exact:
         body += 3 * geom.sb * H * 2 + 2 * geom.sb * H * 4
+        if geom.flat:                 # the fp32 sum of the two blocks' rows
+            body += geom.ch * H * 4
     return blocks + body + _VMEM_SLACK
 
 
@@ -619,7 +632,7 @@ def _p2_vmem_bytes(geom: Geometry, H: int = _MODEL_H,
     stg = staging_itemsize(geom, exact)
     nd = 3 if exact else 1
     blocks = (2 * geom.ch2 * H * stg              # staging chunk
-              + 2 * geom.ch2 * 128 * 4            # dstl (ch2, 1) int32
+              + 2 * 8 * geom.ch2 * 4              # dstl (8, ch2) int32
               + 2 * geom.rb * H * 4)              # resident out window
     body = (geom.ch2 * geom.rb * 2 + geom.ch2 * H * stg
             + nd * geom.rb * H * 4)
@@ -713,16 +726,19 @@ def _group_hbm_bytes(geom: Geometry, steps1: int, steps2: int,
                      groups: int, H: int = _MODEL_H) -> int:
     """HBM the scan over bin groups holds for ONE group at a time, from
     shapes: the staging buffer [C2 * ch2, H] in its staging dtype plus the
-    larger of the two `[rows, 1]` int32 index operands (p1_srcl while
-    phase 1 runs, p2_dstl while phase 2 does), which the step keeps in
-    the tiled layout, 128 lanes a row.  Against the compiler's own
-    analysis of the Reddit train step for a v5e (PR 24): default two-pass
-    2.78 GB here and 4.10 GB of temporaries there, GEOM_FLAT 3.33 and
-    4.65, GEOM_WIDE 11.2 and 12.5 — the same 1.3 GB apart in all three."""
+    larger of the two int32 index operands (p1_srcl while phase 1 runs,
+    p2_dstl while phase 2 does) — a lane-dense slice of the stacked plan,
+    4 bytes an index, where the [rows, 1] columns of PR 24 were re-laid
+    out to 128 lanes a row (1.44 GB a step at the Reddit shape).  Beside
+    it, the compiler's own analysis of the Reddit train step for a v5e
+    (tools/aot_compile.py, PR 26): default two-pass 1.35 GB here and
+    2.66 GB of temporaries there, GEOM_FLAT 2.23 and 3.62, GEOM_WIDE 5.51
+    and 6.80 — 1.3 to 1.4 GB apart in all three, as before PR 26, when
+    each was 1.1 to 5.7 GB of index padding higher on both sides."""
     p1_rows = steps1 // max(groups, 1) * geom.ch
     stg_rows = steps2 // max(groups, 1) * geom.ch2
     return (stg_rows * H * staging_itemsize(geom, False)
-            + max(p1_rows, stg_rows) * 128 * 4)
+            + max(p1_rows, stg_rows) * 4)
 
 
 def _cell_stats(edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -1389,8 +1405,8 @@ def build_binned_plan(edge_src: np.ndarray, edge_dst: np.ndarray,
 
 def _native_plan_arrays(edge_src, edge_dst, num_rows, table_rows,
                         group_row_target, geom):
-    """(host arrays, bins per group) from the C++ builder, in the shapes
-    BinnedPlan documents."""
+    """(host arrays, bins per group) from the C++ builder; the two index
+    arrays flat, as every producer hands them to _plan_from_host."""
     from roc_tpu import native
     if geom.flat:
         (p1_srcl, p1_blk, p1_blk2, p1_dsrc, p1_ddst, p2_dstl, p2_obi,
@@ -1403,10 +1419,7 @@ def _native_plan_arrays(edge_src, edge_dst, num_rows, table_rows,
          bpg) = native.binned_plan(edge_src, edge_dst, num_rows,
                                    table_rows, group_row_target, geom)
         extra = dict(p1_off=p1_off)
-    G, C1 = p1_blk.shape
-    C2 = p2_obi.shape[1]
-    return dict(p1_srcl=p1_srcl.reshape(G, C1 * geom.ch, 1), p1_blk=p1_blk,
-                p2_dstl=p2_dstl.reshape(G, C2 * geom.ch2, 1),
+    return dict(p1_srcl=p1_srcl, p1_blk=p1_blk, p2_dstl=p2_dstl,
                 p2_obi=p2_obi, p2_first=p2_first, **extra), bpg
 
 
@@ -1414,9 +1427,15 @@ def _plan_from_host(host: dict, bins_per_group: int, num_rows: int,
                     table_rows: int, geom: Geometry) -> BinnedPlan:
     """Place one plan's host arrays (from the cache, the native or the
     NumPy builder) on the device, and attach the fused step lists to a
-    flat plan.  `jnp.asarray` may return before the bytes have landed: the
-    span times the calls, and a transfer's tail falls to whatever waits
-    for it next."""
+    flat plan.  Every producer hands the two index arrays over in its
+    linear order ([G, C * ch]); here, once, they take the lane-dense
+    shape BinnedPlan documents, a chunk a row.  `jnp.asarray` may return
+    before the bytes have landed: the span times the calls, and a
+    transfer's tail falls to whatever waits for it next."""
+    G, C1 = host["p1_blk"].shape
+    C2 = host["p2_obi"].shape[1]
+    host = dict(host, p1_srcl=host["p1_srcl"].reshape(G, C1, geom.ch),
+                p2_dstl=host["p2_dstl"].reshape(G, C2, geom.ch2))
     with _obs_span("plan_to_device",
                    bytes=sum(int(v.nbytes) for v in host.values())):
         placed = {k: jnp.asarray(v) for k, v in host.items()}
@@ -1475,9 +1494,6 @@ def _plan_cache_load(path, num_rows, table_rows, geom):
                 else ["p1_off"]
             host = {k: z[k] for k in names}
         G, C1 = host["p1_blk"].shape
-        C2 = host["p2_obi"].shape[1]
-        host["p1_srcl"] = host["p1_srcl"].reshape(G, C1 * geom.ch, 1)
-        host["p2_dstl"] = host["p2_dstl"].reshape(G, C2 * geom.ch2, 1)
         if geom.flat:
             host["p1_dsrc"] = host["p1_dsrc"].reshape(G, C1, geom.kd)
             host["p1_ddst"] = host["p1_ddst"].reshape(G, C1, geom.kd)
@@ -1679,9 +1695,9 @@ def _slot_plan_arrays_numpy(edge_src, edge_dst, num_rows: int,
         p2_first[g, :len(obi)] = first
         if len(obi) < C2:   # pad chunks: revisit last bin, add only zeros
             p2_obi[g, len(obi):] = obi[-1]
-    return dict(p1_srcl=p1_srcl.reshape(G, C1 * CH, 1), p1_off=p1_off,
-                p1_blk=p1_blk, p2_dstl=p2_dstl.reshape(G, C2 * CH2, 1),
-                p2_obi=p2_obi, p2_first=p2_first), bins_per_group
+    return dict(p1_srcl=p1_srcl, p1_off=p1_off, p1_blk=p1_blk,
+                p2_dstl=p2_dstl, p2_obi=p2_obi,
+                p2_first=p2_first), bins_per_group
 
 
 def _build_flat_plan_numpy(edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -1863,8 +1879,8 @@ def _flat_plan_arrays_numpy(edge_src, edge_dst, num_rows: int,
         p2_first[g, :len(obi)] = first
         if len(obi) < C2:
             p2_obi[g, len(obi):] = obi[-1]
-    return dict(p1_srcl=p1_srcl.reshape(G, C1 * CH, 1), p1_blk=p1_blk,
-                p2_dstl=p2_dstl.reshape(G, C2 * CH2, 1), p2_obi=p2_obi,
+    return dict(p1_srcl=p1_srcl, p1_blk=p1_blk, p2_dstl=p2_dstl,
+                p2_obi=p2_obi,
                 p2_first=p2_first, p1_blk2=p1_blk2, p1_dsrc=p1_dsrc,
                 p1_ddst=p1_ddst), bins_per_group
 
@@ -2006,6 +2022,28 @@ def _stg_dtype(exact: bool):
     return jnp.float32 if exact else jnp.bfloat16
 
 
+# dot_general dimension numbers of the two one-hot contractions
+_DOT_DIM0 = (((0,), (0,)), ((), ()))    # t[K, M], v[K, H] -> [M, H]
+_DOT_MM = (((1,), (0,)), ((), ()))      # s[M, K] @ v[K, H]
+
+
+def _idx_row(ref, c):
+    """Chunk c's indices as [1, width], the chunk on the lane axis.  The
+    index operands ([C, width], a chunk a row) ride in (8, width) blocks
+    indexed c // 8, as `off` does: eight chunks' rows a fetch, read as
+    stored, 4 bytes an index where a (width, 1) column block tiled to
+    128 lanes read 512; this chunk's row is c % 8."""
+    return ref[pl.ds(c % 8, 1), :]  # roclint: allow(mosaic-align) — a one-sublane vector load out of a VMEM block, not a DMA; Mosaic compiles it for the v5e
+
+
+def _onehot_rows(idx, n: int):
+    """[n, width] bf16 one-hot of a [1, width] index row: entry (r, j) is
+    1 iff idx[j] == r.  Indices outside [0, n) (the pad values -1 and RB,
+    a flat chunk's other block) give an all-zero column."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[1]), 0)
+    return (sub == idx).astype(jnp.bfloat16)
+
+
 def _p1_kernel_simple(blk_ref, off_ref, srcl_ref, x_ref, stg_ref, gbuf,
                       offbuf, sems, *, exact: bool = False,
                       geom: Geometry = None):
@@ -2017,9 +2055,8 @@ def _p1_kernel_simple(blk_ref, off_ref, srcl_ref, x_ref, stg_ref, gbuf,
     CH, SB, SLOT, NSLOT = geom.ch, geom.sb, geom.slot, geom.nslot  # noqa
     c = pl.program_id(0)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-    t = (lane == srcl_ref[:]).astype(jnp.bfloat16)
-    gbuf[0] = _onehot_dot(t, x_ref[:], (((1,), (0,)), ((), ())),
+    t = _onehot_rows(_idx_row(srcl_ref, c), SB)          # [SB, CH]
+    gbuf[0] = _onehot_dot(t, x_ref[:], _DOT_DIM0,
                           exact).astype(_stg_dtype(exact))
 
     def issue(s, _):
@@ -2070,9 +2107,8 @@ def _p1_kernel(blk_ref, off_ref, srcl_ref, x_ref, stg_ref, gbuf, offbuf,
     def _():
         drain_parity(par)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-    t = (lane == srcl_ref[:]).astype(jnp.bfloat16)
-    gbuf[par] = _onehot_dot(t, x_ref[:], (((1,), (0,)), ((), ())),
+    t = _onehot_rows(_idx_row(srcl_ref, c), SB)          # [SB, CH]
+    gbuf[par] = _onehot_dot(t, x_ref[:], _DOT_DIM0,
                             exact).astype(_stg_dtype(exact))
 
     # off rides in (8, NSLOT) SMEM blocks; this chunk's row is c % 8.
@@ -2115,7 +2151,7 @@ def _p1_run(x, blk, off, srcl, nchunks: int, stg_rows: int,
         in_specs=[
             pl.BlockSpec((8, NSLOT), lambda c, blk: (c // 8, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((CH, 1), lambda c, blk: (c, 0)),
+            pl.BlockSpec((8, CH), lambda c, blk: (c // 8, 0)),
             pl.BlockSpec((SB, H), lambda c, blk: (blk[c], 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -2183,10 +2219,8 @@ def _p1_flat_kernel(blk_ref, blk2_ref, dsrc_ref, ddst_ref, srcl_ref,
         def _():
             drain_parity(par)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-    sl = srcl_ref[:]
-    t1 = (lane == sl).astype(jnp.bfloat16)
-    gbuf[par] = _onehot_dot(t1, x_ref[:], (((1,), (0,)), ((), ())),
+    sl = _idx_row(srcl_ref, c)
+    gbuf[par] = _onehot_dot(_onehot_rows(sl, SB), x_ref[:], _DOT_DIM0,
                             exact).astype(st)
 
     @pl.when(blk2_ref[c] != blk_ref[c])
@@ -2194,9 +2228,9 @@ def _p1_flat_kernel(blk_ref, blk2_ref, dsrc_ref, ddst_ref, srcl_ref,
         # secondary-block rows (disjoint from the primary's by the
         # +SB encoding, so the sum is exact row selection — each row is
         # rounded to the staging dtype exactly once)
-        t2 = (lane == sl - SB).astype(jnp.bfloat16)
         gbuf[par] = (gbuf[par].astype(jnp.float32) + _onehot_dot(
-            t2, x2_ref[:], (((1,), (0,)), ((), ())), exact)).astype(st)
+            _onehot_rows(sl - SB, SB), x2_ref[:], _DOT_DIM0,
+            exact)).astype(st)
 
     # descriptors ride in (8, KD) SMEM blocks; this chunk's row is c % 8
     def issue(e, _):
@@ -2241,7 +2275,7 @@ def _p1_flat_run(x, blk, blk2, dsrc, ddst, srcl, nchunks: int,
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((8, KD), lambda c, blk, blk2: (c // 8, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((CH, 1), lambda c, blk, blk2: (c, 0)),
+            pl.BlockSpec((8, CH), lambda c, blk, blk2: (c // 8, 0)),
             pl.BlockSpec((SB, H), lambda c, blk, blk2: (blk[c], 0)),
             pl.BlockSpec((SB, H), lambda c, blk, blk2: (blk2[c], 0)),
         ],
@@ -2277,12 +2311,14 @@ def _p2_kernel(obi_ref, first_ref, dstl_ref, stg_ref, out_ref, *,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     # Zero-mask pad/garbage rows BEFORE the dot: a 0 one-hot coefficient
-    # alone would still propagate NaN garbage (0 * NaN = NaN).
-    zero = _stg_dtype(exact)(0)
-    rows = jnp.where(dstl_ref[:] == RB, zero, stg_ref[:])
-    lane = jax.lax.broadcasted_iota(jnp.int32, (CH2, RB), 1)
-    s_t = (lane == dstl_ref[:]).astype(jnp.bfloat16)   # [CH2, RB]
-    out_ref[:] += _onehot_dot(s_t, rows, (((0,), (0,)), ((), ())), exact)
+    # alone would still propagate NaN garbage (0 * NaN = NaN).  The mask
+    # is per staging ROW and the indices are a row themselves, so the one
+    # predicate is turned in VMEM (CH2 values a step; whole (8, 128)
+    # tiles, which is what Mosaic transposes).
+    d = _idx_row(dstl_ref, c)                            # [1, CH2]
+    pad = jnp.broadcast_to((d == RB).astype(jnp.int32), (8, CH2)).T[:, :1]
+    rows = jnp.where(pad != 0, _stg_dtype(exact)(0), stg_ref[:])
+    out_ref[:] += _onehot_dot(_onehot_rows(d, RB), rows, _DOT_MM, exact)
 
 
 @partial(jax.jit, static_argnames=("nchunks", "out_rows", "interpret",
@@ -2296,7 +2332,7 @@ def _p2_run(stg, obi, first, dstl, nchunks: int, out_rows: int,
         num_scalar_prefetch=2,                  # obi, first
         grid=(nchunks,),
         in_specs=[
-            pl.BlockSpec((CH2, 1), lambda c, obi, first: (c, 0)),
+            pl.BlockSpec((8, CH2), lambda c, obi, first: (c // 8, 0)),
             pl.BlockSpec((CH2, H), lambda c, obi, first: (c, 0)),
         ],
         out_specs=pl.BlockSpec((RB, H), lambda c, obi, first: (obi[c], 0)),
@@ -3989,8 +4025,7 @@ def pad_binned_plan(plan: BinnedPlan, C1: int, C2: int) -> BinnedPlan:
         # padded layout — so keep them.
         return dataclasses.replace(
             plan,
-            p1_srcl=jnp.pad(plan.p1_srcl,
-                            ((0, 0), (0, d1 * geom.ch), (0, 0)),
+            p1_srcl=jnp.pad(plan.p1_srcl, ((0, 0), (0, d1), (0, 0)),
                             constant_values=-1),
             p1_blk=jnp.pad(plan.p1_blk, ((0, 0), (0, d1))),
             p1_blk2=jnp.pad(plan.p1_blk2, ((0, 0), (0, d1))),
@@ -3998,17 +4033,16 @@ def pad_binned_plan(plan: BinnedPlan, C1: int, C2: int) -> BinnedPlan:
                             constant_values=-1),
             p1_ddst=jnp.pad(plan.p1_ddst, ((0, 0), (0, d1), (0, 0)),
                             constant_values=-1),
-            p2_dstl=jnp.pad(plan.p2_dstl,
-                            ((0, 0), (0, d2 * geom.ch2), (0, 0)),
+            p2_dstl=jnp.pad(plan.p2_dstl, ((0, 0), (0, d2), (0, 0)),
                             constant_values=geom.rb),
             p2_obi=jnp.pad(plan.p2_obi, ((0, 0), (0, d2)), mode="edge"),
             p2_first=jnp.pad(plan.p2_first, ((0, 0), (0, d2))))
     return BinnedPlan(
-        p1_srcl=jnp.pad(plan.p1_srcl, ((0, 0), (0, d1 * geom.ch), (0, 0))),
+        p1_srcl=jnp.pad(plan.p1_srcl, ((0, 0), (0, d1), (0, 0))),
         p1_off=jnp.pad(plan.p1_off, ((0, 0), (0, d1), (0, 0)),
                        constant_values=-1),
         p1_blk=jnp.pad(plan.p1_blk, ((0, 0), (0, d1))),
-        p2_dstl=jnp.pad(plan.p2_dstl, ((0, 0), (0, d2 * geom.ch2), (0, 0)),
+        p2_dstl=jnp.pad(plan.p2_dstl, ((0, 0), (0, d2), (0, 0)),
                         constant_values=geom.rb),
         p2_obi=jnp.pad(plan.p2_obi, ((0, 0), (0, d2)), mode="edge"),
         p2_first=jnp.pad(plan.p2_first, ((0, 0), (0, d2))),

@@ -345,9 +345,9 @@ class _PlanPatcher:
                 f"layout re-derivation; refusing the patch path")
 
     def device_arrays(self):
-        G = self.layout.G
-        return (jnp.asarray(self.p1.reshape(G, -1, 1)),
-                jnp.asarray(self.p2.reshape(G, -1, 1)))
+        lay = self.layout
+        return (jnp.asarray(self.p1.reshape(lay.G, lay.C1, lay.geom.ch)),
+                jnp.asarray(self.p2.reshape(lay.G, lay.C2, lay.geom.ch2)))
 
 
 class _ReplanTicket:

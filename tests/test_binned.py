@@ -3,6 +3,8 @@ oracle, in interpret mode on CPU.  Hardware behavior is covered by the
 TPU-gated tests in tests/test_tpu_hw.py, skipped off-TPU (interpret mode
 has already let two Mosaic lowering bugs ship; see docs/PERF.md)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -269,12 +271,12 @@ def test_native_plan_equals_numpy():
         assert bpg == ref.bins_per_group
         G, C1 = p1_blk.shape
         np.testing.assert_array_equal(
-            p1_srcl.reshape(G, C1 * 2048, 1), np.asarray(ref.p1_srcl))
+            p1_srcl.reshape(G, C1, 2048), np.asarray(ref.p1_srcl))
         np.testing.assert_array_equal(p1_off, np.asarray(ref.p1_off))
         np.testing.assert_array_equal(p1_blk, np.asarray(ref.p1_blk))
         C2 = p2_obi.shape[1]
         np.testing.assert_array_equal(
-            p2_dstl.reshape(G, C2 * 4096, 1), np.asarray(ref.p2_dstl))
+            p2_dstl.reshape(G, C2, 4096), np.asarray(ref.p2_dstl))
         np.testing.assert_array_equal(p2_obi, np.asarray(ref.p2_obi))
         np.testing.assert_array_equal(p2_first, np.asarray(ref.p2_first))
 
@@ -303,14 +305,14 @@ def test_native_plan_equals_numpy_nondefault_geometry():
             G, C1 = p1_blk.shape
             C2 = p2_obi.shape[1]
             np.testing.assert_array_equal(
-                p1_srcl.reshape(G, C1 * geom.ch, 1),
+                p1_srcl.reshape(G, C1, geom.ch),
                 np.asarray(ref.p1_srcl), err_msg=msg)
             np.testing.assert_array_equal(p1_off, np.asarray(ref.p1_off),
                                           err_msg=msg)
             np.testing.assert_array_equal(p1_blk, np.asarray(ref.p1_blk),
                                           err_msg=msg)
             np.testing.assert_array_equal(
-                p2_dstl.reshape(G, C2 * geom.ch2, 1),
+                p2_dstl.reshape(G, C2, geom.ch2),
                 np.asarray(ref.p2_dstl), err_msg=msg)
             np.testing.assert_array_equal(p2_obi, np.asarray(ref.p2_obi),
                                           err_msg=msg)
@@ -739,11 +741,12 @@ def test_cost_model_reproduces_chip_table():
 
 def test_choose_geometry_memory_admission(monkeypatch):
     """A candidate whose per-group temporaries (staging + the larger
-    lane-padded index operand, from shapes) are over _HBM_GROUP_CAP is
-    skipped as a VMEM-inadmissible one is: at the chip table's own counts
-    GEOM_WIDE (12.69 GiB of peak HBM measured) is out and the default and
-    GEOM_FLAT are in; and on a graph where the wider group prices lowest,
-    lowering the cap between the two candidates' needs moves the pick."""
+    index operand, lane-dense: 4 bytes an index, from shapes) are over
+    _HBM_GROUP_CAP is skipped as a VMEM-inadmissible one is: at the chip
+    table's own counts GEOM_WIDE (5.51 GB of staging a group; 12.69 GiB
+    of peak HBM measured by PR 24) is out and the default and GEOM_FLAT
+    are in; and on a graph where the wider group prices lowest, lowering
+    the cap between the two candidates' needs moves the pick."""
     from roc_tpu.ops.pallas import binned as B
     need = {}
     for row in _chip_table()["rows"]:
@@ -752,7 +755,14 @@ def test_choose_geometry_memory_admission(monkeypatch):
                 B.Geometry(*row["geom"]), row["steps1"], row["steps2"],
                 row["groups"])
     assert need["default"] < need["flat"] < B._HBM_GROUP_CAP < need["wide"]
-    assert 2.7e9 < need["default"] < 2.9e9 and 11e9 < need["wide"] < 11.5e9
+    assert 1.3e9 < need["default"] < 1.4e9 and 5.4e9 < need["wide"] < 5.6e9
+    # the index operand is the plan's own bytes, not 128 lanes a row:
+    # 11.3 MB of the default group's 1.351 GB, where PR 24 counted 1.44 GB
+    row = next(r for r in _chip_table()["rows"]
+               if r["cell"].endswith(".regular") and r["preset"] == "default")
+    g = B.Geometry(*row["geom"])
+    stg = row["steps2"] // row["groups"] * g.ch2 * B._MODEL_H * 2
+    assert need["default"] - stg == row["steps1"] // row["groups"] * g.ch * 4
 
     rng = np.random.default_rng(9)
     n, e = 16384, 32 * 32 * 113
@@ -820,13 +830,19 @@ def test_hybrid_forced_correctness():
     np.testing.assert_allclose(np.asarray(gx), gref, rtol=1e-5, atol=1e-4)
 
 
-def test_choose_geometry_hybrid_arm():
+def test_choose_geometry_hybrid_arm(monkeypatch):
     """The policy's hybrid arm: dust cells well under half a slot next to
-    a heavy hub mass make the split win over both pure binned (dust slot
-    padding) and pure matmul (the hub edges' chunk cost) — restricted to
-    the dense default candidate so the sparse presets can't absorb the
-    dust first.  The returned hub_minc must agree with split_hub_edges."""
+    a heavy hub mass make the split win over pure matmul (the hub edges'
+    chunk cost) by price and over pure binned by memory — the dust's slot
+    padding puts a group's staging (3.2 GiB) over the cap set here, and
+    the split is admitted on its own, smaller, schedule.  (At PR 24's
+    rates the pure schedule prices 8 % under the split; until PR 26 it
+    was the 128-lane index operand that kept it out, under the real cap.)
+    Restricted to the dense default candidate so the sparse presets
+    can't absorb the dust first.  The returned hub_minc must agree with
+    split_hub_edges."""
     from roc_tpu.ops.pallas import binned as B
+    monkeypatch.setattr(B, "_HBM_GROUP_CAP", 2 * (1 << 30))
     rng = np.random.default_rng(2)
     n = 100_000
     g0 = B._default_geom()
@@ -913,3 +929,166 @@ def test_plan_cache_roundtrip(tmp_path, monkeypatch):
 
 from roc_tpu.ops.pallas.binned import \
     _build_binned_plan_numpy as _orig_numpy_builder  # noqa: E402
+
+
+# -- lane-dense index operands (PR 26) --------------------------------------
+
+_FLAT_SMALL = dict(sb=256, ch=512, slot=128, rb=256, ch2=512, grt=1 << 14,
+                   flat=1)
+
+
+@pytest.mark.parametrize("precision", ["fast", "exact"])
+@pytest.mark.parametrize("flat", [0, 1])
+def test_staging_garbage_cannot_reach_the_result(flat, precision):
+    """Phase 1 skips pad slots, so the staging rows behind them hold
+    whatever the buffer held; phase 2 must mask them by the PLAN (its
+    dstl == RB rows) before the dot, where 0 * NaN would be NaN.  Every
+    such row is poisoned with NaN and Inf between the phases: the result
+    stays finite and bit-equal to the unpoisoned run, at both precisions
+    and on both schedules."""
+    from roc_tpu.ops.pallas import binned as B
+    rng = np.random.default_rng(26)
+    n = t = 1500
+    e, h = 9000, 40
+    src = rng.integers(0, t, e).astype(np.int64)
+    dst = rng.integers(0, n, e).astype(np.int64)
+    x = rng.standard_normal((t, h), dtype=np.float32)
+    geom = B.Geometry(**dict(_FLAT_SMALL, grt=1 << 12)) if flat else None
+    plan = build_binned_plan(src, dst, n, t, geom=geom,
+                             group_row_target=1 << 12, tuned_ok=False)
+    geom, exact = plan.geom, precision == "exact"
+    G, C1 = plan.p1_blk.shape
+    C2 = plan.p2_obi.shape[1]
+    assert G > 1 and plan.p2_dstl.shape == (G, C2, geom.ch2)
+    xp = jnp.pad(jnp.asarray(x), ((0, B._pad_to(t, geom.sb) - t),
+                                  (0, 128 - h)))
+    stg_rows, poisoned = C2 * geom.ch2, 0
+    outs = {False: [], True: []}
+    for g in range(G):
+        if flat:
+            stg = B._p1_flat_run(xp, plan.p1_blk[g], plan.p1_blk2[g],
+                                 plan.p1_dsrc[g], plan.p1_ddst[g],
+                                 plan.p1_srcl[g], C1, stg_rows, True, exact,
+                                 geom)
+        else:
+            stg = B._p1_run(xp, plan.p1_blk[g], plan.p1_off[g],
+                            plan.p1_srcl[g], C1, stg_rows, True, exact, geom)
+        pad = np.asarray(plan.p2_dstl[g]).reshape(-1) == geom.rb
+        poisoned += int(pad.sum())
+        bad = np.where(np.arange(stg_rows) % 2, np.nan, np.inf)
+        for poison in (False, True):
+            if poison:
+                stg = jnp.where(pad[:, None],
+                                bad[:, None].astype(stg.dtype), stg)
+            outs[poison].append(B._p2_run(
+                stg, plan.p2_obi[g], plan.p2_first[g], plan.p2_dstl[g], C2,
+                plan.bins_per_group * geom.rb, True, exact, geom))
+    assert poisoned > e // 4            # padding is a real share of rows
+    out = np.asarray(jnp.concatenate(outs[True]))[:n, :h]
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(
+        out, np.asarray(jnp.concatenate(outs[False]))[:n, :h])
+    want = np.zeros((n, h), np.float64)
+    xs = x if exact else np.asarray(
+        jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
+    np.add.at(want, dst, xs.astype(np.float64)[src])
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-4)
+
+
+def _pallas_calls(jaxpr, found=None):
+    """Every pallas_call equation of a jaxpr, sub-jaxprs included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, found)
+    return found
+
+
+def _column_index_operands(jaxpr):
+    """(kernel, operand aval) of every int32 operand a pallas_call reads
+    through VMEM whose last dimension is not whole 128-lane rows: Mosaic
+    tiles such an operand to 128 lanes a row — 512 bytes an index — and
+    XLA re-lays the plan's slice out to that every scan step."""
+    from jax.experimental.pallas import tpu as pltpu
+    bad, kernels = [], []
+    for eqn in _pallas_calls(jaxpr):
+        gm = eqn.params["grid_mapping"]
+        kernels.append(eqn.params["name"]
+                       or eqn.params["jaxpr"].debug_info.func_name)
+        ins = eqn.invars[gm.num_index_operands:][:gm.num_inputs]
+        for var, bm in zip(ins, gm.block_mappings):
+            aval = var.aval
+            if aval.dtype != jnp.int32 or getattr(
+                    bm.block_aval, "memory_space", None) == pltpu.SMEM:
+                continue
+            if aval.ndim == 0 or aval.shape[-1] % 128:
+                bad.append((kernels[-1], str(aval)))
+    return bad, kernels
+
+
+def test_gcn_train_step_has_no_column_index_operand():
+    """No [rows, 1] int32 operand is left in the GCN recipe's train step:
+    every index operand of every kernel is scalar-prefetched, rides SMEM
+    blocks, or is lane-dense.  A later plan field cannot bring the
+    128-lane padding (a third of the Reddit epoch until PR 26) back
+    unseen."""
+    from roc_tpu.graph import datasets
+    from roc_tpu.models import build_gcn
+    from roc_tpu.train.config import Config
+    from roc_tpu.train.driver import Trainer
+
+    ds = datasets.synthetic("binned-lanes", 600, 6.0, 32, 5,
+                            n_train=200, n_val=100, n_test=100, seed=3)
+    cfg = Config(layers=[32, 16, 5], num_epochs=1, dropout_rate=0.5,
+                 eval_every=10 ** 9, aggregate_backend="binned", seed=11)
+    tr = Trainer(cfg, ds, build_gcn(cfg.layers, 0.5))
+    assert tr.gdata.backend == "binned"
+    args = (tr.params, tr.opt_state, tr.x, tr.labels, tr.mask, tr.gdata,
+            jax.random.PRNGKey(0), jnp.float32(cfg.learning_rate),
+            jnp.float32(1.0))
+    bad, kernels = _column_index_operands(
+        jax.make_jaxpr(tr._train_step)(*args).jaxpr)
+    # two layers, forward and backward: four sweeps of both phases
+    assert sum("_p1_kernel" in k for k in kernels) == 4, kernels
+    assert sum("_p2_kernel" in k for k in kernels) == 4, kernels
+    assert not bad, bad
+
+
+def test_column_index_operand_is_seen():
+    """The detector has teeth: the [rows, 1] form this PR removed, fed
+    to a kernel through a (CH, 1) block, is reported; the flat schedule's
+    kernels are clean."""
+    from jax.experimental import pallas as pl
+    from roc_tpu.ops.pallas import binned as B
+
+    def column(idx, x):
+        return pl.pallas_call(
+            lambda i_ref, x_ref, o_ref: o_ref.__setitem__(
+                slice(None), x_ref[:] + i_ref[:].astype(jnp.float32)),
+            grid=(2,),
+            in_specs=[pl.BlockSpec((256, 1), lambda c: (c, 0)),
+                      pl.BlockSpec((256, 128), lambda c: (c, 0))],
+            out_specs=pl.BlockSpec((256, 128), lambda c: (c, 0)),
+            out_shape=jax.ShapeDtypeStruct((512, 128), jnp.float32),
+            interpret=True, name="column_reader")(idx, x)
+
+    bad, _ = _column_index_operands(jax.make_jaxpr(column)(
+        jnp.zeros((512, 1), jnp.int32), jnp.zeros((512, 128))).jaxpr)
+    assert bad == [("column_reader", "int32[512,1]")]
+
+    rng = np.random.default_rng(1)
+    src = rng.integers(0, 900, 6000).astype(np.int64)
+    dst = rng.integers(0, 900, 6000).astype(np.int64)
+    plan = build_binned_plan(src, dst, 900, 900, tuned_ok=False,
+                             geom=B.Geometry(**_FLAT_SMALL))
+    # (the fused family's f_rows is a column still, inside no scan)
+    plan = dataclasses.replace(plan, f_meta=None)
+    bad, kernels = _column_index_operands(jax.make_jaxpr(
+        lambda x, p: run_binned(x, p, interpret=True))(
+            jnp.zeros((900, 16)), plan).jaxpr)
+    assert kernels and not bad, (kernels, bad)

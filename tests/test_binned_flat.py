@@ -265,7 +265,7 @@ def test_native_flat_plan_equals_numpy():
             G, C1 = p1_blk.shape
             C2 = p2_obi.shape[1]
             np.testing.assert_array_equal(
-                p1_srcl.reshape(G, C1 * geom.ch, 1),
+                p1_srcl.reshape(G, C1, geom.ch),
                 np.asarray(ref.p1_srcl), err_msg=msg)
             for f, got in (("p1_blk", p1_blk), ("p1_blk2", p1_blk2),
                            ("p2_obi", p2_obi), ("p2_first", p2_first)):
@@ -278,7 +278,7 @@ def test_native_flat_plan_equals_numpy():
                 p1_ddst.reshape(G, C1, geom.kd), np.asarray(ref.p1_ddst),
                 err_msg=msg)
             np.testing.assert_array_equal(
-                p2_dstl.reshape(G, C2 * geom.ch2, 1),
+                p2_dstl.reshape(G, C2, geom.ch2),
                 np.asarray(ref.p2_dstl), err_msg=msg)
 
 

@@ -159,20 +159,24 @@ def test_surrogate_tuned_entry_ignored_on_tpu(tmp_path, monkeypatch):
 
 # Smallest vmem_limit_bytes (MiB, whole numbers) at which Mosaic compiled
 # each two-pass kernel for a v5e, found by bisection with libtpu's compiler
-# in the sandbox (jax 0.9.0, libtpu 0.0.34, PR 21; plan arrays at the
-# Reddit scale: C1=512, C2=256): (preset, H, exact, phase 1, phase 2).
+# in the sandbox (jax 0.9.0, libtpu 0.0.34; plan arrays at the Reddit
+# scale: C1=512, C2=256): (preset, H, exact, phase 1, phase 2).  Bisected
+# again by PR 26 for the kernels that read lane-dense index rows: phase 1
+# asks for more where it is `exact` (three contractions on dimension 0,
+# each with its turned one-hot), phase 2 for less everywhere (no (CH2, 1)
+# block lane-padded to 2 MB, no transposed left operand).
 _MIN_VMEM_MIB = [
-    ("default", 128, 0, 4, 10), ("default", 128, 1, 8, 17),
-    ("default", 256, 0, 8, 15), ("default", 256, 1, 12, 26),
-    ("default", 512, 0, 11, 22), ("default", 512, 1, 20, 44),
-    ("flat", 128, 0, 13, 12), ("flat", 128, 1, 24, 17),
-    ("flat", 256, 0, 24, 19), ("flat", 256, 1, 34, 26),
-    ("flat", 512, 0, 34, 28), ("flat", 512, 1, 54, 44),
-    ("wide", 256, 0, 15, 28), ("wide", 256, 1, 22, 50),
-    ("sparse", 256, 0, 11, 11), ("sparse", 256, 1, 16, 19),
-    ("xsparse", 256, 0, 11, 13), ("xsparse", 256, 1, 16, 21),
-    ("flat_sparse", 256, 0, 20, 13), ("flat_sparse", 256, 1, 27, 19),
-    ("flat_bf16", 128, 0, 11, 10), ("flat_bf16", 512, 0, 30, 22),
+    ("default", 128, 0, 3, 5), ("default", 128, 1, 9, 12),
+    ("default", 256, 0, 9, 11), ("default", 256, 1, 16, 23),
+    ("default", 512, 0, 13, 18), ("default", 512, 1, 30, 39),
+    ("flat", 128, 0, 6, 7), ("flat", 128, 1, 31, 12),
+    ("flat", 256, 0, 19, 15), ("flat", 256, 1, 53, 23),
+    ("flat", 512, 0, 29, 24), ("flat", 512, 1, 97, 39),
+    ("wide", 256, 0, 18, 21), ("wide", 256, 1, 31, 43),
+    ("sparse", 256, 0, 13, 9), ("sparse", 256, 1, 21, 15),
+    ("xsparse", 256, 0, 12, 10), ("xsparse", 256, 1, 18, 14),
+    ("flat_sparse", 256, 0, 21, 11), ("flat_sparse", 256, 1, 39, 15),
+    ("flat_bf16", 128, 0, 4, 5), ("flat_bf16", 512, 0, 37, 18),
 ]
 
 

@@ -100,6 +100,11 @@ def main(argv) -> int:
         f"{op} x{hlo.count(op)}"
         for op in ("all-to-all", "all-reduce", "all-gather",
                    "collective-permute")))
+    # an int32 operand XLA re-lays out for a kernel (a [rows, 1] column
+    # tiled to 128 lanes a row, as the binned plans' were until PR 26)
+    relaid = re.findall(r"= (s32\[[0-9,]+\])\{[^}]*\} copy\(", hlo)
+    print("# copies of s32 arrays: " + (", ".join(
+        f"{t} x{relaid.count(t)}" for t in sorted(set(relaid))) or "none"))
     print(f"# {compiled.memory_analysis()}")
     return 0
 
