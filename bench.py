@@ -184,36 +184,6 @@ SERVE_QPS = _env("ROC_BENCH_SERVE_QPS", "50.0", float)
 # excluded from vs_baseline — the reference figures are fp32-storage
 # numbers.
 DTYPE = "bf16" if os.environ.get("ROC_BF16_STORAGE") == "1" else "fp32"
-# ROC_MEGAFUSE=1 (likewise the Config.__post_init__ env): whole-layer
-# aggregate->linear megakernel fusion.  Same artifact policy as bf16
-# storage: every artifact is stamped with the fusion level, mega legs
-# annotate the metric and are excluded from vs_baseline — the reference
-# figures are two-pass numbers, and the fused program is a different
-# trace.  Since round 12 the fused VJP is on by default under -megafuse,
-# so the stamp distinguishes
-# "mega+bwd" (forward + fused backward) from "mega" (forward-only:
-# ROC_MEGA_BWD=0 kill switch) — hw_revalidate step 4c's three legs.
-FUSION = "none"
-if os.environ.get("ROC_MEGAFUSE") == "1":
-    FUSION = "mega" if os.environ.get("ROC_MEGA_BWD", "") == "0" \
-        else "mega+bwd"
-    # ROC_FUSION_DEPTH != 1 (round 16, mirrors -fusion-depth): the
-    # cross-layer fusion-region planner is active — stamp the depth
-    # (0 = full-model regions).  xlayer legs inherit the mega artifact
-    # policy: excluded from vs_baseline until a chip run confirms
-    # (hw_revalidate step 4d's three legs).
-    _FDEPTH = os.environ.get("ROC_FUSION_DEPTH", "1")
-    if _FDEPTH != "1":
-        FUSION = f"xlayer-{int(_FDEPTH)}"
-    # Fused GAT attention (round 19): -megafuse on an attention model also
-    # engages the per-head score->softmax->aggregate megakernel, so the leg
-    # is stamped "gat" — a different trace again from "mega"/"xlayer" (the
-    # edge softmax rides inside the binned grid).  ROC_NO_GATFUSE declines
-    # back to the plain mega stamp.  gat legs inherit the mega artifact
-    # policy: metric annotated, excluded from vs_baseline until
-    # hw_revalidate step 4e's A/B confirms on a device.
-    if MODEL == "gat" and not os.environ.get("ROC_NO_GATFUSE"):
-        FUSION = "gat"
 # The canonical metric (the one vs_baseline speaks to) is the unmodified
 # Reddit shape; shape overrides annotate the metric name so histories are
 # never conflated.
@@ -232,7 +202,6 @@ METRIC = (f"{MODEL}_{SHAPE}{'-'.join(map(str, LAYERS))}"
           + ("" if BALANCE_EVERY == 0 else f"_balance{BALANCE_EVERY}")
           + ("" if MEM_PLAN == "keep" else f"_mem-{MEM_PLAN}")
           + ("" if DTYPE == "fp32" else f"_{DTYPE}")
-          + ("" if FUSION == "none" else f"_{FUSION}")
           + ("" if not STREAM else f"_stream{STREAM_SLOTS}")
           + ("" if not (STREAM and STREAM_SPILL) else "_spill")
           + ("" if not SERVE else "_serve"))
@@ -445,11 +414,10 @@ def run():
         "vs_baseline": round(REF_EPOCH_S / epoch_s, 3)
         if MODEL == "gcn" and CANONICAL_SHAPE and REORDER == "off"
         and BALANCE_EVERY == 0 and MEM_PLAN == "keep"
-        and DTYPE == "fp32" and FUSION == "none" and not STREAM
+        and DTYPE == "fp32" and not STREAM
         and not SERVE else None,
         "backend": resolved,                   # what auto resolved to
         "dtype": DTYPE,                        # feature-storage dtype
-        "fusion": FUSION,                      # layer-fusion level
         "platform": dev["platform"],
         "device": dev,
         "edges_per_sec_per_chip": round(edges_per_sec_per_chip),
@@ -527,32 +495,6 @@ def run():
                 "step_delta_vs_remat": round(
                     plan.predicted_step_s / remat.predicted_step_s - 1, 4),
             }
-            if FUSION == "mega+bwd":
-                # predicted backward-intermediate HBM the fused VJP skips
-                # (the [rows, H_in] cotangent round trip per fused layer)
-                from roc_tpu.memory.estimator import mega_bwd_cotangent_drop
-                mem["mega_bwd_cotangent_drop_bytes"] = \
-                    mega_bwd_cotangent_drop(trainer.model, est.rows)
-            elif FUSION == "gat":
-                # predicted residual HBM the fused GAT forward never
-                # materializes (edge-width alpha + qpos planes, net of the
-                # node-width m/z planes the kernel keeps for its backward)
-                from roc_tpu.memory.estimator import gat_residual_drop
-                mem["gat_residual_drop_bytes"] = \
-                    gat_residual_drop(trainer.model, est.rows, est.edges)
-            elif FUSION.startswith("xlayer-"):
-                # cross-layer legs: the region planner's predicted
-                # train-step HBM claim, stamped so hw_revalidate step 4d
-                # can compare against hardware counters
-                from roc_tpu.models.model import mega_regions
-                from roc_tpu.ops.pallas import binned as B
-                regs = mega_regions(trainer.model,
-                                    int(FUSION.split("-", 1)[1]))
-                mem["xlayer_trainstep_hbm_bytes"] = sum(  # roclint: allow(unledgered-prediction) — sum of ledgered per-region estimates stamped into the artifact
-                    B.predicted_xlayer_trainstep_hbm_bytes(
-                        est.rows,
-                        r["members"][0]["linear"].attrs["out_dim"],
-                        len(r["members"])) for r in regs.values())
         if plan is not None and plan.any_offload():
             # bench legs must not claim host offload before the streaming
             # executor is the one running: an OFFLOAD verdict lowered by the

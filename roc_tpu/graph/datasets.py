@@ -162,11 +162,6 @@ _REGISTRY = {
     # budgets.json entries from the CLI (budgets are shape-keyed; seed
     # doesn't affect the lowered program)
     "roc-audit":    (96,      4.0, 8,     4,    48,   24,   24),
-    # megakernel A/B shape (tools/hw_revalidate.sh step 4c): one bin, one
-    # block at GEOM_FLAT, so the fused aggregate->linear schedule attaches
-    # AND clears the kernel's trace-time VMEM gate at H<=128 in fp32
-    # (C2=1); sized like one greedy-cut shard of a medium graph
-    "mega-shard":   (448,     4.0, 64,    8,   128,   64,   64),
 }
 
 

@@ -113,46 +113,14 @@ def _op_forward_s(op, in_dim: int, out_dim: int, rows: int,
 
 
 def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
-                   fixed_bytes: int = 0,
-                   megafuse: bool = False, fusion_depth: int = 1,
-                   halo_rows: int = 0) -> ModelEstimate:
+                   fixed_bytes: int = 0) -> ModelEstimate:
     """Per-layer byte/recompute estimates for ``model`` at a per-device
     shard of ``rows`` node rows and ``edges`` edges.
 
     ``itemsize`` is the activation element width (4 for fp32, 2 for bf16);
     ``fixed_bytes`` is the plan-independent resident set (params, optimizer
     state, placed node tensors) the caller already knows.
-
-    ``megafuse=True`` applies the whole-layer megakernel's tensor
-    elimination: every ``mega_matches`` record names the output tensors
-    that never materialize under fusion in its ``gone`` tuple — the
-    aggregate's output (and the linear's, when a trailing relu folds in)
-    for the direct chain; the linear's, aggregate's, and second norm's
-    for the norm-folded GCN chain (the first norm's output stays counted
-    as the proxy for the pre-scaled input the folded path materializes
-    instead).  Those contribute zero to ``bytes_full``/``bytes_saved``
-    and the DP plans over the fused layer's real residual set.
-
-    ``fusion_depth != 1`` (with megafuse) additionally applies the
-    round-16 fusion REGION's kept/dropped tuple: ``mega_regions`` names
-    the inter-layer boundary tensors the cross-layer grid keeps in VMEM
-    for shard-local rows.  Those are NOT free — the halo frontier's
-    rows still round-trip HBM between layers (parallel/halo.py exchange
-    contract) — so they are priced at ``halo_rows`` rows instead of the
-    full shard (zero on a single device, where every row is local).
     """
-    fused_gone: set = set()
-    frontier_gone: set = set()
-    if megafuse:
-        from roc_tpu.models.model import mega_matches, mega_regions
-        for rec in mega_matches(model).values():
-            fused_gone.update(rec["gone"])
-        if fusion_depth != 1:
-            for reg in mega_regions(model, fusion_depth).values():
-                # region-dropped minus per-layer-dropped = the inter-layer
-                # boundaries the region ALSO eliminates; halo rows survive
-                frontier_gone.update(
-                    t for t in reg["gone"] if t not in fused_gone)
     dims = _op_out_dims(model)
     per_layer: Dict[int, List] = {}
     for op in model.ops:
@@ -162,18 +130,10 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
     for idx in sorted(per_layer):
         full = saved = boundary = 0
         fwd = cheap = 0.0
-        saw_boundary = False
         for op in per_layer[idx]:
             in_dim = dims[op.inputs[0]]
             out_dim = dims[op.out]
-            if op.out in fused_gone:
-                out_bytes = 0
-            elif op.out in frontier_gone:
-                # inter-layer boundary inside a fusion region: only the
-                # halo frontier's rows materialize (kept/dropped honesty)
-                out_bytes = halo_rows * out_dim * itemsize
-            else:
-                out_bytes = rows * out_dim * itemsize
+            out_bytes = rows * out_dim * itemsize
             t = _op_forward_s(op, in_dim, out_dim, rows, edges)
             full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize)
             fwd += t
@@ -183,11 +143,7 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
                 cheap += t
             if op.attrs.get("ckpt_boundary"):
                 boundary = out_bytes
-                saw_boundary = True
-        # fallback only when the layer has NO tagged boundary op: a tagged
-        # boundary that priced to 0 is a region-interior tensor the fused
-        # grid keeps in VMEM — re-pricing it full would undo the honesty
-        if not saw_boundary and per_layer[idx]:
+        if not boundary and per_layer[idx]:
             last = per_layer[idx][-1]
             boundary = rows * dims[last.out] * itemsize
         layers.append(LayerEstimate(
@@ -215,38 +171,6 @@ def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     if op.kind != "gat":
         return 0
     return int(op.attrs["heads"]) * int(edges) * (itemsize + 1)
-
-
-def mega_bwd_cotangent_drop(model, rows: int, itemsize: int = 4) -> int:
-    """Predicted backward-intermediate HBM bytes the fused megakernel
-    BACKWARD eliminates: per ``mega_matches`` layer, the ``[rows, H_in]``
-    aggregation cotangent (dL/dagg = g @ W^T) no longer round-trips HBM —
-    one write + one read each (see ``binned.predicted_trainstep_hbm_bytes``
-    for the full train-step accounting this slots into).  bench.py reports
-    this in the mem artifact block on fused-backward legs."""
-    from roc_tpu.models.model import mega_matches
-    total = 0
-    for rec in mega_matches(model).values():
-        total += 2 * rows * rec["linear"].attrs["in_dim"] * itemsize
-    return total
-
-
-def gat_residual_drop(model, rows: int, edges: int,
-                      itemsize: int = 4) -> int:
-    """Predicted residual HBM bytes the fused GAT attention kernel
-    (round 19, ops/pallas/gat.py) eliminates: per gat layer the unfused
-    oracle's VJP saves per-EDGE softmax residuals — the normalized
-    exponentials ``e [E,K]`` fp32 and the leaky-relu sign ``qpos [E,K]``
-    bool — while the fused path keeps per-NODE max/normalizer planes
-    (2 × [rows, K] fp32) instead, pricing the edge-width alpha/gather
-    intermediates at 0.  Reported in bench.py's mem artifact block on
-    fused-attention legs, next to ``mega_bwd_cotangent_drop``."""
-    from roc_tpu.models.model import gat_matches
-    total = 0
-    for rec in gat_matches(model).values():
-        total += (gat_edge_residual_bytes(rec["gat"], edges, itemsize)
-                  - 2 * rows * rec["heads"] * 4)
-    return max(total, 0)
 
 
 def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
@@ -290,21 +214,8 @@ def estimate_for_trainer(trainer) -> ModelEstimate:
         devices = max(int(trainer.config.num_parts) // max(k, 1), 1)
         fixed += sum(int(a.size) * a.dtype.itemsize
                      for a in jax.tree.leaves(gat_plans)) // devices
-    # halo frontier (round 16): rows other shards reference still
-    # round-trip HBM at fused region boundaries — the received halo
-    # block is [P*K] rows per device in halo-exchange mode; 0 on a
-    # single device / allgather mode (where the region drop is total)
-    halo = getattr(trainer, "halo", None)
-    halo_rows = 0
-    if halo is not None and part is not None:
-        halo_rows = int(part.num_parts) * int(halo.K)
     return estimate_model(trainer.model, rows, edges, itemsize=itemsize,
-                          fixed_bytes=fixed,
-                          megafuse=getattr(trainer.config, "megafuse",
-                                           False),
-                          fusion_depth=getattr(trainer.config,
-                                               "fusion_depth", 1),
-                          halo_rows=halo_rows)
+                          fixed_bytes=fixed)
 
 
 # -- XLA cross-checks (analysis/hlo_audit.py lowering machinery) ----------

@@ -14,12 +14,6 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
     because the GIN update is defined on sums).  heads only applies to gat."""
     if name == "gcn":
         return build_gcn(layers, dropout_rate, aggr or "sum")
-    if name == "gcn-chain":
-        # residual-free deep GCN: every hidden layer's boundary is the
-        # plain activation tensor, so the round-16 fusion-region planner
-        # can chain the whole stack (build_gcn docstring)
-        return build_gcn(layers, dropout_rate, aggr or "sum",
-                         residual=False)
     if name == "sage":
         return build_sage(layers, dropout_rate, aggr or "avg")
     if name == "gin":

@@ -22,16 +22,8 @@ from roc_tpu.models.model import Model
 
 
 def build_gcn(layers: Sequence[int], dropout_rate: float = 0.5,
-              aggr: str = "sum", residual: bool = True) -> Model:
-    """layers = [in_dim, hidden..., num_classes] — the CLI's `-layers` spec.
-
-    ``residual=False`` builds the reference's shallow-GCN recipe at any
-    depth (no projected skip path).  The deep-GCN residual ``add``
-    consumes each layer's boundary tensor alongside the projection, so it
-    pins that boundary in HBM and stops the round-16 fusion-region
-    planner at every layer — a residual-free stack is the norm-folded
-    chain ``mega_regions`` can fuse end to end.
-    """
+              aggr: str = "sum") -> Model:
+    """layers = [in_dim, hidden..., num_classes] — the CLI's `-layers` spec."""
     assert len(layers) >= 2
     model = Model(in_dim=layers[0])
     t = model.input
@@ -44,7 +36,7 @@ def build_gcn(layers: Sequence[int], dropout_rate: float = 0.5,
         t = model.indegree_norm(t)
         if i != len(layers) - 1:
             t = model.relu(t)
-        if residual and len(layers) > 3:
+        if len(layers) > 3:
             proj = model.linear(residual_in, t.dim)
             t = model.add(t, proj)
         model.end_layer()

@@ -51,27 +51,6 @@ class GraphCtx(NamedTuple):
     # normalised coefficients are dropped per edge and head
     # (ops.edge.attention_keep).
     attend: Optional[Callable] = None
-    # whole-layer megakernel hook:
-    # (x, w, activation, aggr, fold) -> out or None.
-    # When set, `apply` offers each `mega_matches`-eligible chain to it —
-    # aggregate→linear(→relu) directly, or the norm-folded GCN shape when
-    # fold=True (the hook owns the D^-1/2 pre/post scales); a None return
-    # means "not fusable here" (VMEM gate, hybrid plan, kill switch) and
-    # the unfused op sequence runs unchanged.  Default None keeps every
-    # existing program byte-identical — the HLO budget audit pins that.
-    fuse_linear: Optional[Callable] = None
-    # cross-layer fusion-region hook (round 16):
-    # (x, ws, activations, fold) -> out or None.
-    # When set AND fusion_depth != 1, `apply` offers each
-    # `mega_regions`-eligible multi-layer chain (the region's weight and
-    # activation tuples, head to tail) to it before the per-layer
-    # fuse_linear pass; a None return declines the whole region and the
-    # per-layer matches run unchanged — byte-identical to fusion_depth=1.
-    fuse_region: Optional[Callable] = None
-    # static region-length cap keying the step cache: 1 = off (default,
-    # byte-identical to pre-round-16 programs), 2 = chains of exactly two
-    # layers, 0 = unlimited ("full").
-    fusion_depth: int = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,111 +68,6 @@ class OpNode:
     attrs: dict               # op-specific attributes
 
 
-def mega_matches(model: "Model") -> Dict[int, dict]:
-    """Find megakernel-eligible layer chains in the static op IR.
-
-    Two shapes match.  The direct ``aggregate → linear (→ relu)`` chain
-    (GIN/SAGE) is keyed by the AGGREGATE's op index.  The GCN chain
-    ``linear → norm → aggregate → norm (→ relu)`` is keyed by the
-    LINEAR's op index and carries ``fold=True`` (round 12, norm-folding):
-    since ``indegree_norm`` is a positive diagonal row-scale,
-    D^-½ A D^-½ (xW) = D^-½ · A · ((D^-½ x) W) — the hook pre-scales the
-    layer input, runs the same fused aggregate→linear kernel, and
-    post-scales; relu commutes with the positive scale, so the in-kernel
-    epilogue still applies (bitwise: relu(c·v) = c·relu(v) picks the
-    identical product).  Note the folded forward reassociates the scale
-    through the GEMM — logits parity vs unfused is ≤1e-3-tight, not
-    bitwise (tests/test_mega_bwd.py pins 3-epoch parity).
-
-    Each record carries the matched ``aggregate``/``linear`` nodes, the
-    resolved activation ("none"/"relu"), ``final`` (the node whose output
-    tensor and ckpt tag the fused op takes over), the op indices to
-    ``skip`` when fusion succeeds, ``fold``, and ``gone`` — the output
-    tensor ids that never materialize under fusion (the memory
-    estimator's accounting input).  Folded ``gone`` excludes the first
-    norm's output deliberately: the hook materializes the pre-scaled
-    input z = D^-½ x at exactly that shape, so dropping it would
-    overstate the win.
-
-    Eligibility — all structural: every intermediate feeds exactly one
-    op, the whole chain sits in one builder layer (fusion never crosses
-    an ``end_layer`` checkpoint boundary), the aggregate is sum or avg,
-    the linear's own activation is none or relu (none for the folded
-    shape — GCN's recipe never fuses one), a trailing single-consumer
-    relu folds into the epilogue, and no interior intermediate is the
-    logits tensor.
-    """
-    consumers: Dict[int, List[int]] = {}
-    for i, op in enumerate(model.ops):
-        for t in op.inputs:
-            consumers.setdefault(t, []).append(i)
-    logits_id = model.logits.id if model.logits is not None else -1
-
-    def sole(out_id, layer):
-        """The single same-layer consumer of tensor ``out_id``, or None."""
-        cons = consumers.get(out_id, [])
-        if len(cons) != 1:
-            return None, -1
-        nxt = model.ops[cons[0]]
-        if nxt.attrs.get("layer") != layer:
-            return None, -1
-        return nxt, cons[0]
-
-    found: Dict[int, dict] = {}
-    for i, op in enumerate(model.ops):
-        if op.kind != "aggregate" or op.attrs.get("aggr") not in ("sum",
-                                                                  "avg"):
-            continue
-        if op.out == logits_id:
-            continue
-        layer = op.attrs.get("layer")
-        lin, li = sole(op.out, layer)
-        if (lin is None or lin.kind != "linear"
-                or lin.attrs.get("activation") not in ("none", "relu")):
-            continue
-        activation, skip, final = lin.attrs["activation"], [li], lin
-        if activation == "none" and lin.out != logits_id:
-            nxt, ni = sole(lin.out, layer)
-            if (nxt is not None and nxt.kind == "activation"
-                    and nxt.attrs.get("mode") == "relu"):
-                activation, final = "relu", nxt
-                skip.append(ni)
-        found[i] = {"aggregate": op, "linear": lin,
-                    "activation": activation, "final": final,
-                    "skip": tuple(skip), "fold": False,
-                    "gone": (op.out,) + ((lin.out,)
-                                         if final is not lin else ())}
-    for i, op in enumerate(model.ops):
-        if (op.kind != "linear" or op.attrs.get("activation") != "none"
-                or op.out == logits_id):
-            continue
-        layer = op.attrs.get("layer")
-        n1, i1 = sole(op.out, layer)
-        if n1 is None or n1.kind != "norm" or n1.out == logits_id:
-            continue
-        agg, ia = sole(n1.out, layer)
-        if (agg is None or agg.kind != "aggregate"
-                or agg.attrs.get("aggr") not in ("sum", "avg")
-                or agg.out == logits_id):
-            continue
-        n2, i2 = sole(agg.out, layer)
-        if n2 is None or n2.kind != "norm":
-            continue
-        activation, skip, final = "none", [i1, ia, i2], n2
-        if n2.out != logits_id:
-            nxt, ni = sole(n2.out, layer)
-            if (nxt is not None and nxt.kind == "activation"
-                    and nxt.attrs.get("mode") == "relu"):
-                activation, final = "relu", nxt
-                skip.append(ni)
-        found[i] = {"aggregate": agg, "linear": op,
-                    "activation": activation, "final": final,
-                    "skip": tuple(skip), "fold": True,
-                    "gone": (op.out, agg.out) + ((n2.out,)
-                                                 if final is not n2 else ())}
-    return found
-
-
 def attention_drop(op: "OpNode", key, train: bool):
     """The ``drop`` argument of ``GraphCtx.attend`` for one gat op in one
     step: (the step's key folded with the op's dropout slot, the rate) in
@@ -205,152 +79,6 @@ def attention_drop(op: "OpNode", key, train: bool):
         return None
     assert key is not None, "training attention dropout needs a PRNG key"
     return jax.random.fold_in(key, op.attrs["slot"]), rate
-
-
-def gat_matches(model: "Model") -> Dict[int, dict]:
-    """``gat`` ops by op index — the round-19 fused-attention accounting
-    map (ops/pallas/gat.py).
-
-    Deliberately SEPARATE from ``mega_matches``: those records feed
-    ``fuse_linear`` dispatch and ``mega_bwd_cotangent_drop``, and each
-    carries an ``aggregate``+``linear`` pair — a gat record has neither,
-    so joining the same dict would crash every consumer.  The attention
-    megakernel also declines to chain into the trailing concat→linear:
-    the fused grid emits the gat output as head-stacked lane planes
-    ``[rows, heads·head_dim]`` while the next layer's linear consumes
-    row-major feature tiles, so an in-VMEM hand-off would need a
-    cross-lane transpose pass costing more than the HBM round trip it
-    saves.  Fusion dispatch happens inside the ``gat_attend_binned``
-    custom_vjp instead (trace-time decline ladder, ops/edge.py); this map
-    only drives the memory estimator's residual pricing.
-    """
-    found: Dict[int, dict] = {}
-    for i, op in enumerate(model.ops):
-        if op.kind == "gat":
-            found[i] = {"gat": op, "heads": int(op.attrs["heads"]),
-                        "head_dim": int(op.attrs["head_dim"])}
-    return found
-
-
-def mega_regions(model: "Model", max_depth: int = 0,
-                 train: bool = False) -> Dict[int, dict]:
-    """Chain ``mega_matches`` records into multi-layer fusion regions
-    (round 16): aggregate→linear(→relu)→aggregate→linear…, keyed by the
-    FIRST member's head-op index (the same index `apply` dispatches on,
-    so a declined region falls through to that member's per-layer match
-    byte-identically).
-
-    A chain link exists when member l's ``final`` output reaches member
-    l+1's head op through identity interstitials only — each hop single-
-    consumer, and the only interstitial kind admitted is a dropout that
-    is the identity (rate == 0.0, or eval mode).  Eligibility beyond the
-    per-member ``mega_matches`` gates: every member aggregates with
-    ``sum`` (avg's divide-by-degree runs outside the kernel and would
-    break the in-VMEM hand-off), ``fold`` is uniform across members (the
-    kernel applies one boundary epilogue shape), and no member's
-    ``final`` output is the logits tensor — the classifier layer never
-    fuses into a region, because its output must exist in HBM for the
-    loss anyway, so fusing it saves nothing and would force the region
-    backward to start from a softmax cotangent the kernel cannot see.
-
-    ``max_depth`` is the static region-length cap from
-    ``GraphCtx.fusion_depth``: 1 disables chaining entirely (returns {}),
-    2 caps chains at two members, 0 means unlimited.  Chains are maximal
-    under the cap and greedy from the earliest head, so the partition of
-    matches into regions is deterministic — tools/preflight.sh pins the
-    region plan JSON byte-identical across runs.
-
-    Each record carries ``members`` (the ordered per-layer match
-    records), ``final`` (the last member's final node, whose output
-    tensor and ckpt tag the fused region takes over), ``skip`` (every op
-    index the region replaces except the dispatch head), ``fold``, and
-    ``gone`` — the members' per-layer ``gone`` tensors plus the interior
-    members' final outputs and interstitial outputs, i.e. exactly the
-    inter-layer boundaries that never materialize in HBM (the memory
-    estimator's kept/dropped input; the region INPUT and OUTPUT survive).
-    """
-    if max_depth == 1:
-        return {}
-    matches = mega_matches(model)
-    if not matches:
-        return {}
-    consumers: Dict[int, List[int]] = {}
-    for i, op in enumerate(model.ops):
-        for t in op.inputs:
-            consumers.setdefault(t, []).append(i)
-    logits_id = model.logits.id if model.logits is not None else -1
-
-    def eligible(m):
-        return (m["aggregate"].attrs.get("aggr") == "sum"
-                and m["final"].out != logits_id)
-
-    # next-link map: match head index -> (next head index, interstitial
-    # op indices, interstitial output tensor ids)
-    nxt: Dict[int, tuple] = {}
-    for i, m in matches.items():
-        if not eligible(m):
-            continue
-        tid, inter_ops, inter_outs = m["final"].out, [], []
-        while True:
-            cons = consumers.get(tid, [])
-            if len(cons) != 1:
-                break
-            ci = cons[0]
-            op = model.ops[ci]
-            if op.inputs[0] != tid:
-                break
-            if ci in matches and eligible(matches[ci]):
-                nxt[i] = (ci, tuple(inter_ops), tuple(inter_outs))
-                break
-            if op.kind == "dropout" and (op.attrs.get("rate") == 0.0
-                                         or not train):
-                inter_ops.append(ci)
-                inter_outs.append(op.out)
-                tid = op.out
-                continue
-            break
-
-    # greedy maximal chains in ascending head order: links only run
-    # forward in the (topologically ordered) op list, so by the time a
-    # head is visited its predecessor — if any — has been consumed, and
-    # a capped chain's tail starts its own region deterministically
-    preds: Dict[int, int] = {}
-    for i, (j, _, _) in nxt.items():
-        preds[j] = i
-    found: Dict[int, dict] = {}
-    used: set = set()
-    for h in sorted(set(nxt) | set(preds)):
-        if h in used:
-            continue
-        p = preds.get(h)
-        if p is not None and p not in used:
-            continue
-        fold = matches[h]["fold"]
-        chain, i = [h], h
-        while i in nxt and (max_depth == 0 or len(chain) < max_depth):
-            j, _, _ = nxt[i]
-            if j in used or matches[j]["fold"] != fold:
-                break
-            chain.append(j)
-            i = j
-        used.update(chain)
-        if len(chain) < 2:
-            continue
-        members = tuple(matches[k] for k in chain)
-        skip: List[int] = list(members[0]["skip"])
-        gone: List[int] = list(members[0]["gone"])
-        for k_prev, k in zip(chain, chain[1:]):
-            _, inter_ops, inter_outs = nxt[k_prev]
-            skip.extend(inter_ops)
-            gone.extend(inter_outs)
-            gone.append(matches[k_prev]["final"].out)
-            skip.append(k)
-            skip.extend(matches[k]["skip"])
-            gone.extend(matches[k]["gone"])
-        found[h] = {"members": members, "final": members[-1]["final"],
-                    "fold": fold, "skip": tuple(skip),
-                    "gone": tuple(dict.fromkeys(gone))}
-    return found
 
 
 class Model:
@@ -529,44 +257,8 @@ class Model:
         residuals.  Off by default: untagged programs are byte-identical to
         the pre-planner ones, which the HLO budget audit pins."""
         vals: Dict[int, jnp.ndarray] = {0: x}
-        matches = mega_matches(self) if gctx.fuse_linear is not None else {}
-        regions = (mega_regions(self, gctx.fusion_depth, train)
-                   if gctx.fuse_region is not None
-                   and gctx.fusion_depth != 1 else {})
-        skipped: set = set()
-        for idx, op in enumerate(self.ops):
-            if idx in skipped:
-                continue
+        for op in self.ops:
             a = vals[op.inputs[0]]
-            if idx in regions:
-                r = regions[idx]
-                fused = gctx.fuse_region(
-                    a, tuple(params[m["linear"].attrs["param"]]
-                             for m in r["members"]),
-                    tuple(m["activation"] for m in r["members"]),
-                    r["fold"])
-                if fused is not None:
-                    if ckpt_names:
-                        fused = _checkpoint_name(fused,
-                                                 r["final"].attrs["ckpt"])
-                    vals[r["final"].out] = fused
-                    skipped.update(r["skip"])
-                    continue
-                # declined region: fall through to the per-layer match at
-                # this same index — byte-identical to fusion_depth=1
-            if idx in matches:
-                m = matches[idx]
-                fused = gctx.fuse_linear(
-                    a, params[m["linear"].attrs["param"]],
-                    m["activation"], m["aggregate"].attrs["aggr"],
-                    m["fold"])
-                if fused is not None:
-                    if ckpt_names:
-                        fused = _checkpoint_name(fused,
-                                                 m["final"].attrs["ckpt"])
-                    vals[m["final"].out] = fused
-                    skipped.update(m["skip"])
-                    continue
             if op.kind == "dropout":
                 if train:
                     assert key is not None, "training dropout needs a PRNG key"

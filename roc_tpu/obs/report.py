@@ -88,7 +88,7 @@ def summarize_metrics(records: List[dict]) -> List[str]:
         if r.get("type") == "attention":
             lines.append(
                 f"# attention: backend={r.get('backend', '?')} "
-                f"gat_fused={r.get('fused', '?')} gat_plan_pad_ratio="
+                f"gat_plan_pad_ratio="
                 f"{r.get('gat_plan_pad_ratio', 0):.4f} gat_score_bytes="
                 f"{r.get('gat_score_bytes', 0)}")
     for r in trains:

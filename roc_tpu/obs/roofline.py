@@ -70,7 +70,7 @@ def peaks_for(device_kind: str) -> Peaks:
 
 
 # Planning constants for the host-side cost models (memory estimator
-# recompute prices, the binned fuse_linear credit, the serve p50 bound):
+# recompute prices, the serve p50 bound):
 # those price a v5e ahead of time, on any host.  Measured figures go
 # through mfu()/roofline_frac() with the device's own kind instead.
 PEAK_FLOPS, PEAK_BW = PEAKS["TPU v5 lite"][:2]

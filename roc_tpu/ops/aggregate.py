@@ -344,8 +344,7 @@ class BinnedPlans(NamedTuple):
 def build_binned_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
                        num_rows: int, table_rows: int,
                        geom=None,
-                       storage_dtype: str = "fp32",
-                       fuse_linear: bool = False) -> BinnedPlans:
+                       storage_dtype: str = "fp32") -> BinnedPlans:
     """Schedules for out = A@x (fwd) and grad_x = A^T@grad (bwd) — the bwd
     plan swaps roles exactly as the reference re-launches its forward
     kernel transposed (scattergather_kernel.cu:160-170).
@@ -355,22 +354,15 @@ def build_binned_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
     cell statistics (the directions transpose, so a directed graph can
     legitimately want different windows each way), falling back to the
     default where the model prefers matmul (the caller already chose
-    binned).  A (fwd_spec, bwd_spec) pair sets each direction separately —
-    resolve_backend_geom threads its already-chosen forward Geometry this
-    way so the O(E) statistics aren't recomputed.
+    binned).  A (fwd_spec, bwd_spec) pair sets each direction separately.
 
     A forward geometry with ``hub_minc`` set (choose_geometry's hybrid
     verdict, or an explicit caller) splits the edges: the binned pair
     covers only the dense-cell edges and ``mm`` carries the rest.
 
-    ``fuse_linear`` applies the megakernel's layer-handoff pricing to BOTH
-    directions' auto-choice (round 12): the backward plan now carries the
-    fused-backward schedule (u = A^T g and dx = u @ W^T in one grid), so
-    its round-trip credit prices the same way the forward's does.
-
     ROC_BINNED_GEOM=<preset name> (binned.GEOM_PRESETS) overrides the
     forward auto-choice for hardware A/B runs that must isolate one
-    variable (tools/hw_revalidate.sh step 4c).  A forced preset builds
+    variable.  A forced preset builds
     with ``tuned_ok=False``: an A/B run must get exactly the geometry it
     named even when the tuned tier disagrees (round 12)."""
     import os
@@ -384,20 +376,19 @@ def build_binned_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
     else:
         fwd_spec, bwd_spec = geom, geom
 
-    def pick(spec, src, dst, n, t, fuse=False, forced=""):
+    def pick(spec, src, dst, n, t, forced=""):
         if spec != "auto":
             return spec
         if forced:
             return GEOM_PRESETS[forced]
         with _obs_span("choose_geometry", edges=len(src)):
             g, _ = choose_geometry(src, dst, n, t, force=True,
-                                   storage_dtype=storage_dtype,
-                                   fuse_linear=fuse)
+                                   storage_dtype=storage_dtype)
         return g or _default_geom()
 
     forced_env = os.environ.get("ROC_BINNED_GEOM", "")
     fwd_geom = pick(fwd_spec, edge_src, edge_dst, num_rows, table_rows,
-                    fuse=fuse_linear, forced=forced_env)
+                    forced=forced_env)
     es, ed = np.asarray(edge_src), np.asarray(edge_dst)
     mm = None
     if getattr(fwd_geom, "hub_minc", 0):
@@ -408,7 +399,7 @@ def build_binned_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
             mm = build_aggregate_plans(ts[o], td[o], num_rows, table_rows)
             es, ed = es[keep], ed[keep]
     bwd_geom = pick(bwd_spec, ed, es, table_rows, num_rows,
-                    fuse=fuse_linear, forced=forced_env)
+                    forced=forced_env)
     if getattr(bwd_geom, "hub_minc", 0):
         # the split happened (once) on the forward cells; the bwd binned
         # plan covers exactly the transposed dense edges
@@ -513,166 +504,3 @@ def _bn_bwd(interpret, precision, plans, g):
 
 
 scatter_gather_binned.defvjp(_bn_fwd, _bn_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Whole-layer megakernel (round 10): aggregate -> linear (-> ReLU) fused
-# into one Pallas grid — see roc_tpu/ops/pallas/binned.py run_binned_linear.
-# ---------------------------------------------------------------------------
-
-def _unfused_layer(x, w, plans, interpret, precision, activation):
-    """The two-pass reference composition the megakernel must match:
-    binned sum-aggregation, then ops.linear (fp32 `highest` matmul +
-    activation).  Forward oracle for parity tests AND the backward's
-    recompute target."""
-    from roc_tpu.ops.linear import linear
-    return linear(scatter_gather_binned(x, plans, interpret, precision),
-                  w, activation)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def scatter_gather_linear_binned(x, w, plans: BinnedPlans,
-                                 interpret: bool = False,
-                                 precision: str = "fast",
-                                 activation: str = "none"):
-    """linear(sum-aggregate(x), w)[, ReLU] through the megakernel when the
-    plan's fused schedule and the VMEM gate allow it, else the identical
-    two-pass composition.  Differentiable w.r.t. x and w.
-
-    Backward (round 12) fuses too when ``run_binned_linear_bwd`` admits
-    the transposed plan: one Pallas grid computes u = A^T(g * relu_mask)
-    and dx = u @ W^T, so the ``[rows, H]`` aggregation cotangent never
-    round-trips HBM, and dW = x^T u finishes as a single XLA GEMM (no
-    forward recompute: (Ax)^T g = x^T A^T g).  When the fused backward
-    declines (VMEM gate, non-flat bwd geometry, ROC_MEGA_BWD=0), the VJP
-    replays ``scatter_gather_binned`` -> ``ops.linear`` under jax.vjp —
-    byte-identical to the gradient program the unfused layer would have
-    run, and the bitwise oracle the fused path is tested against on
-    integer data (tests/test_mega_bwd.py; fp32 reassociates within a
-    documented ULP bound).  Hybrid plans (plans.mm) are not eligible:
-    their matmul side adds outside the kernel, so callers route those
-    through the unfused ops."""
-    from roc_tpu.ops.pallas.binned import run_binned_linear
-    assert plans.mm is None, \
-        "megakernel fusion requires a pure binned plan (no hybrid side)"
-    return run_binned_linear(x, w, plans.fwd, interpret, precision,
-                             activation)
-
-
-def _bnl_fwd(x, w, plans, interpret, precision, activation):
-    out = scatter_gather_linear_binned(
-        x, w, plans, interpret, precision, activation)
-    # the saved output is the relu-mask source for the fused backward;
-    # for activation="none" it rides the residuals unused (same buffer
-    # the caller holds anyway — no extra liveness)
-    return out, (x, w, plans, out)
-
-
-def _bnl_bwd(interpret, precision, activation, res, g):
-    x, w, plans, out = res
-    from roc_tpu.ops.pallas.binned import run_binned_linear_bwd
-    fused = run_binned_linear_bwd(g, out, w, plans.bwd, interpret,
-                                  precision, relu=(activation == "relu"))
-    zero = jax.tree.map(
-        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0), plans)
-    if fused is not None:
-        u, dx = fused
-        # dW = x^T u as one XLA GEMM (matches ops.linear's grad precision)
-        gw = jax.lax.dot_general(
-            x.astype(jnp.float32), u, (((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32).astype(w.dtype)
-        return dx.astype(x.dtype), gw, zero
-    _, vjp = jax.vjp(
-        lambda xx, ww: _unfused_layer(xx, ww, plans, interpret, precision,
-                                      activation), x, w)
-    gx, gw = vjp(g)
-    return gx, gw, zero
-
-
-scatter_gather_linear_binned.defvjp(_bnl_fwd, _bnl_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Cross-layer megakernel (round 16): a whole fusion region —
-# aggregate -> linear (-> ReLU) -> aggregate -> linear ... — through one
-# Pallas grid; see roc_tpu/ops/pallas/binned.py run_binned_region.
-# ---------------------------------------------------------------------------
-
-def _unfused_region(x, ws, in_degree, plans, interpret, precision,
-                    activations, fold):
-    """The per-layer composition the cross-layer kernel must match:
-    scatter_gather_linear_binned per member, with GCN's folded norm pair
-    (post-scale of layer l + pre-scale of layer l+1) applied between
-    members.  Forward parity oracle AND the region backward's fallback
-    recompute target (jax.vjp of this function is byte-identical to the
-    gradient program the unchained layers would have run)."""
-    from roc_tpu.ops.norm import indegree_norm
-    h = x
-    for d, (w, act) in enumerate(zip(ws, activations)):
-        h = scatter_gather_linear_binned(h, w, plans, interpret,
-                                         precision, act)
-        if fold and d + 1 < len(ws):
-            # the boundary carries both layers' scales: layer d's
-            # post-norm then layer d+1's pre-norm
-            h = indegree_norm(indegree_norm(h, in_degree), in_degree)
-    return h
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def region_linear_binned(x, ws, in_degree, plans: BinnedPlans,
-                         interpret: bool = False, precision: str = "fast",
-                         activations=(), fold: bool = False):
-    """A whole fusion region through one Pallas grid: layer l's
-    post-linear tile feeds layer l+1's aggregation while still in VMEM,
-    so the ``[rows, H]`` inter-layer boundaries never exist in HBM
-    (round 16).  ``ws``/``activations`` are the region's weight and
-    activation chains, head to tail; ``fold`` applies GCN's norm pair at
-    each interior boundary (``in_degree`` participates only then, and is
-    nondifferentiable by ROC's convention — degrees are graph structure).
-    Differentiable w.r.t. x and every w.
-
-    The caller must pre-gate with ``region_ok`` (this primal asserts);
-    the backward self-gates: ``run_binned_region_bwd`` replays the
-    forward in-kernel for relu masks, ping-pongs interior cotangents in
-    VMEM, and accumulates every dW in-kernel — declining to the
-    ``_unfused_region`` jax.vjp oracle when the transposed plan or the
-    VMEM price says no."""
-    from roc_tpu.ops.pallas.binned import run_binned_region
-    assert plans.mm is None, \
-        "region fusion requires a pure binned plan (no hybrid side)"
-    return run_binned_region(x, ws, in_degree, plans.fwd, interpret,
-                             precision, activations, fold)
-
-
-def _rnl_fwd(x, ws, in_degree, plans, interpret, precision, activations,
-             fold):
-    out = region_linear_binned(x, ws, in_degree, plans, interpret,
-                               precision, activations, fold)
-    # saved out is the last layer's relu-mask source; interior masks are
-    # replayed in-kernel by the backward (that's the HBM saving)
-    return out, (x, ws, in_degree, plans, out)
-
-
-def _rnl_bwd(interpret, precision, activations, fold, res, g):
-    x, ws, in_degree, plans, out = res
-    from roc_tpu.ops.pallas.binned import run_binned_region_bwd
-    zero_p = jax.tree.map(
-        lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0), plans)
-    fused = run_binned_region_bwd(g, out, x, ws, in_degree, plans.fwd,
-                                  plans.bwd, interpret, precision,
-                                  activations, fold)
-    if fused is not None:
-        dx, gws = fused
-        return (dx.astype(x.dtype),
-                tuple(gw.astype(w.dtype) for gw, w in zip(gws, ws)),
-                jnp.zeros_like(in_degree), zero_p)
-    _, vjp = jax.vjp(
-        lambda xx, wws: _unfused_region(xx, wws, in_degree, plans,
-                                        interpret, precision, activations,
-                                        fold), x, tuple(ws))
-    gx, gws = vjp(g)
-    return gx, gws, jnp.zeros_like(in_degree), zero_p
-
-
-region_linear_binned.defvjp(_rnl_fwd, _rnl_bwd)

@@ -16,7 +16,6 @@ routes them to pad destination rows (partition.py edge padding invariants).
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import NamedTuple
 
@@ -733,149 +732,3 @@ def _gat_plan_bwd(slope, precision, rate, res, gout):
 
 
 _gat_plan.defvjp(_gat_plan_fwd, _gat_plan_bwd)
-
-
-# --------------------------------------------------------------------------
-# Fused-kernel dispatch (round 19): gat_attend_plan semantics, with the
-# score -> softmax -> weighted-aggregate composition running as binned
-# Pallas grids when the graph carries a fused schedule.
-# --------------------------------------------------------------------------
-
-from roc_tpu.ops.pallas import gat as _pgat              # noqa: E402
-
-
-def _gat_fuse_state(bplans, heads: int, head_dim: int):
-    """Trace-time (static) fusion decision: (head_groups, bwd_ok).
-    head_groups == 0 means the whole composition declines to the
-    unfused oracle.  Everything consulted is static — plan SHAPES and
-    geometry metadata, never array values — so flipping any input is a
-    (guarded, intentional) retrace, not silent wrong-path reuse."""
-    if (bplans is None or os.environ.get("ROC_BINNED_NO_FUSE")
-            or _pgat.gat_fuse_killed()):
-        return 0, False
-    return _pgat.gat_head_groups(bplans.fwd, bplans.bwd, heads, head_dim)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def gat_attend_binned(h, table, a_src, a_dst, plans: GatPlans, bplans,
-                      edge_ids, slope: float, precision: str = "highest",
-                      interpret: bool = False):
-    """:func:`gat_attend_plan` with a fused binned-kernel fast path.
-
-    ``bplans`` is the graph's BinnedPlans pair (fwd = dst-keyed, bwd =
-    the transposed plan); when it carries a fused flat schedule that
-    passes the VMEM/head-width gates, the forward runs the max+sum
-    Pallas grids of ``ops/pallas/gat.py`` (heads split into the
-    smallest admissible lane groups) and the backward runs the two
-    transposed-plan grids.  Any gate failure declines to the unfused
-    composition — ``_gat_plan_fwd``/``_gat_plan_bwd`` verbatim, so the
-    decline path is byte-identical to the oracle.  The fused forward is
-    bitwise the oracle on integer data and ULP-bounded on continuous
-    data; ``precision`` keeps the oracle's contract (feature sums only).
-    """
-    out, _ = _gat_binned_fwd(h, table, a_src, a_dst, plans, bplans,
-                             edge_ids, slope, precision, interpret)
-    return out
-
-
-def _gat_binned_fwd(h, table, a_src, a_dst, plans, bplans, edge_ids,
-                    slope, precision="highest", interpret=False):
-    K, F = h.shape[1], h.shape[2]
-    ng, _ = _gat_fuse_state(bplans, K, F)
-    if not ng:
-        out, res = _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids,
-                                 None, slope, precision)
-        return out, (res, None, bplans)
-    bprec = "exact" if precision == "highest" else "fast"
-    # the oracle's own einsum builds the dst score contribution — shared
-    # verbatim so the fused and decline paths agree on it bitwise
-    ad_l = jnp.einsum("nkf,kf->nk", h, a_dst, precision="highest")
-    kg = K // ng
-    outs, ms, zs = [], [], []
-    for gi in range(ng):
-        sl = slice(gi * kg, (gi + 1) * kg)
-        o, m, z = _pgat.run_binned_gat(
-            table[:, sl], a_src[sl], ad_l[:, sl], bplans.fwd, slope,
-            interpret=interpret, precision=bprec)
-        outs.append(o)
-        ms.append(m)
-        zs.append(z)
-    out = jnp.concatenate(outs, axis=1) if ng > 1 else outs[0]
-    res_fused = (h, table, a_src, a_dst, plans, edge_ids, ad_l,
-                 jnp.stack(ms), jnp.stack(zs), out)
-    return out, (None, res_fused, bplans)
-
-
-def _gat_binned_bwd(slope, precision, interpret, res, gout):
-    res_plan, res_fused, bplans = res
-
-    def _aux_zeros():
-        return _int_zeros(bplans)
-
-    if res_fused is None:
-        dh, dtable, da_src, da_dst, dplans, dedge, _ = _gat_plan_bwd(
-            slope, precision, 0.0, res_plan, gout)
-        return (dh, dtable, da_src, da_dst, dplans, _aux_zeros(), dedge)
-
-    (h, table, a_src, a_dst, plans, edge_ids, ad_l, m_cat, z_cat,
-     out) = res_fused
-    edge_src, edge_dst = edge_ids
-    N, T = plans.num_rows, plans.table_rows
-    K, F = h.shape[1], h.shape[2]
-    ng_now, bwd_ok = _gat_fuse_state(bplans, K, F)
-    # the SAVED planes pin the group split (env flips between fwd and
-    # bwd trace must not misread them — decline instead)
-    ng = m_cat.shape[0]
-    kg = K // ng
-    bprec = "exact" if precision == "highest" else "fast"
-
-    if ng == ng_now and bwd_ok and not _pgat.gat_bwd_killed():
-        parts = []
-        for gi in range(ng):
-            sl = slice(gi * kg, (gi + 1) * kg)
-            parts.append(_pgat.run_binned_gat_bwd(
-                gout[:, sl], out[:, sl], table[:, sl], a_src[sl],
-                ad_l[:, sl], m_cat[gi], z_cat[gi], bplans.fwd,
-                bplans.bwd, slope, interpret=interpret, precision=bprec))
-        dtable_agg = jnp.concatenate([p[0] for p in parts], axis=1) \
-            if ng > 1 else parts[0][0]
-        dast = jnp.concatenate([p[1] for p in parts], axis=1) \
-            if ng > 1 else parts[0][1]
-        dadl = jnp.concatenate([p[2] for p in parts], axis=1) \
-            if ng > 1 else parts[0][2]
-    else:
-        # decline backward: recompute the oracle VJP from the saved max
-        # plane (max is order-independent => the recomputed q/e are the
-        # oracle's own) and replay _gat_plan_bwd's plan reductions
-        m_nodes = jnp.concatenate(
-            [m_cat[gi, :N, :kg] for gi in range(ng)], axis=1).T   # [K, N]
-        z_nodes = jnp.concatenate(
-            [z_cat[gi, :N, :kg] for gi in range(ng)], axis=1).T
-        zc = jnp.maximum(z_nodes, _Z_GUARD)
-        as_t = jnp.einsum("tkf,kf->kt", table, a_src, precision="highest")
-        q = _take_lanes(ad_l.T, edge_dst) + _take_lanes(as_t, edge_src)
-        e = jnp.exp(jax.nn.leaky_relu(q, negative_slope=slope)
-                    - _take_lanes(m_nodes, edge_dst))             # [K, E]
-        du = gout / zc.T[:, :, None]
-        dz = -jnp.einsum("nkf,nkf->kn", gout, out,
-                         precision="highest") / zc
-        de = (_edge_contract(du, table, edge_src, edge_dst)
-              + _take_lanes(dz, edge_dst))
-        dq = e * de * jnp.where(q >= 0, 1.0, slope)
-        dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst,
-                         plans.dst_pos, plans.dst_nid, N, "highest",
-                         True).T
-        dast = _plan_sum(dq, None, plans.src_obi, plans.src_edst,
-                         plans.src_pos, plans.src_nid, T, "highest").T
-        dtable_agg = _plan_sum(e, du, plans.src_obi, plans.src_edst,
-                               plans.src_pos, plans.src_nid, T, precision)
-
-    dtable = dtable_agg + dast[:, :, None] * a_src[None]
-    dh = dadl[:, :, None] * a_dst[None]
-    da_src = jnp.einsum("tk,tkf->kf", dast, table, precision="highest")
-    da_dst = jnp.einsum("nk,nkf->kf", dadl, h, precision="highest")
-    zeros = _int_zeros((plans, edge_ids))
-    return (dh, dtable, da_src, da_dst, zeros[0], _aux_zeros(), zeros[1])
-
-
-gat_attend_binned.defvjp(_gat_binned_fwd, _gat_binned_bwd)

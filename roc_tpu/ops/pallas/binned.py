@@ -283,23 +283,9 @@ GEOM_FLAT_SPARSE = Geometry(sb=1024, ch=2048, slot=16, rb=1024, ch2=2048,
 GEOM_FLAT_BF16 = GEOM_FLAT._replace(unit=16)
 GEOM_FLAT_SPARSE_BF16 = GEOM_FLAT_SPARSE._replace(unit=16)
 
-# Megakernel candidates (round 10, docs/DESIGN.md §Megakernel): the
-# aggregate->linear megakernel runs on any flat plan whose fused schedule
-# attaches (ch == ch2, group staging within _FUSE_MAX_STG_ROWS), so the
-# mega presets ARE the fused-eligible flat geometries under explicit
-# names — no new window shapes, no Geometry field (the plan-cache key and
-# native builders stay untouched).  choose_geometry(fuse_linear=True)
-# prices the difference instead: candidates whose schedule cannot feed
-# the megakernel pay the eliminated intermediate's HBM round trip.
-GEOM_MEGA = GEOM_FLAT
-GEOM_MEGA_SPARSE = GEOM_FLAT_SPARSE
-GEOM_MEGA_BF16 = GEOM_FLAT_BF16
-
 # Named presets for the ROC_BINNED_GEOM escape hatch (build_binned_plans):
 # force the auto-chosen FORWARD geometry to a specific preset, for
-# hardware A/B runs that must isolate one variable — e.g. hw_revalidate
-# step 4c runs both megakernel legs at "flat" so the measured delta is
-# fusion, not the cost model's geometry pick.
+# hardware A/B runs that must isolate one variable.
 GEOM_PRESETS = {
     "wide": GEOM_WIDE,
     "mid": GEOM_MID,
@@ -360,9 +346,6 @@ class BinnedPlan:
       f_blk/f_blk2/f_obi [S] x blocks + GLOBAL output bin per step (p1
                              steps repeat the previous p2 step's bin)
       f_dsrc/f_ddst [S, KD]  staging-copy run lists (kind 0; else -1)
-      f_last  [S]            1 iff the step is the LAST real p2 chunk of
-                             its output bin (the megakernel's in-register
-                             activation point; pad steps carry 0)
     """
     p1_srcl: jnp.ndarray
     p1_off: jnp.ndarray
@@ -380,7 +363,6 @@ class BinnedPlan:
     f_obi: jnp.ndarray = None
     f_dsrc: jnp.ndarray = None
     f_ddst: jnp.ndarray = None
-    f_last: jnp.ndarray = None
     num_rows: int = dataclasses.field(metadata={"static": True}, default=0)
     table_rows: int = dataclasses.field(metadata={"static": True}, default=0)
     bins_per_group: int = dataclasses.field(
@@ -396,8 +378,7 @@ class BinnedPlan:
 _PLAN_DATA_FIELDS = [
     "p1_srcl", "p1_off", "p1_blk", "p2_dstl", "p2_obi", "p2_first",
     "p1_blk2", "p1_dsrc", "p1_ddst",
-    "f_meta", "f_rows", "f_blk", "f_blk2", "f_obi", "f_dsrc", "f_ddst",
-    "f_last"]
+    "f_meta", "f_rows", "f_blk", "f_blk2", "f_obi", "f_dsrc", "f_ddst"]
 
 jax.tree_util.register_dataclass(
     BinnedPlan,
@@ -497,12 +478,6 @@ _FLAT_COPY_S = 27e-9          # per real size-classed copy (flat phase 1)
 # products shape: 306k windows for 2.45M rows regardless of density.
 _MM_CHUNK_S = 2.9e-6
 _MODEL_H = 256                # nominal width: plans are H-independent
-# HBM bandwidth for the fuse_linear round-trip credit (choose_geometry):
-# one [rows, H] fp32 intermediate written by the aggregate and read back
-# by the linear is what the megakernel eliminates.  The single-source
-# roofline constant (obs/roofline.py, stdlib-only): one re-fit lands in
-# bench.py, the memory estimator, and this credit at once.
-from roc_tpu.obs.roofline import PEAK_BW as _HBM_BW  # noqa: E402
 # Scoped VMEM.  Mosaic gives a kernel 16 MiB of the v5e's 128 MiB unless
 # it is asked for more, and that default is below what the flat and wide
 # presets need at H=256 (the round-2 CH2=8192 compile failure).  The
@@ -515,10 +490,9 @@ from roc_tpu.obs.roofline import PEAK_BW as _HBM_BW  # noqa: E402
 # 32 MiB nominal) asks for 86 MiB at H=512 exact, still inside the chip.
 _VMEM_NOMINAL_CAP = 40 * (1 << 20)
 _VMEM_LIMIT_MAX = 100 * (1 << 20)
-# Gate for the opt-in fused families' own *_vmem_ok formulas (fused,
-# mega, cross-layer, fused GAT).  Those formulas are NOT checked against
-# Mosaic and their kernels still compile under the 16 MiB default
-# (CHANGES.md PR 21 has the compile inventory).
+# Gate for the flat schedule's fused pipeline (_fused_vmem_ok).  The
+# formula is NOT checked against Mosaic and the kernel still compiles
+# under the 16 MiB default (CHANGES.md PR 21 has the compile inventory).
 _VMEM_BUDGET = 14 * (1 << 20)
 # HBM admission for choose_geometry, the analogue of _VMEM_NOMINAL_CAP: a
 # candidate's per-group temporaries (_group_hbm_bytes, from shapes: the
@@ -895,27 +869,13 @@ def _plan_steps(cell_blk: np.ndarray, cell_bin: np.ndarray,
 def fused_plan_steps(cell_blk: np.ndarray, cell_bin: np.ndarray,
                      cnt: np.ndarray, geom: Geometry, num_rows: int,
                      table_rows: int, num_edges: int):
-    """Exact fused/megakernel grid step count for these cells, or None
-    when no fused schedule would attach (non-flat geometry, ch != ch2, or
-    group staging beyond _FUSE_MAX_STG_ROWS).  The fused grid runs REAL
-    chunks only — _attach_fused skips pad chunks — so its step count is
+    """Exact fused grid step count for these cells, or None when no fused
+    schedule would attach (non-flat geometry, ch != ch2, or group staging
+    beyond _FUSE_MAX_STG_ROWS).  The fused grid runs REAL chunks only —
+    _attach_fused skips pad chunks — so its step count is
     pad8(sum c1_per_g + sum bin_chunks), vs the two-pass G*C1 + G*C2
-    (per-group max-padded) that _plan_steps prices; the gap is what the
-    kernel-budget mega gate pins (tools/check_kernel_budgets.py).  Same
-    arithmetic as _flat_plan_steps/_attach_fused, O(cells)."""
-    r = _fused_sched_stats(cell_blk, cell_bin, cnt, geom, num_rows,
-                           table_rows, num_edges)
-    return None if r is None else r[0]
-
-
-def _fused_sched_stats(cell_blk, cell_bin, cnt, geom, num_rows, table_rows,
-                       num_edges):
-    """(fused_steps, C2, G) for these cells, or None when no fused schedule
-    attaches — the shared arithmetic behind fused_plan_steps and the
-    kernel-budget tool's megakernel rows (which also need C2 and the group
-    count to evaluate _mega_vmem_ok/_mega_bwd_vmem_ok offline: a
-    single-group plan stages on ONE parity, halving the dominant VMEM
-    term)."""
+    (per-group max-padded) that _plan_steps prices.  Same arithmetic as
+    _flat_plan_steps/_attach_fused, O(cells)."""
     if not (geom.flat and geom.ch == geom.ch2):
         return None
     num_bins, num_blocks, bpg, G = _plan_groups(geom, num_rows, table_rows,
@@ -936,88 +896,7 @@ def _fused_sched_stats(cell_blk, cell_bin, cnt, geom, num_rows, table_rows,
     C2 = max(int(c2_per_g.max(initial=0)), 1)
     if C2 * geom.ch2 > _FUSE_MAX_STG_ROWS:
         return None
-    steps = _pad_to(max(int(c1_per_g.sum()) + int(bin_chunks.sum()), 1), 8)
-    return steps, C2, G
-
-
-def predicted_layer_hbm_bytes(num_rows: int, h_in: int, h_out: int,
-                              mega: bool = False,
-                              itemsize: int = 4) -> int:
-    """Per-layer HBM bytes of the aggregate->linear handoff, OUTSIDE the
-    x-block streaming and staging traffic the two modes share: the
-    unfused path writes the [rows, H_in] aggregate to HBM and reads it
-    back for the matmul; the megakernel never materializes it.  Both
-    read the weight once and write the [rows, H_out] output.  Pinned by
-    the kernel-budget mega entry and tests/test_binned_flat.py: the drop
-    must be >= the intermediate's write + read."""
-    out = num_rows * h_out * itemsize + h_in * h_out * 4
-    if mega:
-        return out
-    return out + 2 * num_rows * h_in * itemsize
-
-
-def predicted_trainstep_hbm_bytes(num_rows: int, h_in: int, h_out: int,
-                                  mega_bwd: bool = False,
-                                  itemsize: int = 4) -> int:
-    """Per-layer TRAIN-STEP HBM bytes of the aggregate->linear handoff
-    intermediates: the fused forward (predicted_layer_hbm_bytes with
-    mega=True) plus the backward pass's handoff traffic, in the same
-    scope — OUTSIDE the x-block streaming and staging both backward modes
-    share.
-
-    ``mega_bwd=False`` is the two-pass VJP replay: the backward re-reads
-    x (one [rows, h_in]), recomputes the aggregate (write + the replayed
-    linear's read + the dW pass's read = 3x [rows, h_in]) and the output
-    (write + relu-mask read = 2x [rows, h_out]), then materializes the
-    dagg cotangent ([rows, h_in] write + backward-aggregation read) —
-    6 h_in + 2 h_out row trips.  ``mega_bwd=True`` is the fused backward:
-    it writes only u = A^T g ([rows, h_out], read back once by the XLA dW
-    GEMM) and re-reads the saved forward output for the in-kernel relu
-    mask — 3 h_out trips; dx rides the same kernel.  The replay's own
-    recompute staging round trip is NOT counted (the forward's staging is
-    shared, the recompute's is not), so the claimed drop is conservative.
-    The >=2x drop at the Reddit shape is pinned by the CI-gated
-    ``megakernel_bwd`` kernel-budget row (tools/check_kernel_budgets.py)
-    and tests/test_mega_bwd.py."""
-    fwd = predicted_layer_hbm_bytes(num_rows, h_in, h_out, mega=True,
-                                    itemsize=itemsize)
-    if mega_bwd:
-        return fwd + 3 * num_rows * h_out * itemsize
-    return (fwd + 6 * num_rows * h_in * itemsize
-            + 2 * num_rows * h_out * itemsize)
-
-
-def predicted_xlayer_hbm_bytes(num_rows: int, h: int, depth: int,
-                               itemsize: int = 4) -> int:
-    """Forward HBM bytes of a DEPTH-layer fusion region at uniform width
-    ``h``, in the same scope as predicted_layer_hbm_bytes (OUTSIDE the
-    x-block streaming and staging traffic every mode shares): the region
-    writes only the FINAL [rows, h] output — every interior layer
-    boundary stays in the VMEM inter-layer buffer — and reads each of the
-    ``depth`` weights once.  Compare against depth *
-    predicted_layer_hbm_bytes(..., mega=True): the region drops
-    (depth - 1) output-row writes."""
-    return num_rows * h * itemsize + depth * h * h * 4
-
-
-def predicted_xlayer_trainstep_hbm_bytes(num_rows: int, h: int, depth: int,
-                                         itemsize: int = 4) -> int:
-    """TRAIN-STEP HBM bytes of a DEPTH-layer fusion region, same scope as
-    predicted_trainstep_hbm_bytes.  Forward: predicted_xlayer_hbm_bytes.
-    Backward (_xlayer_bwd_run): the region cotangent g enters and dx
-    leaves at the region boundary (boundary tensors, excluded — exactly
-    as the per-layer accounting excludes them), interior cotangents
-    ping-pong in VMEM, u never exists in HBM (dW accumulates in-kernel),
-    and the relu masks come from the in-kernel forward replay — so the
-    backward's counted traffic is one [rows, h] x re-read for the replay
-    (the analogue of the unfused replay's counted x re-read), ``depth``
-    dW writes, and ``depth`` weight re-reads for the replay.  Versus
-    depth * the per-layer mega+bwd number this drops all 3*depth
-    [rows, h] u/mask trips and (depth - 1) forward output writes — the
-    >=2x cut the CI-gated ``megakernel_xlayer`` budget rows pin
-    (tools/check_kernel_budgets.py check_xlayer_claim)."""
-    fwd = predicted_xlayer_hbm_bytes(num_rows, h, depth, itemsize=itemsize)
-    return fwd + num_rows * h * itemsize + 2 * depth * h * h * 4
+    return _pad_to(max(int(c1_per_g.sum()) + int(bin_chunks.sum()), 1), 8)
 
 
 def padded_rows_for(edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -1075,7 +954,7 @@ def _ledger_note_plan(plan: "BinnedPlan", num_edges: int) -> None:
 
 
 def _tuned_geometry(edge_src, edge_dst, num_rows, table_rows,
-                    storage_dtype, fuse_linear):
+                    storage_dtype):
     """The tuned-tier lookup (roc_tpu/tune/store.py), failure-isolated:
     a missing/invalid store, ROC_NO_TUNED=1, or any import problem reads
     as 'no tuned entry' and the analytic model stays in charge.  Lazy
@@ -1085,15 +964,13 @@ def _tuned_geometry(edge_src, edge_dst, num_rows, table_rows,
     try:
         from roc_tpu.tune import store as _tstore
         g, _ = _tstore.lookup(edge_src, edge_dst, num_rows, table_rows,
-                              storage_dtype=storage_dtype,
-                              fuse_linear=fuse_linear)
+                              storage_dtype=storage_dtype)
         return g
     except Exception:
         return None
 
 
-def _priced_tuned(edge_src, edge_dst, num_rows, table_rows, E, geom,
-                  fuse_linear):
+def _priced_tuned(edge_src, edge_dst, num_rows, table_rows, E, geom):
     """Price a tuned winner through the SAME exact-schedule model the
     analytic path uses (so the returned seconds stay comparable and the
     balancer's consumers see one currency) and emit the same calibration
@@ -1104,14 +981,6 @@ def _priced_tuned(edge_src, edge_dst, num_rows, table_rows, E, geom,
                                  table_rows, E)
     t = _binned_cost_model(padded, geom, steps1=s1, steps2=s2,
                            copies=_flat_copies(cnt, geom))
-    if fuse_linear:
-        fs = _fused_sched_stats(cblk, cbin, cnt, geom, num_rows,
-                                table_rows, E)
-        if fs is not None:
-            t *= fs[0] / max(s1 + s2, 1)
-        else:
-            t += (2 * num_rows * _MODEL_H * 4 / _HBM_BW
-                  + -(-num_rows // 512) * _CHUNK_OVERHEAD_S)
     led = _get_ledger()
     if led.attached:
         key = _plan_key(num_rows, table_rows, E, geom)
@@ -1124,8 +993,7 @@ def _priced_tuned(edge_src, edge_dst, num_rows, table_rows, E, geom,
 def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
                     num_rows: int, table_rows: int,
                     candidates=None, force: bool = False,
-                    storage_dtype: str = "fp32",
-                    fuse_linear: bool = False):
+                    storage_dtype: str = "fp32"):
     """Pick the fastest-modeled binned geometry for this graph, or None if
     the matmul backend's modeled cost beats every candidate (VERDICT r3
     item 3: products-density graphs get a measured-stats policy instead of
@@ -1166,25 +1034,7 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
     dtype the trainer will run.  bf16 storage adds the 16-row bf16-unit
     flat presets to the candidate list (their halved staging bytes only
     exist when the input rides bf16; an fp32 run gains nothing and would
-    pay the doubled cell padding).
-
-    ``fuse_linear``: price candidates for an aggregate->linear layer that
-    the megakernel may fuse (round 10).  A candidate whose schedule
-    CANNOT feed the megakernel (non-flat, ch != ch2, oversized groups, or
-    a hybrid split) pays the rest of the layer: the eliminated
-    intermediate's HBM round trip (one [rows, _MODEL_H] fp32 write + read
-    at _HBM_BW) plus the separate linear pass's launch windows (one
-    _CHUNK_OVERHEAD_S per 512-row output window — the same currency the
-    kernel-budget mega gate uses).  A mega-eligible candidate is instead
-    priced at its FUSED schedule: real chunks only, the W matmul riding
-    the existing steps, no second pass.  The same pricing applies to BOTH
-    plan directions since round 12: build_binned_plans passes
-    ``fuse_linear`` through to the backward pick too, so the transposed
-    plan's geometry is chosen knowing the fused backward elides the dagg
-    cotangent's round trip the same way the forward elides the
-    aggregate's.  VMEM admission is NOT checked
-    here (H is unknown until trace time; the kernel's own gate falls back
-    to the two-pass flat schedule, which this candidate also runs well)."""
+    pay the doubled cell padding)."""
     E = len(edge_src)
     if E == 0:
         return None, 0.0
@@ -1198,25 +1048,16 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
     # be diverted to the thing it is measuring against.
     if candidates is None:
         tg = _tuned_geometry(edge_src, edge_dst, num_rows, table_rows,
-                             storage_dtype, fuse_linear)
+                             storage_dtype)
         if tg is not None:
             return _priced_tuned(edge_src, edge_dst, num_rows,
-                                 table_rows, E, tg, fuse_linear)
+                                 table_rows, E, tg)
     cands = list(candidates) if candidates is not None else \
         [_default_geom(), GEOM_WIDE, GEOM_MID, GEOM_MID_WIDE,
          GEOM_SPARSE, GEOM_SPARSE_WIDE, GEOM_XSPARSE,
          GEOM_FLAT, GEOM_FLAT_SPARSE]
     if candidates is None and storage_dtype == "bf16":
         cands += [GEOM_FLAT_BF16, GEOM_FLAT_SPARSE_BF16]
-    # What a NON-fusable candidate pays on top of aggregation when the
-    # layer could have fused: the intermediate [rows, H] fp32 write + read
-    # the megakernel elides, plus the separate linear pass's launch
-    # windows over the output rows.
-    rt = 0.0
-    if fuse_linear:
-        rt = (2 * num_rows * _MODEL_H * 4 / _HBM_BW
-              + -(-num_rows // 512) * _CHUNK_OVERHEAD_S)
-
     def fits_hbm(g, s1, s2, edges):
         groups = _plan_groups(g, num_rows, table_rows, edges)[3]
         return _group_hbm_bytes(g, s1, s2, groups) <= _HBM_GROUP_CAP
@@ -1242,16 +1083,6 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
         if fits_hbm(g, s1, s2, E):
             t = _binned_cost_model(padded, g, steps1=s1, steps2=s2,
                                    copies=_flat_copies(cnt, g))
-            if rt:
-                fs = _fused_sched_stats(cblk, cbin, cnt, g, num_rows,
-                                        table_rows, E)
-                if fs is None:
-                    t += rt
-                else:
-                    # fused layer: real chunks only, matmul in-pipeline —
-                    # scale the two-pass aggregation model by the step
-                    # ratio
-                    t *= fs[0] / max(s1 + s2, 1)
             if t < best_t:
                 best, best_t, best_steps = g, t, (s1, s2)
         # Hybrid variant: the sub-half-full cells' edges go to the matmul
@@ -1272,12 +1103,11 @@ def choose_geometry(edge_src: np.ndarray, edge_dst: np.ndarray,
                 continue
             t_h = (_binned_cost_model(padded_d, g, steps1=s1_d,
                                       steps2=s2_d)
-                   + _matmul_cost(E_thin, num_rows)
-                   + rt)    # hybrid plans carry a matmul side: never mega
+                   + _matmul_cost(E_thin, num_rows))
             if t_h < best_t:
                 best = g._replace(hub_minc=minc)
                 best_t, best_steps = t_h, (s1_d, s2_d)
-    t_matmul = _matmul_cost(E, num_rows) + rt
+    t_matmul = _matmul_cost(E, num_rows)
     if force or (best is not None and best_t < t_matmul):
         if best is not None and best_steps is not None:
             # Prediction half of the plan_steps/staging_rows calibration
@@ -1952,15 +1782,7 @@ def _fused_step_arrays(plan: BinnedPlan):
     f_dsrc = np.full((S, KD), -1, np.int32)
     f_ddst = np.full((S, KD), -1, np.int32)
     f_meta[:, 0] = 1                           # pad steps are kind=p2
-    # Last real p2 chunk of each output bin: the megakernel applies its
-    # in-register activation there (the bin's accumulation is complete;
-    # the out index is nondecreasing, so no later step reopens it — pad
-    # steps revisit the bin but only add exact zeros, which commute with
-    # ReLU).  Kept as a separate [S] array rather than a fifth f_meta
-    # column so the existing (8, 4) SMEM BlockSpec stays untouched.
-    f_last = np.zeros(S, np.int32)
     cur_blk = cur_blk2 = cur_obi = 0
-    prev_p2 = -1
     for i, (kind, g, c) in enumerate(steps):
         if kind == 0:
             cur_blk, cur_blk2 = int(blk[g, c]), int(blk2[g, c])
@@ -1969,24 +1791,17 @@ def _fused_step_arrays(plan: BinnedPlan):
             f_dsrc[i] = dsrc[g, c]
             f_ddst[i] = ddst[g, c]
         else:
-            nxt = g * bpg + int(obi[g, c])
-            if prev_p2 >= 0 and nxt != cur_obi:
-                f_last[prev_p2] = 1
-            cur_obi = nxt
-            prev_p2 = i
+            cur_obi = g * bpg + int(obi[g, c])
             f_meta[i] = (1, g % 2, int(first[g, c]), c)
             f_rows[i] = dstl[g, c * CH:(c + 1) * CH]
         f_blk[i], f_blk2[i], f_obi[i] = cur_blk, cur_blk2, cur_obi
-    if prev_p2 >= 0:
-        f_last[prev_p2] = 1
     if len(steps) < S:                         # pad: revisit the last bin
         f_meta[len(steps):, 1] = steps[-1][1] % 2 if steps else 0
         f_blk[len(steps):] = cur_blk
         f_blk2[len(steps):] = cur_blk2
         f_obi[len(steps):] = cur_obi
     return dict(f_meta=f_meta, f_rows=f_rows.reshape(S * CH, 1), f_blk=f_blk,
-                f_blk2=f_blk2, f_obi=f_obi, f_dsrc=f_dsrc, f_ddst=f_ddst,
-                f_last=f_last)
+                f_blk2=f_blk2, f_obi=f_obi, f_dsrc=f_dsrc, f_ddst=f_ddst)
 
 
 # ---------------------------------------------------------------------------
@@ -2487,1396 +2302,6 @@ def _fused_vmem_ok(geom: Geometry, Hp: int, c2: int) -> bool:
             + max(geom.ch * geom.sb, geom.ch2 * geom.rb) * 2
             + 2 * geom.sb * Hp * 4 + geom.rb * Hp * 4)
     return need <= _VMEM_BUDGET
-
-
-# ---------------------------------------------------------------------------
-# Whole-layer megakernel: aggregate -> linear (-> ReLU) in the SAME fused
-# grid (round 10, docs/DESIGN.md §Megakernel).  Each phase-2 step's [RB, H]
-# aggregation tile stays in registers/VMEM and feeds the MXU weight matmul
-# directly; only the post-linear (optionally post-ReLU) [RB, H_out] window
-# ever reaches HBM — the [rows, H_in] aggregate never materializes.
-# ---------------------------------------------------------------------------
-
-def _mega_kernel(blk_ref, blk2_ref, obi_ref, last_ref, meta_ref, dsrc_ref,
-                 ddst_ref, rows_ref, x_ref, x2_ref, w_ref, out_ref, gbuf,
-                 stgbuf, sems, *, exact: bool = False,
-                 geom: Geometry = None, relu: bool = False):
-    """_fused_kernel with the layer's W matmul grafted onto every phase-2
-    step.  Kind 0 (phase 1) is byte-identical to the fused kernel; kind 1
-    scatter-adds one staging chunk into a per-chunk [RB, H] aggregate
-    tile, then accumulates tile @ W into the resident [RB, H_out] out
-    window (fp32, `highest` — the ops.linear fp32 contract).  Correct per
-    chunk because matmul distributes over the bin's chunk sum:
-    sum_c(tile_c) @ W == sum_c(tile_c @ W) exactly on fp32 adds of the
-    same addends.  The optional ReLU applies on the bin's LAST real chunk
-    (f_last; the out index is nondecreasing so the window is still
-    resident) — pad-step revisits add exact zeros, which commute with it.
-    The weight rides a constant-index BlockSpec: fetched into VMEM once
-    and double-buffer-stable across the whole grid (the index map never
-    changes, so pallas never refetches it alongside the parity staging).
-    """
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    U = geom.unit_rows
-    st = staging_dtype(geom, exact)
-    c = pl.program_id(0)
-    kind = meta_ref[c % 8, 0]
-    par = meta_ref[c % 8, 1]
-    first = meta_ref[c % 8, 2]
-    sq = meta_ref[c % 8, 3]
-
-    @pl.when(kind == 0)
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-        sl = rows_ref[:]
-        t1 = (lane == sl).astype(jnp.bfloat16)
-        gbuf[:] = _onehot_dot(t1, x_ref[:], (((1,), (0,)), ((), ())),
-                              exact).astype(st)
-
-        @pl.when(blk2_ref[c] != blk_ref[c])
-        def _():
-            t2 = (lane == sl - SB).astype(jnp.bfloat16)
-            gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                t2, x2_ref[:], (((1,), (0,)), ((), ())), exact)).astype(st)
-
-        def issue(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).start()
-            return 0
-        jax.lax.fori_loop(0, KD, issue, 0)
-
-        def drain(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).wait()
-            return 0
-        jax.lax.fori_loop(0, KD, drain, 0)
-
-    @pl.when(kind == 1)
-    def _():
-        @pl.when(first == 1)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        dl = rows_ref[:]
-        chunk = stgbuf[par, pl.ds(sq * CH, CH)]
-        rows = jnp.where(dl == RB, jnp.float32(0), chunk)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, RB), 1)
-        s_t = (lane == dl).astype(jnp.bfloat16)
-        tile = _onehot_dot(s_t, rows, (((0,), (0,)), ((), ())), exact)
-        out_ref[:] += jax.lax.dot_general(
-            tile, w_ref[:], (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        if relu:
-            @pl.when(last_ref[c] == 1)
-            def _():
-                out_ref[:] = jnp.maximum(out_ref[:], 0.0)
-
-
-@partial(jax.jit, static_argnames=("nsteps", "c2", "out_rows", "interpret",
-                                   "exact", "geom", "relu", "nparity"))
-def _mega_run(x, w, blk, blk2, obi, last, meta, dsrc, ddst, rows,
-              nsteps: int, c2: int, out_rows: int, interpret: bool = False,
-              exact: bool = False, geom: Geometry = None,
-              relu: bool = False, nparity: int = 2):
-    H = x.shape[-1]
-    Ho = w.shape[-1]
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    srows = c2 * geom.ch2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                  # blk, blk2, obi, last [S]
-        grid=(nsteps,),
-        in_specs=[
-            pl.BlockSpec((8, 4), lambda c, b, b2, o, l: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((8, KD), lambda c, b, b2, o, l: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((8, KD), lambda c, b, b2, o, l: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((CH, 1), lambda c, b, b2, o, l: (c, 0)),
-            pl.BlockSpec((SB, H), lambda c, b, b2, o, l: (b[c], 0)),
-            pl.BlockSpec((SB, H), lambda c, b, b2, o, l: (b2[c], 0)),
-            # whole weight, constant index: fetched once, VMEM-resident
-            pl.BlockSpec((H, Ho), lambda c, b, b2, o, l: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((RB, Ho), lambda c, b, b2, o, l: (o[c], 0)),
-        # Single-group plans stage on ONE parity (every step's meta parity
-        # is g%2 == 0, pads included — _attach_fused), so the second
-        # stgbuf parity would be dead VMEM; dropping it is what admits
-        # C2>1 fp32 fusion at the mega-shard shape (round 12).
-        scratch_shapes=[pltpu.VMEM((CH, H), staging_dtype(geom, exact)),
-                        pltpu.VMEM((nparity, srows, H),
-                                   staging_dtype(geom, exact)),
-                        pltpu.SemaphoreType.DMA((1,))],
-    )
-    return pl.pallas_call(
-        partial(_mega_kernel, exact=exact, geom=geom, relu=relu),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, Ho), jnp.float32),
-        interpret=interpret,
-    )(blk, blk2, obi, last, meta, dsrc, ddst, rows, x, x, w)
-
-
-def _mega_vmem_ok(geom: Geometry, Hp: int, Ho_p: int, c2: int,
-                  groups: int = 2) -> bool:
-    """_fused_vmem_ok extended with the megakernel's extra residents: the
-    [Hp, Ho_p] weight tile, the per-chunk [rb, Hp] aggregate tile the dot
-    produces, and the [rb, Ho_p] post-linear out window (replacing the
-    fused kernel's [rb, Hp] one).  An oversized H_out fails here and
-    run_binned_linear falls back to two-pass aggregate + XLA linear.
-
-    ``groups`` is the plan's group count G: a single-group plan stages on
-    ONE parity (the schedule's parity is g%2 == 0 on every step, pads
-    included), so only one srows*Hp staging buffer is resident — the
-    round-12 admission raise that lets fp32 fuse at C2>1 (the mega-shard
-    shape fits at C2=3 single-parity where double-parity busts the
-    budget).  The default groups=2 is the conservative double-parity
-    charge for callers that don't know G."""
-    srows = c2 * geom.ch2
-    stg = staging_itemsize(geom, False)
-    nparity = 1 if groups == 1 else 2
-    need = (nparity * srows * Hp * stg + geom.ch * Hp * stg
-            + max(geom.ch * geom.sb, geom.ch2 * geom.rb) * 2
-            + 2 * geom.sb * Hp * 4
-            + Hp * Ho_p * 4              # resident weight tile
-            + geom.rb * Hp * 4           # per-chunk aggregate tile
-            + geom.rb * Ho_p * 4)        # post-linear out window
-    return need <= _VMEM_BUDGET
-
-
-# ROC_NO_MEGAFUSE kill switch: one warning per process — a kill this high
-# up changes the layer program (two device passes instead of one), worth
-# one notice where ROC_BINNED_NO_FUSE stays a silent bisection knob.
-_MEGA_KILL_WARNED = [False]
-
-
-def megafuse_killed() -> bool:
-    """True when ROC_NO_MEGAFUSE=1 disables aggregate->linear megakernel
-    fusion at runtime (checked at every dispatch site; warn-once)."""
-    if not os.environ.get("ROC_NO_MEGAFUSE"):
-        return False
-    if not _MEGA_KILL_WARNED[0]:
-        _MEGA_KILL_WARNED[0] = True
-        warnings.warn(
-            "ROC_NO_MEGAFUSE=1: aggregate->linear megakernel fusion "
-            "disabled; eligible layers run the two-pass aggregation plus "
-            "the XLA linear instead.", stacklevel=2)
-    return True
-
-
-def run_binned_linear(x, w, plan: BinnedPlan, interpret: bool = False,
-                      precision: str = "fast", activation: str = "none"):
-    """linear(aggregate-sum(x), w)[, ReLU] in ONE Pallas grid — the
-    whole-layer megakernel (round 10).
-
-    x: [table_rows, H_in], w: [H_in, H_out] -> [num_rows, H_out] in
-    x.dtype.  Semantics match run_binned followed by ops.linear (fp32
-    accumulation, `highest`-precision matmul); on the megakernel path
-    the [num_rows, H_in] aggregate never reaches HBM.  Gating mirrors
-    run_binned's fused gate plus the weight/accumulator VMEM budget
-    (_mega_vmem_ok) and the ROC_NO_MEGAFUSE kill switch; any gate
-    failure falls back to exactly that two-pass composition, so callers
-    always get the layer, just not always in one kernel.  Differentiable
-    through the fallback only — training uses the custom VJP in
-    ops.aggregate.scatter_gather_linear_binned, whose backward replays
-    the two-pass path."""
-    if activation not in ("none", "relu"):
-        raise ValueError(f"activation={activation!r}: the megakernel "
-                         f"fuses 'none' or 'relu' only")
-    if precision not in ("fast", "exact"):
-        raise ValueError(f"precision={precision!r}: must be 'fast' or "
-                         f"'exact'")
-    exact = precision == "exact" and x.dtype == jnp.float32
-    geom = plan.geom or _default_geom()
-    H = x.shape[-1]
-    Ho = w.shape[-1]
-    Hp = _pad_to(H, 128)
-    Ho_p = _pad_to(Ho, 128)
-    C2 = plan.p2_obi.shape[1]
-    G = plan.p1_blk.shape[0]
-    if (geom.flat and plan.f_meta is not None
-            and plan.f_last is not None
-            and not (exact and geom.unit == 16)
-            and not os.environ.get("ROC_BINNED_NO_FUSE")
-            and not megafuse_killed()
-            and _mega_vmem_ok(geom, Hp, Ho_p, C2, groups=G)):
-        out_rows = G * plan.bins_per_group * geom.rb
-        xp = jnp.pad(x, ((0, _pad_to(plan.table_rows, geom.sb)
-                          - x.shape[0]), (0, Hp - H)))
-        # fp32 weight, zero-padded to whole lanes on both axes: pad H_in
-        # rows multiply x's zero pad lanes, pad H_out lanes are stripped
-        wp = jnp.pad(w.astype(jnp.float32),
-                     ((0, Hp - H), (0, Ho_p - Ho)))
-        S = int(plan.f_blk.shape[0])
-        with jax.named_scope("roc_binned_mega"):
-            out = _mega_run(xp, wp, plan.f_blk, plan.f_blk2, plan.f_obi,
-                            plan.f_last, plan.f_meta, plan.f_dsrc,
-                            plan.f_ddst, plan.f_rows, S, C2, out_rows,
-                            interpret, exact, geom,
-                            activation == "relu",
-                            1 if G == 1 else 2)
-        return out[:plan.num_rows, :Ho].astype(x.dtype)
-    # VMEM-gate / kill-switch fallback: the identical two-pass layer
-    from roc_tpu.ops.linear import linear
-    return linear(run_binned(x, plan, interpret, precision), w, activation)
-
-
-# ---------------------------------------------------------------------------
-# Megakernel BACKWARD (round 12): the layer's whole cotangent pipeline —
-# relu mask, transposed aggregation u = A^T g, and dx = u @ W^T — in one
-# Pallas grid over the TRANSPOSED (plans.bwd) flat schedule.  dW = x^T u
-# stays an XLA GEMM outside (it needs x, which the kernel never streams).
-# ---------------------------------------------------------------------------
-
-# ROC_MEGA_BWD=0 kill switch for the FUSED BACKWARD only (the forward
-# megakernel keeps running): gradients fall back to the two-pass VJP
-# replay — today's bitwise-gradient behavior, byte for byte.  Warn-once
-# like megafuse_killed: flipping it changes the backward program.
-_MEGA_BWD_KILL_WARNED = [False]
-
-
-def mega_bwd_killed() -> bool:
-    """True when ROC_MEGA_BWD=0 disables the fused megakernel backward at
-    runtime (checked at every VJP dispatch; warn-once)."""
-    if os.environ.get("ROC_MEGA_BWD", "") != "0":
-        return False
-    if not _MEGA_BWD_KILL_WARNED[0]:
-        _MEGA_BWD_KILL_WARNED[0] = True
-        warnings.warn(
-            "ROC_MEGA_BWD=0: fused megakernel backward disabled; "
-            "eligible layers' gradients replay the two-pass "
-            "aggregate+linear composition instead.", stacklevel=2)
-    return True
-
-
-def _mega_bwd_vmem_ok(geom: Geometry, Ho_p: int, Hi_p: int, c2: int,
-                      groups: int = 2, relu: bool = False) -> bool:
-    """Trace-time admission for the backward megakernel.  Mirrors
-    _mega_vmem_ok at the backward's widths — staging/gbuf/one-hots ride
-    the OUTPUT width Ho_p (the cotangent is what aggregates) — plus the
-    backward's own residents: the relu path streams TWO extra saved-output
-    blocks alongside the cotangent blocks, the transposed [Ho_p, Hi_p]
-    weight tile sits where the forward's [Hp, Ho_p] one did, and BOTH
-    output windows (u at Ho_p, dx at Hi_p) are resident per bin."""
-    srows = c2 * geom.ch2
-    stg = staging_itemsize(geom, False)
-    nparity = 1 if groups == 1 else 2
-    need = (nparity * srows * Ho_p * stg + geom.ch * Ho_p * stg
-            + max(geom.ch * geom.sb, geom.ch2 * geom.rb) * 2
-            + (4 if relu else 2) * geom.sb * Ho_p * 4
-            + Ho_p * Hi_p * 4            # resident W^T tile
-            + geom.rb * Ho_p * 4         # per-chunk cotangent tile
-            + geom.rb * Ho_p * 4         # u out window
-            + geom.rb * Hi_p * 4)        # dx out window
-    return need <= _VMEM_BUDGET
-
-
-def _mega_bwd_kernel(*args, exact: bool = False, geom: Geometry = None,
-                     relu: bool = False):
-    """Backward twin of _mega_kernel over the transposed plan.  Kind 0
-    expands a chunk of the OUTPUT cotangent g — masked in-register by the
-    saved forward output when the layer fused a relu (mask before the
-    one-hot: it is per-source-row, and pad rows carry y=0 so they stay
-    zero) — and stages it; kind 1 scatter-adds one staging chunk into the
-    per-bin cotangent tile u_tile = (A^T g_masked)[bin], accumulates it
-    into the u window (written to HBM for the XLA dW GEMM: dW = x^T u),
-    AND accumulates u_tile @ W^T into the dx window — both outputs ride
-    the same nondecreasing out index, so one grid produces the layer's
-    full input cotangent.  Correct per chunk for the same distributivity
-    reason as the forward (integer data is bit-exact; fp32 reassociates
-    within the documented ULP bound).  No f_last epilogue exists here:
-    the relu mask is a PRE-aggregation operation, applied in kind 0."""
-    if relu:
-        (blk_ref, blk2_ref, obi_ref, meta_ref, dsrc_ref, ddst_ref,
-         rows_ref, g_ref, g2_ref, y_ref, y2_ref, wt_ref,
-         u_ref, dx_ref, gbuf, stgbuf, sems) = args
-    else:
-        (blk_ref, blk2_ref, obi_ref, meta_ref, dsrc_ref, ddst_ref,
-         rows_ref, g_ref, g2_ref, wt_ref,
-         u_ref, dx_ref, gbuf, stgbuf, sems) = args
-        y_ref = y2_ref = None
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    U = geom.unit_rows
-    st = staging_dtype(geom, exact)
-    c = pl.program_id(0)
-    kind = meta_ref[c % 8, 0]
-    par = meta_ref[c % 8, 1]
-    first = meta_ref[c % 8, 2]
-    sq = meta_ref[c % 8, 3]
-
-    @pl.when(kind == 0)
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-        sl = rows_ref[:]
-        t1 = (lane == sl).astype(jnp.bfloat16)
-        gv = g_ref[:]
-        if relu:
-            # d/dy relu at the saved output: pass g where y > 0.  At an
-            # exact pre-activation zero this differs from jnp.maximum's
-            # tie-splitting VJP (0.5*g) — measure-zero on continuous
-            # data; docs/DESIGN.md §Megakernel documents the tie rule.
-            gv = jnp.where(y_ref[:] > 0, gv, jnp.zeros_like(gv))
-        gbuf[:] = _onehot_dot(t1, gv, (((1,), (0,)), ((), ())),
-                              exact).astype(st)
-
-        @pl.when(blk2_ref[c] != blk_ref[c])
-        def _():
-            t2 = (lane == sl - SB).astype(jnp.bfloat16)
-            gv2 = g2_ref[:]
-            if relu:
-                gv2 = jnp.where(y2_ref[:] > 0, gv2, jnp.zeros_like(gv2))
-            gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                t2, gv2, (((1,), (0,)), ((), ())), exact)).astype(st)
-
-        def issue(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).start()
-            return 0
-        jax.lax.fori_loop(0, KD, issue, 0)
-
-        def drain(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).wait()
-            return 0
-        jax.lax.fori_loop(0, KD, drain, 0)
-
-    @pl.when(kind == 1)
-    def _():
-        @pl.when(first == 1)
-        def _():
-            u_ref[:] = jnp.zeros_like(u_ref)
-            dx_ref[:] = jnp.zeros_like(dx_ref)
-
-        dl = rows_ref[:]
-        chunk = stgbuf[par, pl.ds(sq * CH, CH)]
-        rows = jnp.where(dl == RB, jnp.float32(0), chunk)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, RB), 1)
-        s_t = (lane == dl).astype(jnp.bfloat16)
-        tile = _onehot_dot(s_t, rows, (((0,), (0,)), ((), ())), exact)
-        u_ref[:] += tile
-        dx_ref[:] += jax.lax.dot_general(
-            tile, wt_ref[:], (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-
-@partial(jax.jit, static_argnames=("nsteps", "c2", "out_rows", "interpret",
-                                   "exact", "geom", "relu", "nparity"))
-def _mega_bwd_run(g, y, wt, blk, blk2, obi, meta, dsrc, ddst, rows,
-                  nsteps: int, c2: int, out_rows: int,
-                  interpret: bool = False, exact: bool = False,
-                  geom: Geometry = None, relu: bool = False,
-                  nparity: int = 2):
-    Ho = g.shape[-1]
-    Hi = wt.shape[-1]
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    srows = c2 * geom.ch2
-    # The saved-output blocks (relu mask source) ride the SAME index maps
-    # as the cotangent blocks: masking happens per source row, before the
-    # one-hot expand.
-    y_specs = [
-        pl.BlockSpec((SB, Ho), lambda c, b, b2, o: (b[c], 0)),
-        pl.BlockSpec((SB, Ho), lambda c, b, b2, o: (b2[c], 0)),
-    ] if relu else []
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                  # blk, blk2, obi [S]
-        grid=(nsteps,),
-        in_specs=[
-            pl.BlockSpec((8, 4), lambda c, b, b2, o: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((8, KD), lambda c, b, b2, o: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((8, KD), lambda c, b, b2, o: (c // 8, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((CH, 1), lambda c, b, b2, o: (c, 0)),
-            pl.BlockSpec((SB, Ho), lambda c, b, b2, o: (b[c], 0)),
-            pl.BlockSpec((SB, Ho), lambda c, b, b2, o: (b2[c], 0)),
-            *y_specs,
-            # transposed weight tile, constant index: fetched once,
-            # VMEM-resident for the whole grid (the forward's weight
-            # BlockSpec pattern at the transposed shape)
-            pl.BlockSpec((Ho, Hi), lambda c, b, b2, o: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((RB, Ho), lambda c, b, b2, o: (o[c], 0)),
-            pl.BlockSpec((RB, Hi), lambda c, b, b2, o: (o[c], 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((CH, Ho), staging_dtype(geom, exact)),
-                        pltpu.VMEM((nparity, srows, Ho),
-                                   staging_dtype(geom, exact)),
-                        pltpu.SemaphoreType.DMA((1,))],
-    )
-    ins = (blk, blk2, obi, meta, dsrc, ddst, rows, g, g)
-    ins += (y, y) if relu else ()
-    return pl.pallas_call(
-        partial(_mega_bwd_kernel, exact=exact, geom=geom, relu=relu),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((out_rows, Ho), jnp.float32),
-                   jax.ShapeDtypeStruct((out_rows, Hi), jnp.float32)],
-        interpret=interpret,
-    )(*ins, wt)
-
-
-def run_binned_linear_bwd(g, y, w, plan: BinnedPlan,
-                          interpret: bool = False, precision: str = "fast",
-                          relu: bool = False):
-    """Fused backward of the megakernel layer, over the TRANSPOSED plan
-    (ops.aggregate passes plans.bwd): given the output cotangent
-    g [num_rows_fwd, H_out], the saved forward output y (relu mask
-    source; ignored when relu=False) and the layer weight w [H_in, H_out],
-    returns (u, dx) with u = A^T (g * relu_mask) [table_rows_fwd, H_out]
-    and dx = u @ W^T [table_rows_fwd, H_in] — the [rows, H_in] dagg
-    cotangent never reaches HBM.  The caller finishes with the XLA GEMM
-    dW = x^T u.
-
-    Returns None when ANY admission gate fails (non-fused plan, exact on
-    a bf16 unit, ROC_BINNED_NO_FUSE / ROC_NO_MEGAFUSE / ROC_MEGA_BWD=0,
-    or the VMEM budget): the caller must then replay the two-pass
-    composition — which is also the bitwise oracle the fused path is
-    tested against on integer data."""
-    if precision not in ("fast", "exact"):
-        raise ValueError(f"precision={precision!r}: must be 'fast' or "
-                         f"'exact'")
-    exact = precision == "exact" and g.dtype == jnp.float32
-    geom = plan.geom or _default_geom()
-    Ho = g.shape[-1]
-    Hi = w.shape[0]
-    Ho_p = _pad_to(Ho, 128)
-    Hi_p = _pad_to(Hi, 128)
-    C2 = plan.p2_obi.shape[1]
-    G = plan.p1_blk.shape[0]
-    if not (geom.flat and plan.f_meta is not None
-            and plan.f_last is not None
-            and not (exact and geom.unit == 16)
-            and not os.environ.get("ROC_BINNED_NO_FUSE")
-            and not megafuse_killed()
-            and not mega_bwd_killed()
-            and _mega_bwd_vmem_ok(geom, Ho_p, Hi_p, C2, groups=G,
-                                  relu=relu)):
-        return None
-    out_rows = G * plan.bins_per_group * geom.rb
-    rows_pad = _pad_to(plan.table_rows, geom.sb)
-    gp = jnp.pad(g, ((0, rows_pad - g.shape[0]), (0, Ho_p - Ho)))
-    # pad rows carry y=0 -> masked to zero, matching their zero cotangent
-    yp = jnp.pad(y, ((0, rows_pad - y.shape[0]), (0, Ho_p - Ho))) \
-        if relu else None
-    # fp32 W^T, zero-padded: pad H_out rows multiply g's zero pad lanes,
-    # pad H_in lanes are stripped from dx below
-    wtp = jnp.pad(jnp.transpose(w.astype(jnp.float32)),
-                  ((0, Ho_p - Ho), (0, Hi_p - Hi)))
-    S = int(plan.f_blk.shape[0])
-    with jax.named_scope("roc_binned_mega_bwd"):
-        u, dx = _mega_bwd_run(gp, yp, wtp, plan.f_blk, plan.f_blk2,
-                              plan.f_obi, plan.f_meta, plan.f_dsrc,
-                              plan.f_ddst, plan.f_rows, S, C2, out_rows,
-                              interpret, exact, geom, relu,
-                              1 if G == 1 else 2)
-    return u[:plan.num_rows, :Ho], dx[:plan.num_rows, :Hi]
-
-
-# ---------------------------------------------------------------------------
-# CROSS-LAYER megakernel (round 16): a whole fusion REGION —
-# aggregate -> linear (-> relu) [-> fold scales] -> aggregate -> linear ... —
-# in ONE Pallas grid.  The flat fused schedule is depth-agnostic: the grid
-# replays the SAME plan steps once per layer (step = c % S, depth = c // S),
-# and layer d's post-linear [RB, H] tiles accumulate into a VMEM-resident
-# inter-layer buffer that layer d+1's phase-1 staging reads back at block
-# granularity — the [rows, H] layer boundary never touches HBM for
-# shard-local rows.  Per-depth weights ride a stacked [D, Hm, Hm] input
-# whose (1, Hm, Hm) BlockSpec double-buffers the NEXT depth's tile while
-# the current one computes.  Admission (region_ok) additionally requires a
-# SQUARE shard-local plan (table_rows == num_rows: no halo frontier — the
-# SPMD path keeps per-layer fusion) and full bin coverage of the block
-# range (out_rows >= padded table_rows) so every inter-layer block read
-# lands in a window the schedule zeroed (every bin opens with first=1,
-# empty bins included — _attach_fused).
-# ---------------------------------------------------------------------------
-
-# ROC_XLAYER=0 kill switch: disables REGION fusion only — per-layer
-# megakernels (rounds 8-12) keep running, restoring PR-10 behavior
-# exactly.  Warn-once like the other program-changing switches.
-_XLAYER_KILL_WARNED = [False]
-
-
-def xlayer_killed() -> bool:
-    """True when ROC_XLAYER=0 disables cross-layer fusion-region kernels
-    at runtime (checked at every region dispatch; warn-once).  Per-layer
-    megakernel fusion is unaffected."""
-    if os.environ.get("ROC_XLAYER", "") != "0":
-        return False
-    if not _XLAYER_KILL_WARNED[0]:
-        _XLAYER_KILL_WARNED[0] = True
-        warnings.warn(
-            "ROC_XLAYER=0: cross-layer fusion regions disabled; eligible "
-            "regions run the per-layer megakernel chain instead.",
-            stacklevel=2)
-    return True
-
-
-def _xlayer_vmem_ok(geom: Geometry, Hm_p: int, c2: int, depth: int,
-                    groups: int = 2, tp: int = 0) -> bool:
-    """Trace-time admission for the cross-layer FORWARD grid: the
-    per-layer megakernel's residents (_mega_vmem_ok) at the region's
-    uniform padded width, with the weight tile DOUBLE-buffered (its block
-    index now changes once per depth), plus the inter-layer VMEM buffers
-    — one [tp, Hm] activation plane for depth 2, two (ping-pong) beyond.
-    This is the term that keys region admission to SHARD-local row
-    counts: at full-graph scale tp*Hm busts the budget and the planner
-    declines down to per-layer fusion."""
-    srows = c2 * geom.ch2
-    stg = staging_itemsize(geom, False)
-    nparity = 1 if groups == 1 else 2
-    ipar = 1 if depth == 2 else 2
-    need = (nparity * srows * Hm_p * stg + geom.ch * Hm_p * stg
-            + max(geom.ch * geom.sb, geom.ch2 * geom.rb) * 2
-            + 2 * geom.sb * Hm_p * 4
-            + 2 * Hm_p * Hm_p * 4        # per-depth weight, double-buffered
-            + geom.rb * Hm_p * 4         # per-chunk aggregate tile
-            + geom.rb * Hm_p * 4         # out window
-            + ipar * tp * Hm_p * 4)      # inter-layer activation planes
-    return need <= _VMEM_BUDGET
-
-
-def _xlayer_bwd_vmem_ok(geom: Geometry, Hm_p: int, c2: int, depth: int,
-                        groups: int = 2, tp: int = 0,
-                        relu_last: bool = False) -> bool:
-    """Trace-time admission for the cross-layer BACKWARD grid: staging +
-    one-hot residents at the region width, the streamed blocks (x pair
-    for the replay, cotangent pair, saved-output pair when the last layer
-    fused a relu, plus the dW z-window), BOTH stacked weight inputs and
-    the dW out block double-buffered, and the big ones — (depth-1)
-    replayed activation planes plus the cotangent ping-pong."""
-    srows = c2 * geom.ch2
-    stg = staging_itemsize(geom, False)
-    nparity = 1 if groups == 1 else 2
-    ncg = 1 if depth == 2 else 2
-    need = (nparity * srows * Hm_p * stg + geom.ch * Hm_p * stg
-            + max(geom.ch * geom.sb, geom.ch2 * geom.rb) * 2
-            + (4 + (2 if relu_last else 0)) * geom.sb * Hm_p * 4
-            + geom.rb * Hm_p * 4         # dW z window
-            + 4 * Hm_p * Hm_p * 4        # ws + wst, double-buffered
-            + 2 * Hm_p * Hm_p * 4        # dW out block, double-buffered
-            + geom.rb * Hm_p * 4         # per-chunk cotangent tile
-            + geom.rb * Hm_p * 4         # dx out window
-            + (depth - 1 + ncg) * tp * Hm_p * 4)  # replay + cotangent
-    return need <= _VMEM_BUDGET
-
-
-def region_ok(plan: BinnedPlan, widths, precision: str = "fast",
-              x_dtype=jnp.float32) -> bool:
-    """Trace-time admission for a fusion REGION over this (forward) plan.
-    ``widths`` is the region's feature-width chain (H_0, H_1, ..., H_D);
-    all gating is static, so a False here lets the executor hook decline
-    and the per-layer (depth-1) program run byte-identical.  Mirrors the
-    per-layer megakernel gates plus the region-only ones: >=2 layers, a
-    square shard-local plan (table_rows == num_rows — halo-frontier rows
-    would read garbage from the inter-layer buffer), bin coverage of the
-    whole block range, the ROC_XLAYER kill switch, and the region VMEM
-    price."""
-    geom = plan.geom or _default_geom()
-    depth = len(widths) - 1
-    exact = precision == "exact" and x_dtype == jnp.float32
-    if depth < 2 or geom is None or not geom.flat:
-        return False
-    if plan.f_meta is None or plan.f_last is None:
-        return False
-    Hm_p = max(_pad_to(int(h), 128) for h in widths)
-    C2 = plan.p2_obi.shape[1]
-    G = plan.p1_blk.shape[0]
-    out_rows = G * plan.bins_per_group * geom.rb
-    tp = _pad_to(max(_pad_to(plan.table_rows, geom.sb), out_rows),
-                 max(geom.sb, geom.rb))
-    return (not (exact and geom.unit == 16)
-            and not os.environ.get("ROC_BINNED_NO_FUSE")
-            and not megafuse_killed()
-            and not xlayer_killed()
-            and plan.table_rows == plan.num_rows
-            and out_rows >= _pad_to(plan.table_rows, geom.sb)
-            and _xlayer_vmem_ok(geom, Hm_p, C2, depth, groups=G, tp=tp))
-
-
-def _xlayer_kernel(*args, exact: bool = False, geom: Geometry = None,
-                   depth: int = 2, nsteps_per: int = 0, relus=(),
-                   fold: bool = False):
-    """Cross-layer forward: grid step c runs plan step c % S at depth
-    c // S.  Depth 0's phase 1 stages from the x HBM blocks exactly like
-    _mega_kernel; depth d>0 stages from the inter-layer VMEM plane that
-    depth d-1's phase 2 filled (parity (d-1) % ipar).  Phase 2 at the
-    LAST depth accumulates tile @ W_d into the HBM out window (index
-    pinned to 0 on earlier depths: block 0 is also the first real bin,
-    so its first=1 zeroing lands before any real writeback); earlier
-    depths accumulate into their inter-layer window and, on the bin's
-    last real chunk (f_last), apply the layer epilogue in place — relu,
-    then for norm-folded regions the two diagonal scales (v*s)*s, the
-    exact multiply sequence the per-layer hook runs outside the kernel,
-    so the staged values match the depth-1 chain bitwise on fp32."""
-    if fold:
-        (blk_ref, blk2_ref, obi_ref, last_ref, meta_ref, dsrc_ref,
-         ddst_ref, rows_ref, x_ref, x2_ref, ws_ref, s_ref, out_ref,
-         gbuf, stgbuf, tbuf, sems) = args
-    else:
-        (blk_ref, blk2_ref, obi_ref, last_ref, meta_ref, dsrc_ref,
-         ddst_ref, rows_ref, x_ref, x2_ref, ws_ref, out_ref,
-         gbuf, stgbuf, tbuf, sems) = args
-        s_ref = None
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    U = geom.unit_rows
-    st = staging_dtype(geom, exact)
-    S = nsteps_per
-    D = depth
-    ipar = 1 if D == 2 else 2
-    c = pl.program_id(0)
-    step = c % S
-    d = c // S
-    kind = meta_ref[c % 8, 0]
-    par = meta_ref[c % 8, 1]
-    first = meta_ref[c % 8, 2]
-    sq = meta_ref[c % 8, 3]
-
-    @pl.when(kind == 0)
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-        sl = rows_ref[:]
-        t1 = (lane == sl).astype(jnp.bfloat16)
-        t2 = (lane == sl - SB).astype(jnp.bfloat16)
-        two = blk2_ref[step] != blk_ref[step]
-
-        @pl.when(d == 0)
-        def _():
-            gbuf[:] = _onehot_dot(t1, x_ref[:], (((1,), (0,)), ((), ())),
-                                  exact).astype(st)
-
-            @pl.when(two)
-            def _():
-                gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                    t2, x2_ref[:], (((1,), (0,)), ((), ())),
-                    exact)).astype(st)
-
-        for dd in range(1, D):
-            @pl.when(d == dd)
-            def _(dd=dd):
-                j = (dd - 1) % ipar
-                src = tbuf[j, pl.ds(blk_ref[step] * SB, SB), :]
-                gbuf[:] = _onehot_dot(t1, src, (((1,), (0,)), ((), ())),
-                                      exact).astype(st)
-
-                @pl.when(two)
-                def _(j=j):
-                    src2 = tbuf[j, pl.ds(blk2_ref[step] * SB, SB), :]
-                    gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                        t2, src2, (((1,), (0,)), ((), ())),
-                        exact)).astype(st)
-
-        def issue(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).start()
-            return 0
-        jax.lax.fori_loop(0, KD, issue, 0)
-
-        def drain(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).wait()
-            return 0
-        jax.lax.fori_loop(0, KD, drain, 0)
-
-    @pl.when(kind == 1)
-    def _():
-        dl = rows_ref[:]
-        chunk = stgbuf[par, pl.ds(sq * CH, CH)]
-        rows = jnp.where(dl == RB, jnp.float32(0), chunk)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, RB), 1)
-        s_t = (lane == dl).astype(jnp.bfloat16)
-        tile = _onehot_dot(s_t, rows, (((0,), (0,)), ((), ())), exact)
-        contrib = jax.lax.dot_general(
-            tile, ws_ref[0], (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-        @pl.when(d == D - 1)
-        def _():
-            @pl.when(first == 1)
-            def _():
-                out_ref[:] = jnp.zeros_like(out_ref)
-
-            out_ref[:] += contrib
-            if relus[-1]:
-                @pl.when(last_ref[step] == 1)
-                def _():
-                    out_ref[:] = jnp.maximum(out_ref[:], 0.0)
-
-        for dd in range(D - 1):
-            @pl.when(d == dd)
-            def _(dd=dd):
-                j = dd % ipar
-                off = obi_ref[step] * RB
-
-                @pl.when(first == 1)
-                def _(j=j):
-                    tbuf[j, pl.ds(off, RB), :] = jnp.zeros(
-                        (RB, tbuf.shape[-1]), jnp.float32)
-
-                tbuf[j, pl.ds(off, RB), :] = (
-                    tbuf[j, pl.ds(off, RB), :] + contrib)
-
-                @pl.when(last_ref[step] == 1)
-                def _(dd=dd, j=j):
-                    v = tbuf[j, pl.ds(off, RB), :]
-                    if relus[dd]:
-                        v = jnp.maximum(v, 0.0)
-                    if fold:
-                        v = (v * s_ref[:]) * s_ref[:]
-                    tbuf[j, pl.ds(off, RB), :] = v
-
-
-@partial(jax.jit, static_argnames=("nsteps_per", "c2", "out_rows", "tp",
-                                   "interpret", "exact", "geom", "depth",
-                                   "relus", "fold", "nparity"))
-def _xlayer_run(x, ws, s, blk, blk2, obi, last, meta, dsrc, ddst, rows,
-                nsteps_per: int, c2: int, out_rows: int, tp: int,
-                interpret: bool = False, exact: bool = False,
-                geom: Geometry = None, depth: int = 2, relus=(),
-                fold: bool = False, nparity: int = 2):
-    Hm = x.shape[-1]
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    S = nsteps_per
-    D = depth
-    srows = c2 * geom.ch2
-    ipar = 1 if D == 2 else 2
-    in_specs = [
-        pl.BlockSpec((8, 4), lambda c, b, b2, o, l: ((c % S) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((8, KD), lambda c, b, b2, o, l: ((c % S) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((8, KD), lambda c, b, b2, o, l: ((c % S) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((CH, 1), lambda c, b, b2, o, l: (c % S, 0)),
-        # x blocks stream at depth 0 only; pinned to block 0 above so the
-        # buffer never refetches while the inter-layer planes feed
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c // S == 0, b[c % S], 0), 0)),
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c // S == 0, b2[c % S], 0), 0)),
-        # stacked per-depth weights: the block index changes once per
-        # depth, so pallas double-buffers the NEXT layer's tile
-        pl.BlockSpec((1, Hm, Hm), lambda c, b, b2, o, l: (c // S, 0, 0)),
-    ]
-    if fold:
-        in_specs.append(
-            pl.BlockSpec((RB, 1), lambda c, b, b2, o, l: (o[c % S], 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                  # blk, blk2, obi, last [S]
-        grid=(D * S,),
-        in_specs=in_specs,
-        # real out windows on the last depth only; the pin to block 0 on
-        # earlier depths is safe because the out index is nondecreasing
-        # from bin 0, whose first=1 zeroing precedes any writeback
-        out_specs=pl.BlockSpec(
-            (RB, Hm),
-            lambda c, b, b2, o, l: (
-                jnp.where(c // S == D - 1, o[c % S], 0), 0)),
-        scratch_shapes=[pltpu.VMEM((CH, Hm), staging_dtype(geom, exact)),
-                        pltpu.VMEM((nparity, srows, Hm),
-                                   staging_dtype(geom, exact)),
-                        pltpu.VMEM((ipar, tp, Hm), jnp.float32),
-                        pltpu.SemaphoreType.DMA((1,))],
-    )
-    ins = (blk, blk2, obi, last, meta, dsrc, ddst, rows, x, x, ws)
-    ins += (s,) if fold else ()
-    return pl.pallas_call(
-        partial(_xlayer_kernel, exact=exact, geom=geom, depth=D,
-                nsteps_per=S, relus=relus, fold=fold),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, Hm), jnp.float32),
-        interpret=interpret,
-    )(*ins)
-
-
-def run_binned_region(x, ws, in_degree, plan: BinnedPlan,
-                      interpret: bool = False, precision: str = "fast",
-                      activations=(), fold: bool = False):
-    """relu_D(A ... relu_1(A (x W_1)) W_2 ...) — a whole fusion region in
-    ONE Pallas grid.  ``ws`` is the region's weight chain (depth =
-    len(ws) >= 2), ``activations`` the per-layer "none"/"relu" chain, and
-    for norm-folded (GCN) regions ``fold=True`` applies the interior
-    (D^-1/2)^2 diagonal scales in-kernel from ``in_degree`` (the caller
-    still owns the region-boundary pre/post scales, exactly like the
-    per-layer hook).  The caller MUST pre-gate with region_ok — this
-    asserts it, because a half-admitted region has no cheap fallback
-    composition at this level (ops.aggregate.region_linear_binned owns
-    the differentiable wrapper and oracle)."""
-    if any(a not in ("none", "relu") for a in activations):
-        raise ValueError(f"activations={activations!r}: the region kernel "
-                         f"fuses 'none' or 'relu' only")
-    if precision not in ("fast", "exact"):
-        raise ValueError(f"precision={precision!r}: must be 'fast' or "
-                         f"'exact'")
-    D = len(ws)
-    widths = (x.shape[-1],) + tuple(w.shape[-1] for w in ws)
-    assert region_ok(plan, widths, precision, x.dtype), \
-        "run_binned_region called without region_ok admission"
-    exact = precision == "exact" and x.dtype == jnp.float32
-    geom = plan.geom or _default_geom()
-    Hm = max(_pad_to(int(h), 128) for h in widths)
-    C2 = plan.p2_obi.shape[1]
-    G = plan.p1_blk.shape[0]
-    out_rows = G * plan.bins_per_group * geom.rb
-    rows_pad = _pad_to(plan.table_rows, geom.sb)
-    tp = _pad_to(max(rows_pad, out_rows), max(geom.sb, geom.rb))
-    xp = jnp.pad(x, ((0, rows_pad - x.shape[0]), (0, Hm - x.shape[-1])))
-    wsp = jnp.stack([jnp.pad(w.astype(jnp.float32),
-                             ((0, Hm - w.shape[0]), (0, Hm - w.shape[1])))
-                     for w in ws])
-    sp = None
-    if fold:
-        # the EXACT per-row multiplier ops.indegree_norm applies (x *
-        # rsqrt(deg)); pad rows scale by 1 so zeros stay zeros
-        sp = jnp.pad(jax.lax.rsqrt(in_degree)[:, None],
-                     ((0, tp - in_degree.shape[0]), (0, 0)),
-                     constant_values=1.0)
-    relus = tuple(a == "relu" for a in activations)
-    S = int(plan.f_blk.shape[0])
-    with jax.named_scope("roc_binned_xlayer"):
-        out = _xlayer_run(xp, wsp, sp, plan.f_blk, plan.f_blk2, plan.f_obi,
-                          plan.f_last, plan.f_meta, plan.f_dsrc,
-                          plan.f_ddst, plan.f_rows, S, C2, out_rows, tp,
-                          interpret, exact, geom, D, relus, fold,
-                          1 if G == 1 else 2)
-    return out[:plan.num_rows, :ws[-1].shape[-1]].astype(x.dtype)
-
-
-def _xlayer_bwd_kernel(*args, exact: bool = False, geom: Geometry = None,
-                       depth: int = 2, sf: int = 0, sbs: int = 0, relus=(),
-                       fold: bool = False):
-    """Cross-layer backward: one grid, two phases.  Steps [0, (D-1)*sf)
-    REPLAY the forward over the fwd plan (arrays [0, sf) of the
-    concatenated schedule), filling the (D-1) inter-layer activation
-    planes — scaled form for fold, exactly what the per-layer chain
-    staged.  Steps after run D sweeps of the TRANSPOSED plan (arrays
-    [sf, sf+sbs)), layer order ld = D-1-db: phase 1 stages the layer's
-    output cotangent — from g HBM blocks at db=0 (masked by the saved
-    region output, the per-layer rule) or from the cotangent ping-pong
-    plane at db>0 (fold scales (s*)(s*) then the replayed-plane relu
-    mask, the exact per-layer outside-ops order) — and phase 2
-    accumulates BOTH gradients per chunk: dW_ld += z^T @ tile in the
-    resident [1, Hm, Hm] dW block (z = the replayed previous-layer plane
-    window, or the x window at ld=0; valid by distributivity — the same
-    z window spans all of a bin's chunks, and masked pad rows contribute
-    exact zeros) and the cotangent hand-off tile @ W_ld^T into the
-    OTHER ping-pong parity (or the dx HBM window at db=D-1).  u never
-    exists in HBM; each dW block zeroes at its depth's first step."""
-    args = list(args)
-    blk_ref, blk2_ref, obi_ref, last_ref = args[:4]
-    (meta_ref, dsrc_ref, ddst_ref, rows_ref,
-     x_ref, x2_ref, g_ref, g2_ref) = args[4:12]
-    i = 12
-    if relus[-1]:
-        y_ref, y2_ref = args[i:i + 2]
-        i += 2
-    else:
-        y_ref = y2_ref = None
-    xw_ref, ws_ref, wst_ref = args[i:i + 3]
-    i += 3
-    if fold:
-        s_ref, sb1_ref, sb2_ref = args[i:i + 3]
-        i += 3
-    else:
-        s_ref = sb1_ref = sb2_ref = None
-    dw_ref, dx0_ref, gbuf, stgbuf, tbuf, cg, sems = args[i:]
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    U = geom.unit_rows
-    st = staging_dtype(geom, exact)
-    D = depth
-    RPT = (D - 1) * sf
-    NCG = 1 if D == 2 else 2
-    c = pl.program_id(0)
-    in_rep = c < RPT
-    in_bwd = jnp.logical_not(in_rep)
-    cb = c - RPT
-    pidx = jnp.where(in_rep, c % sf, sf + cb % sbs)
-    kind = meta_ref[c % 8, 0]
-    par = meta_ref[c % 8, 1]
-    first = meta_ref[c % 8, 2]
-    sq = meta_ref[c % 8, 3]
-
-    # the resident dW block zeroes at its depth's first step (the block
-    # index just switched to this depth, so the fetched content is HBM
-    # garbage or a stale writeback — never real)
-    @pl.when(in_bwd & (cb % sbs == 0))
-    def _():
-        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
-
-    @pl.when(kind == 0)
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, SB), 1)
-        sl = rows_ref[:]
-        t1 = (lane == sl).astype(jnp.bfloat16)
-        t2 = (lane == sl - SB).astype(jnp.bfloat16)
-        two = blk2_ref[pidx] != blk_ref[pidx]
-
-        @pl.when(in_rep & (c < sf))
-        def _():
-            gbuf[:] = _onehot_dot(t1, x_ref[:], (((1,), (0,)), ((), ())),
-                                  exact).astype(st)
-
-            @pl.when(two)
-            def _():
-                gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                    t2, x2_ref[:], (((1,), (0,)), ((), ())),
-                    exact)).astype(st)
-
-        for dd in range(1, D - 1):
-            @pl.when(in_rep & (c // sf == dd))
-            def _(dd=dd):
-                src = tbuf[dd - 1, pl.ds(blk_ref[pidx] * SB, SB), :]
-                gbuf[:] = _onehot_dot(t1, src, (((1,), (0,)), ((), ())),
-                                      exact).astype(st)
-
-                @pl.when(two)
-                def _(dd=dd):
-                    src2 = tbuf[dd - 1, pl.ds(blk2_ref[pidx] * SB, SB), :]
-                    gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                        t2, src2, (((1,), (0,)), ((), ())),
-                        exact)).astype(st)
-
-        @pl.when(in_bwd & (cb < sbs))
-        def _():
-            gv = g_ref[:]
-            gv2 = g2_ref[:]
-            if relus[-1]:
-                gv = jnp.where(y_ref[:] > 0, gv, jnp.zeros_like(gv))
-                gv2 = jnp.where(y2_ref[:] > 0, gv2, jnp.zeros_like(gv2))
-            gbuf[:] = _onehot_dot(t1, gv, (((1,), (0,)), ((), ())),
-                                  exact).astype(st)
-
-            @pl.when(two)
-            def _():
-                gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                    t2, gv2, (((1,), (0,)), ((), ())), exact)).astype(st)
-
-        for dbs in range(1, D):
-            @pl.when(in_bwd & (cb // sbs == dbs))
-            def _(dbs=dbs):
-                ld = D - 1 - dbs
-                gv = cg[(dbs - 1) % NCG,
-                        pl.ds(blk_ref[pidx] * SB, SB), :]
-                if fold:
-                    gv = (gv * sb1_ref[:]) * sb1_ref[:]
-                if relus[ld]:
-                    msk = tbuf[ld, pl.ds(blk_ref[pidx] * SB, SB), :]
-                    gv = jnp.where(msk > 0, gv, jnp.zeros_like(gv))
-                gbuf[:] = _onehot_dot(t1, gv, (((1,), (0,)), ((), ())),
-                                      exact).astype(st)
-
-                @pl.when(two)
-                def _(dbs=dbs, ld=ld):
-                    gv2 = cg[(dbs - 1) % NCG,
-                             pl.ds(blk2_ref[pidx] * SB, SB), :]
-                    if fold:
-                        gv2 = (gv2 * sb2_ref[:]) * sb2_ref[:]
-                    if relus[ld]:
-                        msk2 = tbuf[ld,
-                                    pl.ds(blk2_ref[pidx] * SB, SB), :]
-                        gv2 = jnp.where(msk2 > 0, gv2,
-                                        jnp.zeros_like(gv2))
-                    gbuf[:] = (gbuf[:].astype(jnp.float32) + _onehot_dot(
-                        t2, gv2, (((1,), (0,)), ((), ())),
-                        exact)).astype(st)
-
-        def issue(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).start()
-            return 0
-        jax.lax.fori_loop(0, KD, issue, 0)
-
-        def drain(e, _):
-            v = dsrc_ref[c % 8, e]
-
-            @pl.when(v >= 0)
-            def _():
-                cls = v // 65536
-                su = v - cls * 65536
-                du = ddst_ref[c % 8, e]
-                for ci, csz in enumerate(_DMA_CLS):
-                    @pl.when(cls == ci)
-                    def _(csz=csz):
-                        pltpu.make_async_copy(
-                            gbuf.at[pl.ds(su * U, csz * U)],
-                            stgbuf.at[par].at[
-                                pl.ds(du * U, csz * U)],
-                            sems.at[0]).wait()
-            return 0
-        jax.lax.fori_loop(0, KD, drain, 0)
-
-    @pl.when(kind == 1)
-    def _():
-        dl = rows_ref[:]
-        chunk = stgbuf[par, pl.ds(sq * CH, CH)]
-        rows = jnp.where(dl == RB, jnp.float32(0), chunk)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (CH, RB), 1)
-        s_t = (lane == dl).astype(jnp.bfloat16)
-        tile = _onehot_dot(s_t, rows, (((0,), (0,)), ((), ())), exact)
-
-        for dd in range(D - 1):
-            @pl.when(in_rep & (c // sf == dd))
-            def _(dd=dd):
-                contrib = jax.lax.dot_general(
-                    tile, ws_ref[0], (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
-                off = obi_ref[pidx] * RB
-
-                @pl.when(first == 1)
-                def _(dd=dd):
-                    tbuf[dd, pl.ds(off, RB), :] = jnp.zeros(
-                        (RB, tbuf.shape[-1]), jnp.float32)
-
-                tbuf[dd, pl.ds(off, RB), :] = (
-                    tbuf[dd, pl.ds(off, RB), :] + contrib)
-
-                @pl.when(last_ref[pidx] == 1)
-                def _(dd=dd):
-                    v = tbuf[dd, pl.ds(off, RB), :]
-                    if relus[dd]:
-                        v = jnp.maximum(v, 0.0)
-                    if fold:
-                        v = (v * s_ref[:]) * s_ref[:]
-                    tbuf[dd, pl.ds(off, RB), :] = v
-
-        for dbs in range(D):
-            @pl.when(in_bwd & (cb // sbs == dbs))
-            def _(dbs=dbs):
-                ld = D - 1 - dbs
-                off = obi_ref[pidx] * RB
-                if ld == 0:
-                    z = xw_ref[:]
-                else:
-                    z = tbuf[ld - 1, pl.ds(off, RB), :]
-                dw_ref[0] = dw_ref[0] + jax.lax.dot_general(
-                    z, tile, (((0,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
-                dxc = jax.lax.dot_general(
-                    tile, wst_ref[0], (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
-                if dbs == D - 1:
-                    @pl.when(first == 1)
-                    def _():
-                        dx0_ref[:] = jnp.zeros_like(dx0_ref)
-
-                    dx0_ref[:] += dxc
-                else:
-                    j = dbs % NCG
-
-                    @pl.when(first == 1)
-                    def _(j=j):
-                        cg[j, pl.ds(off, RB), :] = jnp.zeros(
-                            (RB, cg.shape[-1]), jnp.float32)
-
-                    cg[j, pl.ds(off, RB), :] = (
-                        cg[j, pl.ds(off, RB), :] + dxc)
-
-
-@partial(jax.jit, static_argnames=("sf", "sbs", "c2", "out_rows", "tp",
-                                   "interpret", "exact", "geom", "depth",
-                                   "relus", "fold", "nparity"))
-def _xlayer_bwd_run(x, g, y, ws, wst, s, blk, blk2, obi, last, meta, dsrc,
-                    ddst, rows, sf: int, sbs: int, c2: int, out_rows: int,
-                    tp: int, interpret: bool = False, exact: bool = False,
-                    geom: Geometry = None, depth: int = 2, relus=(),
-                    fold: bool = False, nparity: int = 2):
-    Hm = x.shape[-1]
-    CH, SB, RB, KD = geom.ch, geom.sb, geom.rb, geom.kd            # noqa
-    D = depth
-    RPT = (D - 1) * sf
-    srows = c2 * geom.ch2
-    ncg = 1 if D == 2 else 2
-
-    def pidx(c):
-        return jnp.where(c < RPT, c % sf, sf + (c - RPT) % sbs)
-
-    in_specs = [
-        pl.BlockSpec((8, 4), lambda c, b, b2, o, l: (pidx(c) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((8, KD), lambda c, b, b2, o, l: (pidx(c) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((8, KD), lambda c, b, b2, o, l: (pidx(c) // 8, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((CH, 1), lambda c, b, b2, o, l: (pidx(c), 0)),
-        # x blocks feed the replay's depth 0 only
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c < sf, b[pidx(c)], 0), 0)),
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c < sf, b2[pidx(c)], 0), 0)),
-        # region-output cotangent blocks feed the backward's first sweep
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where((c >= RPT) & (c < RPT + sbs),
-                                   b[pidx(c)], 0), 0)),
-        pl.BlockSpec((SB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where((c >= RPT) & (c < RPT + sbs),
-                                   b2[pidx(c)], 0), 0)),
-    ]
-    if relus[-1]:
-        in_specs += [
-            pl.BlockSpec((SB, Hm),
-                         lambda c, b, b2, o, l: (
-                             jnp.where((c >= RPT) & (c < RPT + sbs),
-                                       b[pidx(c)], 0), 0)),
-            pl.BlockSpec((SB, Hm),
-                         lambda c, b, b2, o, l: (
-                             jnp.where((c >= RPT) & (c < RPT + sbs),
-                                       b2[pidx(c)], 0), 0)),
-        ]
-    in_specs += [
-        # dW z windows at layer 0 (the last backward sweep)
-        pl.BlockSpec((RB, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c >= RPT + (D - 1) * sbs,
-                                   o[pidx(c)], 0), 0)),
-        pl.BlockSpec((1, Hm, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c < RPT, c // sf, 0), 0, 0)),
-        pl.BlockSpec((1, Hm, Hm),
-                     lambda c, b, b2, o, l: (
-                         jnp.where(c >= RPT,
-                                   D - 1 - (c - RPT) // sbs, 0), 0, 0)),
-    ]
-    if fold:
-        in_specs += [
-            pl.BlockSpec((RB, 1),
-                         lambda c, b, b2, o, l: (
-                             jnp.where(c < RPT, o[pidx(c)], 0), 0)),
-            pl.BlockSpec((SB, 1),
-                         lambda c, b, b2, o, l: (
-                             jnp.where(c >= RPT, b[pidx(c)], 0), 0)),
-            pl.BlockSpec((SB, 1),
-                         lambda c, b, b2, o, l: (
-                             jnp.where(c >= RPT, b2[pidx(c)], 0), 0)),
-        ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # concatenated blk, blk2, obi, last
-        grid=(RPT + D * sbs,),
-        in_specs=in_specs,
-        out_specs=[
-            # per-depth dW blocks: index walks D-1 .. 0 across the
-            # backward sweeps (block 0's stale replay-phase writeback is
-            # overwritten by its real depth, which runs LAST)
-            pl.BlockSpec((1, Hm, Hm),
-                         lambda c, b, b2, o, l: (
-                             jnp.where(c >= RPT,
-                                       D - 1 - (c - RPT) // sbs, 0),
-                             0, 0)),
-            pl.BlockSpec((RB, Hm),
-                         lambda c, b, b2, o, l: (
-                             jnp.where(c >= RPT + (D - 1) * sbs,
-                                       o[pidx(c)], 0), 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((CH, Hm), staging_dtype(geom, exact)),
-                        pltpu.VMEM((nparity, srows, Hm),
-                                   staging_dtype(geom, exact)),
-                        pltpu.VMEM((D - 1, tp, Hm), jnp.float32),
-                        pltpu.VMEM((ncg, tp, Hm), jnp.float32),
-                        pltpu.SemaphoreType.DMA((1,))],
-    )
-    ins = (blk, blk2, obi, last, meta, dsrc, ddst, rows, x, x, g, g)
-    ins += (y, y) if relus[-1] else ()
-    ins += (x, ws, wst)
-    ins += (s, s, s) if fold else ()
-    return pl.pallas_call(
-        partial(_xlayer_bwd_kernel, exact=exact, geom=geom, depth=D,
-                sf=sf, sbs=sbs, relus=relus, fold=fold),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((D, Hm, Hm), jnp.float32),
-                   jax.ShapeDtypeStruct((out_rows, Hm), jnp.float32)],
-        interpret=interpret,
-    )(*ins)
-
-
-def run_binned_region_bwd(g, y, x, ws, in_degree, fwd_plan: BinnedPlan,
-                          bwd_plan: BinnedPlan, interpret: bool = False,
-                          precision: str = "fast", activations=(),
-                          fold: bool = False):
-    """Fused backward of a whole fusion region: given the region-output
-    cotangent g, the saved region output y (last-layer relu mask source),
-    the saved region input x and weight chain ws, returns
-    (dx [rows, H_0], (dW_1, ..., dW_D)) — interior cotangents ping-pong
-    in VMEM, the relu masks come from an in-kernel forward replay, and
-    every dW accumulates in-kernel (u never exists in HBM).  Integer
-    data reproduces the per-layer-fused chain bitwise; fp32 dW
-    reassociates (bin-ordered adds vs one XLA GEMM) within the
-    documented ULP bound.
-
-    Returns None when ANY admission gate fails (region_ok on the forward
-    plan, the transposed plan's own fused-schedule/geometry gates,
-    ROC_MEGA_BWD=0, or the backward VMEM price): the caller replays the
-    per-layer composition under jax.vjp — the bitwise oracle."""
-    if precision not in ("fast", "exact"):
-        raise ValueError(f"precision={precision!r}: must be 'fast' or "
-                         f"'exact'")
-    D = len(ws)
-    widths = (x.shape[-1],) + tuple(w.shape[-1] for w in ws)
-    geom = fwd_plan.geom or _default_geom()
-    relus = tuple(a == "relu" for a in activations)
-    if not region_ok(fwd_plan, widths, precision, x.dtype):
-        return None
-    if mega_bwd_killed():
-        return None
-    bgeom = bwd_plan.geom or _default_geom()
-    if (bgeom != geom or bwd_plan.f_meta is None
-            or bwd_plan.f_last is None
-            or bwd_plan.table_rows != bwd_plan.num_rows):
-        return None
-    Hm = max(_pad_to(int(h), 128) for h in widths)
-    C2f = fwd_plan.p2_obi.shape[1]
-    C2b = bwd_plan.p2_obi.shape[1]
-    C2 = max(C2f, C2b)
-    Gf = fwd_plan.p1_blk.shape[0]
-    Gb = bwd_plan.p1_blk.shape[0]
-    out_rows_f = Gf * fwd_plan.bins_per_group * geom.rb
-    out_rows_b = Gb * bwd_plan.bins_per_group * geom.rb
-    rows_pad = _pad_to(fwd_plan.table_rows, geom.sb)
-    if out_rows_b < _pad_to(bwd_plan.table_rows, geom.sb):
-        return None
-    tp = _pad_to(max(rows_pad, out_rows_f, out_rows_b),
-                 max(geom.sb, geom.rb))
-    if not _xlayer_bwd_vmem_ok(geom, Hm, C2, D, groups=max(Gf, Gb), tp=tp,
-                               relu_last=relus[-1]):
-        return None
-    exact = precision == "exact" and x.dtype == jnp.float32
-    xp = jnp.pad(x.astype(jnp.float32),
-                 ((0, tp - x.shape[0]), (0, Hm - x.shape[-1])))
-    gp = jnp.pad(g.astype(jnp.float32),
-                 ((0, tp - g.shape[0]), (0, Hm - g.shape[-1])))
-    yp = jnp.pad(y.astype(jnp.float32),
-                 ((0, tp - y.shape[0]), (0, Hm - y.shape[-1]))) \
-        if relus[-1] else None
-    wsp = jnp.stack([jnp.pad(w.astype(jnp.float32),
-                             ((0, Hm - w.shape[0]), (0, Hm - w.shape[1])))
-                     for w in ws])
-    wstp = jnp.stack([jnp.pad(jnp.transpose(w.astype(jnp.float32)),
-                              ((0, Hm - w.shape[1]), (0, Hm - w.shape[0])))
-                      for w in ws])
-    sp = None
-    if fold:
-        sp = jnp.pad(jax.lax.rsqrt(in_degree)[:, None],
-                     ((0, tp - in_degree.shape[0]), (0, 0)),
-                     constant_values=1.0)
-    blkc = jnp.concatenate([fwd_plan.f_blk, bwd_plan.f_blk])
-    blk2c = jnp.concatenate([fwd_plan.f_blk2, bwd_plan.f_blk2])
-    obic = jnp.concatenate([fwd_plan.f_obi, bwd_plan.f_obi])
-    lastc = jnp.concatenate([fwd_plan.f_last, bwd_plan.f_last])
-    metac = jnp.concatenate([fwd_plan.f_meta, bwd_plan.f_meta])
-    dsrcc = jnp.concatenate([fwd_plan.f_dsrc, bwd_plan.f_dsrc])
-    ddstc = jnp.concatenate([fwd_plan.f_ddst, bwd_plan.f_ddst])
-    rowsc = jnp.concatenate([fwd_plan.f_rows, bwd_plan.f_rows])
-    Sf = int(fwd_plan.f_blk.shape[0])
-    Sb = int(bwd_plan.f_blk.shape[0])
-    nparity = 1 if max(Gf, Gb) == 1 else 2
-    with jax.named_scope("roc_binned_xlayer_bwd"):
-        dws, dx0 = _xlayer_bwd_run(xp, gp, yp, wsp, wstp, sp, blkc, blk2c,
-                                   obic, lastc, metac, dsrcc, ddstc, rowsc,
-                                   Sf, Sb, C2, out_rows_b, tp, interpret,
-                                   exact, geom, D, relus, fold, nparity)
-    dx = dx0[:bwd_plan.num_rows, :widths[0]]
-    gws = tuple(dws[d, :ws[d].shape[0], :ws[d].shape[1]]
-                for d in range(D))
-    return dx, gws
 
 
 # one-shot: the eager path is a silent ~9x dispatch-overhead footgun

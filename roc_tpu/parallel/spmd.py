@@ -89,42 +89,6 @@ class ShardedGraphData:
                                        metadata={"static": True})
     xch_comp: str = dataclasses.field(default="plain",
                                       metadata={"static": True})
-    # Whole-layer megakernel mode (config.megafuse).  Static for the same
-    # reason as xch_dtype: flipping it changes tree_structure(gd), so the
-    # step cache re-traces instead of serving the other mode's program.
-    # Sharded steps currently never run the fused kernel itself —
-    # pad_binned_plans strips the f_* schedule at shard stacking, so every
-    # GraphCtx here keeps fuse_linear=None and the unfused sequence runs;
-    # the field exists so the cache signature is honest the day a sharded
-    # fused path lands, and so mode flips are provably retraces today.
-    megafuse: bool = dataclasses.field(default=False,
-                                       metadata={"static": True})
-    # Fused megakernel BACKWARD mode (round 12): megafuse minus the
-    # ROC_MEGA_BWD=0 kill switch, captured at shard_graph time.  Same
-    # honesty contract as megafuse — the sharded steps never run the
-    # fused backward today (f_* schedules are stripped at stacking), but
-    # flipping the kill switch between trainer builds must change
-    # tree_structure(gd) so the step cache provably re-traces.
-    mega_bwd: bool = dataclasses.field(default=False,
-                                       metadata={"static": True})
-    # Cross-layer fusion-region cap (round 16, config.fusion_depth).
-    # Same honesty contract as megafuse/mega_bwd: sharded steps never run
-    # the region kernel today (f_* schedules are stripped at shard
-    # stacking, so fuse_region stays None), but the field keys the step
-    # cache so depth flips between trainer builds are provably retraces —
-    # and so zero-retrace pins hold with a region active on the
-    # single-device path feeding the same cache signature discipline.
-    fusion_depth: int = dataclasses.field(default=1,
-                                          metadata={"static": True})
-    # Fused GAT attention megakernel mode (round 19, ops/pallas/gat.py).
-    # Same honesty contract as megafuse/mega_bwd/fusion_depth: the sharded
-    # steps never run the fused attention kernel today — pad_binned_plans
-    # strips the f_* schedule at shard stacking, so the sharded attend
-    # closure always runs the unfused gat_attend_plan composition — but
-    # the field keys the step cache so a single-device<->sharded megafuse
-    # flip on a GAT model is provably a retrace, not a replay.
-    gat_fused: bool = dataclasses.field(default=False,
-                                        metadata={"static": True})
 
 
 jax.tree_util.register_dataclass(
@@ -133,8 +97,7 @@ jax.tree_util.register_dataclass(
                  "ring_src", "ring_dst", "plans", "gat_plans", "ring_plans",
                  "plans_local", "plans_remote"],
     meta_fields=["backend", "mode", "precision", "xch_dtype", "xch_round",
-                 "xch_comp", "megafuse", "mega_bwd", "fusion_depth",
-                 "gat_fused"])
+                 "xch_comp"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -668,9 +631,8 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
                 precision: str = "exact",
                 gat_backend: str = "xla",
                 halo_overlap: bool = False,
-                xch: tuple = ("fp32", "nearest", "plain"),
-                megafuse: bool = False,
-                fusion_depth: int = 1) -> ShardedGraphData:
+                xch: tuple = ("fp32", "nearest", "plain")
+                ) -> ShardedGraphData:
     if halo is not None:
         src = halo.edge_src_local
     else:
@@ -706,14 +668,6 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
         backend=backend,
         precision=precision,
         xch_dtype=xch[0], xch_round=xch[1], xch_comp=xch[2],
-        megafuse=megafuse,
-        mega_bwd=(megafuse
-                  and os.environ.get("ROC_MEGA_BWD", "") != "0"),
-        fusion_depth=fusion_depth,
-        # Captured at build time like mega_bwd, honest even though the
-        # sharded attend never runs the fused kernel (see field comment).
-        gat_fused=(megafuse and gat_backend == "plan"
-                   and not os.environ.get("ROC_NO_GATFUSE")),
     )
 
 
@@ -1352,9 +1306,7 @@ class SpmdTrainer(BaseTrainer):
                 in_degree=jnp.asarray(self.part.in_degree, jnp.float32),
                 send_idx=None, plans=plans, gat_plans=gat_plans,
                 backend=backend, mode="edge",
-                precision=cfg.aggregate_precision,
-                megafuse=cfg.megafuse,
-                fusion_depth=getattr(cfg, "fusion_depth", 1))
+                precision=cfg.aggregate_precision)
         if self._exchange_mode == "ring":
             from roc_tpu.parallel.ring import build_ring_groups, \
                 build_ring_plans
@@ -1374,9 +1326,7 @@ class SpmdTrainer(BaseTrainer):
                 ring_dst=jnp.asarray(rm.ring_dst),
                 plans=None, ring_plans=ring_plans, backend=backend,
                 mode="ring", precision=cfg.aggregate_precision,
-                xch_dtype=xd, xch_round=xr, xch_comp=xc,
-                megafuse=cfg.megafuse,
-                fusion_depth=getattr(cfg, "fusion_depth", 1))
+                xch_dtype=xd, xch_round=xr, xch_comp=xc)
         if self._exchange_mode == "halo":
             with obs.span("halo_build", parts=self.part.num_parts):
                 self.halo = build_halo_maps(self.part)
@@ -1402,9 +1352,7 @@ class SpmdTrainer(BaseTrainer):
                                cfg.aggregate_precision,
                                gat_backend=gat_backend,
                                halo_overlap=self._halo_overlap(),
-                               xch=self._xch_meta(),
-                               megafuse=cfg.megafuse,
-                               fusion_depth=getattr(cfg, "fusion_depth", 1))
+                               xch=self._xch_meta())
 
     def _build_graph_perhost(self, backend: str,
                              gat_backend: str = "xla") -> ShardedGraphData:
@@ -1477,9 +1425,7 @@ class SpmdTrainer(BaseTrainer):
                     jnp.float32),
                 send_idx=None, plans=plans, gat_plans=gat_plans,
                 backend=backend, mode="edge",
-                precision=cfg.aggregate_precision,
-                megafuse=cfg.megafuse,
-                fusion_depth=getattr(cfg, "fusion_depth", 1))
+                precision=cfg.aggregate_precision)
         local = shard_load.load_local_shards(path, meta, part_ids)
         if self._exchange_mode == "ring":
             # Ring × perhost (closes a round-3 documented fallback): every
@@ -1508,9 +1454,7 @@ class SpmdTrainer(BaseTrainer):
                 ring_dst=jnp.asarray(rm.ring_dst),
                 plans=None, ring_plans=ring_plans, backend=backend,
                 mode="ring", precision=cfg.aggregate_precision,
-                xch_dtype=xd, xch_round=xr, xch_comp=xc,
-                megafuse=cfg.megafuse,
-                fusion_depth=getattr(cfg, "fusion_depth", 1))
+                xch_dtype=xd, xch_round=xr, xch_comp=xc)
         lhalo = shard_load.build_halo_local(meta, local, ag) \
             if self._exchange_mode == "halo" else None
         self.halo = lhalo
@@ -1552,9 +1496,7 @@ class SpmdTrainer(BaseTrainer):
             plans_remote=plans_remote,
             backend=backend,
             precision=cfg.aggregate_precision,
-            xch_dtype=xd, xch_round=xr, xch_comp=xc,
-            megafuse=cfg.megafuse,
-            fusion_depth=getattr(cfg, "fusion_depth", 1))
+            xch_dtype=xd, xch_round=xr, xch_comp=xc)
 
     def _place_parts(self, gd: ShardedGraphData,
                      spec: NamedSharding) -> ShardedGraphData:

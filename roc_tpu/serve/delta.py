@@ -240,8 +240,8 @@ class DeltaJournal:
 
 def _strip_fused(plan):
     """Drop the fused step lists: they inline copies of srcl/dstl, so a
-    patched plan must run the two-pass path (make_gctx's fuse hook
-    degrades gracefully on f_meta=None).  Done at enable time, BEFORE
+    patched plan must run the two-pass path (run_binned takes it on
+    f_meta=None).  Done at enable time, BEFORE
     the first trace (a treedef change after warmup would retrace)."""
     strip = {f: None for f in binned._PLAN_DATA_FIELDS
              if f.startswith("f_")}

@@ -8,7 +8,7 @@ plans from the content-keyed disk cache — a warm cache means cold start
 is a cache load plus ONE jit trace and ZERO plan rebuilds (pinned:
 `cold_start_stats["plan_builds"]` diffs the builder's process counter) —
 and then answers node-level queries by running the existing
-binned/megakernel forward exactly as eval does, gathering the queried
+binned forward exactly as eval does, gathering the queried
 rows in-graph.  No kernel changes; that is the point.
 
 Shape discipline: query batches are bucketed to a power-of-two ladder
@@ -164,7 +164,7 @@ class ServeEngine:
             return
         from roc_tpu.train.driver import make_gctx
         model = self.model
-        n, mega = self.bundle.num_nodes, self.bundle.megafuse
+        n = self.bundle.num_nodes
         # qidx is consumed once per dispatch — donate it where donation
         # is implemented (TPU); on CPU the hint would only warn.
         donate = (4,) if on_tpu() else ()
@@ -172,7 +172,7 @@ class ServeEngine:
         @partial(jax.jit, donate_argnums=donate)
         def serve_step(params, x, gdata, valid, qidx):
             _retrace.note_trace("serve_step")
-            logits = model.apply(params, x, make_gctx(gdata, n, mega),
+            logits = model.apply(params, x, make_gctx(gdata, n),
                                  train=False)
             del valid  # padding rows are sliced off after the sync
             return jnp.take(logits, qidx, axis=0)

@@ -91,22 +91,6 @@ class Config:
     bf16_exchange: str = "plain"  # plain: one bf16 term (half the bytes) |
                                   # compensated: (hi, lo) bf16 pair — fp32
                                   # bytes, parity control for the pipeline
-    megafuse: bool = False        # whole-layer megakernel: fuse each
-                                  # aggregate->linear(->relu) pair into one
-                                  # Pallas grid on the binned-flat backend
-                                  # (ops/pallas/binned.py run_binned_linear)
-                                  # — the [rows, H_in] aggregate never
-                                  # reaches HBM.  Opt-in; off keeps every
-                                  # program byte-identical.  Runtime kill
-                                  # switch: ROC_NO_MEGAFUSE=1
-    fusion_depth: int = 1         # cross-layer fusion-region cap (round 16,
-                                  # needs -megafuse): 1 = per-layer only
-                                  # (default, byte-identical), 2 = chain at
-                                  # most two layers through one Pallas
-                                  # grid, 0 = "full" (unlimited — the whole
-                                  # eligible chain).  Static: keys the step
-                                  # cache via GraphCtx / ShardedGraphData.
-                                  # Runtime kill switch: ROC_XLAYER=0
     autotune: bool = False        # geometry autotuner (roc_tpu/tune): sweep
                                   # this graph's kernel-config space before
                                   # the plan builds and persist the winners
@@ -289,19 +273,6 @@ class Config:
         if self.bf16_exchange not in ("plain", "compensated"):
             raise SystemExit(f"bad bf16_exchange {self.bf16_exchange!r} "
                              "(plain|compensated)")
-        # ROC_MEGAFUSE mirrors -megafuse for driverless entry points
-        # (bench.py, hw_revalidate mega A/B legs); ROC_NO_MEGAFUSE stays a
-        # runtime kill switch checked at dispatch, not a config field.
-        if env.get("ROC_MEGAFUSE"):
-            self.megafuse = env["ROC_MEGAFUSE"] == "1"
-        # ROC_FUSION_DEPTH mirrors -fusion-depth for driverless entry
-        # points (bench.py xlayer legs, hw_revalidate step 4d);
-        # ROC_XLAYER=0 stays a runtime kill switch checked at dispatch.
-        if env.get("ROC_FUSION_DEPTH"):
-            self.fusion_depth = int(env["ROC_FUSION_DEPTH"])
-        if self.fusion_depth < 0:
-            raise SystemExit(f"bad fusion_depth {self.fusion_depth} "
-                             "(0 = full, 1 = off, >=2 = cap)")
         # ROC_AUTOTUNE mirrors -autotune for driverless entry points
         # (bench.py, hw_revalidate's sweep leg); ROC_NO_TUNED stays the
         # runtime kill switch on tuned-store CONSUMPTION.
@@ -409,7 +380,7 @@ def parse_args(argv: List[str]) -> Config:
     p.add_argument("-parts", "-ng", "-ll:gpu", dest="num_parts", type=int,
                    default=1)
     p.add_argument("-model", default="gcn",
-                   choices=["gcn", "gcn-chain", "sage", "gin", "gat"])
+                   choices=["gcn", "sage", "gin", "gat"])
     p.add_argument("-heads", type=int, default=8)
     p.add_argument("-aggr", default="",
                    choices=["", "sum", "avg", "max", "min"])
@@ -433,14 +404,6 @@ def parse_args(argv: List[str]) -> Config:
                    help="sweep the kernel-config space for this graph and "
                         "persist the winners in the tuned store "
                         "(roc_tpu/tune) before building plans")
-    p.add_argument("-megafuse", dest="megafuse", action="store_true",
-                   help="fuse aggregate->linear(->relu) layers into one "
-                        "Pallas megakernel (binned-flat backend)")
-    p.add_argument("-fusion-depth", dest="fusion_depth", type=int,
-                   default=1,
-                   help="cross-layer fusion-region cap (needs -megafuse): "
-                        "1 per-layer only (default), 2 chain two layers, "
-                        "0 full chain")
     p.add_argument("-lazy", dest="lazy_load", action="store_true")
     p.add_argument("-no-halo", dest="halo", action="store_false")
     p.add_argument("-no-halo-overlap", dest="halo_overlap",

@@ -44,23 +44,15 @@ class DenseGraphData:
     in_degree: jnp.ndarray  # [N] float32
     plans: object = None    # ops.AggregatePlans for plan-based backends
     gat_plans: object = None  # ops.edge.GatPlans for plan-backend attention
-    gat_bplans: object = None  # ops.BinnedPlans for the fused GAT megakernel
     backend: str = dataclasses.field(default="xla", metadata={"static": True})
     precision: str = dataclasses.field(default="exact",
                                        metadata={"static": True})
-    # Honesty contract: gat_fused is pytree METADATA, so a gdata built with
-    # fused GAT plans attached and one without produce different treedefs —
-    # the jitted step caches key on it and a megafuse flip retraces instead
-    # of silently replaying the wrong program (mirrors spmd megafuse field).
-    gat_fused: bool = dataclasses.field(default=False,
-                                        metadata={"static": True})
 
 
 jax.tree_util.register_dataclass(
     DenseGraphData,
-    data_fields=["edge_src", "edge_dst", "in_degree", "plans", "gat_plans",
-                 "gat_bplans"],
-    meta_fields=["backend", "precision", "gat_fused"])
+    data_fields=["edge_src", "edge_dst", "in_degree", "plans", "gat_plans"],
+    meta_fields=["backend", "precision"])
 
 
 def pallas_interpret() -> bool:
@@ -87,54 +79,25 @@ AUTO_MATMUL_EDGES = 1 << 20
 AUTO_BINNED = True
 
 
-def resolve_backend_geom(backend: str, num_edges: int, num_rows: int = 0,
-                         table_rows: int = 0, edge_src=None, edge_dst=None,
-                         storage_dtype: str = "fp32",
-                         fuse_linear: bool = False):
-    """Resolve the aggregation backend; returns (backend, geometry).
-
-    With edge arrays provided, the binned-vs-matmul call uses ACTUAL cell
-    statistics (choose_geometry's calibrated cost model, incl. the
-    sparse-graph geometry presets) instead of the uniform-occupancy bound —
-    a locality-preserving vertex order is credited for the cells it never
-    touches, which is what gives products-density graphs a binned path.
-    The chosen forward-direction Geometry rides back so the plan build
-    doesn't redo the O(E) statistics (None when no choice was made).
-
-    ``fuse_linear`` (the -megafuse path) prices every candidate for the
-    aggregate->linear layer handoff: non-mega-eligible schedules pay the
-    intermediate's HBM round trip, so a flat geometry the megakernel can
-    consume wins wherever its schedule is within that credit."""
+def resolve_backend(backend: str, num_edges: int, num_rows: int = 0,
+                    table_rows: int = 0) -> str:
+    """Resolve the aggregation backend from the graph's shape.  Which
+    geometry a binned run takes is decided where its plans are built
+    (ops.build_binned_plans, from the actual cell statistics)."""
     if backend == "auto":
         if not (on_tpu() and num_edges >= AUTO_MATMUL_EDGES):
-            return "xla", None
-        from roc_tpu.ops.pallas.binned import binned_viable, choose_geometry
-        if AUTO_BINNED and num_rows:
-            if edge_src is not None:
-                # beside plan_build, not under it (dense_graph_data)
-                with obs.span("choose_geometry", edges=num_edges):
-                    g, _ = choose_geometry(edge_src, edge_dst, num_rows,
-                                           table_rows,
-                                           storage_dtype=storage_dtype,
-                                           fuse_linear=fuse_linear)
-                if g is not None:
-                    return "binned", g
-            elif binned_viable(num_rows, table_rows, num_edges):
-                return "binned", None
-        return "matmul", None
+            return "xla"
+        from roc_tpu.ops.pallas.binned import binned_viable
+        if AUTO_BINNED and num_rows \
+                and binned_viable(num_rows, table_rows, num_edges):
+            return "binned"
+        return "matmul"
     if backend == "pallas":
         # Round-1's blocked-CSR kernel cannot lower on hardware (per-row DMA
         # slices of tiled HBM refs; docs/PERF.md); "pallas" now names the
         # binned two-phase kernel pair (ops/pallas/binned.py).
-        return "binned", None
-    return backend, None
-
-
-def resolve_backend(backend: str, num_edges: int, num_rows: int = 0,
-                    table_rows: int = 0, edge_src=None,
-                    edge_dst=None) -> str:
-    return resolve_backend_geom(backend, num_edges, num_rows, table_rows,
-                                edge_src, edge_dst)[0]
+        return "binned"
+    return backend
 
 
 def resolve_gat_backend(backend: str, num_edges: int) -> str:
@@ -146,24 +109,6 @@ def resolve_gat_backend(backend: str, num_edges: int) -> str:
         return "plan" if on_tpu() and num_edges >= AUTO_MATMUL_EDGES \
             else "xla"
     return "xla" if backend == "xla" else "plan"
-
-
-def gat_fusion_refused(megafuse: bool) -> str:
-    """Why the fused GAT kernel (ops/pallas/gat.py) is not even tried on
-    this run, or "" when its plan-dependent gates get to decide.  Checked
-    BEFORE its binned plan pair is built.  On a TPU the family is not
-    admitted at all: its max pass asks 20 MiB of scoped VMEM and the
-    compiler of this installation (libtpu 0.0.34) refuses the train step
-    (CHANGES.md PR 21; the repair is ROADMAP Queue 1 item 4), so a run is
-    never routed into it; the trainer's start-up line says so."""
-    from roc_tpu.ops.pallas import gat as _pgat
-    if not megafuse:
-        return "no -megafuse"
-    if os.environ.get("ROC_BINNED_NO_FUSE") or _pgat.gat_fuse_killed():
-        return "kill switch"
-    if on_tpu():
-        return "does not compile on this TPU installation (scoped VMEM)"
-    return ""
 
 
 def gat_plan_stats(plans, num_edges: int) -> dict:
@@ -185,15 +130,6 @@ def model_aggrs(model: Model) -> set:
 
 def model_has_gat(model: Model) -> bool:
     return any(op.kind == "gat" for op in model.ops)
-
-
-def model_gat_dims(model: Model) -> tuple:
-    """(heads, head_dim) of the model's first gat op — the fused-kernel
-    admission shape.  (0, 0) when the model has no attention."""
-    for op in model.ops:
-        if op.kind == "gat":
-            return int(op.attrs["heads"]), int(op.attrs["head_dim"])
-    return 0, 0
 
 
 def effective_backend(config: Config, dataset: Dataset, model: Model,
@@ -248,8 +184,7 @@ def effective_gat_backend(config: Config, dataset: Dataset,
 
 
 def maybe_autotune(edge_src, edge_dst, num_rows: int, table_rows: int,
-                   storage_dtype: str = "fp32", fuse_linear: bool = False,
-                   watchdog=None, log=None):
+                   storage_dtype: str = "fp32", watchdog=None, log=None):
     """-autotune / ROC_AUTOTUNE: sweep this graph's kernel-config space
     (roc_tpu/tune) and persist the winners in the tuned store BEFORE the
     plan builds below, so choose_geometry / build_binned_plan pick them
@@ -263,7 +198,6 @@ def maybe_autotune(edge_src, edge_dst, num_rows: int, table_rows: int,
             return autotune_graph(
                 np.asarray(edge_src), np.asarray(edge_dst), num_rows,
                 table_rows, storage_dtype=storage_dtype,
-                fuse_linear=fuse_linear,
                 device=on_tpu(),
                 watchdog=watchdog, log=log)
     except Exception as e:      # pragma: no cover - defensive
@@ -276,18 +210,12 @@ def dense_graph_data(graph, backend: str = "xla",
                      precision: str = "exact",
                      gat_backend: str = "xla",
                      storage_dtype: str = "fp32",
-                     megafuse: bool = False,
-                     autotune: bool = False,
-                     gat_heads: int = 0,
-                     gat_head_dim: int = 0) -> DenseGraphData:
+                     autotune: bool = False) -> DenseGraphData:
     if autotune:
         maybe_autotune(graph.col_idx, graph.dst_idx, graph.num_nodes,
-                       graph.num_nodes, storage_dtype=storage_dtype,
-                       fuse_linear=megafuse)
-    backend, geom = resolve_backend_geom(
-        backend, graph.num_edges, graph.num_nodes, graph.num_nodes,
-        graph.col_idx, graph.dst_idx, storage_dtype=storage_dtype,
-        fuse_linear=megafuse)
+                       graph.num_nodes, storage_dtype=storage_dtype)
+    backend = resolve_backend(backend, graph.num_edges, graph.num_nodes,
+                              graph.num_nodes)
     plans = None
     with obs.span("plan_build", backend=backend):
         if backend == "matmul":
@@ -295,61 +223,16 @@ def dense_graph_data(graph, backend: str = "xla",
                 graph.col_idx, graph.dst_idx, graph.num_nodes,
                 graph.num_nodes)
         elif backend == "binned":
-            # fwd rides the geometry the resolution already chose (if any);
-            # bwd (the transposed direction) still chooses its own
             plans = ops.build_binned_plans(
                 graph.col_idx, graph.dst_idx, graph.num_nodes,
-                graph.num_nodes, geom=(geom or "auto", "auto"),
-                storage_dtype=storage_dtype, fuse_linear=megafuse)
+                graph.num_nodes, geom="auto", storage_dtype=storage_dtype)
         gat_plans = None
-        gat_bplans = None
-        gat_fused = False
         if gat_backend == "plan":
             from roc_tpu.ops.edge import build_gat_plans
-            from roc_tpu.ops.pallas import gat as _pgat
             with obs.span("gat_plan_build", edges=graph.num_edges) as sp:
                 gat_plans = build_gat_plans(graph.col_idx, graph.dst_idx,
                                             graph.num_nodes, graph.num_nodes)
                 sp.args.update(gat_plan_stats(gat_plans, graph.num_edges))
-            # the fused kernel's plan-independent gates first: a binned
-            # plan pair (choose_geometry, ~4 s a direction at the Reddit
-            # shape) is built only for a kernel that may still take it
-            if not gat_fusion_refused(megafuse):
-                # The fused attention megakernel rides the SAME binned plan
-                # family as aggregate->linear fusion; fuse_linear=True so
-                # choose_geometry prices flat (fusable) schedules with the
-                # fused credit.  A plan with no fused schedule (hub split,
-                # sparse fallback, bf16 staging under exact) declines below
-                # and gat_bplans stays None — the attend closure then runs
-                # the byte-identical unfused composition.
-                from roc_tpu.ops.edge import _gat_fuse_state
-                bp = ops.build_binned_plans(
-                    graph.col_idx, graph.dst_idx, graph.num_nodes,
-                    graph.num_nodes, geom="auto",
-                    storage_dtype=storage_dtype, fuse_linear=True)
-                if gat_heads:
-                    ng, _ = _gat_fuse_state(bp, gat_heads, gat_head_dim)
-                    gat_fused = bool(ng)
-                else:
-                    gat_fused = bool(_pgat._plan_fused(bp.fwd)
-                                     and not _pgat.gat_fuse_killed())
-                if gat_fused:
-                    gat_bplans = bp
-                    if gat_heads:
-                        from roc_tpu.obs.ledger import (content_key,
-                                                        get_ledger)
-                        led = get_ledger()
-                        if led.attached:
-                            led.predict(
-                                "gat_fused_hbm_bytes",
-                                content_key(rows=int(graph.num_nodes),
-                                            edges=int(graph.num_edges),
-                                            heads=int(gat_heads),
-                                            fdim=int(gat_head_dim)),
-                                _pgat.predicted_gat_trainstep_hbm_bytes(
-                                    graph.num_nodes, graph.num_edges,
-                                    gat_heads, gat_head_dim, fused=True),
-                                "bytes")
     with obs.span("place_data", what="edges"):
         return DenseGraphData(
             edge_src=jnp.asarray(graph.col_idx, jnp.int32),
@@ -357,15 +240,12 @@ def dense_graph_data(graph, backend: str = "xla",
             in_degree=jnp.asarray(graph.in_degrees, jnp.float32),
             plans=plans,
             gat_plans=gat_plans,
-            gat_bplans=gat_bplans,
             backend=backend,
             precision=precision,
-            gat_fused=gat_fused,
         )
 
 
-def make_gctx(g: DenseGraphData, num_nodes: int,
-              megafuse: bool = False, fusion_depth: int = 1) -> GraphCtx:
+def make_gctx(g: DenseGraphData, num_nodes: int) -> GraphCtx:
     interp = pallas_interpret()
 
     def aggregate(x, aggr):
@@ -387,23 +267,6 @@ def make_gctx(g: DenseGraphData, num_nodes: int,
     def attend(h, a_src, a_dst, slope, drop=None):
         # single device: the source table IS the local tensor
         if g.gat_plans is not None:
-            if g.gat_bplans is not None:
-                if drop is not None:
-                    raise ValueError(
-                        "the fused GAT kernel (-megafuse, ops/pallas/gat.py"
-                        ": gat_attend_binned) has no attention dropout; "
-                        "train this gat model with -dropout 0 or without "
-                        "-megafuse (it would otherwise train without the "
-                        "coefficients' mask)")
-                # Fused attention megakernel (ops/pallas/gat.py): per-head
-                # score->softmax->aggregate in one binned grid.  Its own
-                # trace-time decline ladder (head width, VMEM, kill
-                # switches) falls back to the oracle composition inside
-                # the custom_vjp, byte-identically.
-                return ops.gat_attend_binned(
-                    h, h, a_src, a_dst, g.gat_plans, g.gat_bplans,
-                    (g.edge_src, g.edge_dst), slope,
-                    ops.matmul_precision(g.precision), interp)
             from roc_tpu.ops.edge import gat_attend_plan
             return gat_attend_plan(h, h, a_src, a_dst, g.gat_plans,
                                    (g.edge_src, g.edge_dst), slope,
@@ -411,87 +274,8 @@ def make_gctx(g: DenseGraphData, num_nodes: int,
         return ops.gat_attend(h, h, g.edge_src, g.edge_dst, num_nodes,
                               a_src, a_dst, slope, drop)
 
-    fuse_linear = None
-    if megafuse and g.backend == "binned" and g.plans is not None \
-            and g.plans.mm is None:
-        from roc_tpu.ops.pallas import binned as _B
-
-        def fuse_linear(x, w, activation, aggr, fold=False):
-            # Trace-time legality, all static: a None return makes
-            # model.apply run that layer's byte-identical unfused op
-            # sequence instead (hybrid plans were excluded above — their
-            # matmul side adds outside any kernel).  fold=True is the
-            # norm-folded GCN chain: D^-1/2 A D^-1/2 (xW) =
-            # D^-1/2 (A ((D^-1/2 x) W)), so pre-scale the input, run the
-            # same fused kernel, post-scale — relu commutes with the
-            # positive diagonal scale, so the in-kernel epilogue still
-            # applies on the sum path.  Note the folded GCN layer hands
-            # the kernel the PRE-linear width (x.shape[-1] = H_in, e.g.
-            # 602 at the Reddit shape), which is exactly what the VMEM
-            # gate below prices.
-            plan = g.plans.fwd
-            geom = plan.geom
-            exact = g.precision == "exact" and x.dtype == jnp.float32
-            if (geom is None or not geom.flat or plan.f_meta is None
-                    or plan.f_last is None
-                    or (exact and geom.unit == 16)
-                    or os.environ.get("ROC_BINNED_NO_FUSE")
-                    or _B.megafuse_killed()
-                    or not _B._mega_vmem_ok(
-                        geom, _B._pad_to(x.shape[-1], 128),
-                        _B._pad_to(w.shape[-1], 128),
-                        plan.p2_obi.shape[1],
-                        groups=plan.p1_blk.shape[0])):
-                return None
-            if fold:
-                x = ops.indegree_norm(x, g.in_degree)
-            out = ops.scatter_gather_linear_binned(
-                x, w, g.plans, interp, g.precision,
-                "none" if aggr == "avg" else activation)
-            if aggr == "avg":
-                # (D^-1 A) W == D^-1 (A W) — divide after the
-                # sum-aggregating kernel; the activation moves outside
-                # with it (it must see the divided values)
-                out = ops.divide_by_degree(out, g.in_degree)
-            if fold:
-                out = ops.indegree_norm(out, g.in_degree)
-            if aggr == "avg":
-                out = ops.apply_activation(out, activation)
-            return out
-
-    fuse_region = None
-    if fuse_linear is not None and fusion_depth != 1:
-        from roc_tpu.ops.pallas import binned as _B
-
-        def fuse_region(x, ws, activations, fold=False):
-            # Trace-time legality for the whole region, all static: a
-            # None return makes model.apply fall through to the
-            # per-layer fuse_linear pass at the same op index — the
-            # exact fusion_depth=1 program (tests pin byte-identity).
-            # mega_regions only offers sum-aggregating chains, so no
-            # avg handling here; the kill switch restores PR-10
-            # per-layer behavior wholesale.
-            if _B.xlayer_killed():
-                return None
-            widths = (x.shape[-1],) + tuple(w.shape[-1] for w in ws)
-            if not _B.region_ok(g.plans.fwd, widths, g.precision,
-                                x.dtype):
-                return None
-            if fold:
-                # the region kernel owns the INTERIOR norm pairs; the
-                # head pre-scale and tail post-scale stay outside,
-                # exactly like the per-layer folded hook
-                x = ops.indegree_norm(x, g.in_degree)
-            out = ops.region_linear_binned(
-                x, tuple(ws), g.in_degree, g.plans, interp, g.precision,
-                tuple(activations), fold)
-            if fold:
-                out = ops.indegree_norm(out, g.in_degree)
-            return out
-
     return GraphCtx(aggregate=aggregate, in_degree=g.in_degree,
-                    attend=attend, fuse_linear=fuse_linear,
-                    fuse_region=fuse_region, fusion_depth=fusion_depth)
+                    attend=attend)
 
 
 @dataclasses.dataclass
@@ -514,10 +298,9 @@ class TrainStats:
     peak_hbm_source: str = ""
 
 
-# Consecutive guarded-skip steps before the escalation ladder engages
-# (rung 1: drop to the two-pass unfused program; rung 2: restore from
-# the last durable checkpoint).  One bad batch skips silently; K in a
-# row means the run is not recovering on its own.
+# Consecutive guarded-skip steps before the escalation engages (restore
+# from the last durable checkpoint).  One bad batch skips silently; K in
+# a row means the run is not recovering on its own.
 NONFINITE_ESCALATE_AFTER = 3
 
 
@@ -542,7 +325,6 @@ class BaseTrainer:
         self._last_nonfinite = None
         self._nf_streak = 0
         self._nf_skips = 0
-        self._nf_stage = 0
         self._stop_signal = None
         # Edge-sharded aggregation is a multi-device strategy; SpmdTrainer
         # resolves "auto" from measured partition skew during _setup.
@@ -632,23 +414,17 @@ class BaseTrainer:
         """What this trainer resolved for its gat ops (None: the model has
         none): the attention backend ("plan": ops.edge.gat_attend_plan or
         its sharded kin over GatPlans; "xla": the dense / chunked / ring
-        scans), whether the fused Pallas kernel is attached and, if not,
-        why it was not tried, the GatPlans' padding (plan slots / edges)
-        and the bytes of per-edge residuals a train step keeps between
-        forward and backward on the plan path (e float32 + the score's sign,
-        [K, E] each, per gat op; 0 where autodiff keeps what it likes)."""
+        scans), the GatPlans' padding (plan slots / edges) and the bytes
+        of per-edge residuals a train step keeps between forward and
+        backward on the plan path (e float32 + the score's sign, [K, E]
+        each, per gat op; 0 where autodiff keeps what it likes)."""
         if not model_has_gat(self.model):
             return None
         gd = getattr(self, "gdata", None)   # the streamed trainer has none
         plans = getattr(gd, "gat_plans", None)
         plans = getattr(plans, "plans", plans)      # EdgeGatPlans wraps one
         backend = "plan" if plans is not None else "xla"
-        fused = bool(getattr(gd, "gat_bplans", None) is not None)
-        info = {"backend": backend, "fused": fused,
-                "not_fused_because": "" if fused else (
-                    gat_fusion_refused(self.config.megafuse)
-                    or "the plan-dependent gates declined"),
-                "plan_pad_ratio": 0.0, "score_bytes": 0}
+        info = {"backend": backend, "plan_pad_ratio": 0.0, "score_bytes": 0}
         if plans is not None:
             edges = int(gd.edge_src.shape[-1])      # per shard when sharded
             info["plan_pad_ratio"] = gat_plan_stats(plans, edges)["pad_ratio"]
@@ -659,27 +435,27 @@ class BaseTrainer:
 
     def _announce_attention(self):
         """The trainer's own start-up line for a gat model, and the same
-        facts as `attention` record + gauges under -obs: a run is never
-        routed into the fused kernel, or out of it, without a word."""
+        facts as `attention` record + gauges under -obs."""
         info = self.attention_info()
         if info is None:
             return
-        why = "" if info["fused"] else f" ({info['not_fused_because']})"
+        # the second field is fixed text that
+        # tests/benchmark/test_benchmark_gat_cell.py:212 reads (a file of
+        # the benchmark's; ROADMAP Queue 1 item 0 shortens its assertion)
         print(f"# attention: backend={info['backend']} "
-              f"gat_fused={info['fused']}{why} "
+              f"gat_fused=False (no -megafuse) "
               f"gat_plan_pad_ratio={info['plan_pad_ratio']:.4f} "
               f"gat_score_bytes={info['score_bytes']}", file=sys.stderr,
               flush=True)
         if self._metrics is not None:
             self._metrics.emit(
-                "attention", backend=info["backend"], fused=info["fused"],
+                "attention", backend=info["backend"],
                 gat_plan_pad_ratio=info["plan_pad_ratio"],
                 gat_score_bytes=info["score_bytes"])
             for name in ("gat_plan_pad_ratio", "gat_score_bytes"):
                 self._metrics.set_gauge(name, info[name[4:]])
             self._metrics.set_gauge("gat_backend", 1.0,
-                                    backend=info["backend"],
-                                    fused=str(info["fused"]).lower())
+                                    backend=info["backend"])
 
     def _obs_epoch(self, epoch: int, wall_s: float, loss, print_fn):
         """Per-epoch drain: fetch the in-graph metrics pytree (ONE
@@ -725,13 +501,6 @@ class BaseTrainer:
             if src == "measured":
                 led.measure("peak_memory", key, hbm, "bytes",
                             epoch=int(epoch))
-                if getattr(self, "_xlayer_calib", False):
-                    # measurement half of the fusion-region peak pair
-                    # (_resolve_mem_plan): same device-reported peak,
-                    # region-specific model name so its drift is
-                    # attributable to the kept/dropped accounting
-                    led.measure("xlayer_peak_memory", key, hbm, "bytes",
-                                epoch=int(epoch))
         if self.watchdog is not None:
             alert = self.watchdog.observe_epoch(epoch, wall_s)
             if alert is not None:
@@ -844,41 +613,6 @@ class BaseTrainer:
                         self.mem_estimate.base_step_s, "s")
             led.predict("peak_memory", self._calib_key,
                         self.mem_plan.predicted_peak_bytes, "bytes")
-            if getattr(cfg, "megafuse", False):
-                # the megakernel's train-step HBM claim, on the record —
-                # pairable only against hardware counters (unpaired off
-                # device, which the calibration report counts as such)
-                from roc_tpu.models.model import mega_matches
-                from roc_tpu.ops.pallas import binned as B
-                rows = self.mem_estimate.rows
-                tot = sum(B.predicted_trainstep_hbm_bytes(
-                    rows, m["linear"].attrs["in_dim"],
-                    m["linear"].attrs["out_dim"], mega_bwd=True)
-                    for m in mega_matches(self.model).values())
-                if tot:
-                    led.predict("hbm_bytes", self._calib_key, tot,
-                                "bytes")
-                fd = getattr(cfg, "fusion_depth", 1)
-                if fd != 1:
-                    # round-16 fusion-region pair: the cross-layer HBM
-                    # claim (hardware-counter-paired like hbm_bytes) plus
-                    # a region-aware peak prediction that DOES pair with
-                    # the device-reported peak every epoch — a drifted
-                    # kept/dropped tuple in the estimator moves this
-                    # model's ratio, which the calibration report and
-                    # watchdog EWMA then flag
-                    from roc_tpu.models.model import mega_regions
-                    regs = mega_regions(self.model, fd)
-                    xtot = sum(B.predicted_xlayer_trainstep_hbm_bytes(
-                        rows, r["members"][0]["linear"].attrs["out_dim"],
-                        len(r["members"])) for r in regs.values())
-                    if xtot:
-                        led.predict("xlayer_hbm_bytes", self._calib_key,
-                                    xtot, "bytes")
-                        led.predict("xlayer_peak_memory", self._calib_key,
-                                    self.mem_plan.predicted_peak_bytes,
-                                    "bytes")
-                        self._xlayer_calib = True
         if cfg.verbose and (cfg.mem_plan != "keep" or budget):
             print(f"# {self.mem_plan.summary()}")
 
@@ -975,25 +709,10 @@ class BaseTrainer:
             self._nf_streak = 0
 
     def _escalate_nonfinite(self, epoch: int, print_fn) -> None:
-        """K consecutive skipped steps.  Rung 1 — a run on the fused
-        megakernel path falls back to the two-pass unfused program and
-        rebuilds its steps (a kernel-level numeric bug can then no longer
-        poison every step).  Rung 2 — restore params/optimizer state from
-        the last durable checkpoint and keep going."""
+        """K consecutive skipped steps: restore params/optimizer state
+        from the last durable checkpoint and keep going, or say that
+        there is none."""
         cfg = self.config
-        if self._nf_stage == 0 and cfg.megafuse:
-            self._nf_stage = 1
-            fault.emit_event("nonfinite_escalation", stage="unfuse",
-                             epoch=int(epoch), streak=self._nf_streak)
-            print_fn(f"# fault: {self._nf_streak} consecutive non-finite "
-                     f"steps — disabling -megafuse (two-pass fallback) and "
-                     f"rebuilding the train step")
-            cfg.megafuse = False
-            keep = self.params, self.opt_state, self.epoch
-            self._setup()
-            self.params, self.opt_state, self.epoch = keep
-            return
-        self._nf_stage = 2
         path = cfg.checkpoint_path
         if path and os.path.exists(path):
             fault.emit_event("nonfinite_escalation", stage="restore",
@@ -1229,14 +948,11 @@ class Trainer(BaseTrainer):
     def _setup(self):
         ds, model = self.dataset, self.model
         backend = self._effective_backend()
-        gheads, gdim = model_gat_dims(model)
         self.gdata = dense_graph_data(
             ds.graph, backend, self.config.aggregate_precision,
             gat_backend=self._gat_backend(),
             storage_dtype="bf16" if self.config.bf16_storage else "fp32",
-            megafuse=self.config.megafuse,
-            autotune=self.config.autotune,
-            gat_heads=gheads, gat_head_dim=gdim)
+            autotune=self.config.autotune)
         with obs.span("place_data", what="nodes"):
             self.x = jnp.asarray(ds.features, self.dtype)
             self.labels = jnp.asarray(ds.onehot_labels(), jnp.float32)
@@ -1255,8 +971,6 @@ class Trainer(BaseTrainer):
         model, the loss and the node count; nothing compiles here)."""
         model, n = self.model, self.num_nodes
         loss_fn = self._loss_fn()
-        mega = self.config.megafuse
-        fdepth = getattr(self.config, "fusion_depth", 1)
         obs_on = self.config.obs
         if obs_on:
             from roc_tpu.obs import channel as obs_channel
@@ -1265,7 +979,7 @@ class Trainer(BaseTrainer):
         def train_step(params, opt_state, x, labels, mask, gdata, key, alpha,
                        gscale):
             _retrace.note_trace("train_step")
-            gctx = make_gctx(gdata, n, mega, fdepth)
+            gctx = make_gctx(gdata, n)
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, x, labels, mask, gctx, key=key, train=True)
             # gscale is 1.0 on every healthy step (an exact multiply —
@@ -1291,14 +1005,14 @@ class Trainer(BaseTrainer):
         @jax.jit
         def eval_step(params, x, labels, mask, gdata):
             _retrace.note_trace("eval_step")
-            gctx = make_gctx(gdata, n, mega, fdepth)
+            gctx = make_gctx(gdata, n)
             logits = model.apply(params, x, gctx, train=False)
             return ops.perf_metrics(logits, labels, mask)
 
         @jax.jit
         def logits_step(params, x, gdata):
             _retrace.note_trace("logits_step")
-            return model.apply(params, x, make_gctx(gdata, n, mega, fdepth),
+            return model.apply(params, x, make_gctx(gdata, n),
                                train=False)
 
         self._train_step = train_step

@@ -50,7 +50,6 @@ class FrozenBundle:
     x: Optional[jnp.ndarray]
     gdata: object
     num_nodes: int
-    megafuse: bool
     stream_trainer: object = None
     _logits_jit: object = dataclasses.field(default=None, repr=False)
 
@@ -67,12 +66,12 @@ class FrozenBundle:
         if self._logits_jit is None:
             from roc_tpu.analysis import retrace as _retrace
             from roc_tpu.train.driver import make_gctx
-            model, n, mega = self.model, self.num_nodes, self.megafuse
+            model, n = self.model, self.num_nodes
 
             @jax.jit
             def frozen_logits(params, x, gdata):
                 _retrace.note_trace("frozen_logits")
-                return model.apply(params, x, make_gctx(gdata, n, mega),
+                return model.apply(params, x, make_gctx(gdata, n),
                                    train=False)
 
             self._logits_jit = frozen_logits
@@ -103,20 +102,15 @@ def load_frozen(config: Config, dataset: Dataset, model: Model,
             return FrozenBundle(
                 config=config, dataset=dataset, model=model,
                 params=tr.params, x=None, gdata=None,
-                num_nodes=dataset.graph.num_nodes,
-                megafuse=config.megafuse, stream_trainer=tr)
+                num_nodes=dataset.graph.num_nodes, stream_trainer=tr)
         from roc_tpu.train.driver import (dense_graph_data,
                                           effective_backend,
-                                          effective_gat_backend,
-                                          model_gat_dims)
+                                          effective_gat_backend)
         backend = effective_backend(config, dataset, model)
-        gheads, gdim = model_gat_dims(model)
         gdata = dense_graph_data(
             dataset.graph, backend, config.aggregate_precision,
             gat_backend=effective_gat_backend(config, dataset, model),
-            storage_dtype="bf16" if config.bf16_storage else "fp32",
-            megafuse=config.megafuse,
-            gat_heads=gheads, gat_head_dim=gdim)
+            storage_dtype="bf16" if config.bf16_storage else "fp32")
         dtype = jnp.bfloat16 if config.use_bf16 else jnp.float32
         x = jnp.asarray(dataset.features, dtype)
         params = model.init_params(jax.random.PRNGKey(config.seed))
@@ -125,5 +119,4 @@ def load_frozen(config: Config, dataset: Dataset, model: Model,
         params = jax.device_put(params)
         return FrozenBundle(
             config=config, dataset=dataset, model=model, params=params,
-            x=x, gdata=gdata, num_nodes=dataset.graph.num_nodes,
-            megafuse=config.megafuse)
+            x=x, gdata=gdata, num_nodes=dataset.graph.num_nodes)

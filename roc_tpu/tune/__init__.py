@@ -10,7 +10,7 @@ that into a measured SEARCH:
                 the VMEM budget admit (chunk widths, slot, windows, group
                 target, flat/unit) crossed with the non-Geometry kernel
                 knobs (_DMA_CLS run classes, dimension_semantics,
-                double-buffer depth, mega on/off).
+                double-buffer depth).
   surrogate.py  trial pricing: a parameterized mirror of binned's
                 analytic model (exact _plan_steps schedules), plus the
                 seeded CI surrogate — deterministic pseudo-measurements

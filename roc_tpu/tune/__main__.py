@@ -48,8 +48,7 @@ def _run_sweep(args, path: str, log=print):
     shapes = (search.SHAPES_DEVICE if args.shapes == "device"
               else search.SHAPES_CI)
     entries, trials = search.sweep(
-        shapes, storage_dtype=args.storage, fuse_linear=args.fuse,
-        seed=args.seed, device=args.device,
+        shapes, storage_dtype=args.storage, seed=args.seed, device=args.device,
         screen_keep=args.screen_keep, final_keep=args.final_keep,
         log=log)
     doc = store.merge_entries(path, entries,
@@ -101,12 +100,12 @@ def _selftest(args) -> int:
                 gkey = store.graph_key(shape.edge_src, shape.edge_dst,
                                        shape.num_rows, shape.table_rows)
                 want = tuple(docs[0]["entries"][gkey]
-                             [store.variant_key(args.storage, args.fuse)]
+                             [store.variant_key(args.storage)]
                              ["geom"])
                 got, _ = B.choose_geometry(
                     shape.edge_src, shape.edge_dst, shape.num_rows,
                     shape.table_rows, force=True,
-                    storage_dtype=args.storage, fuse_linear=args.fuse)
+                    storage_dtype=args.storage)
                 check("choose_geometry consumes tuned entry",
                       got is not None and tuple(got) == want,
                       f"(geom {want})")
@@ -160,8 +159,6 @@ def main(argv=None) -> int:
     p.add_argument("--device", action="store_true",
                    help="real timed trials (TPU only; refuses interpret)")
     p.add_argument("--storage", choices=("fp32", "bf16"), default="fp32")
-    p.add_argument("--fuse", action="store_true",
-                   help="tune the fuse_linear (megakernel) variant")
     p.add_argument("--refit", action="store_true",
                    help="re-solve rate constants from the trials")
     p.add_argument("--update", action="store_true",

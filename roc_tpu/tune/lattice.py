@@ -12,7 +12,7 @@ Two layers, matching how the kernels are parameterized:
 * The **knob axes** — kernel parameters that are NOT Geometry fields
   (module constants / lowering choices): the ``_DMA_CLS`` staging-copy
   size classes, ``dimension_semantics`` arbitrary-vs-parallel on the
-  grid, the double-buffer depth, and mega (fused layer) on/off.  The
+  grid and the double-buffer depth.  The
   Geometry tuple is append-only and hashed into the v3 plan-cache key,
   so these CANNOT ride the tuple without invalidating every cached plan;
   they travel in a side-channel dict instead, priced by the surrogate's
@@ -62,33 +62,23 @@ class KernelConfig(NamedTuple):
     dma_cls: tuple = _DMA_CLS
     dimension_semantics: str = "arbitrary"
     depth: int = 2
-    mega: int = 0          # price the fused (megakernel) layer schedule
-    fdepth: int = 1        # cross-layer fusion-region cap (round 16):
-    #                        1 per-layer, 2 two-layer regions, 0 full
-    #                        chain.  Append-only — older tuned entries
-    #                        decode with the per-layer default.
-    ghg: int = 0           # GAT head-stacking groups (round 19): 0 auto
-    #                        (smallest admissible divisor,
-    #                        gat.gat_head_groups), n>0 forces n groups via
-    #                        ROC_GAT_HEADGROUPS.  Append-only, same
-    #                        contract as fdepth.
 
     @property
     def label(self) -> str:
         """Stable identity string: the surrogate's noise key and the
-        search's tie-breaker, so it must determine the candidate."""
+        search's tie-breaker, so it must determine the candidate.  The
+        "+m0+f1+g0" tail is fixed text: labels recorded in trial keys
+        and noise draws keep the strings they were written with."""
         g = "/".join(str(v) for v in tuple(self.geom))
         k = (f"dma{'.'.join(str(c) for c in self.dma_cls)}"
-             f"+{self.dimension_semantics}+d{self.depth}+m{self.mega}"
-             f"+f{self.fdepth}+g{self.ghg}")
+             f"+{self.dimension_semantics}+d{self.depth}+m0+f1+g0")
         return f"{g}|{k}"
 
     def knobs(self) -> dict:
         """The tuned-entry side-channel (store.py schema)."""
         return {"dma_cls": list(self.dma_cls),
                 "dimension_semantics": self.dimension_semantics,
-                "depth": self.depth, "mega": int(self.mega),
-                "fdepth": int(self.fdepth), "ghg": int(self.ghg)}
+                "depth": self.depth}
 
 
 def _geometries(storage_dtype: str):
@@ -124,13 +114,12 @@ def _admissible(g: Geometry) -> bool:
     return _vmem_bytes(g) <= _VMEM_NOMINAL_CAP
 
 
-def candidate_lattice(storage_dtype: str = "fp32",
-                      fuse_linear: bool = False) -> list:
+def candidate_lattice(storage_dtype: str = "fp32") -> list:
     """All KernelConfigs the screen stage prices.  Knob axes only fan out
-    where they act (flat schedules own the DMA run lists and the fused
-    path), keeping the lattice in the hundreds — big enough that the
-    hand presets are a measure-zero subset, small enough that the O(cells)
-    exact-schedule screen stays sub-second per shape on CPU."""
+    where they act (flat schedules own the DMA run lists), keeping the
+    lattice in the hundreds — big enough that the hand presets are a
+    measure-zero subset, small enough that the O(cells) exact-schedule
+    screen stays sub-second per shape on CPU."""
     if storage_dtype not in ("fp32", "bf16"):
         raise ValueError(f"storage_dtype={storage_dtype!r}")
     cfgs = []
@@ -138,7 +127,6 @@ def candidate_lattice(storage_dtype: str = "fp32",
         if not g.flat:
             cfgs.append(KernelConfig(geom=g))
             continue
-        megas = (0, 1) if (fuse_linear and g.ch == g.ch2) else (0,)
         for dma_cls in _DMA_CLASSES:
             for sem in _SEMANTICS:
                 for depth in _DEPTHS:
@@ -148,23 +136,9 @@ def candidate_lattice(storage_dtype: str = "fp32",
                                        * staging_itemsize(g, False)
                                        > _VMEM_NOMINAL_CAP):
                         continue
-                    for mega in megas:
-                        # the fusion-depth axis only acts on the fused
-                        # path: {1 per-layer, 2 two-layer, 0 full} —
-                        # successive halving searches it like any knob
-                        fdepths = (1, 2, 0) if mega else (1,)
-                        for fdepth in fdepths:
-                            # head-stacking groups act only on fused
-                            # attention: auto vs forced single vs forced
-                            # split (round 19) — fan out on the depth-1
-                            # fused leg only, keeping growth linear
-                            ghgs = ((0, 1, 2) if mega and fdepth == 1
-                                    else (0,))
-                            for ghg in ghgs:
-                                cfgs.append(KernelConfig(
-                                    geom=g, dma_cls=dma_cls,
-                                    dimension_semantics=sem, depth=depth,
-                                    mega=mega, fdepth=fdepth, ghg=ghg))
+                    cfgs.append(KernelConfig(
+                        geom=g, dma_cls=dma_cls,
+                        dimension_semantics=sem, depth=depth))
     # Sorted by label: the lattice's order is part of the determinism
     # contract (ties in the screen break by position).
     cfgs.sort(key=lambda c: c.label)

@@ -86,7 +86,7 @@ def refit_rates(trials) -> dict:
             if f["steps"] > 0:
                 mm.append(f["t"] / f["steps"])
             continue
-        if not f["default_knobs"] or "+fuse" in f["variant"]:
+        if not f["default_knobs"]:
             continue
         agg.append(f)
     # The probe stage is search.py's designed experiment; the halving's

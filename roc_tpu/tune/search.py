@@ -53,7 +53,7 @@ from roc_tpu.tune import surrogate as S
 from roc_tpu.tune.lattice import KernelConfig, candidate_lattice
 
 # Synthetic sweep shapes, mirroring tools/kernel_bench.py: the CI shape
-# is the mega-shard scale where every variant's gates admit it; device
+# is a one-shard scale where every candidate's gates admit it; device
 # mode adds the dense/sparse scales the step-budget table pins.  The
 # second CI shape is there for refit: at one shape the flat probes issue
 # nearly the same number of copies (64-70), so the per-copy rate is not
@@ -124,7 +124,7 @@ def refit_probes():
 
 def _default_knobs(cfg: KernelConfig) -> bool:
     return (tuple(cfg.dma_cls) == B._DMA_CLS and cfg.depth == 2
-            and cfg.dimension_semantics == "arbitrary" and not cfg.mega)
+            and cfg.dimension_semantics == "arbitrary")
 
 
 def _trial_key(shape: Shape, variant: str, cfg_label: str,
@@ -153,8 +153,8 @@ def _measure(cfg, shape: Shape, modeled: float, stage: str, seed: int,
                              reps=reps)
 
 
-def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
-          seed: int = 0, device: bool = False, screen_keep: int = 16,
+def sweep(shapes, storage_dtype: str = "fp32", seed: int = 0,
+          device: bool = False, screen_keep: int = 16,
           final_keep: int = 4, watchdog=None, log=None):
     """Run the three-stage search over ``shapes`` (Shape tuples or
     (name, rows, edges, seed) specs).  Returns (entries, trials): a
@@ -164,11 +164,11 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
     led = get_ledger()
     entries: dict = {}
     trials: list = []
-    vkey = tstore.variant_key(storage_dtype, fuse_linear)
+    vkey = tstore.variant_key(storage_dtype)
     emit = log or (lambda *_: None)
     for spec in shapes:
         shape = spec if isinstance(spec, Shape) else synth_shape(*spec)
-        cfgs = candidate_lattice(storage_dtype, fuse_linear)
+        cfgs = candidate_lattice(storage_dtype)
         stats_cache: dict = {}
         sched_cache: dict = {}
 
@@ -186,8 +186,7 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
             gk = tuple(cfg.geom)
             t, sched = S.modeled_seconds(
                 cfg, _stats(cfg.geom), shape.num_rows, shape.table_rows,
-                len(shape.edge_src), fuse_linear=fuse_linear,
-                sched=sched_cache.get(gk))
+                len(shape.edge_src), sched=sched_cache.get(gk))
             sched_cache[gk] = sched
             return t, sched
 
@@ -195,8 +194,7 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
         scored = []
         for i, cfg in enumerate(cfgs):
             t, sched = _price(cfg)
-            if np.isfinite(t):
-                scored.append((t, i, cfg, sched))
+            scored.append((t, i, cfg, sched))
         scored.sort(key=lambda r: (r[0], r[1]))
         survivors = scored[:screen_keep]
         emit(f"{shape.name}/{vkey}: screened {len(cfgs)} candidates "
@@ -251,26 +249,23 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
              f"({t_win * 1e3:.3f} ms confirmed, "
              f"{t_win_model * 1e3:.3f} ms modeled)")
 
-        # probe stage — refit's designed experiment (module docstring);
-        # fuse variants are refit-ineligible, so probes ride the plain
-        # sweep only
-        if not fuse_linear:
-            for cfg in refit_probes():
-                t_model, sched = _price(cfg)
-                if not np.isfinite(t_model):
-                    continue
-                key = _trial_key(shape, vkey, cfg.label, "probe")
-                led.predict("tune_probe", key, t_model, "s")
-                t_probe = _measure(cfg, shape, t_model, "probe", seed,
-                                   device, reps=5)
-                terms = S.cost_terms(cfg.geom, _stats(cfg.geom), sched)
-                led.measure("tune_probe", key, t_probe, "s",
-                            stage="probe", steps=sched[1] + sched[2],
-                            flat=int(cfg.geom.flat),
-                            default_knobs=True, **terms)
-                trials.append(TrialRecord(
-                    shape.name, vkey, cfg.label, tuple(cfg.geom), "probe",
-                    sched[1] + sched[2], terms, True, t_model, t_probe))
+        # probe stage — refit's designed experiment (module docstring)
+        for cfg in refit_probes():
+            t_model, sched = _price(cfg)
+            if not np.isfinite(t_model):
+                continue
+            key = _trial_key(shape, vkey, cfg.label, "probe")
+            led.predict("tune_probe", key, t_model, "s")
+            t_probe = _measure(cfg, shape, t_model, "probe", seed,
+                               device, reps=5)
+            terms = S.cost_terms(cfg.geom, _stats(cfg.geom), sched)
+            led.measure("tune_probe", key, t_probe, "s",
+                        stage="probe", steps=sched[1] + sched[2],
+                        flat=int(cfg.geom.flat),
+                        default_knobs=True, **terms)
+            trials.append(TrialRecord(
+                shape.name, vkey, cfg.label, tuple(cfg.geom), "probe",
+                sched[1] + sched[2], terms, True, t_model, t_probe))
 
         # matmul reference trial: sanity anchor + refit's mm-rate record
         mm_model = S.matmul_seconds(len(shape.edge_src), shape.num_rows)
@@ -306,8 +301,8 @@ def sweep(shapes, storage_dtype: str = "fp32", fuse_linear: bool = False,
 
 
 def autotune_graph(edge_src, edge_dst, num_rows: int, table_rows: int,
-                   storage_dtype: str = "fp32", fuse_linear: bool = False,
-                   seed: int = 0, device: bool = False, path: str = "",
+                   storage_dtype: str = "fp32", seed: int = 0,
+                   device: bool = False, path: str = "",
                    watchdog=None, log=None):
     """Tune one REAL graph — both plan directions, since the backward
     plan transposes the roles — and persist the winners into the tuned
@@ -321,13 +316,11 @@ def autotune_graph(edge_src, edge_dst, num_rows: int, table_rows: int,
         return None, None
     shapes = [Shape("fwd", num_rows, table_rows, es, ed),
               Shape("bwd", table_rows, num_rows, ed, es)]
-    entries, _ = sweep(shapes, storage_dtype=storage_dtype,
-                       fuse_linear=fuse_linear, seed=seed, device=device,
-                       watchdog=watchdog, log=log)
+    entries, _ = sweep(shapes, storage_dtype=storage_dtype, seed=seed,
+                       device=device, watchdog=watchdog, log=log)
     p = path or tstore.tuned_store_path()
     if not p:
         return None, None
     tstore.merge_entries(p, entries, interpret=not device, seed=seed)
     return tstore.lookup(es, ed, num_rows, table_rows,
-                         storage_dtype=storage_dtype,
-                         fuse_linear=fuse_linear, path=p)
+                         storage_dtype=storage_dtype, path=p)

@@ -2,7 +2,7 @@
 
 One JSON document maps *graph content* (shape + an edge-list digest, the
 same key discipline as binned's ``_plan_cache_path``) to the sweep's
-winning kernel config per *variant* (storage dtype x fuse_linear).
+winning kernel config per *variant* (the storage dtype).
 ``choose_geometry`` consults this tier BEFORE its analytic model, and
 ``build_binned_plan`` cross-checks explicitly-passed geometries against
 it so a stale plan-cache hit can never silently pin an untuned geometry
@@ -22,14 +22,10 @@ runs it over the selftest sweep's output)::
    "seed": <int — the surrogate seed, for reproduction>,
    "entries": {
      "<content key: edges=..|rows=..|sha=..|table_rows=..>": {
-       "<variant: fp32|bf16[+fuse]>": {
+       "<variant: fp32|bf16>": {
          "geom":      [<the full Geometry tuple, len-validated>],
          "knobs":     {"dma_cls": [...], "dimension_semantics": str,
-                       "depth": int, "mega": 0|1,
-                       "fdepth": 1|2|0 (cross-layer region cap,
-                                        absent = 1 in older stores),
-                       "ghg": int (GAT head-stacking groups, 0 = auto,
-                                   absent = 0 in older stores)},
+                       "depth": int},
          "modeled_s": <stage-0 analytic seconds>,
          "trial_s":   <winning confirmation-trial seconds>,
          "source":    "surrogate" | "device"}}}}
@@ -60,7 +56,7 @@ from roc_tpu.ops.pallas.binned import (Geometry, _plan_cache_dir,
 
 VERSION = 1
 _GEOM_FIELDS = len(Geometry._fields)
-_VARIANTS = ("fp32", "bf16", "fp32+fuse", "bf16+fuse")
+_VARIANTS = ("fp32", "bf16")
 
 # Parsed-store cache: path -> (mtime_ns, size, doc-or-None).  choose_geometry
 # consults the tier on every auto pick, so the file parses once per change,
@@ -97,12 +93,10 @@ def graph_key(edge_src, edge_dst, num_rows: int, table_rows: int) -> str:
                        edges=int(len(edge_src)), sha=h.hexdigest()[:16])
 
 
-def variant_key(storage_dtype: str = "fp32",
-                fuse_linear: bool = False) -> str:
-    """The per-entry variant axis: the two inputs that change which
-    candidates choose_geometry may even consider (bf16 flat units; the
-    megakernel's round-trip credit)."""
-    return storage_dtype + ("+fuse" if fuse_linear else "")
+def variant_key(storage_dtype: str = "fp32") -> str:
+    """The per-entry variant axis: the input that changes which
+    candidates choose_geometry may even consider (bf16 flat units)."""
+    return storage_dtype
 
 
 def validate_store(doc) -> list:
@@ -251,12 +245,9 @@ def _entry_geom(path: str, gkey: str, vkey: str, e: dict):
 
 
 def lookup(edge_src, edge_dst, num_rows: int, table_rows: int,
-           storage_dtype: str = "fp32", fuse_linear: bool = False,
-           path: str = ""):
+           storage_dtype: str = "fp32", path: str = ""):
     """(Geometry, entry) for this graph + variant, or (None, None).
-    EXACT variant match only — a fuse_linear pick never inherits the
-    unfused winner (their round-trip economics differ, which is the whole
-    point of the variant axis); misses fall back to the analytic model."""
+    EXACT variant match only; misses fall back to the analytic model."""
     doc = load_store(path)
     if doc is None:
         return None, None
@@ -264,7 +255,7 @@ def lookup(edge_src, edge_dst, num_rows: int, table_rows: int,
         graph_key(edge_src, edge_dst, num_rows, table_rows))
     if not variants:
         return None, None
-    vkey = variant_key(storage_dtype, fuse_linear)
+    vkey = variant_key(storage_dtype)
     e = variants.get(vkey)
     if e is None:
         return None, None
@@ -283,9 +274,7 @@ def stale_plan_geom(edge_src, edge_dst, num_rows: int, table_rows: int,
     Variant selection without the caller's storage declaration: a
     single-variant entry is unambiguous; otherwise the geometry's own
     staging unit implies the storage family (unit=16 is bf16-only by the
-    Geometry invariant) and the unfused variant is preferred — the fused
-    variants only differ through choose_geometry, which already consults
-    the tier directly.  Warn-once per graph when a switch happens."""
+    Geometry invariant).  Warn-once per graph when a switch happens."""
     doc = load_store(path)
     if doc is None:
         return None
@@ -295,7 +284,7 @@ def stale_plan_geom(edge_src, edge_dst, num_rows: int, table_rows: int,
     if not variants:
         return None
     storage = "bf16" if geom.unit == 16 else "fp32"
-    order = [storage, storage + "+fuse"]
+    order = [storage]
     if len(variants) == 1:
         order = list(variants)
     for vkey in order:

@@ -94,33 +94,23 @@ def knob_factors(cfg) -> tuple:
       dimension_semantics "parallel": neutral (1.0) — both phases carry
         cross-step staging dependences, so until a device run proves the
         revolving-window lowering legal AND faster it cannot win a tie.
-      ghg (GAT head-stacking groups, round 19): forcing MORE groups than
-        the auto divisor multiplies the fused-attention pass count, so a
-        modest per-group overhead prior (+3% per forced group beyond the
-        first) lets the screen prefer auto/single unless a device trial
-        shows the split's smaller VMEM window wins.
     """
     ov, dma = 1.0, 1.0
     if cfg.geom.flat and tuple(cfg.dma_cls) != B._DMA_CLS:
         dma *= 0.96
     if cfg.depth == 3:
         ov *= 0.98
-    if getattr(cfg, "ghg", 0) > 1:
-        ov *= 1.0 + 0.03 * (cfg.ghg - 1)
     return ov, dma
 
 
 def modeled_seconds(cfg, stats, num_rows: int, table_rows: int,
-                    num_edges: int, fuse_linear: bool = False,
-                    rates: dict = None, sched=None) -> tuple:
+                    num_edges: int, rates: dict = None,
+                    sched=None) -> tuple:
     """Candidate price at exact schedule counts: (seconds, sched) where
     sched = (padded, s1, s2) feeds the trial records refit solves from.
-    Mirrors choose_geometry's pricing structure: a fused (mega) candidate
-    scales to its real-chunks-only step count; under ``fuse_linear`` a
-    non-mega candidate pays the eliminated intermediate's HBM round trip
-    plus the separate linear pass's launch windows.  ``sched`` short-
-    circuits the O(cells) _plan_steps when the caller already derived it
-    for this geometry (knob variants share schedules)."""
+    ``sched`` short-circuits the O(cells) _plan_steps when the caller
+    already derived it for this geometry (knob variants share
+    schedules)."""
     cblk, cbin, cnt = stats
     g = cfg.geom
     rates = {**_COST_RATES, **(rates or {})}
@@ -132,22 +122,6 @@ def modeled_seconds(cfg, stats, num_rows: int, table_rows: int,
               "slot_dma": dmaf, "flat_slot": dmaf, "flat_copy": dmaf}
     t = sum(rates[k] * factor[k] * v
             for k, v in cost_terms(g, stats, sched).items())
-    if cfg.mega:
-        fs = B._fused_sched_stats(cblk, cbin, cnt, g, num_rows,
-                                  table_rows, num_edges)
-        if fs is None:
-            return float("inf"), (padded, s1, s2)
-        t *= fs[0] / max(s1 + s2, 1)
-        if cfg.fdepth != 1:
-            # cross-layer region (round 16): the inter-layer [rows, H]
-            # boundary write + next layer's read never reach HBM for
-            # shard-local rows — credit one amortized boundary per fused
-            # layer.  Documented prior; device trials refit it.
-            t = max(t - 2 * num_rows * _MODEL_H * 4 / B._HBM_BW,
-                    t * 0.5)
-    elif fuse_linear:
-        t += (2 * num_rows * _MODEL_H * 4 / B._HBM_BW
-              + -(-num_rows // 512) * rates["p1_step"])
     return t, (padded, s1, s2)
 
 

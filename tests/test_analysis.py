@@ -285,64 +285,17 @@ spec_col = pl.BlockSpec((512, 1), lambda i: (i, 0))      # (N, 1): exempt
 spec_smem = pl.BlockSpec((8, 4), lambda i: (i, 0),
                          memory_space=pltpu.SMEM)        # SMEM: exempt
 spec_dyn = pl.BlockSpec((n, h), lambda i: (i, 0))        # unresolvable
-
-# megakernel epilogue tiles (round 10): the weight / fused-output lane
-# dim must be the 128-padded H_out — a raw H_out lane is exactly the
-# bug class _mega_kernel's BlockSpecs must avoid
-HOP = 128
-mega_w_bad = pl.BlockSpec((128, 41), lambda i: (0, i))   # raw H_out: flag
-mega_w_ok = pl.BlockSpec((128, HOP), lambda i: (0, i))   # padded: clean
-mega_acc_ok = pl.BlockSpec((256, HOP), lambda i: (i, 0))
-
-# fused-backward tiles (round 12): _mega_bwd_run's TRANSPOSED weight tile
-# flips the axes, so its lane dim is the 128-padded H_in — the same
-# raw-width bug class in the other position; dx blocks likewise carry
-# H_in on the lane axis while cotangent blocks keep H_out
-HIP = 128
-bwd_wt_bad = pl.BlockSpec((HOP, 41), lambda i: (0, 0))   # raw H_in: flag
-bwd_wt_ok = pl.BlockSpec((HOP, HIP), lambda i: (0, 0))   # padded: clean
-bwd_dx_bad = pl.BlockSpec((256, 41), lambda i: (i, 0))   # raw H_in: flag
-bwd_dx_ok = pl.BlockSpec((256, HIP), lambda i: (i, 0))
-bwd_g_ok = pl.BlockSpec((256, HOP), lambda i: (i, 0))    # cotangent block
-
-# cross-layer region tiles (round 16): every depth's weight rides ONE
-# stacked (D, Hm, Hm) array whose (1, Hm, Hm) BlockSpec double-buffers
-# the next depth's tile — the lane axis is still the 128-padded uniform
-# width, and the inter-layer VMEM boundary planes reuse the (SB, Hm)
-# pattern at the same padded width
-HM = 128
-xl_w_bad = pl.BlockSpec((1, HM, 41), lambda c: (c, 0, 0))  # raw lane: flag
-xl_w_sub = pl.BlockSpec((1, 12, HM), lambda c: (c, 0, 0))  # sublane 12: flag
-xl_w_ok = pl.BlockSpec((1, HM, HM), lambda c: (c, 0, 0))
-xl_b_bad = pl.BlockSpec((256, 41), lambda c: (c, 0))       # raw width: flag
-xl_b_ok = pl.BlockSpec((256, HM), lambda c: (c, 0))        # VMEM boundary
-
-# fused GAT attention tiles (round 19): the head-stacked feature tiles
-# put heads x head_dim on the LANE axis, so their lane dim must be the
-# 128-padded K*F stack (gat.py HP) — a raw K*F lane is the bug class
-# _gat_sum_run's staging/window BlockSpecs must avoid; the per-head
-# alpha/max/normalizer planes ride (RB, 128) blocks (lane k = head k)
-# with the same 8-row sublane contract
-HP = 128
-gat_w_bad = pl.BlockSpec((HP, 80), lambda i: (0, i))     # raw K*F: flag
-gat_w_ok = pl.BlockSpec((HP, HP), lambda i: (0, i))      # padded stack
-gat_pl_bad = pl.BlockSpec((12, 128), lambda i: (i, 0))   # sublane 12: flag
-gat_pl_ok = pl.BlockSpec((512, 128), lambda i: (i, 0))   # alpha plane
-gat_band_ok = pl.BlockSpec((512, 512), lambda i: (i, 0))  # du|dz|ad|m band
 """
 
 
 def test_mosaic_lint_flags_fixture():
     from roc_tpu.analysis import mosaic
     fs = mosaic.lint_source(_MOSAIC_FIXTURE, "<fixture>")
-    assert len(fs) == 11, fs
+    assert len(fs) == 3, fs
     assert all(f.rule == "mosaic-align" for f in fs)
     lines = sorted(f.line for f in fs)
-    # the ds(0,41), two bad BlockSpecs, the raw-H_out mega weight tile,
-    # the raw-H_in transposed weight + dx tiles, the round-16
-    # stacked-weight (lane + sublane) and inter-layer boundary tiles,
-    # and the round-19 raw-K*F head-stack + alpha-plane sublane tiles
-    assert lines == [8, 13, 14, 25, 34, 36, 46, 47, 49, 59, 61], fs
+    # the ds(0,41) and the two bad BlockSpecs
+    assert lines == [8, 13, 14], fs
 
 
 def test_mosaic_lint_waiver():
@@ -350,7 +303,7 @@ def test_mosaic_lint_waiver():
     src = _MOSAIC_FIXTURE.replace(
         "# sublane 41 % 8 != 0: flag", "# roclint: allow(mosaic-align)")
     fs = mosaic.lint_source(src, "<fixture>")
-    assert len(fs) == 10 and all(f.line > 8 for f in fs), fs
+    assert len(fs) == 2 and all(f.line > 8 for f in fs), fs
 
 
 def test_mosaic_lint_clean_on_tree():

@@ -544,27 +544,6 @@ def test_choose_geometry_policy():
     assert g_c is not None and t_c < t_u, (g_c, t_c, t_u)
 
 
-def test_resolve_backend_uses_stats(monkeypatch):
-    """resolve_backend with edge arrays routes through choose_geometry:
-    community-local graphs upgrade to binned even where the uniform bound
-    says no."""
-    import roc_tpu.train.driver as drv
-    from roc_tpu.ops.pallas.binned import binned_viable
-
-    monkeypatch.setattr(drv, "AUTO_BINNED", True)
-    monkeypatch.setattr(drv, "AUTO_MATMUL_EDGES", 1 << 10)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rng = np.random.default_rng(6)
-    q, k, e = 512, 64, 300_000
-    n = q * k
-    comm = rng.integers(0, k, e) * q
-    src = (comm + rng.integers(0, q, e)).astype(np.int64)
-    dst = (comm + rng.integers(0, q, e)).astype(np.int64)
-    assert not binned_viable(n, n, e)               # uniform bound: no
-    assert drv.resolve_backend("auto", e, n, n) == "matmul"
-    assert drv.resolve_backend("auto", e, n, n, src, dst) == "binned"
-
-
 def test_sweep_products_configs_match_presets():
     """tools/sweep_binned.py hardcodes the preset tuples so its parent
     process never imports jax (subprocess isolation); this pin fails if a

@@ -381,3 +381,61 @@ def test_spmd_flat_env_flag(monkeypatch):
 
 
 _ORIG_NUMPY = B._build_binned_plan_numpy
+
+
+def test_fused_plan_steps_match_built_plan():
+    """The offline step predictor must equal the BUILT fused schedule's
+    grid size, and decline exactly where _attach_fused does."""
+    n, t, e, h = 1500, 2000, 30000, 64
+    src, dst, _ = _int_graph(n, t, e, h, 21)
+    plan = B.build_binned_plan(src, dst, n, t, geom=GF)
+    assert plan.f_meta is not None
+    cb, cn, cnt = B._cell_stats(src, dst, GF.sb, GF.rb)
+    assert B.fused_plan_steps(cb, cn, cnt, GF, n, t, e) \
+        == int(plan.f_blk.shape[0])
+    assert B.fused_plan_steps(cb, cn, cnt, GF2, n, t, e) is None
+
+
+def test_bf16_staging_units_are_flat_only():
+    """The 16-row bf16 STAGING UNIT exists only on the flat schedule — a
+    non-flat unit=16 geometry is a construction error (the slot-padded
+    schedule's 8-row cells would tear the bf16 (16, 128) Mosaic tile).
+    The slot schedule keeps its precision-keyed contract (bf16 fast /
+    fp32 exact); the flat schedule's dtype is a pure function of the
+    geometry."""
+    with pytest.raises(AssertionError, match="flat"):
+        B.Geometry(sb=256, ch=512, slot=128, rb=256, ch2=512,
+                   unit=16).check()
+    slot_geom = B.Geometry(sb=256, ch=512, slot=128, rb=256, ch2=512)
+    assert B.staging_dtype(slot_geom, False) == jnp.bfloat16
+    assert B.staging_dtype(slot_geom, True) == jnp.float32
+    assert B.staging_dtype(GF, False) == jnp.float32    # 8-row unit
+    assert B.staging_dtype(GFB, False) == jnp.bfloat16  # 16-row unit
+
+
+def test_bf16_twopass_bitwise_vs_fp32_unit(monkeypatch):
+    """With phase fusion OFF (two-pass flat schedule), bf16 16-row
+    staging must still be bitwise the fp32 8-row unit's result on
+    integer data — the staging dtype changes bytes moved, never sums."""
+    monkeypatch.setenv("ROC_BINNED_NO_FUSE", "1")
+    n, t, e, h = 700, 700, 5000, 64
+    src, dst, x = _int_graph(n, t, e, h, 42)
+    p32 = B.build_binned_plan(src, dst, n, t, geom=GF)
+    p16 = B.build_binned_plan(src, dst, n, t, geom=GFB)
+    o32 = np.asarray(B.run_binned(jnp.asarray(x), p32, interpret=True))
+    o16 = np.asarray(B.run_binned(jnp.asarray(x), p16, interpret=True))
+    np.testing.assert_array_equal(o16, o32)
+
+
+def test_kernel_budget_table_is_current():
+    """tools/kernel_budgets.json is what tools/check_kernel_budgets.py
+    computes today, and its two claims (flat steps, bf16 staging bytes;
+    the streamed bytes) hold: the preflight gate, as a test."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "check_kernel_budgets.py")
+    spec = importlib.util.spec_from_file_location("check_kernel_budgets",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.main([]) == 0

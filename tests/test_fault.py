@@ -202,34 +202,53 @@ def test_nan_step_skip_is_bitwise_noop():
 
 
 def test_nonfinite_escalation_ladder(tmp_path):
-    """3 consecutive skips: rung 1 disables -megafuse and rebuilds the
-    step with params preserved; 3 more: rung 2 restores from the last
-    checkpoint."""
+    """The guard's host side, whole: one non-finite step counts a skip
+    and a streak and changes nothing; a finite step ends the streak;
+    NONFINITE_ESCALATE_AFTER skips in a row restore the last durable
+    checkpoint (parameters and epoch), say so in one event, and start
+    the count again."""
+    from roc_tpu.train.driver import NONFINITE_ESCALATE_AFTER as K
     tr, cfg = _small_trainer(4, checkpoint_path=str(tmp_path / "ck.npz"))
+    tr.train(print_fn=_noop)
     tr.save_checkpoint(cfg.checkpoint_path)
-    saved_epoch = tr.epoch
-    cfg.megafuse = True
-    before = jax.device_get(tr.params)
+    saved_epoch, saved = tr.epoch, jax.device_get(tr.params)
+    events = []
+    inject.attach(lambda kind, **kw: events.append((kind, kw)))
+    tr.run_epoch()                      # moves on from the checkpoint
+    moved = jax.device_get(tr.params)
+    assert any((np.asarray(a) != np.asarray(b)).any() for a, b in zip(
+        jax.tree.leaves(saved), jax.tree.leaves(moved)))
     tr._last_nonfinite = jnp.asarray(True)
-    for _ in range(3):
+    for i in range(K - 1):
         tr._check_nonfinite(1, _noop)
-    assert tr._nf_stage == 1 and cfg.megafuse is False
-    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(tr.params)):
+        assert (tr._nf_streak, tr._nf_skips) == (i + 1, i + 1)
+    tr._last_nonfinite = jnp.asarray(False)
+    tr._check_nonfinite(1, _noop)       # recovered on its own
+    assert tr._nf_streak == 0 and tr._nf_skips == K - 1 and not events
+    for a, b in zip(jax.tree.leaves(moved), jax.tree.leaves(tr.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    tr.epoch = 99
     tr._last_nonfinite = jnp.asarray(True)
-    for _ in range(3):
+    tr.epoch = 99
+    for _ in range(K):
         tr._check_nonfinite(2, _noop)
-    assert tr._nf_stage == 2
-    assert tr.epoch == saved_epoch, "rung 2 did not restore the checkpoint"
+    assert [(k, e["stage"], e["streak"]) for k, e in events] == [
+        ("nonfinite_escalation", "restore", K)]
+    assert tr._nf_streak == 0
+    assert tr.epoch == saved_epoch, "the checkpoint was not restored"
+    for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(tr.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_nonfinite_escalation_without_checkpoint():
+    from roc_tpu.train.driver import NONFINITE_ESCALATE_AFTER as K
     tr, _ = _small_trainer(4)
+    events = []
+    inject.attach(lambda kind, **kw: events.append(kw["stage"]))
     tr._last_nonfinite = jnp.asarray(True)
-    for _ in range(6):
+    for _ in range(2 * K):
         tr._check_nonfinite(0, _noop)
-    assert tr._nf_stage == 2 and tr._nf_skips == 6  # degraded, still alive
+    # degraded, still alive, and it said so at every escalation
+    assert tr._nf_skips == 2 * K and events == ["no_checkpoint"] * 2
 
 
 def test_watchdog_nonfinite_and_state_roundtrip():

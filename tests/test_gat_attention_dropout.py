@@ -176,26 +176,6 @@ def test_sharded_attention_paths_take_the_mask(mode, monkeypatch):
     assert {(r, h) for r, h, _ in drawn} == {(RATE, 2), (RATE, 1)}
     info = tr.attention_info()
     assert info["backend"] == ("plan" if mode.endswith("plan") else "xla")
-    assert info["fused"] is False
-
-
-def test_the_fused_kernel_refuses_attention_dropout(monkeypatch):
-    """The fused Pallas kernel has no mask: a training step of a gat model
-    that drops coefficients is refused by name, never run without it."""
-    monkeypatch.setenv("ROC_BINNED_GEOM", "flat")
-    monkeypatch.delenv("ROC_NO_GATFUSE", raising=False)
-    ds, _ = _graph(n=200)
-    layers = [ds.in_dim, 8, ds.num_classes]
-    cfg = Config(layers=layers, dropout_rate=RATE, eval_every=10**9,
-                 model="gat", heads=2, aggregate_backend="matmul",
-                 aggregate_precision="exact", megafuse=True,
-                 weight_decay=0.0)
-    tr = Trainer(cfg, ds, build_gat(layers, RATE, heads=2))
-    assert tr.gdata.gat_fused and tr.attention_info()["fused"]
-    np.asarray(tr.predict_logits())          # evaluation: no mask, fused
-    with pytest.raises(ValueError, match="fused GAT kernel.*no attention "
-                                         "dropout"):
-        tr.run_epoch()
 
 
 def test_streamed_attention_takes_the_mask(monkeypatch):

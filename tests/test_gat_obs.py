@@ -6,12 +6,11 @@ fused kernel's binned plans are built only for a kernel that may run."""
 import numpy as np
 import pytest
 
-from roc_tpu import obs, ops
+from roc_tpu import obs
 from roc_tpu.graph import datasets
 from roc_tpu.memory import estimator
 from roc_tpu.models import build_gat
 from roc_tpu.obs import report as obs_report
-from roc_tpu.train import driver
 from roc_tpu.train.config import Config
 from roc_tpu.train.driver import Trainer
 
@@ -65,26 +64,26 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     tr = Trainer(cfg, ds, build_gat(cfg.layers, 0.6, heads=2))
     info = tr.attention_info()
     e = ds.graph.num_edges
-    assert info["backend"] == "plan" and info["fused"] is False
-    assert info["not_fused_because"] == "no -megafuse"
+    assert info["backend"] == "plan"
+    assert set(info) == {"backend", "plan_pad_ratio", "score_bytes"}
     # e float32 + the score's sign, [K, E] each: 2 heads, then 1
     assert info["score_bytes"] == (2 + 1) * e * 5
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
-    assert ("backend=plan gat_fused=False (no -megafuse)" in line
+    assert (line.startswith("# attention: backend=plan ")
             and f"gat_score_bytes={info['score_bytes']}" in line)
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
-    assert (att["backend"], att["fused"]) == ("plan", False)
+    assert att["backend"] == "plan" and "fused" not in att
     assert att["gat_plan_pad_ratio"] == pytest.approx(info["plan_pad_ratio"])
     assert att["gat_score_bytes"] == info["score_bytes"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     assert "roc_gat_plan_pad_ratio " in prom and "roc_gat_score_bytes " in prom
-    assert 'roc_gat_backend{backend="plan",fused="false"} 1' in prom
+    assert 'roc_gat_backend{backend="plan"} 1' in prom
     text = obs_report.report(str(tmp_path / "obs" / "trace.json"),
                              str(tmp_path / "obs" / "metrics.jsonl"))
-    assert "# attention: backend=plan gat_fused=False" in text
+    assert "# attention: backend=plan gat_plan_pad_ratio=" in text
     assert "gat_plan_build" in text
 
 
@@ -95,38 +94,6 @@ def test_models_without_attention_say_nothing(capsys):
     tr = Trainer(cfg, ds, build_gcn(cfg.layers, 0.5))
     assert tr.attention_info() is None
     assert "# attention" not in capsys.readouterr().err
-
-
-@pytest.mark.parametrize("refused", ["kill switch", "tpu", ""])
-def test_fused_plans_are_built_only_for_a_kernel_that_may_run(
-        refused, monkeypatch):
-    """`-megafuse` on a gat model used to build a binned plan pair
-    (`choose_geometry`, ~4 s a direction at the Reddit shape) before asking
-    whether the fused kernel may run at all.  On a TPU it may not (the
-    compiler of this installation refuses it, CHANGES.md PR 21), nor under
-    its kill switch: no plan is built, `gat_fused` is False, and the reason
-    is the trainer's to print."""
-    built = []
-    real = ops.build_binned_plans
-    monkeypatch.setattr(ops, "build_binned_plans",
-                        lambda *a, **k: built.append(1) or real(*a, **k))
-    monkeypatch.setenv("ROC_BINNED_GEOM", "flat")
-    monkeypatch.delenv("ROC_NO_GATFUSE", raising=False)
-    if refused == "kill switch":
-        monkeypatch.setenv("ROC_NO_GATFUSE", "1")
-    elif refused == "tpu":
-        monkeypatch.setattr(driver, "on_tpu", lambda: True)
-    g = _dataset().graph
-    gd = driver.dense_graph_data(g, "xla", "exact", gat_backend="plan",
-                                 megafuse=True, gat_heads=2, gat_head_dim=8)
-    assert gd.gat_plans is not None
-    if refused:
-        assert not built and gd.gat_bplans is None and not gd.gat_fused
-        assert driver.gat_fusion_refused(True)
-    else:
-        assert built and gd.gat_fused and gd.gat_bplans is not None
-        assert driver.gat_fusion_refused(True) == ""
-    assert driver.gat_fusion_refused(False) == "no -megafuse"
 
 
 def test_the_planner_sees_a_gat_ops_edge_residuals():
@@ -147,9 +114,6 @@ def test_the_planner_sees_a_gat_ops_edge_residuals():
     gat0 = next(op for op in model.ops if op.kind == "gat")
     assert estimator.gat_edge_residual_bytes(gat0, edges) == heads * edges * 5
     assert estimator.gat_edge_residual_bytes(model.ops[0], edges) == 0
-    # the fused kernel's saving is the same residuals less its node planes
-    assert estimator.gat_residual_drop(model, rows, edges) == (
-        (heads + 1) * edges * 5 - 2 * rows * (heads + 1) * 4)
 
 
 def test_the_trainers_estimate_counts_the_attention_plans():

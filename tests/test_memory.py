@@ -108,6 +108,24 @@ def _brute_force(est, budget):
     return best, any_ok
 
 
+@pytest.mark.parametrize("name,kw,total", [
+    ("gcn-reddit", dict(layers=[602, 256, 41], dropout_rate=0.5),
+     2145141720),
+    ("gat-reddit", dict(layers=[602, 8, 41], dropout_rate=0.6, heads=8),
+     1836352035),
+])
+def test_estimate_of_the_benchmarks_models_is_pinned(name, kw, total):
+    """The estimator's all-KEEP bytes for the two configurations of
+    BENCHMARK.json at the cells' size (232,965 rows, 23,516,643 edges):
+    the figures of the commit before the fused paths and their drops left
+    the estimator (PR 27), so a change to what it counts shows here."""
+    from roc_tpu.models import build_model
+    model = build_model(name.split("-")[0], kw["layers"], kw["dropout_rate"],
+                        "", heads=kw.get("heads", 8))
+    est = estimate_model(model, 232965, 23516643)
+    assert est.total_full_bytes() == total
+
+
 @pytest.mark.parametrize("L", range(2, 9))
 def test_dp_matches_brute_force(L):
     rng = np.random.default_rng(100 + L)

@@ -182,3 +182,37 @@ def test_profile_flag_writes_trace(tmp_path):
     files = [os.path.join(r, f)
              for r, _, fs in os.walk(tmp_path / "tr") for f in fs]
     assert any("xplane" in f or "trace" in f for f in files), files
+
+
+@pytest.mark.parametrize("flag", [["-megafuse"], ["-fusion-depth", "2"]])
+def test_the_fusion_flags_are_refused(flag, capsys):
+    """The whole-layer and cross-layer fused kernels left with their
+    options (PR 27): the parser names the flag it does not know."""
+    with pytest.raises(SystemExit) as e:
+        parse_args(["-file", "x", "-layers", "8-4"] + flag)
+    assert e.value.code == 2
+    assert flag[0] in capsys.readouterr().err
+
+
+def test_the_fusion_switches_are_named_nowhere():
+    """None of the eight environment names that selected or killed a fused
+    path is read by the program, the second harness or the tools."""
+    import dataclasses
+    import os
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    gone = re.compile("|".join((
+        "ROC_MEGAFUSE", "ROC_FUSION_DEPTH", "ROC_NO_MEGAFUSE",
+        "ROC_MEGA_BWD", "ROC_XLAYER", "ROC_NO_GATFUSE", "ROC_GAT_BWD",
+        "ROC_GAT_HEADGROUPS")))
+    paths = [os.path.join(root, "bench.py")]
+    for top in ("roc_tpu", "tools"):
+        for d, _, files in os.walk(os.path.join(root, top)):
+            paths += [os.path.join(d, f) for f in files
+                      if f.endswith((".py", ".sh", ".json", ".cc", ".h"))]
+    assert len(paths) > 100
+    for p in paths:
+        with open(p, encoding="utf-8") as f:
+            assert not gone.search(f.read()), p
+    fields = {f.name for f in dataclasses.fields(Config)}
+    assert not fields & {"megafuse", "fusion_depth"}

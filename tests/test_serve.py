@@ -3,7 +3,7 @@
 The contract under test mirrors ISSUE 13's acceptance gates:
 
 - served logits match the training-side eval forward to <= 32 ULPs,
-  across matmul/binned/megafuse backends and fp32/bf16 storage (same
+  across matmul/binned backends, both precisions and fp32/bf16 storage (same
   params, same graph data, same model.apply — serving adds a gather,
   never a different forward);
 - an arbitrary mixed-batch-size request stream never retraces after
@@ -42,13 +42,13 @@ def _lock_order_witness(lock_witness):
     yield
 
 
-def _engine(ds, *, model="gcn", backend="matmul", megafuse=False,
-            bf16_storage=False, heads=2, start_queue=False, serve_batch=8,
+def _engine(ds, *, model="gcn", backend="matmul", bf16_storage=False,
+            heads=2, start_queue=False, serve_batch=8,
             serve_wait_ms=1.0, precision="fast"):
     cfg = Config(layers=[ds.in_dim, 16, ds.num_classes], dropout_rate=0.0,
                  eval_every=10**9, model=model, heads=heads,
-                 aggregate_backend=backend, megafuse=megafuse,
-                 bf16_storage=bf16_storage, serve_batch=serve_batch,
+                 aggregate_backend=backend, bf16_storage=bf16_storage,
+                 serve_batch=serve_batch,
                  serve_wait_ms=serve_wait_ms, aggregate_precision=precision)
     m = build_model(model, cfg.layers, cfg.dropout_rate, cfg.aggr,
                     heads=heads)
@@ -78,23 +78,21 @@ def test_bucket_for_maps_to_smallest_fitting():
 
 # -- parity: served == eval forward, <= 32 ULPs ----------------------------
 
-@pytest.mark.parametrize("backend,megafuse,bf16", [
-    ("matmul", False, False),
-    ("binned", False, False),
-    ("binned", True, False),      # whole-layer megakernel
-    ("binned", False, True),      # bf16 storage / fp32 accumulation
+@pytest.mark.parametrize("backend,precision,bf16", [
+    ("matmul", "fast", False),
+    ("binned", "fast", False),
+    ("binned", "exact", False),   # fp32 staging, 3-way bf16 split dots
+    ("binned", "fast", True),     # bf16 storage / fp32 accumulation
 ])
-def test_served_matches_eval_forward(backend, megafuse, bf16, monkeypatch):
+def test_served_matches_eval_forward(backend, precision, bf16):
     """Every query row must equal the eval forward's row to <= 32 ULPs.
 
     The oracle is `FrozenBundle.predict_logits` — the SAME jitted program
     eval runs — so this pins that bucketing/padding/gather never perturb
-    the forward, per backend and storage mode."""
-    if megafuse:
-        # the megakernel path runs the flat schedule (test_mega.py's pin)
-        monkeypatch.setenv("ROC_BINNED_GEOM", "flat")
+    the forward, per backend, precision and storage mode."""
     ds = datasets.get("roc-audit", seed=1)
-    eng = _engine(ds, backend=backend, megafuse=megafuse, bf16_storage=bf16)
+    eng = _engine(ds, backend=backend, precision=precision,
+                  bf16_storage=bf16)
     try:
         ref = np.asarray(eng.bundle.predict_logits())
         rng = np.random.default_rng(7)
@@ -135,31 +133,14 @@ def test_served_matches_eval_forward_gat():
         eng.close()
 
 
-def test_served_matches_eval_forward_gat_fused(tmp_path, monkeypatch):
-    """Round 19: serving inherits the fused attention megakernel for
-    free — the fused-GAT engine serves what eval computes (<= 32 ULPs),
-    a warm plan cache means zero plan rebuilds at cold start, and
-    ``gat_fused`` is pytree metadata so the step caches key on it."""
-    import dataclasses as dc
-
-    import jax
-
-    monkeypatch.setenv("ROC_BINNED_GEOM", "flat")
-    monkeypatch.setenv("ROC_PLAN_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("ROC_PLAN_CACHE_MIN_EDGES", "0")
+def test_served_matches_eval_forward_gat_plan():
+    """A gat model over the plan road of attention (ops.edge
+    gat_attend_plan) serves what eval computes (<= 32 ULPs), from one
+    trace."""
     ds = datasets.get("roc-audit", seed=1)
-    first = _engine(ds, model="gat", backend="binned", megafuse=True)
-    first.close()
-    eng = _engine(ds, model="gat", backend="binned", megafuse=True)
+    eng = _engine(ds, model="gat", backend="binned")
     try:
-        gd = eng.bundle.gdata
-        assert gd.gat_bplans is not None and gd.gat_fused
-        # flipping gat_fused flips the treedef — the serve/eval jit
-        # caches therefore key on the fused mode (zero silent replays)
-        assert (jax.tree_util.tree_structure(gd)
-                != jax.tree_util.tree_structure(
-                    dc.replace(gd, gat_fused=False)))
-        assert eng.cold_start_stats["plan_builds"] == 0
+        assert eng.bundle.gdata.gat_plans is not None
         assert eng.cold_start_stats["traces"] == 1
         ref = np.asarray(eng.bundle.predict_logits())
         ids = np.arange(ds.graph.num_nodes, dtype=np.int32)

@@ -4,8 +4,6 @@ on a machine that has none.
 
     python tools/aot_compile.py -dataset reddit -layers 602-256-41
     python tools/aot_compile.py -dataset reddit -layers 602-256-41 -parts 4
-    python tools/aot_compile.py -dataset mega-shard -layers 64-128-8 \\
-        -model gin -aggr-backend binned -megafuse
 
 Takes `python -m roc_tpu`'s own flags.  libtpu can describe a v5e
 topology and run its compiler without a chip, so a kernel the compiler

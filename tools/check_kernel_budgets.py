@@ -22,36 +22,6 @@ not a clean 0.5 because the 16-row bf16 unit pads every touched cell to
 twice the rows of the 8-row fp32 unit (measured ~0.52 on the uniform
 synthetic shapes); 0.6 leaves headroom without letting the claim decay.
 
-Megakernel rows (PR "whole-layer megakernel"): every shape also carries a
-``megakernel`` entry — does the fused aggregate->linear schedule ATTACH
-(group staging <= _FUSE_MAX_STG_ROWS), its real-chunk step count, the
-phase-2 chunk count C2, and whether the trace-time VMEM gate admits the
-kernel at H=128/256.  At the dense shapes the honest answer is attach=
-false — the fused schedule is a SHARD-SCALE optimization (per-group
-staging must fit VMEM), so the gate runs at ``mega_shard_scaled``: the
-megakernel's steps must be <= 0.85x the two-pass LAYER cost (aggregation
-steps + the rb-row output sweep the separate linear pass adds), and the
-predicted per-layer HBM traffic at the Reddit shape must drop by at least
-the intermediate's write + read (binned.predicted_layer_hbm_bytes).
-
-Backward rows (round 12): every shape also carries a ``megakernel_bwd``
-entry on the TRANSPOSED edges — the fused backward's grid steps + the
-one remaining dW GEMM sweep vs the VJP replay's full recompute +
-transposed aggregation + three GEMM sweeps, gated at the same 0.85x at
-``mega_shard_scaled``, plus predicted per-layer TRAIN-STEP HBM bytes
-(forward-only vs fwd+bwd fusion) pinned at >= 2x drop at the Reddit
-shape (binned.predicted_trainstep_hbm_bytes).
-
-Cross-layer rows (round 16): every shape carries a ``megakernel_xlayer``
-entry — the fusion-region forward/backward grid-step counts at depths 2
-and full (the forward grid is depth * the per-layer fused step count;
-the backward adds the (depth-1)-sweep forward replay), plus predicted
-TRAIN-STEP HBM bytes for a depth-2 and depth-3 region
-(binned.predicted_xlayer_trainstep_hbm_bytes).  check_xlayer_claim gates
-the round's acceptance claim: the region's per-layer share of predicted
-train-step HBM at the Reddit GCN shape must be <= 0.5x PR 10's per-layer
-mega+bwd number (the >= 2x cut of record, docs/PERF.md round 16).
-
 Stream rows (round 20): the Reddit-scale shape carries a ``stream``
 entry — predicted streamed wire bytes/epoch for the out-of-core
 executor at the GCN-of-record layers, priced both ways by
@@ -83,15 +53,6 @@ BUDGETS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 SHAPES = [
     ("reddit_scaled", 32768, 4_194_304, 0),
     ("products_scaled", 262_144, 2_097_152, 1),
-    # Shard-scale shape where the fused aggregate->linear schedule
-    # genuinely attaches AND the megakernel's VMEM gate admits it (bf16
-    # staging at H=128); degree 8, roughly one greedy-cut shard of a
-    # medium graph.
-    ("mega_shard_scaled", 1024, 8192, 2),
-    # Shard-scale shape for the fused GAT attention kernel (round 19):
-    # like mega_shard_scaled but its own seed, so the attention rows
-    # don't ride the aggregate rows' cell statistics.
-    ("gat_shard", 1024, 8192, 3),
 ]
 
 # Max allowed flat/default total-step ratio at the Reddit-scale shape
@@ -102,41 +63,6 @@ FLAT_MAX_RATIO = 0.75
 # (the bf16-storage acceptance criterion: ~2x fewer staging bytes; the
 # 16-row unit's extra cell padding keeps it above a clean 0.5).
 BF16_MAX_RATIO = 0.6
-
-# Max allowed megakernel / two-pass-LAYER step ratio at the mega shard
-# shape.  The two-pass layer pays the aggregation grid PLUS a separate
-# linear pass that sweeps the [rows, H] aggregate again (priced at one
-# step per rb-row window, the same window unit the kernel uses); the
-# megakernel runs the fused grid's real chunks only and issues the matmul
-# from VMEM, so it must clear the whole-layer budget with >= 15% margin.
-MEGA_MAX_RATIO = 0.85
-
-# Hidden width the megakernel HBM pin is evaluated at (binned._MODEL_H).
-MEGA_H = 256
-
-# Min allowed fwdonly/megabwd predicted TRAIN-STEP HBM ratio at the Reddit
-# shape (acceptance: fusing the backward must at least halve the per-layer
-# train-step traffic vs forward-only fusion — the replay's recompute +
-# cotangent round trips dominate; binned.predicted_trainstep_hbm_bytes).
-MEGA_BWD_MIN_DROP = 2.0
-
-# Max allowed (xlayer train-step HBM / depth) / per-layer-mega+bwd ratio at
-# the Reddit shape (round-16 acceptance: a fusion region must at least
-# halve the per-layer train-step traffic again vs PR 10's fused layer —
-# the inter-layer boundary and u/mask round trips it drops dominate).
-XLAYER_MAX_RATIO = 0.5
-
-# Max allowed fused/unfused predicted GAT train-step HBM ratio (round-19
-# acceptance: the attention megakernel must cut per-layer train-step
-# traffic to <= 0.6x the unfused plan composition at every committed
-# shape — the per-edge score/alpha/gather round trips it keeps in VMEM
-# dominate the unfused bill, so the modeled ratio lands far below).
-GAT_MAX_RATIO = 0.6
-
-# Committed attention shape the GAT rows are priced at: heads x head_dim
-# stacks to exactly one 128-lane tile (the kernel's native layout; the
-# paper's K=8, F'=8 and Reddit's K=2, F=64 both pad to the same tile).
-GAT_K, GAT_F = 2, 64
 
 # Max allowed bf16-streamed / fp32-streamed predicted bytes-per-epoch
 # ratio at the Reddit-scale shape (round-20 acceptance: the bf16 slot
@@ -185,10 +111,6 @@ def compute_table():
                 "staging_dtype": str(B.staging_dtype(geom, False).__name__),
                 "staging_bytes": int(B.staging_bytes_for(src, dst, geom)),
             }
-        entry["megakernel"] = _mega_entry(src, dst, n, e)
-        entry["megakernel_bwd"] = _mega_bwd_entry(src, dst, n, e)
-        entry["megakernel_xlayer"] = _xlayer_entry(src, dst, n, e)
-        entry["gat_fused"] = _gat_entry(src, dst, n, e)
         if name == "reddit_scaled":
             # the stream row needs a real partition + halo maps (O(E)
             # with a per-part unique) — priced once, at the shape the
@@ -255,228 +177,6 @@ def check_stream_claim(table):
     return problems
 
 
-def _gat_entry(src, dst, n, e):
-    """Fused GAT attention row (round 19, ops/pallas/gat.py).  Step
-    counts are exact grid sizes at the committed GAT_K x GAT_F shape:
-    the forward runs the max pass + the sum pass, each one sweep of the
-    fwd fused schedule; the backward runs grid D (one fwd-plan sweep,
-    dst-keyed bands) + grid S (one transposed-plan sweep, dual outputs).
-    HBM pins use gat.predicted_gat_trainstep_hbm_bytes both ways."""
-    import roc_tpu.ops.pallas.binned as B
-    from roc_tpu.ops.pallas import gat as G
-    out = {
-        "heads": GAT_K, "head_dim": GAT_F,
-        "hbm_trainstep_bytes_unfused":
-            int(G.predicted_gat_trainstep_hbm_bytes(n, e, GAT_K, GAT_F,
-                                                    fused=False)),
-        "hbm_trainstep_bytes_fused":
-            int(G.predicted_gat_trainstep_hbm_bytes(n, e, GAT_K, GAT_F,
-                                                    fused=True)),
-    }
-    hp = G._pad_to(GAT_K * GAT_F, 128)
-    for gname, geom in [("flat", B.GEOM_FLAT),
-                        ("flat_sparse", B.GEOM_FLAT_SPARSE)]:
-        cbf, cnf, cntf = B._cell_stats(src, dst, geom.sb, geom.rb)
-        cbb, cnb, cntb = B._cell_stats(dst, src, geom.sb, geom.rb)
-        row = {"attaches": False}
-        rf = B._fused_sched_stats(cbf, cnf, cntf, geom, n, n, e)
-        rb = B._fused_sched_stats(cbb, cnb, cntb, geom, n, n, e)
-        if rf is not None:
-            sf, c2f, gf = rf
-            row.update({
-                "attaches": True,
-                "gat_fwd_steps": int(2 * sf),
-                "c2": int(c2f),
-                "vmem_ok_fwd": bool(G._gat_vmem_ok(geom, hp, c2f,
-                                                   groups=gf)),
-            })
-            if rb is not None:
-                sb_, c2b, gb = rb
-                row.update({
-                    "gat_bwd_steps": int(sf + sb_),
-                    "vmem_ok_bwd": bool(G._gat_bwd_vmem_ok(
-                        geom, geom, hp, c2f, c2b, gf, gb)),
-                })
-        out[gname] = row
-    return out
-
-
-def check_gat_claim(table):
-    """Round-19 acceptance gate: predicted fused GAT train-step HBM must
-    stay <= GAT_MAX_RATIO x the unfused composition at every committed
-    shape, and the fused schedule must keep attaching (with the forward
-    VMEM gate admitting it) at the gat_shard shape the parity tests
-    exercise.  The backward admission bool is recorded per shape but only
-    gated where it holds today — a False there is the documented
-    decline-to-oracle-backward story, not a silent regression."""
-    problems = []
-    for name in ("reddit_scaled", "products_scaled", "gat_shard"):
-        r = table[name]["gat_fused"]
-        unf = r["hbm_trainstep_bytes_unfused"]
-        fus = r["hbm_trainstep_bytes_fused"]
-        if fus > GAT_MAX_RATIO * unf:
-            problems.append(
-                f"gat HBM claim: predicted fused train-step bytes {fus} > "
-                f"{GAT_MAX_RATIO}x unfused {unf} at {name} — ratio "
-                f"{fus / unf:.3f}")
-    g = table["gat_shard"]["gat_fused"]["flat"]
-    if not g["attaches"]:
-        problems.append("fused GAT schedule no longer attaches at "
-                        "gat_shard (flat)")
-    elif not g["vmem_ok_fwd"]:
-        problems.append("fused GAT VMEM gate rejects the forward at the "
-                        "committed shape at gat_shard — kernel never runs")
-    return problems
-
-
-def _xlayer_entry(src, dst, n, e):
-    """Cross-layer fusion-region row (round 16).  Step counts are exact
-    grid sizes: the region forward runs depth sweeps of the per-layer
-    fused schedule (one per fused layer, the inter-layer hand-off staying
-    in VMEM); the region backward runs (depth-1) forward-replay sweeps
-    plus depth transposed-plan sweeps, each the fused step count of its
-    plan.  HBM pins use binned.predicted_xlayer_trainstep_hbm_bytes at
-    H=MEGA_H (uniform hidden width — the GCN chain shape)."""
-    import roc_tpu.ops.pallas.binned as B
-    out = {
-        "hbm_trainstep_bytes_perlayer":
-            int(B.predicted_trainstep_hbm_bytes(n, MEGA_H, MEGA_H,
-                                                mega_bwd=True)),
-        "hbm_trainstep_bytes_xlayer_d2":
-            int(B.predicted_xlayer_trainstep_hbm_bytes(n, MEGA_H, 2)),
-        "hbm_trainstep_bytes_xlayer_d3":
-            int(B.predicted_xlayer_trainstep_hbm_bytes(n, MEGA_H, 3)),
-    }
-    for gname, geom in [("flat", B.GEOM_FLAT),
-                        ("flat_bf16", B.GEOM_FLAT_BF16)]:
-        cbf, cnf, cntf = B._cell_stats(src, dst, geom.sb, geom.rb)
-        cbb, cnb, cntb = B._cell_stats(dst, src, geom.sb, geom.rb)
-        row = {"attaches": False}
-        rf = B._fused_sched_stats(cbf, cnf, cntf, geom, n, n, e)
-        rb = B._fused_sched_stats(cbb, cnb, cntb, geom, n, n, e)
-        if rf is not None and rb is not None:
-            sf, c2f, gf = rf
-            sb, c2b, gb = rb
-            tp = -(-n // max(geom.sb, geom.rb)) * max(geom.sb, geom.rb)
-            row.update({
-                "attaches": True,
-                "xlayer_fwd_steps_d2": int(2 * sf),
-                "xlayer_bwd_steps_d2": int(sf + 2 * sb),
-                "vmem_ok_h128_d2": bool(
-                    B._xlayer_vmem_ok(geom, 128, max(c2f, c2b), 2,
-                                      groups=max(gf, gb), tp=tp)
-                    and B._xlayer_bwd_vmem_ok(geom, 128, max(c2f, c2b), 2,
-                                              groups=max(gf, gb), tp=tp,
-                                              relu_last=True)),
-            })
-        out[gname] = row
-    return out
-
-
-def check_xlayer_claim(table):
-    problems = []
-    r = table["reddit_scaled"]["megakernel_xlayer"]
-    perlayer = r["hbm_trainstep_bytes_perlayer"]
-    for depth, key in ((2, "hbm_trainstep_bytes_xlayer_d2"),
-                       (3, "hbm_trainstep_bytes_xlayer_d3")):
-        share = r[key] / depth
-        if share > XLAYER_MAX_RATIO * perlayer:
-            problems.append(
-                f"xlayer HBM claim: depth-{depth} region's per-layer "
-                f"train-step share {share:.0f} B > {XLAYER_MAX_RATIO}x "
-                f"per-layer mega+bwd {perlayer} B at reddit_scaled")
-    m = table["mega_shard_scaled"]["megakernel_xlayer"]
-    for gname in ("flat", "flat_bf16"):
-        if not m[gname]["attaches"]:
-            problems.append(f"fusion region no longer attaches at "
-                            f"mega_shard_scaled ({gname})")
-    # Like the per-layer mega gate: bf16 staging is the configuration the
-    # region must keep running at this shape; fp32 staging pricing the
-    # depth-2 backward working set past the budget is the expected
-    # composition story (the row records the honest False).
-    if (m["flat_bf16"]["attaches"]
-            and not m["flat_bf16"]["vmem_ok_h128_d2"]):
-        problems.append("fusion-region VMEM gate rejects bf16 staging at "
-                        "H=128 depth 2 at mega_shard_scaled — the region "
-                        "never runs")
-    return problems
-
-
-def _mega_entry(src, dst, n, e):
-    """Megakernel row for one shape: attach/steps/C2/VMEM admission per
-    flat geometry, the two-pass LAYER step cost it competes against, and
-    the predicted per-layer HBM bytes either way at H=MEGA_H."""
-    import roc_tpu.ops.pallas.binned as B
-    out = {
-        "hbm_layer_bytes_unfused":
-            int(B.predicted_layer_hbm_bytes(n, MEGA_H, MEGA_H)),
-        "hbm_layer_bytes_mega":
-            int(B.predicted_layer_hbm_bytes(n, MEGA_H, MEGA_H, mega=True)),
-    }
-    for gname, geom in [("flat", B.GEOM_FLAT),
-                        ("flat_bf16", B.GEOM_FLAT_BF16)]:
-        cb, cn, cnt = B._cell_stats(src, dst, geom.sb, geom.rb)
-        _, s1, s2 = B._plan_steps(cb, cn, cnt, geom, n, n, e)
-        lin_steps = -(-n // geom.rb)
-        row = {"attaches": False,
-               "twopass_layer_steps": int(s1 + s2 + lin_steps)}
-        r = B._fused_sched_stats(cb, cn, cnt, geom, n, n, e)
-        if r is not None:
-            steps, c2, g = r
-            row.update({
-                "attaches": True,
-                "mega_steps": int(steps),
-                "c2": int(c2),
-                "vmem_ok_h128": bool(B._mega_vmem_ok(geom, 128, 128, c2,
-                                                     groups=g)),
-                "vmem_ok_h256": bool(B._mega_vmem_ok(geom, 256, 256, c2,
-                                                     groups=g)),
-            })
-        out[gname] = row
-    return out
-
-
-def _mega_bwd_entry(src, dst, n, e):
-    """Backward-megakernel row (round 12), computed on the TRANSPOSED
-    edges — the plans.bwd direction the fused backward's grid runs over.
-    ``twopass_bwd_layer_steps`` prices what the VJP replay pays per layer:
-    the forward aggregation again (the recompute), the transposed
-    aggregation, and three rb-row GEMM sweeps (dagg = g@W^T, gw, gx
-    handoff); ``mega_bwd_steps`` is the fused grid plus the single
-    remaining dW GEMM sweep.  The train-step HBM pins use
-    binned.predicted_trainstep_hbm_bytes at H=MEGA_H."""
-    import roc_tpu.ops.pallas.binned as B
-    out = {
-        "hbm_trainstep_bytes_fwdonly":
-            int(B.predicted_trainstep_hbm_bytes(n, MEGA_H, MEGA_H)),
-        "hbm_trainstep_bytes_megabwd":
-            int(B.predicted_trainstep_hbm_bytes(n, MEGA_H, MEGA_H,
-                                                mega_bwd=True)),
-    }
-    for gname, geom in [("flat", B.GEOM_FLAT),
-                        ("flat_bf16", B.GEOM_FLAT_BF16)]:
-        cbf, cnf, cntf = B._cell_stats(src, dst, geom.sb, geom.rb)
-        _, s1f, s2f = B._plan_steps(cbf, cnf, cntf, geom, n, n, e)
-        cb, cn, cnt = B._cell_stats(dst, src, geom.sb, geom.rb)
-        _, s1b, s2b = B._plan_steps(cb, cn, cnt, geom, n, n, e)
-        sweep = -(-n // geom.rb)
-        row = {"attaches": False,
-               "twopass_bwd_layer_steps":
-                   int(s1f + s2f + s1b + s2b + 3 * sweep)}
-        r = B._fused_sched_stats(cb, cn, cnt, geom, n, n, e)
-        if r is not None:
-            steps, c2, g = r
-            row.update({
-                "attaches": True,
-                "mega_bwd_steps": int(steps + sweep),
-                "c2": int(c2),
-                "vmem_ok_h128": bool(B._mega_bwd_vmem_ok(
-                    geom, 128, 128, c2, groups=g, relu=True)),
-            })
-        out[gname] = row
-    return out
-
-
 def check_flat_claim(table):
     g = table["reddit_scaled"]["geometries"]
     flat, dflt = g["flat"]["steps_total"], g["default"]["steps_total"]
@@ -493,79 +193,11 @@ def check_flat_claim(table):
     return problems
 
 
-def check_mega_claim(table):
-    problems = []
-    m = table["mega_shard_scaled"]["megakernel"]
-    for gname in ("flat", "flat_bf16"):
-        row = m[gname]
-        if not row["attaches"]:
-            problems.append(f"megakernel no longer attaches at "
-                            f"mega_shard_scaled ({gname})")
-            continue
-        steps, layer = row["mega_steps"], row["twopass_layer_steps"]
-        if steps > MEGA_MAX_RATIO * layer:
-            problems.append(
-                f"megakernel step regression ({gname}): {steps} steps vs "
-                f"two-pass layer {layer} at mega_shard_scaled — ratio "
-                f"{steps / layer:.3f} > {MEGA_MAX_RATIO}")
-    # The VMEM gate must keep admitting the bf16-staged kernel at H=128
-    # (the configuration the parity tests execute); fp32 staging doubling
-    # past the budget at the same C2 is the expected composition story.
-    if m["flat_bf16"]["attaches"] and not m["flat_bf16"]["vmem_ok_h128"]:
-        problems.append("megakernel VMEM gate rejects bf16 staging at "
-                        "H=128 at mega_shard_scaled — kernel never runs")
-    # Reddit-shape HBM pin: fusing must drop at least the intermediate's
-    # write + read (2 * rows * H * 4 bytes).
-    r = table["reddit_scaled"]
-    drop = (r["megakernel"]["hbm_layer_bytes_unfused"]
-            - r["megakernel"]["hbm_layer_bytes_mega"])
-    need = 2 * r["num_rows"] * MEGA_H * 4
-    if drop < need:
-        problems.append(f"megakernel HBM claim: predicted per-layer drop "
-                        f"{drop} < intermediate write+read {need} at "
-                        f"reddit_scaled")
-    return problems
-
-
-def check_mega_bwd_claim(table):
-    problems = []
-    m = table["mega_shard_scaled"]["megakernel_bwd"]
-    for gname in ("flat", "flat_bf16"):
-        row = m[gname]
-        if not row["attaches"]:
-            problems.append(f"megakernel backward no longer attaches at "
-                            f"mega_shard_scaled ({gname})")
-            continue
-        steps, layer = row["mega_bwd_steps"], row["twopass_bwd_layer_steps"]
-        if steps > MEGA_MAX_RATIO * layer:
-            problems.append(
-                f"megakernel backward step regression ({gname}): {steps} "
-                f"steps vs two-pass replay {layer} at mega_shard_scaled — "
-                f"ratio {steps / layer:.3f} > {MEGA_MAX_RATIO}")
-        if not row["vmem_ok_h128"]:
-            problems.append(f"megakernel backward VMEM gate rejects "
-                            f"{gname} at H=128 at mega_shard_scaled — "
-                            f"fused backward never runs")
-    # Reddit-shape train-step pin: fwd+bwd fusion must drop predicted
-    # per-layer train-step HBM >= MEGA_BWD_MIN_DROP x vs forward-only.
-    r = table["reddit_scaled"]["megakernel_bwd"]
-    fwdonly = r["hbm_trainstep_bytes_fwdonly"]
-    megabwd = r["hbm_trainstep_bytes_megabwd"]
-    if fwdonly < MEGA_BWD_MIN_DROP * megabwd:
-        problems.append(
-            f"megakernel backward HBM claim: predicted train-step ratio "
-            f"{fwdonly / megabwd:.3f}x < {MEGA_BWD_MIN_DROP}x at "
-            f"reddit_scaled (fwdonly {fwdonly} vs megabwd {megabwd})")
-    return problems
-
-
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     update = "--update" in argv
     table = compute_table()
-    problems = (check_flat_claim(table) + check_mega_claim(table)
-                + check_mega_bwd_claim(table) + check_xlayer_claim(table)
-                + check_gat_claim(table) + check_stream_claim(table))
+    problems = check_flat_claim(table) + check_stream_claim(table)
     if update:
         if problems:
             for p in problems:

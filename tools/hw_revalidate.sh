@@ -137,7 +137,7 @@ timeout 120 python -m roc_tpu.obs calibration -dir /tmp/roc_obs_hw 2>&1 \
     | tee -a "$LOG"
 
 note "3h. per-kernel microbench on the chip: times every Pallas variant"
-note "    (two-pass p1/p2, flat, fused, mega fwd/bwd, matmul) in isolation"
+note "    (two-pass p1/p2, flat, fused, matmul) in isolation"
 note "    across the geometry presets and COMMITS the measured table into"
 note "    tools/kernel_budgets.json — the balance cost model and"
 note "    choose_geometry warm-start from it (interpret=false tables only;"
@@ -169,64 +169,6 @@ note "    in docs/PERF.md and re-fit the flat DMA constant from it)"
 for flat in 0 1; do
     timeout 900 python tools/sweep_binned.py 512 4096 128 512 4096 \
         2097152 $flat 2>&1 | tail -1 | tee -a "$LOG"
-done
-
-note "4c. megakernel FULL TRAIN-STEP A/B at the mega-shard shape: three"
-note "    legs, same seed — (1) two-pass baseline, (2) forward-only fusion"
-note "    (-megafuse with the backward killed via ROC_MEGA_BWD=0), (3)"
-note "    forward+backward fusion (-megafuse, fused VJP).  The -v losses"
-note "    must agree to ~1e-3 across all three; leg 2 vs 1 isolates the"
-note "    forward win, leg 3 vs 2 isolates the backward win (the fused VJP"
-note "    skips the [rows, H] cotangent round trip — kernel_budgets.json"
-note "    megakernel_bwd predicts 10-vs-28 backward layer steps and a"
-note "    >= 2x per-layer train-step HBM drop vs forward-only fusion)."
-note "    Record all three epoch times + the GIN/GCN pair in docs/PERF.md."
-note "    ROC_BINNED_GEOM pins flat on ALL legs so the measured deltas are"
-note "    fusion, not the cost model's geometry pick."
-for leg in "::" "-megafuse:0:" "-megafuse::"; do
-    mf=${leg%%:*}; rest=${leg#*:}; kill=${rest%%:*}
-    ROC_BINNED_GEOM=flat ROC_MEGA_BWD=$kill timeout 900 python -m roc_tpu \
-        -dataset mega-shard -layers 64-128-8 -model gin \
-        -aggr-backend binned -e 10 $mf -v 2>&1 | tail -2 | tee -a "$LOG"
-done
-# norm-folded GCN leg (round 12: GCN is mega-eligible end to end; the
-# fold pre/post-scales by D^-1/2 around the fused kernel)
-for mf in "" "-megafuse"; do
-    ROC_BINNED_GEOM=flat timeout 900 python -m roc_tpu \
-        -dataset mega-shard -layers 64-128-8 -model gcn \
-        -aggr-backend binned -e 10 $mf -v 2>&1 | tail -2 | tee -a "$LOG"
-done
-
-note "4d. cross-layer fusion-region FULL TRAIN-STEP A/B (round 16): the"
-note "    residual-free deep GCN chain (gcn-chain) at three region caps,"
-note "    same seed — depth 1 (per-layer fusion, the PR-10 program),"
-note "    depth 2 (two-layer regions), full (the whole hidden stack in"
-note "    one grid).  The -v losses must agree to ~1e-3 across all three;"
-note "    depth 2 vs 1 isolates the first inter-layer boundary's HBM"
-note "    round trip, full vs 2 the rest (kernel_budgets.json"
-note "    megakernel_xlayer predicts a depth-2 region at <= 0.51x the"
-note "    per-layer mega+bwd train-step HBM per layer at the Reddit"
-note "    shape).  Record all three epoch times in docs/PERF.md round 16."
-for fd in 1 2 0; do
-    ROC_BINNED_GEOM=flat timeout 900 python -m roc_tpu \
-        -dataset mega-shard -layers 64-128-128-8 -model gcn-chain \
-        -aggr-backend binned -e 10 -megafuse -fusion-depth $fd -v 2>&1 \
-        | tail -2 | tee -a "$LOG"
-done
-
-note "4e. fused GAT attention A/B (round 19): same seed, plan attention"
-note "    backend, fused attention megakernel on vs ROC_NO_GATFUSE=1"
-note "    (the unfused gat_attend_plan composition).  The -v losses must"
-note "    agree to ~1e-3; the fused leg's epoch time is the round-19"
-note "    claim of record (kernel_budgets.json gat_fused predicts"
-note "    <= 0.6x unfused train-step HBM at every committed shape)."
-note "    Measured gat_fused_hbm_bytes also rides kernel_bench --filter"
-note "    gat (calibration ledger joins it to the plan-build prediction)."
-for gf in "ROC_NO_GATFUSE=1" ""; do
-    env $gf ROC_BINNED_GEOM=flat timeout 900 python -m roc_tpu \
-        -dataset mega-shard -layers 64-128-8 -model gat -heads 2 \
-        -aggr-backend matmul -e 10 -megafuse -v 2>&1 \
-        | tail -2 | tee -a "$LOG"
 done
 fi
 

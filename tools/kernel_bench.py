@@ -8,14 +8,6 @@ Variants, per shape x geometry (only those whose gates admit them):
   flat       run_binned over the flat compacted schedule with the fused
              step list stripped — the scan fallback the VMEM gate runs
   fused      run_binned over the fused single-grid pipeline
-  mega_fwd   run_binned_linear (aggregate->linear megakernel) at H=KB_H
-  mega_bwd   run_binned_linear_bwd over the TRANSPOSED plan (relu path)
-  gat        run_binned_gat (+_bwd when the head-group gate admits it):
-             the fused per-head score->softmax->aggregate megakernel at
-             K=2 heads x F=64 (the lane-packed Hp=128 shape); also pairs
-             the ledger's gat_fused_hbm_bytes prediction (same content
-             key dense_graph_data predicts under) against the compiled
-             program's XLA bytes-accessed figure
   matmul     scatter_gather_matmul — the one-hot backend the balance
              cost model's warm-start prior prices
 
@@ -79,10 +71,10 @@ DEVICE = bool(int(os.environ.get("KB_DEVICE", "0")))
 H = int(os.environ.get("KB_H", "128"))
 REPS = int(os.environ.get("KB_REPS", "5" if DEVICE else "1"))
 
-# CI shape: the mega-shard scale where the fused schedule attaches and
-# the VMEM gate admits the megakernel at H=128, so interpret mode
-# exercises EVERY variant.  Device mode adds the dense/sparse scales the
-# step-budget table pins (check_kernel_budgets.SHAPES).
+# CI shape: a one-shard scale where the flat plans' fused schedule
+# attaches, so interpret mode exercises EVERY variant.  Device mode adds
+# the dense/sparse scales the step-budget table pins
+# (check_kernel_budgets.SHAPES).
 SHAPES_CI = [("mega_shard_scaled", 1024, 8192, 2)]
 SHAPES_DEVICE = SHAPES_CI + [
     ("reddit_scaled", 32768, 4_194_304, 0),
@@ -131,7 +123,7 @@ def _strip_fused(plan):
     """The flat scan-fallback variant: same plan, fused step list gone."""
     return dataclasses.replace(
         plan, f_meta=None, f_rows=None, f_blk=None, f_blk2=None,
-        f_obi=None, f_dsrc=None, f_ddst=None, f_last=None)
+        f_obi=None, f_dsrc=None, f_ddst=None)
 
 
 def _phase_times(x, plan, geom, interpret):
@@ -193,7 +185,6 @@ def bench_shape(name, n, e, seed, interpret, led):
     src = rng.integers(0, n, size=e).astype(np.int64)
     dst = rng.integers(0, n, size=e).astype(np.int64)
     x = jnp.asarray(rng.standard_normal((n, H)).astype(np.float32))
-    w = jnp.asarray(rng.standard_normal((H, H)).astype(np.float32) * 0.1)
     entry = {"num_rows": n, "num_edges": e, "seed": seed, "kernels": {}}
 
     for gname, geom in _geometries():
@@ -222,10 +213,6 @@ def bench_shape(name, n, e, seed, interpret, led):
                 tf = _timeit(lambda p=plan: jax.jit(
                     lambda xx: B.run_binned(xx, p, interpret))(x))
                 row["fused_s"] = tf
-                tm = _timeit(lambda p=plan: jax.jit(
-                    lambda xx, ww: B.run_binned_linear(
-                        xx, ww, p, interpret))(x, w))
-                row["mega_fwd_s"] = tm
                 t = min(t, tf)
         else:
             t = _timeit(lambda p=plan: jax.jit(
@@ -240,119 +227,6 @@ def bench_shape(name, n, e, seed, interpret, led):
         entry["kernels"][gname] = row
         print(f"{name}/{gname}: {row['variant']} {t * 1e3:.2f} ms "
               f"({row['steps_total']} steps, modeled {pred_t * 1e3:.2f} ms)")
-
-    # Fused backward over the transposed plan (the plans.bwd direction).
-    if _want(name, "mega_bwd"):
-        bwd_geom = B.GEOM_FLAT_BF16
-        bwd_plan = B.build_binned_plan(dst, src, n, n, geom=bwd_geom,
-                                       tuned_ok=False)
-        g = jnp.asarray(rng.standard_normal((n, H)).astype(np.float32))
-        y = jnp.abs(x)
-        probe = B.run_binned_linear_bwd(g, y, w, bwd_plan, interpret,
-                                        relu=True)
-        if probe is not None:
-            tb = _timeit(lambda: jax.jit(
-                lambda gg, yy, ww: B.run_binned_linear_bwd(
-                    gg, yy, ww, bwd_plan, interpret, relu=True))(g, y, w))
-            entry["kernels"]["flat_bf16/mega_bwd"] = {
-                "variant": "mega_bwd", "total_s": tb,
-                "steps_total": int(bwd_plan.f_blk.shape[0]),
-                "per_step_s": tb / max(int(bwd_plan.f_blk.shape[0]), 1)}
-            print(f"{name}/flat_bf16 mega_bwd: {tb * 1e3:.2f} ms")
-        else:
-            print(f"{name}/flat_bf16 mega_bwd: gate closed (skipped)")
-
-    # Fused GAT attention (round 19): forward + hand-derived backward
-    # over the fwd/transposed plan pair, at the K=2 x F=64 head-stacked
-    # shape (Hp = 128, one head group).  The section also closes the
-    # gat_fused_hbm_bytes calibration loop: it re-issues the plan-build
-    # prediction under the bench ledger (same predictor + content key as
-    # train/driver.dense_graph_data) and measures the jitted step's XLA
-    # bytes-accessed — a compiler figure, so it is paired on hardware
-    # AND in interpret mode (where it prices the emulation, another
-    # reason interpret tables are harness-only).
-    if _want(name, "gat"):
-        import roc_tpu.ops.pallas.gat as G
-        from roc_tpu.obs.ledger import content_key
-        K, F = 2, H // 2
-        gplan = B.build_binned_plan(src, dst, n, n, geom=B.GEOM_FLAT,
-                                    tuned_ok=False)
-        gbwd = B.build_binned_plan(dst, src, n, n, geom=B.GEOM_FLAT,
-                                   tuned_ok=False)
-        ng, bwd_ok = G.gat_head_groups(gplan, gbwd, K, F)
-        if ng:
-            table = x.reshape(n, K, F)
-            a_src = jnp.asarray(
-                rng.standard_normal((K, F)).astype(np.float32))
-            ad_l = jnp.asarray(
-                rng.standard_normal((n, K)).astype(np.float32))
-            sf = int(gplan.f_meta.shape[0])
-
-            def gat_fwd(tt, aa, dd):
-                return G.run_binned_gat(tt, aa, dd, gplan, 0.2,
-                                        interpret, "exact")
-
-            jfwd = jax.jit(gat_fwd)
-            tg = _timeit(lambda: jfwd(table, a_src, ad_l))
-            entry["kernels"]["flat/gat_fwd"] = {
-                "variant": "gat_fwd", "total_s": tg, "heads": K,
-                "head_dim": F, "steps_total": 2 * sf,
-                "per_step_s": tg / max(2 * sf, 1)}
-            print(f"{name}/flat gat_fwd: {tg * 1e3:.2f} ms "
-                  f"({2 * sf} steps, K={K} F={F})")
-
-            if bwd_ok:
-                out, m, z = jfwd(table, a_src, ad_l)
-                gout = jnp.asarray(rng.standard_normal(
-                    (n, K, F)).astype(np.float32))
-                sb_ = int(gbwd.f_meta.shape[0])
-
-                def gat_bwd(gg, oo, tt, aa, dd, mm, zz):
-                    return G.run_binned_gat_bwd(
-                        gg, oo, tt, aa, dd, mm, zz, gplan, gbwd, 0.2,
-                        interpret, "exact")
-
-                jbwd = jax.jit(gat_bwd)
-                tb2 = _timeit(lambda: jbwd(gout, out, table, a_src,
-                                           ad_l, m, z))
-                entry["kernels"]["flat/gat_bwd"] = {
-                    "variant": "gat_bwd", "total_s": tb2,
-                    "steps_total": sf + sb_,
-                    "per_step_s": tb2 / max(sf + sb_, 1)}
-                print(f"{name}/flat gat_bwd: {tb2 * 1e3:.2f} ms "
-                      f"({sf + sb_} steps)")
-
-            if led is not None:
-                def _bytes_accessed(jitted, *a):
-                    try:
-                        ca = jitted.lower(*a).compile().cost_analysis()
-                        if isinstance(ca, (list, tuple)):
-                            ca = ca[0] if ca else {}
-                        return float(ca.get("bytes accessed", 0.0))
-                    except Exception:  # cost analysis is backend-optional
-                        return 0.0
-
-                gkey = content_key(rows=n, edges=e, heads=K, fdim=F)
-                led.predict(
-                    "gat_fused_hbm_bytes", gkey,
-                    G.predicted_gat_trainstep_hbm_bytes(
-                        n, e, K, F, fused=True),
-                    "bytes", shape=name)
-                measured = _bytes_accessed(jfwd, table, a_src, ad_l)
-                if bwd_ok:
-                    measured += _bytes_accessed(jbwd, gout, out, table,
-                                                a_src, ad_l, m, z)
-                if measured:
-                    ratio = led.measure("gat_fused_hbm_bytes", gkey,
-                                        measured, "bytes", shape=name)
-                    if ratio is not None:
-                        print(f"{name}/flat gat_fused_hbm_bytes: "
-                              f"measured/predicted {ratio:.3g}")
-                else:
-                    print(f"{name}/flat gat: no bytes-accessed figure "
-                          "from this backend (measurement skipped)")
-        else:
-            print(f"{name}/flat gat: head-group gate closed (skipped)")
 
     # The one-hot matmul backend — the rate the balance prior prices.
     # Its chunk planner requires dst-sorted edges (csr order; the binned

@@ -96,29 +96,6 @@ cmp -s "$PLAN_A" "$PLAN_B" || {
     diff "$PLAN_A" "$PLAN_B" >&2; rm -f "$PLAN_A" "$PLAN_B"; exit 1; }
 rm -f "$PLAN_A" "$PLAN_B"
 
-# Fusion-region determinism gate (round 16): same graph + config must
-# produce byte-identical region-plan JSON — the region partition keys
-# the step cache via fusion_depth, so nondeterminism here means phantom
-# retraces on device.  Covers the chainable model (gcn-chain, full
-# region), a per-layer-only model (sage, empty partition), and the
-# MLP-break negative case (gin).  Analytic op-IR walk, ~a second.
-echo "== fusion-region determinism =="
-REG_A=$(mktemp) REG_B=$(mktemp)
-for pass in "$REG_A" "$REG_B"; do
-    { timeout -k 10 120 env JAX_PLATFORMS=cpu python -m roc_tpu.models \
-          --model gcn-chain --layers 100-256-256-256-47 --depth 0 && \
-      timeout -k 10 120 env JAX_PLATFORMS=cpu python -m roc_tpu.models \
-          --model sage --layers 100-256-256-47 --depth 0 && \
-      timeout -k 10 120 env JAX_PLATFORMS=cpu python -m roc_tpu.models \
-          --model gin --layers 100-256-256-47 --depth 2; } > "$pass" || {
-        echo "preflight: region-plan dump failed" >&2
-        rm -f "$REG_A" "$REG_B"; exit 1; }
-done
-cmp -s "$REG_A" "$REG_B" || {
-    echo "preflight: fusion-region plan JSON not deterministic" >&2
-    diff "$REG_A" "$REG_B" >&2; rm -f "$REG_A" "$REG_B"; exit 1; }
-rm -f "$REG_A" "$REG_B"
-
 # Streamed smoke: the out-of-core executor must still train end-to-end
 # (tiny graph, 2 shards through 2 slots).  This is the cheapest proof that
 # slot rotation, the prefetch ring, and the host-side gradient scatter all
