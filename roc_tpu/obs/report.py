@@ -90,7 +90,8 @@ def summarize_metrics(records: List[dict]) -> List[str]:
                 f"# attention: backend={r.get('backend', '?')} "
                 f"gat_plan_pad_ratio="
                 f"{r.get('gat_plan_pad_ratio', 0):.4f} gat_score_bytes="
-                f"{r.get('gat_score_bytes', 0)}")
+                f"{r.get('gat_score_bytes', 0)} gat_dst_reads="
+                f"{r.get('gat_dst_reads', '?')}")
     for r in trains:
         lines.append(f"#   verdict: {r.get('watchdog_verdict', '?')} "
                      f"({r.get('epochs', '?')} epochs, "

@@ -246,14 +246,27 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # float32 array is stored at 128 lanes a row, 16 x its size (12 GB apiece at
 # the Reddit shape, K = 8), an [8, E] one at its size (752 MB).  Node-sized
 # score tables are [K, N] for the same reason; only the [*, K*F] feature
-# rows keep nodes/slots on the sublane axis.  tests/test_gat_layout.py pins
-# it in the jaxpr.
+# rows keep nodes/slots on the sublane axis.  Pinned in the jaxpr by
+# tests/test_gat_attention_dropout.py
+# (test_plan_path_keeps_edges_on_the_lane_axis) and tests/test_gat_plans.py
+# (test_no_gather_of_the_plan_path_is_indexed_by_edge_dst).
+#
+# READS: edge_dst is sorted, so a node table read by it is a segment
+# broadcast, and the aligned dst-keyed plan holds it (_plan_broadcast;
+# _edge_contract's du rows): no gather of this path takes edge_dst as its
+# index.  The src side still gathers (_take_lanes by edge_src, the
+# src-keyed plan's column reads, the feature rows by the plans' nid).
 #
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
-# backward is built from these primitives plus plain gathers — autodiff of
-# the forward would otherwise transpose every gather into a scatter.
+# backward is built from these primitives plus the src side's plain gathers
+# — autodiff of the forward would otherwise transpose every gather into a
+# scatter.
 
 _PLAN_CB_SUM = 512   # chunks per scan step, one-hot dot passes
+# the block-landing scans (_plan_blocks).  128, 256 and 512 are within 1 ms
+# a pass of each other on a v5e (the combine dot grows cb^2, the step count
+# falls); 128 is _plan_max's, so both scans pad the plan alike
+_PLAN_CB_BLOCKS = 128
 _PLAN_CB_MAX = 128   # smaller: the masked-max intermediate is [K, cb, cb, VB]
 _LANE_GATHER_CHUNK = 1 << 20   # indices a step of a long [K, M] lane gather
 
@@ -601,34 +614,131 @@ def _plan_max(edge_w, obi, edst, pos, num_rows: int):
     return acc.transpose(1, 0, 2).reshape(K, acc_windows * VB)[:, :num_rows]
 
 
-def _edge_contract(du, table, edge_src, edge_dst):
-    """c[k, e] = Σ_f du[dst_e, k, f]·table[src_e, k, f] as ``[K, E]``,
-    streamed over edge chunks so the [E, K, F] product never materializes;
-    each chunk's head sums land in place in the [K, E] result."""
+def _plan_blocks(form, heads: int, obi, edst, pos, nid, num_edges: int, ref,
+                 init=None):
+    """``[K, E]`` in edge order from per-slot values formed chunk by chunk
+    over the ALIGNED dst-keyed plan: the write side of :func:`_slot_reader`.
+
+    ``form(ob, ed, ni)`` gives one scan step's ``[K, cb, EB]`` float32
+    slot values, EXACT ZEROS on masked slots (``edst == VB``).  A chunk is
+    one piece of one aligned block of EB positions; a window boundary
+    inside a block makes several chunks of it, and every position is live
+    in exactly one.  So a step sums its chunks block by block (a one-hot
+    dot: one addend is the value, the others are zeros, the sum is exact)
+    and adds the result into one aligned lane range of the output.  The
+    live chunks of a step cover a contiguous run of blocks (consecutive
+    pieces step the block by 0 or 1), counted from the first LIVE chunk's
+    block: the all-masked chunks of empty windows and the pad chunks carry
+    block 0, add zeros wherever they land, and must not set the base.
+    ``init`` ([K, E]) is what the values are added onto (default zeros).
+    Nothing edge-sized exists besides the ``[K, E]`` result itself."""
     from roc_tpu.ops.aggregate import _vary_like
-    E, (K, F) = edge_src.shape[0], table.shape[1:]
-    chunk = max(_GAT_CHUNK_TARGET_ELEMS // max(K * F, 1), _GAT_CHUNK_MIN)
-    chunk = -(-min(chunk, E) // 128) * 128        # whole lane tiles
-    nchunks = -(-E // chunk)
-    pad = nchunks * chunk - E
-    src = jnp.pad(edge_src, (0, pad)).reshape(nchunks, chunk)
-    dst = jnp.pad(edge_dst, (0, pad)).reshape(nchunks, chunk)
-    duf = du.reshape(du.shape[0], K * F)
-    tf = table.reshape(table.shape[0], K * F)
-    collapse = _head_expand(K, F, jnp.float32)
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    cb = min(_PLAN_CB_BLOCKS, max(8, obi.shape[0]))
+    obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
+    nb = max(-(-num_edges // EB), 1)
+    # plan-sized, once a pass: a step's first live block, its chunks' offsets
+    blk = (pos[:, 0] // EB).reshape(nsteps, cb)
+    live = (jnp.min(edst, axis=1) < VB).reshape(nsteps, cb)
+    base = jnp.minimum(jnp.min(jnp.where(live, blk, nb), axis=1), nb - 1)
+    off = blk - base[:, None]
 
     def body(out, sl):
-        i, s_ids, d_ids = sl
-        prod = (jnp.take(duf, d_ids, axis=0, mode="clip")
-                * jnp.take(tf, s_ids, axis=0, mode="clip"))   # [chunk, K*F]
-        c = jax.lax.dot_general(                              # [K, chunk]
-            collapse, prod, (((1,), (1,)), ((), ())), precision="highest",
-            preferred_element_type=jnp.float32).astype(out.dtype)
-        return jax.lax.dynamic_update_slice(out, c, (0, i * chunk)), None
+        ob, ed, ni, b0, of = sl
+        vals = form(ob, ed, ni)                           # [K, cb, EB]
+        same_b = (jax.lax.broadcasted_iota(jnp.int32, (heads, cb, cb), 1)
+                  == of[None, None, :]).astype(vals.dtype)    # [K, block, chunk]
+        outs = jax.lax.dot_general(                       # [K, block, EB]
+            same_b, vals, (((2,), (1,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32
+        ).reshape(heads, cb * EB).astype(out.dtype)
+        cur = jax.lax.dynamic_slice(out, (0, b0 * EB), (heads, cb * EB))
+        return jax.lax.dynamic_update_slice(out, cur + outs,
+                                            (0, b0 * EB)), None
 
-    out = _vary_like(jnp.zeros((K, nchunks * chunk), du.dtype), du)
-    out, _ = jax.lax.scan(body, out, (jnp.arange(nchunks), src, dst))
-    return out[:, :E]
+    # nb - 1 + cb blocks: the update never clamps
+    width = (nb - 1 + cb) * EB
+    if init is None:
+        out = _vary_like(jnp.zeros((heads, width), ref.dtype), ref)
+    else:
+        out = jnp.pad(init, ((0, 0), (0, width - num_edges)))
+    out, _ = jax.lax.scan(
+        body, out, (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+                    nid.reshape(nsteps, cb, EB), base, off))
+    return out[:, :num_edges]
+
+
+def _window_rows(x):
+    """``[rows, width]`` node rows as ``[windows, VB * width]``: a window's
+    VB rows side by side, the last window zero-filled.  A step reads its
+    chunks' windows as whole rows of this (``jnp.take`` by ``obi``: as many
+    rows as the plan has chunks, E / EB + N / VB a pass)."""
+    from roc_tpu.ops.pallas.segment_sum import VB
+    rows, width = x.shape
+    W = max(-(-rows // VB), 1)
+    return jnp.pad(x, ((0, W * VB - rows), (0, 0))).reshape(W, VB * width)
+
+
+def _plan_broadcast(node_t, obi, edst, pos, num_edges: int, init=None):
+    """``node_t[:, edge_dst]`` for a ``[K, rows]`` node table, as ``[K, E]``,
+    WITHOUT a gather by edge: ``edge_dst`` is sorted, so the read is a
+    segment broadcast, and the aligned dst-keyed plan already holds it:
+    slot j of chunk c wants row ``VB * obi[c] + edst[c, j]``, a ``[K, VB] x
+    [VB, EB]`` one-hot product over the chunk's window (the transpose of
+    _plan_sum's ``body_k``).  Bit for bit the gather's result (a -0.0
+    reads +0.0); ``node_t`` must be finite.  With ``init`` ([K, E]) the
+    result is ``init + node_t[:, edge_dst]``, one addition a slot, and the
+    scan follows ``init`` in the program's order.  A lane gather by the
+    same index costs 15.7 ns an index at K = 8 on a v5e (PERF.md PR 25),
+    this 0.9 (PERF.md PR 28)."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    K = node_t.shape[0]
+    # node-sized, once a pass: window w's [VB, K] values as one row
+    node_w = _window_rows(node_t.T)
+
+    def form(ob, ed, _):
+        cb = ob.shape[0]
+        mine = jnp.take(node_w, ob, axis=0, mode="clip").reshape(cb, VB, K)
+        s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
+              == ed[:, None, :]).astype(mine.dtype)
+        return jax.lax.dot_general(                       # [cb, K, EB]
+            mine, s1, (((1,), (1,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32).transpose(1, 0, 2)
+
+    return _plan_blocks(form, K, obi, edst, pos, pos, num_edges, node_t, init)
+
+
+def _edge_contract(du, table, obi, edst, pos, nid, num_edges: int):
+    """c[k, e] = Σ_f du[dst_e, k, f]·table[src_e, k, f] as ``[K, E]``, walked
+    over the aligned dst-keyed plan so the [E, K, F] product never
+    materializes and ``du[dst_e]`` is no gather by edge: a chunk's ``du``
+    rows are its window's VB rows spread over the slots by a one-hot
+    product (exact, as in :func:`_plan_broadcast`); only ``table[src_e]``
+    is gathered, by the plan's ``nid`` (1 + 32 N / E slots an edge).
+    ``du`` holds the dst windows' rows ([rows, K, F], rows from the plan's
+    row 0)."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    rows, K, F = du.shape
+    H = K * F
+    du_w = _window_rows(du.reshape(rows, H))
+    tf = table.reshape(table.shape[0], H)
+    collapse = _head_expand(K, F, jnp.float32)
+
+    def form(ob, ed, ni):
+        cb = ob.shape[0]
+        mine = jnp.take(du_w, ob, axis=0, mode="clip").reshape(cb, VB, H)
+        s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, EB, VB), 2)
+              == ed[:, :, None]).astype(mine.dtype)
+        du_e = jax.lax.dot_general(                       # [cb, EB, K*F]
+            s1, mine, (((2,), (1,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32)
+        prod = du_e.reshape(cb * EB, H) * jnp.take(
+            tf, ni.reshape(cb * EB), axis=0, mode="clip")
+        return jax.lax.dot_general(                       # [K, cb * EB]
+            collapse, prod, (((1,), (1,)), ((), ())), precision="highest",
+            preferred_element_type=jnp.float32).reshape(K, cb, EB)
+
+    return _plan_blocks(form, K, obi, edst, pos, nid, num_edges, du)
 
 
 def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
@@ -662,7 +772,7 @@ def _gat_plan(h, table, a_src, a_dst, plans, edge_ids, key, slope,
 
 def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
                   precision="highest", rate=0.0):
-    edge_src, edge_dst = edge_ids
+    edge_src, _ = edge_ids
     N = plans.num_rows
     K, E = h.shape[1], edge_src.shape[0]
     # the score products are tiny and always float32-exact: at the MXU's
@@ -671,11 +781,13 @@ def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
                       precision="highest")                # [K, T]
     ad_l = jnp.einsum("nkf,kf->kn", h, a_dst,
                       precision="highest")                # [K, N]
-    q = _take_lanes(ad_l, edge_dst) + _take_lanes(as_t, edge_src)   # [K, E]
+    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
+    # every read of a node table by edge_dst rides the dst plan
+    q = _plan_broadcast(ad_l, *dplan, E, _take_lanes(as_t, edge_src))  # [K, E]
     s = jax.nn.leaky_relu(q, negative_slope=slope)
-    m = _plan_max(s, plans.dst_obi, plans.dst_edst, plans.dst_pos, N)
+    m = _plan_max(s, *dplan, N)
     m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-    e = jnp.exp(s - _take_lanes(m, edge_dst))             # [K, E]
+    e = jnp.exp(s - _plan_broadcast(m, *dplan, E))        # [K, E]
     z = _plan_sum(e, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
                   plans.dst_nid, N, "highest", True)      # [K, N]
     # attention dropout: the weighted sum sees the dropped coefficients,
@@ -705,17 +817,18 @@ def _int_zeros(tree):
 
 def _gat_plan_bwd(slope, precision, rate, res, gout):
     h, table, a_src, a_dst, plans, edge_ids, key, qpos, e, zc, out = res
-    edge_src, edge_dst = edge_ids
+    edge_src, _ = edge_ids
     N, T = plans.num_rows, plans.table_rows
     K, E = h.shape[1], edge_src.shape[0]
     du = gout / zc.T[:, :, None]                          # [N, K, F]
     dz = -jnp.einsum("nkf,nkf->kn", gout, out,
                      precision="highest") / zc            # [K, N]
     w = _keep_scale((key, rate), K, E, e.dtype)           # the fwd's mask
-    de = _edge_contract(du, table, edge_src, edge_dst)    # [K, E]
+    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
+    de = _edge_contract(du, table, *dplan, plans.dst_nid, E)   # [K, E]
     if w is not None:
         de = de * w
-    de = de + _take_lanes(dz, edge_dst)
+    de = _plan_broadcast(dz, *dplan, E, de)
     dq = e * de * jnp.where(qpos, 1.0, slope)             # [K, E]
     dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
                      plans.dst_nid, N, "highest", True)   # [K, N]

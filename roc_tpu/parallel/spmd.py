@@ -432,12 +432,22 @@ def _egat(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
 
 
 def _egat_fwd(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
-    from roc_tpu.ops.edge import (_keep_scale, _plan_max, _plan_sum,
-                                  _take_lanes)
-    es, ed = edge_ids
+    from roc_tpu.ops.edge import (_keep_scale, _plan_broadcast, _plan_max,
+                                  _plan_sum, _take_lanes)
+    es, _ = edge_ids
     S, K, F = h.shape
     pl = egp.plans
     span_d = pl.num_rows
+    dplan = (pl.dst_obi, pl.dst_edst, pl.dst_pos)
+
+    def at_dst(node_t, onto=None):
+        """``node_t[:, ed]`` (added to ``onto``) for a [K, NS] table: the
+        block's destinations are global ids inside its window [dst_base,
+        dst_base + span_d), so the read is the plan's segment broadcast of
+        that slice."""
+        return _plan_broadcast(
+            jax.lax.dynamic_slice(node_t, (0, egp.dst_base), (K, span_d)),
+            *dplan, es.shape[0], onto)
     table = jax.lax.all_gather(h.reshape(S, K * F), PARTS_AXIS, tiled=True)
     NS = table.shape[0]
     table = table.reshape(NS, K, F)
@@ -447,18 +457,17 @@ def _egat_fwd(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
                               PARTS_AXIS, axis=1, tiled=True)
     ad_t = jax.lax.all_gather(jnp.einsum("skf,kf->ks", h, a_dst),
                               PARTS_AXIS, axis=1, tiled=True)
-    q = _take_lanes(ad_t, ed) + _take_lanes(as_t, es)            # [K, Eb]
+    q = at_dst(ad_t, _take_lanes(as_t, es))                      # [K, Eb]
     s = jax.nn.leaky_relu(q, negative_slope=slope)
     NEG = jnp.float32(-1e30)     # finite sentinel: see _ring_attend note
-    m_loc = jnp.maximum(
-        _plan_max(s, pl.dst_obi, pl.dst_edst, pl.dst_pos, span_d), NEG)
+    m_loc = jnp.maximum(_plan_max(s, *dplan, span_d), NEG)
     m_all = jax.lax.dynamic_update_slice(
         jax.lax.pcast(jnp.full((K, NS), NEG, s.dtype), PARTS_AXIS,
                       to="varying"),
         m_loc, (0, egp.dst_base))
     # stop_gradient BEFORE pmax: shift invariance; pmax has no diff rule
     m = jax.lax.pmax(jax.lax.stop_gradient(m_all), PARTS_AXIS)   # [K, NS]
-    e = jnp.exp(s - _take_lanes(m, ed))                          # [K, Eb]
+    e = jnp.exp(s - at_dst(m))                                   # [K, Eb]
     z_loc = _plan_sum(e, None, pl.dst_obi, pl.dst_edst, pl.dst_pos,
                       pl.dst_nid, span_d, "highest", True)      # [K, spanD]
     w = _keep_scale((key, rate), K, es.shape[0], e.dtype)
@@ -479,9 +488,9 @@ def _egat_fwd(h, a_src, a_dst, egp, edge_ids, key, slope, precision, rate):
 
 def _egat_bwd(slope, precision, rate, res, gout):
     from roc_tpu.ops.edge import (_edge_contract, _int_zeros, _keep_scale,
-                                  _plan_sum, _take_lanes)
+                                  _plan_broadcast, _plan_sum)
     h, table, a_src, a_dst, egp, edge_ids, key, qpos, e, zc, out = res
-    es, ed = edge_ids
+    es, _ = edge_ids
     S, K, F = h.shape
     NS = table.shape[0]
     pl = egp.plans
@@ -494,10 +503,17 @@ def _egat_bwd(slope, precision, rate, res, gout):
                               tiled=True).reshape(NS, K, F)
     dz_t = jax.lax.all_gather(dz.T, PARTS_AXIS, axis=1, tiled=True)  # [K, NS]
     w = _keep_scale((key, rate), K, es.shape[0], e.dtype)   # the fwd's mask
-    de = _edge_contract(du_t, table, es, ed)                     # [K, Eb]
+    # the block's destinations lie in [dst_base, dst_base + span_d): both
+    # reads by destination are the dst plan's broadcast of that slice
+    dplan = (pl.dst_obi, pl.dst_edst, pl.dst_pos)
+    de = _edge_contract(
+        jax.lax.dynamic_slice(du_t, (egp.dst_base, 0, 0), (span_d, K, F)),
+        table, *dplan, pl.dst_nid, es.shape[0])                  # [K, Eb]
     if w is not None:
         de = de * w
-    de = de + _take_lanes(dz_t, ed)
+    de = _plan_broadcast(
+        jax.lax.dynamic_slice(dz_t, (0, egp.dst_base), (K, span_d)),
+        *dplan, es.shape[0], de)
     dq = e * de * jnp.where(qpos, 1.0, slope)
     dadl = _scatter_to_owner(
         _plan_sum(dq, None, pl.dst_obi, pl.dst_edst, pl.dst_pos,

@@ -417,14 +417,18 @@ class BaseTrainer:
         scans), the GatPlans' padding (plan slots / edges) and the bytes
         of per-edge residuals a train step keeps between forward and
         backward on the plan path (e float32 + the score's sign, [K, E]
-        each, per gat op; 0 where autodiff keeps what it likes)."""
+        each, per gat op; 0 where autodiff keeps what it likes), and how
+        the path reads node tables by ``edge_dst`` ("plan": the aligned
+        dst plan's segment broadcast, ops.edge._plan_broadcast, on every
+        plan path; "gather": by index, the xla scans)."""
         if not model_has_gat(self.model):
             return None
         gd = getattr(self, "gdata", None)   # the streamed trainer has none
         plans = getattr(gd, "gat_plans", None)
         plans = getattr(plans, "plans", plans)      # EdgeGatPlans wraps one
         backend = "plan" if plans is not None else "xla"
-        info = {"backend": backend, "plan_pad_ratio": 0.0, "score_bytes": 0}
+        info = {"backend": backend, "plan_pad_ratio": 0.0, "score_bytes": 0,
+                "dst_reads": "plan" if plans is not None else "gather"}
         if plans is not None:
             edges = int(gd.edge_src.shape[-1])      # per shard when sharded
             info["plan_pad_ratio"] = gat_plan_stats(plans, edges)["pad_ratio"]
@@ -445,17 +449,21 @@ class BaseTrainer:
         print(f"# attention: backend={info['backend']} "
               f"gat_fused=False (no -megafuse) "
               f"gat_plan_pad_ratio={info['plan_pad_ratio']:.4f} "
-              f"gat_score_bytes={info['score_bytes']}", file=sys.stderr,
+              f"gat_score_bytes={info['score_bytes']} "
+              f"gat_dst_reads={info['dst_reads']}", file=sys.stderr,
               flush=True)
         if self._metrics is not None:
             self._metrics.emit(
                 "attention", backend=info["backend"],
                 gat_plan_pad_ratio=info["plan_pad_ratio"],
-                gat_score_bytes=info["score_bytes"])
+                gat_score_bytes=info["score_bytes"],
+                gat_dst_reads=info["dst_reads"])
             for name in ("gat_plan_pad_ratio", "gat_score_bytes"):
                 self._metrics.set_gauge(name, info[name[4:]])
             self._metrics.set_gauge("gat_backend", 1.0,
                                     backend=info["backend"])
+            self._metrics.set_gauge("gat_dst_reads", 1.0,
+                                    dst_reads=info["dst_reads"])
 
     def _obs_epoch(self, epoch: int, wall_s: float, loss, print_fn):
         """Per-epoch drain: fetch the in-graph metrics pytree (ONE

@@ -65,26 +65,51 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     info = tr.attention_info()
     e = ds.graph.num_edges
     assert info["backend"] == "plan"
-    assert set(info) == {"backend", "plan_pad_ratio", "score_bytes"}
+    assert set(info) == {"backend", "plan_pad_ratio", "score_bytes",
+                         "dst_reads"}
+    # the plan path reads node tables by edge_dst through the dst plan
+    assert info["dst_reads"] == "plan"
     # e float32 + the score's sign, [K, E] each: 2 heads, then 1
     assert info["score_bytes"] == (2 + 1) * e * 5
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
     assert (line.startswith("# attention: backend=plan ")
             and f"gat_score_bytes={info['score_bytes']}" in line)
+    # the old text first, unchanged; the new field after it
+    assert line == ("# attention: backend=plan gat_fused=False (no -megafuse)"
+                    f" gat_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
+                    f" gat_score_bytes={info['score_bytes']}"
+                    " gat_dst_reads=plan")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
     assert att["backend"] == "plan" and "fused" not in att
     assert att["gat_plan_pad_ratio"] == pytest.approx(info["plan_pad_ratio"])
     assert att["gat_score_bytes"] == info["score_bytes"]
+    assert att["gat_dst_reads"] == "plan"
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     assert "roc_gat_plan_pad_ratio " in prom and "roc_gat_score_bytes " in prom
     assert 'roc_gat_backend{backend="plan"} 1' in prom
+    assert 'roc_gat_dst_reads{dst_reads="plan"} 1' in prom
     text = obs_report.report(str(tmp_path / "obs" / "trace.json"),
                              str(tmp_path / "obs" / "metrics.jsonl"))
     assert "# attention: backend=plan gat_plan_pad_ratio=" in text
+    assert f"gat_score_bytes={info['score_bytes']} gat_dst_reads=plan" in text
     assert "gat_plan_build" in text
+
+
+def test_the_xla_scans_still_gather_by_edge_dst(capsys):
+    """`-aggr-backend xla` (the dense / chunked scans) indexes its node
+    tables by edge_dst: the trainer says so."""
+    ds = _dataset()
+    cfg = _config(ds, aggregate_backend="xla")
+    tr = Trainer(cfg, ds, build_gat(cfg.layers, 0.6, heads=2))
+    info = tr.attention_info()
+    assert (info["backend"], info["dst_reads"]) == ("xla", "gather")
+    line = next(ln for ln in capsys.readouterr().err.splitlines()
+                if ln.startswith("# attention:"))
+    assert line.startswith("# attention: backend=xla ")
+    assert line.endswith(" gat_dst_reads=gather")
 
 
 def test_models_without_attention_say_nothing(capsys):

@@ -162,3 +162,203 @@ def test_plan_attention_against_a_dense_softmax(what, heads):
     else:
         scale = np.abs(ref).max() * np.finfo(np.float32).eps
         assert np.abs(got - ref).max() <= 32 * scale
+
+
+# -- reads by edge_dst through the plan: the segment broadcast ---------------
+
+def _stepped_edges():
+    """A sorted dst list whose aligned plan, walked 8 chunks a step, opens
+    its SECOND step with an empty window's all-masked chunk (block 0)
+    while that step's live chunks start at block 2 and run past block 7:
+    six windows of 100 in-edges (8 pieces over blocks 0-2), an empty
+    window, a hub of 3,000 in one row, another empty window, a few rows of
+    a ragged tail, and empty windows to the end."""
+    rng = np.random.default_rng(11)
+    dst = np.concatenate([np.repeat(np.arange(6) * VB + 3, 100),
+                          np.full(3000, 7 * VB + 5),
+                          np.sort(rng.integers(9 * VB, 12 * VB, 333))])
+    rows = 16 * VB + 3
+    return rng.integers(0, rows, dst.size).astype(np.int64), \
+        dst.astype(np.int64), rows
+
+
+def _edge_list(kind):
+    return _stepped_edges() if kind == "stepped" else _edges(kind, seed=2)
+
+
+def test_the_stepped_list_opens_a_step_with_an_empty_windows_chunk():
+    _, dst, rows = _stepped_edges()
+    obi, edst, pos, _ = em._aligned_position_plan(dst, dst, rows)
+    live = (edst != VB).any(axis=1)
+    step = slice(8, 16)
+    assert obi.shape[0] > 16 and not live[8] and pos[8, 0] == 0
+    blocks = pos[step, 0][live[step]] // EB
+    assert blocks.min() == 2 and blocks.max() > 7
+
+
+@pytest.mark.parametrize("heads", [1, 8])
+@pytest.mark.parametrize("kind", ["regular", "hub", "none", "stepped"])
+def test_plan_broadcast_is_the_gather_by_edge_dst_bit_for_bit(
+        kind, heads, monkeypatch):
+    """``table[:, edge_dst]`` without a gather: chunks of one block summed
+    in place, empty windows' chunks dropped, several steps (8 chunks a
+    step), and one step over the whole plan."""
+    src, dst, rows = _edge_list(kind)
+    obi, edst, pos, _ = (jnp.asarray(a) for a in
+                         em._aligned_position_plan(dst, src, rows))
+    table = np.random.default_rng(heads).standard_normal(
+        (heads, rows)).astype(np.float32)
+    for cb in (8, 256):
+        monkeypatch.setattr(em, "_PLAN_CB_BLOCKS", cb)
+        got = np.asarray(em._plan_broadcast(jnp.asarray(table), obi, edst,
+                                            pos, dst.size))
+        assert got.shape == (heads, dst.size)
+        np.testing.assert_array_equal(got, table[:, dst])
+    # onto a [K, E] array already there: one addition a slot, as x + gather
+    onto = np.random.default_rng(9).standard_normal(
+        (heads, dst.size)).astype(np.float32)
+    got = np.asarray(em._plan_broadcast(jnp.asarray(table), obi, edst, pos,
+                                        dst.size, jnp.asarray(onto)))
+    np.testing.assert_array_equal(got, onto + table[:, dst])
+
+
+@pytest.mark.parametrize("heads", [1, 8])
+@pytest.mark.parametrize("kind", ["regular", "hub", "none", "stepped"])
+def test_edge_contract_over_the_plan_against_numpy(kind, heads, monkeypatch):
+    """c[k, e] = sum_f du[dst_e, k, f] * table[src_e, k, f]: du rows spread
+    by the plan, table rows by the plan's nid."""
+    src, dst, rows = _edge_list(kind)
+    F = 5
+    rng = np.random.default_rng(heads + 1)
+    du = rng.standard_normal((rows, heads, F)).astype(np.float32)
+    table = rng.standard_normal((rows + 9, heads, F)).astype(np.float32)
+    plan = tuple(jnp.asarray(a) for a in
+                 em._aligned_position_plan(dst, src, rows))
+    want = np.einsum("ekf,ekf->ke", du[dst].astype(np.float64),
+                     table[src].astype(np.float64))
+    for cb in (8, 256):
+        monkeypatch.setattr(em, "_PLAN_CB_BLOCKS", cb)
+        got = np.asarray(em._edge_contract(jnp.asarray(du),
+                                           jnp.asarray(table), *plan,
+                                           dst.size))
+        assert got.shape == (heads, dst.size)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("heads", [1, 8])
+def test_pad_chunks_of_stacked_plans_change_nothing(heads, monkeypatch):
+    """pad_gat_plans pads the shorter shard's plan with all-masked chunks
+    (block 0, the last window): the broadcast and the contraction read the
+    same values from a padded plan, mid-step and over whole steps of pad."""
+    monkeypatch.setattr(em, "_PLAN_CB_BLOCKS", 8)
+    lists = [_edges("hub", seed=4), _edges("regular", seed=4)]
+    rows = lists[0][2]
+    plans = [em.build_gat_plans(s, d, rows, rows) for s, d, _ in lists]
+    counts = [int(p.dst_obi.shape[0]) for p in plans]
+    stacked = em.pad_gat_plans(plans, min_d=max(counts) + 21)
+    assert stacked.dst_obi.shape == (2, max(counts) + 21)
+    F = 3
+    rng = np.random.default_rng(heads)
+    table = jnp.asarray(rng.standard_normal((heads, rows)), jnp.float32)
+    du = jnp.asarray(rng.standard_normal((rows, heads, F)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((rows, heads, F)), jnp.float32)
+    for i, (plan, (_, dst, _)) in enumerate(zip(plans, lists)):
+        bare = (plan.dst_obi, plan.dst_edst, plan.dst_pos)
+        padded = (stacked.dst_obi[i], stacked.dst_edst[i], stacked.dst_pos[i])
+        got = np.asarray(em._plan_broadcast(table, *padded, dst.size))
+        np.testing.assert_array_equal(got, np.asarray(table)[:, dst])
+        np.testing.assert_array_equal(
+            np.asarray(em._edge_contract(du, x, *padded,
+                                         stacked.dst_nid[i], dst.size)),
+            np.asarray(em._edge_contract(du, x, *bare, plan.dst_nid,
+                                         dst.size)))
+
+
+def _sub_jaxprs(param):
+    from jax.extend import core as jcore
+    if isinstance(param, jcore.ClosedJaxpr):
+        yield param.jaxpr
+    elif isinstance(param, jcore.Jaxpr):
+        yield param
+    elif isinstance(param, (tuple, list)):
+        for p in param:
+            yield from _sub_jaxprs(p)
+
+
+def _walk(jaxpr, tainted, gathers, shapes):
+    """Follow everything computed from the ``tainted`` variables through
+    ``jaxpr`` and its sub-jaxprs (an equation with a tainted input taints
+    its outputs): collect the gathers whose INDICES are tainted, and every
+    result shape.  Returns the tainted outvars' flags."""
+    from jax.extend.core import Literal
+    tainted = set(tainted)
+    for eqn in jaxpr.eqns:
+        ins = [not isinstance(v, Literal) and v in tainted
+               for v in eqn.invars]
+        if eqn.primitive.name == "gather" and ins[1]:
+            gathers.append(str(eqn))
+        hit = any(ins)
+        for p in eqn.params.values():
+            for sub in _sub_jaxprs(p):
+                # operands map onto the sub-jaxpr's inputs from the end
+                # (scan, pjit, custom calls: one for one; while and cond
+                # put their own constants and predicate in front)
+                flags = ([False] * len(sub.invars) + ins)[-len(sub.invars):] \
+                    if len(sub.invars) <= len(ins) else [hit] * len(sub.invars)
+                inner = _walk(sub, [v for v, f in zip(sub.invars, flags)
+                                    if f], gathers, shapes)
+                hit = hit or any(inner)
+        for v in eqn.outvars:
+            if getattr(v.aval, "shape", None) is not None:
+                shapes.append((eqn.primitive.name, tuple(v.aval.shape)))
+            if hit:
+                tainted.add(v)
+    return [not isinstance(v, Literal) and v in tainted
+            for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.6])
+def test_no_gather_of_the_plan_path_is_indexed_by_edge_dst(dropout,
+                                                           monkeypatch):
+    """jax.grad of gat_attend_plan, forward and hand-derived backward: no
+    gather takes edge_dst, or anything computed from it, as its indices
+    (edge_src still does: the walk must find those, or it sees nothing);
+    and what the plan reads add has no edge-sized array with the heads, or
+    a feature row, on the lane axis: rows of K*F exist a scan step at a
+    time only, as in _plan_sum."""
+    import jax
+    for name, cb in (("_PLAN_CB_BLOCKS", 8), ("_PLAN_CB_SUM", 16),
+                     ("_PLAN_CB_MAX", 16)):
+        monkeypatch.setattr(em, name, cb)
+    monkeypatch.setattr(em, "_LANE_GATHER_CHUNK", 4096)
+    src, dst, rows = _edges("hub", seed=6)
+    K, F, E = 4, 16, dst.size            # no other axis of the path is 4 long
+    step_slots = 16 * EB
+    assert E > 2 * step_slots > 2 * rows
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((rows, K, F)), jnp.float32)
+    a_src = jnp.asarray(rng.standard_normal((K, F)), jnp.float32)
+    a_dst = jnp.asarray(rng.standard_normal((K, F)), jnp.float32)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    drop = (jax.random.PRNGKey(5), dropout) if dropout else None
+
+    def loss(hh, s, d, e_src, e_dst):
+        return jnp.sum(em.gat_attend_plan(hh, hh, s, d, plans,
+                                          (e_src, e_dst), 0.2, "default",
+                                          drop) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        h, a_src, a_dst, jnp.asarray(src, jnp.int32),
+        jnp.asarray(dst, jnp.int32)).jaxpr
+    by_dst, by_src, shapes = [], [], []
+    _walk(jaxpr, [jaxpr.invars[4]], by_dst, shapes)
+    _walk(jaxpr, [jaxpr.invars[3]], by_src, [])
+    assert by_src, "the walk no longer finds the gathers by edge_src"
+    assert not by_dst, by_dst[:3]
+    assert sum(1 for _, s in shapes if s == (K, E)) >= 8
+    heads_last = [(p, s) for p, s in shapes if len(s) >= 2 and s[-1] == K
+                  and int(np.prod(s[:-1])) >= step_slots]
+    assert not heads_last, heads_last[:5]
+    rows_last = [(p, s) for p, s in shapes if len(s) >= 2 and s[-1] == K * F
+                 and int(np.prod(s[:-1])) > step_slots]
+    assert not rows_last, rows_last[:5]
