@@ -190,6 +190,19 @@ def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
     return int(4 * params * 4 + node + edge)
 
 
+PLAN_FIELDS = ("plans", "plans_local", "plans_remote", "ring_plans",
+               "gat_plans")
+
+
+def plan_bytes(gdata) -> int:
+    """Bytes of every aggregation and attention plan set a graph-data
+    pytree carries (all parts of a sharded one)."""
+    import jax
+    return sum(int(a.size) * a.dtype.itemsize
+               for name in PLAN_FIELDS
+               for a in jax.tree.leaves(getattr(gdata, name, None)))
+
+
 def estimate_for_trainer(trainer) -> ModelEstimate:
     """Estimates at the trainer's actual per-device shard shape."""
     import numpy as np
@@ -206,14 +219,15 @@ def estimate_for_trainer(trainer) -> ModelEstimate:
     itemsize = int(np.dtype(trainer.dtype).itemsize)
     fixed = fixed_bytes_for(trainer.model, rows, ds.features.shape[1],
                             ds.num_classes, edges, itemsize)
-    # the attention plans (six [C, EB] int32 arrays of ~1.2 E slots) are
-    # step arguments like the edge arrays: one device's share of them
-    gat_plans = getattr(getattr(trainer, "gdata", None), "gat_plans", None)
-    if gat_plans is not None:
-        import jax
-        devices = max(int(trainer.config.num_parts) // max(k, 1), 1)
-        fixed += sum(int(a.size) * a.dtype.itemsize
-                     for a in jax.tree.leaves(gat_plans)) // devices
+    # every plan set is a step argument like the edge arrays: one device's
+    # share.  At a products-size shard the matmul chunk plans are 0.9 GB
+    # of the 1.28 GB of arguments the compiler counts for the train step
+    # (tests/test_exchange_obs.py holds this sum to that number); the
+    # attention plans are six [C, EB] int32 arrays of ~1.2 E slots.  The
+    # exchange's send and receive blocks are temporaries of the step, in
+    # the layers' bytes and not here.
+    devices = max(int(trainer.config.num_parts) // max(k, 1), 1)
+    fixed += plan_bytes(getattr(trainer, "gdata", None)) // devices
     return estimate_model(trainer.model, rows, edges, itemsize=itemsize,
                           fixed_bytes=fixed)
 

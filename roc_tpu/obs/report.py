@@ -60,6 +60,18 @@ def _alert_detail(a: dict) -> str:
     return ", ".join(parts)
 
 
+def exchange_line(info: dict) -> str:
+    """The sharded trainer's start-up line from its `exchange` record
+    (SpmdTrainer.exchange_info): every field as ``key=value`` in the
+    record's order, the backend's reason last in brackets.  One format for
+    stderr and for this report."""
+    fields = " ".join(
+        f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
+        for k, v in info.items()
+        if v is not None and k not in ("type", "agg_backend_reason"))
+    return f"# exchange: {fields} ({info.get('agg_backend_reason', '?')})"
+
+
 def summarize_metrics(records: List[dict]) -> List[str]:
     epochs = [r for r in records if r.get("type") == "metrics"]
     alerts = [r for r in records if r.get("type") == "watchdog"]
@@ -92,6 +104,8 @@ def summarize_metrics(records: List[dict]) -> List[str]:
                 f"{r.get('gat_plan_pad_ratio', 0):.4f} gat_score_bytes="
                 f"{r.get('gat_score_bytes', 0)} gat_dst_reads="
                 f"{r.get('gat_dst_reads', '?')}")
+        elif r.get("type") == "exchange":
+            lines.append(exchange_line(r))
     for r in trains:
         lines.append(f"#   verdict: {r.get('watchdog_verdict', '?')} "
                      f"({r.get('epochs', '?')} epochs, "

@@ -153,9 +153,12 @@ class AggregatePlans(NamedTuple):
     bwd_esrc: jnp.ndarray   # [C_b, EB]
 
 
-def build_aggregate_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
-                          num_rows: int, table_rows: int) -> AggregatePlans:
-    """Chunk schedules for out = A@x (fwd) and grad_x = A^T@grad (bwd).
+def build_aggregate_plans_host(edge_src: np.ndarray, edge_dst: np.ndarray,
+                               num_rows: int,
+                               table_rows: int) -> AggregatePlans:
+    """Chunk schedules for out = A@x (fwd) and grad_x = A^T@grad (bwd), as
+    NumPy arrays: nothing here touches a device, so the sharded trainer
+    builds its parts' plans side by side and places each on its own chip.
 
     The transposed plan re-sorts the edge list by source — the exact move
     the reference makes by launching its forward kernel with input/output
@@ -175,18 +178,26 @@ def build_aggregate_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
     for plan in (fwd, bwd):
         assert np.all(np.diff(np.asarray(plan.obi)) <= 1), \
             "chunk plan skips output windows (obi jump > 1)"
+    return AggregatePlans(
+        fwd_obi=fwd.obi, fwd_first=fwd.first, fwd_edst=fwd.edst,
+        fwd_esrc=fwd.esrc, bwd_obi=bwd.obi, bwd_first=bwd.first,
+        bwd_edst=bwd.edst, bwd_esrc=bwd.esrc)
+
+
+def build_aggregate_plans(edge_src: np.ndarray, edge_dst: np.ndarray,
+                          num_rows: int, table_rows: int) -> AggregatePlans:
+    """:func:`build_aggregate_plans_host`, placed on the default device."""
+    host = build_aggregate_plans_host(edge_src, edge_dst, num_rows,
+                                      table_rows)
     with _obs_span("plan_to_device"):
-        return AggregatePlans(
-            fwd_obi=jnp.asarray(fwd.obi), fwd_first=jnp.asarray(fwd.first),
-            fwd_edst=jnp.asarray(fwd.edst), fwd_esrc=jnp.asarray(fwd.esrc),
-            bwd_obi=jnp.asarray(bwd.obi), bwd_first=jnp.asarray(bwd.first),
-            bwd_edst=jnp.asarray(bwd.edst), bwd_esrc=jnp.asarray(bwd.esrc))
+        return AggregatePlans(*(jnp.asarray(a) for a in host))
 
 
 def pad_plans(plans: "list[AggregatePlans]", min_fwd: int = 0,
               min_bwd: int = 0) -> AggregatePlans:
-    """Stack per-shard plans to common chunk counts (shard_map needs one
-    static program).  Pad chunks are the canonical no-ops of
+    """Stack per-shard host plans to common chunk counts (shard_map needs
+    one static program), as NumPy arrays: the trainer places each part's
+    block on its own device.  Pad chunks are the canonical no-ops of
     :func:`roc_tpu.ops.pallas.segment_sum.pad_chunks`.
 
     ``min_fwd``/``min_bwd`` raise the target chunk counts — the per-host
@@ -200,8 +211,8 @@ def pad_plans(plans: "list[AggregatePlans]", min_fwd: int = 0,
                  for p in plans]
         C = max(max(q[0].shape[0] for q in quads),
                 min_fwd if prefix == "fwd_" else min_bwd)
-        padded = [pad_chunks(*q, C - q[0].shape[0], jnp) for q in quads]
-        return [jnp.stack([p[i] for p in padded]) for i in range(4)]
+        padded = [pad_chunks(*q, C - q[0].shape[0], np) for q in quads]
+        return [np.stack([p[i] for p in padded]) for i in range(4)]
 
     f, b = stack("fwd_"), stack("bwd_")
     return AggregatePlans(fwd_obi=f[0], fwd_first=f[1], fwd_edst=f[2],
@@ -229,19 +240,27 @@ def pad_plans(plans: "list[AggregatePlans]", min_fwd: int = 0,
 # No scatter instruction anywhere; everything is gather + matmul + DUS.
 
 _MM_CB = 512   # chunks per scan step
+# The S2 combine of the sum scans, whatever ``precision`` the S1 products
+# take: "high" is three bf16 passes over the float32 partial sums (S2's
+# one-hot operand is exact in one), so a window's chunk sums are added with
+# 16 bits of significand kept and not 8.  On the chip, one aggregation of a
+# products-size shard at width 256 (PERF.md PR 29): error against float32
+# 1.104e-3 at "default", 1.914e-4 at "high" and at "highest" alike (what is
+# left is the features' one rounding); a scan takes 520.5 / 509.1 / 523.6 ms,
+# and the gcn-products.p4 epoch 0.36 % more at "high" than at "default".
+_MM_COMBINE = "high"
 
 
-def _one_hot_dots(g, ed, ob, cb, precision, combine_precision=None):
+def _one_hot_dots(g, ed, ob, cb, precision, combine_precision):
     """S1/S2 one-hot matmuls for one scan step (see module comment).
-    ``combine_precision`` (default: ``precision``) feeds the S2 dot, which
-    adds the chunks' float32 partial sums of a window: at the MXU's default
-    precision it rounds each PARTIAL SUM to bf16, a second rounding on top
-    of the features' (the matmul backend's 0.9e-3 to 1.8e-3 on the chip);
-    the attention path passes "highest" there (PERF.md PR 25)."""
+    ``combine_precision`` feeds the S2 dot, which adds the chunks' float32
+    partial sums of a window: at the MXU's default precision it would
+    round each PARTIAL SUM to bf16, a second rounding on top of the
+    features' (:data:`_MM_COMBINE` has the chip's numbers).  The attention
+    path passes "highest" (PERF.md PR 25), the sum scans ``_MM_COMBINE``
+    ("highest" where the S1 products are)."""
     from roc_tpu.ops.pallas.segment_sum import EB, VB
     H = g.shape[-1]
-    if combine_precision is None:
-        combine_precision = precision
     s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
           == ed[:, None, :]).astype(g.dtype)
     psum = jax.lax.dot_general(
@@ -268,11 +287,12 @@ def _matmul_run(x, obi, edst, esrc, num_rows: int, precision):
                                     nsteps * cb - C, jnp)
     num_windows = (num_rows + VB - 1) // VB
     acc_rows = (num_windows - 1 + cb) * VB   # DUS windows never clamp
+    combine = "highest" if precision == "highest" else _MM_COMBINE
 
     def body(acc, sl):
         ob, es, ed = sl
         g = jnp.take(x, es.reshape(cb * EB), axis=0, mode="clip")
-        outs = _one_hot_dots(g, ed, ob, cb, precision)
+        outs = _one_hot_dots(g, ed, ob, cb, precision, combine)
         base = ob[0] * VB
         cur = jax.lax.dynamic_slice(acc, (base, 0), (cb * VB, H))
         return jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0)), None

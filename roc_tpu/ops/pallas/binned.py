@@ -432,9 +432,21 @@ def binned_viable(num_rows: int, table_rows: int, num_edges: int,
     if edge_src is not None:
         g, _ = choose_geometry(edge_src, edge_dst, num_rows, table_rows)
         return g is not None
+    return binned_viable_why(num_rows, table_rows, num_edges)[0]
+
+
+def binned_viable_why(num_rows: int, table_rows: int,
+                      num_edges: int) -> tuple:
+    """(viable, reason): :func:`binned_viable`'s uniform-occupancy test and
+    the statistics it was decided on, as one phrase without spaces (the
+    trainer prints it in its start-up line and labels a gauge with it)."""
     num_bins = max(-(-num_rows // RB), 1)
     num_blocks = max(-(-table_rows // SB), 1)
-    return num_blocks * num_bins * SLOT * 4 <= num_edges * 5
+    ok = num_blocks * num_bins * SLOT * 4 <= num_edges * 5
+    per_cell = num_edges / (num_blocks * num_bins)
+    return ok, (f"occupancy:{per_cell:.1f}_edges_a_cell_"
+                f"{'>=' if ok else '<'}_{SLOT * 4 / 5:.1f}"
+                f"(bins={num_bins},blocks={num_blocks},edges={num_edges})")
 
 
 # Cost-model calibration: re-fit in PR 24 (2026-09-30) from per-kernel device

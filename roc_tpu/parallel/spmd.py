@@ -596,8 +596,21 @@ def _build_shard_plans(backend: str, srcs, dsts, S: int, table_rows: int,
              [p.bwd.p2_obi.shape[1] for p in plan_list]], allgather)
         return ops.pad_binned_plans(plan_list, min_fwd=(f[0], f[1]),
                                     min_bwd=(f[2], f[3]))
-    plan_list = [ops.build_aggregate_plans(srcs[i], dsts[i], S, table_rows)
-                 for i in range(len(srcs))]
+    # Host arrays, the parts side by side (the sorts and the native chunk
+    # builder release the GIL); _place_parts puts each part's block on its
+    # own device.  Staging them through the default device held every
+    # shard's plans on chip 0 during set-up (5.4 GiB at the products size)
+    # and fetched them back.
+    from concurrent.futures import ThreadPoolExecutor
+    from roc_tpu import native
+    from roc_tpu.ops.aggregate import build_aggregate_plans_host
+    native.available()          # load the library once, before the threads
+    with ThreadPoolExecutor(max(1, min(len(srcs),
+                                       os.cpu_count() or 1))) as pool:
+        plan_list = list(pool.map(
+            lambda i: build_aggregate_plans_host(srcs[i], dsts[i], S,
+                                                 table_rows),
+            range(len(srcs))))
     f = _allgather_floors([[p.fwd_obi.shape[0] for p in plan_list],
                            [p.bwd_obi.shape[0] for p in plan_list]],
                           allgather)
@@ -672,11 +685,13 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
             gat_plans = pad_gat_plans(
                 [build_gat_plans(src[i], part.edge_dst[i], S, table_rows)
                  for i in range(P_)])
+    # host arrays: the trainer places each part's block on its own device
+    # (_place_parts), and none is staged on the default one
     return ShardedGraphData(
-        edge_src=jnp.asarray(src, jnp.int32),
-        edge_dst=jnp.asarray(part.edge_dst, jnp.int32),
-        in_degree=jnp.asarray(part.in_degree, jnp.float32),
-        send_idx=None if halo is None else jnp.asarray(halo.send_idx),
+        edge_src=np.asarray(src, np.int32),
+        edge_dst=np.asarray(part.edge_dst, np.int32),
+        in_degree=np.asarray(part.in_degree, np.float32),
+        send_idx=None if halo is None else np.asarray(halo.send_idx),
         plans=plans,
         gat_plans=gat_plans,
         plans_local=plans_local,
@@ -1303,6 +1318,7 @@ class SpmdTrainer(BaseTrainer):
                               "the occupancy bound; using matmul",
                               file=sys.stderr)
                     backend = "matmul"
+                    self._backend_why = "edge_blocks_fail_binned_occupancy"
             if backend == "matmul":
                 # Windowed one-hot plans per block (TPU would otherwise
                 # serialize each block's scatter); backward rides the
@@ -1352,16 +1368,14 @@ class SpmdTrainer(BaseTrainer):
             # The global viability check (BaseTrainer's resolve) sees the
             # whole-graph geometry; the per-shard plan only spans the halo
             # table (S own rows + P*K received), which for locality-heavy
-            # partitions is far smaller than P*S — re-evaluate there before
-            # settling for matmul.  Gated on the same hardware flag.
-            from roc_tpu.ops.pallas.binned import binned_viable
-            from roc_tpu.train.driver import AUTO_BINNED
+            # partitions is far smaller than P*S — ask the same policy
+            # again there before settling for matmul, and keep its reason.
+            from roc_tpu.train.driver import resolve_backend_why
             S_ = self.part.shard_nodes
             table_rows = S_ + self.part.num_parts * self.halo.K \
                 if self.halo is not None else self.part.num_parts * S_
-            if AUTO_BINNED and binned_viable(
-                    S_, table_rows, int(self.part.num_edges_valid.max())):
-                backend = "binned"
+            backend, self._backend_why = resolve_backend_why(
+                "auto", int(self.part.num_edges_valid.max()), S_, table_rows)
         with obs.span("plan_build", backend=backend,
                       parts=self.part.num_parts):
             return shard_graph(self.part, self.halo, backend,
@@ -1405,6 +1419,7 @@ class SpmdTrainer(BaseTrainer):
                           "windowed plans (binned block windows need the "
                           "whole graph's occupancy stats)", file=sys.stderr)
                 backend = "matmul"
+                self._backend_why = "edge_shard_perhost_rides_matmul"
             plans = None
             if backend == "matmul":
                 # bwd (src-sorted) blocks come from the transposed sidecar
@@ -1540,21 +1555,91 @@ class SpmdTrainer(BaseTrainer):
 
         return jax.tree.map(place, gd)
 
-    def _log_shard_stats(self):
-        """Aggregation skew report (SURVEY §7 hard part): every shard pays
-        the padded-max edge count, so the tax is E_pad/E_live - 1.  The
-        reference balances edges precisely because kernel work ∝ edges
-        (gnn.cc:806-829); here skew additionally becomes *padding*, the
-        scaling ceiling for skewed graphs."""
-        if jax.process_index() != 0:   # one banner per pod, not per host
+    def _wire_geometry(self, gd: ShardedGraphData) -> tuple:
+        """(the exchange as obs.channel counts it, halo rows a peer sends):
+        edge-sharded blocks all_gather their rows whatever -exchange says;
+        K is 0 without a send map.  One place for the step's in-graph
+        wire_bytes and exchange_info's per-epoch figures."""
+        on_wire = "allgather" if gd.mode == "edge" else self._exchange_mode
+        K = int(gd.send_idx.shape[-1]) if gd.send_idx is not None else 0
+        return on_wire, K
+
+    def exchange_info(self) -> dict:
+        """What this trainer resolved for its partition and its exchange,
+        from static geometry alone (no device work): the mode ("halo" |
+        "allgather" | "ring", or "edge" under -edge-shard); the feature
+        rows and bytes ONE device puts on the wire in a training epoch
+        (one exchange an aggregation forward and its transpose backward,
+        by the wire dtype; obs.channel.exchange_rows has the per-mode
+        count); the halo rows a peer sends (K, padded) and their share of
+        a shard's table (P*K of S + P*K rows); the share of live edges
+        whose source another part owns (the partition's edge cut); the
+        padded-max tax (SURVEY section 7: every shard runs the fullest
+        shard's edge count, so skew becomes padding; the reference
+        balances edges because kernel work follows them, gnn.cc:806-829);
+        the live edges of the emptiest and fullest shard; the aggregation
+        backend with the reason the policy gave where it decided
+        (driver.resolve_backend_why, or the exchange that overrode it)."""
+        m, gd = self.part, self.gdata
+        P_, S = int(m.num_parts), int(m.shard_nodes)
+        mode = "edge" if gd.mode == "edge" else self._exchange_mode
+        on_wire, K = self._wire_geometry(gd)
+        widths = self._aggregate_widths()
+        rows = 2 * len(widths) * obs.channel.exchange_rows(
+            on_wire, P_, S, send_cols=K)
+        nbytes = 2 * obs.channel.wire_bytes_per_step(
+            on_wire, P_, S, widths, send_cols=K, xch_dtype=gd.xch_dtype,
+            xch_comp=gd.xch_comp)
+        live = np.asarray(m.num_edges_valid, np.int64)
+        info = {"mode": mode, "parts": P_, "rows_per_epoch": int(rows),
+                "bytes_per_epoch": int(nbytes), "halo_rows_per_peer": K,
+                "halo_fraction": P_ * K / (S + P_ * K) if K else 0.0,
+                "edge_cut_share": None,
+                "padded_max_tax": float(_padded_max_tax(m)),
+                "shard_edges_live_min": int(live.min()),
+                "shard_edges_live_max": int(live.max())}
+        halo_src = getattr(getattr(self, "halo", None), "edge_src_local",
+                           None)
+        if halo_src is not None and len(halo_src) == P_:
+            # a remote source reads the received block, past the S own rows
+            # (pad edges sit on an own pad row: never counted)
+            cut = sum(int(np.count_nonzero(e >= S)) for e in halo_src)
+        elif mode != "edge" and hasattr(m, "edge_src"):
+            # padded-global ids (allgather, ring): the owner is id // S
+            cut = sum(int(np.count_nonzero(
+                (e < p * S) | (e >= (p + 1) * S)))
+                for p, e in enumerate(m.edge_src))
+        else:           # per-host loading holds local parts only
+            cut = None
+        if cut is not None:
+            info["edge_cut_share"] = cut / max(int(live.sum()), 1)
+        info["agg_backend"] = gd.backend
+        info["agg_backend_reason"] = self._backend_why
+        return info
+
+    def _announce_exchange(self):
+        """The sharded trainer's own start-up line, `# exchange: ...` on
+        stderr in every run (once a pod, not once a host), and the same
+        facts as one `exchange` record + gauges under -obs (what
+        _announce_attention is to a gat model); again after a reshard."""
+        info = self.exchange_info()
+        if jax.process_index() == 0:
+            from roc_tpu.obs.report import exchange_line
+            print(exchange_line(info), file=sys.stderr, flush=True)
+        if self._metrics is None:
             return
-        m = self.part
-        live = np.asarray(m.num_edges_valid, np.float64)
-        pad_tax = _padded_max_tax(m)
-        print(f"# shards: P={m.num_parts} S={m.shard_nodes} "
-              f"E={m.shard_edges} edges/shard min={int(live.min())} "
-              f"mean={int(live.mean())} max={int(live.max())} "
-              f"padded-max tax={pad_tax * 100:.1f}%", file=sys.stderr)
+        self._metrics.emit("exchange", **info)
+        for name in ("rows_per_epoch", "bytes_per_epoch"):
+            self._metrics.set_gauge("exchange_" + name, info[name])
+        for name in ("halo_rows_per_peer", "halo_fraction", "edge_cut_share",
+                     "padded_max_tax", "shard_edges_live_min",
+                     "shard_edges_live_max"):
+            if info[name] is not None:
+                self._metrics.set_gauge(name, info[name])
+        self._metrics.set_gauge("exchange_mode", 1.0, mode=info["mode"])
+        self._metrics.set_gauge("agg_backend", 1.0,
+                                backend=info["agg_backend"],
+                                reason=info["agg_backend_reason"])
 
     # Auto edge-shard threshold: below this padded-max tax, vertex+halo
     # wins on comms; above it, the padding dominates (measured crossover in
@@ -1658,6 +1743,7 @@ class SpmdTrainer(BaseTrainer):
                       f"{cfg.aggregate_backend} rides the matmul ring "
                       f"plans", file=sys.stderr)
             backend = "matmul"
+            self._backend_why = "exchange_ring_rides_matmul"
 
         # Plan-backend attention composes with halo/allgather vertex
         # sharding (gat_attend_plan), single-host or perhost, and — since
@@ -1670,8 +1756,6 @@ class SpmdTrainer(BaseTrainer):
         gd = self._build_graph_perhost(backend, gat_backend) \
             if cfg.perhost_load else self._build_graph_full(backend,
                                                             gat_backend)
-        if cfg.verbose:
-            self._log_shard_stats()
         # Remember the resolved backends + sharding specs: reshard() rebuilds
         # graph data and steps from these without re-running the auto policy.
         self._backend_resolved = backend
@@ -1691,8 +1775,9 @@ class SpmdTrainer(BaseTrainer):
         # the step cache below still hits on a same-structure rebuild.
         with obs.span("mem_plan"):
             self._resolve_mem_plan()
-        with obs.span("step_build"):
+        with obs.span("step_build", parts=P_):
             self._build_steps(gd)
+        self._announce_exchange()
 
     def _place_data(self, gd: ShardedGraphData):
         """Place the node tensors + graph data for the current partition
@@ -1785,12 +1870,11 @@ class SpmdTrainer(BaseTrainer):
         # backward roughly doubles it); edge counts reduce only the local
         # block, one scalar per device.
         if obs_on:
+            on_wire, send_cols = self._wire_geometry(gd)
             wire_bytes = obs.channel.wire_bytes_per_step(
-                "allgather" if gd.mode == "edge" else exchange,
-                self.part.num_parts, S, self._aggregate_widths(),
-                send_cols=(gd.send_idx.shape[-1]
-                           if gd.send_idx is not None else 0),
-                xch_dtype=gd.xch_dtype, xch_comp=gd.xch_comp)
+                on_wire, self.part.num_parts, S, self._aggregate_widths(),
+                send_cols=send_cols, xch_dtype=gd.xch_dtype,
+                xch_comp=gd.xch_comp)
             # Ledger prediction at step-build time (host-side, outside the
             # traced body); _obs_epoch pairs it with the per-epoch value
             # from the metrics channel.  The channel returns this same
@@ -1801,8 +1885,7 @@ class SpmdTrainer(BaseTrainer):
             if led.attached:
                 from roc_tpu.obs.ledger import content_key
                 self._wire_key = content_key(
-                    mode="allgather" if gd.mode == "edge" else exchange,
-                    parts=self.part.num_parts, shard_nodes=S)
+                    mode=on_wire, parts=self.part.num_parts, shard_nodes=S)
                 led.predict("wire_bytes", self._wire_key, wire_bytes,
                             "bytes")
             metric_specs = {"grad_norm": P(), "param_norm": P(),
@@ -1922,6 +2005,5 @@ class SpmdTrainer(BaseTrainer):
                                         self._gat_backend_resolved)
             self._place_data(gd)
             self._build_steps(gd)
-        if self.config.verbose:
-            self._log_shard_stats()
+        self._announce_exchange()
         return sp.dur_s

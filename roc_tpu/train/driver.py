@@ -68,36 +68,52 @@ def device_sync(x):
     return np.asarray(jax.tree.leaves(x)[0])
 
 
-# Above this many edges the "auto" backend switches from segment_sum to the
-# scatter-free matmul plan — on TPU only, where XLA scatter serializes per
-# index (measured ~6.5 s/aggregation at Reddit scale on v5e; see
-# roc_tpu/ops/aggregate.py).  CPU/GPU scatters are fine as-is.
+# Above this many edges the "auto" backend leaves segment_sum for a
+# scatter-free plan backend, on TPU only, where XLA's scatter serialises per
+# index (roc_tpu/ops/aggregate.py).  CPU/GPU scatters are fine as they are.
 AUTO_MATMUL_EDGES = 1 << 20
-# Measured on v5e (2026-07-31, Reddit-shape bench): binned 0.752 s/epoch vs
-# matmul-fast 0.821 s vs xla 2.39 s — binned wins where its padding model
-# holds (binned_viable); elsewhere matmul remains the fast path.  PERF.md.
+# Which plan backend: the binned kernels where their padding model holds
+# (binned_viable: the benchmark's gcn-reddit cells), the one-hot matmul
+# scans elsewhere (a four-chip shard of gcn-products); PERF.md section 5
+# has the chip's numbers for both.  resolve_backend_why() says which test
+# decided, on which statistics.
 AUTO_BINNED = True
 
 
-def resolve_backend(backend: str, num_edges: int, num_rows: int = 0,
-                    table_rows: int = 0) -> str:
-    """Resolve the aggregation backend from the graph's shape.  Which
-    geometry a binned run takes is decided where its plans are built
-    (ops.build_binned_plans, from the actual cell statistics)."""
+def resolve_backend_why(backend: str, num_edges: int, num_rows: int = 0,
+                        table_rows: int = 0) -> tuple:
+    """(backend, reason): the aggregation backend from the graph's shape and
+    why, as one phrase without spaces: the flag that set it, or the test of
+    the ``auto`` policy that decided with the statistics it decided on.
+    The ONE place the policy lives; the sharded trainer asks it again on a
+    shard's rows, table rows and fullest live edge count
+    (spmd._build_graph_full) and carries the reason in its `# exchange:`
+    line and ``agg_backend`` gauge.  Which geometry a binned run takes is
+    decided where its plans are built (ops.build_binned_plans, from the
+    actual cell statistics)."""
     if backend == "auto":
-        if not (on_tpu() and num_edges >= AUTO_MATMUL_EDGES):
-            return "xla"
-        from roc_tpu.ops.pallas.binned import binned_viable
-        if AUTO_BINNED and num_rows \
-                and binned_viable(num_rows, table_rows, num_edges):
-            return "binned"
-        return "matmul"
+        if not on_tpu():
+            return "xla", "auto:no_tpu"
+        if num_edges < AUTO_MATMUL_EDGES:
+            return "xla", f"auto:edges<{AUTO_MATMUL_EDGES}"
+        if not AUTO_BINNED:
+            return "matmul", "auto:AUTO_BINNED_off"
+        if not num_rows:
+            return "matmul", "auto:no_row_count"
+        from roc_tpu.ops.pallas.binned import binned_viable_why
+        ok, why = binned_viable_why(num_rows, table_rows, num_edges)
+        return ("binned" if ok else "matmul"), "auto:" + why
     if backend == "pallas":
         # Round-1's blocked-CSR kernel cannot lower on hardware (per-row DMA
         # slices of tiled HBM refs; docs/PERF.md); "pallas" now names the
         # binned two-phase kernel pair (ops/pallas/binned.py).
-        return "binned"
-    return backend
+        return "binned", "-aggr-backend=pallas"
+    return backend, f"-aggr-backend={backend}"
+
+
+def resolve_backend(backend: str, num_edges: int, num_rows: int = 0,
+                    table_rows: int = 0) -> str:
+    return resolve_backend_why(backend, num_edges, num_rows, table_rows)[0]
 
 
 def resolve_gat_backend(backend: str, num_edges: int) -> str:
@@ -132,17 +148,20 @@ def model_has_gat(model: Model) -> bool:
     return any(op.kind == "gat" for op in model.ops)
 
 
-def effective_backend(config: Config, dataset: Dataset, model: Model,
-                      use_edge_shard: bool = False) -> str:
-    """The run's aggregation backend, model-aware: the plan-based backends
-    (binned/matmul) implement sum and avg (avg = plan-sum / in-degree), so
-    don't pay plan construction when the built model contains neither.
+def effective_backend_why(config: Config, dataset: Dataset, model: Model,
+                          use_edge_shard: bool = False) -> tuple:
+    """(backend, reason): the run's aggregation backend, model-aware: the
+    plan-based backends (binned/matmul) implement sum and avg (avg =
+    plan-sum / in-degree), so don't pay plan construction when the built
+    model contains neither.  The reason is resolve_backend_why's, or that
+    the model has no such aggregate.
     Module-level (not a trainer method) because the frozen/serving loader
     (train/frozen.py) must resolve the SAME backend as the trainer that
     wrote the checkpoint — two copies of this policy would let an
     inference process silently compile a different program than eval."""
     cfg = config
     g = dataset.graph
+    no_plan_aggr = "xla", "model_has_no_sum_or_avg_aggregate"
     if use_edge_shard:
         # Edge-sharded aggregation supports xla, matmul (windowed
         # per-block one-hot plans, spmd.edge_aggregate_matmul) and,
@@ -151,17 +170,18 @@ def effective_backend(config: Config, dataset: Dataset, model: Model,
         # _build_graph_full otherwise).  auto resolves to matmul — the
         # binned viability bound needs the block spans, known only
         # after the edge blocks are built.
-        backend = resolve_backend(cfg.aggregate_backend, g.num_edges)
+        backend, why = resolve_backend_why(cfg.aggregate_backend,
+                                           g.num_edges)
         if backend in ("matmul", "binned") \
                 and not ({"sum", "avg"} & model_aggrs(model)):
             if cfg.aggregate_backend != "auto":
                 print(f"# aggregate_backend={cfg.aggregate_backend} "
                       f"only accelerates sum/avg aggregation under "
                       f"-edge-shard; using xla")
-            return "xla"
-        return backend
-    backend = resolve_backend(cfg.aggregate_backend, g.num_edges,
-                              g.num_nodes, g.num_nodes)
+            return no_plan_aggr
+        return backend, why
+    backend, why = resolve_backend_why(cfg.aggregate_backend, g.num_edges,
+                                       g.num_nodes, g.num_nodes)
     aggrs = model_aggrs(model)
     if backend in ("binned", "matmul") and not ({"sum", "avg"} & aggrs):
         if cfg.aggregate_backend != "auto" and not model_has_gat(model):
@@ -170,8 +190,13 @@ def effective_backend(config: Config, dataset: Dataset, model: Model,
             print(f"# aggregate_backend={backend} only accelerates "
                   f"sum/avg aggregation; this model uses "
                   f"{sorted(aggrs)} — using xla")
-        return "xla"
-    return backend
+        return no_plan_aggr
+    return backend, why
+
+
+def effective_backend(config: Config, dataset: Dataset, model: Model,
+                      use_edge_shard: bool = False) -> str:
+    return effective_backend_why(config, dataset, model, use_edge_shard)[0]
 
 
 def effective_gat_backend(config: Config, dataset: Dataset,
@@ -650,8 +675,12 @@ class BaseTrainer:
         raise NotImplementedError
 
     def _effective_backend(self) -> str:
-        return effective_backend(self.config, self.dataset, self.model,
-                                 use_edge_shard=self._use_edge_shard)
+        """Also remembers why (``self._backend_why``), for the sharded
+        trainer's `# exchange:` line."""
+        backend, self._backend_why = effective_backend_why(
+            self.config, self.dataset, self.model,
+            use_edge_shard=self._use_edge_shard)
+        return backend
 
     def _gat_backend(self) -> str:
         return effective_gat_backend(self.config, self.dataset, self.model)

@@ -325,7 +325,11 @@ def test_sharded_setup_and_balance_rounds_are_attributed():
              if s.depth == 0]
     assert setup == ["init_params", "partition", "halo_build", "plan_build",
                      "place_data", "init_params", "mem_plan", "step_build"]
-    assert "plan_to_device" in obs.get_tracer().span_types()
+    # the shards' chunk plans stay host arrays until `place_data` puts each
+    # part's block on its own device: none is staged on the default one
+    assert "plan_to_device" not in obs.get_tracer().span_types()
+    assert all(isinstance(a, jax.Array) and len(a.sharding.device_set) == 4
+               for a in jax.tree.leaves(tr.gdata.plans_local))
     assert tr.balancer is not None
     obs.get_tracer().clear()
     tr.train(print_fn=lambda *a, **k: None)
