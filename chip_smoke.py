@@ -91,15 +91,12 @@ class CompileCounter:
 
 
 def geometries(gdata) -> str:
-    """The forward and transposed-backward Geometry of every plan set the
-    trainer built (single: `plans`; sharded halo-overlap: local/remote)."""
-    out = []
-    for name in ("plans", "plans_local", "plans_remote"):
-        p = getattr(gdata, name, None)
-        if p is not None:
-            out.append(f"{name}: fwd={tuple(p.fwd.geom)} "
-                       f"bwd={tuple(p.bwd.geom)}")
-    return "; ".join(out) or "none"
+    """The forward and transposed-backward Geometry of the binned plan set
+    the trainer built ("none" on any other backend)."""
+    p = getattr(gdata, "plans", None)
+    if not hasattr(getattr(p, "fwd", None), "geom"):
+        return "none"
+    return f"plans: fwd={tuple(p.fwd.geom)} bwd={tuple(p.bwd.geom)}"
 
 
 def one_part_per_device(trainer, parts: int) -> bool:

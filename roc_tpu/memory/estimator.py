@@ -190,8 +190,7 @@ def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
     return int(4 * params * 4 + node + edge)
 
 
-PLAN_FIELDS = ("plans", "plans_local", "plans_remote", "ring_plans",
-               "gat_plans")
+PLAN_FIELDS = ("plans", "ring_plans", "gat_plans")
 
 
 def plan_bytes(gdata) -> int:

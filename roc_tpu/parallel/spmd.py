@@ -67,13 +67,6 @@ class ShardedGraphData:
     plans: object = None             # stacked AggregatePlans ([P, ...] axes)
     gat_plans: object = None         # stacked ops.edge.GatPlans
     ring_plans: object = None        # ring.RingPlans ([P, P, ...] axes)
-    # Halo-overlap split (vertex halo mode): local-source edges aggregate
-    # over x's own S rows while the all_to_all is in flight; remote-source
-    # edges aggregate over the received [P*K] halo rows afterwards.  When
-    # set, `plans` stays None (sum/avg never build the combined table
-    # schedule; max/min and attention keep the table path).
-    plans_local: object = None       # plans over table = own [S] rows
-    plans_remote: object = None      # plans over table = halo [P*K] rows
     backend: str = dataclasses.field(default="xla", metadata={"static": True})
     mode: str = dataclasses.field(default="vertex",
                                   metadata={"static": True})
@@ -94,8 +87,7 @@ class ShardedGraphData:
 jax.tree_util.register_dataclass(
     ShardedGraphData,
     data_fields=["edge_src", "edge_dst", "in_degree", "send_idx",
-                 "ring_src", "ring_dst", "plans", "gat_plans", "ring_plans",
-                 "plans_local", "plans_remote"],
+                 "ring_src", "ring_dst", "plans", "gat_plans", "ring_plans"],
     meta_fields=["backend", "mode", "precision", "xch_dtype", "xch_round",
                  "xch_comp"])
 
@@ -623,43 +615,10 @@ from roc_tpu.graph.shard_load import allgather_floors as _allgather_floors  # no
 from roc_tpu.ops.edge import _Z_GUARD  # noqa: E402  (guard rationale there)
 
 
-def _build_shard_plans_split(backend: str, srcs, dsts, S: int,
-                             halo_rows: int, allgather=None,
-                             storage_dtype: str = "fp32"):
-    """(plans_local, plans_remote) for the halo-overlap aggregation.
-
-    Each shard's edge list is cut by source residence: table-local ids
-    < S read the shard's own rows (no communication), ids >= S read the
-    received halo block (shifted to be [0, P*K)-local).  Aggregating the
-    local set while the all_to_all is in flight is the TPU-explicit form
-    of the pipelining Legion gives the reference implicitly — its async
-    IndexLaunchers overlap each op's data movement with compute
-    (scattergather.cc:49-81, SURVEY §3.2).
-
-    Pad edges (source at an own-shard pad node, partition.py) land in the
-    local set by construction, so the remote set carries live halo edges
-    only.  Sum split = exact up to fp32 reassociation, the same freedom
-    the combined plan already exercises across its chunks."""
-    loc_s, loc_d, rem_s, rem_d = [], [], [], []
-    for i in range(len(srcs)):
-        si = np.asarray(srcs[i])
-        di = np.asarray(dsts[i])
-        m = si < S
-        loc_s.append(si[m].astype(np.int32))
-        loc_d.append(di[m].astype(np.int32))
-        rem_s.append((si[~m] - S).astype(np.int32))
-        rem_d.append(di[~m].astype(np.int32))
-    return (_build_shard_plans(backend, loc_s, loc_d, S, S, allgather,
-                               storage_dtype=storage_dtype),
-            _build_shard_plans(backend, rem_s, rem_d, S, halo_rows,
-                               allgather, storage_dtype=storage_dtype))
-
-
 def shard_graph(part: Partition, halo: Optional[HaloMaps],
                 backend: str = "xla",
                 precision: str = "exact",
                 gat_backend: str = "xla",
-                halo_overlap: bool = False,
                 xch: tuple = ("fp32", "nearest", "plain")
                 ) -> ShardedGraphData:
     if halo is not None:
@@ -668,16 +627,11 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
         src = part.edge_src.astype(np.int32)
     P_, S = part.num_parts, part.shard_nodes
     table_rows = S + P_ * halo.K if halo is not None else P_ * S
-    plans = plans_local = plans_remote = None
-    sd = "bf16" if xch[0] == "bf16" else "fp32"
+    plans = None
     if backend in ("matmul", "binned"):
-        if halo is not None and halo_overlap:
-            plans_local, plans_remote = _build_shard_plans_split(
-                backend, src, part.edge_dst, S, P_ * halo.K,
-                storage_dtype=sd)
-        else:
-            plans = _build_shard_plans(backend, src, part.edge_dst, S,
-                                       table_rows, storage_dtype=sd)
+        plans = _build_shard_plans(
+            backend, src, part.edge_dst, S, table_rows,
+            storage_dtype="bf16" if xch[0] == "bf16" else "fp32")
     gat_plans = None
     if gat_backend == "plan":
         from roc_tpu.ops.edge import build_gat_plans, pad_gat_plans
@@ -694,8 +648,6 @@ def shard_graph(part: Partition, halo: Optional[HaloMaps],
         send_idx=None if halo is None else np.asarray(halo.send_idx),
         plans=plans,
         gat_plans=gat_plans,
-        plans_local=plans_local,
-        plans_remote=plans_remote,
         backend=backend,
         precision=precision,
         xch_dtype=xch[0], xch_round=xch[1], xch_comp=xch[2],
@@ -1085,29 +1037,6 @@ def _shard_gctx(gd_block, shard_nodes: int, exchange: str) -> GraphCtx:
                         in_degree=gd_block.in_degree, attend=attend_ring)
 
     def aggregate(x, aggr):
-        # avg rides the sum fast path: per-shard in_degree is the live
-        # in-edge count (pad rows carry 1, and their sums are zero anyway).
-        if gd_block.plans_local is not None and aggr in ("sum", "avg"):
-            # Halo overlap: issue the all_to_all FIRST, aggregate the
-            # local-source edges while it is in flight (the local plan
-            # consumes only x, so XLA's async collective scheduler runs
-            # the send concurrently with the local matmuls), then fold the
-            # remote-source contributions from the received halo rows —
-            # the explicit form of the reference's Legion pipelining
-            # (scattergather.cc:49-81 async IndexLaunchers).
-            send = _wire_down(jnp.take(x, gd_block.send_idx, axis=0),
-                              gd_block)                          # [P, K, H]
-            recv = jax.lax.all_to_all(send, PARTS_AXIS,
-                                      split_axis=0, concat_axis=0)
-            out = _plan_sum(x, gd_block.plans_local, gd_block.backend,
-                            gd_block.precision, shard_nodes, interp)
-            halo = _wire_up(recv, gd_block, x.dtype, x.shape[-1])
-            out = out + _plan_sum(halo.reshape(-1, x.shape[-1]),
-                                  gd_block.plans_remote, gd_block.backend,
-                                  gd_block.precision, shard_nodes, interp)
-            if aggr == "avg":
-                out = ops.divide_by_degree(out, gd_block.in_degree)
-            return out
         table = _exchange(gd_block, exchange, x)
         return _vertex_aggregate(table, gd_block, shard_nodes, aggr, interp)
 
@@ -1127,23 +1056,20 @@ def _part_view(tree_, j: int):
     return jax.tree.map(lambda a: a[j], tree_)
 
 
-def _plan_sum(table, plans, backend: str, precision: str, S: int,
-              interp: bool):
-    """Sum-aggregate ``table`` through one stacked plan set (the backend
-    dispatch shared by the combined-table and halo-overlap paths)."""
-    if backend == "binned":
-        return ops.scatter_gather_binned(table, plans, interp, precision)
-    return ops.scatter_gather_matmul(table, plans, S, table.shape[0],
-                                     ops.matmul_precision(precision))
-
-
 def _vertex_aggregate(table, gdj, S: int, aggr: str, interp: bool):
     """One part's vertex-mode aggregation over its source table — the
     single backend dispatch shared by _shard_gctx (k=1) and
-    _shard_gctx_over (k parts stacked per device)."""
+    _shard_gctx_over (k parts stacked per device).  avg rides the sum
+    fast path: per-shard in_degree is the live in-edge count (pad rows
+    carry 1, and their sums are zero anyway)."""
     if gdj.plans is not None and aggr in ("sum", "avg"):
-        out = _plan_sum(table, gdj.plans, gdj.backend, gdj.precision, S,
-                        interp)
+        if gdj.backend == "binned":
+            out = ops.scatter_gather_binned(table, gdj.plans, interp,
+                                            gdj.precision)
+        else:
+            out = ops.scatter_gather_matmul(
+                table, gdj.plans, S, table.shape[0],
+                ops.matmul_precision(gdj.precision))
         if aggr == "avg":
             out = ops.divide_by_degree(out, gdj.in_degree)
         return out
@@ -1233,6 +1159,18 @@ def _shard_gctx_over(gd_block, S: int, k: int, exchange: str) -> GraphCtx:
                     in_degree=gd_block.in_degree.reshape(-1), attend=attend)
 
 
+def _plan_chunks(plans, direction: str) -> tuple:
+    """(chunks a part, slots a chunk) of one direction of a stacked
+    vertex-mode plan set, from its static shapes: the matmul backend's
+    [P, C, EB] gather ids, the binned backend's [P, G, C1, CH] phase-1
+    rows (every edge sits in one slot of either)."""
+    if isinstance(plans, ops.BinnedPlans):
+        _, G, C1, width = getattr(plans, direction).p1_srcl.shape
+        return G * C1, width
+    _, chunks, width = getattr(plans, direction + "_esrc").shape
+    return chunks, width
+
+
 def _padded_max_tax(meta) -> float:
     """E_padded/E_live - 1: what every shard overpays because all shards run
     the padded-max edge count (the skew cost of vertex partitioning)."""
@@ -1280,14 +1218,6 @@ class SpmdTrainer(BaseTrainer):
             f"non-contiguous local parts {ids}: mesh device order is not "
             "process-major")
         return ids
-
-    def _halo_overlap(self) -> bool:
-        """Build split local/remote plans for the halo exchange?  On by
-        default (cfg.halo_overlap) for the plan backends in vertex halo
-        mode; overcommit (k>1) keeps the combined table — its k per-part
-        aggregations already interleave with the single all_to_all."""
-        return bool(self.config.halo_overlap) and self.k == 1 \
-            and self._exchange_mode == "halo"
 
     def _xch_meta(self) -> tuple:
         """(xch_dtype, xch_round, xch_comp) wire metadata for the feature
@@ -1381,7 +1311,6 @@ class SpmdTrainer(BaseTrainer):
             return shard_graph(self.part, self.halo, backend,
                                cfg.aggregate_precision,
                                gat_backend=gat_backend,
-                               halo_overlap=self._halo_overlap(),
                                xch=self._xch_meta())
 
     def _build_graph_perhost(self, backend: str,
@@ -1492,17 +1421,12 @@ class SpmdTrainer(BaseTrainer):
         P_, S = meta.num_parts, meta.shard_nodes
         src = lhalo.edge_src_local if lhalo is not None else local.edge_src
         table_rows = S + P_ * lhalo.K if lhalo is not None else P_ * S
-        plans = plans_local = plans_remote = None
-        sd = "bf16" if self._xch_meta()[0] == "bf16" else "fp32"
+        xd, xr, xc = self._xch_meta()
+        plans = None
         if backend in ("matmul", "binned"):
-            if lhalo is not None and self._halo_overlap():
-                plans_local, plans_remote = _build_shard_plans_split(
-                    backend, src, local.edge_dst, S, P_ * lhalo.K,
-                    allgather=ag, storage_dtype=sd)
-            else:
-                plans = _build_shard_plans(backend, src, local.edge_dst, S,
-                                           table_rows, allgather=ag,
-                                           storage_dtype=sd)
+            plans = _build_shard_plans(
+                backend, src, local.edge_dst, S, table_rows, allgather=ag,
+                storage_dtype="bf16" if xd == "bf16" else "fp32")
         gat_plans = None
         if gat_backend == "plan":
             from roc_tpu.ops.edge import build_gat_plans, pad_gat_plans
@@ -1515,7 +1439,6 @@ class SpmdTrainer(BaseTrainer):
                      [p.src_obi.shape[0] for p in local_plans]], ag)
                 gat_plans = pad_gat_plans(local_plans, min_d=f[0],
                                           min_s=f[1])
-        xd, xr, xc = self._xch_meta()
         return ShardedGraphData(
             edge_src=jnp.asarray(src, jnp.int32),
             edge_dst=jnp.asarray(local.edge_dst, jnp.int32),
@@ -1523,8 +1446,6 @@ class SpmdTrainer(BaseTrainer):
             send_idx=None if lhalo is None else jnp.asarray(lhalo.send_idx),
             plans=plans,
             gat_plans=gat_plans,
-            plans_local=plans_local,
-            plans_remote=plans_remote,
             backend=backend,
             precision=cfg.aggregate_precision,
             xch_dtype=xd, xch_round=xr, xch_comp=xc)
@@ -1577,7 +1498,10 @@ class SpmdTrainer(BaseTrainer):
         padded-max tax (SURVEY section 7: every shard runs the fullest
         shard's edge count, so skew becomes padding; the reference
         balances edges because kernel work follows them, gnn.cc:806-829);
-        the live edges of the emptiest and fullest shard; the aggregation
+        the live edges of the emptiest and fullest shard; for a vertex-
+        sharded plan backend the chunks a part's forward and backward
+        plan hold (after padding to the common count) and the share of
+        their slots the fullest part's live edges fill; the aggregation
         backend with the reason the policy gave where it decided
         (driver.resolve_backend_why, or the exchange that overrode it)."""
         m, gd = self.part, self.gdata
@@ -1613,6 +1537,14 @@ class SpmdTrainer(BaseTrainer):
             cut = None
         if cut is not None:
             info["edge_cut_share"] = cut / max(int(live.sum()), 1)
+        if gd.mode == "vertex" and gd.plans is not None:
+            # an edge fills one slot of one chunk a pass; the rest of the
+            # chunks x width slots are padding the scans gather all the same
+            for d in ("fwd", "bwd"):
+                chunks, width = _plan_chunks(gd.plans, d)
+                info["agg_chunks_" + d] = chunks
+                info["agg_slot_fill_" + d] = \
+                    int(live.max()) / max(chunks * width, 1)
         info["agg_backend"] = gd.backend
         info["agg_backend_reason"] = self._backend_why
         return info
@@ -1633,8 +1565,10 @@ class SpmdTrainer(BaseTrainer):
             self._metrics.set_gauge("exchange_" + name, info[name])
         for name in ("halo_rows_per_peer", "halo_fraction", "edge_cut_share",
                      "padded_max_tax", "shard_edges_live_min",
-                     "shard_edges_live_max"):
-            if info[name] is not None:
+                     "shard_edges_live_max", "agg_chunks_fwd",
+                     "agg_chunks_bwd", "agg_slot_fill_fwd",
+                     "agg_slot_fill_bwd"):
+            if info.get(name) is not None:
                 self._metrics.set_gauge(name, info[name])
         self._metrics.set_gauge("exchange_mode", 1.0, mode=info["mode"])
         self._metrics.set_gauge("agg_backend", 1.0,
@@ -1838,12 +1772,8 @@ class SpmdTrainer(BaseTrainer):
             self._train_step, self._eval_step, self._logits_step = cached
             return
         # pallas_call can't annotate vma yet; the matmul backend is plain
-        # XLA.  Binned pallas plans can live in `plans` (fused exchange) OR
-        # in the halo-overlap split pair `plans_local`/`plans_remote` —
-        # any of them present means pallas_call traces inside shard_map.
-        has_plans = (gd.plans is not None or gd.plans_local is not None
-                     or gd.plans_remote is not None)
-        check_vma = (not has_plans) or gd.backend == "matmul"
+        # XLA.  Binned plans mean pallas_call traces inside shard_map.
+        check_vma = gd.plans is None or gd.backend == "matmul"
 
         def block_gctx(gd_block):
             """Per-device GraphCtx: one part (squeezed) or k stacked."""

@@ -106,15 +106,6 @@ class Config:
     exchange: str = ""            # halo | allgather | ring (empty: derive
                                   # from `halo`; ring = ppermute rotation,
                                   # memory-bounded — parallel/ring.py)
-    halo_overlap: bool = True     # split each shard's edges into local- vs
-                                  # remote-source plans so the local
-                                  # aggregation runs while the halo
-                                  # all_to_all is in flight — the explicit
-                                  # TPU recovery of Legion's implicit op
-                                  # pipelining (scattergather.cc:49-81).
-                                  # Plan backends + sum/avg, k=1 only;
-                                  # -no-halo-overlap restores the
-                                  # materialize-then-aggregate path
     check_sharding: bool = False  # validate sharded == single-device first
     analyze: bool = False         # static audit before + retrace report
                                   # after the run (roc_tpu/analysis/):
@@ -406,8 +397,6 @@ def parse_args(argv: List[str]) -> Config:
                         "(roc_tpu/tune) before building plans")
     p.add_argument("-lazy", dest="lazy_load", action="store_true")
     p.add_argument("-no-halo", dest="halo", action="store_false")
-    p.add_argument("-no-halo-overlap", dest="halo_overlap",
-                   action="store_false")
     p.add_argument("-exchange", dest="exchange", default="",
                    choices=["", "halo", "allgather", "ring"])
     p.add_argument("-check-sharding", dest="check_sharding",

@@ -46,8 +46,7 @@ def _loss(ds, cfg, model=None, n=3):
     dict(num_parts=4, halo=True),
     dict(num_parts=4, halo=False),                      # allgather
     dict(num_parts=4, exchange="ring"),
-    dict(num_parts=4, halo=True, halo_overlap=True,
-         aggregate_backend="matmul"),                   # split-plan path
+    dict(num_parts=4, halo=True, aggregate_backend="matmul"),  # plan path
 ])
 def test_gcn_bf16_matches_fp32(mode):
     """GCN final-loss parity within 1e-2 of the fp32 run on every exchange

@@ -338,9 +338,7 @@ def test_binned_sharded_matches_xla(halo):
     tx = SpmdTrainer(Config(**base), ds, build_gcn(base["layers"], 0.0))
     tb = SpmdTrainer(Config(**base, aggregate_backend="binned"), ds,
                      build_gcn(base["layers"], 0.0))
-    # halo_overlap (default on) stores the split pair instead of `plans`
-    assert tb.gdata.backend == "binned" and (
-        tb.gdata.plans is not None or tb.gdata.plans_local is not None)
+    assert tb.gdata.backend == "binned" and tb.gdata.plans is not None
     for i in range(2):
         lx, lb = float(tx.run_epoch()), float(tb.run_epoch())
         np.testing.assert_allclose(lb, lx, rtol=5e-3, err_msg=f"epoch {i}")

@@ -372,9 +372,7 @@ def test_spmd_flat_env_flag(monkeypatch):
     tb = SpmdTrainer(Config(**base, aggregate_backend="binned"), ds,
                      build_gcn(base["layers"], 0.0))
     assert tb.gdata.backend == "binned"
-    plans = tb.gdata.plans if tb.gdata.plans is not None \
-        else tb.gdata.plans_local
-    assert plans.fwd.geom.flat == 1, plans.fwd.geom
+    assert tb.gdata.plans.fwd.geom.flat == 1, tb.gdata.plans.fwd.geom
     for i in range(2):
         lx, lb = float(tx.run_epoch()), float(tb.run_epoch())
         np.testing.assert_allclose(lb, lx, rtol=5e-3, err_msg=f"epoch {i}")

@@ -67,7 +67,8 @@ def test_halo_run_says_what_it_exchanges(capsys):
         "mode", "parts", "rows_per_epoch", "bytes_per_epoch",
         "halo_rows_per_peer", "halo_fraction", "edge_cut_share",
         "padded_max_tax", "shard_edges_live_min", "shard_edges_live_max",
-        "agg_backend", "agg_backend_reason"]
+        "agg_chunks_fwd", "agg_slot_fill_fwd", "agg_chunks_bwd",
+        "agg_slot_fill_bwd", "agg_backend", "agg_backend_reason"]
     assert (info["mode"], info["parts"], info["halo_rows_per_peer"]) == (
         "halo", PARTS, K)
     # three aggregations at widths 16, 16, 4; each exchanges P x K rows a
@@ -87,6 +88,13 @@ def test_halo_run_says_what_it_exchanges(capsys):
     assert info["edge_cut_share"] == pytest.approx(
         _edge_cut(ds, part) / live.sum())
     assert 0.0 < info["edge_cut_share"] < 1.0
+    # ONE plan set over the combined table: what its placed arrays hold
+    for d in ("fwd", "bwd"):
+        parts, chunks, slots = getattr(tr.gdata.plans, d + "_esrc").shape
+        assert parts == PARTS and info["agg_chunks_" + d] == chunks
+        assert info["agg_slot_fill_" + d] == pytest.approx(
+            live.max() / (chunks * slots))
+        assert 0.0 < info["agg_slot_fill_" + d] <= 1.0
     assert (info["agg_backend"], info["agg_backend_reason"]) == (
         "matmul", "-aggr-backend=matmul")
     line = _line(capsys)
@@ -94,7 +102,34 @@ def test_halo_run_says_what_it_exchanges(capsys):
     assert line.startswith(f"# exchange: mode=halo parts={PARTS} "
                            f"rows_per_epoch={info['rows_per_epoch']} ")
     assert f" halo_rows_per_peer={K} " in line
+    assert (f" agg_chunks_fwd={info['agg_chunks_fwd']} "
+            f"agg_slot_fill_fwd={info['agg_slot_fill_fwd']:.4f} "
+            f"agg_chunks_bwd={info['agg_chunks_bwd']} "
+            f"agg_slot_fill_bwd={info['agg_slot_fill_bwd']:.4f} "
+            "agg_backend=matmul (-aggr-backend=matmul)") in line
     assert line.endswith(" agg_backend=matmul (-aggr-backend=matmul)")
+
+
+def test_a_backend_without_plans_reports_no_chunks(capsys):
+    info = _trainer(_dataset(), aggregate_backend="xla").exchange_info()
+    assert info["agg_backend"] == "xla"
+    assert not [k for k in info if k.startswith(("agg_chunks", "agg_slot"))]
+    line = _line(capsys)
+    assert "agg_chunks" not in line and "agg_slot_fill" not in line
+    assert line.endswith(" agg_backend=xla (-aggr-backend=xla)")
+
+
+def test_the_binned_backend_counts_its_phase_one_chunks():
+    tr = _trainer(_dataset(), aggregate_backend="binned")
+    info = tr.exchange_info()
+    live = int(np.asarray(tr.part.num_edges_valid).max())
+    for d in ("fwd", "bwd"):
+        parts, groups, chunks, slots = getattr(tr.gdata.plans,
+                                               d).p1_srcl.shape
+        assert parts == PARTS
+        assert info["agg_chunks_" + d] == groups * chunks
+        assert info["agg_slot_fill_" + d] == pytest.approx(
+            live / (groups * chunks * slots))
 
 
 def test_bf16_wire_halves_the_bytes_not_the_rows():
@@ -148,7 +183,8 @@ def test_record_gauges_and_report(tmp_path):
     for name in ("exchange_rows_per_epoch", "exchange_bytes_per_epoch",
                  "halo_rows_per_peer", "halo_fraction", "edge_cut_share",
                  "padded_max_tax", "shard_edges_live_min",
-                 "shard_edges_live_max"):
+                 "shard_edges_live_max", "agg_chunks_fwd", "agg_chunks_bwd",
+                 "agg_slot_fill_fwd", "agg_slot_fill_bwd"):
         assert f"roc_{name} " in prom, name
     assert 'roc_exchange_mode{mode="halo"} 1' in prom
     assert ('roc_agg_backend{backend="matmul",'
@@ -284,11 +320,9 @@ def test_the_planner_prices_the_shards_plans():
     import jax
     from roc_tpu.memory import estimator
     ds = _dataset()
-    tr = _trainer(ds)                   # matmul, halo overlap: split plans
+    tr = _trainer(ds)                   # matmul: one plan set a shard
     gd, part = tr.gdata, tr.part
-    assert gd.plans is None and gd.plans_local is not None
-    plans = sum(int(a.size) * 4 for p in (gd.plans_local, gd.plans_remote)
-                for a in jax.tree.leaves(p))
+    plans = sum(int(a.size) * 4 for a in jax.tree.leaves(gd.plans))
     assert estimator.plan_bytes(gd) == plans > 0
     bare = estimator.fixed_bytes_for(tr.model, part.shard_nodes, ds.in_dim,
                                      ds.num_classes, part.shard_edges)
@@ -304,21 +338,22 @@ def test_the_planner_prices_the_shards_plans():
 
 def test_the_fixed_bytes_of_a_products_shard_are_the_compilers_arguments():
     """Held to a number that was read, not modelled: for the gcn-products.p4
-    train step on a described v5e 2x2 the compiler counts 1,280,220,160
-    bytes of arguments a chip (tools/aot_compile.py, PR 29; PR 22 read the
-    same 1.28 GB).  jit drops the two edge arrays the plan backends never
-    read, so they are resident and not arguments.  The chunk counts are the
-    chip run's (PERF.md section 5): 160,760 local chunks each way, 77,816
-    remote forward, 37,920 remote backward, of EB slots twice over plus a
-    window index and a first-chunk flag."""
+    train step on a described v5e 2x2 the compiler counts 1,126,184,960
+    bytes of arguments a chip (the cell's own trainer lowered for the
+    topology, PR 30).  jit
+    drops the two edge arrays the plan backends never read, so they are
+    resident and not arguments.  The chunk counts are the cell's own
+    (`# exchange:` agg_chunks_fwd / agg_chunks_bwd): 163,656 forward and
+    198,680 backward over the combined table, of EB slots twice over plus
+    a window index and a first-chunk flag."""
     from roc_tpu.memory import estimator
     from roc_tpu.ops.pallas.segment_sum import EB
     rows, edges = 612_864, 31_150_848
-    chunks = 2 * 160_760 + 77_816 + 37_920
+    chunks = 163_656 + 198_680
     plans = chunks * (2 * EB + 2) * 4
     fixed = estimator.fixed_bytes_for(
         build_gcn([100, 256, 256, 47], 0.5), rows, 100, 47, edges) + plans
-    arguments, unread_edge_arrays = 1_280_220_160, edges * 2 * 4
+    arguments, unread_edge_arrays = 1_126_184_960, edges * 2 * 4
     assert abs(fixed / (arguments + unread_edge_arrays) - 1) < 0.02
     # without the plans the planner saw under half of it
     assert (fixed - plans) / (arguments + unread_edge_arrays) < 0.45

@@ -329,7 +329,7 @@ def test_sharded_setup_and_balance_rounds_are_attributed():
     # part's block on its own device: none is staged on the default one
     assert "plan_to_device" not in obs.get_tracer().span_types()
     assert all(isinstance(a, jax.Array) and len(a.sharding.device_set) == 4
-               for a in jax.tree.leaves(tr.gdata.plans_local))
+               for a in jax.tree.leaves(tr.gdata.plans))
     assert tr.balancer is not None
     obs.get_tracer().clear()
     tr.train(print_fn=lambda *a, **k: None)

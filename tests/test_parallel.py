@@ -402,55 +402,107 @@ def test_ring_exchange_sage_avg_and_max():
 
 
 # ---------------------------------------------------------------------------
-# Halo overlap (round 5): local-source edges aggregate while the all_to_all
-# is in flight — the explicit TPU form of the reference's Legion pipelining
-# (scattergather.cc:49-81 async IndexLaunchers; SURVEY §3.2).
+# Halo mode on a plan backend: ONE plan set over the combined table (own
+# rows ++ received halo rows).  A second plan for the remote edges would pay
+# a floor chunk for every window without one (PERF.md, PR 30).
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["matmul", "binned"])
-def test_halo_overlap_matches_combined_table(backend):
-    """Split local/remote plans == combined-table plans, fwd AND bwd
-    (training epochs), on both plan backends."""
+def test_halo_plan_backends_train_as_one_device(backend):
+    """Four parts over the halo exchange == the one-device Trainer, fwd AND
+    bwd (training epochs), on both plan backends; avg (SAGE) rides the same
+    sum, then divides by degree."""
     from roc_tpu.models import build_sage
 
     ds = small_ds(seed=23)
     base = dict(layers=[ds.in_dim, 8, ds.num_classes], num_epochs=3,
-                dropout_rate=0.0, eval_every=10**9, num_parts=4, halo=True,
-                edge_shard="off", aggregate_backend=backend)
-    on = SpmdTrainer(Config(**base), ds,
-                     build_gcn(base["layers"], 0.0))
-    off = SpmdTrainer(Config(**base, halo_overlap=False), ds,
-                      build_gcn(base["layers"], 0.0))
-    assert on.gdata.plans_local is not None \
-        and on.gdata.plans_remote is not None and on.gdata.plans is None
-    assert off.gdata.plans is not None and off.gdata.plans_local is None
+                dropout_rate=0.0, eval_every=10**9, edge_shard="off",
+                aggregate_precision="exact")
+    four = SpmdTrainer(Config(**base, num_parts=4, halo=True,
+                              aggregate_backend=backend), ds,
+                       build_gcn(base["layers"], 0.0))
+    one = Trainer(Config(**base), ds, build_gcn(base["layers"], 0.0))
+    assert four.gdata.backend == backend and four.gdata.plans is not None
+    assert four._exchange_mode == "halo" and one.gdata.backend == "xla"
     for i in range(3):
-        l_on, l_off = float(on.run_epoch()), float(off.run_epoch())
-        np.testing.assert_allclose(l_on, l_off, rtol=1e-5,
+        np.testing.assert_allclose(float(four.run_epoch()),
+                                   float(one.run_epoch()), rtol=1e-5,
                                    err_msg=f"epoch {i}")
     np.testing.assert_allclose(
-        np.asarray(jax.device_get(on.params["linear_1"])),
-        np.asarray(jax.device_get(off.params["linear_1"])), rtol=1e-4,
+        np.asarray(jax.device_get(four.params["linear_1"])),
+        np.asarray(jax.device_get(one.params["linear_1"])), rtol=1e-4,
         atol=1e-6)
-    # avg (SAGE) rides the same split then divides by degree
-    m_on = SpmdTrainer(Config(**base, model="sage", aggr="avg"), ds,
-                       build_sage(base["layers"], 0.0, aggr="avg"))
-    m_off = SpmdTrainer(Config(**base, model="sage", aggr="avg",
-                               halo_overlap=False), ds,
-                        build_sage(base["layers"], 0.0, aggr="avg"))
+    sage = dict(base, model="sage", aggr="avg")
+    m4 = SpmdTrainer(Config(**sage, num_parts=4, halo=True,
+                            aggregate_backend=backend), ds,
+                     build_sage(base["layers"], 0.0, aggr="avg"))
+    m1 = Trainer(Config(**sage), ds,
+                 build_sage(base["layers"], 0.0, aggr="avg"))
     for i in range(2):
-        np.testing.assert_allclose(float(m_on.run_epoch()),
-                                   float(m_off.run_epoch()), rtol=1e-5,
+        np.testing.assert_allclose(float(m4.run_epoch()),
+                                   float(m1.run_epoch()), rtol=1e-5,
                                    err_msg=f"sage epoch {i}")
 
 
-def test_halo_overlap_local_dots_independent_of_collective():
-    """The POINT of the split: the local-plan matmuls must not depend on
-    the all_to_all's result, or XLA cannot overlap them.  Verified on the
-    traced jaxpr of the aggregation: collect every var transitively
-    derived from the all_to_all output and assert at least one
-    dot_general consumes none of them (the local one-hot dots), while at
-    least one does (the remote fold)."""
+def test_combined_plan_folds_remote_edges_into_local_windows():
+    """On a community-local graph (the gcn-products.p4 recipe at 6,000
+    nodes: a window of 8 rows holds about 400 edges, the cut sits in the
+    border communities) a part's remote edges land in windows that already
+    own chunks: the combined forward plan holds hardly more chunks than a
+    plan of the local edges alone, where a plan of the remote edges alone
+    pays a floor chunk for every window.  exchange_info reports the
+    placed plans' own shapes."""
+    import os
+
+    from benchmark import graphgen
+    from benchmark import manifest as mf
+    from roc_tpu.ops.pallas.segment_sum import (CPAD, EB, VB,
+                                                build_chunk_plan)
+
+    recipe = dict(graphgen.load_recipe(os.path.join(
+        mf.ROOT, "benchmark", "traffic", "products-local-p4.json")),
+        nodes=6000, splits={"train": 600, "val": 600, "test": 600})
+    ds = graphgen.generate(recipe, 8, 4, 3)
+    cfg = Config(layers=[8, 8, 4], num_epochs=1, dropout_rate=0.0,
+                 eval_every=10**9, num_parts=4, halo=True,
+                 edge_shard="off", aggregate_backend="matmul")
+    tr = SpmdTrainer(cfg, ds, build_gcn(cfg.layers, 0.0))
+    part, halo, S = tr.part, tr.halo, tr.part.shard_nodes
+    combined = []
+    for p in range(4):
+        src, dst = halo.edge_src_local[p], part.edge_dst[p]
+        remote = src >= S
+        assert 0 < remote.sum() < 0.2 * src.size
+        comb = build_chunk_plan(src, dst, S).num_chunks
+        local = build_chunk_plan(src[~remote], dst[~remote], S).num_chunks
+        alone = build_chunk_plan(src[remote] - S, dst[remote],
+                                 S).num_chunks
+        windows = np.unique(dst[remote] // VB).size
+        assert comb < local + -(-int(remote.sum()) // EB) + windows + CPAD
+        assert alone >= S // VB and comb - local < alone // 4
+        combined.append(comb)
+    plans, info = tr.gdata.plans, tr.exchange_info()
+    assert plans.fwd_esrc.shape == (4, max(combined), EB)
+    live = int(np.asarray(part.num_edges_valid).max())
+    for d in ("fwd", "bwd"):
+        chunks = getattr(plans, d + "_obi").shape[1]
+        assert info["agg_chunks_" + d] == chunks
+        assert info["agg_slot_fill_" + d] == pytest.approx(
+            live / (chunks * EB))
+    assert 0.5 < info["agg_slot_fill_fwd"] < 1.0
+    # the backward plan is keyed by table row: the halo rows keep windows
+    # of their own, so it holds more chunks for the same edges
+    assert info["agg_chunks_bwd"] > info["agg_chunks_fwd"]
+
+
+def test_sharded_sum_is_one_scan_each_way_fed_by_the_exchange():
+    """One shard's aggregate(x, "sum") on the matmul backend is ONE scan
+    over the table the all_to_all built, and its gradient adds ONE more
+    (the transposed plan), nothing beside them: a plan set beside `plans`
+    cannot arrive unnoticed."""
+    import jax.numpy as jnp
+    from jax.extend.core import Literal
+
     from roc_tpu.parallel import spmd as sp
 
     ds = small_ds(seed=29)
@@ -458,75 +510,53 @@ def test_halo_overlap_local_dots_independent_of_collective():
                  dropout_rate=0.0, eval_every=10**9, num_parts=4, halo=True,
                  edge_shard="off", aggregate_backend="matmul")
     tr = SpmdTrainer(cfg, ds, build_gcn(cfg.layers, 0.0))
-    gd = tr.gdata
     S = tr.part.shard_nodes
-
-    def one_shard_aggregate(x, gd_block):
-        gctx = sp._shard_gctx(gd_block, S, "halo")
-        return gctx.aggregate(x, "sum")
-
-    x = jax.ShapeDtypeStruct((S, ds.in_dim), jax.numpy.float32)
-    import jax.numpy as jnp
-
-    def wrapped(x, gd_arrays):
-        gd_block = jax.tree_util.tree_unflatten(gd_treedef, gd_arrays)
-        return one_shard_aggregate(x, gd_block)
-
-    gd_one = jax.tree.map(lambda a: a[0], gd)   # squeeze the parts axis
-    gd_arrays, gd_treedef = jax.tree_util.tree_flatten(gd_one)
-    # trace THROUGH shard_map so all_to_all sees a bound axis name — the
-    # aggregation body alone would fail to trace its collective
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("parts",))
     Pspec = jax.sharding.PartitionSpec
-    sm = jax.shard_map(
-        lambda x_, *a: wrapped(x_, list(a)),
-        mesh=mesh,
-        in_specs=(Pspec(),) * (1 + len(gd_arrays)),
-        out_specs=Pspec(),
-        check_vma=False,
-    )
-    jaxpr = jax.make_jaxpr(lambda x, arrs: sm(x, *arrs))(x, gd_arrays)
+    gd_specs = jax.tree.map(lambda a: Pspec("parts"), tr.gdata)
 
-    # Taint-walk the jaxpr, following taint through sub-jaxpr call
-    # boundaries (shard_map body, pjit, the matmul backend's lax.scan):
-    # an eqn's tainted invars map positionally onto its sub-jaxpr's
-    # invars, and a sub-jaxpr with tainted outvars taints the eqn.
-    from jax.extend.core import Literal
+    # trace THROUGH shard_map so all_to_all sees a bound axis name
+    @jax.shard_map(mesh=mesh, in_specs=(Pspec("parts"), gd_specs),
+                   out_specs=Pspec("parts"))
+    def aggregate(x, gd_block):
+        gctx = sp._shard_gctx(sp._squeeze_gd(gd_block), S, "halo")
+        return gctx.aggregate(x, "sum")
 
-    saw = {"a2a": False, "clean": False, "tainted": False}
+    x = jnp.zeros((4 * S, ds.in_dim), jnp.float32)
 
-    def run(jx, tainted_in):
+    def subjaxprs(e):
+        for v in e.params.values():
+            for vv in (v if isinstance(v, (list, tuple)) else (v,)):
+                if hasattr(vv, "jaxpr") and hasattr(vv.jaxpr, "eqns"):
+                    yield vv.jaxpr          # ClosedJaxpr
+                elif hasattr(vv, "eqns"):
+                    yield vv                # open Jaxpr (shard_map)
+
+    def loops(jx, tainted_in, found):
+        """Every scan/while of ``jx`` and below as (name, reads a value
+        derived from an all_to_all); returns whether an output does."""
         tainted = set(tainted_in)
         for e in jx.eqns:
             ein = [v for v in e.invars if not isinstance(v, Literal)]
-            is_tainted = any(v in tainted for v in ein)
-            if "all_to_all" in e.primitive.name:
-                saw["a2a"] = True
-                is_tainted = True
-            subs = []
-            for v in e.params.values():
-                for vv in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if hasattr(vv, "jaxpr") and hasattr(vv.jaxpr, "eqns"):
-                        subs.append(vv.jaxpr)   # ClosedJaxpr
-                    elif hasattr(vv, "eqns"):
-                        subs.append(vv)         # open Jaxpr (shard_map)
-            sub_out_tainted = False
-            for sj in subs:
-                if len(sj.invars) == len(ein):
+            hot = any(v in tainted for v in ein) \
+                or "all_to_all" in e.primitive.name
+            if e.primitive.name in ("scan", "while"):
+                found.append((e.primitive.name, hot))
+            else:
+                for sj in subjaxprs(e):
+                    same = len(sj.invars) == len(ein)
                     tin = {sv for sv, ov in zip(sj.invars, ein)
-                           if ov in tainted}
-                else:   # conservative: arity mismatch, taint all or none
-                    tin = set(sj.invars) if is_tainted else set()
-                if run(sj, tin):
-                    sub_out_tainted = True
-            if is_tainted or sub_out_tainted:
+                           if ov in tainted} if same \
+                        else (set(sj.invars) if hot else set())
+                    hot = loops(sj, tin, found) or hot
+            if hot:
                 tainted.update(e.outvars)
-            if e.primitive.name == "dot_general":
-                saw["tainted" if is_tainted else "clean"] = True
         return any(v in tainted for v in jx.outvars)
 
-    run(jaxpr.jaxpr, set())
-    assert saw["a2a"], "no all_to_all in the overlap aggregation"
-    assert saw["clean"], ("every dot_general depends on the collective — "
-                          "the local aggregation cannot overlap it")
-    assert saw["tainted"], "no dot consumes the halo rows (remote fold lost)"
+    fwd = []
+    loops(jax.make_jaxpr(aggregate)(x, tr.gdata).jaxpr, set(), fwd)
+    assert fwd == [("scan", True)], fwd
+    both = []
+    loops(jax.make_jaxpr(jax.grad(
+        lambda x_: aggregate(x_, tr.gdata).sum()))(x).jaxpr, set(), both)
+    assert [name for name, _ in both] == ["scan", "scan"], both

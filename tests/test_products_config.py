@@ -72,7 +72,7 @@ def test_the_configuration_is_sharded_as_the_cell_shards_it(dataset, parts,
     assert type(tr).__name__ == "SpmdTrainer"
     assert checks.one_part_per_device(tr, parts)
     assert tr._exchange_mode == "halo" and tr.halo.K > 0
-    assert (tr.gdata.plans_local is not None) == (backend == "matmul")
+    assert (tr.gdata.plans is not None) == (backend == "matmul")
 
 
 @pytest.mark.parametrize("parts,backend", CASES)
