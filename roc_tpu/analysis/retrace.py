@@ -75,7 +75,13 @@ class RetraceGuard:
         return self
 
     def __exit__(self, *exc):
-        _ACTIVE.remove(self)
+        # idempotent: an engine closed from two threads at once (a kill
+        # racing a close, tests/test_fleet.py's chaos) exits its guard twice
+        if self in _ACTIVE:
+            try:
+                _ACTIVE.remove(self)
+            except ValueError:  # roclint: allow(silent-swallow) — the other closer won the race
+                pass
         return False
 
     # -- wiring (called via the module-level hooks) -----------------------

@@ -36,6 +36,7 @@ from typing import Dict, List, Tuple
 # same constants bench.py reports against); used here only to PRICE
 # recompute relative to step time, never as a claim about achieved
 # throughput.
+from roc_tpu.models.model import attention_heads, attention_score
 from roc_tpu.obs.roofline import PEAK_BW, PEAK_FLOPS
 # Feature width _MM_CHUNK_S (the aggregation chunk prior) was measured at
 # (the reddit bench's in_dim); aggregation recompute scales linearly in
@@ -46,7 +47,8 @@ PRIOR_AGG_WIDTH = 602
 # per-tensor half of the granularity decision — see module docstring).
 SAVED_KINDS = frozenset({"linear", "aggregate", "gat"})
 # Elementwise kinds: cheap to recompute, never saved under an active plan.
-CHEAP_KINDS = frozenset({"dropout", "norm", "activation", "add"})
+CHEAP_KINDS = frozenset({"dropout", "norm", "layernorm", "activation",
+                         "add"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,9 +106,14 @@ def _op_forward_s(op, in_dim: int, out_dim: int, rows: int,
         t *= max(out_dim, 1) / PRIOR_AGG_WIDTH
         if op.kind == "gat":
             # projection matmul + per-edge score/softmax passes on top of
-            # the aggregation sweep
-            flops = 2.0 * rows * in_dim * out_dim
-            t = 2.0 * t + flops / PEAK_FLOPS
+            # the aggregation sweep; dot scores project four times (q, k,
+            # v at the attention heads' width, the skip at the output's)
+            proj, t = out_dim, 2.0 * t
+            if attention_score(op) == "dot":
+                proj = attention_heads(op) * op.attrs["head_dim"]
+                t *= proj / max(out_dim, 1)     # sweeps at the heads' width
+                proj = 3 * proj + out_dim
+            t += 2.0 * rows * in_dim * proj / PEAK_FLOPS
         return t
     # elementwise: read input, write output (+ one op in between)
     return 4.0 * rows * (in_dim + 2 * out_dim) / PEAK_BW
@@ -135,7 +142,8 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
             out_dim = dims[op.out]
             out_bytes = rows * out_dim * itemsize
             t = _op_forward_s(op, in_dim, out_dim, rows, edges)
-            full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize)
+            full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize) \
+                + dot_table_bytes(op, rows, itemsize)
             fwd += t
             if op.kind in SAVED_KINDS or op.attrs.get("ckpt_boundary"):
                 saved += out_bytes
@@ -161,7 +169,8 @@ def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     """Per-EDGE bytes a gat op keeps from forward to backward on the plan
     attention path (ops.edge._gat_plan_fwd): the shifted exponentials
     ``e [K, E]`` at the activation width and the score's sign ``qpos
-    [K, E]`` bool.  Both carry edges on the lane axis, so these are the
+    [K, E]`` bool (a dot-score op keeps ``e`` alone).  Both carry edges on
+    the lane axis, so these are the
     bytes the device holds (the old [E, K] layout held 16 x as much at
     K = 8: 128 lanes a row); the attention-dropout mask is redrawn, not
     kept.  They live inside the custom VJP: an all-KEEP step holds them
@@ -170,7 +179,21 @@ def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     dense xla path (small graphs) lets autodiff keep more than this."""
     if op.kind != "gat":
         return 0
-    return int(op.attrs["heads"]) * int(edges) * (itemsize + 1)
+    # a dot-product score has no sign to keep: e alone
+    # (ops.edge._tconv_plan_fwd)
+    sign = 0 if attention_score(op) == "dot" else 1
+    return attention_heads(op) * int(edges) * (itemsize + sign)
+
+
+def dot_table_bytes(op, rows: int, itemsize: int = 4) -> int:
+    """What a dot-score gat op's custom VJP holds besides ``e`` and the
+    op's output: its three node tables ``q, k, v`` [rows, attention heads x
+    head_dim] (an additive op's one table is its own projection, as wide as
+    its output and counted with it).  0 for any other op."""
+    if attention_score(op) != "dot":
+        return 0
+    return 3 * int(rows) * attention_heads(op) * int(op.attrs["head_dim"]) \
+        * itemsize
 
 
 def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
@@ -182,9 +205,16 @@ def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
     for op in model.ops:
         if op.kind == "linear":
             params += op.attrs["in_dim"] * op.attrs["out_dim"]
+        elif attention_score(op) == "dot":
+            # Wq, Wk, Wv, Wr with their biases, and the gate's 3 x out
+            out = op.attrs["heads"] * op.attrs["head_dim"]
+            kf = attention_heads(op) * op.attrs["head_dim"]
+            params += (op.attrs["in_dim"] + 1) * (3 * kf + out) + 3 * out
         elif op.kind == "gat":
             kf = op.attrs["heads"] * op.attrs["head_dim"]
             params += op.attrs["in_dim"] * kf + 2 * kf
+        elif op.kind == "layernorm":
+            params += 2 * op.attrs["dim"]
     node = rows * (in_dim * itemsize + num_classes * 4 + 4 + 4)
     edge = edges * 2 * 4
     return int(4 * params * 4 + node + edge)

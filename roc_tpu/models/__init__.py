@@ -3,6 +3,7 @@ from roc_tpu.models.gcn import build_gcn
 from roc_tpu.models.sage import build_sage
 from roc_tpu.models.gin import build_gin
 from roc_tpu.models.gat import build_gat
+from roc_tpu.models.tconv import build_tconv
 
 
 def build_model(name: str, layers, dropout_rate: float = 0.5,
@@ -11,7 +12,8 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
 
     aggr="" means "the model's own default" (gcn: sum — the reference's only
     wired AggrType; sage: avg; gin: sum, where a non-sum choice is rejected
-    because the GIN update is defined on sums).  heads only applies to gat."""
+    because the GIN update is defined on sums).  heads only applies to gat
+    and tconv."""
     if name == "gcn":
         return build_gcn(layers, dropout_rate, aggr or "sum")
     if name == "sage":
@@ -22,8 +24,10 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
         return build_gin(layers, dropout_rate)
     if name == "gat":
         return build_gat(layers, dropout_rate, heads=heads)
-    raise ValueError(f"unknown model {name!r} (gcn|sage|gin|gat)")
+    if name == "tconv":
+        return build_tconv(layers, dropout_rate, heads=heads)
+    raise ValueError(f"unknown model {name!r} (gcn|sage|gin|gat|tconv)")
 
 
 __all__ = ["Model", "GraphCtx", "build_gcn", "build_sage", "build_gin",
-           "build_gat", "build_model"]
+           "build_gat", "build_tconv", "build_model"]

@@ -51,6 +51,10 @@ class GraphCtx(NamedTuple):
     # normalised coefficients are dropped per edge and head
     # (ops.edge.attention_keep).
     attend: Optional[Callable] = None
+    # dot-product attention aggregation (a gat op with score "dot"): (q
+    # [N,K,F], k [N,K,F], v [N,K,F], drop) -> [N, K, F], ``drop`` as above.
+    # Only the one-chip trainer builds it; the other roads refuse the op.
+    attend_dot: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,14 +66,42 @@ class TensorRef:
 
 @dataclasses.dataclass(frozen=True)
 class OpNode:
-    kind: str                 # dropout|linear|norm|aggregate|activation|add
+    kind: str                 # dropout|linear|norm|aggregate|gat|layernorm|
+                              # activation|add
     inputs: tuple             # input tensor ids
     out: int                  # output tensor id
     attrs: dict               # op-specific attributes
 
 
+def attention_score(op: "OpNode") -> Optional[str]:
+    """How an op scores an in-edge: "additive" (GAT's rank-one ``a_dst . h_i
+    + a_src . h_j``, ``GraphCtx.attend``), "dot" (the graph transformer's
+    ``q_i . k_j / sqrt(d)``, ``GraphCtx.attend_dot``); None for an op that
+    is no attention.  Both are ops of kind "gat"; only the one-chip trainer
+    carries "dot"."""
+    return op.attrs.get("score", "additive") if op.kind == "gat" else None
+
+
+def attention_heads(op: "OpNode") -> int:
+    """Attention heads of a gat op, the rows of its ``[K, E]`` arrays: its
+    ``heads`` output groups times the ``mean_heads`` averaged in each."""
+    return int(op.attrs["heads"]) * int(op.attrs.get("mean_heads", 1))
+
+
+def refuse_dot_attention(model: "Model", road: str) -> None:
+    """Only the one-chip Trainer carries a dot-score gat op (``-model
+    tconv``): every other road says so by name when it is built, where
+    running on would treat it as the additive op it is not."""
+    if any(attention_score(op) == "dot" for op in model.ops):
+        raise ValueError(
+            f"-model tconv: the tconv op (dot-product attention, "
+            f"ops.edge.tconv_attend_plan) is not carried by {road}; it "
+            f"trains on the one-chip Trainer (-parts 1, no -stream)")
+
+
 def attention_drop(op: "OpNode", key, train: bool):
-    """The ``drop`` argument of ``GraphCtx.attend`` for one gat op in one
+    """The ``drop`` argument of ``GraphCtx.attend`` / ``attend_dot`` for one
+    gat op (either score) in one
     step: (the step's key folded with the op's dropout slot, the rate) in
     training when the op drops coefficients, else None.  The one place the
     key is derived, so a test can ask ops.edge.attention_keep for the very
@@ -169,6 +201,38 @@ class Model:
         self.num_linear += 1
         return out
 
+    def tconv(self, t: TensorRef, head_dim: int, heads: int = 1,
+              mean_heads: int = 1, attn_drop: float = 0.0) -> TensorRef:
+        """Graph Transformer operator with gated residual (Shi et al.,
+        UniMP, arXiv:2009.03509 eqs 3-5; PyG ``TransformerConv(heads,
+        concat, beta=True, dropout)``): biased projections to queries, keys
+        and values, multi-head dot-product attention over in-edges, and a
+        learned per-node gate between the attention output and a biased
+        linear skip of the input.  A gat op whose ``score`` is "dot"
+        (:func:`attention_score`).  The output concatenates ``heads`` groups
+        of width ``head_dim``, each the MEAN of ``mean_heads`` attention
+        heads: PyG's ``concat=True`` is (heads, 1), ``concat=False`` (1,
+        heads).  ``attn_drop`` as for :meth:`gat`."""
+        out = self._new(head_dim * heads)
+        attrs = {"in_dim": t.dim, "head_dim": head_dim, "heads": heads,
+                 "mean_heads": mean_heads, "score": "dot",
+                 "attn_drop": attn_drop,
+                 "param": f"tconv_{self.num_linear}"}
+        if attn_drop:
+            attrs["slot"] = self.num_dropout
+            self.num_dropout += 1
+        self._emit(OpNode("gat", (t.id,), out.id, attrs))
+        self.num_linear += 1
+        return out
+
+    def layer_norm(self, t: TensorRef) -> TensorRef:
+        """Row LayerNorm with gain and bias (ops.layer_norm)."""
+        out = self._new(t.dim)
+        n = sum(op.kind == "layernorm" for op in self.ops)
+        self._emit(OpNode("layernorm", (t.id,), out.id,
+                          {"dim": t.dim, "param": f"ln_{n}"}))
+        return out
+
     def relu(self, t: TensorRef) -> TensorRef:
         return self._activation(t, "relu")
 
@@ -209,6 +273,20 @@ class Model:
                 params[op.attrs["param"]] = ops.glorot_uniform(
                     k, op.attrs["in_dim"], op.attrs["out_dim"])
                 i += 1
+            elif attention_score(op) == "dot":
+                # Glorot weights, zero biases (the paper states neither)
+                name, k = op.attrs["param"], jax.random.fold_in(key, i)
+                out = op.attrs["heads"] * op.attrs["head_dim"]
+                proj = attention_heads(op) * op.attrs["head_dim"]
+                widths = {"q": proj, "k": proj, "v": proj, "r": out}
+                for j, (s, width) in enumerate(widths.items()):
+                    params[f"{name}_w{s}"] = ops.glorot_uniform(
+                        jax.random.fold_in(k, j + 1), op.attrs["in_dim"],
+                        width)
+                    params[f"{name}_b{s}"] = jnp.zeros((width,), jnp.float32)
+                params[name + "_wg"] = ops.glorot_uniform(
+                    jax.random.fold_in(k, 5), 3 * out, 1)[:, 0]
+                i += 1
             elif op.kind == "gat":
                 name = op.attrs["param"]
                 kk, fd = op.attrs["heads"], op.attrs["head_dim"]
@@ -220,13 +298,19 @@ class Model:
                     params[name + suff] = ops.glorot_uniform(
                         ka, kk * fd, 1).reshape(kk, fd)
                 i += 1
+            elif op.kind == "layernorm":
+                name = op.attrs["param"]
+                params[name + "_gain"] = jnp.ones((op.attrs["dim"],),
+                                                  jnp.float32)
+                params[name + "_bias"] = jnp.zeros((op.attrs["dim"],),
+                                                   jnp.float32)
         return params
 
     def keep_masks(self, key, num_nodes: int, num_edges: int) -> dict:
         """The keep masks a training step with ``key`` draws on one device,
         by op index: [N, d] bool for a dropout op, [K, E] bool (in-edges in
-        CSR order) for a gat op that drops coefficients.  Drawn through the
-        very functions the step calls (ops.dropout_keep,
+        CSR order) for a gat op (either score) that drops coefficients.  Drawn
+        through the very functions the step calls (ops.dropout_keep,
         ops.edge.attention_keep) from the same folded keys: a test compares
         training-mode arithmetic with a reference that is GIVEN the masks."""
         from roc_tpu.memory.estimator import _op_out_dims
@@ -238,7 +322,7 @@ class Model:
                 drop = attention_drop(op, key, True)
                 if drop is not None:
                     masks[idx] = attention_keep(
-                        drop[0], drop[1], op.attrs["heads"], num_edges)
+                        drop[0], drop[1], attention_heads(op), num_edges)
             elif op.kind == "dropout" and op.attrs["rate"]:
                 masks[idx] = dropout_keep(
                     jax.random.fold_in(key, op.attrs["slot"]),
@@ -273,6 +357,9 @@ class Model:
                 out = ops.indegree_norm(a, gctx.in_degree)
             elif op.kind == "aggregate":
                 out = gctx.aggregate(a, op.attrs["aggr"])
+            elif attention_score(op) == "dot":
+                out = self._apply_tconv(op, params, a, gctx,
+                                        attention_drop(op, key, train))
             elif op.kind == "gat":
                 assert gctx.attend is not None, \
                     "this GraphCtx was built without attention support"
@@ -283,6 +370,10 @@ class Model:
                                   params[name + "_adst"], op.attrs["slope"],
                                   attention_drop(op, key, train)
                                   ).reshape(-1, kk * fd)
+            elif op.kind == "layernorm":
+                name = op.attrs["param"]
+                out = ops.layer_norm(a, params[name + "_gain"],
+                                     params[name + "_bias"])
             elif op.kind == "activation":
                 out = ops.apply_activation(a, op.attrs["mode"])
             elif op.kind == "add":
@@ -294,6 +385,36 @@ class Model:
             vals[op.out] = out
         assert self.logits is not None, "call softmax_cross_entropy() last"
         return vals[self.logits.id]
+
+    @staticmethod
+    def _apply_tconv(op: OpNode, params, x, gctx: GraphCtx, drop):
+        """One dot-score gat op (builder docstring: :meth:`tconv`): m =
+        attention over in-edges of the projected rows; r = x Wr + br; b =
+        sigmoid(wg . [m ; r ; m - r]); out = (1 - b) m + b r.  The gate's
+        products are float32 at "highest", like the scores."""
+        assert gctx.attend_dot is not None, \
+            "this GraphCtx was built without dot-product attention support"
+        name = op.attrs["param"]
+        kk, fd = attention_heads(op), op.attrs["head_dim"]
+        groups, per = op.attrs["heads"], op.attrs.get("mean_heads", 1)
+
+        def proj(s):
+            return ops.linear(x, params[f"{name}_w{s}"]) \
+                + params[f"{name}_b{s}"].astype(x.dtype)
+
+        m = gctx.attend_dot(*(proj(s).reshape(-1, kk, fd) for s in "qkv"),
+                            drop)
+        if per > 1:     # average within a group, then concatenate groups
+            m = jnp.mean(m.reshape(-1, groups, per, fd), axis=2)
+        m = m.reshape(-1, groups * fd)
+        r = proj("r")
+        wm, wr, wd = jnp.split(params[name + "_wg"].astype(jnp.float32), 3)
+        m32, r32 = m.astype(jnp.float32), r.astype(jnp.float32)
+        gate = jax.nn.sigmoid(
+            jnp.dot(m32, wm, precision="highest")
+            + jnp.dot(r32, wr, precision="highest")
+            + jnp.dot(m32 - r32, wd, precision="highest"))[:, None]
+        return ((1.0 - gate) * m32 + gate * r32).astype(x.dtype)
 
     def loss(self, params, x, labels, mask, gctx, key=None,
              train: bool = True):

@@ -72,6 +72,17 @@ def exchange_line(info: dict) -> str:
     return f"# exchange: {fields} ({info.get('agg_backend_reason', '?')})"
 
 
+def attention_line(info: dict) -> str:
+    """An attention model's start-up line from its `attention` record
+    (BaseTrainer._announce_attention): every field as ``key=value`` in the
+    record's order, the backend first.  One format for stderr and for this
+    report."""
+    fields = " ".join(
+        f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
+        for k, v in info.items() if k not in ("type", "backend"))
+    return f"# attention: backend={info.get('backend', '?')} {fields}"
+
+
 def summarize_metrics(records: List[dict]) -> List[str]:
     epochs = [r for r in records if r.get("type") == "metrics"]
     alerts = [r for r in records if r.get("type") == "watchdog"]
@@ -98,12 +109,7 @@ def summarize_metrics(records: List[dict]) -> List[str]:
                 lines.append(f"#   final {key} = {last[key]:.6g}")
     for r in records:
         if r.get("type") == "attention":
-            lines.append(
-                f"# attention: backend={r.get('backend', '?')} "
-                f"gat_plan_pad_ratio="
-                f"{r.get('gat_plan_pad_ratio', 0):.4f} gat_score_bytes="
-                f"{r.get('gat_score_bytes', 0)} gat_dst_reads="
-                f"{r.get('gat_dst_reads', '?')}")
+            lines.append(attention_line(r))
         elif r.get("type") == "exchange":
             lines.append(exchange_line(r))
     for r in trains:

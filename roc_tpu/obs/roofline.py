@@ -101,6 +101,17 @@ def model_flops_bytes(model, num_nodes: int, num_edges: int,
             out = int(op.attrs["out_dim"])
             flops += 6.0 * N * a * out
             nbytes += 3.0 * (N * a * b + N * out * b)
+        elif op.kind == "gat" and op.attrs.get("score") == "dot":
+            # four projections; six row sweeps at the attention heads'
+            # width, 2 E D FLOPs each (forward: the score contraction and
+            # the weighted sum; backward: the contraction for de, then dq,
+            # dk and dv)
+            out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
+            # (attrs read here, not models.model's helpers: stdlib-only)
+            proj = out * int(op.attrs.get("mean_heads", 1))
+            flops += 6.0 * N * a * (3 * proj + out) + 6 * 2.0 * E * proj
+            nbytes += 3.0 * (N * a * b + N * (3 * proj + out) * b)
+            nbytes += 6.0 * (E * proj * b + N * proj * b + E * 4)
         elif op.kind == "gat":
             out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
             flops += 6.0 * N * a * out + 4.0 * E * out
@@ -136,6 +147,15 @@ def forward_flops_bytes(model, num_nodes: int, num_edges: int,
             out = int(op.attrs["out_dim"])
             flops += 2.0 * N * a * out
             nbytes += N * a * b + N * out * b
+        elif op.kind == "gat" and op.attrs.get("score") == "dot":
+            # forward: four projections, the score contraction and the
+            # weighted sum (two row sweeps at the attention heads' width)
+            out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
+            # (attrs read here, not models.model's helpers: stdlib-only)
+            proj = out * int(op.attrs.get("mean_heads", 1))
+            flops += 2.0 * N * a * (3 * proj + out) + 2 * 2.0 * E * proj
+            nbytes += N * a * b + N * (3 * proj + out) * b
+            nbytes += 2.0 * (E * proj * b + N * proj * b + E * 4)
         elif op.kind == "gat":
             out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
             flops += 2.0 * N * a * out + 2.0 * E * out

@@ -3,8 +3,9 @@ from roc_tpu.ops.aggregate import (
     divide_by_degree, matmul_precision, pad_binned_plans, pad_plans,
     scatter_gather, scatter_gather_binned, scatter_gather_matmul)
 from roc_tpu.ops.edge import (GatPlans, build_gat_plans, edge_softmax,
-                              gat_attend, gat_attend_plan, pad_gat_plans)
-from roc_tpu.ops.norm import indegree_norm
+                              gat_attend, gat_attend_plan, pad_gat_plans,
+                              tconv_attend, tconv_attend_plan)
+from roc_tpu.ops.norm import indegree_norm, layer_norm
 from roc_tpu.ops.linear import linear
 from roc_tpu.ops.activation import apply_activation, elu, relu, sigmoid
 from roc_tpu.ops.element import add, mul
@@ -19,8 +20,9 @@ __all__ = [
     "BinnedPlans", "build_binned_plans",
     "pad_binned_plans", "matmul_precision", "divide_by_degree",
     "edge_softmax", "gat_attend", "gat_attend_plan",
+    "tconv_attend", "tconv_attend_plan",
     "GatPlans", "build_gat_plans", "pad_gat_plans",
-    "indegree_norm", "linear", "relu", "sigmoid", "elu",
+    "indegree_norm", "layer_norm", "linear", "relu", "sigmoid", "elu",
     "apply_activation", "add",
     "mul", "dropout", "PerfMetrics", "masked_softmax_cross_entropy",
     "perf_metrics", "glorot_uniform",

@@ -14,6 +14,7 @@ pad rows get degree 1) and make this a fused broadcast-multiply.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
 def indegree_norm(x, in_degree):
@@ -23,3 +24,19 @@ def indegree_norm(x, in_degree):
     (self-edges on real nodes, explicit 1.0 on pad rows).
     """
     return x * jax.lax.rsqrt(in_degree)[:, None]
+
+
+LAYER_NORM_EPS = 1e-5
+
+
+def layer_norm(x, gain, bias, eps: float = LAYER_NORM_EPS):
+    """Row LayerNorm with parameters (Ba et al. 2016): every row of ``x``
+    [N, H] is centred and scaled by its own mean and (biased) variance over
+    the H features, then ``* gain + bias`` ([H] each).  The reference has
+    no such op; the graph transformer's hidden layers need it
+    (models/tconv.py).  Statistics in float32 whatever ``x`` is."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    xc = xf - mean
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * gain + bias).astype(x.dtype)

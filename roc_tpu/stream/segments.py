@@ -20,7 +20,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
-from roc_tpu.models.model import Model, OpNode, attention_drop
+from roc_tpu.models.model import (Model, OpNode, attention_drop,
+                                  refuse_dot_attention)
 from roc_tpu.memory.estimator import _op_out_dims
 from roc_tpu import ops
 
@@ -51,6 +52,11 @@ class Segment:
 
 
 def split_segments(model: Model) -> List[Segment]:
+    # a dot-score gat head would need three tables a segment (q by
+    # destination, k and v by source) where an additive one has one: say so,
+    # do not stream it as the op it is not
+    refuse_dot_attention(model, "the streamed executor (-stream, "
+                                "stream/segments.py _HEAD_KINDS)")
     ops_list = list(model.ops)
     dims = _op_out_dims(model)
     head_pos = [i for i, op in enumerate(ops_list) if op.kind in _HEAD_KINDS]

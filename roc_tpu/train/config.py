@@ -60,7 +60,7 @@ class Config:
     seed: int = 1
     num_parts: int = 1            # total shards (== mesh size when > 1)
     model: str = "gcn"            # gcn | sage | gin | gat
-    heads: int = 8                # attention heads (gat only)
+    heads: int = 8                # attention heads (gat, tconv)
     aggr: str = ""                # "" = model default; sum|avg|max|min
     aggregate_backend: str = "auto"  # auto | xla | matmul | pallas(=binned) | binned
     aggregate_precision: str = "fast"  # fast (default): features take one
@@ -371,7 +371,7 @@ def parse_args(argv: List[str]) -> Config:
     p.add_argument("-parts", "-ng", "-ll:gpu", dest="num_parts", type=int,
                    default=1)
     p.add_argument("-model", default="gcn",
-                   choices=["gcn", "sage", "gin", "gat"])
+                   choices=["gcn", "sage", "gin", "gat", "tconv"])
     p.add_argument("-heads", type=int, default=8)
     p.add_argument("-aggr", default="",
                    choices=["", "sum", "avg", "max", "min"])

@@ -28,7 +28,8 @@ from roc_tpu import fault, obs, ops
 from roc_tpu.analysis import retrace as _retrace
 from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
-from roc_tpu.models.model import GraphCtx, Model
+from roc_tpu.models.model import (GraphCtx, Model, attention_heads,
+                                  attention_score)
 from roc_tpu.ops.softmax import format_metrics
 from roc_tpu.optim.adam import Adam
 from roc_tpu.train.config import Config
@@ -144,8 +145,24 @@ def model_aggrs(model: Model) -> set:
     return {op.attrs["aggr"] for op in model.ops if op.kind == "aggregate"}
 
 
-def model_has_gat(model: Model) -> bool:
-    return any(op.kind == "gat" for op in model.ops)
+def attention_kind(model: Model) -> Optional[str]:
+    """What the trainer calls the model's attention ops, both gat ops of
+    the IR: "gat" (additive scores) or "tconv" (dot-product scores,
+    models.model.attention_score; a builder uses one), None without any."""
+    scores = {attention_score(op) for op in model.ops} - {None}
+    return "tconv" if "dot" in scores else "gat" if scores else None
+
+
+def model_has_attention(model: Model) -> bool:
+    """Whether the model attends over in-edges (either kind): what decides
+    the attention backend and whether GatPlans are built."""
+    return attention_kind(model) is not None
+
+
+# row-gathering passes over the plans a TRAINING step makes per tconv op on
+# the plan road: the score contraction and the weighted sum forward, the
+# contraction for de, dq, dk and dv backward (ops.edge.tconv_attend_plan)
+TCONV_ROW_PASSES = 6
 
 
 def effective_backend_why(config: Config, dataset: Dataset, model: Model,
@@ -184,8 +201,9 @@ def effective_backend_why(config: Config, dataset: Dataset, model: Model,
                                        g.num_nodes, g.num_nodes)
     aggrs = model_aggrs(model)
     if backend in ("binned", "matmul") and not ({"sum", "avg"} & aggrs):
-        if cfg.aggregate_backend != "auto" and not model_has_gat(model):
-            # (a GAT model honors the choice through the attention
+        if cfg.aggregate_backend != "auto" \
+                and not model_has_attention(model):
+            # (an attention model honors the choice through the attention
             # plan backend instead — effective_gat_backend)
             print(f"# aggregate_backend={backend} only accelerates "
                   f"sum/avg aggregation; this model uses "
@@ -201,8 +219,9 @@ def effective_backend(config: Config, dataset: Dataset, model: Model,
 
 def effective_gat_backend(config: Config, dataset: Dataset,
                           model: Model) -> str:
-    """Attention backend for models with gat ops ("plan" | "xla")."""
-    if not model_has_gat(model):
+    """Attention backend for models with gat or tconv ops ("plan" |
+    "xla"); both kinds ride the same GatPlans."""
+    if not model_has_attention(model):
         return "xla"
     return resolve_gat_backend(config.aggregate_backend,
                                dataset.graph.num_edges)
@@ -235,7 +254,10 @@ def dense_graph_data(graph, backend: str = "xla",
                      precision: str = "exact",
                      gat_backend: str = "xla",
                      storage_dtype: str = "fp32",
-                     autotune: bool = False) -> DenseGraphData:
+                     autotune: bool = False,
+                     attention: str = "gat") -> DenseGraphData:
+    """``attention``: the op kind the attention plans serve (what the
+    ``gat_plan_build`` span says; the plans are the same for both)."""
     if autotune:
         maybe_autotune(graph.col_idx, graph.dst_idx, graph.num_nodes,
                        graph.num_nodes, storage_dtype=storage_dtype)
@@ -254,7 +276,8 @@ def dense_graph_data(graph, backend: str = "xla",
         gat_plans = None
         if gat_backend == "plan":
             from roc_tpu.ops.edge import build_gat_plans
-            with obs.span("gat_plan_build", edges=graph.num_edges) as sp:
+            with obs.span("gat_plan_build", edges=graph.num_edges,
+                          serves=attention) as sp:
                 gat_plans = build_gat_plans(graph.col_idx, graph.dst_idx,
                                             graph.num_nodes, graph.num_nodes)
                 sp.args.update(gat_plan_stats(gat_plans, graph.num_edges))
@@ -299,8 +322,17 @@ def make_gctx(g: DenseGraphData, num_nodes: int) -> GraphCtx:
         return ops.gat_attend(h, h, g.edge_src, g.edge_dst, num_nodes,
                               a_src, a_dst, slope, drop)
 
+    def attend_dot(q, k, v, drop=None):
+        # dot-product scores over the same plans; one table set a device;
+        # float32 at "highest" whatever g.precision (tconv_attend_plan)
+        if g.gat_plans is not None:
+            return ops.tconv_attend_plan(q, k, v, g.gat_plans,
+                                         g.edge_src.shape[0], drop)
+        return ops.tconv_attend(q, k, v, g.edge_src, g.edge_dst, num_nodes,
+                                drop)
+
     return GraphCtx(aggregate=aggregate, in_degree=g.in_degree,
-                    attend=attend)
+                    attend=attend, attend_dot=attend_dot)
 
 
 @dataclasses.dataclass
@@ -436,59 +468,69 @@ class BaseTrainer:
             seed_s=obs.seed_for_graph(g.num_nodes, g.num_edges))
 
     def attention_info(self) -> Optional[dict]:
-        """What this trainer resolved for its gat ops (None: the model has
-        none): the attention backend ("plan": ops.edge.gat_attend_plan or
-        its sharded kin over GatPlans; "xla": the dense / chunked / ring
-        scans), the GatPlans' padding (plan slots / edges) and the bytes
-        of per-edge residuals a train step keeps between forward and
-        backward on the plan path (e float32 + the score's sign, [K, E]
-        each, per gat op; 0 where autodiff keeps what it likes), and how
-        the path reads node tables by ``edge_dst`` ("plan": the aligned
-        dst plan's segment broadcast, ops.edge._plan_broadcast, on every
-        plan path; "gather": by index, the xla scans)."""
-        if not model_has_gat(self.model):
+        """What this trainer resolved for its attention ops (None: the
+        model has none), keyed by what the ``# attention:`` line, the
+        `attention` record and the gauges call it less the op kind's
+        prefix (``gat_`` / ``tconv_``, :func:`attention_kind`):
+
+        ``backend``: "plan" (ops.edge.gat_attend_plan / tconv_attend_plan
+        or the sharded kin over GatPlans) or "xla" (the dense / chunked /
+        ring scans); ``plan_pad_ratio``: the GatPlans' slots over edges;
+        ``score_bytes`` (gat): the per-edge residuals a train step keeps
+        between forward and backward on the plan path (e float32 + the
+        score's sign, [K, E] each, per op; 0 where autodiff keeps what it
+        likes); ``dst_reads`` (gat): how node tables are read by
+        ``edge_dst`` ("plan": the aligned dst plan's segment broadcast;
+        "gather": by index, the xla scans).  A tconv model instead says
+        ``score`` ("dot"), ``score_bytes`` (ONE [K, E] float32 array of
+        its widest op: what each per-edge array live in a layer's backward
+        costs), ``residual_bytes`` (the [K, E] bytes kept for the
+        backward, e of every op) and ``row_passes`` (row-gathering passes
+        over the plans a training step makes: 6 an op)."""
+        kind = attention_kind(self.model)
+        if kind is None:
             return None
         gd = getattr(self, "gdata", None)   # the streamed trainer has none
         plans = getattr(gd, "gat_plans", None)
         plans = getattr(plans, "plans", plans)      # EdgeGatPlans wraps one
-        backend = "plan" if plans is not None else "xla"
-        info = {"backend": backend, "plan_pad_ratio": 0.0, "score_bytes": 0,
-                "dst_reads": "plan" if plans is not None else "gather"}
-        if plans is not None:
-            edges = int(gd.edge_src.shape[-1])      # per shard when sharded
-            info["plan_pad_ratio"] = gat_plan_stats(plans, edges)["pad_ratio"]
-            info["score_bytes"] = sum(
-                int(op.attrs["heads"]) * edges * (4 + 1)
-                for op in self.model.ops if op.kind == "gat")
+        on_plan = plans is not None
+        edges = int(gd.edge_src.shape[-1]) if on_plan else 0    # per shard
+        heads = [attention_heads(op) for op in self.model.ops
+                 if op.kind == "gat"]
+        info = {"backend": "plan" if on_plan else "xla",
+                "plan_pad_ratio": gat_plan_stats(plans, edges)["pad_ratio"]
+                if on_plan else 0.0}
+        if kind == "gat":
+            info["score_bytes"] = sum(heads) * edges * (4 + 1)
+            info["dst_reads"] = "plan" if on_plan else "gather"
+        else:
+            info.update(score="dot", score_bytes=max(heads) * edges * 4,
+                        residual_bytes=sum(heads) * edges * 4,
+                        row_passes=TCONV_ROW_PASSES * len(heads))
         return info
 
     def _announce_attention(self):
-        """The trainer's own start-up line for a gat model, and the same
-        facts as `attention` record + gauges under -obs."""
+        """The trainer's own start-up line for an attention model, and the
+        same facts as `attention` record + gauges under -obs: numbers as
+        unlabelled gauges, texts as labelled ones, every name prefixed
+        with the op kind."""
         info = self.attention_info()
         if info is None:
             return
-        # the second field is fixed text that
-        # tests/benchmark/test_benchmark_gat_cell.py:212 reads (a file of
-        # the benchmark's; ROADMAP Queue 1 item 0 shortens its assertion)
-        print(f"# attention: backend={info['backend']} "
-              f"gat_fused=False (no -megafuse) "
-              f"gat_plan_pad_ratio={info['plan_pad_ratio']:.4f} "
-              f"gat_score_bytes={info['score_bytes']} "
-              f"gat_dst_reads={info['dst_reads']}", file=sys.stderr,
-              flush=True)
+        kind = attention_kind(self.model)
+        backend = info.pop("backend")
+        record = {"backend": backend,
+                  **{f"{kind}_{k}": v for k, v in info.items()}}
+        from roc_tpu.obs.report import attention_line
+        print(attention_line(record), file=sys.stderr, flush=True)
         if self._metrics is not None:
-            self._metrics.emit(
-                "attention", backend=info["backend"],
-                gat_plan_pad_ratio=info["plan_pad_ratio"],
-                gat_score_bytes=info["score_bytes"],
-                gat_dst_reads=info["dst_reads"])
-            for name in ("gat_plan_pad_ratio", "gat_score_bytes"):
-                self._metrics.set_gauge(name, info[name[4:]])
-            self._metrics.set_gauge("gat_backend", 1.0,
-                                    backend=info["backend"])
-            self._metrics.set_gauge("gat_dst_reads", 1.0,
-                                    dst_reads=info["dst_reads"])
+            self._metrics.emit("attention", **record)
+            self._metrics.set_gauge(f"{kind}_backend", 1.0, backend=backend)
+            for k, v in info.items():
+                if isinstance(v, str):
+                    self._metrics.set_gauge(f"{kind}_{k}", 1.0, **{k: v})
+                else:
+                    self._metrics.set_gauge(f"{kind}_{k}", v)
 
     def _obs_epoch(self, epoch: int, wall_s: float, loss, print_fn):
         """Per-epoch drain: fetch the in-graph metrics pytree (ONE
@@ -989,7 +1031,8 @@ class Trainer(BaseTrainer):
             ds.graph, backend, self.config.aggregate_precision,
             gat_backend=self._gat_backend(),
             storage_dtype="bf16" if self.config.bf16_storage else "fp32",
-            autotune=self.config.autotune)
+            autotune=self.config.autotune,
+            attention=attention_kind(model) or "gat")
         with obs.span("place_data", what="nodes"):
             self.x = jnp.asarray(ds.features, self.dtype)
             self.labels = jnp.asarray(ds.onehot_labels(), jnp.float32)

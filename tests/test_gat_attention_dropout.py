@@ -110,15 +110,18 @@ def test_keep_rate_is_one_minus_p():
     assert (keep[0] != keep[1]).mean() > 0.3
 
 
-def test_evaluation_is_untouched_by_the_rate():
+@pytest.mark.parametrize("score", ["gat", "tconv"])
+def test_evaluation_is_untouched_by_the_rate(score):
+    """Additive scores (gat) and dot-product scores (tconv) alike."""
+    from roc_tpu.models import build_model
     ds, g = _graph()
     layers = [ds.in_dim, 4, ds.num_classes]
-    cfg = dict(layers=layers, eval_every=10**9, model="gat", heads=2,
+    cfg = dict(layers=layers, eval_every=10**9, model=score, heads=2,
                aggregate_backend="matmul", weight_decay=0.0)
     dropped = Trainer(Config(dropout_rate=RATE, **cfg), ds,
-                      build_gat(layers, RATE, heads=2))
+                      build_model(score, layers, RATE, heads=2))
     plain = Trainer(Config(dropout_rate=0.0, **cfg), ds,
-                    build_gat(layers, 0.0, heads=2))
+                    build_model(score, layers, 0.0, heads=2))
     assert dropped.gdata.gat_plans is not None
     np.testing.assert_array_equal(np.asarray(dropped.predict_logits()),
                                   np.asarray(plain.predict_logits()))

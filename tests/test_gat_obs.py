@@ -75,8 +75,9 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
                 if ln.startswith("# attention:"))
     assert (line.startswith("# attention: backend=plan ")
             and f"gat_score_bytes={info['score_bytes']}" in line)
-    # the old text first, unchanged; the new field after it
-    assert line == ("# attention: backend=plan gat_fused=False (no -megafuse)"
+    # the record's fields in its order (obs.report.attention_line); the
+    # fixed text `gat_fused=False (no -megafuse)` went with PR 33
+    assert line == ("# attention: backend=plan"
                     f" gat_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
                     f" gat_score_bytes={info['score_bytes']}"
                     " gat_dst_reads=plan")

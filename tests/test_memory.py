@@ -113,9 +113,11 @@ def _brute_force(est, budget):
      2145141720),
     ("gat-reddit", dict(layers=[602, 8, 41], dropout_rate=0.6, heads=8),
      1836352035),
+    ("tconv-reddit", dict(layers=[602, 128, 128, 41], dropout_rate=0.3,
+                          heads=4), 3856353084),
 ])
 def test_estimate_of_the_benchmarks_models_is_pinned(name, kw, total):
-    """The estimator's all-KEEP bytes for the two configurations of
+    """The estimator's all-KEEP bytes for the one-chip configurations of
     BENCHMARK.json at the cells' size (232,965 rows, 23,516,643 edges):
     the figures of the commit before the fused paths and their drops left
     the estimator (PR 27), so a change to what it counts shows here."""

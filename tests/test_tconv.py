@@ -1,0 +1,425 @@
+"""Dot-product attention over in-edges (the tconv op, ops.edge
+tconv_attend / tconv_attend_plan; models/tconv.py): the plan road against a
+dense masked softmax written here, the rows a softmax can trip on, the
+[K, E] layout, what the trainer says about the op, and every road that does
+not carry it refusing it by name."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from roc_tpu import obs, ops
+from roc_tpu.graph import datasets
+from roc_tpu.models import build_model, build_tconv
+from roc_tpu.models.model import Model, attention_heads, attention_score
+from roc_tpu.ops import edge as em
+from roc_tpu.ops.pallas.segment_sum import EB
+from roc_tpu.train.config import Config, parse_args
+from roc_tpu.train.driver import Trainer, make_trainer
+
+from test_gat_plans import _edges, _sub_jaxprs
+
+
+# -- the plan road against softmax(Q K^T / sqrt(d) + mask) V ----------------
+
+def _dense_attention(q, k, v, src, dst, rows):
+    """softmax(Q K^T / sqrt(d) + mask) V per head, float64 NumPy: the mask
+    is 0 where j -> i is an in-edge (once per copy of the edge: a multigraph
+    counts multiplicity) and -inf elsewhere; a row with no in-edge gives
+    zeros."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    n, heads, d = q.shape
+    count = np.zeros((rows, k.shape[0]))
+    np.add.at(count, (dst, src), 1.0)
+    out = np.zeros((n, heads, d))
+    for c in range(heads):
+        s = q[:, c] @ k[:, c].T / np.sqrt(d)
+        s = np.where(count > 0, s, -np.inf)
+        m = np.where(np.isfinite(s.max(1)), s.max(1), 0.0)
+        e = np.exp(s - m[:, None]) * count
+        z = e.sum(1)
+        out[:, c] = (e / np.where(z > 0, z, 1.0)[:, None]) @ v[:, c]
+    return out
+
+
+def _qkv(rows, heads, d, seed):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((rows, heads, d)),
+                             jnp.float32) for _ in range(3))
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["regular", "hub"])
+@pytest.mark.parametrize("road", ["xla", "plan"])
+def test_attention_against_a_dense_masked_softmax(road, kind, heads):
+    """Values within 64 ulps of the output's scale (a hub of 3,000 in-edges
+    included; rows without in-edges exact zeros)."""
+    src, dst, rows = _edges(kind, seed=5)
+    q, k, v = _qkv(rows, heads, 8, heads)
+    if road == "plan":
+        plans = em.build_gat_plans(src, dst, rows, rows)
+        got = em.tconv_attend_plan(q, k, v, plans, dst.size)
+    else:
+        got = em.tconv_attend(q, k, v, jnp.asarray(src), jnp.asarray(dst),
+                              rows)
+    want = _dense_attention(q, k, v, src, dst, rows)
+    got = np.asarray(got)
+    assert not got[np.setdiff1d(np.arange(rows), np.unique(dst))].any()
+    scale = np.abs(want).max() * np.finfo(np.float32).eps
+    assert np.abs(got - want).max() <= 64 * scale
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+@pytest.mark.parametrize("heads", [1, 4])
+def test_the_hand_derived_backward_against_autodiff(heads, dropout):
+    """dq, dk, dv of the plan road (three weighted row sums over the dst and
+    src plans, the mask redrawn from the key) against jax.grad of the xla
+    road given the same key."""
+    src, dst, rows = _edges("hub", seed=6)
+    q, k, v = _qkv(rows, heads, 8, 10 + heads)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    sj, dj = jnp.asarray(src), jnp.asarray(dst)
+    drop = (jax.random.PRNGKey(3), dropout) if dropout else None
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a)))
+
+    got = jax.grad(loss(lambda *a: em.tconv_attend_plan(
+        *a, plans, dst.size, drop)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: em.tconv_attend(
+        *a, sj, dj, rows, drop)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) / np.linalg.norm(b) <= 2e-5, name
+
+
+def test_one_in_edge_and_a_padded_row_with_none():
+    """A destination with ONE in-edge copies that source's value (its
+    coefficient is 1 whatever the score, its score's gradient 0); a row
+    with none (a padded shard row) reads zeros and hands finite zeros back
+    (z = 0 meets _Z_GUARD, not 0 / 0)."""
+    rows = 40
+    src = np.array([5, 7, 9, 9, 3], np.int64)
+    dst = np.array([2, 4, 4, 4, 11], np.int64)      # 2 and 11: one in-edge
+    q, k, v = _qkv(rows, 2, 8, 1)
+    q = q * 30.0                                    # scores far from 0
+    plans = em.build_gat_plans(src, dst, rows, rows)
+
+    def out(q_, k_, v_):
+        return em.tconv_attend_plan(q_, k_, v_, plans, dst.size)
+
+    got = np.asarray(out(q, k, v))
+    np.testing.assert_array_equal(got[2], np.asarray(v)[5])
+    np.testing.assert_array_equal(got[11], np.asarray(v)[3])
+    assert not got[[0, 1, 3, 39]].any()
+    grads = jax.grad(lambda *a: jnp.sum(out(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+    dq, dk, _ = (np.asarray(g) for g in grads)
+    assert not dq[[2, 11]].any() and not dk[[5, 3]].any()
+    assert dq[4].any()          # three in-edges: the scores matter there
+
+
+# -- layout: [K, E], edges on the lane axis ---------------------------------
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in _sub_jaxprs(p):
+                yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
+        dropout, monkeypatch):
+    """jax.grad of tconv_attend_plan, forward and hand-derived backward:
+    the road takes no edge list at all (the plans hold every index), no
+    gather's indices are as long as the edge list (each is one scan step's
+    slots), no scatter exists (no gather was transposed), and every
+    edge-sized intermediate is [K, E]: never the heads, nor a feature row,
+    on the lane axis of an edge-sized array."""
+    for name, cb in (("_PLAN_CB_BLOCKS", 8), ("_PLAN_CB_SUM", 16),
+                     ("_PLAN_CB_MAX", 16)):
+        monkeypatch.setattr(em, name, cb)
+    monkeypatch.setattr(em, "_LANE_GATHER_CHUNK", 4096)
+    src, dst, rows = _edges("hub", seed=6)
+    K, F, E = 4, 16, dst.size           # no other axis of the road is 4 long
+    step_slots = 16 * EB
+    assert E > 2 * step_slots > 2 * rows
+    q, k, v = _qkv(rows, K, F, 0)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    drop = (jax.random.PRNGKey(5), dropout) if dropout else None
+
+    def loss(*a):
+        return jnp.sum(em.tconv_attend_plan(*a, plans, E, drop) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    shapes, gathers = [], []
+    for eqn in _eqns(jaxpr):
+        assert not eqn.primitive.name.startswith("scatter"), str(eqn)[:200]
+        if eqn.primitive.name == "gather":
+            gathers.append(int(np.prod(eqn.invars[1].aval.shape[:-1])))
+        shapes += [tuple(o.aval.shape) for o in eqn.outvars
+                   if getattr(o.aval, "shape", None) is not None]
+    assert gathers and max(gathers) <= step_slots
+    # s, e, the mask, de, ds, the broadcasts ...
+    assert sum(1 for s in shapes if s == (K, E)) >= 8
+    heads_last = [s for s in shapes if len(s) >= 2 and s[-1] == K
+                  and int(np.prod(s[:-1])) >= step_slots]
+    assert not heads_last, heads_last[:5]
+    rows_last = [s for s in shapes if len(s) >= 2 and s[-1] == K * F
+                 and int(np.prod(s[:-1])) > step_slots]
+    assert not rows_last, rows_last[:5]
+
+
+# -- the builder and the op IR ----------------------------------------------
+
+def test_build_tconv_reads_layers_as_the_docstring_says():
+    model = build_tconv([602, 128, 128, 41], 0.3, heads=4)
+    kinds = [op.kind for op in model.ops]
+    # the op is a gat op of the IR whose score is a dot product
+    assert kinds == ["dropout", "gat", "layernorm", "activation"] * 2 + [
+        "dropout", "gat"]
+    tconv = [op for op in model.ops if op.kind == "gat"]
+    assert {attention_score(op) for op in model.ops} == {None, "dot"}
+    assert [(op.attrs["heads"], op.attrs["mean_heads"], op.attrs["head_dim"],
+             attention_heads(op)) for op in tconv] == [
+        (4, 1, 32, 4), (4, 1, 32, 4), (1, 4, 41, 4)]
+    # inputs and coefficients dropped at one rate, masks of their own
+    slots = [op.attrs["slot"] for op in model.ops
+             if op.kind in ("dropout", "gat")]
+    assert sorted(slots) == list(range(6))
+    assert all(op.attrs["attn_drop"] == 0.3 for op in tconv)
+    params = model.init_params(jax.random.PRNGKey(0))
+    assert params["tconv_2_wq"].shape == (128, 164)
+    assert params["tconv_2_wr"].shape == (128, 41)
+    assert params["tconv_2_wg"].shape == (123,)
+    assert params["tconv_0_wg"].shape == (384,)
+    assert not np.asarray(params["tconv_1_bq"]).any()
+    assert np.asarray(params["ln_1_gain"]).tolist() == [1.0] * 128
+    assert "ln_2_gain" not in params            # no LayerNorm on the output
+    assert model.logits.dim == 41
+    masks = model.keep_masks(jax.random.PRNGKey(1), 10, 50)
+    assert sorted(v.shape for v in masks.values()) == sorted(
+        [(10, 602), (10, 128), (10, 128)] + [(4, 50)] * 3)
+    with pytest.raises(ValueError, match="not a multiple of heads=3"):
+        build_tconv([602, 128, 41], heads=3)
+    assert [op.kind for op in build_model(
+        "tconv", [8, 4, 3], 0.0, heads=2).ops][:2] == ["dropout", "gat"]
+    assert parse_args(["-model", "tconv", "-layers", "8-4-3"]).model \
+        == "tconv"
+
+
+def test_layer_norm_against_numpy():
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((50, 12)).astype(np.float32) * 3 + 1
+    gain = rng.standard_normal(12).astype(np.float32)
+    bias = rng.standard_normal(12).astype(np.float32)
+    x64 = x.astype(np.float64)
+    want = (x64 - x64.mean(1, keepdims=True)) / np.sqrt(
+        x64.var(1, keepdims=True) + 1e-5) * gain + bias
+    got = np.asarray(ops.layer_norm(jnp.asarray(x), gain, bias))
+    assert np.abs(got - want).max() < 1e-5
+
+
+def test_a_hand_built_model_can_average_heads_anywhere():
+    """Two output groups, each the mean of three heads, against the
+    six-head concatenation averaged by hand."""
+    ds = _dataset()
+    n = ds.graph.num_nodes
+
+    def build(heads, mean_heads):
+        m = Model(in_dim=8)
+        t = m.tconv(m.input, 5, heads=heads, mean_heads=mean_heads)
+        m.end_layer()
+        m.softmax_cross_entropy(t)
+        return m
+
+    grouped, flat = build(2, 3), build(6, 1)
+    assert (grouped.logits.dim, flat.logits.dim) == (10, 30)
+    assert grouped.keep_masks(jax.random.PRNGKey(0), 10, 50) == {}
+    params = flat.init_params(jax.random.PRNGKey(4))
+    from roc_tpu.train.driver import dense_graph_data, make_gctx
+    gctx = make_gctx(dense_graph_data(ds.graph, "xla", "exact"), n)
+    # gate shut: wg = 0 gives b = 1/2; skip off: logits = m / 2
+    params = dict(params, tconv_0_wg=jnp.zeros(90),
+                  tconv_0_wr=jnp.zeros((8, 30)))
+    x = jnp.asarray(ds.features)
+    m_flat = 2 * np.asarray(flat.apply(params, x, gctx))       # [N, 6 x 5]
+    want = m_flat.reshape(n, 2, 3, 5).mean(2).reshape(n, 10)
+    small = dict(params, tconv_0_wg=jnp.zeros(30),
+                 tconv_0_wr=jnp.zeros((8, 10)), tconv_0_br=jnp.zeros(10))
+    got = 2 * np.asarray(grouped.apply(small, x, gctx))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# -- what the trainer says ---------------------------------------------------
+
+def _dataset(n=200):
+    return datasets.synthetic("t", n, 4.0, 8, 4, n_train=30, n_val=30,
+                              n_test=30, seed=3)
+
+
+def _config(ds, **kw):
+    base = dict(layers=[ds.in_dim, 8, 8, ds.num_classes], num_epochs=1,
+                eval_every=10**9, dropout_rate=0.3, model="tconv", heads=2,
+                aggregate_backend="matmul", weight_decay=0.0)
+    base.update(kw)
+    return Config(**base)
+
+
+def _model(cfg):
+    return build_model("tconv", cfg.layers, cfg.dropout_rate, heads=cfg.heads)
+
+
+@pytest.fixture
+def recording():
+    was = obs.enabled()
+    obs.enable(True)
+    obs.get_tracer().clear()
+    yield obs.get_tracer()
+    obs.get_tracer().clear()
+    obs.enable(was)
+
+
+@pytest.mark.parametrize("model,serves", [("gat", "gat"),
+                                          ("tconv", "tconv")])
+def test_gat_plan_build_says_which_op_kind_its_plans_serve(model, serves,
+                                                           recording):
+    ds = _dataset()
+    cfg = _config(ds, model=model, layers=[ds.in_dim, 8, ds.num_classes])
+    Trainer(cfg, ds, build_model(model, cfg.layers, 0.3, heads=2))
+    span, = [s for s in recording.spans() if s.name == "gat_plan_build"]
+    assert span.args["serves"] == serves
+    assert span.args["pad_ratio"] >= 1.0
+
+
+def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
+    ds = _dataset()
+    cfg = _config(ds, obs=True, obs_dir=str(tmp_path / "obs"))
+    tr = Trainer(cfg, ds, _model(cfg))
+    info = tr.attention_info()
+    e = ds.graph.num_edges
+    assert set(info) == {"backend", "plan_pad_ratio", "score", "score_bytes",
+                         "residual_bytes", "row_passes"}
+    assert (info["backend"], info["score"]) == ("plan", "dot")
+    # one [K, E] float32 array; e of each of the three ops; 6 passes an op
+    assert info["score_bytes"] == 2 * e * 4
+    assert info["residual_bytes"] == 3 * 2 * e * 4
+    assert info["row_passes"] == 18
+    line = next(ln for ln in capsys.readouterr().err.splitlines()
+                if ln.startswith("# attention:"))
+    assert line == (
+        "# attention: backend=plan"
+        f" tconv_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
+        f" tconv_score=dot tconv_score_bytes={info['score_bytes']}"
+        f" tconv_residual_bytes={info['residual_bytes']}"
+        " tconv_row_passes=18")
+    tr.train(print_fn=lambda *a, **k: None)
+    recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
+    att, = [r for r in recs if r["type"] == "attention"]
+    assert att["backend"] == "plan" and att["tconv_score"] == "dot"
+    assert att["tconv_residual_bytes"] == info["residual_bytes"]
+    prom = (tmp_path / "obs" / "metrics.prom").read_text()
+    for name in ("plan_pad_ratio", "score_bytes", "residual_bytes",
+                 "row_passes"):
+        assert f"roc_tconv_{name} " in prom
+    assert 'roc_tconv_backend{backend="plan"} 1' in prom
+    assert 'roc_tconv_score{score="dot"} 1' in prom
+    from roc_tpu.obs import report as obs_report
+    text = obs_report.report(str(tmp_path / "obs" / "trace.json"),
+                             str(tmp_path / "obs" / "metrics.jsonl"))
+    assert line in text
+
+
+def test_the_xla_road_keeps_no_plan_residual(capsys):
+    ds = _dataset()
+    cfg = _config(ds, aggregate_backend="xla")
+    tr = Trainer(cfg, ds, _model(cfg))
+    info = tr.attention_info()
+    assert (info["backend"], info["residual_bytes"]) == ("xla", 0)
+    assert tr.gdata.gat_plans is None and tr.gdata.backend == "xla"
+    assert "# attention: backend=xla " in capsys.readouterr().err
+
+
+def test_plan_and_xla_roads_train_to_the_same_losses():
+    """Three epochs with both dropouts on: the same keys drop the same
+    inputs and coefficients on both roads, so the losses agree to float32
+    reassociation, epoch after epoch, and fall."""
+    ds = _dataset(300)
+    losses = {}
+    for backend in ("xla", "matmul"):
+        cfg = _config(ds, aggregate_backend=backend, num_epochs=3,
+                      aggregate_precision="exact", learning_rate=0.01)
+        tr = Trainer(cfg, ds, _model(cfg))
+        losses[backend] = [float(tr.run_epoch()) for _ in range(3)]
+    np.testing.assert_allclose(losses["xla"], losses["matmul"], rtol=2e-5)
+    assert losses["xla"][-1] < losses["xla"][0]
+
+
+def test_checkpoint_round_trip_keeps_every_parameter(tmp_path):
+    from roc_tpu.train import checkpoint
+    ds = _dataset()
+    cfg = _config(ds)
+    tr = Trainer(cfg, ds, _model(cfg))
+    tr.run_epoch()
+    path = str(tmp_path / "ck.npz")
+    tr.save_checkpoint(path)
+    fresh = Trainer(cfg, ds, _model(cfg))
+    loaded = checkpoint.load_params(path, fresh.params)
+    assert set(loaded) == set(tr.params)
+    for name in loaded:
+        np.testing.assert_array_equal(np.asarray(loaded[name]),
+                                      np.asarray(tr.params[name]))
+
+
+# -- roads that do not carry the op say so by name ---------------------------
+
+ROADS = {
+    "spmd-halo": dict(num_parts=4),
+    "spmd-allgather": dict(num_parts=4, exchange="allgather"),
+    "spmd-ring": dict(num_parts=4, exchange="ring"),
+    "spmd-edge-shard": dict(num_parts=4, edge_shard="on"),
+    "spmd-overcommit": dict(num_parts=16),
+    "stream": dict(num_parts=2, stream=True),
+}
+SAYS = {
+    "spmd-halo": r"SpmdTrainer \(-exchange halo, -parts 4\)",
+    "spmd-allgather": r"SpmdTrainer \(-exchange allgather, -parts 4\)",
+    "spmd-ring": r"SpmdTrainer \(-exchange ring, -parts 4\)",
+    "spmd-edge-shard": r"SpmdTrainer \(-edge-shard, -exchange halo",
+    "spmd-overcommit": r"SpmdTrainer \(overcommit, -exchange halo, -parts 16",
+    "stream": r"streamed executor \(-stream, stream/segments.py",
+}
+
+
+@pytest.mark.parametrize("road", sorted(ROADS))
+def test_a_road_without_the_op_refuses_it_by_name(road):
+    ds = _dataset()
+    cfg = _config(ds, **ROADS[road])
+    with pytest.raises(ValueError, match="-model tconv: the tconv op") as e:
+        make_trainer(cfg, ds, _model(cfg))
+    import re
+    assert re.search(SAYS[road], str(e.value)), str(e.value)
+    assert "one-chip Trainer" in str(e.value)
+
+
+def test_the_serving_loader_refuses_it_by_name():
+    from roc_tpu.serve.engine import ServeEngine
+    ds = _dataset()
+    cfg = _config(ds)
+    with pytest.raises(ValueError, match=r"tconv op.*frozen loader behind "
+                                         r"serve/ and fleet/"):
+        ServeEngine(cfg, ds, _model(cfg), start_queue=False)
+
+
+def test_a_gat_model_still_takes_every_road():
+    """The refusal is the tconv op's alone."""
+    ds = _dataset()
+    cfg = _config(ds, model="gat", layers=[ds.in_dim, 8, ds.num_classes],
+                  num_parts=4)
+    tr = make_trainer(cfg, ds, build_model("gat", cfg.layers, 0.3, heads=2))
+    assert np.isfinite(float(tr.run_epoch()))
