@@ -255,7 +255,13 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # broadcast, and the aligned dst-keyed plan holds it (_plan_broadcast;
 # _edge_contract's du rows): no gather of this path takes edge_dst as its
 # index.  The src side still gathers (_take_lanes by edge_src, the
-# src-keyed plan's column reads, the feature rows by the plans' nid).
+# src-keyed plan's column reads, the feature rows by the plans' nid), and
+# pays for each index list ONCE a backward where memory allows: what a layer
+# sums over the src-keyed plan is one scan, its per-edge weights stacked into one [K', E]
+# array read by one column gather (src_pos) and its node tables side by
+# side read by one row gather (src_nid): gat's dast rides dtable's scan
+# (_plan_sum's ``ride``; while the stack fits a tile's sublanes,
+# gat_src_scans), tconv's dk and dv are one sum of 2K heads.
 #
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
 # backward is built from these primitives plus the src side's plain gathers
@@ -504,15 +510,26 @@ def _slot_reader(edge_w, cb: int, aligned: bool):
 
 
 def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
-              aligned: bool = False):
+              aligned: bool = False, ride=None):
     """Segment-sum over plan windows of per-slot values
     ``edge_w[:, pos] (⊗) node_x[nid]`` — the one-hot MXU machinery of
     ops.aggregate._matmul_run generalized to edge-position plans.
 
       edge_w: [K, E] or None;  node_x: [R2, K, F] or None (not both None).
       ``aligned``: the plan is dst-keyed (:func:`_slot_reader`).
+      ``ride``: further [K', E] weights (with both operands above) that
+      ride the SAME scan: ``edge_w`` and ``ride`` are read stacked, one
+      column gather of a [K + K', E] array by one index list, and ``ride``
+      is summed plainly, as a call without ``node_x`` sums.  On a v5e over
+      the Reddit src plan (26.8 M slots; PERF.md PR 34) an index of that
+      gather costs 7.3 / 10.9 / 11.5 / 13.7 / 21.9 ns at 1 / 2 / 4 / 8 / 16
+      rows, so one stacked read beats two at both head counts the GAT cell
+      has (K = 8: 589 ms against 2 x 367; K = 1: the [2, E] lane gather 293
+      against two flat reads of 195; the whole pair 1,010 -> 863 and 715 ->
+      561 ms); the stack's MEMORY decides who rides (:func:`gat_src_scans`).
     Returns [K, num_rows] (node_x None: always summed at "highest") or
-    [num_rows, K, F] (``precision`` feeds the one-hot dots).
+    [num_rows, K, F] (``precision`` feeds the one-hot dots); with ``ride``
+    both, the pair ([num_rows, K, F], [K', num_rows]).
     """
     from roc_tpu.ops.aggregate import _one_hot_dots, _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
@@ -520,47 +537,57 @@ def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
     K = edge_w.shape[0] if edge_w is not None else node_x.shape[1]
     ref = edge_w if edge_w is not None else node_x
-    read = None if edge_w is None else _slot_reader(edge_w, cb, aligned)
+    read = None if edge_w is None else _slot_reader(
+        edge_w if ride is None else jnp.concatenate([edge_w, ride], axis=0),
+        cb, aligned)
     xs = (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
           pos.reshape(nsteps, cb, EB), nid.reshape(nsteps, cb, EB))
 
+    def add_plain(acc, g, ob, ed):
+        # [cb, K', EB] slot values onto the window-indexed [W, K' * VB]
+        # sums: the S1 dot contracts the slot axis directly, so K' never
+        # reaches the lane axis
+        s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
+              == ed[:, None, :]).astype(g.dtype)
+        psum = jax.lax.dot_general(          # [cb, K', VB]
+            g, s1, (((2,), (2,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32)
+        s2 = (jax.lax.broadcasted_iota(jnp.int32, (cb, cb), 0)
+              == (ob - ob[0])[None, :]).astype(g.dtype)
+        outs = jax.lax.dot_general(
+            s2, psum.reshape(cb, g.shape[1] * VB), (((1,), (0,)), ((), ())),
+            precision="highest", preferred_element_type=jnp.float32)
+        cur = jax.lax.dynamic_slice(acc, (ob[0], 0), outs.shape)
+        return jax.lax.dynamic_update_slice(acc, cur + outs, (ob[0], 0))
+
+    def plain_result(acc, heads):
+        out = acc.reshape(acc_windows, heads, VB).transpose(1, 0, 2)
+        return out.reshape(heads, acc_windows * VB)[:, :num_rows].astype(
+            ref.dtype)
+
     if node_x is None:
-        # [K, E] in, [K, rows] out: the S1 dot contracts the slot axis of
-        # [cb, K, EB] directly, so K never reaches the lane axis
+        # [K, E] in, [K, rows] out
         def body_k(acc, sl):
             ob, ed, po, _ = sl
-            g = read(po)                                      # [cb, K, EB]
-            s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
-                  == ed[:, None, :]).astype(g.dtype)
-            psum = jax.lax.dot_general(          # [cb, K, VB]
-                g, s1, (((2,), (2,)), ((0,), (0,))), precision="highest",
-                preferred_element_type=jnp.float32)
-            s2 = (jax.lax.broadcasted_iota(jnp.int32, (cb, cb), 0)
-                  == (ob - ob[0])[None, :]).astype(g.dtype)
-            outs = jax.lax.dot_general(
-                s2, psum.reshape(cb, K * VB), (((1,), (0,)), ((), ())),
-                precision="highest", preferred_element_type=jnp.float32)
-            cur = jax.lax.dynamic_slice(acc, (ob[0], 0), (cb, K * VB))
-            return jax.lax.dynamic_update_slice(acc, cur + outs,
-                                                (ob[0], 0)), None
+            return add_plain(acc, read(po), ob, ed), None     # [cb, K, EB]
 
         acc = _vary_like(jnp.zeros((acc_windows, K * VB), jnp.float32), ref)
         acc, _ = jax.lax.scan(body_k, acc, xs)
-        out = acc.reshape(acc_windows, K, VB).transpose(1, 0, 2)
-        return out.reshape(K, acc_windows * VB)[:, :num_rows].astype(
-            ref.dtype)
+        return plain_result(acc, K)
 
     F = node_x.shape[2]
     H = K * F
     flat = node_x.reshape(node_x.shape[0], H)
     expand = _head_expand(K, F, jnp.float32) if edge_w is not None else None
 
-    def body(acc, sl):
+    def body(carry, sl):
+        acc, acc_k = carry                    # acc_k: None without ``ride``
         ob, ed, po, ni = sl
         g = jnp.take(flat, ni.reshape(cb * EB), axis=0, mode="clip")
         if edge_w is not None:
+            slots = read(po)                  # [cb, K (+ K'), EB]
             g = g * jax.lax.dot_general(          # [cb, EB, K*F]
-                read(po), expand, (((1,), (0,)), ((), ())),
+                slots[:, :K], expand, (((1,), (0,)), ((), ())),
                 precision="highest", preferred_element_type=jnp.float32
             ).astype(g.dtype).reshape(cb * EB, H)
         # one rounding only under `fast`: the products e * h, once, at the
@@ -569,11 +596,17 @@ def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
         outs = _one_hot_dots(g, ed, ob, cb, precision, "highest")
         base = ob[0] * VB
         cur = jax.lax.dynamic_slice(acc, (base, 0), (cb * VB, H))
-        return jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0)), None
+        acc = jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0))
+        if ride is not None:
+            acc_k = add_plain(acc_k, slots[:, K:], ob, ed)
+        return (acc, acc_k), None
 
     acc = _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), ref)
-    acc, _ = jax.lax.scan(body, acc, xs)
-    return acc[:num_rows].astype(ref.dtype).reshape(num_rows, K, F)
+    acc_k = None if ride is None else _vary_like(
+        jnp.zeros((acc_windows, ride.shape[0] * VB), jnp.float32), ref)
+    (acc, acc_k), _ = jax.lax.scan(body, (acc, acc_k), xs)
+    rows = acc[:num_rows].astype(ref.dtype).reshape(num_rows, K, F)
+    return rows if ride is None else (rows, plain_result(acc_k, ride.shape[0]))
 
 
 def _plan_max(edge_w, obi, edst, pos, num_rows: int):
@@ -815,6 +848,19 @@ def _int_zeros(tree):
         tree)
 
 
+def gat_src_scans(heads: int) -> int:
+    """Scans over the src-keyed plan the backward of one gat op makes on
+    the one-device plan road: ONE (dast rides dtable's, _plan_sum's
+    ``ride``) while the stacked [2K, E] weights fit the 8 sublanes either
+    part is padded to anyway, else the two it always made.  On the chip
+    (gat-reddit.skewed, PR 34) riding at K = 8 saved 146 ms of the 3,768 ms
+    epoch and cost 0.57 GiB of the peak (6.0211 -> 6.5951: the [16, E]
+    stack, 1.5 GB, is live where the step is fullest, beside e, de and the
+    mask); at K = 1 it saves 154 ms and the compiler's temporaries are the
+    parent's to the megabyte."""
+    return 1 if 2 * heads <= 8 else 2
+
+
 def _gat_plan_bwd(slope, precision, rate, res, gout):
     h, table, a_src, a_dst, plans, edge_ids, key, qpos, e, zc, out = res
     edge_src, _ = edge_ids
@@ -832,11 +878,13 @@ def _gat_plan_bwd(slope, precision, rate, res, gout):
     dq = e * de * jnp.where(qpos, 1.0, slope)             # [K, E]
     dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
                      plans.dst_nid, N, "highest", True)   # [K, N]
-    dast = _plan_sum(dq, None, plans.src_obi, plans.src_edst, plans.src_pos,
-                     plans.src_nid, T, "highest")         # [K, T]
-    dtable = _plan_sum(e if w is None else e * w, du, plans.src_obi,
-                       plans.src_edst, plans.src_pos, plans.src_nid, T,
-                       precision)                         # [T, K, F]
+    src = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
+    ew = e if w is None else e * w
+    if gat_src_scans(K) == 1:       # dq rides dtable's read of the src plan
+        dtable, dast = _plan_sum(ew, du, *src, T, precision, ride=dq)
+    else:
+        dast = _plan_sum(dq, None, *src, T, "highest")    # [K, T]
+        dtable = _plan_sum(ew, du, *src, T, precision)    # [T, K, F]
     dtable = dtable + dast.T[:, :, None] * a_src[None]
     dh = dadl.T[:, :, None] * a_dst[None]
     da_src = jnp.einsum("kt,tkf->kf", dast, table, precision="highest")
@@ -895,9 +943,11 @@ def tconv_attend_plan(q, k, v, plans: GatPlans, num_edges: int, drop=None):
     the reference by seed, against 1.1e-3 to 2.3e-3 with a bf16 accumulate,
     which no bound separates; at "highest" they read 2e-7 to 5e-7 and the
     epoch costs 1.25 % more (9.4314 -> 9.5497 s: the row gather is the
-    pass, not the one-hot dots).  Six row-gathering
-    passes a layer (score, u; the backward's contraction, dq, dk, dv)
-    against GAT's three."""
+    pass, not the one-hot dots).  Five scans a layer
+    gather node rows, reading six tables (k for the score, v for u; v for
+    the backward's contraction, k for dq, and q beside du for dk and dv,
+    which walk the src-keyed plan as ONE scan of 2K heads) against GAT's
+    three."""
     key, rate = _drop_args(drop)
     return _tconv_plan(q, k, v, plans, key, num_edges, rate)
 
@@ -944,10 +994,17 @@ def _tconv_plan_bwd(num_edges, rate, res, gout):
     de = _plan_broadcast(dz, *dst[:3], E, de)
     ds = e * de * (1.0 / np.sqrt(F))                          # [K, E]
     dq = _plan_sum(ds, k, *dst, N, "highest", True)           # [N, K, F]
-    dk = _plan_sum(ds, q, *src, T, "highest")                 # [T, K, F]
-    dv = _plan_sum(e if w is None else e * w, du, *src, T,
-                   "highest")                                 # [T, K, F]
-    return (dq, dk, dv) + _int_zeros((plans, key))
+    # dk = sum ds (x) q[nid] and dv = sum (e w) (x) du[nid] walk the SAME
+    # src-keyed plan, and _plan_sum treats heads independently: the pair is
+    # one scan of 2K heads, one column gather of the stacked [2K, E]
+    # weights by src_pos and one row gather of the side-by-side [N, 2K, F]
+    # table by src_nid a step, every output column the contraction it was
+    # (v5e, the Reddit src plan, K = 4: 1,253 -> 844 ms a layer at F = 32,
+    # 1,572 -> 1,043 at F = 41; PERF.md PR 34)
+    sw = jnp.concatenate([ds, e if w is None else e * w], axis=0)
+    dkv = _plan_sum(sw, jnp.concatenate([q, du], axis=1), *src, T,
+                    "highest")                                # [T, 2K, F]
+    return (dq, dkv[:, :K], dkv[:, K:]) + _int_zeros((plans, key))
 
 
 _tconv_plan.defvjp(_tconv_plan_fwd, _tconv_plan_bwd)

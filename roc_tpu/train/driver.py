@@ -30,6 +30,7 @@ from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
 from roc_tpu.models.model import (GraphCtx, Model, attention_heads,
                                   attention_score)
+from roc_tpu.ops.edge import gat_src_scans
 from roc_tpu.ops.softmax import format_metrics
 from roc_tpu.optim.adam import Adam
 from roc_tpu.train.config import Config
@@ -159,9 +160,11 @@ def model_has_attention(model: Model) -> bool:
     return attention_kind(model) is not None
 
 
-# row-gathering passes over the plans a TRAINING step makes per tconv op on
-# the plan road: the score contraction and the weighted sum forward, the
-# contraction for de, dq, dk and dv backward (ops.edge.tconv_attend_plan)
+# node tables a TRAINING step reads by row over the plans per tconv op on
+# the plan road: k for the score and v for the weighted sum forward; v for
+# de, k for dq, and q and du side by side for dk and dv backward
+# (ops.edge.tconv_attend_plan).  The last two share ONE scan since PR 34:
+# five scans gather rows, six tables are read.
 TCONV_ROW_PASSES = 6
 
 
@@ -485,8 +488,14 @@ class BaseTrainer:
         ``score`` ("dot"), ``score_bytes`` (ONE [K, E] float32 array of
         its widest op: what each per-edge array live in a layer's backward
         costs), ``residual_bytes`` (the [K, E] bytes kept for the
-        backward, e of every op) and ``row_passes`` (row-gathering passes
-        over the plans a training step makes: 6 an op)."""
+        backward, e of every op) and ``row_passes`` (node tables a training
+        step reads by row over the plans, 6 an op: k, v forward; v, k, q, du
+        backward, the last two side by side in one scan).  Both kinds end
+        with ``src_scans``: the scans over the src-keyed plan a training
+        step makes, all in the backward: 1 an op (tconv: dk and dv
+        together; gat: dast riding dtable's where ops.edge.gat_src_scans
+        lets it), 2 an op on the edge-sharded road (parallel/spmd.py
+        ``_egat_bwd``), 0 on the xla scans."""
         kind = attention_kind(self.model)
         if kind is None:
             return None
@@ -507,6 +516,16 @@ class BaseTrainer:
             info.update(score="dot", score_bytes=max(heads) * edges * 4,
                         residual_bytes=sum(heads) * edges * 4,
                         row_passes=TCONV_ROW_PASSES * len(heads))
+        sharded = plans is not getattr(gd, "gat_plans", None)
+
+        def src_scans(k):       # of one op of k heads, a training step
+            if not on_plan:
+                return 0
+            if sharded:         # parallel/spmd.py _egat_bwd keeps two calls
+                return 2
+            return 1 if kind == "tconv" else gat_src_scans(k)
+
+        info["src_scans"] = sum(map(src_scans, heads))
         return info
 
     def _announce_attention(self):

@@ -274,6 +274,47 @@ def test_pad_chunks_of_stacked_plans_change_nothing(heads, monkeypatch):
                                          dst.size)))
 
 
+# -- further weights riding a row sum's read (the backward's src side) -------
+
+@pytest.mark.parametrize("precision", ["highest", "default"])
+@pytest.mark.parametrize("heads,riding", [(1, 1), (8, 8), (1, 2)])
+def test_riding_weights_against_the_two_calls(heads, riding, precision,
+                                              monkeypatch):
+    """_plan_sum(w, x, ..., ride=r) over a PACKED src-keyed plan with pad
+    chunks and an empty window, several scan steps: the row sum and the
+    plain sum of the two calls it replaces (dtable and dast of
+    _gat_plan_bwd), bit for bit: the rows' weights are the first K rows of
+    the one stacked read, the riding ones (K' of them) the rest."""
+    monkeypatch.setattr(em, "_PLAN_CB_SUM", 8)
+    src, dst, rows = _edges("hub", seed=8)
+    src = np.where(src // VB == 5, src + VB, src)       # window 5: empty
+    E, F = dst.size, 4
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    splan = em._pad_posplan(plans.src_obi, plans.src_edst, plans.src_pos,
+                            plans.src_nid, 5)           # not a step multiple
+    assert splan[0].shape[0] > 16 and splan[0].shape[0] % 8
+    assert 5 in np.asarray(splan[0])
+    assert not (np.asarray(splan[1])[np.asarray(splan[0]) == 5] != VB).any()
+    rng = np.random.default_rng(heads)
+    w, r = (jnp.asarray(rng.standard_normal((k, E)), jnp.float32)
+            for k in (heads, riding))
+    x = jnp.asarray(rng.standard_normal((rows, heads, F)), jnp.float32)
+    got_rows, got_plain = em._plan_sum(w, x, *splan, rows, precision, ride=r)
+    want_rows = em._plan_sum(w, x, *splan, rows, precision)
+    want_plain = em._plan_sum(r, None, *splan, rows, "highest")
+    assert got_rows.shape == (rows, heads, F)
+    assert got_plain.shape == (riding, rows)
+    np.testing.assert_array_equal(np.asarray(got_rows), np.asarray(want_rows))
+    np.testing.assert_array_equal(np.asarray(got_plain),
+                                  np.asarray(want_plain))
+    # and the plain sum is the sum: NumPy over the edges by source
+    ref = np.zeros((rows, riding), np.float64)
+    np.add.at(ref, src, np.asarray(r, np.float64).T)
+    np.testing.assert_allclose(np.asarray(got_plain), ref.T, rtol=1e-5,
+                               atol=1e-4)
+    assert not np.asarray(got_plain)[:, 5 * VB:6 * VB].any()
+
+
 def _sub_jaxprs(param):
     from jax.extend import core as jcore
     if isinstance(param, jcore.ClosedJaxpr):
@@ -283,6 +324,31 @@ def _sub_jaxprs(param):
     elif isinstance(param, (tuple, list)):
         for p in param:
             yield from _sub_jaxprs(p)
+
+
+def _small_steps(monkeypatch):
+    """Several scan steps over the small test graphs."""
+    for name, cb in (("_PLAN_CB_BLOCKS", 8), ("_PLAN_CB_SUM", 16),
+                     ("_PLAN_CB_MAX", 16)):
+        monkeypatch.setattr(em, name, cb)
+    monkeypatch.setattr(em, "_LANE_GATHER_CHUNK", 4096)
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in _sub_jaxprs(p):
+                yield from _eqns(sub)
+
+
+def _scans_and_their_gathers(jaxpr):
+    """For every scan in the jaxpr, nested ones included: the shapes of the
+    arrays its body gathers from."""
+    return [[tuple(g.invars[0].aval.shape)
+             for g in _eqns(eqn.params["jaxpr"].jaxpr)
+             if g.primitive.name == "gather"]
+            for eqn in _eqns(jaxpr) if eqn.primitive.name == "scan"]
 
 
 def _walk(jaxpr, tainted, gathers, shapes):
@@ -327,10 +393,7 @@ def test_no_gather_of_the_plan_path_is_indexed_by_edge_dst(dropout,
     a feature row, on the lane axis: rows of K*F exist a scan step at a
     time only, as in _plan_sum."""
     import jax
-    for name, cb in (("_PLAN_CB_BLOCKS", 8), ("_PLAN_CB_SUM", 16),
-                     ("_PLAN_CB_MAX", 16)):
-        monkeypatch.setattr(em, name, cb)
-    monkeypatch.setattr(em, "_LANE_GATHER_CHUNK", 4096)
+    _small_steps(monkeypatch)
     src, dst, rows = _edges("hub", seed=6)
     K, F, E = 4, 16, dst.size            # no other axis of the path is 4 long
     step_slots = 16 * EB
@@ -362,3 +425,77 @@ def test_no_gather_of_the_plan_path_is_indexed_by_edge_dst(dropout,
     rows_last = [(p, s) for p, s in shapes if len(s) >= 2 and s[-1] == K * F
                  and int(np.prod(s[:-1])) > step_slots]
     assert not rows_last, rows_last[:5]
+
+
+
+@pytest.mark.parametrize("heads,scans", [(1, 1), (4, 1), (8, 2)])
+def test_dast_rides_dtables_scan_while_the_stack_fits_a_tile(heads, scans,
+                                                             monkeypatch):
+    """jax.grad of gat_attend_plan: the scans that gather per-edge weights
+    by COLUMN are the backward's walks of the src-keyed plan (every
+    dst-keyed read is by aligned blocks).  While 2K rows fit a tile's 8
+    sublanes it is ONE scan reading the stacked [2K, E] array; at K = 8 the
+    two scans it always was, [K, E] each (the stack would be one more
+    edge-sized array at the step's fullest moment: gat_src_scans)."""
+    import jax
+    _small_steps(monkeypatch)
+    assert em.gat_src_scans(heads) == scans
+    src, dst, rows = _edges("hub", seed=6)
+    K, F, E = heads, 4, dst.size
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((rows, K, F)), jnp.float32)
+    a_s, a_d = (jnp.asarray(rng.standard_normal((K, F)), jnp.float32)
+                for _ in range(2))
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    ids = (jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+
+    def loss(h, a_s, a_d):
+        return jnp.sum(em.gat_attend_plan(h, h, a_s, a_d, plans, ids, 0.2,
+                                          drop=(jax.random.PRNGKey(1), 0.5))
+                       ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(h, a_s, a_d)
+    by_column = [g for g in _scans_and_their_gathers(jaxpr.jaxpr)
+                 if any(len(s) in (1, 2) and s[-1] == E for s in g)]
+    assert len(by_column) == scans
+    want = [(2 * K, E)] if scans == 1 else [(K, E)]
+    assert all([s for s in g if s[-1] == E] == want for g in by_column)
+
+
+def test_the_edge_sharded_backward_keeps_its_two_calls_and_its_numbers():
+    """parallel/spmd.py's _egat_bwd sums dast and dtable in two _plan_sum
+    calls WITHOUT riding weights (_scatter_to_owner sits between them): its
+    first step's gradients are the single-device road's, which sums both in
+    one scan.  After one Adam step from zero moments ``m / (1 - beta1)`` is
+    the gradient the step applied."""
+    import jax
+
+    from roc_tpu.graph import datasets
+    from roc_tpu.models import build_gat
+    from roc_tpu.parallel.spmd import SpmdTrainer
+    from roc_tpu.train.config import Config
+    from roc_tpu.train.driver import Trainer
+    ds = datasets.synthetic("t", 220, 4.0, 8, 4, n_train=30, n_val=30,
+                            n_test=30, seed=3)
+    layers = [ds.in_dim, 6, ds.num_classes]
+    base = dict(layers=layers, num_epochs=1, dropout_rate=0.0,
+                eval_every=10**9, weight_decay=0.0,
+                aggregate_backend="matmul")
+    one = Trainer(Config(**base, edge_shard="off"), ds,
+                  build_gat(layers, 0.0, heads=2))
+    four = SpmdTrainer(Config(**base, num_parts=4, edge_shard=True), ds,
+                       build_gat(layers, 0.0, heads=2))
+    assert four.gdata.mode == "edge" and four.gdata.gat_plans is not None
+    assert one.gdata.gat_plans is not None
+    # what each says of itself: one scan an op against two
+    assert one.attention_info()["src_scans"] == 2
+    assert four.attention_info()["src_scans"] == 4
+    one.run_epoch()
+    four.run_epoch()
+    m1, m4 = (jax.device_get(t.opt_state.m) for t in (one, four))
+    assert set(m1) == set(m4)
+    for name in m1:
+        a, b = np.asarray(m4[name], np.float64), np.asarray(m1[name],
+                                                            np.float64)
+        assert np.linalg.norm(b) > 0, name
+        assert np.linalg.norm(a - b) <= 2e-5 * np.linalg.norm(b), name

@@ -18,7 +18,8 @@ from roc_tpu.ops.pallas.segment_sum import EB
 from roc_tpu.train.config import Config, parse_args
 from roc_tpu.train.driver import Trainer, make_trainer
 
-from test_gat_plans import _edges, _sub_jaxprs
+from test_gat_plans import (_edges, _eqns, _scans_and_their_gathers,
+                            _small_steps)
 
 
 # -- the plan road against softmax(Q K^T / sqrt(d) + mask) V ----------------
@@ -124,14 +125,6 @@ def test_one_in_edge_and_a_padded_row_with_none():
 
 # -- layout: [K, E], edges on the lane axis ---------------------------------
 
-def _eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for p in eqn.params.values():
-            for sub in _sub_jaxprs(p):
-                yield from _eqns(sub)
-
-
 @pytest.mark.parametrize("dropout", [0.0, 0.3])
 def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
         dropout, monkeypatch):
@@ -141,10 +134,7 @@ def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
     slots), no scatter exists (no gather was transposed), and every
     edge-sized intermediate is [K, E]: never the heads, nor a feature row,
     on the lane axis of an edge-sized array."""
-    for name, cb in (("_PLAN_CB_BLOCKS", 8), ("_PLAN_CB_SUM", 16),
-                     ("_PLAN_CB_MAX", 16)):
-        monkeypatch.setattr(em, name, cb)
-    monkeypatch.setattr(em, "_LANE_GATHER_CHUNK", 4096)
+    _small_steps(monkeypatch)
     src, dst, rows = _edges("hub", seed=6)
     K, F, E = 4, 16, dst.size           # no other axis of the road is 4 long
     step_slots = 16 * EB
@@ -173,6 +163,74 @@ def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
     rows_last = [s for s in shapes if len(s) >= 2 and s[-1] == K * F
                  and int(np.prod(s[:-1])) > step_slots]
     assert not rows_last, rows_last[:5]
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+@pytest.mark.parametrize("heads", [1, 4])
+@pytest.mark.parametrize("kind", ["regular", "hub"])
+def test_dk_and_dv_in_one_scan_against_the_two_sums(kind, heads, dropout,
+                                                    monkeypatch):
+    """The backward's src side, ONE _plan_sum of 2K heads (stacked [2K, E]
+    weights, the tables side by side), against the two calls it replaced,
+    over several scan steps of the packed src-keyed plan: every column is
+    the same float32 contraction over the same slots in the same order, so
+    at most another blocking of the same dot (1e-6 of the sum's norm)."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges(kind, seed=4)
+    K, F, E = heads, 8, dst.size
+    q, k, v = _qkv(rows, K, F, 20 + heads)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    assert plans.src_obi.shape[0] > 2 * 16          # several steps
+    key = jax.random.PRNGKey(7) if dropout else None
+    out, res = em._tconv_plan_fwd(q, k, v, plans, key, E, dropout)
+    gout = jnp.cos(out)
+    dq, dk, dv = em._tconv_plan_bwd(E, dropout, res, gout)[:3]
+    # the two sums as they were, from the same per-edge weights
+    e, zc = res[5], res[6]
+    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
+    splan = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
+    du = gout / zc.T[:, :, None]
+    dz = -jnp.einsum("nkf,nkf->kn", gout, out, precision="highest") / zc
+    w = em._keep_scale((key, dropout), K, E, e.dtype)
+    de = em._edge_contract(du, v, *dplan, E)
+    de = em._plan_broadcast(dz, *dplan[:3], E, de if w is None else de * w)
+    ds = e * de * (1.0 / np.sqrt(F))
+    want_dk = em._plan_sum(ds, q, *splan, rows, "highest")
+    want_dv = em._plan_sum(e if w is None else e * w, du, *splan, rows,
+                           "highest")
+    assert (w is None) == (dropout == 0.0)
+    for name, a, b in (("dk", dk, want_dk), ("dv", dv, want_dv)):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert a.shape == (rows, K, F) and np.linalg.norm(b) > 0
+        assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b), name
+    assert np.asarray(dq).shape == (rows, K, F)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+def test_the_backward_walks_the_src_plan_once_an_op(dropout, monkeypatch):
+    """jax.grad of tconv_attend_plan: FIVE scans gather node rows (score
+    and u forward; de, dq and the fused dk / dv backward) and exactly ONE
+    of them reads 2 K F wide rows; that scan's column gather reads ONE
+    stacked [2K, E] per-edge array, and no other scan gathers from a
+    [K, E] array by column (every dst-keyed read is by aligned blocks)."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges("hub", seed=6)
+    K, F, E = 4, 16, dst.size
+    q, k, v = _qkv(rows, K, F, 0)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    drop = (jax.random.PRNGKey(5), dropout) if dropout else None
+
+    def loss(*a):
+        return jnp.sum(em.tconv_attend_plan(*a, plans, E, drop) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    scans = _scans_and_their_gathers(jaxpr)
+    narrow = [g for g in scans if (rows, K * F) in g]
+    wide = [g for g in scans if (rows, 2 * K * F) in g]
+    assert (len(narrow), len(wide)) == (4, 1)
+    assert wide[0].count((2 * K, E)) == 1 and (K, E) not in wide[0]
+    by_column = [g for g in scans if (K, E) in g or (2 * K, E) in g]
+    assert by_column == wide
 
 
 # -- the builder and the op IR ----------------------------------------------
@@ -303,13 +361,15 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     tr = Trainer(cfg, ds, _model(cfg))
     info = tr.attention_info()
     e = ds.graph.num_edges
-    assert set(info) == {"backend", "plan_pad_ratio", "score", "score_bytes",
-                         "residual_bytes", "row_passes"}
+    assert list(info) == ["backend", "plan_pad_ratio", "score", "score_bytes",
+                          "residual_bytes", "row_passes", "src_scans"]
     assert (info["backend"], info["score"]) == ("plan", "dot")
-    # one [K, E] float32 array; e of each of the three ops; 6 passes an op
+    # one [K, E] float32 array; e of each of the three ops; six tables an
+    # op read by row; ONE scan an op over the src-keyed plan (dk with dv)
     assert info["score_bytes"] == 2 * e * 4
     assert info["residual_bytes"] == 3 * 2 * e * 4
     assert info["row_passes"] == 18
+    assert info["src_scans"] == 3
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
     assert line == (
@@ -317,16 +377,19 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
         f" tconv_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
         f" tconv_score=dot tconv_score_bytes={info['score_bytes']}"
         f" tconv_residual_bytes={info['residual_bytes']}"
-        " tconv_row_passes=18")
+        " tconv_row_passes=18 tconv_src_scans=3")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
     assert att["backend"] == "plan" and att["tconv_score"] == "dot"
     assert att["tconv_residual_bytes"] == info["residual_bytes"]
+    assert (att["tconv_row_passes"], att["tconv_src_scans"]) == (18, 3)
+    assert list(att)[-2:] == ["tconv_row_passes", "tconv_src_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     for name in ("plan_pad_ratio", "score_bytes", "residual_bytes",
-                 "row_passes"):
+                 "row_passes", "src_scans"):
         assert f"roc_tconv_{name} " in prom
+    assert "roc_tconv_src_scans 3" in prom          # unlabelled: a counter
     assert 'roc_tconv_backend{backend="plan"} 1' in prom
     assert 'roc_tconv_score{score="dot"} 1' in prom
     from roc_tpu.obs import report as obs_report
@@ -341,6 +404,7 @@ def test_the_xla_road_keeps_no_plan_residual(capsys):
     tr = Trainer(cfg, ds, _model(cfg))
     info = tr.attention_info()
     assert (info["backend"], info["residual_bytes"]) == ("xla", 0)
+    assert info["src_scans"] == 0           # no plan is walked at all
     assert tr.gdata.gat_plans is None and tr.gdata.backend == "xla"
     assert "# attention: backend=xla " in capsys.readouterr().err
 
