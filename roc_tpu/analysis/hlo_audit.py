@@ -168,21 +168,27 @@ def trainer_key(trainer) -> str:
             f"{cfg.aggregate_backend}/{exch}")
 
 
-def lower_steps(trainer) -> Dict[str, object]:
-    """Lower the trainer's jitted train/eval steps with its real arguments
-    (lowering only — nothing runs).  Shared by the HLO audit below and the
-    memory estimator's XLA cross-checks (roc_tpu/memory/estimator.py)."""
+def lower_train_step(trainer):
+    """Lower the trainer's jitted train step with its real arguments
+    (lowering only — nothing runs)."""
     import jax
     import jax.numpy as jnp
     rng = jax.random.PRNGKey(0)
     alpha = jnp.float32(trainer.optimizer.alpha)
-    lo_train = trainer._train_step.lower(
+    return trainer._train_step.lower(
         trainer.params, trainer.opt_state, trainer.x, trainer.labels,
         trainer.mask, trainer.gdata, rng, alpha, jnp.float32(1.0))
+
+
+def lower_steps(trainer) -> Dict[str, object]:
+    """Lower the trainer's jitted train/eval steps with their real
+    arguments.  Shared by the HLO audit below, the memory estimator's XLA
+    cross-checks (roc_tpu/memory/estimator.py) and the trainer's
+    device_scopes()."""
     lo_eval = trainer._eval_step.lower(
         trainer.params, trainer.x, trainer.labels, trainer.mask,
         trainer.gdata)
-    return {"train": lo_train, "eval": lo_eval}
+    return {"train": lower_train_step(trainer), "eval": lo_eval}
 
 
 def audit_trainer(trainer, key: Optional[str] = None) -> AuditReport:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from roc_tpu import ops
+from roc_tpu.obs import scopes
 
 try:
     from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
@@ -341,50 +342,58 @@ class Model:
         residuals.  Off by default: untagged programs are byte-identical to
         the pre-planner ones, which the HLO budget audit pins."""
         vals: Dict[int, jnp.ndarray] = {0: x}
-        for op in self.ops:
-            a = vals[op.inputs[0]]
-            if op.kind == "dropout":
-                if train:
-                    assert key is not None, "training dropout needs a PRNG key"
-                    k = jax.random.fold_in(key, op.attrs["slot"])
-                else:
-                    k = None
-                out = ops.dropout(k, a, op.attrs["rate"], train)
-            elif op.kind == "linear":
-                out = ops.linear(a, params[op.attrs["param"]],
-                                 op.attrs["activation"])
-            elif op.kind == "norm":
-                out = ops.indegree_norm(a, gctx.in_degree)
-            elif op.kind == "aggregate":
-                out = gctx.aggregate(a, op.attrs["aggr"])
-            elif attention_score(op) == "dot":
-                out = self._apply_tconv(op, params, a, gctx,
-                                        attention_drop(op, key, train))
-            elif op.kind == "gat":
-                assert gctx.attend is not None, \
-                    "this GraphCtx was built without attention support"
-                name = op.attrs["param"]
-                kk, fd = op.attrs["heads"], op.attrs["head_dim"]
-                h = ops.linear(a, params[name + "_w"]).reshape(-1, kk, fd)
-                out = gctx.attend(h, params[name + "_asrc"],
-                                  params[name + "_adst"], op.attrs["slope"],
-                                  attention_drop(op, key, train)
-                                  ).reshape(-1, kk * fd)
-            elif op.kind == "layernorm":
-                name = op.attrs["param"]
-                out = ops.layer_norm(a, params[name + "_gain"],
-                                     params[name + "_bias"])
-            elif op.kind == "activation":
-                out = ops.apply_activation(a, op.attrs["mode"])
-            elif op.kind == "add":
-                out = ops.add(a, vals[op.inputs[1]])
-            else:
-                raise ValueError(f"unknown op kind {op.kind!r}")
-            if ckpt_names:
-                out = _checkpoint_name(out, op.attrs["ckpt"])
-            vals[op.out] = out
+        for index, op in enumerate(self.ops):
+            # the op's device scope, set here and nowhere else
+            with scopes.scope(scopes.op_scope(index, op.kind)):
+                vals[op.out] = self._apply_op(op, params, vals, gctx,
+                                              key, train, ckpt_names)
         assert self.logits is not None, "call softmax_cross_entropy() last"
         return vals[self.logits.id]
+
+    def _apply_op(self, op: OpNode, params, vals, gctx: GraphCtx, key,
+                  train: bool, ckpt_names: bool):
+        """One op of the list: its output from the values so far."""
+        a = vals[op.inputs[0]]
+        if op.kind == "dropout":
+            if train:
+                assert key is not None, "training dropout needs a PRNG key"
+                k = jax.random.fold_in(key, op.attrs["slot"])
+            else:
+                k = None
+            out = ops.dropout(k, a, op.attrs["rate"], train)
+        elif op.kind == "linear":
+            out = ops.linear(a, params[op.attrs["param"]],
+                             op.attrs["activation"])
+        elif op.kind == "norm":
+            out = ops.indegree_norm(a, gctx.in_degree)
+        elif op.kind == "aggregate":
+            out = gctx.aggregate(a, op.attrs["aggr"])
+        elif attention_score(op) == "dot":
+            out = self._apply_tconv(op, params, a, gctx,
+                                    attention_drop(op, key, train))
+        elif op.kind == "gat":
+            assert gctx.attend is not None, \
+                "this GraphCtx was built without attention support"
+            name = op.attrs["param"]
+            kk, fd = op.attrs["heads"], op.attrs["head_dim"]
+            h = ops.linear(a, params[name + "_w"]).reshape(-1, kk, fd)
+            out = gctx.attend(h, params[name + "_asrc"],
+                              params[name + "_adst"], op.attrs["slope"],
+                              attention_drop(op, key, train)
+                              ).reshape(-1, kk * fd)
+        elif op.kind == "layernorm":
+            name = op.attrs["param"]
+            out = ops.layer_norm(a, params[name + "_gain"],
+                                 params[name + "_bias"])
+        elif op.kind == "activation":
+            out = ops.apply_activation(a, op.attrs["mode"])
+        elif op.kind == "add":
+            out = ops.add(a, vals[op.inputs[1]])
+        else:
+            raise ValueError(f"unknown op kind {op.kind!r}")
+        if ckpt_names:
+            out = _checkpoint_name(out, op.attrs["ckpt"])
+        return out
 
     @staticmethod
     def _apply_tconv(op: OpNode, params, x, gctx: GraphCtx, drop):

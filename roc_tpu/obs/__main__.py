@@ -1,6 +1,8 @@
 """CLI: `python -m roc_tpu.obs report|calibration|selftest`.
 
-report      — text summary of a -obs run's trace.json + metrics.jsonl
+report      — text summary of a -obs run's trace.json + metrics.jsonl;
+              with -profile DIR, a -profile run's device time by program
+              op x pass x part (obs/scopes.py) and its idle gaps by span
 calibration — join a run's prediction/measurement ledger records and
               report per-cost-model calibration error; --selftest runs
               the preflight gate (tiny CPU runs must pair >= 5 models
@@ -25,6 +27,9 @@ def main(argv=None) -> int:
                     help="obs output dir (default: roc_obs)")
     rp.add_argument("-trace", default="", help="trace.json path override")
     rp.add_argument("-metrics", default="", help="metrics.jsonl override")
+    rp.add_argument("-profile", dest="profile_dir", default="",
+                    help="a -profile DIR: device time by program op "
+                         "(the trace joined with DIR/roc_scopes.json)")
     cp = sub.add_parser("calibration",
                         help="per-cost-model predicted-vs-measured report")
     cp.add_argument("-dir", dest="obs_dir", default="roc_obs",
@@ -46,6 +51,17 @@ def main(argv=None) -> int:
             return calibration_selftest()
         return calibration(ns.metrics
                            or os.path.join(ns.obs_dir, "metrics.jsonl"))
+
+    if ns.profile_dir:
+        from roc_tpu.obs.report import device_report
+        try:
+            print(device_report(ns.profile_dir))
+        except FileNotFoundError as e:
+            print(f"# {e} (run with -profile {ns.profile_dir} first: the "
+                  f"trainer writes the trace and roc_scopes.json there)",
+                  file=sys.stderr)
+            return 2
+        return 0
 
     from roc_tpu.obs.report import report
     trace = ns.trace or os.path.join(ns.obs_dir, "trace.json")

@@ -12,7 +12,10 @@ watchdog fire/quiet behavior, and the span overhead bound, all stdlib-only
 
 from __future__ import annotations
 
+import glob
 import json
+import os
+import re
 from typing import List
 
 from roc_tpu.obs.metrics import load_jsonl
@@ -74,9 +77,9 @@ def exchange_line(info: dict) -> str:
 
 def attention_line(info: dict) -> str:
     """An attention model's start-up line from its `attention` record
-    (BaseTrainer._announce_attention): every field as ``key=value`` in the
-    record's order, the backend first.  One format for stderr and for this
-    report."""
+    (BaseTrainer._announce_attention_info): every field as ``key=value``
+    in the record's order, the backend first.  One format for stderr and
+    for this report."""
     fields = " ".join(
         f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
         for k, v in info.items() if k not in ("type", "backend"))
@@ -171,6 +174,213 @@ def report(trace_path: str = "", metrics_path: str = "") -> str:
         else:
             lines.append(f"# metrics: no records at {metrics_path}")
     return "\n".join(lines) if lines else "# nothing to report"
+
+
+# -- device time by program op (`report -profile DIR`) ---------------------
+#
+# A `-profile DIR` run leaves a `jax.profiler` trace and, beside it,
+# `roc_scopes.json` (BaseTrainer._write_device_scopes): per program the
+# compiled step's {instruction name: (op, pass, part)}.  The trace names
+# every device event by its instruction, so the two join by name.  What the
+# trace holds (jax 0.9.0, libtpu 0.0.34): one plane a chip,
+# ``/device:TPU:<n>``, whose line ``XLA Ops`` has one event per executed
+# instruction (the name is the instruction's whole HLO text, ``%fusion.9 =
+# f32[...] fusion(...)``) and whose line ``XLA Modules`` has one per program
+# run (``jit_train_step(<fingerprint>)``); events nest (a `while` encloses
+# its body), so every time below is SELF time, duration less children, and
+# the self times of a line add up to its busy time.  A CPU trace has no
+# device plane: the host threads' events that carry an ``hlo_op`` stat
+# stand in, a line a thread, with ``hlo_module`` naming the program.
+
+SCOPES_FILE = "roc_scopes.json"
+NO_SCOPE = (None, "", None)     # the key of what no `roc.` scope claims
+_DEVICE_PLANE = re.compile(r"^/device:(?:TPU|GPU):(\d+)$")
+_INSTRUCTION = re.compile(r"^%?([^\s=(]+)")
+
+
+def find_xplane(profile_dir: str) -> str:
+    """The newest `.xplane.pb` under a `jax.profiler` log directory."""
+    found = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {profile_dir!r}")
+    return max(found, key=os.path.getmtime)
+
+
+def _self_times(events: list) -> list:
+    """[[name, program, start, dur, self]] of one line's [name, program,
+    start, dur] events: self = duration less the direct children's (an
+    event that starts inside an open one nests under it)."""
+    events.sort(key=lambda e: (e[2], -e[3]))
+    stack: list = []
+    for ev in events:
+        while stack and ev[2] >= stack[-1][2] + stack[-1][3]:
+            stack.pop()
+        ev.append(ev[3])
+        if stack:
+            stack[-1][4] -= ev[3]
+        stack.append(ev)
+    for ev in events:
+        ev[4] = max(ev[4], 0.0)
+    return events
+
+
+def _busy_intervals(events: list) -> list:
+    out: list = []
+    for _, _, start, dur, _ in events:          # sorted by start
+        end = start + dur
+        if out and start <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], end)
+        else:
+            out.append([start, end])
+    return out
+
+
+def read_device_events(xplane_path: str) -> tuple:
+    """({chip: [line, ...]}, {chip: {program: runs}}, annotations): a line
+    is [[instruction, program, start ns, duration ns, self ns], ...] by
+    start; annotations are the host's ``roc.*`` events as (name, start,
+    duration)."""
+    import bisect
+
+    from jax.profiler import ProfileData
+
+    from roc_tpu.obs.tracer import ANNOTATION_PREFIX
+    data = ProfileData.from_file(xplane_path)
+    chips: dict = {}
+    runs: dict = {}
+    hosts = []
+    for plane in data.planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m is None:
+            if plane.name.startswith("/host:"):
+                hosts.append(plane)
+            continue
+        chip = int(m.group(1))
+        lines = {line.name: line for line in plane.lines}
+        ran = sorted(
+            (float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
+             ev.name.split("(")[0])
+            for ev in (lines["XLA Modules"].events
+                       if "XLA Modules" in lines else ()))
+        starts = [r[0] for r in ran]
+        events = []
+        for ev in (lines["XLA Ops"].events if "XLA Ops" in lines else ()):
+            start = float(ev.start_ns)
+            i = bisect.bisect_right(starts, start) - 1
+            program = ran[i][2] if i >= 0 and start < ran[i][1] else ""
+            events.append([_INSTRUCTION.match(ev.name).group(1), program,
+                           start, float(ev.duration_ns)])
+        chips[chip] = [_self_times(events)]
+        runs[chip] = {}
+        for _, _, program in ran:
+            runs[chip][program] = runs[chip].get(program, 0) + 1
+    annotations = []
+    run_ids: dict = {}
+    for plane in hosts:
+        for line in plane.lines:
+            events: dict = {}
+            for ev in line.events:
+                if ev.name.startswith(ANNOTATION_PREFIX):
+                    annotations.append((ev.name, float(ev.start_ns),
+                                        float(ev.duration_ns)))
+                elif not runs:          # no device plane: the stand-in
+                    stats = dict(ev.stats)
+                    if "hlo_op" not in stats:
+                        continue
+                    chip = int(stats.get("device_ordinal", 0))
+                    program = str(stats.get("hlo_module", ""))
+                    events.setdefault(chip, []).append(
+                        [str(stats["hlo_op"]), program,
+                         float(ev.start_ns), float(ev.duration_ns)])
+                    run_ids.setdefault(chip, {}).setdefault(
+                        program, set()).add(stats.get("run_id"))
+            for chip, evs in events.items():
+                chips.setdefault(chip, []).append(_self_times(evs))
+    for chip, by_program in run_ids.items():
+        runs[chip] = {program: len(ids) for program, ids in
+                      by_program.items()}
+    return chips, runs, sorted(annotations, key=lambda a: a[1])
+
+
+def scope_times(lines: list, program: dict) -> dict:
+    """{(op, pass, part): self ns} of the events of one program on one
+    chip's lines, by its instruction map; what no scope claims (an
+    instruction the map lacks, or one under no `roc.` scope) is under
+    :data:`NO_SCOPE`."""
+    out: dict = {}
+    scopes_of = program["scopes"]
+    for events in lines:
+        for name, module, _, _, self_ns in events:
+            if module != program["module"]:
+                continue
+            op, pass_, part = scopes_of.get(name) or NO_SCOPE
+            key = (op, pass_, part) if op else NO_SCOPE
+            out[key] = out.get(key, 0.0) + self_ns
+    return out
+
+
+def idle_gaps(lines: list, annotations: list, k: int = 5) -> list:
+    """[(seconds, annotation)] of the k longest gaps between a chip's busy
+    intervals, each named by the innermost ``roc.*`` annotation over its
+    middle (what the host was doing meanwhile)."""
+    events = sorted((e for line in lines for e in line),
+                    key=lambda e: e[2])
+    busy = _busy_intervals(events)
+    gaps = [(b[0] - a[1], (a[1] + b[0]) / 2) for a, b in zip(busy, busy[1:])
+            if b[0] > a[1]]
+    out = []
+    for length, mid in sorted(gaps, reverse=True)[:k]:
+        cover = [(d, n) for n, t, d in annotations if t <= mid < t + d]
+        out.append((length / 1e9,
+                    min(cover)[1] if cover else "outside roc.*"))
+    return out
+
+
+def device_report(profile_dir: str) -> str:
+    """The text `python -m roc_tpu.obs report -profile DIR` prints: per
+    program and chip the device self time by op x pass x part (ms a
+    ``roc.epoch`` span for the train step, ms a run for the others), the
+    share of busy time under no scope, the mixed-fusion count, and the
+    chip's five longest idle gaps by annotation."""
+    with open(os.path.join(profile_dir, SCOPES_FILE), encoding="utf-8") as f:
+        record = json.load(f)
+    path = find_xplane(profile_dir)
+    chips, runs, annotations = read_device_events(path)
+    epochs = sum(n == "roc.epoch" for n, _, _ in annotations)
+    layer = {f"roc.{o['index']:02d}_{o['kind']}": o["layer"]
+             for o in record.get("ops", ())}
+    lines = [f"# device time by program op: {path}",
+             f"#   names from jax {record.get('jax', '?')}, "
+             f"{record.get('platform_version', '?')}; {epochs} roc.epoch "
+             f"span(s) in the trace"]
+    for chip in sorted(chips):
+        for name, program in record["programs"].items():
+            times = scope_times(chips[chip], program)
+            if not times:
+                continue
+            per, what = (epochs, "epoch") if name == "train" and epochs \
+                else (max(runs[chip].get(program["module"], 1), 1), "run")
+            busy = sum(times.values())
+            unscoped = times.get(NO_SCOPE, 0.0)
+            lines.append(
+                f"# program {name} ({program['module']}), chip {chip}: busy "
+                f"{busy / 1e6 / per:.3f} ms a {what} over {per} {what}(s); "
+                f"on no scope {100.0 * unscoped / max(busy, 1.0):.3f} %; "
+                f"{program['mixed_fusions']} fusion(s) mix op scopes")
+            for (op, pass_, part), ns in sorted(
+                    times.items(), key=lambda kv: (kv[0][0] is None, str(
+                        kv[0][0]), kv[0][1], str(kv[0][2]))):
+                where = f"L{layer[op]}" if op in layer else ""
+                lines.append(
+                    f"#   {op or '(no scope)':<18} {where:<4} {pass_:<6} "
+                    f"{part or '':<8} {ns / 1e6 / per:12.3f} ms "
+                    f"{100.0 * ns / max(busy, 1.0):7.3f} %")
+        gaps = idle_gaps(chips[chip], annotations)
+        lines.append(f"# idle, chip {chip}: the {len(gaps)} longest gap(s)")
+        for seconds, name in gaps:
+            lines.append(f"#   {seconds * 1e3:10.3f} ms  {name}")
+    return "\n".join(lines)
 
 
 # -- calibration (the ledger's CLI + preflight gate) -----------------------

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from roc_tpu.obs import scopes
 from roc_tpu.obs.tracer import span as _obs_span
 
 
@@ -317,7 +318,7 @@ def scatter_gather_matmul(x, plans: AggregatePlans, num_rows: int,
     is exact in bf16, so error comes only from rounding the features), while
     "default" trades ~1e-2 relative error for single-pass MXU throughput.
     """
-    with jax.named_scope("roc_matmul_agg"):
+    with scopes.scope("fwd", "mm"):
         return _matmul_run(x, plans.fwd_obi, plans.fwd_edst, plans.fwd_esrc,
                            num_rows, precision)
 
@@ -328,8 +329,9 @@ def _mm_fwd(x, plans, num_rows, table_rows, precision):
 
 
 def _mm_bwd(num_rows, table_rows, precision, plans, g):
-    gx = _matmul_run(g, plans.bwd_obi, plans.bwd_edst, plans.bwd_esrc,
-                     table_rows, precision)
+    with scopes.scope("bwd", "mm"):
+        gx = _matmul_run(g, plans.bwd_obi, plans.bwd_edst, plans.bwd_esrc,
+                         table_rows, precision)
     zero = jax.tree.map(
         lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0), plans)
     return gx, zero
@@ -499,11 +501,15 @@ def scatter_gather_binned(x, plans: BinnedPlans, interpret: bool = False,
     A hybrid plan (plans.mm set) adds the thin-cell edges' one-hot matmul
     aggregation: A = A_dense + A_thin."""
     from roc_tpu.ops.pallas.binned import run_binned
-    out = run_binned(x, plans.fwd, interpret, precision)
-    if plans.mm is not None:
-        out = out + _matmul_run(
-            x, plans.mm.fwd_obi, plans.mm.fwd_edst, plans.mm.fwd_esrc,
-            plans.fwd.num_rows, matmul_precision(precision))
+    # the pass; run_binned names its kernels p1 / p1_flat / p2 / fused
+    with scopes.scope("fwd"):
+        out = run_binned(x, plans.fwd, interpret, precision)
+        if plans.mm is not None:
+            with scopes.scope("mm"):
+                out = out + _matmul_run(
+                    x, plans.mm.fwd_obi, plans.mm.fwd_edst,
+                    plans.mm.fwd_esrc, plans.fwd.num_rows,
+                    matmul_precision(precision))
     return out
 
 
@@ -513,11 +519,14 @@ def _bn_fwd(x, plans, interpret, precision):
 
 def _bn_bwd(interpret, precision, plans, g):
     from roc_tpu.ops.pallas.binned import run_binned
-    gx = run_binned(g, plans.bwd, interpret, precision)
-    if plans.mm is not None:
-        gx = gx + _matmul_run(
-            g, plans.mm.bwd_obi, plans.mm.bwd_edst, plans.mm.bwd_esrc,
-            plans.bwd.num_rows, matmul_precision(precision))
+    with scopes.scope("bwd"):
+        gx = run_binned(g, plans.bwd, interpret, precision)
+        if plans.mm is not None:
+            with scopes.scope("mm"):
+                gx = gx + _matmul_run(
+                    g, plans.mm.bwd_obi, plans.mm.bwd_edst,
+                    plans.mm.bwd_esrc, plans.bwd.num_rows,
+                    matmul_precision(precision))
     zero = jax.tree.map(
         lambda a: np.zeros(a.shape, dtype=jax.dtypes.float0), plans)
     return gx, zero

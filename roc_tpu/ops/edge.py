@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from roc_tpu.obs import scopes
+
 # Normalizer guard for every softmax division (live rows have z >= 1 by
 # the max shift; the guard only touches edgeless/pad rows, whose quotient
 # is 0 either way).  The VALUE is load-bearing twice over:
@@ -808,33 +810,53 @@ def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
     edge_src, _ = edge_ids
     N = plans.num_rows
     K, E = h.shape[1], edge_src.shape[0]
-    # the score products are tiny and always float32-exact: at the MXU's
-    # default precision h and a would be rounded to bf16 inside the exp
-    as_t = jnp.einsum("tkf,kf->kt", table, a_src,
-                      precision="highest")                # [K, T]
-    ad_l = jnp.einsum("nkf,kf->kn", h, a_dst,
-                      precision="highest")                # [K, N]
     dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
-    # every read of a node table by edge_dst rides the dst plan
-    q = _plan_broadcast(ad_l, *dplan, E, _take_lanes(as_t, edge_src))  # [K, E]
-    s = jax.nn.leaky_relu(q, negative_slope=slope)
-    m = _plan_max(s, *dplan, N)
-    m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-    e = jnp.exp(s - _plan_broadcast(m, *dplan, E))        # [K, E]
-    z = _plan_sum(e, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
-                  plans.dst_nid, N, "highest", True)      # [K, N]
-    # attention dropout: the weighted sum sees the dropped coefficients,
-    # the normaliser never does (alpha~ = alpha * keep / (1 - p))
-    w = _keep_scale((key, rate), K, E, e.dtype)
-    u = _plan_sum(e if w is None else e * w, table, plans.dst_obi,
-                  plans.dst_edst, plans.dst_pos, plans.dst_nid, N,
-                  precision, True)                        # [N, K, F]
-    # Guard is _Z_GUARD (rationale at its definition): XLA flushes
-    # subnormals to zero,
-    # and rows with no in-edges (padded shard rows) have z == 0 → 0/0 NaN.
-    # Any live row has z >= 1 (the max edge contributes exp(0)).
-    zc = jnp.maximum(z, _Z_GUARD)
-    out = u / zc.T[:, :, None]
+    # the device scopes (obs/scopes.py): the pass, then one part a scan or
+    # kernel; every line of the rule sits in one
+    with scopes.scope("fwd"):
+        with scopes.scope("score"):
+            # the score products are tiny and always float32-exact: at the
+            # MXU's default precision h and a would be rounded to bf16
+            # inside the exp
+            as_t = jnp.einsum("tkf,kf->kt", table, a_src,
+                              precision="highest")            # [K, T]
+            ad_l = jnp.einsum("nkf,kf->kn", h, a_dst,
+                              precision="highest")            # [K, N]
+        with scopes.scope("lanes"):
+            q = _take_lanes(as_t, edge_src)                   # [K, E]
+        # every read of a node table by edge_dst rides the dst plan
+        with scopes.scope("bcast"):
+            q = _plan_broadcast(ad_l, *dplan, E, q)           # [K, E]
+        with scopes.scope("edge"):
+            s = jax.nn.leaky_relu(q, negative_slope=slope)
+        with scopes.scope("max"):
+            m = _plan_max(s, *dplan, N)
+            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
+        with scopes.scope("bcast"):
+            mb = _plan_broadcast(m, *dplan, E)
+        with scopes.scope("edge"):
+            e = jnp.exp(s - mb)                               # [K, E]
+        with scopes.scope("norm"):
+            z = _plan_sum(e, None, plans.dst_obi, plans.dst_edst,
+                          plans.dst_pos, plans.dst_nid, N, "highest",
+                          True)                               # [K, N]
+        # attention dropout: the weighted sum sees the dropped
+        # coefficients, the normaliser never does (alpha~ = alpha * keep /
+        # (1 - p))
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)
+            ew = e if w is None else e * w
+        with scopes.scope("u"):
+            u = _plan_sum(ew, table, plans.dst_obi, plans.dst_edst,
+                          plans.dst_pos, plans.dst_nid, N, precision,
+                          True)                               # [N, K, F]
+        with scopes.scope("norm"):
+            # Guard is _Z_GUARD (rationale at its definition): XLA flushes
+            # subnormals to zero, and rows with no in-edges (padded shard
+            # rows) have z == 0 -> 0/0 NaN.  Any live row has z >= 1 (the
+            # max edge contributes exp(0)).
+            zc = jnp.maximum(z, _Z_GUARD)
+            out = u / zc.T[:, :, None]
     # the mask is NOT a residual: the backward redraws it from the key
     return out, (h, table, a_src, a_dst, plans, edge_ids, key,
                  q >= 0, e, zc, out)
@@ -866,29 +888,42 @@ def _gat_plan_bwd(slope, precision, rate, res, gout):
     edge_src, _ = edge_ids
     N, T = plans.num_rows, plans.table_rows
     K, E = h.shape[1], edge_src.shape[0]
-    du = gout / zc.T[:, :, None]                          # [N, K, F]
-    dz = -jnp.einsum("nkf,nkf->kn", gout, out,
-                     precision="highest") / zc            # [K, N]
-    w = _keep_scale((key, rate), K, E, e.dtype)           # the fwd's mask
     dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
-    de = _edge_contract(du, table, *dplan, plans.dst_nid, E)   # [K, E]
-    if w is not None:
-        de = de * w
-    de = _plan_broadcast(dz, *dplan, E, de)
-    dq = e * de * jnp.where(qpos, 1.0, slope)             # [K, E]
-    dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst, plans.dst_pos,
-                     plans.dst_nid, N, "highest", True)   # [K, N]
     src = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
-    ew = e if w is None else e * w
-    if gat_src_scans(K) == 1:       # dq rides dtable's read of the src plan
-        dtable, dast = _plan_sum(ew, du, *src, T, precision, ride=dq)
-    else:
-        dast = _plan_sum(dq, None, *src, T, "highest")    # [K, T]
-        dtable = _plan_sum(ew, du, *src, T, precision)    # [T, K, F]
-    dtable = dtable + dast.T[:, :, None] * a_src[None]
-    dh = dadl.T[:, :, None] * a_dst[None]
-    da_src = jnp.einsum("kt,tkf->kf", dast, table, precision="highest")
-    da_dst = jnp.einsum("kn,nkf->kf", dadl, h, precision="highest")
+    with scopes.scope("bwd"):
+        with scopes.scope("norm"):
+            du = gout / zc.T[:, :, None]                      # [N, K, F]
+            dz = -jnp.einsum("nkf,nkf->kn", gout, out,
+                             precision="highest") / zc        # [K, N]
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)       # the fwd's mask
+        with scopes.scope("de"):
+            de = _edge_contract(du, table, *dplan, plans.dst_nid, E)  # [K, E]
+        if w is not None:
+            with scopes.scope("edge"):
+                de = de * w
+        with scopes.scope("bcast"):
+            de = _plan_broadcast(dz, *dplan, E, de)
+        with scopes.scope("edge"):
+            dq = e * de * jnp.where(qpos, 1.0, slope)         # [K, E]
+        with scopes.scope("dq"):
+            dadl = _plan_sum(dq, None, plans.dst_obi, plans.dst_edst,
+                             plans.dst_pos, plans.dst_nid, N, "highest",
+                             True)                            # [K, N]
+        with scopes.scope("edge"):
+            ew = e if w is None else e * w
+        with scopes.scope("src"):
+            if gat_src_scans(K) == 1:   # dq rides dtable's read of the plan
+                dtable, dast = _plan_sum(ew, du, *src, T, precision, ride=dq)
+            else:
+                dast = _plan_sum(dq, None, *src, T, "highest")    # [K, T]
+                dtable = _plan_sum(ew, du, *src, T, precision)    # [T, K, F]
+        with scopes.scope("score"):     # the score products' transposes
+            dtable = dtable + dast.T[:, :, None] * a_src[None]
+            dh = dadl.T[:, :, None] * a_dst[None]
+            da_src = jnp.einsum("kt,tkf->kf", dast, table,
+                                precision="highest")
+            da_dst = jnp.einsum("kn,nkf->kf", dadl, h, precision="highest")
     return (dh, dtable, da_src, da_dst) + _int_zeros((plans, edge_ids, key))
 
 
@@ -961,19 +996,31 @@ def _tconv_plan_fwd(q, k, v, plans, key, num_edges, rate):
     N, E = plans.num_rows, num_edges
     K, F = q.shape[1:]
     dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
-    s = _edge_contract(q, k, *dst, E) * (1.0 / np.sqrt(F))    # [K, E]
-    m = _plan_max(s, *dst[:3], N)
-    m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-    e = jnp.exp(s - _plan_broadcast(m, *dst[:3], E))          # [K, E]
-    z = _plan_sum(e, None, *dst, N, "highest", True)          # [K, N]
-    # the weighted sum sees the dropped coefficients, the normaliser never
-    w = _keep_scale((key, rate), K, E, e.dtype)
-    u = _plan_sum(e if w is None else e * w, v, *dst, N, "highest",
-                  True)                                       # [N, K, F]
-    # _Z_GUARD (rationale at its definition): rows with no in-edge (padded
-    # rows) have z == 0; any live row has z >= 1
-    zc = jnp.maximum(z, _Z_GUARD)
-    out = u / zc.T[:, :, None]
+    # the device scopes, as in _gat_plan_fwd
+    with scopes.scope("fwd"):
+        with scopes.scope("score"):
+            s = _edge_contract(q, k, *dst, E) * (1.0 / np.sqrt(F))  # [K, E]
+        with scopes.scope("max"):
+            m = _plan_max(s, *dst[:3], N)
+            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
+        with scopes.scope("bcast"):
+            mb = _plan_broadcast(m, *dst[:3], E)
+        with scopes.scope("edge"):
+            e = jnp.exp(s - mb)                                   # [K, E]
+        with scopes.scope("norm"):
+            z = _plan_sum(e, None, *dst, N, "highest", True)      # [K, N]
+        # the weighted sum sees the dropped coefficients, the normaliser
+        # never
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)
+            ew = e if w is None else e * w
+        with scopes.scope("u"):
+            u = _plan_sum(ew, v, *dst, N, "highest", True)        # [N, K, F]
+        with scopes.scope("norm"):
+            # _Z_GUARD (rationale at its definition): rows with no in-edge
+            # (padded rows) have z == 0; any live row has z >= 1
+            zc = jnp.maximum(z, _Z_GUARD)
+            out = u / zc.T[:, :, None]
     # ONE [K, E] residual: e.  The mask is redrawn from the key.
     return out, (q, k, v, plans, key, e, zc, out)
 
@@ -984,27 +1031,38 @@ def _tconv_plan_bwd(num_edges, rate, res, gout):
     K, F = q.shape[1:]
     dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
     src = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
-    du = gout / zc.T[:, :, None]                              # [N, K, F]
-    dz = -jnp.einsum("nkf,nkf->kn", gout, out,
-                     precision="highest") / zc                # [K, N]
-    w = _keep_scale((key, rate), K, E, e.dtype)               # the fwd's mask
-    de = _edge_contract(du, v, *dst, E)                       # [K, E]
-    if w is not None:
-        de = de * w
-    de = _plan_broadcast(dz, *dst[:3], E, de)
-    ds = e * de * (1.0 / np.sqrt(F))                          # [K, E]
-    dq = _plan_sum(ds, k, *dst, N, "highest", True)           # [N, K, F]
-    # dk = sum ds (x) q[nid] and dv = sum (e w) (x) du[nid] walk the SAME
-    # src-keyed plan, and _plan_sum treats heads independently: the pair is
-    # one scan of 2K heads, one column gather of the stacked [2K, E]
-    # weights by src_pos and one row gather of the side-by-side [N, 2K, F]
-    # table by src_nid a step, every output column the contraction it was
-    # (v5e, the Reddit src plan, K = 4: 1,253 -> 844 ms a layer at F = 32,
-    # 1,572 -> 1,043 at F = 41; PERF.md PR 34)
-    sw = jnp.concatenate([ds, e if w is None else e * w], axis=0)
-    dkv = _plan_sum(sw, jnp.concatenate([q, du], axis=1), *src, T,
-                    "highest")                                # [T, 2K, F]
-    return (dq, dkv[:, :K], dkv[:, K:]) + _int_zeros((plans, key))
+    with scopes.scope("bwd"):
+        with scopes.scope("norm"):
+            du = gout / zc.T[:, :, None]                          # [N, K, F]
+            dz = -jnp.einsum("nkf,nkf->kn", gout, out,
+                             precision="highest") / zc            # [K, N]
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)   # the fwd's mask
+        with scopes.scope("de"):
+            de = _edge_contract(du, v, *dst, E)                   # [K, E]
+        if w is not None:
+            with scopes.scope("edge"):
+                de = de * w
+        with scopes.scope("bcast"):
+            de = _plan_broadcast(dz, *dst[:3], E, de)
+        with scopes.scope("edge"):
+            ds = e * de * (1.0 / np.sqrt(F))                      # [K, E]
+        with scopes.scope("dq"):
+            dq = _plan_sum(ds, k, *dst, N, "highest", True)       # [N, K, F]
+        # dk = sum ds (x) q[nid] and dv = sum (e w) (x) du[nid] walk the
+        # SAME src-keyed plan, and _plan_sum treats heads independently: the
+        # pair is one scan of 2K heads, one column gather of the stacked
+        # [2K, E] weights by src_pos and one row gather of the side-by-side
+        # [N, 2K, F] table by src_nid a step, every output column the
+        # contraction it was (v5e, the Reddit src plan, K = 4: 1,253 -> 844
+        # ms a layer at F = 32, 1,572 -> 1,043 at F = 41; PERF.md PR 34)
+        with scopes.scope("edge"):      # the stack and the table: no scan
+            sw = jnp.concatenate([ds, e if w is None else e * w], axis=0)
+            side = jnp.concatenate([q, du], axis=1)
+        with scopes.scope("src"):
+            dkv = _plan_sum(sw, side, *src, T, "highest")         # [T, 2K, F]
+            dk, dv = dkv[:, :K], dkv[:, K:]
+    return (dq, dk, dv) + _int_zeros((plans, key))
 
 
 _tconv_plan.defvjp(_tconv_plan_fwd, _tconv_plan_bwd)

@@ -73,6 +73,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # stdlib-only tracer entry point (no obs package body is pulled in here)
+from roc_tpu.obs import scopes
 from roc_tpu.obs.tracer import span as _obs_span
 # Calibration ledger (stdlib-only, like the tracer): choose_geometry
 # PREDICTS the winning schedule's step/staging-row counts, the plan
@@ -2395,7 +2396,7 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
             # adjacent groups interleaved (gating re-checked against the
             # REAL padded width — the plan-build gate used a model H)
             S = int(plan.f_blk.shape[0])
-            with jax.named_scope("roc_binned_fused"):
+            with scopes.scope("fused"):
                 out = _fused_run(xp, plan.f_blk, plan.f_blk2, plan.f_obi,
                                  plan.f_meta, plan.f_dsrc, plan.f_ddst,
                                  plan.f_rows, S, C2, out_rows, interpret,
@@ -2404,10 +2405,10 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
 
         def fbody(_, gplan):
             srcl, blk, blk2, dsrc, ddst, dstl, obi, first = gplan
-            with jax.named_scope("roc_binned_p1_flat"):
+            with scopes.scope("p1_flat"):
                 stg = _p1_flat_run(xp, blk, blk2, dsrc, ddst, srcl, C1,
                                    stg_rows, interpret, exact, geom)
-            with jax.named_scope("roc_binned_p2"):
+            with scopes.scope("p2"):
                 out_g = _p2_run(stg, obi, first, dstl, C2,
                                 plan.bins_per_group * geom.rb, interpret,
                                 exact, geom)
@@ -2423,10 +2424,10 @@ def run_binned(x, plan: BinnedPlan, interpret: bool = False,
 
     def body(_, gplan):
         srcl, off, blk, dstl, obi, first = gplan
-        with jax.named_scope("roc_binned_p1"):
+        with scopes.scope("p1"):
             stg = _p1_run(xp, blk, off, srcl, C1, stg_rows, interpret,
                           exact, geom)
-        with jax.named_scope("roc_binned_p2"):
+        with scopes.scope("p2"):
             out_g = _p2_run(stg, obi, first, dstl, C2,
                             plan.bins_per_group * geom.rb, interpret,
                             exact, geom)

@@ -24,6 +24,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from roc_tpu.obs import scopes
+
 # Mask encoding, gnn.h:98-103.
 MASK_TRAIN, MASK_VAL, MASK_TEST, MASK_NONE = 0, 1, 2, 3
 
@@ -44,30 +46,32 @@ def masked_softmax_cross_entropy(logits, labels, mask):
 
     logits: [N, C]; labels: [N, C] one-hot float; mask: [N] int32.
     """
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ce = -jnp.sum(labels * logp, axis=-1)
-    train = (mask == MASK_TRAIN).astype(logits.dtype)
-    return jnp.sum(ce * train)
+    with scopes.scope("roc.loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ce = -jnp.sum(labels * logp, axis=-1)
+        train = (mask == MASK_TRAIN).astype(logits.dtype)
+        return jnp.sum(ce * train)
 
 
 def perf_metrics(logits, labels, mask) -> PerfMetrics:
     """The reference's evaluation pass (calc_loss, softmax_kernel.cu:41-79)."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    p_true = jnp.sum(probs * labels, axis=-1)
-    # Reference picks the first strictly-greater maximum starting from 0.0;
-    # probabilities are strictly positive, so this is plain argmax.
-    correct = jnp.argmax(probs, axis=-1) == jnp.argmax(labels, axis=-1)
+    with scopes.scope("roc.metrics"):
+        probs = jax.nn.softmax(logits, axis=-1)
+        p_true = jnp.sum(probs * labels, axis=-1)
+        # Reference picks the first strictly-greater maximum starting from
+        # 0.0; probabilities are strictly positive, so this is plain argmax.
+        correct = jnp.argmax(probs, axis=-1) == jnp.argmax(labels, axis=-1)
 
-    def tally(m):
-        sel = mask == m
-        return jnp.sum(sel), jnp.sum(sel & correct)
+        def tally(m):
+            sel = mask == m
+            return jnp.sum(sel), jnp.sum(sel & correct)
 
-    train_all, train_correct = tally(MASK_TRAIN)
-    val_all, val_correct = tally(MASK_VAL)
-    test_all, test_correct = tally(MASK_TEST)
-    train_loss = jnp.sum(jnp.where(mask == MASK_TRAIN, 1.0 - p_true, 0.0))
-    return PerfMetrics(train_loss, train_all, train_correct,
-                       val_all, val_correct, test_all, test_correct)
+        train_all, train_correct = tally(MASK_TRAIN)
+        val_all, val_correct = tally(MASK_VAL)
+        test_all, test_correct = tally(MASK_TEST)
+        train_loss = jnp.sum(jnp.where(mask == MASK_TRAIN, 1.0 - p_true, 0.0))
+        return PerfMetrics(train_loss, train_all, train_correct,
+                           val_all, val_correct, test_all, test_correct)
 
 
 def format_metrics(epoch: int, m: PerfMetrics, infer: bool = True) -> str:

@@ -25,6 +25,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from roc_tpu.obs import scopes
+
 
 class AdamState(NamedTuple):
     m: Any            # pytree like params
@@ -49,7 +51,7 @@ class Adam:
 
     def update(self, params, grads, state: AdamState, alpha):
         """One step; pure/jittable.  ``alpha`` is the (host-decayed) base LR."""
-        with jax.named_scope("roc_adam_update"):
+        with scopes.scope("roc.adam"):
             t = state.t + 1
             tf = t.astype(jnp.float32)
             alpha_t = (alpha * jnp.sqrt(1.0 - self.beta2 ** tf)

@@ -41,6 +41,7 @@ from roc_tpu.analysis import retrace as _retrace
 from roc_tpu.graph.partition import (Partition, edge_block_arrays,
                                      edge_block_arrays_t, partition_graph)
 from roc_tpu.models.model import GraphCtx, refuse_dot_attention
+from roc_tpu.obs import scopes
 from roc_tpu.parallel.halo import HaloMaps, build_halo_maps
 from roc_tpu.ops.softmax import MASK_NONE
 from roc_tpu.parallel.mesh import PARTS_AXIS, make_mesh
@@ -725,24 +726,31 @@ def _exchange(gd_block, exchange: str, x):
     """Materialize the per-shard source table for a [S, H] local tensor:
     local rows ++ halo rows (one all_to_all) or the all-gathered tensor.
     (Ring mode never builds a table — see _ring_aggregate.)
-    named_scope: pure HLO metadata (xprof grouping for -profile traces —
-    the op-count budget audit is blind to it)."""
+    The device scope `roc.exchange` (obs/scopes.py) with its parts: ``down``
+    (the send rows' gather and wire encoding), ``wire`` (the collective),
+    ``up`` (decoding and the table's assembly); `python -m roc_tpu.obs
+    report -profile` prices each."""
     H = x.shape[-1]
     if exchange == "halo":
-        with jax.named_scope("roc_halo_exchange"):
-            with jax.named_scope("roc_wire_down"):
+        with scopes.scope("roc.exchange"):
+            with scopes.scope("down"):
                 send = _wire_down(jnp.take(x, gd_block.send_idx, axis=0),
                                   gd_block)                     # [P, K, H]
-            recv = jax.lax.all_to_all(send, PARTS_AXIS,
-                                      split_axis=0, concat_axis=0)
-            with jax.named_scope("roc_wire_up"):
+            with scopes.scope("wire"):
+                recv = jax.lax.all_to_all(send, PARTS_AXIS,
+                                          split_axis=0, concat_axis=0)
+            with scopes.scope("up"):
                 halo = _wire_up(recv, gd_block, x.dtype, H)
-            return jnp.concatenate(
-                [x, halo.reshape(-1, H)], axis=0)               # [S+P*K, H]
-    with jax.named_scope("roc_allgather_exchange"):
-        table = jax.lax.all_gather(_wire_down(x, gd_block), PARTS_AXIS,
-                                   tiled=True)                  # [P*S, H]
-        return _wire_up(table, gd_block, x.dtype, H)
+                return jnp.concatenate(
+                    [x, halo.reshape(-1, H)], axis=0)           # [S+P*K, H]
+    with scopes.scope("roc.exchange"):
+        with scopes.scope("down"):
+            send = _wire_down(x, gd_block)
+        with scopes.scope("wire"):
+            table = jax.lax.all_gather(send, PARTS_AXIS,
+                                       tiled=True)              # [P*S, H]
+        with scopes.scope("up"):
+            return _wire_up(table, gd_block, x.dtype, H)
 
 
 def _ring_aggregate(gd_block, shard_nodes: int, x, aggr: str):
@@ -1105,9 +1113,7 @@ def _overcommit_tables(gd_block, k: int, S: int, exchange: str, x):
     parts (padded-global ids index [P*S] in device-major == part order)."""
     H = x.shape[-1]
     if exchange != "halo":
-        table = jax.lax.all_gather(_wire_down(x, gd_block), PARTS_AXIS,
-                                   tiled=True)                  # [P*S, H]
-        return [_wire_up(table, gd_block, x.dtype, H)] * k
+        return [_exchange(gd_block, exchange, x)] * k
     sidx = gd_block.send_idx                 # [k_i, P, K] (i = sender)
     k_, P_, K = sidx.shape
     D = P_ // k
@@ -1115,10 +1121,14 @@ def _overcommit_tables(gd_block, k: int, S: int, exchange: str, x):
     # offsets: send_idx values are local to sender part i
     idx = sidx.reshape(k, D, k, K).transpose(1, 0, 2, 3) \
         + (jnp.arange(k, dtype=sidx.dtype) * S)[None, :, None, None]
-    send = _wire_down(jnp.take(x, idx.reshape(D, k * k * K), axis=0),
-                      gd_block)
-    recv = jax.lax.all_to_all(send, PARTS_AXIS, split_axis=0, concat_axis=0)
-    recv = _wire_up(recv, gd_block, x.dtype, H)
+    with scopes.scope("roc.exchange", "down"):
+        send = _wire_down(jnp.take(x, idx.reshape(D, k * k * K), axis=0),
+                          gd_block)
+    with scopes.scope("roc.exchange", "wire"):
+        recv = jax.lax.all_to_all(send, PARTS_AXIS, split_axis=0,
+                                  concat_axis=0)
+    with scopes.scope("roc.exchange", "up"):
+        recv = _wire_up(recv, gd_block, x.dtype, H)
     recv = recv.reshape(D, k, k, K, H)       # [from-dev, from-part, j, K, H]
     tables = []
     for j in range(k):
@@ -1549,11 +1559,18 @@ class SpmdTrainer(BaseTrainer):
         info["agg_backend_reason"] = self._backend_why
         return info
 
+    def announce(self):
+        """The exchange's facts before the base trainer's (attention, the
+        step's scopes)."""
+        self._announce_exchange()
+        super().announce()
+
     def _announce_exchange(self):
         """The sharded trainer's own start-up line, `# exchange: ...` on
         stderr in every run (once a pod, not once a host), and the same
         facts as one `exchange` record + gauges under -obs (what
-        _announce_attention is to a gat model); again after a reshard."""
+        _announce_attention_info is to a gat model); again after a
+        reshard."""
         info = self.exchange_info()
         if jax.process_index() == 0:
             from roc_tpu.obs.report import exchange_line
@@ -1717,7 +1734,6 @@ class SpmdTrainer(BaseTrainer):
             self._resolve_mem_plan()
         with obs.span("step_build", parts=P_):
             self._build_steps(gd)
-        self._announce_exchange()
 
     def _place_data(self, gd: ShardedGraphData):
         """Place the node tensors + graph data for the current partition
@@ -1840,7 +1856,9 @@ class SpmdTrainer(BaseTrainer):
             _retrace.note_trace("train_step")
             # per-device dropout masks: fold the device index into the key
             # (k stacked parts draw distinct rows of the same stream)
-            key = jax.random.fold_in(key, jax.lax.axis_index(PARTS_AXIS))
+            with scopes.scope("roc.rng"):
+                key = jax.random.fold_in(key,
+                                         jax.lax.axis_index(PARTS_AXIS))
             # Differentiate a device-VARYING view of the replicated params:
             # the cotangents then stay local and the psum below is the one
             # gradient all-reduce.  Under check_vma jax would otherwise
@@ -1856,9 +1874,10 @@ class SpmdTrainer(BaseTrainer):
             loss_l, grads_l = jax.value_and_grad(local_loss)(
                 params_v, x, labels, mask, gd, key)
             # all-reduce over ICI (replaces gather-to-one-GPU + serial sum)
-            grads = jax.tree.map(lambda g: jax.lax.psum(g, PARTS_AXIS),
-                                 grads_l)
-            loss = jax.lax.psum(loss_l, PARTS_AXIS)
+            with scopes.scope("roc.allreduce"):
+                grads = jax.tree.map(
+                    lambda g: jax.lax.psum(g, PARTS_AXIS), grads_l)
+                loss = jax.lax.psum(loss_l, PARTS_AXIS)
             # gscale is 1.0 on healthy steps (exact multiply); the chaos
             # harness feeds NaN to exercise the non-finite guard.  Applied
             # AFTER the psums so loss/grads are already replicated and the
@@ -1890,7 +1909,9 @@ class SpmdTrainer(BaseTrainer):
             gctx = block_gctx(gd)
             logits = model.apply(params, x, gctx, train=False)
             m = ops.perf_metrics(logits, labels, mask)
-            return jax.tree.map(lambda v: jax.lax.psum(v, PARTS_AXIS), m)
+            with scopes.scope("roc.allreduce"):
+                return jax.tree.map(lambda v: jax.lax.psum(v, PARTS_AXIS),
+                                    m)
 
         @partial(jax.shard_map, mesh=self.mesh, check_vma=check_vma,
                  in_specs=(P(), P(PARTS_AXIS), gd_specs),

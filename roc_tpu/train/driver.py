@@ -391,7 +391,7 @@ class BaseTrainer:
         self._use_edge_shard = False
         self._obs_init()
         self._setup()
-        self._announce_attention()
+        self.announce()
         self.balancer = None
         if config.balance_every:
             if self._balance_supported():
@@ -430,6 +430,7 @@ class BaseTrainer:
         the step builders see cfg.obs when shaping their outputs."""
         cfg = self.config
         self._metrics = None
+        self._step_scopes_announced = False
         self.watchdog = None
         self._last_step_metrics = None
         if not cfg.obs:
@@ -528,7 +529,24 @@ class BaseTrainer:
         info["src_scans"] = sum(map(src_scans, heads))
         return info
 
-    def _announce_attention(self):
+    def announce(self):
+        """Everything this trainer has to say of itself, to stderr and,
+        where it holds a metrics registry, as records and gauges: the
+        attention's facts, the step's own count of its device scopes (and
+        the sharded trainer's exchange before them).  At start-up; and the
+        public name for a caller that lends a registry after a run and
+        wants the gauges made again, as the benchmark's traced run does."""
+        self._announce_attention_info()
+        self._announce_step_scopes()
+
+    # benchmark/run.py's `program_gauges` (no file of the benchmark is a
+    # tracing PR's to edit) still asks for the announcements by the two
+    # private names they had when there were two; this is the one a
+    # one-chip trainer answers to.  It goes when the harness calls
+    # `announce()` (PERF.md section 7 item 6).
+    _announce_attention = announce
+
+    def _announce_attention_info(self):
         """The trainer's own start-up line for an attention model, and the
         same facts as `attention` record + gauges under -obs: numbers as
         unlabelled gauges, texts as labelled ones, every name prefixed
@@ -550,6 +568,86 @@ class BaseTrainer:
                     self._metrics.set_gauge(f"{kind}_{k}", 1.0, **{k: v})
                 else:
                     self._metrics.set_gauge(f"{kind}_{k}", v)
+
+    def _announce_step_scopes(self):
+        """What the train step's own lowering says of its device scopes,
+        as unlabelled gauges: ``step_whiles`` (its `stablehlo.while`s, on
+        this platform's lowering: the CPU's random-bit generators are
+        loops, the chip's are not) and ``step_unscoped_share`` (per cent
+        of its heavy ops that sit under no `roc.` scope,
+        obs.scopes.lowered_counts: 0.0 while every kind of device work is
+        issued under its name).  Only for a trainer that holds a metrics
+        registry and whose steps have run: `-obs`, once, when ``train()``
+        first ends (before ``metrics.prom`` is written; at start-up it
+        would trace the step ahead of its first call); the benchmark's
+        traced run, when it lends a registry after its window.  It lowers
+        the train step again (a lookup once it ran), compiles nothing, and
+        a run without a registry never comes here."""
+        if (self._metrics is None or self.epoch == 0
+                or not hasattr(self, "_train_step")):
+            return
+        from roc_tpu.analysis.hlo_audit import lower_train_step
+        from roc_tpu.obs import scopes
+        with obs.span("step_scopes"):       # what the lowering costs
+            counts = scopes.lowered_counts(lower_train_step(self))
+        self._metrics.set_gauge("step_whiles", counts["whiles"])
+        self._metrics.set_gauge(
+            "step_unscoped_share",
+            100.0 * counts["heavy_unscoped"] / max(counts["heavy"], 1))
+        self._step_scopes_announced = True
+
+    def device_scopes(self) -> dict:
+        """{"train": map, "eval": map}: for each of the two steps the
+        epoch loop runs, {instruction name: (op, pass, part)} of its
+        compiled executable (obs.scopes.describe_module).  The programs
+        are lowered anew and compiled WITHOUT JAX's persistent cache,
+        whose executables carry the names of whoever compiled them first
+        (obs/scopes.py); the instruction names are those of the
+        executables the steps run.  Minutes on a chip at a cell's size, so
+        nothing on the training path calls it: `-profile`, `python -m
+        roc_tpu.obs report -profile`, tools/device_by_scope.py."""
+        return {name: program["scopes"]
+                for name, program in self._device_programs().items()}
+
+    def _device_programs(self) -> dict:
+        """Per step: its module's name in a trace, the instruction map and
+        the count of fusions that mix op scopes."""
+        from roc_tpu.analysis.hlo_audit import lower_steps
+        from roc_tpu.obs import scopes
+        return {name: scopes.describe_module(scopes.compile_uncached(lowered))
+                for name, lowered in lower_steps(self).items()}
+
+    def _write_device_scopes(self, profile_dir: str, print_fn) -> None:
+        """``<profile_dir>/roc_scopes.json``: what `python -m roc_tpu.obs
+        report -profile` joins the trace with (the programs' maps, the
+        model's op list, the versions the instruction names belong to).
+        Observability never kills a run: whatever the compile or the file
+        system raises is one printed line."""
+        import json
+        path = os.path.join(profile_dir, "roc_scopes.json")
+        try:
+            with obs.span("device_scopes") as sp:
+                programs = self._device_programs()
+            record = {
+                "jax": jax.__version__,
+                # the runtime's own words, on one line: libtpu's build on
+                # a TPU
+                "platform_version": " ".join(
+                    jax.devices()[0].client.platform_version.split()),
+                "ops": [{"index": i, "kind": op.kind,
+                         "layer": int(op.attrs.get("layer", 0))}
+                        for i, op in enumerate(self.model.ops)],
+                "programs": programs}
+            os.makedirs(profile_dir, exist_ok=True)
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump(record, f)
+        except Exception as e:      # a backend that refuses the compile, a
+            # full disk: the run and its checkpoint stand
+            print_fn(f"# device scopes not written to {path}: "
+                     f"{type(e).__name__}: {e}")
+            return
+        print_fn(f"# device scopes written to {path} ({sp.dur_s:.1f} s of "
+                 f"lowering and compiling {len(programs)} programs)")
 
     def _obs_epoch(self, epoch: int, wall_s: float, loss, print_fn):
         """Per-epoch drain: fetch the in-graph metrics pytree (ONE
@@ -663,6 +761,8 @@ class BaseTrainer:
         # its records into this run's stream
         obs.get_ledger().detach()
         fault.detach()
+        if not self._step_scopes_announced:     # once a trainer
+            self._announce_step_scopes()
         verdict = self.watchdog.verdict() if self.watchdog else "off"
         self._metrics.emit(
             "train", epochs=stats.epochs, total_s=round(stats.total_s, 6),
@@ -860,7 +960,7 @@ class BaseTrainer:
         p_off, p_cnt = cfg.profile_window()
         prof_start = start + min(p_off, max(cfg.num_epochs - 1, 0))
         prof_stop = min(prof_start + p_cnt, start + cfg.num_epochs)
-        tracing = annotated = False
+        tracing = annotated = profiled = False
         loss = float("nan")
         rebalance_events = []
         peak_hbm = []
@@ -917,7 +1017,7 @@ class BaseTrainer:
                         device_sync(self.params)
                         jax.profiler.stop_trace()
                         obs.annotate(annotated)
-                        tracing = False
+                        tracing, profiled = False, True
                         print_fn(f"# profiler trace written to "
                                  f"{cfg.profile_dir}")
                     if epoch % cfg.eval_every == 0:
@@ -971,6 +1071,11 @@ class BaseTrainer:
         if cfg.checkpoint_path:
             with obs.span("checkpoint"):
                 self.save_checkpoint(cfg.checkpoint_path)
+        if profiled:
+            # the map the report joins the trace with: compiles of its own
+            # (minutes at a cell's size), outside every span the loop
+            # times and after the checkpoint is safe
+            self._write_device_scopes(cfg.profile_dir, print_fn)
         if cfg.verbose and self.epoch_times:
             # steady-state epoch time: median of post-compile epochs
             steady = sorted(self.epoch_times[2:] or self.epoch_times)
