@@ -259,11 +259,15 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # index.  The src side still gathers (_take_lanes by edge_src, the
 # src-keyed plan's column reads, the feature rows by the plans' nid), and
 # pays for each index list ONCE a backward where memory allows: what a layer
-# sums over the src-keyed plan is one scan, its per-edge weights stacked into one [K', E]
-# array read by one column gather (src_pos) and its node tables side by
-# side read by one row gather (src_nid): gat's dast rides dtable's scan
-# (_plan_sum's ``ride``; while the stack fits a tile's sublanes,
-# gat_src_scans), tconv's dk and dv are one sum of 2K heads.
+# sums over the src-keyed plan is one scan, its per-edge weights stacked
+# into one [K', E] array read by one column gather (src_pos) and its node
+# tables side by side read by one row gather (src_nid): gat's dast rides
+# dtable's scan (_plan_sum's ``ride``; while the stack fits a tile's
+# sublanes, gat_src_scans), tconv's dk and dv are one sum of 2K heads.
+# Tconv's backward reads dst_nid once too: de (v rows) and dq (k rows) are
+# one scan over one gather of [k | v] rows (_contract_then_sum).  What is
+# left that takes one list twice is the forward's pair, score (k rows)
+# then u (v rows), with the softmax's max and normaliser between them.
 #
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
 # backward is built from these primitives plus the src side's plain gathers
@@ -273,7 +277,9 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 _PLAN_CB_SUM = 512   # chunks per scan step, one-hot dot passes
 # the block-landing scans (_plan_blocks).  128, 256 and 512 are within 1 ms
 # a pass of each other on a v5e (the combine dot grows cb^2, the step count
-# falls); 128 is _plan_max's, so both scans pad the plan alike
+# falls); 128 is _plan_max's, so both scans pad the plan alike.  Also the
+# step of the scan that lands blocks AND sums rows (_contract_then_sum),
+# where it is worth 144 to 285 ms a pass over 512
 _PLAN_CB_BLOCKS = 128
 _PLAN_CB_MAX = 128   # smaller: the masked-max intermediate is [K, cb, cb, VB]
 _LANE_GATHER_CHUNK = 1 << 20   # indices a step of a long [K, M] lane gather
@@ -511,6 +517,32 @@ def _slot_reader(edge_w, cb: int, aligned: bool):
         K, cb, EB).transpose(1, 0, 2)
 
 
+def _over_head_lanes(slots, expand, heads_axis: int, dtype):
+    """Per-slot, per-head values spread over each head's F lanes, as the
+    ``[cb * EB, K * F]`` factor of a step's gathered rows: ``slots`` is
+    ``[cb, K, EB]`` (``heads_axis`` 1) or ``[K, cb, EB]`` (0), ``expand``
+    :func:`_head_expand`'s matrix.  Exact at "highest": one factor is 0/1."""
+    return jax.lax.dot_general(                   # [cb, EB, K*F]
+        slots, expand, (((heads_axis,), (0,)), ((), ())),
+        precision="highest", preferred_element_type=jnp.float32
+    ).astype(dtype).reshape(-1, expand.shape[1])
+
+
+def _add_window_rows(acc, g, ed, ob, precision):
+    """One step's ``[cb * EB, H]`` slot rows summed by window row
+    (ops.aggregate._one_hot_dots) onto the row accumulator ``[.., H]``."""
+    from roc_tpu.ops.aggregate import _one_hot_dots
+    from roc_tpu.ops.pallas.segment_sum import VB
+    cb = ob.shape[0]
+    # one rounding only under `fast`: the products e * h, once, at the
+    # S1 dot; the S2 dot adds float32 partial sums and stays exact
+    # (0.3 % of the pass's MXU work at six passes)
+    outs = _one_hot_dots(g, ed, ob, cb, precision, "highest")
+    base = ob[0] * VB
+    cur = jax.lax.dynamic_slice(acc, (base, 0), outs.shape)
+    return jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0))
+
+
 def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
               aligned: bool = False, ride=None):
     """Segment-sum over plan windows of per-slot values
@@ -533,7 +565,7 @@ def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
     [num_rows, K, F] (``precision`` feeds the one-hot dots); with ``ride``
     both, the pair ([num_rows, K, F], [K', num_rows]).
     """
-    from roc_tpu.ops.aggregate import _one_hot_dots, _vary_like
+    from roc_tpu.ops.aggregate import _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
     cb, acc_windows = _plan_scan_shapes(obi, num_rows, _PLAN_CB_SUM)
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
@@ -588,17 +620,8 @@ def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
         g = jnp.take(flat, ni.reshape(cb * EB), axis=0, mode="clip")
         if edge_w is not None:
             slots = read(po)                  # [cb, K (+ K'), EB]
-            g = g * jax.lax.dot_general(          # [cb, EB, K*F]
-                slots[:, :K], expand, (((1,), (0,)), ((), ())),
-                precision="highest", preferred_element_type=jnp.float32
-            ).astype(g.dtype).reshape(cb * EB, H)
-        # one rounding only under `fast`: the products e * h, once, at the
-        # S1 dot; the S2 dot adds float32 partial sums and stays exact
-        # (0.3 % of the pass's MXU work at six passes)
-        outs = _one_hot_dots(g, ed, ob, cb, precision, "highest")
-        base = ob[0] * VB
-        cur = jax.lax.dynamic_slice(acc, (base, 0), (cb * VB, H))
-        acc = jax.lax.dynamic_update_slice(acc, cur + outs, (base, 0))
+            g = g * _over_head_lanes(slots[:, :K], expand, 1, g.dtype)
+        acc = _add_window_rows(acc, g, ed, ob, precision)
         if ride is not None:
             acc_k = add_plain(acc_k, slots[:, K:], ob, ed)
         return (acc, acc_k), None
@@ -649,6 +672,59 @@ def _plan_max(edge_w, obi, edst, pos, num_rows: int):
     return acc.transpose(1, 0, 2).reshape(K, acc_windows * VB)[:, :num_rows]
 
 
+def _block_steps(edst, pos, nsteps: int, cb: int, num_edges: int):
+    """Where each step of ``cb`` chunks of the ALIGNED dst-keyed plan lands
+    in a ``[K, E]`` array: (blocks of EB positions in all, each step's
+    first LIVE block ``[nsteps]``, its chunks' block offsets from it
+    ``[nsteps, cb]``).  Plan-sized, once a pass.  The live chunks of a
+    step cover a contiguous run of blocks (consecutive pieces step the
+    block by 0 or 1); the all-masked chunks of empty windows and the pad
+    chunks carry block 0, add zeros wherever they land, and must not set
+    the base."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    nb = max(-(-num_edges // EB), 1)
+    blk = (pos[:, 0] // EB).reshape(nsteps, cb)
+    live = (jnp.min(edst, axis=1) < VB).reshape(nsteps, cb)
+    base = jnp.minimum(jnp.min(jnp.where(live, blk, nb), axis=1), nb - 1)
+    return nb, base, blk - base[:, None]
+
+
+def _blocks_carry(heads: int, nb: int, cb: int, num_edges: int, ref,
+                  init=None):
+    """The ``[K, (nb - 1 + cb) * EB]`` array :func:`_land_blocks` adds
+    into (a step's update never clamps): zeros, or ``init`` ([K, E])."""
+    from roc_tpu.ops.aggregate import _vary_like
+    from roc_tpu.ops.pallas.segment_sum import EB
+    width = (nb - 1 + cb) * EB
+    if init is None:
+        return _vary_like(jnp.zeros((heads, width), ref.dtype), ref)
+    return jnp.pad(init, ((0, 0), (0, width - num_edges)))
+
+
+def _sum_blocks(vals, of):
+    """One step's ``[K, cb, EB]`` float32 slot values, EXACT ZEROS on
+    masked slots, summed block by block as ``[K, cb * EB]`` (a one-hot dot:
+    one addend is the value, the others are zeros, the sum is exact);
+    ``of``: the chunks' block offsets from the step's base block
+    (:func:`_block_steps`)."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+    heads, cb = vals.shape[:2]
+    same_b = (jax.lax.broadcasted_iota(jnp.int32, (heads, cb, cb), 1)
+              == of[None, None, :]).astype(vals.dtype)    # [K, block, chunk]
+    return jax.lax.dot_general(                       # [K, block, EB]
+        same_b, vals, (((2,), (1,)), ((0,), (0,))), precision="highest",
+        preferred_element_type=jnp.float32).reshape(heads, cb * EB)
+
+
+def _land_blocks(out, vals, b0, of):
+    """:func:`_sum_blocks` of one step added into its aligned lane range of
+    ``out``, from the step's base block ``b0``."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+    outs = _sum_blocks(vals, of).astype(out.dtype)
+    cur = jax.lax.dynamic_slice(out, (0, b0 * EB), outs.shape)
+    return jax.lax.dynamic_update_slice(out, cur + outs, (0, b0 * EB))
+
+
 def _plan_blocks(form, heads: int, obi, edst, pos, nid, num_edges: int, ref,
                  init=None):
     """``[K, E]`` in edge order from per-slot values formed chunk by chunk
@@ -658,48 +734,24 @@ def _plan_blocks(form, heads: int, obi, edst, pos, nid, num_edges: int, ref,
     slot values, EXACT ZEROS on masked slots (``edst == VB``).  A chunk is
     one piece of one aligned block of EB positions; a window boundary
     inside a block makes several chunks of it, and every position is live
-    in exactly one.  So a step sums its chunks block by block (a one-hot
-    dot: one addend is the value, the others are zeros, the sum is exact)
-    and adds the result into one aligned lane range of the output.  The
-    live chunks of a step cover a contiguous run of blocks (consecutive
-    pieces step the block by 0 or 1), counted from the first LIVE chunk's
-    block: the all-masked chunks of empty windows and the pad chunks carry
-    block 0, add zeros wherever they land, and must not set the base.
+    in exactly one.  So a step sums its chunks block by block and adds the
+    result into one aligned lane range of the output
+    (:func:`_block_steps`, :func:`_land_blocks`).
     ``init`` ([K, E]) is what the values are added onto (default zeros).
     Nothing edge-sized exists besides the ``[K, E]`` result itself."""
-    from roc_tpu.ops.aggregate import _vary_like
-    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    from roc_tpu.ops.pallas.segment_sum import EB
     cb = min(_PLAN_CB_BLOCKS, max(8, obi.shape[0]))
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
-    nb = max(-(-num_edges // EB), 1)
-    # plan-sized, once a pass: a step's first live block, its chunks' offsets
-    blk = (pos[:, 0] // EB).reshape(nsteps, cb)
-    live = (jnp.min(edst, axis=1) < VB).reshape(nsteps, cb)
-    base = jnp.minimum(jnp.min(jnp.where(live, blk, nb), axis=1), nb - 1)
-    off = blk - base[:, None]
+    nb, base, off = _block_steps(edst, pos, nsteps, cb, num_edges)
 
     def body(out, sl):
         ob, ed, ni, b0, of = sl
-        vals = form(ob, ed, ni)                           # [K, cb, EB]
-        same_b = (jax.lax.broadcasted_iota(jnp.int32, (heads, cb, cb), 1)
-                  == of[None, None, :]).astype(vals.dtype)    # [K, block, chunk]
-        outs = jax.lax.dot_general(                       # [K, block, EB]
-            same_b, vals, (((2,), (1,)), ((0,), (0,))), precision="highest",
-            preferred_element_type=jnp.float32
-        ).reshape(heads, cb * EB).astype(out.dtype)
-        cur = jax.lax.dynamic_slice(out, (0, b0 * EB), (heads, cb * EB))
-        return jax.lax.dynamic_update_slice(out, cur + outs,
-                                            (0, b0 * EB)), None
+        return _land_blocks(out, form(ob, ed, ni), b0, of), None
 
-    # nb - 1 + cb blocks: the update never clamps
-    width = (nb - 1 + cb) * EB
-    if init is None:
-        out = _vary_like(jnp.zeros((heads, width), ref.dtype), ref)
-    else:
-        out = jnp.pad(init, ((0, 0), (0, width - num_edges)))
     out, _ = jax.lax.scan(
-        body, out, (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
-                    nid.reshape(nsteps, cb, EB), base, off))
+        body, _blocks_carry(heads, nb, cb, num_edges, ref, init),
+        (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+         nid.reshape(nsteps, cb, EB), base, off))
     return out[:, :num_edges]
 
 
@@ -714,6 +766,43 @@ def _window_rows(x):
     return jnp.pad(x, ((0, W * VB - rows), (0, 0))).reshape(W, VB * width)
 
 
+def _window_lanes(node_w, ob, ed, width: int):
+    """A step's slots' own rows of a node table, ``[cb, width, EB]`` (slots
+    on the lane axis): ``node_w`` is :func:`_window_rows` of the
+    ``[rows, width]`` table, and a chunk's window's VB rows are spread over
+    its slots by a one-hot product, exact at "highest"; masked slots
+    (``edst == VB`` matches no row) read zeros."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    cb = ob.shape[0]
+    mine = jnp.take(node_w, ob, axis=0, mode="clip").reshape(cb, VB, width)
+    s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
+          == ed[:, None, :]).astype(mine.dtype)
+    return jax.lax.dot_general(                       # [cb, width, EB]
+        mine, s1, (((1,), (1,)), ((0,), (0,))), precision="highest",
+        preferred_element_type=jnp.float32)
+
+
+def _window_slot_rows(node_w, ob, ed, width: int):
+    """:func:`_window_lanes` with the rows' width on the lane axis:
+    ``[cb, EB, width]``, what multiplies a step's gathered rows."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    cb = ob.shape[0]
+    mine = jnp.take(node_w, ob, axis=0, mode="clip").reshape(cb, VB, width)
+    s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, EB, VB), 2)
+          == ed[:, :, None]).astype(mine.dtype)
+    return jax.lax.dot_general(                       # [cb, EB, width]
+        s1, mine, (((2,), (1,)), ((0,), (0,))), precision="highest",
+        preferred_element_type=jnp.float32)
+
+
+def _contract_heads(a, b, collapse):
+    """``[K, slots]``: per head the sum over its F lanes of ``a * b``, both
+    ``[slots, K * F]``; ``collapse`` is :func:`_head_expand`'s matrix."""
+    return jax.lax.dot_general(
+        collapse, a * b, (((1,), (1,)), ((), ())), precision="highest",
+        preferred_element_type=jnp.float32)
+
+
 def _plan_broadcast(node_t, obi, edst, pos, num_edges: int, init=None):
     """``node_t[:, edge_dst]`` for a ``[K, rows]`` node table, as ``[K, E]``,
     WITHOUT a gather by edge: ``edge_dst`` is sorted, so the read is a
@@ -726,19 +815,12 @@ def _plan_broadcast(node_t, obi, edst, pos, num_edges: int, init=None):
     scan follows ``init`` in the program's order.  A lane gather by the
     same index costs 15.7 ns an index at K = 8 on a v5e (PERF.md PR 25),
     this 0.9 (PERF.md PR 28)."""
-    from roc_tpu.ops.pallas.segment_sum import EB, VB
     K = node_t.shape[0]
     # node-sized, once a pass: window w's [VB, K] values as one row
     node_w = _window_rows(node_t.T)
 
     def form(ob, ed, _):
-        cb = ob.shape[0]
-        mine = jnp.take(node_w, ob, axis=0, mode="clip").reshape(cb, VB, K)
-        s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
-              == ed[:, None, :]).astype(mine.dtype)
-        return jax.lax.dot_general(                       # [cb, K, EB]
-            mine, s1, (((1,), (1,)), ((0,), (0,))), precision="highest",
-            preferred_element_type=jnp.float32).transpose(1, 0, 2)
+        return _window_lanes(node_w, ob, ed, K).transpose(1, 0, 2)
 
     return _plan_blocks(form, K, obi, edst, pos, pos, num_edges, node_t, init)
 
@@ -752,7 +834,7 @@ def _edge_contract(du, table, obi, edst, pos, nid, num_edges: int):
     is gathered, by the plan's ``nid`` (1 + 32 N / E slots an edge).
     ``du`` holds the dst windows' rows ([rows, K, F], rows from the plan's
     row 0)."""
-    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    from roc_tpu.ops.pallas.segment_sum import EB
     rows, K, F = du.shape
     H = K * F
     du_w = _window_rows(du.reshape(rows, H))
@@ -761,19 +843,103 @@ def _edge_contract(du, table, obi, edst, pos, nid, num_edges: int):
 
     def form(ob, ed, ni):
         cb = ob.shape[0]
-        mine = jnp.take(du_w, ob, axis=0, mode="clip").reshape(cb, VB, H)
-        s1 = (jax.lax.broadcasted_iota(jnp.int32, (cb, EB, VB), 2)
-              == ed[:, :, None]).astype(mine.dtype)
-        du_e = jax.lax.dot_general(                       # [cb, EB, K*F]
-            s1, mine, (((2,), (1,)), ((0,), (0,))), precision="highest",
-            preferred_element_type=jnp.float32)
-        prod = du_e.reshape(cb * EB, H) * jnp.take(
-            tf, ni.reshape(cb * EB), axis=0, mode="clip")
-        return jax.lax.dot_general(                       # [K, cb * EB]
-            collapse, prod, (((1,), (1,)), ((), ())), precision="highest",
-            preferred_element_type=jnp.float32).reshape(K, cb, EB)
+        du_e = _window_slot_rows(du_w, ob, ed, H)
+        return _contract_heads(
+            du_e.reshape(cb * EB, H),
+            jnp.take(tf, ni.reshape(cb * EB), axis=0, mode="clip"),
+            collapse).reshape(K, cb, EB)
 
     return _plan_blocks(form, K, obi, edst, pos, nid, num_edges, du)
+
+
+def _contract_then_sum(du, dz, k, v, e, ew, obi, edst, pos, nid,
+                       num_edges: int):
+    """The dst side of a dot-product score's backward in ONE scan over the
+    aligned dst-keyed plan, where :func:`_edge_contract`,
+    :func:`_plan_broadcast` and :func:`_plan_sum` walked it three times and
+    gathered by its ``nid`` twice:
+
+      de[k, e] = Σ_f du[dst_e, k, f]·v[src_e, k, f]       (never edge-sized)
+      ds[k, e] = (ew[k, e]·de + e[k, e]·dz[k, dst_e]) / sqrt(F)    [K, E]
+      dq[i]    = Σ_{e: dst_e = i} ds[:, e] (x) k[src_e]       [rows, K, F]
+
+    Every term of ``ds`` at a slot is that slot's own (no reduction over a
+    row's edges stands between the contraction and the sum, as the softmax
+    does between the forward's pair), so a step gathers ONE row list, the
+    side-by-side ``[k | v][nid]``, forms ``de`` from the ``v`` half, ``ds``
+    in place and its addend to ``dq`` from the ``k`` half
+    (:func:`_add_window_rows`).  A gathered row costs its index, not its
+    bytes (PERF.md PR 34), so one doubled row beats two.  Float32 at
+    "highest" throughout.  Steps of ``_PLAN_CB_BLOCKS`` chunks, not the
+    sums' 512: the gathered ``[cb * EB, 2 K F]`` block then lies in VMEM
+    (v5e, the Reddit dst plan, K = 4: 11.1 ns a 1,024 B row against 14.0
+    into HBM, and the step's products stay there too: 482 ms a pass
+    against 626 at F = 32, 633 against 918 at F = 41; PERF.md PR 36).
+
+    The scan's carry is the ``[2K, E]`` stack the src-keyed scan reads
+    next (``_tconv_plan_bwd``): ``[e ; ew]`` going in, ``[ds ; ew]`` coming
+    out.  A step's live slots are one run of positions inside one aligned
+    lane range (:func:`_block_steps`), so it slices that range, takes each
+    chunk's block of ``e`` and ``ew`` out of it, and writes ``ds`` over
+    ``e`` there: no edge-sized array exists beside the stack, where blocks
+    of ``e`` made for this scan would be the forward's own (one expression)
+    and live from there to here, a layer's worth each (the compiler's
+    memory analysis for a v5e: + 0.8 GB; PERF.md PR 36).  Masked slots read
+    finite values and zeros of ``du`` and ``dz``: exact zeros.
+    ``du``: [rows, K, F]; ``dz``: [K, rows]; ``k``, ``v``: [T, K, F];
+    ``e``, ``ew``: [K, E] (``ew`` is ``e`` without dropout).
+    Returns ([ds ; ew], dq)."""
+    from roc_tpu.ops.aggregate import _vary_like
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    rows, K, F = du.shape
+    H = K * F
+    cb, acc_windows = _plan_scan_shapes(obi, rows, _PLAN_CB_BLOCKS)
+    obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
+    nb, base, off = _block_steps(edst, pos, nsteps, cb, num_edges)
+    # plan-sized, once a pass: the run of positions live in each step
+    live = (edst < VB).reshape(nsteps, cb * EB)
+    at = pos.reshape(nsteps, cb * EB)
+    lo = jnp.min(jnp.where(live, at, num_edges), axis=1)
+    hi = jnp.max(jnp.where(live, at + 1, 0), axis=1)
+    # node-sized, once a pass
+    du_w = _window_rows(du.reshape(rows, H))
+    dz_w = _window_rows(dz.T)
+    kv = jnp.concatenate([k.reshape(-1, H), v.reshape(-1, H)], axis=1)
+    expand = _head_expand(K, F, jnp.float32)
+    scale = 1.0 / np.sqrt(F)
+
+    def body(carry, sl):
+        sw, acc = carry
+        ob, ed, ni, b0, of, p0, p1 = sl
+        g = jnp.take(kv, ni.reshape(cb * EB), axis=0, mode="clip")
+        de = _contract_heads(
+            _window_slot_rows(du_w, ob, ed, H).reshape(cb * EB, H),
+            g[:, H:], expand).reshape(K, cb, EB)
+        cur = jax.lax.dynamic_slice(sw, (0, b0 * EB), (2 * K, cb * EB))
+        # a chunk's block of [e ; ew]; a pad chunk's offset is anything
+        slots = jnp.take(cur.reshape(2 * K, cb, EB), of, axis=1,
+                         mode="clip")                     # [2K, chunk, EB]
+        dz_e = _window_lanes(dz_w, ob, ed, K).transpose(1, 0, 2)
+        ds = (slots[K:] * de + slots[:K] * dz_e) * scale  # [K, chunk, EB]
+        lane = b0 * EB + jax.lax.broadcasted_iota(jnp.int32, (1, cb * EB), 1)
+        top = jnp.where((lane >= p0) & (lane < p1), _sum_blocks(ds, of),
+                        cur[:K])
+        sw = jax.lax.dynamic_update_slice(
+            sw, jnp.concatenate([top, cur[K:]], axis=0), (0, b0 * EB))
+        acc = _add_window_rows(
+            acc, g[:, :H] * _over_head_lanes(ds, expand, 0, g.dtype),
+            ed, ob, "highest")
+        return (sw, acc), None
+
+    sw = _blocks_carry(2 * K, nb, cb, num_edges, e,
+                       jnp.concatenate([e, ew], axis=0))
+    acc = _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), e)
+    (sw, acc), _ = jax.lax.scan(
+        body, (sw, acc),
+        (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+         nid.reshape(nsteps, cb, EB), base, off, lo, hi))
+    return (sw[:, :num_edges],
+            acc[:rows].astype(e.dtype).reshape(rows, K, F))
 
 
 def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
@@ -978,11 +1144,11 @@ def tconv_attend_plan(q, k, v, plans: GatPlans, num_edges: int, drop=None):
     the reference by seed, against 1.1e-3 to 2.3e-3 with a bf16 accumulate,
     which no bound separates; at "highest" they read 2e-7 to 5e-7 and the
     epoch costs 1.25 % more (9.4314 -> 9.5497 s: the row gather is the
-    pass, not the one-hot dots).  Five scans a layer
-    gather node rows, reading six tables (k for the score, v for u; v for
-    the backward's contraction, k for dq, and q beside du for dk and dv,
-    which walk the src-keyed plan as ONE scan of 2K heads) against GAT's
-    three."""
+    pass, not the one-hot dots).  Four scans a layer
+    gather node rows, reading six tables (k for the score, v for u; in the
+    backward [k | v] side by side for the contraction and dq, ONE scan over
+    the dst-keyed plan, and q beside du for dk and dv, ONE scan of 2K heads
+    over the src-keyed plan) against GAT's three."""
     key, rate = _drop_args(drop)
     return _tconv_plan(q, k, v, plans, key, num_edges, rate)
 
@@ -1038,26 +1204,23 @@ def _tconv_plan_bwd(num_edges, rate, res, gout):
                              precision="highest") / zc            # [K, N]
         with scopes.scope("edge"):
             w = _keep_scale((key, rate), K, E, e.dtype)   # the fwd's mask
-        with scopes.scope("de"):
-            de = _edge_contract(du, v, *dst, E)                   # [K, E]
-        if w is not None:
-            with scopes.scope("edge"):
-                de = de * w
-        with scopes.scope("bcast"):
-            de = _plan_broadcast(dz, *dst[:3], E, de)
-        with scopes.scope("edge"):
-            ds = e * de * (1.0 / np.sqrt(F))                      # [K, E]
-        with scopes.scope("dq"):
-            dq = _plan_sum(ds, k, *dst, N, "highest", True)       # [N, K, F]
+            ew = e if w is None else e * w
+        # de = du[dst] . v[nid], ds = (e w de + e dz[dst]) / sqrt(F) and
+        # dq = sum ds (x) k[nid] walk the SAME dst-keyed plan and nothing
+        # summed over a row's edges stands between them: one scan, one
+        # gather of [k | v] rows by dst_nid a step (three scans and two
+        # gathers before PR 36), and ds lands where the next scan reads it
+        with scopes.scope("dedq"):
+            sw, dq = _contract_then_sum(du, dz, k, v, e, ew, *dst, E)
         # dk = sum ds (x) q[nid] and dv = sum (e w) (x) du[nid] walk the
         # SAME src-keyed plan, and _plan_sum treats heads independently: the
         # pair is one scan of 2K heads, one column gather of the stacked
-        # [2K, E] weights by src_pos and one row gather of the side-by-side
-        # [N, 2K, F] table by src_nid a step, every output column the
-        # contraction it was (v5e, the Reddit src plan, K = 4: 1,253 -> 844
-        # ms a layer at F = 32, 1,572 -> 1,043 at F = 41; PERF.md PR 34)
-        with scopes.scope("edge"):      # the stack and the table: no scan
-            sw = jnp.concatenate([ds, e if w is None else e * w], axis=0)
+        # [2K, E] weights sw = [ds ; e w] by src_pos and one row gather of
+        # the side-by-side [N, 2K, F] table by src_nid a step, every output
+        # column the contraction it was (v5e, the Reddit src plan, K = 4:
+        # 1,253 -> 844 ms a layer at F = 32, 1,572 -> 1,043 at F = 41;
+        # PERF.md PR 34)
+        with scopes.scope("edge"):      # the table: no scan
             side = jnp.concatenate([q, du], axis=1)
         with scopes.scope("src"):
             dkv = _plan_sum(sw, side, *src, T, "highest")         # [T, 2K, F]

@@ -162,10 +162,14 @@ def model_has_attention(model: Model) -> bool:
 
 # node tables a TRAINING step reads by row over the plans per tconv op on
 # the plan road: k for the score and v for the weighted sum forward; v for
-# de, k for dq, and q and du side by side for dk and dv backward
-# (ops.edge.tconv_attend_plan).  The last two share ONE scan since PR 34:
-# five scans gather rows, six tables are read.
+# de, k for dq, and q and du for dk and dv backward
+# (ops.edge.tconv_attend_plan).  Six tables in FOUR scans: q and du share
+# the src-keyed scan since PR 34 (side by side, one gather by src_nid), k
+# and v the backward's dst-keyed one since PR 36 (de and dq in one scan,
+# one gather of [k | v] by dst_nid); the forward's two (score: k, u: v)
+# have the softmax between them.
 TCONV_ROW_PASSES = 6
+TCONV_ROW_SCANS = 4
 
 
 def effective_backend_why(config: Config, dataset: Dataset, model: Model,
@@ -489,9 +493,12 @@ class BaseTrainer:
         ``score`` ("dot"), ``score_bytes`` (ONE [K, E] float32 array of
         its widest op: what each per-edge array live in a layer's backward
         costs), ``residual_bytes`` (the [K, E] bytes kept for the
-        backward, e of every op) and ``row_passes`` (node tables a training
+        backward, e of every op), ``row_passes`` (node tables a training
         step reads by row over the plans, 6 an op: k, v forward; v, k, q, du
-        backward, the last two side by side in one scan).  Both kinds end
+        backward) and ``row_scans`` (the scans that gather them by an index
+        list, 4 an op: score, u, then [k | v] side by side for de and dq
+        over the dst-keyed plan and [q | du] for dk and dv over the
+        src-keyed one; 0 on the xla road).  Both kinds end
         with ``src_scans``: the scans over the src-keyed plan a training
         step makes, all in the backward: 1 an op (tconv: dk and dv
         together; gat: dast riding dtable's where ops.edge.gat_src_scans
@@ -516,7 +523,9 @@ class BaseTrainer:
         else:
             info.update(score="dot", score_bytes=max(heads) * edges * 4,
                         residual_bytes=sum(heads) * edges * 4,
-                        row_passes=TCONV_ROW_PASSES * len(heads))
+                        row_passes=TCONV_ROW_PASSES * len(heads),
+                        row_scans=TCONV_ROW_SCANS * len(heads)
+                        if on_plan else 0)
         sharded = plans is not getattr(gd, "gat_plans", None)
 
         def src_scans(k):       # of one op of k heads, a training step

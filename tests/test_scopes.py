@@ -127,10 +127,14 @@ def test_every_while_of_the_compiled_step_has_op_pass_and_part(family,
         parts = {(p, part) for op, p, part in whiles.values()
                  if kinds.get(op) == "gat"}
         assert {("fwd", "max"), ("fwd", "norm"), ("fwd", "u"),
-                ("fwd", "bcast"), ("bwd", "de"), ("bwd", "dq"),
-                ("bwd", "src"), ("bwd", "bcast")} <= parts
+                ("fwd", "bcast"), ("bwd", "src")} <= parts
+    if family == "gat":
+        assert {("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")} <= parts
     if family == "tconv":
         assert ("fwd", "score") in parts        # the contraction, forward
+        # de, dz's broadcast and dq are ONE scan of the backward
+        assert ("bwd", "dedq") in parts and not parts & {
+            ("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")}
     if family == "gcn-matmul":
         assert {(p, part) for _, p, part in whiles.values()} >= {
             ("fwd", "mm"), ("bwd", "mm")}
@@ -180,14 +184,17 @@ def test_src_scans_of_the_compiled_step_are_what_the_trainer_says(family,
 
 
 def test_row_passes_of_the_compiled_tconv_step(built):
-    """Node tables read by row, 6 an op: one scan each for `score`, `u`,
-    `de`, `dq`, and the src scan, which reads two side by side."""
+    """Node tables read by row, 6 an op, in `row_scans` scans, 4 an op: one
+    table each for `score` and `u`, two side by side for `dedq` ([k | v])
+    and for the src scan ([q | du])."""
     tr, _, text = built("tconv")
     info = tr.attention_info()
     rows = [s for s in _whiles(text).values()
-            if s[2] in ("score", "u", "de", "dq", "src")]
-    assert len(rows) == info["row_passes"] - info["src_scans"]
-    assert len(rows) + sum(s[2] == "src" for s in rows) == info["row_passes"]
+            if s[2] in ("score", "u", "de", "dq", "dedq", "src")]
+    assert len(rows) == info["row_scans"] == 12
+    assert sorted({s[2] for s in rows}) == ["dedq", "score", "src", "u"]
+    assert len(rows) + sum(s[2] in ("dedq", "src") for s in rows) \
+        == info["row_passes"]
 
 
 # -- (iii) metadata only ----------------------------------------------------
@@ -360,8 +367,9 @@ def test_the_profile_report_of_a_tiny_tconv_run(built, tmp_path):
     # every part of the rule has a line of its own, a layer and pass
     got = {(p, part) for op, p, part in times if op == "roc.05_gat"}
     assert {("fwd", "score"), ("fwd", "max"), ("fwd", "norm"), ("fwd", "u"),
-            ("fwd", "edge"), ("fwd", "bcast"), ("bwd", "de"), ("bwd", "dq"),
-            ("bwd", "src"), ("bwd", "edge"), ("bwd", "bcast")} <= got
+            ("fwd", "edge"), ("fwd", "bcast"), ("bwd", "dedq"),
+            ("bwd", "src"), ("bwd", "edge")} <= got
+    assert not got & {("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")}
     # no instruction the trace names is missing from the map
     assert all(e[0] in train["scopes"] for line in chips[0] for e in line
                if e[1] == train["module"])

@@ -14,7 +14,7 @@ from roc_tpu.graph import datasets
 from roc_tpu.models import build_model, build_tconv
 from roc_tpu.models.model import Model, attention_heads, attention_score
 from roc_tpu.ops import edge as em
-from roc_tpu.ops.pallas.segment_sum import EB
+from roc_tpu.ops.pallas.segment_sum import EB, VB
 from roc_tpu.train.config import Config, parse_args
 from roc_tpu.train.driver import Trainer, make_trainer
 
@@ -155,14 +155,35 @@ def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
         shapes += [tuple(o.aval.shape) for o in eqn.outvars
                    if getattr(o.aval, "shape", None) is not None]
     assert gathers and max(gathers) <= step_slots
-    # s, e, the mask, de, ds, the broadcasts ...
-    assert sum(1 for s in shapes if s == (K, E)) >= 8
+    # s, e, the broadcasts ... (the mask and e w with dropout); de and ds
+    # are no [K, E] arrays since PR 36: ds is written over e in the
+    # [2K, E] stack the src scan reads
+    assert sum(1 for s in shapes if s == (K, E)) >= 5
+    assert sum(1 for s in shapes if s == (2 * K, E)) >= 2
     heads_last = [s for s in shapes if len(s) >= 2 and s[-1] == K
                   and int(np.prod(s[:-1])) >= step_slots]
     assert not heads_last, heads_last[:5]
     rows_last = [s for s in shapes if len(s) >= 2 and s[-1] == K * F
                  and int(np.prod(s[:-1])) > step_slots]
     assert not rows_last, rows_last[:5]
+
+
+def _backward_pieces(kind, heads, dropout, seed):
+    """A forward over the ``kind`` graph and what the backward's scans
+    start from: (plans, (q, k, v), e, w, du, dz, gout, res)."""
+    src, dst, rows = _edges(kind, seed=seed)
+    K, F, E = heads, 8, dst.size
+    q, k, v = _qkv(rows, K, F, 20 + heads)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    key = jax.random.PRNGKey(7) if dropout else None
+    out, res = em._tconv_plan_fwd(q, k, v, plans, key, E, dropout)
+    gout = jnp.cos(out)
+    e, zc = res[5], res[6]
+    du = gout / zc.T[:, :, None]
+    dz = -jnp.einsum("nkf,nkf->kn", gout, out, precision="highest") / zc
+    w = em._keep_scale((key, dropout), K, E, e.dtype)
+    assert (w is None) == (dropout == 0.0)
+    return plans, (q, k, v), e, w, du, dz, gout, res
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.3])
@@ -176,29 +197,21 @@ def test_dk_and_dv_in_one_scan_against_the_two_sums(kind, heads, dropout,
     the same float32 contraction over the same slots in the same order, so
     at most another blocking of the same dot (1e-6 of the sum's norm)."""
     _small_steps(monkeypatch)
-    src, dst, rows = _edges(kind, seed=4)
-    K, F, E = heads, 8, dst.size
-    q, k, v = _qkv(rows, K, F, 20 + heads)
-    plans = em.build_gat_plans(src, dst, rows, rows)
+    plans, (q, k, v), e, w, du, dz, gout, res = _backward_pieces(
+        kind, heads, dropout, seed=4)
+    rows, K, F = q.shape
+    E = e.shape[1]
     assert plans.src_obi.shape[0] > 2 * 16          # several steps
-    key = jax.random.PRNGKey(7) if dropout else None
-    out, res = em._tconv_plan_fwd(q, k, v, plans, key, E, dropout)
-    gout = jnp.cos(out)
     dq, dk, dv = em._tconv_plan_bwd(E, dropout, res, gout)[:3]
     # the two sums as they were, from the same per-edge weights
-    e, zc = res[5], res[6]
     dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
     splan = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
-    du = gout / zc.T[:, :, None]
-    dz = -jnp.einsum("nkf,nkf->kn", gout, out, precision="highest") / zc
-    w = em._keep_scale((key, dropout), K, E, e.dtype)
     de = em._edge_contract(du, v, *dplan, E)
     de = em._plan_broadcast(dz, *dplan[:3], E, de if w is None else de * w)
     ds = e * de * (1.0 / np.sqrt(F))
     want_dk = em._plan_sum(ds, q, *splan, rows, "highest")
     want_dv = em._plan_sum(e if w is None else e * w, du, *splan, rows,
                            "highest")
-    assert (w is None) == (dropout == 0.0)
     for name, a, b in (("dk", dk, want_dk), ("dv", dv, want_dv)):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
         assert a.shape == (rows, K, F) and np.linalg.norm(b) > 0
@@ -207,12 +220,59 @@ def test_dk_and_dv_in_one_scan_against_the_two_sums(kind, heads, dropout,
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.3])
+@pytest.mark.parametrize("heads", [1, 4])
+@pytest.mark.parametrize("kind", ["regular", "hub"])
+def test_de_and_dq_in_one_scan_against_the_scans_it_replaced(
+        kind, heads, dropout, monkeypatch):
+    """The backward's dst side, ONE scan over one gather of [k | v] rows
+    (_contract_then_sum), against the three scans it replaced (the
+    contraction with v, dz's broadcast, the sum with k), over several steps
+    of the aligned plan: rows without an in-edge, empty windows and window
+    boundaries inside an aligned block included.  ds is the same value slot
+    by slot up to one reassociation ((e w) de + e dz for e (de w + dz)) and
+    every masked slot adds an exact zero, so it is held elementwise; dq is the same sum in steps of its own length."""
+    _small_steps(monkeypatch)
+    plans, (q, k, v), e, w, du, dz, _, _ = _backward_pieces(
+        kind, heads, dropout, seed=4)
+    rows, K, F = q.shape
+    E = e.shape[1]
+    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
+    chunks, blocks = plans.dst_obi.shape[0], -(-E // EB)
+    assert chunks > 2 * 16                          # several steps
+    live = np.asarray(plans.dst_edst) != VB
+    assert chunks > blocks and not live.all(axis=1).all()   # cut blocks
+    assert not live.any(axis=1).all()               # an empty window's chunk
+    no_in_edge = np.setdiff1d(np.arange(rows), np.asarray(
+        plans.src_nid)[np.asarray(plans.src_edst) != VB])
+    assert no_in_edge.size
+    ew = e if w is None else e * w
+    sw, dq = em._contract_then_sum(du, dz, k, v, e, ew, *dplan, E)
+    # the three scans as they were
+    de = em._edge_contract(du, v, *dplan, E)
+    de = em._plan_broadcast(dz, *dplan[:3], E, de if w is None else de * w)
+    want_ds = np.asarray(e * de * (1.0 / np.sqrt(F)), np.float64)
+    want_dq = np.asarray(em._plan_sum(jnp.asarray(want_ds, jnp.float32), k,
+                                      *dplan, rows, "highest", True),
+                         np.float64)
+    assert sw.shape == (2 * K, E) and dq.shape == (rows, K, F)
+    # the stack the src scan reads: ds over e, and e w as it came
+    np.testing.assert_array_equal(np.asarray(sw[K:]), np.asarray(ew))
+    ds = np.asarray(sw[:K], np.float64)
+    assert np.abs(want_ds).max() > 0
+    assert np.abs(ds - want_ds).max() <= 1e-6 * np.abs(want_ds).max()
+    dq = np.asarray(dq, np.float64)
+    assert np.linalg.norm(dq - want_dq) <= 1e-6 * np.linalg.norm(want_dq)
+    assert not dq[no_in_edge].any()                 # exact zeros
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
 def test_the_backward_walks_the_src_plan_once_an_op(dropout, monkeypatch):
-    """jax.grad of tconv_attend_plan: FIVE scans gather node rows (score
-    and u forward; de, dq and the fused dk / dv backward) and exactly ONE
-    of them reads 2 K F wide rows; that scan's column gather reads ONE
-    stacked [2K, E] per-edge array, and no other scan gathers from a
-    [K, E] array by column (every dst-keyed read is by aligned blocks)."""
+    """jax.grad of tconv_attend_plan: FOUR scans gather node rows (score
+    and u forward; the fused de / dq and the fused dk / dv backward) and
+    exactly TWO of them read 2 K F wide rows ([k | v] by dst_nid, [q | du]
+    by src_nid); the src scan's column gather reads ONE stacked [2K, E]
+    per-edge array, and no other scan gathers from a [K, E] or [2K, E]
+    array by column (every dst-keyed read is by aligned blocks)."""
     _small_steps(monkeypatch)
     src, dst, rows = _edges("hub", seed=6)
     K, F, E = 4, 16, dst.size
@@ -227,10 +287,40 @@ def test_the_backward_walks_the_src_plan_once_an_op(dropout, monkeypatch):
     scans = _scans_and_their_gathers(jaxpr)
     narrow = [g for g in scans if (rows, K * F) in g]
     wide = [g for g in scans if (rows, 2 * K * F) in g]
-    assert (len(narrow), len(wide)) == (4, 1)
-    assert wide[0].count((2 * K, E)) == 1 and (K, E) not in wide[0]
+    assert (len(narrow), len(wide)) == (2, 2)
     by_column = [g for g in scans if (K, E) in g or (2 * K, E) in g]
-    assert by_column == wide
+    assert len(by_column) == 1 and by_column[0] in wide
+    assert by_column[0].count((2 * K, E)) == 1 and (K, E) not in by_column[0]
+    # the other wide scan takes its blocks of e and e w out of one step's
+    # lane range of the stack, which it carries
+    fused, = [g for g in wide if g is not by_column[0]]
+    assert (2 * K, 8, EB) in fused          # _PLAN_CB_BLOCKS of _small_steps
+
+
+def test_an_additive_score_has_no_second_table_to_pair(monkeypatch):
+    """Separation by what the op is: gat's backward has ONE dst-keyed row
+    gather (de; its dq is a plain [K, E] -> [K, N] sum with no table), so
+    it keeps its scans and never reaches the fused one."""
+    def refuse(*a, **k):
+        raise AssertionError("gat_attend_plan reached _contract_then_sum")
+    monkeypatch.setattr(em, "_contract_then_sum", refuse)
+    src, dst, rows = _edges("regular", seed=2)
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((rows, 2, 8)), jnp.float32)
+    a = jnp.asarray(rng.standard_normal((2, 2, 8)), jnp.float32)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    ids = (jnp.asarray(src), jnp.asarray(dst))
+
+    def loss(h_, a_):
+        return jnp.sum(em.gat_attend_plan(h_, h_, a_[0], a_[1], plans, ids,
+                                          0.2) ** 2)
+
+    dh, da = jax.grad(loss, argnums=(0, 1))(h, a)
+    assert np.isfinite(np.asarray(dh)).all() and np.asarray(da).any()
+    q, k, v = _qkv(rows, 2, 8, 0)
+    with pytest.raises(AssertionError, match="_contract_then_sum"):
+        jax.grad(lambda q_: jnp.sum(em.tconv_attend_plan(
+            q_, k, v, plans, dst.size)))(q)
 
 
 # -- the builder and the op IR ----------------------------------------------
@@ -362,13 +452,16 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     info = tr.attention_info()
     e = ds.graph.num_edges
     assert list(info) == ["backend", "plan_pad_ratio", "score", "score_bytes",
-                          "residual_bytes", "row_passes", "src_scans"]
+                          "residual_bytes", "row_passes", "row_scans",
+                          "src_scans"]
     assert (info["backend"], info["score"]) == ("plan", "dot")
     # one [K, E] float32 array; e of each of the three ops; six tables an
-    # op read by row; ONE scan an op over the src-keyed plan (dk with dv)
+    # op read by row in four scans (k with v for de and dq, q with du for
+    # dk and dv); ONE of them an op over the src-keyed plan
     assert info["score_bytes"] == 2 * e * 4
     assert info["residual_bytes"] == 3 * 2 * e * 4
     assert info["row_passes"] == 18
+    assert info["row_scans"] == 12
     assert info["src_scans"] == 3
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
@@ -377,19 +470,22 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
         f" tconv_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
         f" tconv_score=dot tconv_score_bytes={info['score_bytes']}"
         f" tconv_residual_bytes={info['residual_bytes']}"
-        " tconv_row_passes=18 tconv_src_scans=3")
+        " tconv_row_passes=18 tconv_row_scans=12 tconv_src_scans=3")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
     assert att["backend"] == "plan" and att["tconv_score"] == "dot"
     assert att["tconv_residual_bytes"] == info["residual_bytes"]
-    assert (att["tconv_row_passes"], att["tconv_src_scans"]) == (18, 3)
-    assert list(att)[-2:] == ["tconv_row_passes", "tconv_src_scans"]
+    assert (att["tconv_row_passes"], att["tconv_row_scans"],
+            att["tconv_src_scans"]) == (18, 12, 3)
+    assert list(att)[-3:] == ["tconv_row_passes", "tconv_row_scans",
+                              "tconv_src_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     for name in ("plan_pad_ratio", "score_bytes", "residual_bytes",
-                 "row_passes", "src_scans"):
+                 "row_passes", "row_scans", "src_scans"):
         assert f"roc_tconv_{name} " in prom
     assert "roc_tconv_src_scans 3" in prom          # unlabelled: a counter
+    assert "roc_tconv_row_scans 12" in prom
     assert 'roc_tconv_backend{backend="plan"} 1' in prom
     assert 'roc_tconv_score{score="dot"} 1' in prom
     from roc_tpu.obs import report as obs_report
@@ -405,6 +501,7 @@ def test_the_xla_road_keeps_no_plan_residual(capsys):
     info = tr.attention_info()
     assert (info["backend"], info["residual_bytes"]) == ("xla", 0)
     assert info["src_scans"] == 0           # no plan is walked at all
+    assert (info["row_scans"], info["row_passes"]) == (0, 18)
     assert tr.gdata.gat_plans is None and tr.gdata.backend == "xla"
     assert "# attention: backend=xla " in capsys.readouterr().err
 
