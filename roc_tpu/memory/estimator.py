@@ -62,6 +62,11 @@ class LayerEstimate:
     bytes_boundary: int       # the layer-boundary tensor alone
     recompute_full_s: float   # forward recompute of the whole segment
     recompute_cheap_s: float  # elementwise-only recompute (KEEP under plan)
+    # what a later layer reads of this one (its boundary, and a FAR output
+    # such as GCNII's H0, read by every layer): a plan checkpoints a layer
+    # at a time and a segment's inputs are live from forward to backward,
+    # so these bytes stay whatever the layer's verdict; part of bytes_saved
+    bytes_pinned: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,10 +137,11 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
     per_layer: Dict[int, List] = {}
     for op in model.ops:
         per_layer.setdefault(op.attrs.get("layer", 0), []).append(op)
+    read_later = model.pinned_outputs()
     layers = []
     total_fwd = 0.0
     for idx in sorted(per_layer):
-        full = saved = boundary = 0
+        full = saved = boundary = pinned = 0
         fwd = cheap = 0.0
         for op in per_layer[idx]:
             in_dim = dims[op.inputs[0]]
@@ -145,9 +151,12 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
             full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize) \
                 + dot_table_bytes(op, rows, itemsize)
             fwd += t
-            if op.kind in SAVED_KINDS or op.attrs.get("ckpt_boundary"):
+            tagged = op.kind in SAVED_KINDS or op.attrs.get("ckpt_boundary")
+            if op.out in read_later:
+                pinned += out_bytes
+            if tagged or op.out in read_later:
                 saved += out_bytes
-            else:
+            if not tagged:
                 cheap += t
             if op.attrs.get("ckpt_boundary"):
                 boundary = out_bytes
@@ -157,7 +166,8 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
         layers.append(LayerEstimate(
             index=idx, name=f"L{idx}", bytes_full=int(full),
             bytes_saved=int(saved), bytes_boundary=int(boundary),
-            recompute_full_s=fwd, recompute_cheap_s=cheap))
+            recompute_full_s=fwd, recompute_cheap_s=cheap,
+            bytes_pinned=int(pinned)))
         total_fwd += fwd
     # backward ~ 2x forward (grad-of-linear is two matmuls; grad-of-
     # aggregate is one transposed aggregation + accumulation)
@@ -204,7 +214,8 @@ def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
     params = 0
     for op in model.ops:
         if op.kind == "linear":
-            params += op.attrs["in_dim"] * op.attrs["out_dim"]
+            params += (op.attrs["in_dim"] + bool(op.attrs.get("bias"))) \
+                * op.attrs["out_dim"]
         elif attention_score(op) == "dot":
             # Wq, Wk, Wv, Wr with their biases, and the gate's 3 x out
             out = op.attrs["heads"] * op.attrs["head_dim"]

@@ -5,10 +5,18 @@ OFFLOAD-candidate per layer to minimize predicted step time subject to a
 per-device HBM budget.  The cost model the DP optimizes (and that the
 brute-force acceptance test enumerates) is:
 
-  peak(d)  = fixed + sum_{keep} saved_i + max_{remat} full_i
+  peak(d)  = fixed + sum_{all} pinned_i
+                   + sum_{keep} (saved_i - pinned_i) + max_{remat} full_i
   time(d)  = base                                  if no layer remats
            = base + sum_{keep} cheap_i
                   + sum_{remat} full_i             otherwise
+
+An active plan checkpoints the forward pass a LAYER at a time (policy.py),
+and a checkpointed segment's inputs are live from forward to backward
+whatever its verdict: ``pinned_i`` is what later layers read of layer i,
+its boundary and any far output (GCNII's ``H0``, an input of every layer,
+is pinned once and recomputed by no one).  ``saved_i`` counts them too, so
+a REMAT verdict frees ``saved_i - pinned_i``.
 
 The transient ``max_{remat} full_i`` term is the working set of the
 largest rematerialized segment: its residuals exist only while its own
@@ -57,9 +65,11 @@ OFFLOAD = "offload"     # host round-trip beats recompute; executes as
                         # otherwise (MemPlan.offload_executes_as says which)
 
 # Beyond this many layers the exact DP (L knapsacks, Pareto states) gives
-# way to the greedy pack.  GNNs in this repo are 2-8 layers; 16 is already
-# far past anything the step cache has seen.
-DP_MAX_LAYERS = 16
+# way to the greedy pack.  The deepest model the tree trains is the
+# benchmark's gcnii-reddit, 18 closed layers (16 GCNII layers between two
+# dense ones), which the exact DP plans in milliseconds: its sixteen equal
+# layers collapse to one Pareto state a weight.
+DP_MAX_LAYERS = 24
 # Host-DMA round-trip bandwidth used only to flag offload candidates
 # (PCIe-class; deliberately conservative).
 OFFLOAD_BYTES_PER_S = 5e10
@@ -142,15 +152,23 @@ class MemPlan:
                 f"{off}")
 
 
+def saved_bytes(est: ModelEstimate, decisions: Sequence[str]) -> int:
+    """Bytes the plan holds from forward to backward: every layer's pinned
+    outputs and the kept layers' other tagged ones; what all-KEEP, which
+    runs unwrapped, is priced at (every op's output) without a remat."""
+    if all(d == KEEP for d in decisions):
+        return est.total_full_bytes()
+    return sum(l.bytes_saved if d == KEEP else l.bytes_pinned
+               for l, d in zip(est.layers, decisions))
+
+
 def predict_peak(est: ModelEstimate, decisions: Sequence[str]) -> int:
     """Predicted per-device peak bytes under a decision vector."""
-    kept = sum(l.bytes_saved for l, d in zip(est.layers, decisions)
-               if d == KEEP)
     remat = [l.bytes_full for l, d in zip(est.layers, decisions)
              if d != KEEP]
-    if not remat:   # all-KEEP runs unwrapped: full residuals stay live
-        return est.fixed_bytes + est.total_full_bytes()
-    return est.fixed_bytes + kept + max(remat)
+    # all-KEEP runs unwrapped: full residuals stay live, no transient
+    return est.fixed_bytes + saved_bytes(est, decisions) \
+        + max(remat, default=0)
 
 
 def predict_time(est: ModelEstimate, decisions: Sequence[str]) -> float:
@@ -170,7 +188,8 @@ def feasible(est: ModelEstimate, decisions: Sequence[str],
 def _knapsack(items, budget: int):
     """Exact 0/1 knapsack: items [(weight, value, idx)], weights/budget in
     bytes.  Returns (best_value, chosen idx frozenset).  Pareto-pruned
-    state list — exact, and small in practice (layer counts <= 16)."""
+    state list — exact, and small in practice (at most DP_MAX_LAYERS
+    layers)."""
     states = [(0, 0.0, frozenset())]       # (weight, value, chosen)
     for w, v, idx in items:
         merged = dict()
@@ -210,15 +229,20 @@ def _plan_auto(est: ModelEstimate, budget_bytes: int):
     # Order by (bytes_full, index) desc; candidate k = first rematted
     # layer in this order (fixes the transient term, forces 0..k-1 KEEP).
     order = sorted(range(L), key=lambda i: (-est.layers[i].bytes_full, i))
+    pinned = sum(l.bytes_pinned for l in est.layers)
+
+    def freed(i):       # what a REMAT verdict on layer i gives back
+        return est.layers[i].bytes_saved - est.layers[i].bytes_pinned
+
     best = None    # (time, decisions)
     for k in range(L):
         lk = est.layers[order[k]]
-        head = budget_bytes - est.fixed_bytes - lk.bytes_full - \
-            sum(est.layers[order[j]].bytes_saved for j in range(k))
+        head = budget_bytes - est.fixed_bytes - pinned - lk.bytes_full - \
+            sum(freed(order[j]) for j in range(k))
         if head < 0:
             continue
         free = order[k + 1:]
-        items = [(est.layers[i].bytes_saved,
+        items = [(freed(i),
                   est.layers[i].recompute_full_s
                   - est.layers[i].recompute_cheap_s, i) for i in free]
         _, chosen = _knapsack(items, head)
@@ -247,7 +271,8 @@ def _plan_greedy(est: ModelEstimate, budget_bytes: int):
         range(L),
         key=lambda i: (-(est.layers[i].recompute_full_s
                          - est.layers[i].recompute_cheap_s)
-                       / max(est.layers[i].bytes_saved, 1), i))
+                       / max(est.layers[i].bytes_saved
+                             - est.layers[i].bytes_pinned, 1), i))
     for i in order:
         trial = list(decisions)
         trial[i] = KEEP

@@ -4,6 +4,7 @@ from roc_tpu.models.sage import build_sage
 from roc_tpu.models.gin import build_gin
 from roc_tpu.models.gat import build_gat
 from roc_tpu.models.tconv import build_tconv
+from roc_tpu.models.gcnii import build_gcnii
 
 
 def build_model(name: str, layers, dropout_rate: float = 0.5,
@@ -12,8 +13,8 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
 
     aggr="" means "the model's own default" (gcn: sum — the reference's only
     wired AggrType; sage: avg; gin: sum, where a non-sum choice is rejected
-    because the GIN update is defined on sums).  heads only applies to gat
-    and tconv."""
+    because the GIN update is defined on sums; gcnii: sum, the operator's
+    own).  heads only applies to gat and tconv."""
     if name == "gcn":
         return build_gcn(layers, dropout_rate, aggr or "sum")
     if name == "sage":
@@ -26,8 +27,13 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
         return build_gat(layers, dropout_rate, heads=heads)
     if name == "tconv":
         return build_tconv(layers, dropout_rate, heads=heads)
-    raise ValueError(f"unknown model {name!r} (gcn|sage|gin|gat|tconv)")
+    if name == "gcnii":
+        if aggr not in ("", "sum"):
+            raise ValueError("gcnii is defined on sum aggregation")
+        return build_gcnii(layers, dropout_rate)
+    raise ValueError(
+        f"unknown model {name!r} (gcn|sage|gin|gat|tconv|gcnii)")
 
 
 __all__ = ["Model", "GraphCtx", "build_gcn", "build_sage", "build_gin",
-           "build_gat", "build_tconv", "build_model"]
+           "build_gat", "build_tconv", "build_gcnii", "build_model"]

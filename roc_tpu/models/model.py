@@ -100,6 +100,13 @@ def refuse_dot_attention(model: "Model", road: str) -> None:
             f"trains on the one-chip Trainer (-parts 1, no -stream)")
 
 
+def linear_bias(op: "OpNode", params):
+    """The bias row of a linear op that has one (``Model.linear(...,
+    bias=True)``: parameter ``<param>_bias``), else None."""
+    return params[op.attrs["param"] + "_bias"] if op.attrs.get("bias") \
+        else None
+
+
 def attention_drop(op: "OpNode", key, train: bool):
     """The ``drop`` argument of ``GraphCtx.attend`` / ``attend_dot`` for one
     gat op (either score) in one
@@ -163,12 +170,17 @@ class Model:
         return out
 
     def linear(self, t: TensorRef, out_dim: int,
-               activation: str = "none") -> TensorRef:
+               activation: str = "none", bias: bool = False) -> TensorRef:
+        """``t W`` (+ activation).  ``bias``: add a learned ``[out_dim]``
+        row, parameter ``<param>_bias``, zero at the start; off by default
+        (the reference has none, linear.cc:39-44)."""
         out = self._new(out_dim)
-        self._emit(OpNode("linear", (t.id,), out.id,
-                          {"in_dim": t.dim, "out_dim": out_dim,
-                           "activation": activation,
-                           "param": f"linear_{self.num_linear}"}))
+        attrs = {"in_dim": t.dim, "out_dim": out_dim,
+                 "activation": activation,
+                 "param": f"linear_{self.num_linear}"}
+        if bias:
+            attrs["bias"] = True
+        self._emit(OpNode("linear", (t.id,), out.id, attrs))
         self.num_linear += 1
         return out
 
@@ -248,10 +260,16 @@ class Model:
         self._emit(OpNode("activation", (t.id,), out.id, {"mode": mode}))
         return out
 
-    def add(self, a: TensorRef, b: TensorRef) -> TensorRef:
+    def add(self, a: TensorRef, b: TensorRef, wa: Optional[float] = None,
+            wb: Optional[float] = None) -> TensorRef:
+        """``a + b``, or ``wa * a + wb * b`` with scalar weights fixed at
+        build time (a weight left out is 1; without any the op is the
+        reference's ADD and its attrs stay empty)."""
         assert a.dim == b.dim
         out = self._new(a.dim)
-        self._emit(OpNode("add", (a.id, b.id), out.id, {}))
+        attrs = {k: float(w) for k, w in (("wa", wa), ("wb", wb))
+                 if w is not None}
+        self._emit(OpNode("add", (a.id, b.id), out.id, attrs))
         return out
 
     def softmax_cross_entropy(self, t: TensorRef) -> TensorRef:
@@ -273,6 +291,9 @@ class Model:
                 k = jax.random.fold_in(key, i)
                 params[op.attrs["param"]] = ops.glorot_uniform(
                     k, op.attrs["in_dim"], op.attrs["out_dim"])
+                if op.attrs.get("bias"):
+                    params[op.attrs["param"] + "_bias"] = jnp.zeros(
+                        (op.attrs["out_dim"],), jnp.float32)
                 i += 1
             elif attention_score(op) == "dot":
                 # Glorot weights, zero biases (the paper states neither)
@@ -330,23 +351,87 @@ class Model:
                     op.attrs["rate"], (num_nodes, dims[op.inputs[0]]))
         return masks
 
+    def layer_segments(self) -> List[tuple]:
+        """The closed layers as segments of the op list, in order: (layer,
+        op indices, ins, outs).  ``ins``: the tensors the layer's ops read
+        that an earlier layer (or the model's input) made: its boundary
+        and any FAR input, a tensor read by layers beyond the next, as
+        GCNII's ``H0`` is by every layer.  ``outs``: what it makes that a
+        later layer, or the loss, reads.  The memory plan checkpoints the
+        forward pass a segment at a time (roc_tpu/memory/policy.py): a
+        segment's ``ins`` are live from forward to backward whatever the
+        plan decides, so no segment recomputes another's output."""
+        by_layer: Dict[int, List[int]] = {}
+        for index, op in enumerate(self.ops):
+            by_layer.setdefault(op.attrs.get("layer", 0), []).append(index)
+        last_read = {self.logits.id: len(self.ops)} if self.logits else {}
+        for index, op in enumerate(self.ops):
+            for t in op.inputs:
+                last_read[t] = max(last_read.get(t, -1), index)
+        segments = []
+        for layer in sorted(by_layer):
+            indices = by_layer[layer]
+            made = {self.ops[i].out for i in indices}
+            ins = tuple(dict.fromkeys(
+                t for i in indices for t in self.ops[i].inputs
+                if t not in made))
+            outs = tuple(self.ops[i].out for i in indices
+                         if last_read.get(self.ops[i].out, -1) > indices[-1])
+            segments.append((layer, tuple(indices), ins, outs))
+        return segments
+
+    def pinned_outputs(self) -> set:
+        """Ids of the tensors a later layer (or the loss) reads: every
+        segment's ``outs``, live from forward to backward under every
+        memory plan because they are a later segment's inputs."""
+        return {t for _, _, _, outs in self.layer_segments() for t in outs}
+
+    def far_outputs(self) -> Dict[int, int]:
+        """{tensor id: its producer's layer} of every FAR input
+        (:meth:`layer_segments`): read by a layer beyond the one after its
+        producer's, so live across more than one boundary."""
+        layer_of = {op.out: op.attrs.get("layer", 0) for op in self.ops}
+        return {t: layer_of[t] for layer, _, ins, _ in self.layer_segments()
+                for t in ins if t in layer_of and layer > layer_of[t] + 1}
+
     # -- execution --------------------------------------------------------
     def apply(self, params: Dict[str, Any], x: jnp.ndarray, gctx: GraphCtx,
-              key=None, train: bool = False,
-              ckpt_names: bool = False) -> jnp.ndarray:
+              key=None, train: bool = False, ckpt_names: bool = False,
+              wrap_layer: Optional[Callable] = None) -> jnp.ndarray:
         """Run the op list; returns logits ([N_local, C]).
 
         ``ckpt_names=True`` tags every op output with its stable
-        ``checkpoint_name`` so a surrounding ``jax.checkpoint`` with a
+        ``checkpoint_name`` so a ``jax.checkpoint`` with a
         ``save_only_these_names`` policy (roc_tpu/memory/policy.py) can pick
         residuals.  Off by default: untagged programs are byte-identical to
-        the pre-planner ones, which the HLO budget audit pins."""
+        the pre-planner ones, which the HLO budget audit pins.
+
+        ``wrap_layer(layer, fn) -> fn`` runs the ops a closed layer at a
+        time (:meth:`layer_segments`), each layer's function ``fn(params,
+        *ins) -> outs`` handed through it first: how an active memory plan
+        puts its checkpoint around every layer.  None: one flat loop."""
         vals: Dict[int, jnp.ndarray] = {0: x}
-        for index, op in enumerate(self.ops):
-            # the op's device scope, set here and nowhere else
-            with scopes.scope(scopes.op_scope(index, op.kind)):
-                vals[op.out] = self._apply_op(op, params, vals, gctx,
-                                              key, train, ckpt_names)
+
+        def run_ops(indices, p, vals):
+            for index in indices:
+                op = self.ops[index]
+                # the op's device scope, set here and nowhere else
+                with scopes.scope(scopes.op_scope(index, op.kind)):
+                    vals[op.out] = self._apply_op(op, p, vals, gctx, key,
+                                                  train, ckpt_names)
+
+        if wrap_layer is None:
+            run_ops(range(len(self.ops)), params, vals)
+        else:
+            for layer, indices, ins, outs in self.layer_segments():
+                def segment(p, *args, indices=indices, ins=ins, outs=outs):
+                    local = dict(zip(ins, args))
+                    run_ops(indices, p, local)
+                    return tuple(local[t] for t in outs)
+
+                got = wrap_layer(layer, segment)(
+                    params, *(vals[t] for t in ins))
+                vals.update(zip(outs, got))
         assert self.logits is not None, "call softmax_cross_entropy() last"
         return vals[self.logits.id]
 
@@ -363,7 +448,7 @@ class Model:
             out = ops.dropout(k, a, op.attrs["rate"], train)
         elif op.kind == "linear":
             out = ops.linear(a, params[op.attrs["param"]],
-                             op.attrs["activation"])
+                             op.attrs["activation"], linear_bias(op, params))
         elif op.kind == "norm":
             out = ops.indegree_norm(a, gctx.in_degree)
         elif op.kind == "aggregate":
@@ -388,7 +473,8 @@ class Model:
         elif op.kind == "activation":
             out = ops.apply_activation(a, op.attrs["mode"])
         elif op.kind == "add":
-            out = ops.add(a, vals[op.inputs[1]])
+            out = ops.add(a, vals[op.inputs[1]], op.attrs.get("wa"),
+                          op.attrs.get("wb"))
         else:
             raise ValueError(f"unknown op kind {op.kind!r}")
         if ckpt_names:

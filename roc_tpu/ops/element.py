@@ -7,7 +7,13 @@ autodiff, so MUL is fully supported here.
 """
 
 
-def add(a, b):
+def add(a, b, wa=None, wb=None):
+    """``a + b``; with scalar weights, ``wa * a + wb * b`` (GCNII's initial
+    residual and identity mapping; a weight left out is 1)."""
+    if wa is not None:
+        a = a * wa
+    if wb is not None:
+        b = b * wb
     return a + b
 
 

@@ -19,8 +19,10 @@ import jax.numpy as jnp
 from roc_tpu.ops.activation import apply_activation
 
 
-def linear(x, w, activation: str = "none"):
-    """x: [N, in_dim]; w: [in_dim, out_dim]; activation in {none,relu,sigmoid}.
+def linear(x, w, activation: str = "none", bias=None):
+    """x: [N, in_dim]; w: [in_dim, out_dim]; activation in {none,relu,sigmoid};
+    ``bias`` [out_dim] is added before the activation (the reference has
+    none; a builder asks for it, models/model.py ``Model.linear``).
 
     fp32 inputs use full-precision accumulation (`highest`) to match the
     reference's cuBLAS SGEMM; bf16 inputs (the opt-in fast path) take the
@@ -29,4 +31,6 @@ def linear(x, w, activation: str = "none"):
     precision = "highest" if x.dtype == jnp.float32 else None
     out = jnp.dot(x, w.astype(x.dtype), precision=precision,
                   preferred_element_type=jnp.float32).astype(x.dtype)
+    if bias is not None:
+        out = out + bias.astype(x.dtype)
     return apply_activation(out, activation)

@@ -21,7 +21,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from roc_tpu.models.model import (Model, OpNode, attention_drop,
-                                  refuse_dot_attention)
+                                  linear_bias, refuse_dot_attention)
 from roc_tpu.memory.estimator import _op_out_dims
 from roc_tpu import ops
 
@@ -189,13 +189,14 @@ def run_segment(seg: Segment, params, table, own, esrc, edst, indeg, key,
             out = ops.dropout(k, a, op.attrs["rate"], train)
         elif op.kind == "linear":
             out = ops.linear(a, params[op.attrs["param"]],
-                             op.attrs["activation"])
+                             op.attrs["activation"], linear_bias(op, params))
         elif op.kind == "norm":
             out = ops.indegree_norm(a, indeg)
         elif op.kind == "activation":
             out = ops.apply_activation(a, op.attrs["mode"])
         elif op.kind == "add":
-            out = ops.add(a, vals[op.inputs[1]])
+            out = ops.add(a, vals[op.inputs[1]], op.attrs.get("wa"),
+                          op.attrs.get("wb"))
         else:  # pragma: no cover - split_segments asserts heads out of body
             raise ValueError(f"unstreamable op kind {op.kind!r}")
         vals[op.out] = out

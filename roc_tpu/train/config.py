@@ -59,7 +59,7 @@ class Config:
     decay_steps: int = 100
     seed: int = 1
     num_parts: int = 1            # total shards (== mesh size when > 1)
-    model: str = "gcn"            # gcn | sage | gin | gat
+    model: str = "gcn"            # gcn | sage | gin | gat | tconv | gcnii
     heads: int = 8                # attention heads (gat, tconv)
     aggr: str = ""                # "" = model default; sum|avg|max|min
     aggregate_backend: str = "auto"  # auto | xla | matmul | pallas(=binned) | binned
@@ -371,7 +371,10 @@ def parse_args(argv: List[str]) -> Config:
     p.add_argument("-parts", "-ng", "-ll:gpu", dest="num_parts", type=int,
                    default=1)
     p.add_argument("-model", default="gcn",
-                   choices=["gcn", "sage", "gin", "gat", "tconv"])
+                   choices=["gcn", "sage", "gin", "gat", "tconv", "gcnii"],
+                   help="gcn | sage | gin | gat | tconv (graph transformer) "
+                        "| gcnii (deep GCN: a hidden -layers entry is one "
+                        "GCNII layer, all equal); models.build_model")
     p.add_argument("-heads", type=int, default=8)
     p.add_argument("-aggr", default="",
                    choices=["", "sum", "avg", "max", "min"])

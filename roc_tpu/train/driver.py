@@ -541,11 +541,13 @@ class BaseTrainer:
     def announce(self):
         """Everything this trainer has to say of itself, to stderr and,
         where it holds a metrics registry, as records and gauges: the
-        attention's facts, the step's own count of its device scopes (and
-        the sharded trainer's exchange before them).  At start-up; and the
+        attention's facts, the memory plan's verdicts, the step's own count
+        of its device scopes (and the sharded trainer's exchange before
+        them).  At start-up; and the
         public name for a caller that lends a registry after a run and
         wants the gauges made again, as the benchmark's traced run does."""
         self._announce_attention_info()
+        self._announce_mem_plan()
         self._announce_step_scopes()
 
     # benchmark/run.py's `program_gauges` (no file of the benchmark is a
@@ -577,6 +579,30 @@ class BaseTrainer:
                     self._metrics.set_gauge(f"{kind}_{k}", 1.0, **{k: v})
                 else:
                     self._metrics.set_gauge(f"{kind}_{k}", v)
+
+    def _announce_mem_plan(self):
+        """What the memory plan decided (roc_tpu/memory), as unlabelled
+        gauges, under every mode: ``mem_plan_remat_layers`` /
+        ``mem_plan_kept_layers`` (closed layers by verdict; OFFLOAD counts
+        as remat where it executes as one), ``mem_plan_saved_bytes`` (what
+        the plan holds from forward to backward by the estimator's count:
+        the kept layers' tagged outputs and every layer's pinned ones; an
+        all-KEEP step runs unwrapped and is priced at every op's output)
+        and ``mem_plan_predicted_peak_bytes`` (the planner's forecast,
+        which the benchmark's `peak_hbm_gib` stands beside)."""
+        plan = getattr(self, "mem_plan", None)
+        if self._metrics is None or plan is None:
+            return
+        from roc_tpu import memory
+        # the forecast itself was ledgered where it was made
+        # (_resolve_mem_plan); this is the plan's field as a gauge
+        for name, value in (
+                ("remat_layers", plan.num_remat()),
+                ("kept_layers", len(plan.decisions) - plan.num_remat()),
+                ("saved_bytes", memory.saved_bytes(self.mem_estimate,
+                                                   plan.decisions)),
+                ("predicted_peak_bytes", plan.predicted_peak_bytes)):
+            self._metrics.set_gauge(f"mem_plan_{name}", value)
 
     def _announce_step_scopes(self):
         """What the train step's own lowering says of its device scopes,

@@ -79,16 +79,21 @@ def test_estimator_layer_structure():
 
 # -- DP optimality vs brute force -----------------------------------------
 
-def _synthetic_estimate(rng, L):
+def _synthetic_estimate(rng, L, far=False):
+    """``far``: every layer pins its boundary (a later segment's input,
+    live under every verdict) and layer 0 a far output of its own size
+    besides, as GCNII's H0 is: what an active plan never gives back."""
     layers = []
     for i in range(L):
         full = int(rng.integers(8, 100)) * 1024
         saved = int(full * rng.uniform(0.3, 0.9))
         fwd = float(rng.uniform(0.5, 5.0))
+        pinned = 0 if not far else saved // 2 if i else saved
         layers.append(LayerEstimate(
             index=i, name=f"L{i}", bytes_full=full, bytes_saved=saved,
             bytes_boundary=saved // 2, recompute_full_s=fwd,
-            recompute_cheap_s=fwd * float(rng.uniform(0.05, 0.4))))
+            recompute_cheap_s=fwd * float(rng.uniform(0.05, 0.4)),
+            bytes_pinned=pinned))
     return ModelEstimate(layers=tuple(layers), fixed_bytes=16 * 1024,
                          base_step_s=3.0 * sum(l.recompute_full_s
                                                for l in layers),
@@ -115,6 +120,9 @@ def _brute_force(est, budget):
      1836352035),
     ("tconv-reddit", dict(layers=[602, 128, 128, 41], dropout_rate=0.3,
                           heads=4), 3856353084),
+    # PR 37: eight [N, 256] float32 outputs a GCNII layer, sixteen layers
+    ("gcnii-reddit", dict(layers=[602] + [256] * 16 + [41],
+                          dropout_rate=0.5), 31850042940),
 ])
 def test_estimate_of_the_benchmarks_models_is_pinned(name, kw, total):
     """The estimator's all-KEEP bytes for the one-chip configurations of
@@ -128,11 +136,14 @@ def test_estimate_of_the_benchmarks_models_is_pinned(name, kw, total):
     assert est.total_full_bytes() == total
 
 
-@pytest.mark.parametrize("L", range(2, 9))
-def test_dp_matches_brute_force(L):
+@pytest.mark.parametrize("L,far", [(L, False) for L in range(2, 9)]
+                         + [(4, True), (7, True), (10, True)])
+def test_dp_matches_brute_force(L, far):
+    """All 2^L plans, also on a model with a far input (pinned bytes that
+    no verdict frees), up to L = 10."""
     rng = np.random.default_rng(100 + L)
     for trial in range(6):
-        est = _synthetic_estimate(rng, L)
+        est = _synthetic_estimate(rng, L, far)
         keep_peak = predict_peak(est, [KEEP] * L)
         remat_peak = predict_peak(est, [REMAT] * L)
         for frac in (0.0, 0.35, 0.6, 0.85, 1.1):
@@ -242,6 +253,173 @@ def test_zero_retraces_with_active_plan(monkeypatch):
         tr.run_epoch()
         tr.evaluate()
         g.assert_no_new_traces(snap)
+
+
+# -- a deep model with a far input: GCNII, 18 closed layers -----------------
+
+GCNII_LAYERS = [12] + [8] * 16 + [3]
+GCNII_ROWS, GCNII_EDGES = 1000, 6000
+
+
+def _gcnii_estimate():
+    model = build_model("gcnii", GCNII_LAYERS, 0.5)
+    fixed = fixed_bytes_for(model, GCNII_ROWS, GCNII_LAYERS[0],
+                            GCNII_LAYERS[-1], GCNII_EDGES)
+    return model, estimate_model(model, GCNII_ROWS, GCNII_EDGES,
+                                 fixed_bytes=fixed)
+
+
+@pytest.mark.parametrize("kept", [0, 1, 5, 11, 16])
+def test_auto_fits_a_deep_gcnii_into_a_budget_for_k_layers(kept):
+    """At a budget that admits ``kept`` of the 16 GCNII layers beside
+    every pinned boundary, H0 and one layer's transient, `auto` (the exact
+    DP: 18 <= DP_MAX_LAYERS) returns a feasible plan that keeps exactly
+    that many of them."""
+    from roc_tpu.memory.planner import DP_MAX_LAYERS
+    model, est = _gcnii_estimate()
+    assert len(est.layers) == model.num_layers == 18 <= DP_MAX_LAYERS
+    inner = est.layers[1]
+    assert all(l.bytes_saved == inner.bytes_saved
+               and l.bytes_pinned == inner.bytes_pinned == l.bytes_boundary
+               for l in est.layers[1:17])
+    floor = predict_peak(est, [REMAT] * 18)
+    # the two dense layers' own tagged extras first: they are the cheapest
+    dense = sum(l.bytes_saved - l.bytes_pinned
+                for l in (est.layers[0], est.layers[17]))
+    budget = floor + dense + kept * (inner.bytes_saved - inner.bytes_pinned)
+    plan = plan_memory(est, mode="auto", budget_bytes=budget)
+    assert plan.planner == "dp" and plan.feasible
+    assert predict_peak(est, plan.decisions) <= budget
+    assert plan.predicted_peak_bytes == predict_peak(est, plan.decisions)
+    assert sum(d == KEEP for d in plan.decisions[1:17]) == kept
+    # H0 is pinned: layer 0's boundary and nothing else of the model is
+    # read beyond the next layer
+    assert model.far_outputs() == {3: 0}
+    assert est.layers[0].bytes_pinned == est.layers[0].bytes_boundary
+
+
+@pytest.mark.parametrize("mode", ["auto", "remat"])
+def test_every_plan_saves_h0_and_no_segment_recomputes_layer_0(mode):
+    """The names an active plan holds from forward to backward include
+    H0's producer whatever layer 0's verdict (it is an input of every
+    later segment), and every layer's boundary."""
+    from roc_tpu.memory import saved_names
+    model, est = _gcnii_estimate()
+    budget = (predict_peak(est, [REMAT] * 18) + 2 * est.layers[1].bytes_saved
+              if mode == "auto" else 0)
+    plan = plan_memory(est, mode=mode, budget_bytes=budget)
+    assert plan.any_remat() and plan.decisions[0] != KEEP
+    names = saved_names(model, plan)
+    h0 = model.ops[2]
+    assert h0.kind == "activation" and h0.out == 3
+    assert h0.attrs["ckpt"] in names
+    boundaries = [op.attrs["ckpt"] for op in model.ops
+                  if op.attrs.get("ckpt_boundary")]
+    assert len(boundaries) == 18 and set(boundaries) <= set(names)
+    # a REMAT layer keeps nothing else
+    for i, verdict in enumerate(plan.decisions):
+        inner = [op.attrs["ckpt"] for op in model.ops
+                 if op.attrs["layer"] == i and op.kind == "aggregate"]
+        assert all((n in names) == (verdict == KEEP) for n in inner)
+    # every GCNII segment takes H0 in; none makes it again
+    for layer, indices, ins, outs in model.layer_segments()[1:17]:
+        assert 3 in ins and 3 not in outs
+
+
+def _gcnii_trainer(mode, budget=""):
+    from roc_tpu.graph import datasets
+    from roc_tpu.train.config import Config
+    from roc_tpu.train.driver import Trainer
+    ds = datasets.synthetic("t", 300, 3.0, GCNII_LAYERS[0], GCNII_LAYERS[-1],
+                            n_train=60, n_val=60, n_test=60, seed=41)
+    cfg = Config(layers=GCNII_LAYERS, model="gcnii", num_epochs=1,
+                 dropout_rate=0.5, eval_every=10**9, mem_plan=mode,
+                 mem_budget=budget, learning_rate=0.01, weight_decay=5e-4)
+    return Trainer(cfg, ds, build_model("gcnii", GCNII_LAYERS, 0.5))
+
+
+def test_keep_auto_and_remat_give_one_loss_and_one_set_of_gradients():
+    """Remat changes no arithmetic: from one key the three plans' steps
+    give the same loss bit for bit and gradients equal to float32 rounding
+    (a recomputed fusion may associate a product another way)."""
+    import jax
+
+    from roc_tpu.train.driver import make_gctx
+    out = {}
+    for mode, budget in (("keep", ""), ("auto", "600k"), ("remat", "")):
+        tr = _gcnii_trainer(mode, budget)
+        if mode == "auto":      # some layers kept, some not
+            kinds = set(tr.mem_plan.decisions)
+            assert KEEP in kinds and len(kinds) > 1, tr.mem_plan.summary()
+        n = tr.num_nodes
+
+        @jax.jit
+        def step(params, x, labels, mask, gdata, key, loss_fn=tr._loss_fn()):
+            return jax.value_and_grad(loss_fn)(
+                params, x, labels, mask, make_gctx(gdata, n), key=key,
+                train=True)
+
+        out[mode] = jax.device_get(step(
+            tr.params, tr.x, tr.labels, tr.mask, tr.gdata,
+            jax.random.PRNGKey(5)))
+    loss, grads = out["keep"]
+    for mode in ("auto", "remat"):
+        assert out[mode][0] == loss, mode
+        for name, g in grads.items():
+            scale = float(np.abs(g).max())
+            assert scale > 0, name
+            assert float(np.abs(out[mode][1][name] - g).max()) \
+                <= 1e-5 * scale, (mode, name)
+
+
+def test_a_recomputed_sweep_is_told_from_a_first_one_by_its_pass():
+    """In the compiled train step of an all-REMAT plan every GCNII layer's
+    aggregate runs three times under its own `roc.` scope: forward,
+    recomputed (pass "remat", read off `rematted_computation`) and
+    transposed (pass "bwd"); all-KEEP has no "remat" instruction."""
+    found = {}
+    for mode in ("keep", "remat"):
+        tr = _gcnii_trainer(mode)
+        tr.run_epoch()
+        scopes_ = tr.device_scopes()["train"].values()
+        found[mode] = {(op, pass_) for op, pass_, _ in scopes_
+                       if op and op.endswith("_aggregate")}
+    ops = {op for op, _ in found["keep"]}
+    assert len(ops) == 16
+    assert found["keep"] == {(op, p) for op in ops for p in ("fwd", "bwd")}
+    assert found["remat"] == {(op, p) for op in ops
+                              for p in ("fwd", "remat", "bwd")}
+
+
+@pytest.mark.parametrize("mode", ["keep", "auto", "remat"])
+def test_announce_sets_the_four_plan_gauges_under_every_mode(mode):
+    from roc_tpu import obs
+    from roc_tpu.memory import saved_bytes
+    tr = _gcnii_trainer(mode, "600k" if mode == "auto" else "")
+    tr._metrics = obs.MetricsRegistry()
+    try:
+        tr.announce()
+        gauges = {name: value for (name, labels), value
+                  in tr._metrics.gauges.items() if not labels}
+    finally:
+        tr._metrics = None
+    plan, est = tr.mem_plan, tr.mem_estimate
+    assert gauges["mem_plan_remat_layers"] == plan.num_remat()
+    assert gauges["mem_plan_kept_layers"] == 18 - plan.num_remat()
+    assert gauges["mem_plan_predicted_peak_bytes"] \
+        == plan.predicted_peak_bytes > 0
+    assert gauges["mem_plan_saved_bytes"] \
+        == saved_bytes(est, plan.decisions) > 0
+    pinned = sum(l.bytes_pinned for l in est.layers)
+    if mode == "keep":      # unwrapped: priced at every op's output
+        assert gauges["mem_plan_remat_layers"] == 0
+        assert gauges["mem_plan_saved_bytes"] == est.total_full_bytes()
+    elif mode == "remat":   # the pinned boundaries and H0, nothing else
+        assert gauges["mem_plan_kept_layers"] == 0
+        assert gauges["mem_plan_saved_bytes"] == pinned
+    else:
+        assert pinned < gauges["mem_plan_saved_bytes"] \
+            < est.total_full_bytes()
 
 
 def test_trainstats_carry_peak_hbm(monkeypatch):

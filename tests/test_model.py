@@ -27,12 +27,42 @@ def test_gcn_op_graph_structure():
     assert m.logits is not None and m.logits.dim == 4
 
 
-def test_gcn_deep_residual_structure():
-    # >3 entries in -layers adds a projected residual per layer (gnn.cc:86-90)
-    m = build_gcn([16, 8, 8, 4], 0.5)
-    kinds = [op.kind for op in m.ops]
-    assert kinds.count("add") == 3
-    assert m.num_linear == 6  # 3 main + 3 residual projections
+@pytest.mark.parametrize("name,adds,linears,weights", [
+    # >3 entries in -layers adds a projected residual per layer
+    # (gnn.cc:86-90): the reference's ADD, no weights, no bias
+    ("gcn", 3, 6, None),
+    # two weighted sums a GCNII layer (initial residual, identity mapping)
+    ("gcnii", 4, 4, ("wa", "wb")),
+])
+def test_deep_models_add_structure(name, adds, linears, weights):
+    from roc_tpu.models import build_model
+    m = build_model(name, [16, 8, 8, 4], 0.5)
+    found = [op for op in m.ops if op.kind == "add"]
+    assert len(found) == adds
+    assert m.num_linear == linears
+    stamps = {"layer", "ckpt", "ckpt_save", "ckpt_boundary"}
+    for op in found:
+        own = {k: v for k, v in op.attrs.items() if k not in stamps}
+        assert tuple(own) == (weights or ())
+        assert not weights or own["wa"] + own["wb"] == pytest.approx(1.0)
+    biased = [op for op in m.ops if op.kind == "linear"
+              and op.attrs.get("bias")]
+    assert len(biased) == (2 if name == "gcnii" else 0)
+
+
+def test_add_without_weights_lowers_as_before():
+    """`ops.add(a, b)` and a `Model.add` without weights are the
+    reference's ADD: one `add` and no multiply in the jaxpr; a weight
+    brings its multiply."""
+    import jax
+    from roc_tpu import ops
+    x = jnp.ones((4, 3))
+    plain = jax.make_jaxpr(lambda a, b: ops.add(a, b))(x, x)
+    assert [e.primitive.name for e in plain.eqns] == ["add"]
+    mixed = jax.make_jaxpr(lambda a, b: ops.add(a, b, 0.9, 0.1))(x, x)
+    assert [e.primitive.name for e in mixed.eqns] == ["mul", "mul", "add"]
+    np.testing.assert_allclose(ops.add(x, 2 * x, 0.9, 0.1), 1.1 * x,
+                               rtol=1e-6)
 
 
 def test_gcn_apply_shapes_and_pad_zero_preservation():

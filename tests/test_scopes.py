@@ -443,7 +443,15 @@ def test_only_a_trainer_with_a_registry_lowers_its_step(monkeypatch,
     assert calls == [tr]
     gauges = {name: v for (name, labels), v in registry.gauges.items()
               if not labels}
-    assert gauges == {
+    # the memory plan's four verdict gauges come with every announcement
+    # (all-KEEP here: two layers kept, none recomputed)
+    plan = {k: v for k, v in gauges.items() if k.startswith("mem_plan_")}
+    assert sorted(plan) == ["mem_plan_kept_layers",
+                            "mem_plan_predicted_peak_bytes",
+                            "mem_plan_remat_layers", "mem_plan_saved_bytes"]
+    assert (plan["mem_plan_kept_layers"], plan["mem_plan_remat_layers"]) \
+        == (2, 0)
+    assert {k: v for k, v in gauges.items() if k not in plan} == {
         "step_unscoped_share": 0.0,
         "step_whiles": scopes.lowered_counts(real(tr))["whiles"]}
     assert gauges["step_whiles"] > 0
