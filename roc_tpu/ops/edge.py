@@ -274,7 +274,19 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # — autodiff of the forward would otherwise transpose every gather into a
 # scatter.
 
-_PLAN_CB_SUM = 512   # chunks per scan step, one-hot dot passes
+_PLAN_CB_SUM = 512   # chunks per scan step, one-hot dot passes (the cap)
+# a _plan_sum that gathers rows steps shorter where its gathered
+# [cb * EB, lanes] float32 block would pass this (plan_sum_step).  One
+# scan alone over the Reddit plans on a v5e, ms a pass at 64 / 128 / 256
+# / 512 chunks a step (PERF.md PR 38):
+#   src, 2K F = 256 lanes   754.7 / 750.8 / 778.6 / 876.9
+#   src, 328 -> 384 lanes   854.8 / 851.7 / 928.5 / 1,079.2
+#   u, 164 -> 256 lanes     451.1 / 445.9 / 445.0 / 587.3
+#   u, 128 lanes            369.9 / 367.0 / 365.9 / 381.2
+# The 128 MiB blocks (512 chunks over 256 lanes and more) go to HBM, the
+# rest lie in VMEM; this budget keeps 128-lane rows (every gat sum) at
+# the cap and gives the wider ones 256 (256 lanes) or 128 (384)
+_PLAN_SUM_BLOCK_BYTES = 64 << 20
 # the block-landing scans (_plan_blocks).  128, 256 and 512 are within 1 ms
 # a pass of each other on a v5e (the combine dot grows cb^2, the step count
 # falls); 128 is _plan_max's, so both scans pad the plan alike.  Also the
@@ -480,6 +492,25 @@ def _head_expand(heads: int, head_dim: int, dtype):
     return jnp.repeat(jnp.eye(heads, dtype=dtype), head_dim, axis=1)
 
 
+def plan_sum_step(width: int) -> int:
+    """Chunks a step of a :func:`_plan_sum` that gathers node rows
+    ``width`` wide (K*F): the largest power of two, at most
+    ``_PLAN_CB_SUM``, whose gathered ``[cb * EB, lanes]`` float32 block,
+    the width tiled to 128 lanes, fits ``_PLAN_SUM_BLOCK_BYTES``."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+    lanes = -(-width // 128) * 128
+    cb = _PLAN_CB_SUM
+    while cb > 1 and cb * EB * lanes * 4 > _PLAN_SUM_BLOCK_BYTES:
+        cb //= 2
+    return cb
+
+
+def short_plan_sums(widths) -> int:
+    """How many of the row-gathering :func:`_plan_sum` scans over rows of
+    these widths step shorter than ``_PLAN_CB_SUM``."""
+    return sum(plan_sum_step(w) < _PLAN_CB_SUM for w in widths)
+
+
 def _plan_scan_shapes(obi, num_rows: int, cb_max: int):
     from roc_tpu.ops.pallas.segment_sum import VB
     cb = min(cb_max, max(8, obi.shape[0]))
@@ -567,7 +598,9 @@ def _plan_sum(edge_w, node_x, obi, edst, pos, nid, num_rows: int, precision,
     """
     from roc_tpu.ops.aggregate import _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
-    cb, acc_windows = _plan_scan_shapes(obi, num_rows, _PLAN_CB_SUM)
+    cb, acc_windows = _plan_scan_shapes(
+        obi, num_rows, _PLAN_CB_SUM if node_x is None
+        else plan_sum_step(node_x.shape[1] * node_x.shape[2]))
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
     K = edge_w.shape[0] if edge_w is not None else node_x.shape[1]
     ref = edge_w if edge_w is not None else node_x
@@ -871,10 +904,11 @@ def _contract_then_sum(du, dz, k, v, e, ew, obi, edst, pos, nid,
     (:func:`_add_window_rows`).  A gathered row costs its index, not its
     bytes (PERF.md PR 34), so one doubled row beats two.  Float32 at
     "highest" throughout.  Steps of ``_PLAN_CB_BLOCKS`` chunks, not the
-    sums' 512: the gathered ``[cb * EB, 2 K F]`` block then lies in VMEM
-    (v5e, the Reddit dst plan, K = 4: 11.1 ns a 1,024 B row against 14.0
-    into HBM, and the step's products stay there too: 482 ms a pass
-    against 626 at F = 32, 633 against 918 at F = 41; PERF.md PR 36).
+    sums' cap of 512: the gathered ``[cb * EB, 2 K F]`` block then lies in
+    VMEM (v5e, the Reddit dst plan, K = 4: 11.1 ns a 1,024 B row against
+    14.0 into HBM, and the step's products stay there too: 482 ms a pass
+    against 626 at F = 32, 633 against 918 at F = 41; PERF.md PR 36), as
+    :func:`plan_sum_step` keeps the plain sums' wide blocks (PR 38).
 
     The scan's carry is the ``[2K, E]`` stack the src-keyed scan reads
     next (``_tconv_plan_bwd``): ``[e ; ew]`` going in, ``[ds ; ew]`` coming

@@ -30,7 +30,7 @@ from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
 from roc_tpu.models.model import (GraphCtx, Model, attention_heads,
                                   attention_score)
-from roc_tpu.ops.edge import gat_src_scans
+from roc_tpu.ops.edge import gat_src_scans, short_plan_sums
 from roc_tpu.ops.softmax import format_metrics
 from roc_tpu.optim.adam import Adam
 from roc_tpu.train.config import Config
@@ -503,7 +503,11 @@ class BaseTrainer:
         step makes, all in the backward: 1 an op (tconv: dk and dv
         together; gat: dast riding dtable's where ops.edge.gat_src_scans
         lets it), 2 an op on the edge-sharded road (parallel/spmd.py
-        ``_egat_bwd``), 0 on the xla scans."""
+        ``_egat_bwd``), 0 on the xla scans; then ``short_scans``: the
+        row-gathering sums a training step makes (``u`` forward, the src
+        side's rows backward: K F wide, tconv's src side 2 K F) at a step
+        shorter than ops.edge's cap, by ops.edge.plan_sum_step, the rule
+        they are stepped by; 0 on the xla scans."""
         kind = attention_kind(self.model)
         if kind is None:
             return None
@@ -536,6 +540,14 @@ class BaseTrainer:
             return 1 if kind == "tconv" else gat_src_scans(k)
 
         info["src_scans"] = sum(map(src_scans, heads))
+
+        def row_widths(op):     # u's rows, then the src side's
+            kf = attention_heads(op) * op.attrs["head_dim"]
+            return kf, kf * (2 if kind == "tconv" else 1)
+
+        info["short_scans"] = short_plan_sums(
+            [w for op in self.model.ops if op.kind == "gat"
+             for w in row_widths(op)]) if on_plan else 0
         return info
 
     def announce(self):

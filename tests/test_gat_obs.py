@@ -66,7 +66,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     e = ds.graph.num_edges
     assert info["backend"] == "plan"
     assert list(info) == ["backend", "plan_pad_ratio", "score_bytes",
-                          "dst_reads", "src_scans"]
+                          "dst_reads", "src_scans", "short_scans"]
     # the backward walks the src-keyed plan once an op: dast rides
     # dtable's scan (two ops here)
     assert info["src_scans"] == 2
@@ -83,7 +83,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert line == ("# attention: backend=plan"
                     f" gat_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
                     f" gat_score_bytes={info['score_bytes']}"
-                    " gat_dst_reads=plan gat_src_scans=2")
+                    " gat_dst_reads=plan gat_src_scans=2 gat_short_scans=0")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
@@ -91,7 +91,8 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert att["gat_plan_pad_ratio"] == pytest.approx(info["plan_pad_ratio"])
     assert att["gat_score_bytes"] == info["score_bytes"]
     assert att["gat_dst_reads"] == "plan"
-    assert att["gat_src_scans"] == 2 and list(att)[-1] == "gat_src_scans"
+    assert att["gat_src_scans"] == 2 and list(att)[-2:] == [
+        "gat_src_scans", "gat_short_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     assert "roc_gat_src_scans 2" in prom            # unlabelled: a counter
     assert "roc_gat_plan_pad_ratio " in prom and "roc_gat_score_bytes " in prom
@@ -101,7 +102,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
                              str(tmp_path / "obs" / "metrics.jsonl"))
     assert "# attention: backend=plan gat_plan_pad_ratio=" in text
     assert (f"gat_score_bytes={info['score_bytes']} gat_dst_reads=plan"
-            " gat_src_scans=2") in text
+            " gat_src_scans=2 gat_short_scans=0") in text
     assert "gat_plan_build" in text
 
 
@@ -116,8 +117,9 @@ def test_the_xla_scans_still_gather_by_edge_dst(capsys):
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
     assert line.startswith("# attention: backend=xla ")
-    assert line.endswith(" gat_dst_reads=gather gat_src_scans=0")
-    assert info["src_scans"] == 0
+    assert line.endswith(
+        " gat_dst_reads=gather gat_src_scans=0 gat_short_scans=0")
+    assert info["src_scans"] == info["short_scans"] == 0
 
 
 def test_models_without_attention_say_nothing(capsys):

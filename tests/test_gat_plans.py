@@ -315,6 +315,76 @@ def test_riding_weights_against_the_two_calls(heads, riding, precision,
     assert not np.asarray(got_plain)[:, 5 * VB:6 * VB].any()
 
 
+# -- the step of a row-gathering sum, from the gathered row's width ---------
+
+# the shipped budget's steps: gat's rows (41, 64) and tconv's hidden u (128)
+# keep the cap; tconv's L2 u (164), hidden src (256), L2 src (328) do not
+_STEPS = {41: 512, 64: 512, 128: 512, 164: 256, 256: 256, 328: 128}
+
+
+@pytest.mark.parametrize("width", sorted(_STEPS))
+def test_plan_sum_step_reads_the_lane_tiled_width(width):
+    cb = em.plan_sum_step(width)
+    lanes = -(-width // 128) * 128
+    block = lambda c: c * EB * lanes * 4                    # noqa: E731
+    assert cb == _STEPS[width] and cb & (cb - 1) == 0
+    assert cb <= em._PLAN_CB_SUM and block(cb) <= em._PLAN_SUM_BLOCK_BYTES
+    assert cb == em._PLAN_CB_SUM or block(2 * cb) > em._PLAN_SUM_BLOCK_BYTES
+    # the width, not the bytes of a row, decides: 41 and 128 lanes alike
+    assert em.plan_sum_step(lanes) == cb
+    assert em.short_plan_sums([width]) == (cb < em._PLAN_CB_SUM)
+
+
+def _scan_lengths(fn, *args):
+    """The scans' trip counts, traced afresh (the module's constants are
+    read at trace time)."""
+    import jax
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr
+    return [e.params["length"] for e in _eqns(jaxpr)
+            if e.primitive.name == "scan"]
+
+
+@pytest.mark.parametrize("form", ["rows", "ride"])
+def test_plan_sum_at_the_short_step_equals_the_cap(form, monkeypatch):
+    """Rows of 2 x 100 lanes (256 tiled) under a budget of 4 chunks at that
+    width step at 4 where the cap says 16: the same sums, to float32
+    reassociation, over a packed src-keyed plan with pad chunks and an
+    empty window; the row-less call keeps the cap."""
+    import jax
+    monkeypatch.setattr(em, "_PLAN_CB_SUM", 16)
+    src, dst, rows = _edges("hub", seed=8)
+    src = np.where(src // VB == 5, src + VB, src)       # window 5: empty
+    E, K, F = dst.size, 2, 100
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    splan = em._pad_posplan(plans.src_obi, plans.src_edst, plans.src_pos,
+                            plans.src_nid, 5)           # not a step multiple
+    C = splan[0].shape[0]
+    assert C > 64 and C % 16 and 5 in np.asarray(splan[0])
+    rng = np.random.default_rng(4)
+    w, r = (jnp.asarray(rng.standard_normal((K, E)), jnp.float32)
+            for _ in range(2))
+    x = jnp.asarray(rng.standard_normal((rows, K, F)), jnp.float32)
+    ride = r if form == "ride" else None
+
+    def rowsum(w, x, r):
+        return em._plan_sum(w, x, *splan, rows, "highest", ride=r)
+
+    def plain(r):
+        return em._plan_sum(r, None, *splan, rows, "highest")
+
+    want = rowsum(w, x, ride)
+    assert _scan_lengths(rowsum, w, x, ride) == [-(-C // 16)]
+    monkeypatch.setattr(em, "_PLAN_SUM_BLOCK_BYTES", 4 * EB * 256 * 4)
+    assert em.plan_sum_step(K * F) == 4
+    assert _scan_lengths(rowsum, w, x, ride) == [-(-C // 4)]
+    assert _scan_lengths(plain, r) == [-(-C // 16)]       # no rows: the cap
+    got = rowsum(w, x, ride)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+    assert not np.asarray(jax.tree.leaves(got)[0])[5 * VB:6 * VB].any()
+
+
 def _sub_jaxprs(param):
     from jax.extend import core as jcore
     if isinstance(param, jcore.ClosedJaxpr):

@@ -197,6 +197,44 @@ def test_row_passes_of_the_compiled_tconv_step(built):
         == info["row_passes"]
 
 
+_TRIPS = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = .*?\swhile\(.*"
+                    r'"known_trip_count":\{"n":"(\d+)"\}', re.M)
+
+
+@pytest.mark.parametrize("family", ATTENTION)
+def test_short_scans_are_the_row_sums_of_the_shortened_trip_count(
+        family, built, monkeypatch):
+    """`short_scans`: the train step's row-gathering sums (`u`, `src`)
+    whose `while` runs more trips than the cap's step gives.  With a block
+    budget of 8 chunks at 128 lanes (the cap of `small_steps`), rows of
+    256 lanes step at 4 and of 384 at 2: a tconv of hidden 2 x 64 (u 128,
+    src 256 lanes) then 2 x 80 (u 160, src 320) has three; gat's rows are
+    128 lanes or narrower and keep the cap."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+    monkeypatch.setattr(em, "_PLAN_SUM_BLOCK_BYTES", 8 * EB * 128 * 4)
+    if family == "tconv":
+        cfg = Config(layers=[8, 128, 160, 4], num_epochs=1,
+                     eval_every=10**9, dropout_rate=0.3, model="tconv",
+                     heads=2, aggregate_backend="matmul", weight_decay=0.0)
+        tr = make_trainer(cfg, _dataset(), build_model(
+            "tconv", cfg.layers, cfg.dropout_rate, heads=cfg.heads))
+        text = scopes.compile_uncached(hlo_audit.lower_steps(tr)["train"])
+    else:
+        tr, _, text = built(family)
+    plans = tr.gdata.gat_plans
+    cap = {"u": -(-plans.dst_obi.shape[0] // 8),
+           "src": -(-plans.src_obi.shape[0] // 8)}
+    trips = dict(_TRIPS.findall(text))
+    rows = [(part, int(trips[name])) for name, (_, pass_, part)
+            in _whiles(text).items()
+            if (pass_, part) in (("fwd", "u"), ("bwd", "src"))]
+    assert {p for p, _ in rows} == {"u", "src"}
+    assert all(n >= cap[p] for p, n in rows)
+    short = sum(n > cap[p] for p, n in rows)
+    assert short == tr.attention_info()["short_scans"]
+    assert short == (3 if family == "tconv" else 0)
+
+
 # -- (iii) metadata only ----------------------------------------------------
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))

@@ -453,7 +453,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     e = ds.graph.num_edges
     assert list(info) == ["backend", "plan_pad_ratio", "score", "score_bytes",
                           "residual_bytes", "row_passes", "row_scans",
-                          "src_scans"]
+                          "src_scans", "short_scans"]
     assert (info["backend"], info["score"]) == ("plan", "dot")
     # one [K, E] float32 array; e of each of the three ops; six tables an
     # op read by row in four scans (k with v for de and dq, q with du for
@@ -463,6 +463,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert info["row_passes"] == 18
     assert info["row_scans"] == 12
     assert info["src_scans"] == 3
+    assert info["short_scans"] == 0         # every row here is 128 lanes
     line = next(ln for ln in capsys.readouterr().err.splitlines()
                 if ln.startswith("# attention:"))
     assert line == (
@@ -470,7 +471,8 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
         f" tconv_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
         f" tconv_score=dot tconv_score_bytes={info['score_bytes']}"
         f" tconv_residual_bytes={info['residual_bytes']}"
-        " tconv_row_passes=18 tconv_row_scans=12 tconv_src_scans=3")
+        " tconv_row_passes=18 tconv_row_scans=12 tconv_src_scans=3"
+        " tconv_short_scans=0")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
@@ -478,11 +480,11 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert att["tconv_residual_bytes"] == info["residual_bytes"]
     assert (att["tconv_row_passes"], att["tconv_row_scans"],
             att["tconv_src_scans"]) == (18, 12, 3)
-    assert list(att)[-3:] == ["tconv_row_passes", "tconv_row_scans",
-                              "tconv_src_scans"]
+    assert list(att)[-4:] == ["tconv_row_passes", "tconv_row_scans",
+                              "tconv_src_scans", "tconv_short_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     for name in ("plan_pad_ratio", "score_bytes", "residual_bytes",
-                 "row_passes", "row_scans", "src_scans"):
+                 "row_passes", "row_scans", "src_scans", "short_scans"):
         assert f"roc_tconv_{name} " in prom
     assert "roc_tconv_src_scans 3" in prom          # unlabelled: a counter
     assert "roc_tconv_row_scans 12" in prom
@@ -501,6 +503,7 @@ def test_the_xla_road_keeps_no_plan_residual(capsys):
     info = tr.attention_info()
     assert (info["backend"], info["residual_bytes"]) == ("xla", 0)
     assert info["src_scans"] == 0           # no plan is walked at all
+    assert info["short_scans"] == 0
     assert (info["row_scans"], info["row_passes"]) == (0, 18)
     assert tr.gdata.gat_plans is None and tr.gdata.backend == "xla"
     assert "# attention: backend=xla " in capsys.readouterr().err
