@@ -22,7 +22,8 @@ from roc_tpu.train.config import parse_size
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="roc_tpu.memory")
     p.add_argument("--model", default="gcn",
-                   choices=["gcn", "sage", "gin", "gat", "tconv"])
+                   choices=["gcn", "sage", "gin", "gat", "gatv2",
+                            "tconv"])
     p.add_argument("--layers", default="100-256-256-47",
                    help="dash-separated widths incl. input and classes")
     p.add_argument("--rows", type=int, default=612_258,

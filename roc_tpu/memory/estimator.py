@@ -112,8 +112,11 @@ def _op_forward_s(op, in_dim: int, out_dim: int, rows: int,
         if op.kind == "gat":
             # projection matmul + per-edge score/softmax passes on top of
             # the aggregation sweep; dot scores project four times (q, k,
-            # v at the attention heads' width, the skip at the output's)
+            # v at the attention heads' width, the skip at the output's),
+            # dynamic ones twice (xl, xr)
             proj, t = out_dim, 2.0 * t
+            if attention_score(op) == "dynamic":
+                proj = 2 * out_dim
             if attention_score(op) == "dot":
                 proj = attention_heads(op) * op.attrs["head_dim"]
                 t *= proj / max(out_dim, 1)     # sweeps at the heads' width
@@ -149,7 +152,7 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
             out_bytes = rows * out_dim * itemsize
             t = _op_forward_s(op, in_dim, out_dim, rows, edges)
             full += out_bytes + gat_edge_residual_bytes(op, edges, itemsize) \
-                + dot_table_bytes(op, rows, itemsize)
+                + attention_table_bytes(op, rows, itemsize)
             fwd += t
             tagged = op.kind in SAVED_KINDS or op.attrs.get("ckpt_boundary")
             if op.out in read_later:
@@ -179,8 +182,9 @@ def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     """Per-EDGE bytes a gat op keeps from forward to backward on the plan
     attention path (ops.edge._gat_plan_fwd): the shifted exponentials
     ``e [K, E]`` at the activation width and the score's sign ``qpos
-    [K, E]`` bool (a dot-score op keeps ``e`` alone).  Both carry edges on
-    the lane axis, so these are the
+    [K, E]`` bool (a dot or dynamic score keeps ``e`` alone: the dynamic
+    score's slope is [K F, E], recomputed in the backward's scans, never
+    kept).  Both carry edges on the lane axis, so these are the
     bytes the device holds (the old [E, K] layout held 16 x as much at
     K = 8: 128 lanes a row); the attention-dropout mask is redrawn, not
     kept.  They live inside the custom VJP: an all-KEEP step holds them
@@ -189,21 +193,26 @@ def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     dense xla path (small graphs) lets autodiff keep more than this."""
     if op.kind != "gat":
         return 0
-    # a dot-product score has no sign to keep: e alone
-    # (ops.edge._tconv_plan_fwd)
-    sign = 0 if attention_score(op) == "dot" else 1
+    # only the additive score keeps a sign (ops.edge._tconv_plan_fwd and
+    # _gatv2_plan_fwd keep e alone)
+    sign = 1 if attention_score(op) == "additive" else 0
     return attention_heads(op) * int(edges) * (itemsize + sign)
 
 
-def dot_table_bytes(op, rows: int, itemsize: int = 4) -> int:
-    """What a dot-score gat op's custom VJP holds besides ``e`` and the
-    op's output: its three node tables ``q, k, v`` [rows, attention heads x
-    head_dim] (an additive op's one table is its own projection, as wide as
-    its output and counted with it).  0 for any other op."""
-    if attention_score(op) != "dot":
-        return 0
-    return 3 * int(rows) * attention_heads(op) * int(op.attrs["head_dim"]) \
-        * itemsize
+# node tables a pair-score gat op's custom VJP holds: q, k, v (dot);
+# xl, xr (dynamic)
+PAIR_TABLES = {"dot": 3, "dynamic": 2}
+
+
+def attention_table_bytes(op, rows: int, itemsize: int = 4) -> int:
+    """What a gat op whose score reads both rows holds in its custom VJP
+    besides ``e`` and the op's output: its node tables (:data:`PAIR_TABLES`)
+    [rows, attention heads x head_dim] each.  An additive op's one table is
+    its own projection, as wide as its output and counted with it: 0, as
+    for any other op."""
+    tables = PAIR_TABLES.get(attention_score(op), 0)
+    return tables * int(rows) * attention_heads(op) \
+        * int(op.attrs["head_dim"]) * itemsize if tables else 0
 
 
 def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
@@ -221,6 +230,10 @@ def fixed_bytes_for(model, rows: int, in_dim: int, num_classes: int,
             out = op.attrs["heads"] * op.attrs["head_dim"]
             kf = attention_heads(op) * op.attrs["head_dim"]
             params += (op.attrs["in_dim"] + 1) * (3 * kf + out) + 3 * out
+        elif attention_score(op) == "dynamic":
+            # Wl, Wr and a
+            kf = op.attrs["heads"] * op.attrs["head_dim"]
+            params += 2 * op.attrs["in_dim"] * kf + kf
         elif op.kind == "gat":
             kf = op.attrs["heads"] * op.attrs["head_dim"]
             params += op.attrs["in_dim"] * kf + 2 * kf

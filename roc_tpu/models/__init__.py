@@ -3,6 +3,7 @@ from roc_tpu.models.gcn import build_gcn
 from roc_tpu.models.sage import build_sage
 from roc_tpu.models.gin import build_gin
 from roc_tpu.models.gat import build_gat
+from roc_tpu.models.gatv2 import build_gatv2
 from roc_tpu.models.tconv import build_tconv
 from roc_tpu.models.gcnii import build_gcnii
 
@@ -14,7 +15,7 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
     aggr="" means "the model's own default" (gcn: sum — the reference's only
     wired AggrType; sage: avg; gin: sum, where a non-sum choice is rejected
     because the GIN update is defined on sums; gcnii: sum, the operator's
-    own).  heads only applies to gat and tconv."""
+    own).  heads only applies to gat, gatv2 and tconv."""
     if name == "gcn":
         return build_gcn(layers, dropout_rate, aggr or "sum")
     if name == "sage":
@@ -25,6 +26,8 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
         return build_gin(layers, dropout_rate)
     if name == "gat":
         return build_gat(layers, dropout_rate, heads=heads)
+    if name == "gatv2":
+        return build_gatv2(layers, dropout_rate, heads=heads)
     if name == "tconv":
         return build_tconv(layers, dropout_rate, heads=heads)
     if name == "gcnii":
@@ -32,8 +35,9 @@ def build_model(name: str, layers, dropout_rate: float = 0.5,
             raise ValueError("gcnii is defined on sum aggregation")
         return build_gcnii(layers, dropout_rate)
     raise ValueError(
-        f"unknown model {name!r} (gcn|sage|gin|gat|tconv|gcnii)")
+        f"unknown model {name!r} (gcn|sage|gin|gat|gatv2|tconv|gcnii)")
 
 
 __all__ = ["Model", "GraphCtx", "build_gcn", "build_sage", "build_gin",
-           "build_gat", "build_tconv", "build_gcnii", "build_model"]
+           "build_gat", "build_gatv2", "build_tconv", "build_gcnii",
+           "build_model"]

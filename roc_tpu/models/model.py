@@ -52,10 +52,12 @@ class GraphCtx(NamedTuple):
     # normalised coefficients are dropped per edge and head
     # (ops.edge.attention_keep).
     attend: Optional[Callable] = None
-    # dot-product attention aggregation (a gat op with score "dot"): (q
-    # [N,K,F], k [N,K,F], v [N,K,F], drop) -> [N, K, F], ``drop`` as above.
-    # Only the one-chip trainer builds it; the other roads refuse the op.
-    attend_dot: Optional[Callable] = None
+    # attention whose score reads both rows at every edge (a gat op whose
+    # score is "dot" or "dynamic"): (score, tables, drop, **attrs) -> [N,
+    # K, F], ``drop`` as above; "dot": tables (q, k, v) [N,K,F] each;
+    # "dynamic": (xl [N,K,F], xr [N,K,F], a [K,F]) and ``slope``.  Only the
+    # one-chip trainer builds it; the other roads refuse such an op.
+    attend_pair: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,9 +79,10 @@ class OpNode:
 def attention_score(op: "OpNode") -> Optional[str]:
     """How an op scores an in-edge: "additive" (GAT's rank-one ``a_dst . h_i
     + a_src . h_j``, ``GraphCtx.attend``), "dot" (the graph transformer's
-    ``q_i . k_j / sqrt(d)``, ``GraphCtx.attend_dot``); None for an op that
-    is no attention.  Both are ops of kind "gat"; only the one-chip trainer
-    carries "dot"."""
+    ``q_i . k_j / sqrt(d)``) or "dynamic" (GATv2's ``a . LeakyReLU(xr_i +
+    xl_j)``), both ``GraphCtx.attend_pair``; None for an op that is no
+    attention.  All are ops of kind "gat"; only the one-chip trainer
+    carries a score but "additive"."""
     return op.attrs.get("score", "additive") if op.kind == "gat" else None
 
 
@@ -89,15 +92,26 @@ def attention_heads(op: "OpNode") -> int:
     return int(op.attrs["heads"]) * int(op.attrs.get("mean_heads", 1))
 
 
-def refuse_dot_attention(model: "Model", road: str) -> None:
-    """Only the one-chip Trainer carries a dot-score gat op (``-model
-    tconv``): every other road says so by name when it is built, where
-    running on would treat it as the additive op it is not."""
-    if any(attention_score(op) == "dot" for op in model.ops):
-        raise ValueError(
-            f"-model tconv: the tconv op (dot-product attention, "
-            f"ops.edge.tconv_attend_plan) is not carried by {road}; it "
-            f"trains on the one-chip Trainer (-parts 1, no -stream)")
+# the gat ops only the one-chip Trainer carries, by score: (-model, what,
+# the plan road's rule in ops.edge)
+PAIR_SCORES = {"dot": ("tconv", "dot-product attention", "tconv_attend_plan"),
+               "dynamic": ("gatv2", "dynamic attention",
+                           "gatv2_attend_plan")}
+
+
+def refuse_pair_attention(model: "Model", road: str) -> None:
+    """Only the one-chip Trainer carries a gat op whose score is not
+    additive (``-model tconv``, ``-model gatv2``): every other road says so
+    by name, with the score, when it is built, where running on would treat
+    it as the additive op it is not."""
+    for op in model.ops:
+        score = attention_score(op)
+        if score in PAIR_SCORES:
+            name, what, rule = PAIR_SCORES[score]
+            raise ValueError(
+                f"-model {name}: the {name} op ({what}, score {score!r}, "
+                f"ops.edge.{rule}) is not carried by {road}; it trains on "
+                f"the one-chip Trainer (-parts 1, no -stream)")
 
 
 def linear_bias(op: "OpNode", params):
@@ -108,8 +122,8 @@ def linear_bias(op: "OpNode", params):
 
 
 def attention_drop(op: "OpNode", key, train: bool):
-    """The ``drop`` argument of ``GraphCtx.attend`` / ``attend_dot`` for one
-    gat op (either score) in one
+    """The ``drop`` argument of ``GraphCtx.attend`` / ``attend_pair`` for one
+    gat op (any score) in one
     step: (the step's key folded with the op's dropout slot, the rate) in
     training when the op drops coefficients, else None.  The one place the
     key is derived, so a test can ask ops.edge.attention_keep for the very
@@ -238,6 +252,27 @@ class Model:
         self.num_linear += 1
         return out
 
+    def gatv2(self, t: TensorRef, head_dim: int, heads: int = 1,
+              slope: float = 0.2, attn_drop: float = 0.0) -> TensorRef:
+        """GATv2's dynamic attention (Brody, Alon, Yahav, ICLR 2022,
+        arXiv:2105.14491 eq 7; PyG ``GATv2Conv(heads, concat=True,
+        share_weights=False, bias=False)``): xl = t Wl for sources and
+        messages, xr = t Wr for targets, the score of j -> i is
+        ``a . LeakyReLU(xr_i + xl_j)`` per head, and the heads' weighted
+        sums of xl rows are concatenated.  A gat op whose ``score`` is
+        "dynamic" (:func:`attention_score`); ``attn_drop`` as for
+        :meth:`gat`."""
+        out = self._new(head_dim * heads)
+        attrs = {"in_dim": t.dim, "head_dim": head_dim, "heads": heads,
+                 "slope": slope, "score": "dynamic", "attn_drop": attn_drop,
+                 "param": f"gatv2_{self.num_linear}"}
+        if attn_drop:
+            attrs["slot"] = self.num_dropout
+            self.num_dropout += 1
+        self._emit(OpNode("gat", (t.id,), out.id, attrs))
+        self.num_linear += 1
+        return out
+
     def layer_norm(self, t: TensorRef) -> TensorRef:
         """Row LayerNorm with gain and bias (ops.layer_norm)."""
         out = self._new(t.dim)
@@ -308,6 +343,17 @@ class Model:
                     params[f"{name}_b{s}"] = jnp.zeros((width,), jnp.float32)
                 params[name + "_wg"] = ops.glorot_uniform(
                     jax.random.fold_in(k, 5), 3 * out, 1)[:, 0]
+                i += 1
+            elif attention_score(op) == "dynamic":
+                # Glorot Wl, Wr and a, no bias (the paper's equations)
+                name, k = op.attrs["param"], jax.random.fold_in(key, i)
+                kk, fd = op.attrs["heads"], op.attrs["head_dim"]
+                for j, s in enumerate(("wl", "wr")):
+                    params[f"{name}_{s}"] = ops.glorot_uniform(
+                        jax.random.fold_in(k, j + 1), op.attrs["in_dim"],
+                        kk * fd)
+                params[name + "_a"] = ops.glorot_uniform(
+                    jax.random.fold_in(k, 3), kk * fd, 1).reshape(kk, fd)
                 i += 1
             elif op.kind == "gat":
                 name = op.attrs["param"]
@@ -456,6 +502,17 @@ class Model:
         elif attention_score(op) == "dot":
             out = self._apply_tconv(op, params, a, gctx,
                                     attention_drop(op, key, train))
+        elif attention_score(op) == "dynamic":
+            assert gctx.attend_pair is not None, \
+                "this GraphCtx was built without dynamic attention support"
+            name = op.attrs["param"]
+            kk, fd = op.attrs["heads"], op.attrs["head_dim"]
+            xl, xr = (ops.linear(a, params[f"{name}_{s}"]).reshape(-1, kk, fd)
+                      for s in ("wl", "wr"))
+            out = gctx.attend_pair("dynamic", (xl, xr, params[name + "_a"]),
+                                   attention_drop(op, key, train),
+                                   slope=op.attrs["slope"]).reshape(-1,
+                                                                    kk * fd)
         elif op.kind == "gat":
             assert gctx.attend is not None, \
                 "this GraphCtx was built without attention support"
@@ -487,7 +544,7 @@ class Model:
         attention over in-edges of the projected rows; r = x Wr + br; b =
         sigmoid(wg . [m ; r ; m - r]); out = (1 - b) m + b r.  The gate's
         products are float32 at "highest", like the scores."""
-        assert gctx.attend_dot is not None, \
+        assert gctx.attend_pair is not None, \
             "this GraphCtx was built without dot-product attention support"
         name = op.attrs["param"]
         kk, fd = attention_heads(op), op.attrs["head_dim"]
@@ -497,8 +554,8 @@ class Model:
             return ops.linear(x, params[f"{name}_w{s}"]) \
                 + params[f"{name}_b{s}"].astype(x.dtype)
 
-        m = gctx.attend_dot(*(proj(s).reshape(-1, kk, fd) for s in "qkv"),
-                            drop)
+        m = gctx.attend_pair("dot", tuple(proj(s).reshape(-1, kk, fd)
+                                          for s in "qkv"), drop)
         if per > 1:     # average within a group, then concatenate groups
             m = jnp.mean(m.reshape(-1, groups, per, fd), axis=2)
         m = m.reshape(-1, groups * fd)

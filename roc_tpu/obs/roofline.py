@@ -112,6 +112,13 @@ def model_flops_bytes(model, num_nodes: int, num_edges: int,
             flops += 6.0 * N * a * (3 * proj + out) + 6 * 2.0 * E * proj
             nbytes += 3.0 * (N * a * b + N * (3 * proj + out) * b)
             nbytes += 6.0 * (E * proj * b + N * proj * b + E * 4)
+        elif op.kind == "gat" and op.attrs.get("score") == "dynamic":
+            # two projections; four row sweeps (forward the score and the
+            # weighted sum; backward one over each plan), 2 E D FLOPs each
+            out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
+            flops += 6.0 * N * a * 2 * out + 4 * 2.0 * E * out
+            nbytes += 3.0 * (N * a * b + N * 2 * out * b)
+            nbytes += 4.0 * (E * out * b + N * out * b + E * 4)
         elif op.kind == "gat":
             out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
             flops += 6.0 * N * a * out + 4.0 * E * out
@@ -156,6 +163,12 @@ def forward_flops_bytes(model, num_nodes: int, num_edges: int,
             flops += 2.0 * N * a * (3 * proj + out) + 2 * 2.0 * E * proj
             nbytes += N * a * b + N * (3 * proj + out) * b
             nbytes += 2.0 * (E * proj * b + N * proj * b + E * 4)
+        elif op.kind == "gat" and op.attrs.get("score") == "dynamic":
+            # forward: two projections, the score and the weighted sum
+            out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
+            flops += 2.0 * N * a * 2 * out + 2 * 2.0 * E * out
+            nbytes += N * a * b + N * 2 * out * b
+            nbytes += 2.0 * (E * out * b + N * out * b + E * 4)
         elif op.kind == "gat":
             out = int(op.attrs["heads"]) * int(op.attrs["head_dim"])
             flops += 2.0 * N * a * out + 2.0 * E * out

@@ -3,7 +3,8 @@ from roc_tpu.ops.aggregate import (
     divide_by_degree, matmul_precision, pad_binned_plans, pad_plans,
     scatter_gather, scatter_gather_binned, scatter_gather_matmul)
 from roc_tpu.ops.edge import (GatPlans, build_gat_plans, edge_softmax,
-                              gat_attend, gat_attend_plan, pad_gat_plans,
+                              gat_attend, gat_attend_plan, gatv2_attend,
+                              gatv2_attend_plan, pad_gat_plans,
                               tconv_attend, tconv_attend_plan)
 from roc_tpu.ops.norm import indegree_norm, layer_norm
 from roc_tpu.ops.linear import linear
@@ -20,7 +21,7 @@ __all__ = [
     "BinnedPlans", "build_binned_plans",
     "pad_binned_plans", "matmul_precision", "divide_by_degree",
     "edge_softmax", "gat_attend", "gat_attend_plan",
-    "tconv_attend", "tconv_attend_plan",
+    "tconv_attend", "tconv_attend_plan", "gatv2_attend", "gatv2_attend_plan",
     "GatPlans", "build_gat_plans", "pad_gat_plans",
     "indegree_norm", "layer_norm", "linear", "relu", "sigmoid", "elu",
     "apply_activation", "add",

@@ -923,6 +923,35 @@ def _contract_then_sum(du, dz, k, v, e, ew, obi, edst, pos, nid,
     ``du``: [rows, K, F]; ``dz``: [K, rows]; ``k``, ``v``: [T, K, F];
     ``e``, ``ew``: [K, E] (``ew`` is ``e`` without dropout).
     Returns ([ds ; ew], dq)."""
+    def tables(rows, K, F):
+        H = K * F
+        kv = jnp.concatenate([k.reshape(-1, H), v.reshape(-1, H)], axis=1)
+        expand = _head_expand(K, F, jnp.float32)
+
+        def addend(g, ds, ob, ed, extra):
+            return g[:, :H] * _over_head_lanes(ds, expand, 0, g.dtype), extra
+
+        return kv, expand, lambda g: g[:, H:], addend, None
+
+    sw, dq, _ = _land_ds_then_sum(du, dz, e, ew, obi, edst, pos, nid,
+                                  num_edges, 1.0 / np.sqrt(du.shape[2]),
+                                  tables)
+    return sw, dq
+
+
+def _land_ds_then_sum(du, dz, e, ew, obi, edst, pos, nid, num_edges: int,
+                      scale, tables):
+    """The scan both pair scores' backward makes over the aligned dst-keyed
+    plan (:func:`_contract_then_sum`, :func:`_dynamic_then_sum`).
+    ``tables(rows, K, F)`` gives the score's own part, made once a pass:
+    (the node table a step gathers by ``nid``, :func:`_head_expand`'s
+    matrix, ``de_rows(g)``: the gathered rows ``du`` is contracted with for
+    ``de``, ``addend(g, ds, ob, ed, extra) -> (rows [slots, K F], extra)``:
+    what the step sums by window row, and ``extra0()``: the initial value
+    of a carry of the score's own, or None).  A step gathers the table
+    once, forms ``ds = (ew de + e dz[dst]) * scale`` (``scale`` None: no
+    factor), writes it over ``e`` in the ``[2K, E]`` carry and sums the
+    addend.  Returns ([ds ; ew], the row sums [rows, K, F], extra)."""
     from roc_tpu.ops.aggregate import _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
     rows, K, F = du.shape
@@ -938,42 +967,42 @@ def _contract_then_sum(du, dz, k, v, e, ew, obi, edst, pos, nid,
     # node-sized, once a pass
     du_w = _window_rows(du.reshape(rows, H))
     dz_w = _window_rows(dz.T)
-    kv = jnp.concatenate([k.reshape(-1, H), v.reshape(-1, H)], axis=1)
-    expand = _head_expand(K, F, jnp.float32)
-    scale = 1.0 / np.sqrt(F)
+    table, expand, de_rows, addend, extra0 = tables(rows, K, F)
 
     def body(carry, sl):
-        sw, acc = carry
+        sw, acc, extra = carry
         ob, ed, ni, b0, of, p0, p1 = sl
-        g = jnp.take(kv, ni.reshape(cb * EB), axis=0, mode="clip")
+        g = jnp.take(table, ni.reshape(cb * EB), axis=0, mode="clip")
         de = _contract_heads(
             _window_slot_rows(du_w, ob, ed, H).reshape(cb * EB, H),
-            g[:, H:], expand).reshape(K, cb, EB)
+            de_rows(g), expand).reshape(K, cb, EB)
         cur = jax.lax.dynamic_slice(sw, (0, b0 * EB), (2 * K, cb * EB))
         # a chunk's block of [e ; ew]; a pad chunk's offset is anything
         slots = jnp.take(cur.reshape(2 * K, cb, EB), of, axis=1,
                          mode="clip")                     # [2K, chunk, EB]
         dz_e = _window_lanes(dz_w, ob, ed, K).transpose(1, 0, 2)
-        ds = (slots[K:] * de + slots[:K] * dz_e) * scale  # [K, chunk, EB]
+        ds = slots[K:] * de + slots[:K] * dz_e            # [K, chunk, EB]
+        if scale is not None:
+            ds = ds * scale
         lane = b0 * EB + jax.lax.broadcasted_iota(jnp.int32, (1, cb * EB), 1)
         top = jnp.where((lane >= p0) & (lane < p1), _sum_blocks(ds, of),
                         cur[:K])
         sw = jax.lax.dynamic_update_slice(
             sw, jnp.concatenate([top, cur[K:]], axis=0), (0, b0 * EB))
-        acc = _add_window_rows(
-            acc, g[:, :H] * _over_head_lanes(ds, expand, 0, g.dtype),
-            ed, ob, "highest")
-        return (sw, acc), None
+        add, extra = addend(g, ds, ob, ed, extra)
+        acc = _add_window_rows(acc, add, ed, ob, "highest")
+        return (sw, acc, extra), None
 
     sw = _blocks_carry(2 * K, nb, cb, num_edges, e,
                        jnp.concatenate([e, ew], axis=0))
     acc = _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), e)
-    (sw, acc), _ = jax.lax.scan(
-        body, (sw, acc),
+    extra = None if extra0 is None else extra0()
+    (sw, acc, extra), _ = jax.lax.scan(
+        body, (sw, acc, extra),
         (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
          nid.reshape(nsteps, cb, EB), base, off, lo, hi))
     return (sw[:, :num_edges],
-            acc[:rows].astype(e.dtype).reshape(rows, K, F))
+            acc[:rows].astype(e.dtype).reshape(rows, K, F), extra)
 
 
 def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
@@ -1263,3 +1292,250 @@ def _tconv_plan_bwd(num_edges, rate, res, gout):
 
 
 _tconv_plan.defvjp(_tconv_plan_fwd, _tconv_plan_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Dynamic attention (GATv2: Brody, Alon, Yahav, "How Attentive are Graph
+# Attention Networks?", ICLR 2022, arXiv:2105.14491 eq 7; PyG's
+# GATv2Conv(share_weights=False)): the score of an in-edge j -> i is
+# a . LeakyReLU(xr_i + xl_j) per head, where GAT's is the rank-one
+# LeakyReLU(a_dst . h_i + a_src . h_j).  The nonlinearity sits at every
+# channel between the pair and ``a``, so the score needs both rows at every
+# edge (the score is a _plan_blocks form over gathered xl rows and the
+# window's xr rows), and the backward needs the slope at every edge and
+# channel, which no [E, K F] array could keep: each backward scan
+# regathers the rows and recomputes it.
+# ---------------------------------------------------------------------------
+
+def gatv2_attend(xl, xr, a, edge_src, edge_dst, num_nodes: int, drop=None,
+                 slope: float = 0.2):
+    """Multi-head dynamic attention over in-edges, the xla road (sorted
+    segment reductions; autodiff keeps what it likes):
+
+      xl: [T, K, F] source rows (scores and values); xr: [N_local, K, F]
+      destination rows; a: [K, F];
+      s_e = sum_f a[k, f] LeakyReLU(xr[dst_e] + xl[src_e]) per head;
+      alpha = edge_softmax(s); out[i] = sum_e alpha~_e xl[src_e], alpha~
+      the coefficients after ``drop`` = (key, rate) (:func:`attention_keep`;
+      not renormalised).
+    Returns [N_local, K, F].  Materialises [E, K, F]: small graphs and the
+    CPU tests; the plan road below is the one sized for a chip."""
+    E, (K, _) = edge_src.shape[0], xl.shape[1:]
+    g = jnp.take(xl, edge_src, axis=0)                 # [E, K, F]
+    p = jnp.take(xr, edge_dst, axis=0) + g
+    s = jnp.einsum("ekf,kf->ek", jax.nn.leaky_relu(p, negative_slope=slope),
+                   a, precision="highest")
+    alpha = edge_softmax(s, edge_dst, num_nodes)       # [E, K]
+    w = _keep_scale(drop, K, E, alpha.dtype)
+    if w is not None:
+        alpha = alpha * w.T
+    return jax.ops.segment_sum(g * alpha[:, :, None], edge_dst,
+                               num_segments=num_nodes,
+                               indices_are_sorted=True)
+
+
+def gatv2_attend_plan(xl, xr, a, plans: GatPlans, num_edges: int, drop=None,
+                      slope: float = 0.2):
+    """:func:`gatv2_attend` over the chunk plans :func:`build_gat_plans`
+    builds, scatter-free forward AND backward, equal to it up to float
+    reassociation; the same key drops the same coefficients.  No [E, K F]
+    array exists outside one scan step.
+
+    Four scans a layer gather node rows, one index list each: forward the
+    score (xl by dst_nid, xr spread from the window) and u (xl); backward
+    ONE scan over the dst-keyed plan that regathers xl, forms de and ds,
+    recomputes the slope and lands dxr and the sum for da
+    (:func:`_dynamic_then_sum`), and ONE over the src-keyed plan that
+    gathers [xr | du] side by side and sums both terms of dxl
+    (:func:`_dynamic_src_sum`).  Every sum is float32 at "highest",
+    whatever ``-aggr-precision`` says, as tconv_attend_plan's are: on a
+    v5e at the Reddit shape the logits read 2.2e-7 of the float32
+    reference so, and 2.1e-4 to 2.6e-4 with u at the MXU's default (one
+    bf16 rounding of each product; PERF.md section 2)."""
+    key, rate = _drop_args(drop)
+    return _gatv2_plan(xl, xr, a, plans, key, num_edges, float(slope), rate)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _gatv2_plan(xl, xr, a, plans, key, num_edges, slope, rate):
+    return _gatv2_plan_fwd(xl, xr, a, plans, key, num_edges, slope, rate)[0]
+
+
+def _dynamic_score(xl, xr, a, obi, edst, pos, nid, num_edges: int,
+                   slope: float):
+    """s[k, e] = Σ_f a[k, f] LeakyReLU(xr[dst_e, k, f] + xl[src_e, k, f]) as
+    ``[K, E]``, over the aligned dst-keyed plan: a step gathers its slots'
+    ``xl`` rows by the plan's ``nid``, spreads its windows' ``xr`` rows over
+    the slots (one-hot, exact) and collapses each head's F lanes against
+    ``a`` on the MXU; the [slots, K F] pre-activation lives in the step."""
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    rows, K, F = xr.shape
+    H = K * F
+    xr_w = _window_rows(xr.reshape(rows, H))
+    lf = xl.reshape(xl.shape[0], H)
+    collapse = _head_expand(K, F, jnp.float32)
+    a_lanes = a.reshape(1, H).astype(jnp.float32)
+
+    def form(ob, ed, ni):
+        cb = ob.shape[0]
+        p = _window_slot_rows(xr_w, ob, ed, H).reshape(cb * EB, H) \
+            + jnp.take(lf, ni.reshape(cb * EB), axis=0, mode="clip")
+        s = _contract_heads(jax.nn.leaky_relu(p, negative_slope=slope),
+                            a_lanes, collapse)                # [K, slots]
+        # a masked slot gathered row 0: an exact zero there, as
+        # _plan_blocks asks
+        return jnp.where((ed < VB).reshape(1, cb * EB), s, 0.0).reshape(
+            K, cb, EB)
+
+    return _plan_blocks(form, K, obi, edst, pos, nid, num_edges, xr)
+
+
+def _gatv2_plan_fwd(xl, xr, a, plans, key, num_edges, slope, rate):
+    N, E = plans.num_rows, num_edges
+    K = xl.shape[1]
+    dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
+    # the device scopes, as in _gat_plan_fwd
+    with scopes.scope("fwd"):
+        with scopes.scope("score"):
+            s = _dynamic_score(xl, xr, a, *dst, E, slope)         # [K, E]
+        with scopes.scope("max"):
+            m = _plan_max(s, *dst[:3], N)
+            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
+        with scopes.scope("bcast"):
+            mb = _plan_broadcast(m, *dst[:3], E)
+        with scopes.scope("edge"):
+            e = jnp.exp(s - mb)                                   # [K, E]
+        with scopes.scope("norm"):
+            z = _plan_sum(e, None, *dst, N, "highest", True)      # [K, N]
+        # the weighted sum sees the dropped coefficients, the normaliser
+        # never
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)
+            ew = e if w is None else e * w
+        with scopes.scope("u"):
+            u = _plan_sum(ew, xl, *dst, N, "highest", True)       # [N, K, F]
+        with scopes.scope("norm"):
+            # _Z_GUARD (rationale at its definition): rows with no in-edge
+            # (padded rows) have z == 0; any live row has z >= 1
+            zc = jnp.maximum(z, _Z_GUARD)
+            out = u / zc.T[:, :, None]
+    # ONE [K, E] residual, e, and the two node tables: no slope is kept,
+    # each backward scan recomputes it; the mask is redrawn from the key
+    return out, (xl, xr, a, plans, key, e, zc, out)
+
+
+def _dynamic_then_sum(du, dz, xl, xr, a, e, ew, obi, edst, pos, nid,
+                      num_edges: int, slope: float):
+    """The dst side of a dynamic score's backward in ONE scan over the
+    aligned dst-keyed plan, one gather of ``xl`` rows by its ``nid`` a step
+    (the pattern of :func:`_contract_then_sum`):
+
+      de[k, e]  = Σ_f du[dst_e, k, f]·xl[src_e, k, f]     (never edge-sized)
+      ds[k, e]  = ew[k, e]·de + e[k, e]·dz[k, dst_e]                [K, E]
+      p         = xr[dst_e] + xl[src_e]                    (never edge-sized)
+      dxr[i]    = Σ_{e: dst_e = i} ds (x) a · LeakyReLU'(p)     [rows, K, F]
+      da[k, f]  = Σ_e ds[k, e] · LeakyReLU(p)[k, f]                  [K, F]
+
+    The slope is the derivative the forward's ``jax.nn.leaky_relu`` has: 1
+    where p >= 0, ``slope`` below.  The scan's carry is the ``[2K, E]``
+    stack the src-keyed scan reads next (``[e ; ew]`` going in, ``[ds ;
+    ew]`` coming out) and the ``[K * F]`` sum for da.
+    Masked slots read zeros of ``du`` and ``dz``, so ds and every addend
+    are exact zeros there.  Float32 at "highest" throughout.
+    ``du``: [rows, K, F]; ``dz``: [K, rows]; ``xl``: [T, K, F]; ``xr``:
+    [rows, K, F]; ``e``, ``ew``: [K, E].  Returns ([ds ; ew], dxr, da)."""
+    from roc_tpu.ops.aggregate import _vary_like
+
+    def tables(rows, K, F):
+        H = K * F
+        xr_w = _window_rows(xr.reshape(rows, H))
+        lf = xl.reshape(xl.shape[0], H)
+        expand = _head_expand(K, F, jnp.float32)
+        a_lanes = a.reshape(1, H).astype(jnp.float32)
+
+        def addend(g, ds, ob, ed, da):
+            p = _window_slot_rows(xr_w, ob, ed, H).reshape(-1, H) + g
+            ds_l = _over_head_lanes(ds, expand, 0, g.dtype)   # [slots, K F]
+            da = da + jnp.sum(
+                ds_l * jax.nn.leaky_relu(p, negative_slope=slope), axis=0)
+            return ds_l * a_lanes * jnp.where(p >= 0, 1.0, slope), da
+
+        return lf, expand, lambda g: g, addend, lambda: _vary_like(
+            jnp.zeros((H,), jnp.float32), e)
+
+    sw, dxr, da = _land_ds_then_sum(du, dz, e, ew, obi, edst, pos, nid,
+                                    num_edges, None, tables)
+    return sw, dxr.astype(xr.dtype), da.reshape(*a.shape).astype(a.dtype)
+
+
+def _dynamic_src_sum(sw, du, xl, xr, a, obi, edst, pos, nid,
+                     table_rows: int, slope: float):
+    """dxl of a dynamic score, ONE scan over the src-keyed plan (windows:
+    source rows; ``nid``: each edge's destination):
+
+      dxl[j] = Σ_{e: src_e = j} ew (x) du[dst_e]
+                              + ds (x) a · LeakyReLU'(xr[dst_e] + xl[j])
+
+    the value path and the score path of the same slots.  A step reads the
+    stacked ``[2K, E]`` weights ``sw = [ds ; ew]`` by ONE column gather of
+    its positions (:func:`_slot_reader`), gathers ``[xr | du]`` side by side
+    by ONE row list and spreads its windows' own ``xl`` rows over the slots
+    (one-hot, exact) to recompute the slope.  Steps of
+    :func:`plan_sum_step` of the gathered row's width, as a row sum of
+    :func:`_plan_sum`.  Masked slots (``edst == VB``) match no window row.
+    Float32 at "highest".  Returns [table_rows, K, F]."""
+    from roc_tpu.ops.aggregate import _vary_like
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    rows, K, F = du.shape
+    H = K * F
+    cb, acc_windows = _plan_scan_shapes(obi, table_rows, plan_sum_step(2 * H))
+    obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
+    read = _slot_reader(sw, cb, False)
+    side = jnp.concatenate([xr.reshape(rows, H), du.reshape(rows, H)], axis=1)
+    xl_w = _window_rows(xl.reshape(xl.shape[0], H))
+    expand = _head_expand(K, F, jnp.float32)
+    a_lanes = a.reshape(1, H).astype(jnp.float32)
+
+    def body(acc, sl):
+        ob, ed, po, ni = sl
+        g = jnp.take(side, ni.reshape(cb * EB), axis=0, mode="clip")
+        slots = read(po)                                  # [cb, 2K, EB]
+        p = g[:, :H] + _window_slot_rows(xl_w, ob, ed, H).reshape(
+            cb * EB, H)
+        add = _over_head_lanes(slots[:, K:], expand, 1, g.dtype) * g[:, H:] \
+            + _over_head_lanes(slots[:, :K], expand, 1, g.dtype) * a_lanes \
+            * jnp.where(p >= 0, 1.0, slope)
+        return _add_window_rows(acc, add, ed, ob, "highest"), None
+
+    acc = _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), sw)
+    acc, _ = jax.lax.scan(
+        body, acc, (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+                    pos.reshape(nsteps, cb, EB), nid.reshape(nsteps, cb, EB)))
+    return acc[:table_rows].astype(xl.dtype).reshape(table_rows, K, F)
+
+
+def _gatv2_plan_bwd(num_edges, slope, rate, res, gout):
+    xl, xr, a, plans, key, e, zc, out = res
+    T, E = plans.table_rows, num_edges
+    K = xl.shape[1]
+    dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
+    src = (plans.src_obi, plans.src_edst, plans.src_pos, plans.src_nid)
+    with scopes.scope("bwd"):
+        with scopes.scope("norm"):
+            du = gout / zc.T[:, :, None]                          # [N, K, F]
+            dz = -jnp.einsum("nkf,nkf->kn", gout, out,
+                             precision="highest") / zc            # [K, N]
+        with scopes.scope("edge"):
+            w = _keep_scale((key, rate), K, E, e.dtype)   # the fwd's mask
+            ew = e if w is None else e * w
+        # de, ds, dxr and da: one scan over one gather of xl rows by
+        # dst_nid, as tconv's dedq; ds lands where the next scan reads it
+        with scopes.scope("dedq"):
+            sw, dxr, da = _dynamic_then_sum(du, dz, xl, xr, a, e, ew, *dst,
+                                            E, slope)
+        with scopes.scope("src"):
+            dxl = _dynamic_src_sum(sw, du, xl, xr, a, *src, T, slope)
+    return (dxl, dxr, da) + _int_zeros((plans, key))
+
+
+_gatv2_plan.defvjp(_gatv2_plan_fwd, _gatv2_plan_bwd)

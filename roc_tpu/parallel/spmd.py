@@ -40,7 +40,7 @@ from roc_tpu import fault, obs, ops
 from roc_tpu.analysis import retrace as _retrace
 from roc_tpu.graph.partition import (Partition, edge_block_arrays,
                                      edge_block_arrays_t, partition_graph)
-from roc_tpu.models.model import GraphCtx, refuse_dot_attention
+from roc_tpu.models.model import GraphCtx, refuse_pair_attention
 from roc_tpu.obs import scopes
 from roc_tpu.parallel.halo import HaloMaps, build_halo_maps
 from roc_tpu.ops.softmax import MASK_NONE
@@ -1642,7 +1642,7 @@ class SpmdTrainer(BaseTrainer):
         self.k = P_ // self.mesh.devices.size   # parts per device (>1 =
         self.part = None                        # reference's overcommit)
         self._exchange_mode = cfg.exchange_mode()
-        refuse_dot_attention(model, "SpmdTrainer ("
+        refuse_pair_attention(model, "SpmdTrainer ("
                              + ("overcommit, " if self.k > 1 else "")
                              + ("-edge-shard, "
                                 if cfg.edge_shard in (True, "on") else "")

@@ -21,7 +21,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from roc_tpu.models.model import (Model, OpNode, attention_drop,
-                                  linear_bias, refuse_dot_attention)
+                                  linear_bias, refuse_pair_attention)
 from roc_tpu.memory.estimator import _op_out_dims
 from roc_tpu import ops
 
@@ -55,7 +55,7 @@ def split_segments(model: Model) -> List[Segment]:
     # a dot-score gat head would need three tables a segment (q by
     # destination, k and v by source) where an additive one has one: say so,
     # do not stream it as the op it is not
-    refuse_dot_attention(model, "the streamed executor (-stream, "
+    refuse_pair_attention(model, "the streamed executor (-stream, "
                                 "stream/segments.py _HEAD_KINDS)")
     ops_list = list(model.ops)
     dims = _op_out_dims(model)

@@ -59,8 +59,8 @@ class Config:
     decay_steps: int = 100
     seed: int = 1
     num_parts: int = 1            # total shards (== mesh size when > 1)
-    model: str = "gcn"            # gcn | sage | gin | gat | tconv | gcnii
-    heads: int = 8                # attention heads (gat, tconv)
+    model: str = "gcn"            # gcn|sage|gin|gat|gatv2|tconv|gcnii
+    heads: int = 8                # attention heads (gat, gatv2, tconv)
     aggr: str = ""                # "" = model default; sum|avg|max|min
     aggregate_backend: str = "auto"  # auto | xla | matmul | pallas(=binned) | binned
     aggregate_precision: str = "fast"  # fast (default): features take one
@@ -371,8 +371,10 @@ def parse_args(argv: List[str]) -> Config:
     p.add_argument("-parts", "-ng", "-ll:gpu", dest="num_parts", type=int,
                    default=1)
     p.add_argument("-model", default="gcn",
-                   choices=["gcn", "sage", "gin", "gat", "tconv", "gcnii"],
-                   help="gcn | sage | gin | gat | tconv (graph transformer) "
+                   choices=["gcn", "sage", "gin", "gat", "gatv2", "tconv",
+                            "gcnii"],
+                   help="gcn | sage | gin | gat | gatv2 (dynamic attention) "
+                        "| tconv (graph transformer) "
                         "| gcnii (deep GCN: a hidden -layers entry is one "
                         "GCNII layer, all equal); models.build_model")
     p.add_argument("-heads", type=int, default=8)

@@ -28,8 +28,8 @@ from roc_tpu import fault, obs, ops
 from roc_tpu.analysis import retrace as _retrace
 from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
-from roc_tpu.models.model import (GraphCtx, Model, attention_heads,
-                                  attention_score)
+from roc_tpu.models.model import (PAIR_SCORES, GraphCtx, Model,
+                                  attention_heads, attention_score)
 from roc_tpu.ops.edge import gat_src_scans, short_plan_sums
 from roc_tpu.ops.softmax import format_metrics
 from roc_tpu.optim.adam import Adam
@@ -146,12 +146,20 @@ def model_aggrs(model: Model) -> set:
     return {op.attrs["aggr"] for op in model.ops if op.kind == "aggregate"}
 
 
+# what the trainer calls a model's attention ops, by their score
+# (models.model.attention_score; a builder uses one): the -model of each
+# pair score, "gat" for the additive one
+ATTENTION_KINDS = {"additive": "gat",
+                   **{score: spec[0] for score, spec in PAIR_SCORES.items()}}
+
+
 def attention_kind(model: Model) -> Optional[str]:
-    """What the trainer calls the model's attention ops, both gat ops of
-    the IR: "gat" (additive scores) or "tconv" (dot-product scores,
-    models.model.attention_score; a builder uses one), None without any."""
-    scores = {attention_score(op) for op in model.ops} - {None}
-    return "tconv" if "dot" in scores else "gat" if scores else None
+    """What the trainer calls the model's attention ops, all gat ops of
+    the IR: "gat" (additive scores), "tconv" (dot-product scores) or
+    "gatv2" (dynamic scores), None without any."""
+    scores = {attention_score(op) for op in model.ops}
+    return next((ATTENTION_KINDS[s] for s in ("dot", "dynamic", "additive")
+                 if s in scores), None)
 
 
 def model_has_attention(model: Model) -> bool:
@@ -169,7 +177,10 @@ def model_has_attention(model: Model) -> bool:
 # one gather of [k | v] by dst_nid); the forward's two (score: k, u: v)
 # have the softmax between them.
 TCONV_ROW_PASSES = 6
-TCONV_ROW_SCANS = 4
+# scans that gather node rows per op of either pair score on the plan road,
+# a training step: tconv's four above; gatv2's score and u forward, each by
+# dst_nid, and one scan over each plan backward (ops.edge.gatv2_attend_plan)
+PAIR_ROW_SCANS = 4
 
 
 def effective_backend_why(config: Config, dataset: Dataset, model: Model,
@@ -329,17 +340,21 @@ def make_gctx(g: DenseGraphData, num_nodes: int) -> GraphCtx:
         return ops.gat_attend(h, h, g.edge_src, g.edge_dst, num_nodes,
                               a_src, a_dst, slope, drop)
 
-    def attend_dot(q, k, v, drop=None):
-        # dot-product scores over the same plans; one table set a device;
-        # float32 at "highest" whatever g.precision (tconv_attend_plan)
+    def attend_pair(score, tables, drop=None, **attrs):
+        # dot-product or dynamic scores over the same plans; one table set
+        # a device; float32 at "highest" whatever g.precision
+        # (tconv_attend_plan, gatv2_attend_plan)
+        plan, dense = {"dot": (ops.tconv_attend_plan, ops.tconv_attend),
+                       "dynamic": (ops.gatv2_attend_plan,
+                                   ops.gatv2_attend)}[score]
         if g.gat_plans is not None:
-            return ops.tconv_attend_plan(q, k, v, g.gat_plans,
-                                         g.edge_src.shape[0], drop)
-        return ops.tconv_attend(q, k, v, g.edge_src, g.edge_dst, num_nodes,
-                                drop)
+            return plan(*tables, g.gat_plans, g.edge_src.shape[0], drop,
+                        **attrs)
+        return dense(*tables, g.edge_src, g.edge_dst, num_nodes, drop,
+                     **attrs)
 
     return GraphCtx(aggregate=aggregate, in_degree=g.in_degree,
-                    attend=attend, attend_dot=attend_dot)
+                    attend=attend, attend_pair=attend_pair)
 
 
 @dataclasses.dataclass
@@ -479,11 +494,12 @@ class BaseTrainer:
         """What this trainer resolved for its attention ops (None: the
         model has none), keyed by what the ``# attention:`` line, the
         `attention` record and the gauges call it less the op kind's
-        prefix (``gat_`` / ``tconv_``, :func:`attention_kind`):
+        prefix (``gat_`` / ``tconv_`` / ``gatv2_``, :func:`attention_kind`):
 
-        ``backend``: "plan" (ops.edge.gat_attend_plan / tconv_attend_plan
-        or the sharded kin over GatPlans) or "xla" (the dense / chunked /
-        ring scans); ``plan_pad_ratio``: the GatPlans' slots over edges;
+        ``backend``: "plan" (ops.edge.gat_attend_plan / tconv_attend_plan /
+        gatv2_attend_plan or the sharded kin over GatPlans) or "xla" (the
+        dense / chunked / ring scans); ``plan_pad_ratio``: the GatPlans'
+        slots over edges;
         ``score_bytes`` (gat): the per-edge residuals a train step keeps
         between forward and backward on the plan path (e float32 + the
         score's sign, [K, E] each, per op; 0 where autodiff keeps what it
@@ -498,16 +514,22 @@ class BaseTrainer:
         backward) and ``row_scans`` (the scans that gather them by an index
         list, 4 an op: score, u, then [k | v] side by side for de and dq
         over the dst-keyed plan and [q | du] for dk and dv over the
-        src-keyed one; 0 on the xla road).  Both kinds end
+        src-keyed one; 0 on the xla road).  A gatv2 model says the same
+        but ``row_passes``: ``score`` ("dynamic"), ``score_bytes``,
+        ``residual_bytes`` (its e, by memory.estimator's count: no sign is
+        kept) and ``row_scans`` (4 an op: score and u over xl rows; xl
+        again for de, ds, dxr and da over the dst-keyed plan, [xr | du] for
+        dxl over the src-keyed one).  Every kind ends
         with ``src_scans``: the scans over the src-keyed plan a training
         step makes, all in the backward: 1 an op (tconv: dk and dv
-        together; gat: dast riding dtable's where ops.edge.gat_src_scans
-        lets it), 2 an op on the edge-sharded road (parallel/spmd.py
-        ``_egat_bwd``), 0 on the xla scans; then ``short_scans``: the
-        row-gathering sums a training step makes (``u`` forward, the src
-        side's rows backward: K F wide, tconv's src side 2 K F) at a step
-        shorter than ops.edge's cap, by ops.edge.plan_sum_step, the rule
-        they are stepped by; 0 on the xla scans."""
+        together; gatv2: both terms of dxl; gat: dast riding dtable's
+        where ops.edge.gat_src_scans lets it), 2 an op on the edge-sharded
+        road (parallel/spmd.py ``_egat_bwd``), 0 on the xla scans; then
+        ``short_scans``: the row-gathering sums a training step makes
+        (``u`` forward, the src side's rows backward: K F wide, tconv's and
+        gatv2's src side 2 K F) at a step shorter than ops.edge's cap, by
+        ops.edge.plan_sum_step, the rule they are stepped by; 0 on the xla
+        scans."""
         kind = attention_kind(self.model)
         if kind is None:
             return None
@@ -525,11 +547,16 @@ class BaseTrainer:
             info["score_bytes"] = sum(heads) * edges * (4 + 1)
             info["dst_reads"] = "plan" if on_plan else "gather"
         else:
-            info.update(score="dot", score_bytes=max(heads) * edges * 4,
-                        residual_bytes=sum(heads) * edges * 4,
-                        row_passes=TCONV_ROW_PASSES * len(heads),
-                        row_scans=TCONV_ROW_SCANS * len(heads)
-                        if on_plan else 0)
+            from roc_tpu.memory.estimator import gat_edge_residual_bytes
+            info.update(score="dot" if kind == "tconv" else "dynamic",
+                        score_bytes=max(heads) * edges * 4,
+                        residual_bytes=sum(
+                            gat_edge_residual_bytes(op, edges)
+                            for op in self.model.ops))
+            if kind == "tconv":
+                info["row_passes"] = TCONV_ROW_PASSES * len(heads)
+            info["row_scans"] = PAIR_ROW_SCANS * len(heads) if on_plan \
+                else 0
         sharded = plans is not getattr(gd, "gat_plans", None)
 
         def src_scans(k):       # of one op of k heads, a training step
@@ -537,13 +564,13 @@ class BaseTrainer:
                 return 0
             if sharded:         # parallel/spmd.py _egat_bwd keeps two calls
                 return 2
-            return 1 if kind == "tconv" else gat_src_scans(k)
+            return gat_src_scans(k) if kind == "gat" else 1
 
         info["src_scans"] = sum(map(src_scans, heads))
 
         def row_widths(op):     # u's rows, then the src side's
             kf = attention_heads(op) * op.attrs["head_dim"]
-            return kf, kf * (2 if kind == "tconv" else 1)
+            return kf, kf * (1 if kind == "gat" else 2)
 
         info["short_scans"] = short_plan_sums(
             [w for op in self.model.ops if op.kind == "gat"

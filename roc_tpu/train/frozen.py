@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from roc_tpu.graph.datasets import Dataset
-from roc_tpu.models.model import Model, refuse_dot_attention
+from roc_tpu.models.model import Model, refuse_pair_attention
 from roc_tpu.train import checkpoint
 from roc_tpu.train.config import Config
 
@@ -93,7 +93,7 @@ def load_frozen(config: Config, dataset: Dataset, model: Model,
     from roc_tpu import obs
 
     path = checkpoint_path or config.checkpoint_path
-    refuse_dot_attention(model, "the frozen loader behind serve/ and "
+    refuse_pair_attention(model, "the frozen loader behind serve/ and "
                                 "fleet/ (train/frozen.py load_frozen)")
     with obs.span("load_frozen", stream=bool(config.stream)):
         if config.stream:

@@ -31,9 +31,10 @@ FAMILIES = {
     "gcn-xla": ("gcn", "xla", 1, [8, 8, 4], 1),
     "gat": ("gat", "matmul", 1, [8, 4, 4], 8),        # K = 8, then K = 1
     "tconv": ("tconv", "matmul", 1, [8, 8, 8, 4], 2),
+    "gatv2": ("gatv2", "matmul", 1, [8, 4, 4], 8),    # K = 8, then K = 1
     "gcn3-p4": ("gcn", "matmul", 4, [8, 8, 8, 4], 1),  # tiny-gcn3.p4's kind
 }
-ATTENTION = ("gat", "tconv")
+ATTENTION = ("gat", "tconv", "gatv2")
 
 
 def _dataset():
@@ -130,9 +131,10 @@ def test_every_while_of_the_compiled_step_has_op_pass_and_part(family,
                 ("fwd", "bcast"), ("bwd", "src")} <= parts
     if family == "gat":
         assert {("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")} <= parts
-    if family == "tconv":
-        assert ("fwd", "score") in parts        # the contraction, forward
-        # de, dz's broadcast and dq are ONE scan of the backward
+    if family in ("tconv", "gatv2"):
+        assert ("fwd", "score") in parts        # both rows, forward
+        # de, dz's broadcast and dq (gatv2: dxr and da) are ONE scan of the
+        # backward
         assert ("bwd", "dedq") in parts and not parts & {
             ("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")}
     if family == "gcn-matmul":
@@ -180,7 +182,21 @@ def test_src_scans_of_the_compiled_step_are_what_the_trainer_says(family,
         assert heads == [8, 1] and sorted(by_op.values()) == [1, 2]
         assert [em.gat_src_scans(k) for k in heads] == [2, 1]
     else:
-        assert set(by_op.values()) == {1} and len(by_op) == 3
+        ops = sum(op.kind == "gat" for op in tr.model.ops)
+        assert set(by_op.values()) == {1} and len(by_op) == ops
+
+
+def test_row_scans_of_the_compiled_gatv2_step(built):
+    """The dynamic score's rule gathers node rows in `row_scans` scans, 4
+    an op: `score` and `u` forward, `dedq` (xl again) and `src` ([xr | du])
+    backward; one of them a layer over the src-keyed plan."""
+    tr, _, text = built("gatv2")
+    info = tr.attention_info()
+    rows = [s for s in _whiles(text).values()
+            if s[2] in ("score", "u", "de", "dq", "dedq", "src")]
+    assert len(rows) == info["row_scans"] == 8
+    assert sorted({s[2] for s in rows}) == ["dedq", "score", "src", "u"]
+    assert sum(s[2] == "src" for s in rows) == info["src_scans"] == 2
 
 
 def test_row_passes_of_the_compiled_tconv_step(built):
