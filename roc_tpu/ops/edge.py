@@ -264,10 +264,11 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # tables side by side read by one row gather (src_nid): gat's dast rides
 # dtable's scan (_plan_sum's ``ride``; while the stack fits a tile's
 # sublanes, gat_src_scans), tconv's dk and dv are one sum of 2K heads.
-# Tconv's backward reads dst_nid once too: de (v rows) and dq (k rows) are
-# one scan over one gather of [k | v] rows (_contract_then_sum).  What is
-# left that takes one list twice is the forward's pair, score (k rows)
-# then u (v rows), with the softmax's max and normaliser between them.
+# Tconv reads dst_nid once a pass: in the backward de (v rows) and dq (k
+# rows) are one scan over one gather of [k | v] rows (_contract_then_sum),
+# in the forward the score (k rows) and u (v rows) are one too, the
+# softmax's max and normaliser, which stand between them, carried online
+# (_score_then_sum).
 #
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
 # backward is built from these primitives plus the src side's plain gathers
@@ -290,8 +291,8 @@ _PLAN_SUM_BLOCK_BYTES = 64 << 20
 # the block-landing scans (_plan_blocks).  128, 256 and 512 are within 1 ms
 # a pass of each other on a v5e (the combine dot grows cb^2, the step count
 # falls); 128 is _plan_max's, so both scans pad the plan alike.  Also the
-# step of the scan that lands blocks AND sums rows (_contract_then_sum),
-# where it is worth 144 to 285 ms a pass over 512
+# step of the scans that land blocks AND sum rows (_contract_then_sum,
+# where it is worth 144 to 285 ms a pass over 512, and _score_then_sum)
 _PLAN_CB_BLOCKS = 128
 _PLAN_CB_MAX = 128   # smaller: the masked-max intermediate is [K, cb, cb, VB]
 _LANE_GATHER_CHUNK = 1 << 20   # indices a step of a long [K, M] lane gather
@@ -1005,6 +1006,123 @@ def _land_ds_then_sum(du, dz, e, ew, obi, edst, pos, nid, num_edges: int,
             acc[:rows].astype(e.dtype).reshape(rows, K, F), extra)
 
 
+def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
+    """The forward of a pair score in ONE scan over the aligned dst-keyed
+    plan, one gather by its ``nid`` a step, where a score scan, the max,
+    the normaliser and the weighted sum walked it four times and gathered
+    by ``nid`` twice:
+
+      s[k, e] = score(x[dst_e], t[src_e])                          [K, E]
+      m[k, i] = max_{e: dst_e = i} s[k, e]
+      z[k, i] = Σ_{e: dst_e = i} exp(s[k, e] - m[k, i])
+      u[i]    = Σ_{e: dst_e = i} exp(s - m)[:, e]·w[:, e] (x) val(t[src_e])
+
+    The max and the normaliser are reductions over a row's edges that stand
+    between the score and the sum, so the scan carries them online: a
+    running max ``m`` of the step's window rows, and the rows' ``z`` and
+    ``u`` scaled by ``exp(m_old - m_new)`` whenever a step raises it (a row
+    with no slot yet reads ``-inf`` and its factor is 0, never ``exp(-inf +
+    inf)``).  A step lands ``s`` into the ``[K, E]`` carry as
+    :func:`_plan_blocks` does, takes its masked max by window row as
+    :func:`_plan_max`'s body does, forms ``e = exp(s - m[dst])`` at its
+    slots and adds ``e`` to ``z`` and ``e w`` times the value rows to ``u``
+    (one-hot dots at "highest": float32 throughout).  Steps of
+    ``_PLAN_CB_BLOCKS`` chunks, as :func:`_land_ds_then_sum`: the gathered
+    block then lies in VMEM (:func:`_contract_then_sum` has the numbers).
+
+    ``x``: [rows, K, F] destination rows, spread over a step's slots from
+    their windows (one-hot, exact); ``w``: [K, E] multiplier of the sum's
+    weights (the dropout mask) or None.  ``tables(rows, K, F)`` gives the
+    score's own part, made once a pass: (the node table a step gathers by
+    ``nid``, ``score(g, x_e)``: the ``[K, slots]`` scores of the gathered
+    rows against the slots' own ``[slots, K F]`` rows of ``x``,
+    ``values(g)``: the ``[slots, K F]`` rows ``u`` sums).  Masked slots
+    score an exact zero and add nothing.  Returns (s [K, E], m [K, rows]:
+    -inf on a row with no in-edge, z [K, rows], u [rows, K, F]); such a row
+    sums zeros."""
+    from roc_tpu.ops.aggregate import _one_hot_dots, _vary_like
+    from roc_tpu.ops.pallas.segment_sum import EB, VB
+    rows, K, F = x.shape
+    H = K * F
+    cb, acc_windows = _plan_scan_shapes(obi, rows, _PLAN_CB_BLOCKS)
+    obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
+    nb, base, off = _block_steps(edst, pos, nsteps, cb, num_edges)
+    # node-sized, once a pass
+    x_w = _window_rows(x.reshape(rows, H))
+    table, score, values = tables(rows, K, F)
+    expand = _head_expand(K, F, jnp.float32)
+    read_w = None if w is None else _slot_reader(w, cb, True)
+    neg = jnp.asarray(-jnp.inf, jnp.float32)
+
+    def body(carry, sl):
+        s_out, m, z, u = carry
+        ob, ed, po, ni, b0, of = sl
+        g = jnp.take(table, ni.reshape(cb * EB), axis=0, mode="clip")
+        x_e = _window_slot_rows(x_w, ob, ed, H).reshape(cb * EB, H)
+        live = (ed < VB)[:, None, :]                          # [chunk, 1, EB]
+        s = jnp.where(live.transpose(1, 0, 2),
+                      score(g, x_e).reshape(K, cb, EB), 0.0)  # [K, chunk, EB]
+        s_out = _land_blocks(s_out, s, b0, of)
+        s = s.transpose(1, 0, 2)                              # [chunk, K, EB]
+        # the step's max by window row (_plan_max's body), onto the rows'
+        in_row = (jax.lax.broadcasted_iota(jnp.int32, (cb, VB, EB), 1)
+                  == ed[:, None, :])                          # [chunk, VB, EB]
+        within = jnp.max(jnp.where(in_row[:, None], s[:, :, None, :], neg),
+                         axis=3)                              # [chunk, K, VB]
+        lw = ob - ob[0]
+        same_w = (jax.lax.broadcasted_iota(jnp.int32, (cb, cb), 0)
+                  == lw[None, :])                             # [w, chunk]
+        m_old = jax.lax.dynamic_slice(m, (ob[0], 0, 0), (cb, K, VB))
+        m_new = jnp.maximum(m_old, jnp.max(jnp.where(
+            same_w[:, :, None, None], within[None], neg), axis=1))
+        m = jax.lax.dynamic_update_slice(m, m_new, (ob[0], 0, 0))
+        scale = jnp.exp(jnp.where(m_old > neg, m_old - m_new, neg))
+        # each slot's row max: a live slot's row has one, finite
+        s1 = in_row.astype(jnp.float32)
+        m_e = jax.lax.dot_general(                            # [chunk, K, EB]
+            jnp.take(jnp.where(m_new > neg, m_new, 0.0), lw, axis=0), s1,
+            (((2,), (1,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32)
+        e = jnp.where(live, jnp.exp(s - m_e), 0.0)            # [chunk, K, EB]
+        # z: the plain one-hot sum of e by window row (_plan_sum's add_plain)
+        psum = jax.lax.dot_general(                           # [chunk, K, VB]
+            e, s1, (((2,), (2,)), ((0,), (0,))), precision="highest",
+            preferred_element_type=jnp.float32)
+        outs = jax.lax.dot_general(
+            same_w.astype(jnp.float32), psum.reshape(cb, K * VB),
+            (((1,), (0,)), ((), ())), precision="highest",
+            preferred_element_type=jnp.float32)               # [w, K VB]
+        cur = jax.lax.dynamic_slice(z, (ob[0], 0), outs.shape)
+        z = jax.lax.dynamic_update_slice(
+            z, cur * scale.reshape(cb, K * VB) + outs, (ob[0], 0))
+        # u: e w times the value rows, summed by window row
+        ew = e if read_w is None else e * read_w(po)
+        outs = _one_hot_dots(
+            values(g) * _over_head_lanes(ew, expand, 1, g.dtype), ed, ob, cb,
+            "highest", "highest")                             # [w VB, K F]
+        cur = jax.lax.dynamic_slice(u, (ob[0] * VB, 0), outs.shape)
+        u = jax.lax.dynamic_update_slice(
+            u, cur * _over_head_lanes(scale, expand, 1, u.dtype) + outs,
+            (ob[0] * VB, 0))
+        return (s_out, m, z, u), None
+
+    carry = (_blocks_carry(K, nb, cb, num_edges, x),
+             _vary_like(jnp.full((acc_windows, K, VB), neg), x),
+             _vary_like(jnp.zeros((acc_windows, K * VB), jnp.float32), x),
+             _vary_like(jnp.zeros((acc_windows * VB, H), jnp.float32), x))
+    (s, m, z, u), _ = jax.lax.scan(
+        body, carry,
+        (obi.reshape(nsteps, cb), edst.reshape(nsteps, cb, EB),
+         pos.reshape(nsteps, cb, EB), nid.reshape(nsteps, cb, EB), base, off))
+
+    def by_row(acc):            # window-indexed [W, K, VB] -> [K, rows]
+        return acc.reshape(acc_windows, K, VB).transpose(1, 0, 2).reshape(
+            K, acc_windows * VB)[:, :rows].astype(x.dtype)
+
+    return (s[:, :num_edges], by_row(m), by_row(z),
+            u[:rows].astype(x.dtype).reshape(rows, K, F))
+
+
 def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
                     slope: float, precision: str = "highest", drop=None):
     """GAT attention over chunk plans — scatter-free fwd AND bwd.
@@ -1164,8 +1282,9 @@ _gat_plan.defvjp(_gat_plan_fwd, _gat_plan_bwd)
 # UniMP, arXiv:2009.03509 eqs 3-4; PyG's TransformerConv): the score of an
 # in-edge j -> i is q_i . k_j / sqrt(F) per head, where GAT's is the rank-one
 # a_dst . h_i + a_src . h_j.  Both rows are needed at every edge, so the
-# score is _edge_contract run in the FORWARD, and the backward has three
-# weighted row sums where additive attention has one.
+# forward contracts the window's q rows with gathered k rows in the scan
+# that sums the v rows, and the backward has three weighted row sums where
+# additive attention has one.
 # ---------------------------------------------------------------------------
 
 def tconv_attend(q, k, v, edge_src, edge_dst, num_nodes: int, drop=None):
@@ -1207,49 +1326,85 @@ def tconv_attend_plan(q, k, v, plans: GatPlans, num_edges: int, drop=None):
     the reference by seed, against 1.1e-3 to 2.3e-3 with a bf16 accumulate,
     which no bound separates; at "highest" they read 2e-7 to 5e-7 and the
     epoch costs 1.25 % more (9.4314 -> 9.5497 s: the row gather is the
-    pass, not the one-hot dots).  Four scans a layer
-    gather node rows, reading six tables (k for the score, v for u; in the
-    backward [k | v] side by side for the contraction and dq, ONE scan over
-    the dst-keyed plan, and q beside du for dk and dv, ONE scan of 2K heads
-    over the src-keyed plan) against GAT's three."""
+    pass, not the one-hot dots).  Three scans a layer gather node rows,
+    reading six tables: [k | v] side by side for the score and u, ONE scan
+    over the dst-keyed plan with the softmax carried online
+    (:func:`_score_then_sum`); in the backward [k | v] again for the
+    contraction and dq, ONE scan over the dst-keyed plan, and q beside du
+    for dk and dv, ONE scan of 2K heads over the src-keyed plan."""
     key, rate = _drop_args(drop)
     return _tconv_plan(q, k, v, plans, key, num_edges, rate)
 
 
+def _dot_tables(k, v):
+    """:func:`_score_then_sum`'s ``tables`` of a dot-product score: ``k``
+    (the score) and ``v`` (u's rows) side by side, one row list a step, and
+    per head ``q_i . k_j / sqrt(F)``.  The table is made from ``k`` and
+    ``v`` behind an optimization barrier: the backward makes the same
+    ``[k | v]`` from the same two arrays (:func:`_contract_then_sum`), and
+    XLA would make both here, as one expression or as one fusion, and keep
+    the backward's alive from the forward, a layer's worth each (+0.4 to
+    0.6 GB at the train step's peak by the compiler's buffer assignment
+    for a v5e at the Reddit shape)."""
+    def tables(rows, K, F):
+        H = K * F
+        kb, vb = jax.lax.optimization_barrier((k, v))
+        kv = jnp.concatenate([kb.reshape(-1, H), vb.reshape(-1, H)], axis=1)
+        collapse = _head_expand(K, F, jnp.float32)
+
+        def score(g, q_e):
+            return _contract_heads(q_e, g[:, :H], collapse) * (
+                1.0 / np.sqrt(F))
+
+        return kv, score, lambda g: g[:, H:]
+
+    return tables
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _tconv_plan(q, k, v, plans, key, num_edges, rate):
-    return _tconv_plan_fwd(q, k, v, plans, key, num_edges, rate)[0]
+    return _tconv_plan_out(q, k, v, plans, key, num_edges, rate)[0]
 
 
-def _tconv_plan_fwd(q, k, v, plans, key, num_edges, rate):
-    N, E = plans.num_rows, num_edges
-    K, F = q.shape[1:]
+def _tconv_plan_out(q, k, v, plans, key, num_edges, rate):
+    """The forward's one scan and the division: (out, s, m, zc), all an
+    evaluation pass makes (it needs no e, so no broadcast of the max)."""
+    E, K = num_edges, q.shape[1]
     dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
     # the device scopes, as in _gat_plan_fwd
     with scopes.scope("fwd"):
-        with scopes.scope("score"):
-            s = _edge_contract(q, k, *dst, E) * (1.0 / np.sqrt(F))  # [K, E]
-        with scopes.scope("max"):
-            m = _plan_max(s, *dst[:3], N)
-            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-        with scopes.scope("bcast"):
-            mb = _plan_broadcast(m, *dst[:3], E)
-        with scopes.scope("edge"):
-            e = jnp.exp(s - mb)                                   # [K, E]
-        with scopes.scope("norm"):
-            z = _plan_sum(e, None, *dst, N, "highest", True)      # [K, N]
         # the weighted sum sees the dropped coefficients, the normaliser
         # never
         with scopes.scope("edge"):
-            w = _keep_scale((key, rate), K, E, e.dtype)
-            ew = e if w is None else e * w
-        with scopes.scope("u"):
-            u = _plan_sum(ew, v, *dst, N, "highest", True)        # [N, K, F]
+            w = _keep_scale((key, rate), K, E, q.dtype)
+        # the score, its max, the normaliser and u: one scan over one
+        # gather of [k | v] rows by dst_nid, the softmax carried online
+        with scopes.scope("su"):
+            s, m, z, u = _score_then_sum(q, w, *dst, E, _dot_tables(k, v))
+            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
         with scopes.scope("norm"):
             # _Z_GUARD (rationale at its definition): rows with no in-edge
             # (padded rows) have z == 0; any live row has z >= 1
             zc = jnp.maximum(z, _Z_GUARD)
             out = u / zc.T[:, :, None]
+    return out, s, m, zc
+
+
+def _tconv_plan_fwd(q, k, v, plans, key, num_edges, rate):
+    out, s, m, zc = _tconv_plan_out(q, k, v, plans, key, num_edges, rate)
+    dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
+    with scopes.scope("fwd"):
+        # e, the backward's one [K, E] residual, from the rows' max
+        with scopes.scope("bcast"):
+            mb = _plan_broadcast(m, *dst, num_edges)
+        with scopes.scope("edge"):
+            e = jnp.exp(s - mb)                                   # [K, E]
+            # made before the output is handed on: nothing of the forward
+            # needs e, and XLA would make every layer's next to the
+            # backward, each layer's s kept until then (+1.1 GiB at the
+            # train step's peak by the compiler's count for a v5e at the
+            # Reddit shape)
+            out, e = jax.lax.optimization_barrier((out, e))
     # ONE [K, E] residual: e.  The mask is redrawn from the key.
     return out, (q, k, v, plans, key, e, zc, out)
 

@@ -171,16 +171,17 @@ def model_has_attention(model: Model) -> bool:
 # node tables a TRAINING step reads by row over the plans per tconv op on
 # the plan road: k for the score and v for the weighted sum forward; v for
 # de, k for dq, and q and du for dk and dv backward
-# (ops.edge.tconv_attend_plan).  Six tables in FOUR scans: q and du share
+# (ops.edge.tconv_attend_plan).  Six tables in THREE scans: q and du share
 # the src-keyed scan since PR 34 (side by side, one gather by src_nid), k
 # and v the backward's dst-keyed one since PR 36 (de and dq in one scan,
-# one gather of [k | v] by dst_nid); the forward's two (score: k, u: v)
-# have the softmax between them.
+# one gather of [k | v] by dst_nid), and so does the forward (score and u
+# in one scan, the softmax carried online).
 TCONV_ROW_PASSES = 6
-# scans that gather node rows per op of either pair score on the plan road,
-# a training step: tconv's four above; gatv2's score and u forward, each by
-# dst_nid, and one scan over each plan backward (ops.edge.gatv2_attend_plan)
-PAIR_ROW_SCANS = 4
+# scans that gather node rows per op of a pair score on the plan road, a
+# training step, by score: tconv's three above; gatv2's score and u
+# forward, each by dst_nid, and one scan over each plan backward
+# (ops.edge.gatv2_attend_plan)
+PAIR_ROW_SCANS = {"tconv": 3, "gatv2": 4}
 
 
 def effective_backend_why(config: Config, dataset: Dataset, model: Model,
@@ -512,10 +513,10 @@ class BaseTrainer:
         backward, e of every op), ``row_passes`` (node tables a training
         step reads by row over the plans, 6 an op: k, v forward; v, k, q, du
         backward) and ``row_scans`` (the scans that gather them by an index
-        list, 4 an op: score, u, then [k | v] side by side for de and dq
-        over the dst-keyed plan and [q | du] for dk and dv over the
-        src-keyed one; 0 on the xla road).  A gatv2 model says the same
-        but ``row_passes``: ``score`` ("dynamic"), ``score_bytes``,
+        list, 3 an op: [k | v] side by side for the score and u, and again
+        for de and dq, over the dst-keyed plan, and [q | du] for dk and dv
+        over the src-keyed one; 0 on the xla road).  A gatv2 model says the
+        same but ``row_passes``: ``score`` ("dynamic"), ``score_bytes``,
         ``residual_bytes`` (its e, by memory.estimator's count: no sign is
         kept) and ``row_scans`` (4 an op: score and u over xl rows; xl
         again for de, ds, dxr and da over the dst-keyed plan, [xr | du] for
@@ -526,8 +527,9 @@ class BaseTrainer:
         where ops.edge.gat_src_scans lets it), 2 an op on the edge-sharded
         road (parallel/spmd.py ``_egat_bwd``), 0 on the xla scans; then
         ``short_scans``: the row-gathering sums a training step makes
-        (``u`` forward, the src side's rows backward: K F wide, tconv's and
-        gatv2's src side 2 K F) at a step shorter than ops.edge's cap, by
+        (``u`` forward, but tconv's, which its score's scan carries; the
+        src side's rows backward: K F wide, tconv's and gatv2's src side
+        2 K F) at a step shorter than ops.edge's cap, by
         ops.edge.plan_sum_step, the rule they are stepped by; 0 on the xla
         scans."""
         kind = attention_kind(self.model)
@@ -555,8 +557,8 @@ class BaseTrainer:
                             for op in self.model.ops))
             if kind == "tconv":
                 info["row_passes"] = TCONV_ROW_PASSES * len(heads)
-            info["row_scans"] = PAIR_ROW_SCANS * len(heads) if on_plan \
-                else 0
+            info["row_scans"] = PAIR_ROW_SCANS[kind] * len(heads) \
+                if on_plan else 0
         sharded = plans is not getattr(gd, "gat_plans", None)
 
         def src_scans(k):       # of one op of k heads, a training step
@@ -570,6 +572,8 @@ class BaseTrainer:
 
         def row_widths(op):     # u's rows, then the src side's
             kf = attention_heads(op) * op.attrs["head_dim"]
+            if kind == "tconv":     # u rides the score's scan
+                return (2 * kf,)
             return kf, kf * (1 if kind == "gat" else 2)
 
         info["short_scans"] = short_plan_sums(

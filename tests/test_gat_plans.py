@@ -317,8 +317,8 @@ def test_riding_weights_against_the_two_calls(heads, riding, precision,
 
 # -- the step of a row-gathering sum, from the gathered row's width ---------
 
-# the shipped budget's steps: gat's rows (41, 64) and tconv's hidden u (128)
-# keep the cap; tconv's L2 u (164), hidden src (256), L2 src (328) do not
+# the shipped budget's steps: gat's rows (41, 64) and 128-lane rows keep the
+# cap; 164 lanes, tconv's hidden src (256) and L2 src (328) do not
 _STEPS = {41: 512, 64: 512, 128: 512, 164: 256, 256: 256, 328: 128}
 
 

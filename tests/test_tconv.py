@@ -155,10 +155,11 @@ def test_the_plan_road_gathers_a_step_at_a_time_and_keeps_edges_last(
         shapes += [tuple(o.aval.shape) for o in eqn.outvars
                    if getattr(o.aval, "shape", None) is not None]
     assert gathers and max(gathers) <= step_slots
-    # s, e, the broadcasts ... (the mask and e w with dropout); de and ds
-    # are no [K, E] arrays since PR 36: ds is written over e in the
-    # [2K, E] stack the src scan reads
-    assert sum(1 for s in shapes if s == (K, E)) >= 5
+    # s, the broadcast max, s - max and e (the mask and e w with dropout);
+    # de and ds are no [K, E] arrays since PR 36: ds is written over e in
+    # the [2K, E] stack the src scan reads; the score's scale is applied
+    # inside the forward's one scan
+    assert sum(1 for s in shapes if s == (K, E)) >= 4
     assert sum(1 for s in shapes if s == (2 * K, E)) >= 2
     heads_last = [s for s in shapes if len(s) >= 2 and s[-1] == K
                   and int(np.prod(s[:-1])) >= step_slots]
@@ -267,12 +268,13 @@ def test_de_and_dq_in_one_scan_against_the_scans_it_replaced(
 
 @pytest.mark.parametrize("dropout", [0.0, 0.3])
 def test_the_backward_walks_the_src_plan_once_an_op(dropout, monkeypatch):
-    """jax.grad of tconv_attend_plan: FOUR scans gather node rows (score
-    and u forward; the fused de / dq and the fused dk / dv backward) and
-    exactly TWO of them read 2 K F wide rows ([k | v] by dst_nid, [q | du]
-    by src_nid); the src scan's column gather reads ONE stacked [2K, E]
-    per-edge array, and no other scan gathers from a [K, E] or [2K, E]
-    array by column (every dst-keyed read is by aligned blocks)."""
+    """jax.grad of tconv_attend_plan: THREE scans gather node rows (the
+    fused score / u forward; the fused de / dq and the fused dk / dv
+    backward) and all three read 2 K F wide rows ([k | v] by dst_nid
+    twice, [q | du] by src_nid); the src scan's column gather reads ONE
+    stacked [2K, E] per-edge array, and no other scan gathers from a
+    [K, E] or [2K, E] array by column (every dst-keyed read is by aligned
+    blocks)."""
     _small_steps(monkeypatch)
     src, dst, rows = _edges("hub", seed=6)
     K, F, E = 4, 16, dst.size
@@ -287,14 +289,14 @@ def test_the_backward_walks_the_src_plan_once_an_op(dropout, monkeypatch):
     scans = _scans_and_their_gathers(jaxpr)
     narrow = [g for g in scans if (rows, K * F) in g]
     wide = [g for g in scans if (rows, 2 * K * F) in g]
-    assert (len(narrow), len(wide)) == (2, 2)
+    assert (len(narrow), len(wide)) == (0, 3)
     by_column = [g for g in scans if (K, E) in g or (2 * K, E) in g]
     assert len(by_column) == 1 and by_column[0] in wide
     assert by_column[0].count((2 * K, E)) == 1 and (K, E) not in by_column[0]
-    # the other wide scan takes its blocks of e and e w out of one step's
-    # lane range of the stack, which it carries
-    fused, = [g for g in wide if g is not by_column[0]]
-    assert (2 * K, 8, EB) in fused          # _PLAN_CB_BLOCKS of _small_steps
+    # of the other two, the backward's takes its blocks of e and e w out of
+    # one step's lane range of the stack, which it carries
+    dst_side = [g for g in wide if g is not by_column[0]]
+    assert sum((2 * K, 8, EB) in g for g in dst_side) == 1  # _small_steps
 
 
 def test_an_additive_score_has_no_second_table_to_pair(monkeypatch):
@@ -321,6 +323,158 @@ def test_an_additive_score_has_no_second_table_to_pair(monkeypatch):
     with pytest.raises(AssertionError, match="_contract_then_sum"):
         jax.grad(lambda q_: jnp.sum(em.tconv_attend_plan(
             q_, k, v, plans, dst.size)))(q)
+
+
+# -- the forward's one scan: score, max, normaliser and u -------------------
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+@pytest.mark.parametrize("kind", ["regular", "hub"])
+def test_the_fused_forward_against_the_xla_road(kind, dropout, monkeypatch):
+    """The forward's ONE scan over the aligned plan (_score_then_sum: the
+    softmax's max and normaliser carried online over several steps)
+    against tconv_attend given the same key: within 64 ulps of the
+    output's scale, rows without an in-edge exact zeros."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges(kind, seed=8)
+    q, k, v = _qkv(rows, 4, 8, 30)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    assert plans.dst_obi.shape[0] > 2 * 8           # several steps
+    drop = (jax.random.PRNGKey(9), dropout) if dropout else None
+    got = np.asarray(em.tconv_attend_plan(q, k, v, plans, dst.size, drop))
+    want = np.asarray(em.tconv_attend(q, k, v, jnp.asarray(src),
+                                      jnp.asarray(dst), rows, drop))
+    assert not got[np.setdiff1d(np.arange(rows), np.unique(dst))].any()
+    scale = np.abs(want).max() * np.finfo(np.float32).eps
+    assert np.abs(got - want).max() <= 64 * scale
+
+
+def test_a_hub_whose_largest_score_comes_last_is_rescaled(monkeypatch):
+    """A hub row of 6,000 in-edges over at least three scan steps, its
+    largest scores (110) on its last 16 edges, in its LAST step, every other
+    score 77 to 83: exp of the largest overflows float32 and 6,000 of the
+    others nearly do, so every sum is taken against a running max, and what
+    the earlier steps summed must be scaled by exp(m_old - m_new) (about
+    e^-30) when the last step raises it.  The hub then reads row 0's
+    values, as the dense softmax does."""
+    _small_steps(monkeypatch)
+    rng = np.random.default_rng(11)
+    rows, hub, K, F = 64, 17, 2, 8
+    other = rng.integers(0, 40, 2000)
+    dst = np.sort(np.concatenate([other[other != hub],
+                                  np.full(6000, hub)])).astype(np.int64)
+    src = rng.integers(1, rows, dst.size).astype(np.int64)
+    last = np.flatnonzero(dst == hub)[-16:]
+    src[last] = 0
+    t = 80.0 + rng.uniform(-3.0, 3.0, (rows, K))
+    t[0] = 110.0
+    q = jnp.ones((rows, K, F), jnp.float32)         # the score is t[src]
+    k = jnp.asarray(np.repeat(t[:, :, None] / np.sqrt(F), F, axis=2),
+                    jnp.float32)
+    v = jnp.asarray(rng.standard_normal((rows, K, F)), jnp.float32)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    # the hub's chunks span three steps or more, its last edge in the last
+    obi, edst, pos = (np.asarray(a) for a in (
+        plans.dst_obi, plans.dst_edst, plans.dst_pos))
+    steps = np.unique(np.flatnonzero(obi == hub // VB) // em._PLAN_CB_BLOCKS)
+    assert steps.size >= 3
+    chunk, = np.flatnonzero(((pos == last[-1]) & (edst < VB)).any(axis=1))
+    assert chunk // em._PLAN_CB_BLOCKS == steps[-1]
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(np.float32(t.max())))
+    got = np.asarray(em.tconv_attend_plan(q, k, v, plans, dst.size))
+    assert np.isfinite(got).all()
+    want = _dense_attention(q, k, v, src, dst, rows)
+    scale = np.abs(want).max() * np.finfo(np.float32).eps
+    assert np.abs(got - want).max() <= 64 * scale
+    np.testing.assert_allclose(got[hub], np.asarray(v)[0], atol=1e-5)
+
+
+def test_a_row_with_no_in_edge_sums_nothing_in_the_fused_scan(monkeypatch):
+    """_score_then_sum over the hub graph (empty windows, a tail past the
+    last edge): the score at every edge, the row max, and on a row with no
+    in-edge a max of -inf, exact zeros of z and u, and an output of 0."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges("hub", seed=6)
+    K, F = 2, 8
+    q, k, v = _qkv(rows, K, F, 3)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
+    s, m, z, u = (np.asarray(a) for a in em._score_then_sum(
+        q, None, *dplan, dst.size, em._dot_tables(k, v)))
+    want_s = np.einsum("ekf,ekf->ke", np.asarray(q, np.float64)[dst],
+                       np.asarray(k, np.float64)[src]) / np.sqrt(F)
+    np.testing.assert_allclose(s, want_s, rtol=1e-5, atol=1e-5)
+    want_m = np.full((K, rows), -np.inf)
+    np.maximum.at(want_m.T, dst, s.T)
+    np.testing.assert_array_equal(m, want_m.astype(np.float32))
+    none = np.setdiff1d(np.arange(rows), dst)
+    some = np.unique(dst)
+    assert none.size and not z[:, none].any() and not u[none].any()
+    assert (z[:, some] >= 1.0).all()
+    out = np.asarray(em.tconv_attend_plan(q, k, v, plans, dst.size))
+    assert not out[none].any() and out[some].any()
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+@pytest.mark.parametrize("kind", ["regular", "hub"])
+def test_the_gradient_through_the_fused_forward_against_the_xla_road(
+        kind, dropout, monkeypatch):
+    """dq, dk, dv of the plan road over several steps of every scan (the
+    backward starts from the fused forward's e and normaliser) against
+    jax.grad of tconv_attend given the same key."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges(kind, seed=9)
+    q, k, v = _qkv(rows, 4, 8, 40)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    sj, dj = jnp.asarray(src), jnp.asarray(dst)
+    drop = (jax.random.PRNGKey(4), dropout) if dropout else None
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a)))
+
+    got = jax.grad(loss(lambda *a: em.tconv_attend_plan(
+        *a, plans, dst.size, drop)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: em.tconv_attend(
+        *a, sj, dj, rows, drop)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) / np.linalg.norm(b) <= 2e-5, name
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+def test_three_row_gathering_scans_an_op_and_no_row_on_a_lane(dropout,
+                                                              monkeypatch):
+    """jax.grad through two tconv ops: three scans an op gather node rows
+    (the forward's score / u, the backward's de / dq and dk / dv), each of
+    2 K F wide rows; no scatter; no edge-sized array has a node row, or
+    the heads, on its lane axis."""
+    _small_steps(monkeypatch)
+    src, dst, rows = _edges("hub", seed=6)
+    K, F, E = 4, 16, dst.size
+    step_slots = 16 * EB
+    q, k, v = _qkv(rows, K, F, 0)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    drop = (jax.random.PRNGKey(5), dropout) if dropout else None
+
+    def loss(q_, k_, v_):
+        h = em.tconv_attend_plan(q_, k_, v_, plans, E, drop)
+        return jnp.sum(em.tconv_attend_plan(h, k_, v_, plans, E, drop) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    scans = _scans_and_their_gathers(jaxpr)
+    by_rows = [g for g in scans if any(
+        s[0] == rows and len(s) == 2 for s in g)]
+    assert len(by_rows) == 2 * 3
+    assert all((rows, 2 * K * F) in g for g in by_rows)
+    shapes = []
+    for eqn in _eqns(jaxpr):
+        assert not eqn.primitive.name.startswith("scatter"), str(eqn)[:200]
+        shapes += [tuple(o.aval.shape) for o in eqn.outvars
+                   if getattr(o.aval, "shape", None) is not None]
+    on_lanes = [s for s in shapes if len(s) >= 2
+                and s[-1] in (K, K * F, 2 * K * F)
+                and int(np.prod(s[:-1])) > step_slots]
+    assert not on_lanes, on_lanes[:5]
 
 
 # -- the builder and the op IR ----------------------------------------------
@@ -456,12 +610,13 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
                           "src_scans", "short_scans"]
     assert (info["backend"], info["score"]) == ("plan", "dot")
     # one [K, E] float32 array; e of each of the three ops; six tables an
-    # op read by row in four scans (k with v for de and dq, q with du for
-    # dk and dv); ONE of them an op over the src-keyed plan
+    # op read by row in three scans (k with v for the score and u, k with
+    # v for de and dq, q with du for dk and dv); ONE of them an op over the
+    # src-keyed plan
     assert info["score_bytes"] == 2 * e * 4
     assert info["residual_bytes"] == 3 * 2 * e * 4
     assert info["row_passes"] == 18
-    assert info["row_scans"] == 12
+    assert info["row_scans"] == 9
     assert info["src_scans"] == 3
     assert info["short_scans"] == 0         # every row here is 128 lanes
     line = next(ln for ln in capsys.readouterr().err.splitlines()
@@ -471,7 +626,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
         f" tconv_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
         f" tconv_score=dot tconv_score_bytes={info['score_bytes']}"
         f" tconv_residual_bytes={info['residual_bytes']}"
-        " tconv_row_passes=18 tconv_row_scans=12 tconv_src_scans=3"
+        " tconv_row_passes=18 tconv_row_scans=9 tconv_src_scans=3"
         " tconv_short_scans=0")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
@@ -479,7 +634,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert att["backend"] == "plan" and att["tconv_score"] == "dot"
     assert att["tconv_residual_bytes"] == info["residual_bytes"]
     assert (att["tconv_row_passes"], att["tconv_row_scans"],
-            att["tconv_src_scans"]) == (18, 12, 3)
+            att["tconv_src_scans"]) == (18, 9, 3)
     assert list(att)[-4:] == ["tconv_row_passes", "tconv_row_scans",
                               "tconv_src_scans", "tconv_short_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
@@ -487,7 +642,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
                  "row_passes", "row_scans", "src_scans", "short_scans"):
         assert f"roc_tconv_{name} " in prom
     assert "roc_tconv_src_scans 3" in prom          # unlabelled: a counter
-    assert "roc_tconv_row_scans 12" in prom
+    assert "roc_tconv_row_scans 9" in prom
     assert 'roc_tconv_backend{backend="plan"} 1' in prom
     assert 'roc_tconv_score{score="dot"} 1' in prom
     from roc_tpu.obs import report as obs_report
