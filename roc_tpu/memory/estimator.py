@@ -181,10 +181,12 @@ def estimate_model(model, rows: int, edges: int, itemsize: int = 4,
 def gat_edge_residual_bytes(op, edges: int, itemsize: int = 4) -> int:
     """Per-EDGE bytes a gat op keeps from forward to backward on the plan
     attention path (ops.edge._gat_plan_fwd): the shifted exponentials
-    ``e [K, E]`` at the activation width and the score's sign ``qpos
-    [K, E]`` bool (a dot or dynamic score keeps ``e`` alone: the dynamic
-    score's slope is [K F, E], recomputed in the backward's scans, never
-    kept).  Both carry edges on the lane axis, so these are the
+    ``e [K, E]`` at the activation width, made from the score its one
+    forward scan lands and the broadcast row max, and the score's sign
+    ``s >= 0`` as ``[K, E]`` bool, the LeakyReLU's side (a dot or dynamic
+    score keeps ``e`` alone: the dynamic score's slope is [K F, E],
+    recomputed in the backward's scans, never kept).  Both carry edges on
+    the lane axis, so these are the
     bytes the device holds (the old [E, K] layout held 16 x as much at
     K = 8: 128 lanes a row); the attention-dropout mask is redrawn, not
     kept.  They live inside the custom VJP: an all-KEEP step holds them

@@ -43,13 +43,13 @@ from typing import Optional, Tuple
 
 PREFIX = "roc."
 PASSES = ("fwd", "bwd", "remat")
-# attention (ops/edge.py): score, max, norm, u, de, dq (gat), dedq (tconv:
-# the two in one scan), su (tconv: score and u in one scan), src, bcast,
-# lanes, edge; binned aggregation (ops/pallas/binned.py): p1, p1_flat, p2,
-# fused; matmul aggregation (ops/aggregate.py): mm
+# attention (ops/edge.py): score, max, norm, u, de, dq (gat), dedq (the
+# two in one scan), su (gat, tconv: score and u in one scan), src, bcast,
+# edge; binned aggregation (ops/pallas/binned.py): p1, p1_flat, p2, fused;
+# matmul aggregation (ops/aggregate.py): mm
 PARTS = frozenset({"score", "max", "norm", "u", "de", "dq", "dedq", "su",
-                   "src", "bcast", "lanes", "edge", "p1", "p1_flat", "p2",
-                   "fused", "mm"})
+                   "src", "bcast", "edge", "p1", "p1_flat", "p2", "fused",
+                   "mm"})
 # ops whose parts follow the op itself: JAX differentiates them, so no
 # explicit pass stands between
 OP_PARTS = {"roc.exchange": frozenset({"down", "wire", "up"})}

@@ -242,7 +242,7 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # Segment-max (the softmax shift) is the same one-hot window machinery with
 # masked max in place of the MXU dot.
 #
-# LAYOUT: every per-edge array of this path (q, s, e, qpos, the dropout
+# LAYOUT: every per-edge array of this path (s, e, its sign, the dropout
 # multiplier, de, dq) is [K, E] — heads on the sublane axis, EDGES ON THE
 # LANE AXIS.  The TPU tiles the two minor dimensions to (8, 128): an [E, 8]
 # float32 array is stored at 128 lanes a row, 16 x its size (12 GB apiece at
@@ -256,8 +256,9 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # READS: edge_dst is sorted, so a node table read by it is a segment
 # broadcast, and the aligned dst-keyed plan holds it (_plan_broadcast;
 # _edge_contract's du rows): no gather of this path takes edge_dst as its
-# index.  The src side still gathers (_take_lanes by edge_src, the
-# src-keyed plan's column reads, the feature rows by the plans' nid), and
+# index.  The src side still gathers (the src-keyed plan's column reads,
+# the feature rows by the plans' nid; on the edge-sharded road of
+# parallel/spmd.py a lane gather by edge_src too), and
 # pays for each index list ONCE a backward where memory allows: what a layer
 # sums over the src-keyed plan is one scan, its per-edge weights stacked
 # into one [K', E] array read by one column gather (src_pos) and its node
@@ -268,7 +269,8 @@ def _chunked_gat_attend(h, table, edge_src, edge_dst, num_nodes: int,
 # rows) are one scan over one gather of [k | v] rows (_contract_then_sum),
 # in the forward the score (k rows) and u (v rows) are one too, the
 # softmax's max and normaliser, which stand between them, carried online
-# (_score_then_sum).
+# (_score_then_sum).  Gat's forward is the same scan over [h | as_src]
+# rows: the score's source half rides u's rows (_additive_tables).
 #
 # The full GAT layer is a custom_vjp (gat_attend_plan) whose hand-derived
 # backward is built from these primitives plus the src side's plain gathers
@@ -1006,11 +1008,12 @@ def _land_ds_then_sum(du, dz, e, ew, obi, edst, pos, nid, num_edges: int,
             acc[:rows].astype(e.dtype).reshape(rows, K, F), extra)
 
 
-def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
-    """The forward of a pair score in ONE scan over the aligned dst-keyed
-    plan, one gather by its ``nid`` a step, where a score scan, the max,
-    the normaliser and the weighted sum walked it four times and gathered
-    by ``nid`` twice:
+def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables,
+                    precision):
+    """The forward of an attention score in ONE scan over the aligned
+    dst-keyed plan, one gather by its ``nid`` a step, where a score scan
+    (or a lane gather by ``edge_src``), the max, the normaliser and the
+    weighted sum walked it four times and gathered by ``nid`` twice:
 
       s[k, e] = score(x[dst_e], t[src_e])                          [K, E]
       m[k, i] = max_{e: dst_e = i} s[k, e]
@@ -1026,20 +1029,23 @@ def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
     :func:`_plan_blocks` does, takes its masked max by window row as
     :func:`_plan_max`'s body does, forms ``e = exp(s - m[dst])`` at its
     slots and adds ``e`` to ``z`` and ``e w`` times the value rows to ``u``
-    (one-hot dots at "highest": float32 throughout).  Steps of
-    ``_PLAN_CB_BLOCKS`` chunks, as :func:`_land_ds_then_sum`: the gathered
-    block then lies in VMEM (:func:`_contract_then_sum` has the numbers).
+    (one-hot dots: ``z`` at "highest", ``u``'s S1 dot at ``precision``,
+    which rounds each product once at the MXU's default, its S2 dot at
+    "highest").  Steps of ``_PLAN_CB_BLOCKS`` chunks, as
+    :func:`_land_ds_then_sum`: the gathered block then lies in VMEM
+    (:func:`_contract_then_sum` has the numbers).
 
-    ``x``: [rows, K, F] destination rows, spread over a step's slots from
-    their windows (one-hot, exact); ``w``: [K, E] multiplier of the sum's
-    weights (the dropout mask) or None.  ``tables(rows, K, F)`` gives the
-    score's own part, made once a pass: (the node table a step gathers by
-    ``nid``, ``score(g, x_e)``: the ``[K, slots]`` scores of the gathered
-    rows against the slots' own ``[slots, K F]`` rows of ``x``,
-    ``values(g)``: the ``[slots, K F]`` rows ``u`` sums).  Masked slots
-    score an exact zero and add nothing.  Returns (s [K, E], m [K, rows]:
-    -inf on a row with no in-edge, z [K, rows], u [rows, K, F]); such a row
-    sums zeros."""
+    ``x``: [rows, K, F], the destination rows: their count, heads and
+    width are ``u``'s.  ``w``: [K, E] multiplier of the sum's weights (the
+    dropout mask) or None.  ``tables(rows, K, F)`` gives the score's own
+    part, made once a pass (:func:`_dot_tables`, :func:`_additive_tables`):
+    (the node table a step gathers by ``nid``, ``near(ob, ed)``: the step's
+    slots' own term of their destination rows, spread from the windows by
+    a one-hot product (exact), ``score(g, d)``: the ``[K, slots]`` scores
+    of the gathered rows against it, ``values(g)``: the ``[slots, K F]``
+    rows ``u`` sums).  Masked slots score an exact zero and add nothing.
+    Returns (s [K, E], m [K, rows]: -inf on a row with no in-edge, z [K,
+    rows], u [rows, K, F]); such a row sums zeros."""
     from roc_tpu.ops.aggregate import _one_hot_dots, _vary_like
     from roc_tpu.ops.pallas.segment_sum import EB, VB
     rows, K, F = x.shape
@@ -1048,8 +1054,7 @@ def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
     obi, edst, pos, nid, nsteps = _pad_steps(obi, edst, pos, nid, cb)
     nb, base, off = _block_steps(edst, pos, nsteps, cb, num_edges)
     # node-sized, once a pass
-    x_w = _window_rows(x.reshape(rows, H))
-    table, score, values = tables(rows, K, F)
+    table, near, score, values = tables(rows, K, F)
     expand = _head_expand(K, F, jnp.float32)
     read_w = None if w is None else _slot_reader(w, cb, True)
     neg = jnp.asarray(-jnp.inf, jnp.float32)
@@ -1058,10 +1063,10 @@ def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
         s_out, m, z, u = carry
         ob, ed, po, ni, b0, of = sl
         g = jnp.take(table, ni.reshape(cb * EB), axis=0, mode="clip")
-        x_e = _window_slot_rows(x_w, ob, ed, H).reshape(cb * EB, H)
+        d = near(ob, ed)
         live = (ed < VB)[:, None, :]                          # [chunk, 1, EB]
         s = jnp.where(live.transpose(1, 0, 2),
-                      score(g, x_e).reshape(K, cb, EB), 0.0)  # [K, chunk, EB]
+                      score(g, d).reshape(K, cb, EB), 0.0)    # [K, chunk, EB]
         s_out = _land_blocks(s_out, s, b0, of)
         s = s.transpose(1, 0, 2)                              # [chunk, K, EB]
         # the step's max by window row (_plan_max's body), onto the rows'
@@ -1099,7 +1104,7 @@ def _score_then_sum(x, w, obi, edst, pos, nid, num_edges: int, tables):
         ew = e if read_w is None else e * read_w(po)
         outs = _one_hot_dots(
             values(g) * _over_head_lanes(ew, expand, 1, g.dtype), ed, ob, cb,
-            "highest", "highest")                             # [w VB, K F]
+            precision, "highest")                             # [w VB, K F]
         cur = jax.lax.dynamic_slice(u, (ob[0] * VB, 0), outs.shape)
         u = jax.lax.dynamic_update_slice(
             u, cur * _over_head_lanes(scale, expand, 1, u.dtype) + outs,
@@ -1134,11 +1139,20 @@ def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
     backward is hand-derived so no gather is ever transposed into a TPU
     scatter; all reductions ride the dst-/src-keyed plans.
 
-    ``precision`` feeds ONLY the two [*, K, F] weighted feature sums (u
-    fwd, dtable bwd) — the FLOP carriers; "default" is the fast policy's
-    single-pass bf16 (one feature rounding).  The [K, E] score/normalizer
-    sums stay at "highest" always: their FLOPs are negligible and the
-    softmax normalization stays exact in both modes.
+    The forward is ONE scan over the dst-keyed plan, one gather of
+    ``[table | as_src]`` rows by its ``nid`` a step (:func:`_score_then_sum`
+    with :func:`_additive_tables`: the score, its max, the normaliser and
+    u, the softmax carried online), then :func:`_plan_broadcast` of the
+    max for the backward's ``e``.  Of the three score shapes over these
+    plans (additive here, dot-product in :func:`tconv_attend_plan`, dynamic
+    in :func:`gatv2_attend_plan`) the first two make their forward so.
+
+    ``precision`` feeds ONLY the two [*, K, F] weighted feature sums (u's
+    S1 dot in the forward's scan, dtable bwd) — the FLOP carriers;
+    "default" is the fast policy's single-pass bf16 (one rounding of each
+    product).  The [K, E] score/normalizer sums stay at "highest" always:
+    their FLOPs are negligible and the softmax normalization stays exact
+    in both modes.
     """
     key, rate = _drop_args(drop)
     return _gat_plan(h, table, a_src, a_dst, plans, edge_ids, key, slope,
@@ -1148,55 +1162,32 @@ def gat_attend_plan(h, table, a_src, a_dst, plans: GatPlans, edge_ids,
 @partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def _gat_plan(h, table, a_src, a_dst, plans, edge_ids, key, slope,
               precision, rate):
-    return _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key,
+    return _gat_plan_out(h, table, a_src, a_dst, plans, edge_ids, key,
                          slope, precision, rate)[0]
 
 
-def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
-                  precision="highest", rate=0.0):
-    edge_src, _ = edge_ids
-    N = plans.num_rows
-    K, E = h.shape[1], edge_src.shape[0]
-    dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
+def _gat_plan_out(h, table, a_src, a_dst, plans, edge_ids, key, slope,
+                  precision, rate):
+    """The forward's one scan and the division: (out, s, m, zc), all an
+    evaluation pass makes (it needs no e, so no broadcast of the max)."""
+    E, K = edge_ids[0].shape[0], h.shape[1]
+    dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
     # the device scopes (obs/scopes.py): the pass, then one part a scan or
     # kernel; every line of the rule sits in one
     with scopes.scope("fwd"):
-        with scopes.scope("score"):
-            # the score products are tiny and always float32-exact: at the
-            # MXU's default precision h and a would be rounded to bf16
-            # inside the exp
-            as_t = jnp.einsum("tkf,kf->kt", table, a_src,
-                              precision="highest")            # [K, T]
-            ad_l = jnp.einsum("nkf,kf->kn", h, a_dst,
-                              precision="highest")            # [K, N]
-        with scopes.scope("lanes"):
-            q = _take_lanes(as_t, edge_src)                   # [K, E]
-        # every read of a node table by edge_dst rides the dst plan
-        with scopes.scope("bcast"):
-            q = _plan_broadcast(ad_l, *dplan, E, q)           # [K, E]
-        with scopes.scope("edge"):
-            s = jax.nn.leaky_relu(q, negative_slope=slope)
-        with scopes.scope("max"):
-            m = _plan_max(s, *dplan, N)
-            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
-        with scopes.scope("bcast"):
-            mb = _plan_broadcast(m, *dplan, E)
-        with scopes.scope("edge"):
-            e = jnp.exp(s - mb)                               # [K, E]
-        with scopes.scope("norm"):
-            z = _plan_sum(e, None, plans.dst_obi, plans.dst_edst,
-                          plans.dst_pos, plans.dst_nid, N, "highest",
-                          True)                               # [K, N]
         # attention dropout: the weighted sum sees the dropped
         # coefficients, the normaliser never does (alpha~ = alpha * keep /
         # (1 - p))
         with scopes.scope("edge"):
-            w = _keep_scale((key, rate), K, E, e.dtype)
-            ew = e if w is None else e * w
-        with scopes.scope("u"):
-            u = _plan_sum(ew, table, plans.dst_obi, plans.dst_edst,
-                          plans.dst_pos, plans.dst_nid, N, precision,
-                          True)                               # [N, K, F]
+            w = _keep_scale((key, rate), K, E, h.dtype)
+        # the score (its source half riding u's rows), its max, the
+        # normaliser and u: one scan over one gather of [h | as_src] rows
+        # by dst_nid, the softmax carried online
+        with scopes.scope("su"):
+            s, m, z, u = _score_then_sum(
+                h, w, *dst, E, _additive_tables(h, table, a_src, a_dst,
+                                                slope), precision)
+            m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
         with scopes.scope("norm"):
             # Guard is _Z_GUARD (rationale at its definition): XLA flushes
             # subnormals to zero, and rows with no in-edges (padded shard
@@ -1204,9 +1195,31 @@ def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
             # max edge contributes exp(0)).
             zc = jnp.maximum(z, _Z_GUARD)
             out = u / zc.T[:, :, None]
+    return out, s, m, zc
+
+
+def _gat_plan_fwd(h, table, a_src, a_dst, plans, edge_ids, key, slope,
+                  precision="highest", rate=0.0):
+    out, s, m, zc = _gat_plan_out(h, table, a_src, a_dst, plans, edge_ids,
+                                  key, slope, precision, rate)
+    dst = (plans.dst_obi, plans.dst_edst, plans.dst_pos)
+    with scopes.scope("fwd"):
+        # e, read by the backward alone, from the rows' max
+        with scopes.scope("bcast"):
+            mb = _plan_broadcast(m, *dst, edge_ids[0].shape[0])
+        with scopes.scope("edge"):
+            e = jnp.exp(s - mb)                               # [K, E]
+            # the slope's side: LeakyReLU with a positive slope keeps the
+            # sign of its argument
+            spos = s >= 0
+            # both made before the output is handed on, as tconv's e
+            # (_tconv_plan_fwd): nothing of the forward needs them, and
+            # XLA would make them in the backward from s and the broadcast
+            # max, both kept until then
+            out, e, spos = jax.lax.optimization_barrier((out, e, spos))
     # the mask is NOT a residual: the backward redraws it from the key
     return out, (h, table, a_src, a_dst, plans, edge_ids, key,
-                 q >= 0, e, zc, out)
+                 spos, e, zc, out)
 
 
 def _int_zeros(tree):
@@ -1228,6 +1241,18 @@ def gat_src_scans(heads: int) -> int:
     mask); at K = 1 it saves 154 ms and the compiler's temporaries are the
     parent's to the megabyte."""
     return 1 if 2 * heads <= 8 else 2
+
+
+def gat_fwd_scans(edges: int, edge_sharded: bool) -> int:
+    """Scans over the plans the forward of one gat op makes, a training
+    step: two on the plan road (:func:`_gat_plan_fwd`: ``su``, then the
+    max's broadcast for ``e``); five on the edge-sharded road
+    (parallel/spmd.py ``_egat_fwd``: the two broadcasts, the max, the
+    normaliser and ``u``), its lane gather by ``edge_src`` a sixth where a
+    shard's ``edges`` pass ``_LANE_GATHER_CHUNK``."""
+    if not edge_sharded:
+        return 2
+    return 5 + int(edges > _LANE_GATHER_CHUNK)
 
 
 def _gat_plan_bwd(slope, precision, rate, res, gout):
@@ -1336,27 +1361,77 @@ def tconv_attend_plan(q, k, v, plans: GatPlans, num_edges: int, drop=None):
     return _tconv_plan(q, k, v, plans, key, num_edges, rate)
 
 
-def _dot_tables(k, v):
-    """:func:`_score_then_sum`'s ``tables`` of a dot-product score: ``k``
-    (the score) and ``v`` (u's rows) side by side, one row list a step, and
-    per head ``q_i . k_j / sqrt(F)``.  The table is made from ``k`` and
-    ``v`` behind an optimization barrier: the backward makes the same
-    ``[k | v]`` from the same two arrays (:func:`_contract_then_sum`), and
-    XLA would make both here, as one expression or as one fusion, and keep
-    the backward's alive from the forward, a layer's worth each (+0.4 to
-    0.6 GB at the train step's peak by the compiler's buffer assignment
-    for a v5e at the Reddit shape)."""
+def _additive_tables(h, table, a_src, a_dst, slope: float):
+    """:func:`_score_then_sum`'s ``tables`` of GAT's additive score,
+    ``LeakyReLU(a_dst . h_i + a_src . t_j)`` per head.  The source half is
+    one number a head and a source row, so it rides u's own rows as K more
+    columns, ``[t | as_src]`` (72 lanes at K = 8, F = 8; 42 at K = 1, F =
+    41: both within the 128 lanes u's rows take anyway, and a gathered row
+    costs its index, not its bytes: PERF.md section 6), where it was a
+    lane gather by ``edge_src`` of its own (``_take_lanes``).  A step takes
+    it out of the gathered rows by a one-hot product, ``[K, slots]`` (heads
+    on sublanes; exact at "highest").  The destination half ``ad = a_dst .
+    h`` is spread over the slots from the windows as
+    :func:`_plan_broadcast` spreads it, ``[K, slots]`` too: spreading
+    ``h`` and contracting it at every slot, as the dot score does ``q``,
+    would make a ``[slots, K F]`` block a step for K numbers a slot.  Both
+    halves are float32 at "highest" (at the MXU's default ``h`` and ``a``
+    would be rounded to bf16 inside the exp) and ``s`` is their one sum
+    through the LeakyReLU, as the three scans it replaces made it.
+    ``table`` may have more rows than ``h`` (a shard's ``x ++ halo``)."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+
     def tables(rows, K, F):
         H = K * F
+        ad_w = _window_rows(jnp.einsum("nkf,kf->nk", h, a_dst,
+                                       precision="highest"))
+        as_t = jnp.einsum("tkf,kf->tk", table, a_src, precision="highest")
+        ts = jnp.concatenate([table.reshape(-1, H), as_t], axis=1)
+        pick = jnp.eye(K, H + K, H, dtype=jnp.float32)    # as_src's columns
+
+        def near(ob, ed):
+            return _window_lanes(ad_w, ob, ed, K).transpose(1, 0, 2).reshape(
+                K, ob.shape[0] * EB)
+
+        def score(g, ad_e):
+            as_e = jax.lax.dot_general(
+                pick, g, (((1,), (1,)), ((), ())), precision="highest",
+                preferred_element_type=jnp.float32)
+            return jax.nn.leaky_relu(as_e + ad_e, negative_slope=slope)
+
+        return ts, near, score, lambda g: g[:, :H]
+
+    return tables
+
+
+def _dot_tables(q, k, v):
+    """:func:`_score_then_sum`'s ``tables`` of a dot-product score: ``k``
+    (the score) and ``v`` (u's rows) side by side, one row list a step, the
+    slots' own ``q`` rows, and per head ``q_i . k_j / sqrt(F)``.  The table
+    is made from ``k`` and ``v`` behind an optimization barrier: the
+    backward makes the same ``[k | v]`` from the same two arrays
+    (:func:`_contract_then_sum`), and XLA would make both here, as one
+    expression or as one fusion, and keep the backward's alive from the
+    forward, a layer's worth each (+0.4 to 0.6 GB at the train step's peak
+    by the compiler's buffer assignment for a v5e at the Reddit shape)."""
+    from roc_tpu.ops.pallas.segment_sum import EB
+
+    def tables(rows, K, F):
+        H = K * F
+        q_w = _window_rows(q.reshape(rows, H))
         kb, vb = jax.lax.optimization_barrier((k, v))
         kv = jnp.concatenate([kb.reshape(-1, H), vb.reshape(-1, H)], axis=1)
         collapse = _head_expand(K, F, jnp.float32)
+
+        def near(ob, ed):
+            return _window_slot_rows(q_w, ob, ed, H).reshape(
+                ob.shape[0] * EB, H)
 
         def score(g, q_e):
             return _contract_heads(q_e, g[:, :H], collapse) * (
                 1.0 / np.sqrt(F))
 
-        return kv, score, lambda g: g[:, H:]
+        return kv, near, score, lambda g: g[:, H:]
 
     return tables
 
@@ -1380,7 +1455,8 @@ def _tconv_plan_out(q, k, v, plans, key, num_edges, rate):
         # the score, its max, the normaliser and u: one scan over one
         # gather of [k | v] rows by dst_nid, the softmax carried online
         with scopes.scope("su"):
-            s, m, z, u = _score_then_sum(q, w, *dst, E, _dot_tables(k, v))
+            s, m, z, u = _score_then_sum(q, w, *dst, E, _dot_tables(q, k, v),
+                                         "highest")
             m = jax.lax.stop_gradient(jnp.where(jnp.isfinite(m), m, 0.0))
         with scopes.scope("norm"):
             # _Z_GUARD (rationale at its definition): rows with no in-edge

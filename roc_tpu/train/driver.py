@@ -30,7 +30,7 @@ from roc_tpu.device import on_tpu
 from roc_tpu.graph.datasets import Dataset
 from roc_tpu.models.model import (PAIR_SCORES, GraphCtx, Model,
                                   attention_heads, attention_score)
-from roc_tpu.ops.edge import gat_src_scans, short_plan_sums
+from roc_tpu.ops.edge import gat_fwd_scans, gat_src_scans, short_plan_sums
 from roc_tpu.ops.softmax import format_metrics
 from roc_tpu.optim.adam import Adam
 from roc_tpu.train.config import Config
@@ -506,11 +506,14 @@ class BaseTrainer:
         score's sign, [K, E] each, per op; 0 where autodiff keeps what it
         likes); ``dst_reads`` (gat): how node tables are read by
         ``edge_dst`` ("plan": the aligned dst plan's segment broadcast;
-        "gather": by index, the xla scans).  A tconv model instead says
-        ``score`` ("dot"), ``score_bytes`` (ONE [K, E] float32 array of
-        its widest op: what each per-edge array live in a layer's backward
-        costs), ``residual_bytes`` (the [K, E] bytes kept for the
-        backward, e of every op), ``row_passes`` (node tables a training
+        "gather": by index, the xla scans); ``fwd_scans`` (gat): the scans
+        over the plans a training step's gat forwards make
+        (ops.edge.gat_fwd_scans: 2 an op, ``su`` and the max's broadcast;
+        5 or 6 on the edge-sharded road; 0 on the xla scans).  A tconv
+        model instead says ``score`` ("dot"), ``score_bytes`` (ONE [K, E]
+        float32 array of its widest op: what each per-edge array live in a
+        layer's backward costs), ``residual_bytes`` (the [K, E] bytes kept
+        for the backward, e of every op), ``row_passes`` (tables a training
         step reads by row over the plans, 6 an op: k, v forward; v, k, q, du
         backward) and ``row_scans`` (the scans that gather them by an index
         list, 3 an op: [k | v] side by side for the score and u, and again
@@ -527,9 +530,10 @@ class BaseTrainer:
         where ops.edge.gat_src_scans lets it), 2 an op on the edge-sharded
         road (parallel/spmd.py ``_egat_bwd``), 0 on the xla scans; then
         ``short_scans``: the row-gathering sums a training step makes
-        (``u`` forward, but tconv's, which its score's scan carries; the
-        src side's rows backward: K F wide, tconv's and gatv2's src side
-        2 K F) at a step shorter than ops.edge's cap, by
+        (``u`` forward, but tconv's and gat's, which their score's scan
+        carries, gat's on the edge-sharded road excepted; the src side's
+        rows backward: K F wide, tconv's and gatv2's src side 2 K F) at a
+        step shorter than ops.edge's cap, by
         ops.edge.plan_sum_step, the rule they are stepped by; 0 on the xla
         scans."""
         kind = attention_kind(self.model)
@@ -545,9 +549,12 @@ class BaseTrainer:
         info = {"backend": "plan" if on_plan else "xla",
                 "plan_pad_ratio": gat_plan_stats(plans, edges)["pad_ratio"]
                 if on_plan else 0.0}
+        sharded = plans is not getattr(gd, "gat_plans", None)
         if kind == "gat":
             info["score_bytes"] = sum(heads) * edges * (4 + 1)
             info["dst_reads"] = "plan" if on_plan else "gather"
+            info["fwd_scans"] = len(heads) * gat_fwd_scans(
+                edges, sharded) if on_plan else 0
         else:
             from roc_tpu.memory.estimator import gat_edge_residual_bytes
             info.update(score="dot" if kind == "tconv" else "dynamic",
@@ -559,7 +566,6 @@ class BaseTrainer:
                 info["row_passes"] = TCONV_ROW_PASSES * len(heads)
             info["row_scans"] = PAIR_ROW_SCANS[kind] * len(heads) \
                 if on_plan else 0
-        sharded = plans is not getattr(gd, "gat_plans", None)
 
         def src_scans(k):       # of one op of k heads, a training step
             if not on_plan:
@@ -574,7 +580,9 @@ class BaseTrainer:
             kf = attention_heads(op) * op.attrs["head_dim"]
             if kind == "tconv":     # u rides the score's scan
                 return (2 * kf,)
-            return kf, kf * (1 if kind == "gat" else 2)
+            if kind == "gat":       # so does gat's, but edge-sharded
+                return (kf, kf) if sharded else (kf,)
+            return kf, 2 * kf
 
         info["short_scans"] = short_plan_sums(
             [w for op in self.model.ops if op.kind == "gat"

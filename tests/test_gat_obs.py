@@ -66,7 +66,10 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     e = ds.graph.num_edges
     assert info["backend"] == "plan"
     assert list(info) == ["backend", "plan_pad_ratio", "score_bytes",
-                          "dst_reads", "src_scans", "short_scans"]
+                          "dst_reads", "fwd_scans", "src_scans",
+                          "short_scans"]
+    # the forward walks the plans twice an op: su and the max's broadcast
+    assert info["fwd_scans"] == 4
     # the backward walks the src-keyed plan once an op: dast rides
     # dtable's scan (two ops here)
     assert info["src_scans"] == 2
@@ -83,7 +86,8 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
     assert line == ("# attention: backend=plan"
                     f" gat_plan_pad_ratio={info['plan_pad_ratio']:.4f}"
                     f" gat_score_bytes={info['score_bytes']}"
-                    " gat_dst_reads=plan gat_src_scans=2 gat_short_scans=0")
+                    " gat_dst_reads=plan gat_fwd_scans=4 gat_src_scans=2"
+                    " gat_short_scans=0")
     tr.train(print_fn=lambda *a, **k: None)
     recs = obs.load_jsonl(str(tmp_path / "obs" / "metrics.jsonl"))
     att, = [r for r in recs if r["type"] == "attention"]
@@ -95,6 +99,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
         "gat_src_scans", "gat_short_scans"]
     prom = (tmp_path / "obs" / "metrics.prom").read_text()
     assert "roc_gat_src_scans 2" in prom            # unlabelled: a counter
+    assert "roc_gat_fwd_scans 4" in prom
     assert "roc_gat_plan_pad_ratio " in prom and "roc_gat_score_bytes " in prom
     assert 'roc_gat_backend{backend="plan"} 1' in prom
     assert 'roc_gat_dst_reads{dst_reads="plan"} 1' in prom
@@ -102,7 +107,7 @@ def test_attention_record_gauges_and_start_up_line(tmp_path, capsys):
                              str(tmp_path / "obs" / "metrics.jsonl"))
     assert "# attention: backend=plan gat_plan_pad_ratio=" in text
     assert (f"gat_score_bytes={info['score_bytes']} gat_dst_reads=plan"
-            " gat_src_scans=2 gat_short_scans=0") in text
+            " gat_fwd_scans=4 gat_src_scans=2 gat_short_scans=0") in text
     assert "gat_plan_build" in text
 
 
@@ -118,8 +123,9 @@ def test_the_xla_scans_still_gather_by_edge_dst(capsys):
                 if ln.startswith("# attention:"))
     assert line.startswith("# attention: backend=xla ")
     assert line.endswith(
-        " gat_dst_reads=gather gat_src_scans=0 gat_short_scans=0")
-    assert info["src_scans"] == info["short_scans"] == 0
+        " gat_dst_reads=gather gat_fwd_scans=0 gat_src_scans=0"
+        " gat_short_scans=0")
+    assert info["fwd_scans"] == info["src_scans"] == info["short_scans"] == 0
 
 
 def test_models_without_attention_say_nothing(capsys):

@@ -457,11 +457,12 @@ def _walk(jaxpr, tainted, gathers, shapes):
 def test_no_gather_of_the_plan_path_is_indexed_by_edge_dst(dropout,
                                                            monkeypatch):
     """jax.grad of gat_attend_plan, forward and hand-derived backward: no
-    gather takes edge_dst, or anything computed from it, as its indices
-    (edge_src still does: the walk must find those, or it sees nothing);
-    and what the plan reads add has no edge-sized array with the heads, or
-    a feature row, on the lane axis: rows of K*F exist a scan step at a
-    time only, as in _plan_sum."""
+    gather takes edge_dst, or anything computed from it, as its indices,
+    and none takes edge_src either since the score's source half rides
+    u's rows (the plans' nid does: the walk must find those, or it sees
+    nothing); and what the plan reads add has no edge-sized array with the
+    heads, or a feature row, on the lane axis: rows of K*F exist a scan
+    step at a time only, as in _plan_sum."""
     import jax
     _small_steps(monkeypatch)
     src, dst, rows = _edges("hub", seed=6)
@@ -475,19 +476,21 @@ def test_no_gather_of_the_plan_path_is_indexed_by_edge_dst(dropout,
     plans = em.build_gat_plans(src, dst, rows, rows)
     drop = (jax.random.PRNGKey(5), dropout) if dropout else None
 
-    def loss(hh, s, d, e_src, e_dst):
-        return jnp.sum(em.gat_attend_plan(hh, hh, s, d, plans,
-                                          (e_src, e_dst), 0.2, "default",
-                                          drop) ** 2)
+    def loss(hh, s, d, e_src, e_dst, nid):
+        return jnp.sum(em.gat_attend_plan(
+            hh, hh, s, d, plans._replace(dst_nid=nid), (e_src, e_dst), 0.2,
+            "default", drop) ** 2)
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
         h, a_src, a_dst, jnp.asarray(src, jnp.int32),
-        jnp.asarray(dst, jnp.int32)).jaxpr
-    by_dst, by_src, shapes = [], [], []
+        jnp.asarray(dst, jnp.int32), plans.dst_nid).jaxpr
+    by_dst, by_src, by_nid, shapes = [], [], [], []
     _walk(jaxpr, [jaxpr.invars[4]], by_dst, shapes)
     _walk(jaxpr, [jaxpr.invars[3]], by_src, [])
-    assert by_src, "the walk no longer finds the gathers by edge_src"
+    _walk(jaxpr, [jaxpr.invars[5]], by_nid, [])
+    assert by_nid, "the walk no longer finds the gathers by the plan's nid"
     assert not by_dst, by_dst[:3]
+    assert not by_src, by_src[:3]
     assert sum(1 for _, s in shapes if s == (K, E)) >= 8
     heads_last = [(p, s) for p, s in shapes if len(s) >= 2 and s[-1] == K
                   and int(np.prod(s[:-1])) >= step_slots]
@@ -560,6 +563,9 @@ def test_the_edge_sharded_backward_keeps_its_two_calls_and_its_numbers():
     # what each says of itself: one scan an op against two
     assert one.attention_info()["src_scans"] == 2
     assert four.attention_info()["src_scans"] == 4
+    # and forward: su and the max's broadcast against _egat_fwd's five
+    assert one.attention_info()["fwd_scans"] == 4
+    assert four.attention_info()["fwd_scans"] == 10
     one.run_epoch()
     four.run_epoch()
     m1, m4 = (jax.device_get(t.opt_state.m) for t in (one, four))
@@ -569,3 +575,142 @@ def test_the_edge_sharded_backward_keeps_its_two_calls_and_its_numbers():
                                                             np.float64)
         assert np.linalg.norm(b) > 0, name
         assert np.linalg.norm(a - b) <= 2e-5 * np.linalg.norm(b), name
+
+
+# -- the forward's one scan: score, max, normaliser and u -------------------
+
+def _gat_case(heads, width, halo, seed):
+    """(h, table, a_src, a_dst, src, dst, rows) over the hub graph: the
+    table is h, or h and 37 rows more that some edges come from (a shard's
+    ``x ++ halo``, as SpmdTrainer passes it)."""
+    src, dst, rows = _edges("hub", seed=seed)
+    rng = np.random.default_rng(seed)
+    extra = 37 if halo else 0
+    if halo:
+        far = rng.random(src.size) < 0.2
+        src = np.where(far, rows + rng.integers(0, extra, src.size), src)
+    table = rng.standard_normal((rows + extra, heads, width))
+    h = table[:rows] if not halo else rng.standard_normal(
+        (rows, heads, width))
+    a_src, a_dst = (rng.standard_normal((heads, width)) for _ in range(2))
+    return tuple(jnp.asarray(a, jnp.float32)
+                 for a in (h, table, a_src, a_dst)) + (src, dst, rows)
+
+
+@pytest.mark.parametrize("precision", ["highest", "default"])
+@pytest.mark.parametrize("dropout", [0.0, 0.6])
+@pytest.mark.parametrize("halo", [False, True])
+@pytest.mark.parametrize("heads,width", [(8, 8), (1, 41)])
+def test_the_fused_forward_and_its_gradients_against_the_xla_road(
+        heads, width, halo, dropout, precision, monkeypatch):
+    """gat_attend_plan (the forward one `su` scan of several steps, then
+    the hand-derived backward) against gat_attend, the xla road, given the
+    same key: values within 64 ulps of the output's scale, every gradient
+    within 2e-5 of its norm (float32 reassociation; on the CPU "default"
+    is float32 too, so it is the path and not the rounding that is held);
+    rows with no in-edge read exact zeros."""
+    import jax
+    _small_steps(monkeypatch)
+    h, table, a_src, a_dst, src, dst, rows = _gat_case(heads, width, halo,
+                                                       seed=7)
+    plans = em.build_gat_plans(src, dst, rows, table.shape[0])
+    assert plans.dst_obi.shape[0] > 2 * em._PLAN_CB_BLOCKS  # several steps
+    sj, dj = jnp.asarray(src), jnp.asarray(dst)
+    drop = (jax.random.PRNGKey(3), dropout) if dropout else None
+
+    def plan(hh, tt, s, d):
+        return em.gat_attend_plan(hh, tt, s, d, plans, (sj, dj), 0.2,
+                                  precision, drop)
+
+    def xla(hh, tt, s, d):
+        return em.gat_attend(hh, tt, sj, dj, rows, s, d, 0.2, drop)
+
+    args = (h, table, a_src, a_dst)
+    got, want = (np.asarray(f(*args)) for f in (plan, xla))
+    assert np.isfinite(got).all()
+    assert not got[np.setdiff1d(np.arange(rows), dst)].any()
+    scale = np.abs(want).max() * np.finfo(np.float32).eps
+    assert np.abs(got - want).max() <= 64 * scale
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(jnp.sin(f(*a))),
+                      argnums=(0, 1, 2, 3))(*args) for f in (plan, xla)]
+    for name, a, b in zip(("dh", "dtable", "da_src", "da_dst"), *grads):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) <= 2e-5 * np.linalg.norm(b), name
+
+
+def test_a_gat_hub_whose_largest_score_comes_last_is_rescaled(monkeypatch):
+    """A hub row of 6,000 in-edges over at least three scan steps, its
+    largest scores (110) on its last 16 edges, in its LAST step, every
+    other score 77 to 83: exp of the largest overflows float32, so every
+    sum is taken against a running max, and what the earlier steps summed
+    must be scaled by exp(m_old - m_new) (about e^-30) when the last step
+    raises it.  The hub then reads row 0's values, as the xla road does,
+    and the rows without an in-edge read 0, not NaN."""
+    _small_steps(monkeypatch)
+    rng = np.random.default_rng(11)
+    rows, hub, K, F = 64, 17, 2, 8
+    other = rng.integers(0, 40, 2000)
+    dst = np.sort(np.concatenate([other[other != hub],
+                                  np.full(6000, hub)])).astype(np.int64)
+    src = rng.integers(1, rows, dst.size).astype(np.int64)
+    last = np.flatnonzero(dst == hub)[-16:]
+    src[last] = 0
+    t = 80.0 + rng.uniform(-3.0, 3.0, (rows, K))
+    t[0] = 110.0
+    # a_src . h_j = t[j], a_dst . h_i = 0: the score is t[src]
+    h = jnp.asarray(np.repeat(t[:, :, None] / F, F, axis=2), jnp.float32)
+    a_src = jnp.ones((K, F), jnp.float32)
+    a_dst = jnp.zeros((K, F), jnp.float32)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    obi, edst, pos = (np.asarray(a) for a in (
+        plans.dst_obi, plans.dst_edst, plans.dst_pos))
+    steps = np.unique(np.flatnonzero(obi == hub // VB) // em._PLAN_CB_BLOCKS)
+    assert steps.size >= 3
+    chunk, = np.flatnonzero(((pos == last[-1]) & (edst < VB)).any(axis=1))
+    assert chunk // em._PLAN_CB_BLOCKS == steps[-1]
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(np.float32(t.max())))
+    sj, dj = jnp.asarray(src), jnp.asarray(dst)
+    got = np.asarray(em.gat_attend_plan(h, h, a_src, a_dst, plans, (sj, dj),
+                                        0.2, "highest"))
+    assert np.isfinite(got).all()
+    assert not got[np.setdiff1d(np.arange(rows), dst)].any()
+    want = np.asarray(em.gat_attend(h, h, sj, dj, rows, a_src, a_dst, 0.2))
+    scale = np.abs(want).max() * np.finfo(np.float32).eps
+    assert np.abs(got - want).max() <= 64 * scale
+    np.testing.assert_allclose(got[hub], np.asarray(h)[0], rtol=1e-6)
+
+
+@pytest.mark.parametrize("precision", ["highest", "default"])
+def test_only_the_feature_sums_take_the_ops_precision(precision,
+                                                      monkeypatch):
+    """jax.grad of gat_attend_plan: the op's ``precision`` reaches two
+    products, u's in the forward's one scan and dtable's in the src scan
+    (under `fast`, "default": one bf16 rounding of each product, as the
+    feature sums always took it), and every other dot of the rule, the
+    score's, the max's, the normaliser's and every one-hot spread, stays
+    at "highest"."""
+    import jax
+    from jax import lax
+    _small_steps(monkeypatch)
+    h, table, a_src, a_dst, src, dst, rows = _gat_case(8, 8, False, seed=4)
+    plans = em.build_gat_plans(src, dst, rows, rows)
+    ids = (jnp.asarray(src), jnp.asarray(dst))
+
+    def loss(hh, s, d):
+        return jnp.sum(em.gat_attend_plan(hh, hh, s, d, plans, ids, 0.2,
+                                          precision) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        h, a_src, a_dst).jaxpr
+    found = {}
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "dot_general":
+            p = eqn.params["precision"]
+            key = p[0] if isinstance(p, tuple) else p
+            found[key] = found.get(key, 0) + 1
+    if precision == "highest":
+        assert set(found) == {lax.Precision.HIGHEST}
+    else:
+        assert found[lax.Precision.DEFAULT] == 2
+        assert set(found) == {lax.Precision.DEFAULT, lax.Precision.HIGHEST}

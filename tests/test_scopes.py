@@ -128,16 +128,18 @@ def test_every_while_of_the_compiled_step_has_op_pass_and_part(family,
         parts = {(p, part) for op, p, part in whiles.values()
                  if kinds.get(op) == "gat"}
         assert {("fwd", "bcast"), ("bwd", "src")} <= parts
-    if family in ("gat", "gatv2"):
+    if family == "gatv2":
         assert {("fwd", "max"), ("fwd", "norm"), ("fwd", "u")} <= parts
     if family == "gat":
         assert {("bwd", "de"), ("bwd", "dq"), ("bwd", "bcast")} <= parts
+    if family in ("gat", "tconv"):
+        # the score, its max, the normaliser and u are ONE scan forward
+        # (gat's source half of the score rides u's rows: no lane gather)
+        assert ("fwd", "su") in parts and not parts & {
+            ("fwd", "score"), ("fwd", "max"), ("fwd", "norm"), ("fwd", "u"),
+            ("fwd", "lanes")}
     if family == "gatv2":
         assert ("fwd", "score") in parts        # both rows, forward
-    if family == "tconv":
-        # the score, its max, the normaliser and u are ONE scan forward
-        assert ("fwd", "su") in parts and not parts & {
-            ("fwd", "score"), ("fwd", "max"), ("fwd", "norm"), ("fwd", "u")}
     if family in ("tconv", "gatv2"):
         # de, dz's broadcast and dq (gatv2: dxr and da) are ONE scan of the
         # backward
@@ -192,6 +194,23 @@ def test_src_scans_of_the_compiled_step_are_what_the_trainer_says(family,
         assert set(by_op.values()) == {1} and len(by_op) == ops
 
 
+def test_fwd_scans_of_the_compiled_gat_step_are_what_the_trainer_says(
+        built):
+    """`fwd_scans`: gat's forward walks the plans twice an op, `su` (the
+    score, its max, the normaliser and u) and the max's `bcast` for the
+    backward's e; the mask's random bits (a loop on the CPU, under `edge`)
+    and the op's dropout slot (no part) walk none."""
+    tr, _, text = built("gat")
+    info = tr.attention_info()
+    kinds = {scopes.op_scope(i, op.kind): op.kind
+             for i, op in enumerate(tr.model.ops)}
+    fwd = [part for op, pass_, part in _whiles(text).values()
+           if kinds.get(op) == "gat" and pass_ == "fwd"
+           and part not in (None, "edge")]
+    assert sorted(fwd) == ["bcast", "bcast", "su", "su"]
+    assert len(fwd) == info["fwd_scans"] == 4
+
+
 def test_row_scans_of_the_compiled_gatv2_step(built):
     """The dynamic score's rule gathers node rows in `row_scans` scans, 4
     an op: `score` and `u` forward, `dedq` (xl again) and `src` ([xr | du])
@@ -231,8 +250,8 @@ def test_short_scans_are_the_row_sums_of_the_shortened_trip_count(
     budget of 8 chunks at 128 lanes (the cap of `small_steps`), rows of
     256 lanes step at 4 and of 384 at 2: a tconv of hidden 2 x 64 (src 256
     lanes) then 2 x 80 (src 320) has two (its u rides the `su` scan, which
-    steps as the block-landing scans do); gat's rows are 128 lanes or
-    narrower and keep the cap."""
+    steps as the block-landing scans do, and so does gat's); gat's rows are
+    128 lanes or narrower and keep the cap."""
     from roc_tpu.ops.pallas.segment_sum import EB
     monkeypatch.setattr(em, "_PLAN_SUM_BLOCK_BYTES", 8 * EB * 128 * 4)
     if family == "tconv":
@@ -251,8 +270,8 @@ def test_short_scans_are_the_row_sums_of_the_shortened_trip_count(
     rows = [(part, int(trips[name])) for name, (_, pass_, part)
             in _whiles(text).items()
             if (pass_, part) in (("fwd", "u"), ("bwd", "src"))]
-    assert {p for p, _ in rows} == ({"src"} if family == "tconv"
-                                    else {"u", "src"})
+    assert {p for p, _ in rows} == ({"u", "src"} if family == "gatv2"
+                                    else {"src"})
     assert all(n >= cap[p] for p, n in rows)
     short = sum(n > cap[p] for p, n in rows)
     assert short == tr.attention_info()["short_scans"]

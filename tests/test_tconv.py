@@ -400,7 +400,7 @@ def test_a_row_with_no_in_edge_sums_nothing_in_the_fused_scan(monkeypatch):
     plans = em.build_gat_plans(src, dst, rows, rows)
     dplan = (plans.dst_obi, plans.dst_edst, plans.dst_pos, plans.dst_nid)
     s, m, z, u = (np.asarray(a) for a in em._score_then_sum(
-        q, None, *dplan, dst.size, em._dot_tables(k, v)))
+        q, None, *dplan, dst.size, em._dot_tables(q, k, v), "highest"))
     want_s = np.einsum("ekf,ekf->ke", np.asarray(q, np.float64)[dst],
                        np.asarray(k, np.float64)[src]) / np.sqrt(F)
     np.testing.assert_allclose(s, want_s, rtol=1e-5, atol=1e-5)
